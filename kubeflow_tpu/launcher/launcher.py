@@ -25,6 +25,7 @@ import sys
 import time
 
 from kubeflow_tpu.parallel import distributed as dist
+from kubeflow_tpu.utils.compile_cache import enable_compile_cache
 
 log = logging.getLogger(__name__)
 
@@ -138,6 +139,9 @@ def main(argv: list[str] | None = None) -> int:
     )
 
     if args.module:
+        # In-process entrypoint: this process is the one that compiles
+        # for the chip, so it is the one that places the compile cache.
+        enable_compile_cache()
         dist.initialize_from_env()
         mod_name, _, fn_name = args.module.partition(":")
         mod = importlib.import_module(mod_name)
@@ -149,6 +153,9 @@ def main(argv: list[str] | None = None) -> int:
         parser.error("either --module or a command is required")
     # The child inherits the TPUJOB_* env as-is; it calls
     # initialize_from_env itself (same contract as TF_CONFIG pass-through).
+    # A chip belongs to one process: this path never touches a JAX
+    # device (ProcessEnv is env parsing only), so the child it spawns
+    # finds the chip free.
     return run_and_stream(args.cmd)
 
 

@@ -19,13 +19,13 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import warnings
 from typing import Any
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from kubeflow_tpu.ops.attention import dense_attention, ring_attention
@@ -36,6 +36,7 @@ from kubeflow_tpu.ops.flash import (
     flash_attention,
     flash_kernel_tileable,
     flash_usable,
+    kernels_compiled,
 )
 
 
@@ -254,14 +255,15 @@ def _attend(q, k, v, mesh: Mesh | None, cfg: "TransformerConfig"):
             "or 'dense'"
         )
     if mesh is not None and mesh.shape.get("sp", 1) > 1:
-        # Ring (sequence-parallel) path. On TPU with flash-tileable local
-        # chunks, every ring hop runs the Pallas kernel (ring flash:
-        # per-device attention memory O(C·D), not O(C²)) — the
-        # long-context composition; otherwise the dense-hop ring.
+        # Ring (sequence-parallel) path. Where the kernels compile (any
+        # backend but the CPU) and the local chunks are flash-tileable,
+        # every ring hop runs the Pallas kernel (ring flash: per-device
+        # attention memory O(C·D), not O(C²)) — the long-context
+        # composition; otherwise the dense-hop ring.
         chunk = q.shape[1] // mesh.shape["sp"]
         if (
             impl in ("auto", "flash")
-            and jax.default_backend() == "tpu"
+            and kernels_compiled()
             and flash_kernel_tileable(chunk, bq)
             and flash_kernel_tileable(chunk, bk)
         ):
@@ -277,13 +279,14 @@ def _attend(q, k, v, mesh: Mesh | None, cfg: "TransformerConfig"):
     # stays as the dispatch contract.
     use_flash = impl == "flash" or (
         impl == "auto"
-        and jax.default_backend() == "tpu"
+        and kernels_compiled()
         and flash_usable(q.shape[1], k.shape[1], bq, bk)
     )
     if use_flash and mesh is not None:
         # The shard_map wrapper needs batch % (dp·fsdp) == 0 and
         # heads % tp == 0 — stricter than pjit auto-partitioning, so the
-        # auto path falls back to dense rather than erroring.
+        # auto path falls back to dense rather than erroring, and says
+        # so: O(S²) attention on an accelerator is never silent.
 
         bsz = 1
         for a in batch_axes(mesh):
@@ -296,6 +299,14 @@ def _attend(q, k, v, mesh: Mesh | None, cfg: "TransformerConfig"):
                     f"({q.shape[0]}) divisible by dp·fsdp ({bsz}) and heads "
                     f"({q.shape[2]}) divisible by tp ({tp})"
                 )
+            warnings.warn(
+                f"attention_impl='auto': batch ({q.shape[0]}) does not "
+                f"divide dp·fsdp ({bsz}) or heads ({q.shape[2]}) do not "
+                f"divide tp ({tp}); running DENSE O(S²) attention instead "
+                "of the flash kernels",
+                RuntimeWarning,
+                stacklevel=2,
+            )
             use_flash = False
     if not use_flash:
         return dense_attention(q, k, v, causal=True)
@@ -310,14 +321,14 @@ def _attend(q, k, v, mesh: Mesh | None, cfg: "TransformerConfig"):
 
     heads = "tp" if mesh.shape.get("tp", 1) > 1 else None
     spec = P(batch_axes(mesh), None, heads, None)
-    return shard_map(
+    return jax.shard_map(
         functools.partial(
             flash_attention, causal=True, block_q=bq, block_k=bk, **bwd
         ),
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-        check_rep=False,
+        check_vma=False,
     )(q, k, v)
 
 

@@ -8,6 +8,7 @@ library (scheduler, control-plane core). No packaging step, no pybind11
 from __future__ import annotations
 
 import ctypes
+import shutil
 import subprocess
 import threading
 from pathlib import Path
@@ -18,8 +19,27 @@ _BUILD = _NATIVE / "build"
 _build_lock = threading.Lock()
 
 
+def _foreign_build_dir() -> bool:
+    """Whether native/build was configured for ANOTHER source tree: the
+    directory is git-ignored, so a copy of the checkout at a different
+    root carries the old root's absolute paths in its CMakeCache.txt and
+    cmake refuses to reuse it."""
+    cache = _BUILD / "CMakeCache.txt"
+    if not cache.exists():
+        return False
+    for line in cache.read_text(errors="replace").splitlines():
+        if line.startswith("CMAKE_HOME_DIRECTORY:"):
+            home = line.partition("=")[2].strip()
+            return Path(home).resolve() != _NATIVE.resolve()
+    return True
+
+
 def ensure_built(lib_name: str) -> Path:
-    """Build (if stale) and return the path to native/build/<lib_name>."""
+    """Build (if stale) and return the path to native/build/<lib_name>.
+
+    Nothing here depends on a pre-existing build directory: a fresh
+    checkout builds from the committed sources, and a build directory
+    carried over from another root is thrown away first."""
     lib = _BUILD / lib_name
     # _build_lock exists to serialize exactly these cmake invocations
     # (two racing builders corrupt the ninja state); the subprocess IS
@@ -29,6 +49,8 @@ def ensure_built(lib_name: str) -> Path:
             _NATIVE / "CMakeLists.txt"
         ]
         src_newest = max(p.stat().st_mtime for p in sources)
+        if _foreign_build_dir():
+            shutil.rmtree(_BUILD)
         if not lib.exists() or lib.stat().st_mtime < src_newest:
             subprocess.run(  # kftpu-lint: disable=blocking-under-lock
                 ["cmake", "-S", str(_NATIVE), "-B", str(_BUILD), "-G",

@@ -20,7 +20,6 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from kubeflow_tpu.parallel.collectives import axis_size
@@ -134,10 +133,10 @@ def ring_attention(
     bspec = batch_axes(mesh)
     spec = P(bspec, sp_axis, heads_axis, None)
     body = functools.partial(_ring_body, axis=sp_axis, causal=causal)
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-        check_rep=False,
+        check_vma=False,
     )(q, k, v)

@@ -22,9 +22,11 @@ on top:
   otherwise force (a [BH, S] vector output is not lowerable). That cuts
   the lse's HBM footprint and its fwd→bwd traffic 128×. Packing happens
   in-register via (128, 128) transposes of the lane-replicated scratch
-  (a supported Mosaic relayout), not a 1-D reshape. Block sizes that are
-  not lane-aligned fall back to the replicated layout with a slim
-  [BH, S, 1] residual.
+  (a supported Mosaic relayout), not a 1-D reshape. A q block packs to
+  a (bq/128, 128) tile, which the TPU lowering accepts only when
+  bq/128 is a sublane multiple (bq % 1024 == 0) or the block is the
+  whole sequence; every other block size falls back to the replicated
+  layout with a slim [BH, S, 1] residual.
 - **Shared-delta backward.** A small precompute kernel emits
   delta = rowsum(dO ∘ O) once per backward; the backward kernels read
   it as an input instead of each recomputing the rowsum on-chip — which
@@ -42,11 +44,14 @@ on top:
   step; the two-pass backward streamed K/V per dq step AND Q/dO per
   dkv step, so fusing halves the dominant bwd HBM traffic — and the
   (s, p, ds) recurrence is computed once instead of twice (5 block
-  matmuls, not 7). The dq ring costs S·d·4 bytes of VMEM, so fusion is
-  gated by `_bwd_fused` (the same predicate `flash_schedule` reports as
-  `bwd_fused`); past the budget — or on the rectangular fallback — the
-  two-pass kernels run unchanged. `KFTPU_FLASH_FUSED_BWD=0` force-
-  disables fusion (operational escape hatch).
+  matmuls, not 7). The dq ring costs S·d·4 bytes of VMEM on top of a
+  fixed ~14 MiB, which is past the compiler's default 16 MiB scoped
+  limit from S=8k on, so the fused call asks for its VMEM explicitly
+  (`_FUSED_VMEM_BUDGET`) and fusion is gated by `_bwd_fused` (the same
+  predicate `flash_schedule` reports as `bwd_fused`); past the budget —
+  or on the rectangular fallback — the two-pass kernels run unchanged.
+  `KFTPU_FLASH_FUSED_BWD=0` force-disables fusion (operational escape
+  hatch).
 - **Internal padding.** Sequence lengths with no 8-aligned divisor pad
   to the next lane multiple inside `flash_attention`; the tail is
   masked in-kernel (`kv_len`) and sliced off the output, so ragged
@@ -66,9 +71,16 @@ a block checkpoint — the backward then never re-runs the forward kernel
 saved).
 
 Everything is wired through ``jax.custom_vjp`` so the op drops into any
-``jax.grad`` / ``pjit`` / ``shard_map`` context. On non-TPU backends the
+``jax.grad`` / ``pjit`` / ``shard_map`` context. On the CPU backend the
 same kernels run under the Pallas interpreter (slow, test-only), which is
-how the CPU test suite validates them against the dense reference.
+how the CPU test suite validates them against the dense reference; on
+every other backend they are compiled, and a device the TPU compiler does
+not know fails loudly instead of interpreting.
+
+Every `pallas_call` carries a stable `name=` (`flash_fwd_*`,
+`flash_delta`, `flash_bwd_fused`, `flash_dq_*`, `flash_dkv_*`): that is
+what `testing/hlo.pallas_kernel_names` reads out of a jaxpr to tell which
+schedule was traced, and what a profiler trace keys kernel time on.
 """
 
 from __future__ import annotations
@@ -102,10 +114,20 @@ CHECKPOINT_OUT_NAME = "flash_attn_out"
 CHECKPOINT_LSE_NAME = "flash_attn_lse"
 
 
+def kernels_compiled() -> bool:
+    """Whether Pallas kernels compile for the default backend. Only the
+    CPU backend (the test suite) interprets; any accelerator compiles,
+    so a platform string this code has never seen reaches the TPU
+    compiler and is refused there, never quietly interpreted or routed
+    to dense attention. `models/transformer._attend` dispatches on the
+    same predicate."""
+    return jax.default_backend() != "cpu"
+
+
 def _auto_interpret(interpret: bool | None) -> bool:
     if interpret is not None:
         return interpret
-    return jax.default_backend() != "tpu"
+    return not kernels_compiled()
 
 
 def _causal_mask(s, i, j, bq, bk):
@@ -130,7 +152,9 @@ def _kv_tail_mask(s, j, bk, kv_len: int):
 #              not lane-aligned).
 # The packed layout needs S and every q-block size in play (fwd and bwd)
 # to be multiples of 128 so block boundaries land on packed-row
-# boundaries. Outside the kernels the canonical form is per-row
+# boundaries, and each packed (bq/128, 128) block must itself be a
+# legal TPU tile: its second-minor dim a sublane multiple, or the whole
+# array's. Outside the kernels the canonical form is per-row
 # [BH, S, 1] ("rows"), to which both layouts convert with free reshapes.
 
 
@@ -147,7 +171,15 @@ def _lse_block(bq: int, packed: bool) -> tuple[int, ...]:
 
 
 def _lse_is_packed(sq: int, *q_blocks: int) -> bool:
-    return sq % _LANES == 0 and all(b % _LANES == 0 for b in q_blocks)
+    """The predicate the TPU lowering enforces on the packed lse block
+    (1, bq/128, 128): bq/128 divides by 8, or the block spans the
+    sequence. Interpret mode accepts any 128-multiple, which is how
+    128..896-wide blocks passed every CPU test and were refused by the
+    chip's compiler."""
+    tile = _SUBLANES * _LANES
+    return sq % _LANES == 0 and all(
+        b % tile == 0 or (b == sq and b % _LANES == 0) for b in q_blocks
+    )
 
 
 def _pack_rows(x_rep):
@@ -274,13 +306,22 @@ def _tri_tables(nq: int, order: str):
 #
 # The fused one-pass backward holds a full dq accumulator ring in VMEM
 # (one f32 row-block slot per q block: every row is live from the first
-# kv column), so it engages only while that scratch — plus the dk/dv
-# accumulators and the double-buffered streamed blocks — fits a VMEM
-# budget. ~16 MiB/core on v5e; 12 MiB leaves margin for Mosaic's own
-# buffers. At the flagship shape (S=16384, d=128, bf16, 1024² blocks)
-# the fused footprint is ~11.1 MiB, so the 16k target regime fuses; a
-# 32k/d=128 dq ring alone is 16 MiB and falls back to two-pass.
-_FUSED_VMEM_BUDGET = 12 * 1024 * 1024
+# kv column), so its footprint grows with S where every other kernel's
+# is fixed by the block sizes. What the v5e compiler allocates for it
+# (bf16, d=128, 1024² blocks; compiled for a described v5e, PR 23):
+# 14.93 MiB at S=2048, 17.93 at 8192, 21.93 at 16384 — a fixed 13.9 MiB
+# (accumulators, double-buffered in/out blocks and ~9.4 MiB of
+# (bq, bk) f32 temporaries) plus the S·d·4 ring. The compiler's default
+# scoped-VMEM limit is 16 MiB of the chip's 128, so the fused call
+# states its limit itself: `_FUSED_VMEM_BUDGET` is both what
+# `_bwd_fused` holds the modelled footprint to and the
+# `vmem_limit_bytes` handed to the compiler, a quarter of a v5e's VMEM.
+# The model is an upper bound (18.5 / 21.5 / 25.5 MiB at those shapes),
+# so what `_bwd_fused` admits, the compiler accepts. At 32k the model
+# says 33.5 MiB and the backward falls to two-pass — the same 16k/32k
+# boundary the first, too-small model (ring + accumulators + inputs
+# against 12 MiB) drew.
+_FUSED_VMEM_BUDGET = 32 * 1024 * 1024
 # Operational escape hatch: KFTPU_FLASH_FUSED_BWD=0 pins the two-pass
 # backward everywhere (e.g. if a toolchain rejects the fused kernel).
 # Read at TRACE time — jit caches a traced backward by shapes/static
@@ -305,15 +346,23 @@ def _lse_block_bytes(bq: int, packed: bool) -> int:
 def _fused_vmem_bytes(
     sq: int, bq: int, bk: int, d: int, itemsize: int, packed: bool
 ) -> int:
-    """VMEM the fused kernel needs: the dq ring (f32, one slot per q
-    block — i.e. the whole padded sequence), per-column dk/dv f32
-    accumulators, and the Pallas-double-buffered streamed blocks."""
+    """Upper bound on the scoped VMEM the compiler allocates for the
+    fused kernel: the dq ring (f32, one slot per q block — i.e. the
+    whole padded sequence), per-column dk/dv f32 accumulators, the
+    Pallas-double-buffered input AND output blocks, and the kernel
+    body's live values — three (bq, bk) f32 temporaries of the
+    s/p/dp/ds recurrence and the f32 casts of q and do. Checked against
+    the compiler's own figures over bq ∈ {256..2048}, d ∈ {64..256},
+    bf16 and f32: 10–25% above each."""
     return (
         sq * d * 4  # dq ring scratch
         + 2 * bk * d * 4  # dk/dv accumulators
         + 2 * 2 * bq * d * itemsize  # q, do blocks (double-buffered)
         + 2 * 2 * bk * d * itemsize  # k, v blocks (double-buffered)
+        + 2 * (bq + 2 * bk) * d * itemsize  # dq, dk, dv output blocks
         + 2 * 2 * _lse_block_bytes(bq, packed)  # lse, delta blocks
+        + 3 * bq * bk * 4  # s/p, dp, ds temporaries
+        + 2 * bq * d * 4  # f32 q, do
     )
 
 
@@ -322,8 +371,8 @@ def _bwd_fused(
     itemsize: int, packed: bool,
 ) -> bool:
     """Whether the backward runs the fused one-pass kernel: compact
-    causal grid (square blocks, self-attention) AND the dq ring fits
-    the VMEM budget. Shared verbatim by `flash_schedule` (reported as
+    causal grid (square blocks, self-attention) AND the modelled
+    footprint fits the VMEM the call asks the compiler for. Shared verbatim by `flash_schedule` (reported as
     `bwd_fused`) and the `_flash_bwd_kernels` dispatch, so the
     accounting benches/tests gate on is the schedule that actually
     runs."""
@@ -894,6 +943,7 @@ def _flash_fwd_impl(
             out_shape=out_shape,
             cost_estimate=cost,
             interpret=interpret,
+            name="flash_fwd_compact",
         )(rows, cols, q, k, v)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, **kernel_kw),
@@ -907,6 +957,7 @@ def _flash_fwd_impl(
         scratch_shapes=scratch,
         cost_estimate=cost,
         interpret=interpret,
+        name="flash_fwd_rect",
     )(q, k, v)
 
 
@@ -929,6 +980,7 @@ def _flash_delta_impl(o, do, block_q, interpret, packed):
             _lse_layout_shape(bh, sq, packed), jnp.float32
         ),
         interpret=interpret,
+        name="flash_delta",
     )(o, do)
 
 
@@ -972,7 +1024,7 @@ def _flash_bwd_kernels(
         if vmem > _FUSED_VMEM_BUDGET:
             raise ValueError(
                 "fused flash backward forced on an over-budget shape: "
-                f"the dq ring + accumulators need {vmem / 2**20:.1f} MiB "
+                f"the dq ring + buffers need {vmem / 2**20:.1f} MiB "
                 f"of VMEM (budget {_FUSED_VMEM_BUDGET / 2**20:.0f} MiB) "
                 "— use the two-pass path"
             )
@@ -1041,7 +1093,14 @@ def _flash_bwd_kernels(
                 jax.ShapeDtypeStruct((bh, sk, d), v.dtype),
             ],
             cost_estimate=cost,
+            # The one kernel whose footprint grows with S: past the
+            # compiler's 16 MiB default from S=8k on, so it names the
+            # limit `_bwd_fused` admitted it under.
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=_FUSED_VMEM_BUDGET
+            ),
             interpret=interpret,
+            name="flash_bwd_fused",
         )(rows_c, cols_c, q, k, v, do, lse, delta)
         return dq, dk, dv
 
@@ -1062,6 +1121,7 @@ def _flash_bwd_kernels(
             ),
             out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
             interpret=interpret,
+            name="flash_dq_compact",
         )(rows, cols, q, k, v, do, lse, delta)
         rows_c, cols_c = _tri_tables(nq, "col")
         dk, dv = pl.pallas_call(
@@ -1090,6 +1150,7 @@ def _flash_bwd_kernels(
                 jax.ShapeDtypeStruct((bh, sk, d), v.dtype),
             ],
             interpret=interpret,
+            name="flash_dkv_compact",
         )(rows_c, cols_c, q, k, v, do, lse, delta)
         return dq, dk, dv
 
@@ -1104,6 +1165,7 @@ def _flash_bwd_kernels(
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
+        name="flash_dq_rect",
     )(q, k, v, do, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -1126,6 +1188,7 @@ def _flash_bwd_kernels(
             pltpu.VMEM((bk, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_dkv_rect",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -1220,7 +1283,8 @@ def flash_attention(
     Numerically matches ``dense_attention`` (same online-softmax math) while
     never materializing the [S, S] score matrix in HBM — at S=8192 the
     dense path OOMs a 16 GB v5e chip outright; this runs. ``interpret=None``
-    autodetects: compiled on TPU, Pallas interpreter elsewhere (tests).
+    autodetects: Pallas interpreter on the CPU backend (tests), compiled
+    on anything else.
 
     Sequence lengths that don't divide into 8-aligned blocks are padded
     internally to the next lane multiple; the tail is masked in-kernel
@@ -1522,7 +1586,6 @@ def ring_flash_attention(
             q, k, v, causal=causal, block_q=block_q, block_k=block_k,
             interpret=interpret,
         )
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from kubeflow_tpu.parallel.sharding import batch_axes
@@ -1551,10 +1614,10 @@ def ring_flash_attention(
             q_, k_, v_, sp_axis, causal, block_q, block_k, interp
         )
 
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-        check_rep=False,
+        check_vma=False,
     )(q, k, v)

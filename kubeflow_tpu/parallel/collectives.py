@@ -18,18 +18,9 @@ from jax import lax
 
 
 def axis_size(axis: str) -> int:
-    """Static size of a named mesh axis, from inside traced code.
-
-    `lax.axis_size` comes and goes across jax versions (absent in the
-    pinned 0.4.x); `core.axis_frame(name)` resolves the same static int
-    from the axis environment, which is what the ring loops need — the
-    hop count must be a Python int so the ring unrolls at trace time.
-    """
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis)
-    from jax import core
-
-    return core.axis_frame(axis)
+    """Static size of a named mesh axis, from inside traced code — a
+    Python int, so the ring loops unroll at trace time."""
+    return lax.axis_size(axis)
 
 
 def axis_index(axis: str) -> jax.Array:

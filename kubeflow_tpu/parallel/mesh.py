@@ -96,18 +96,18 @@ def build_mesh(
     """Build a `jax.sharding.Mesh` for `spec` over `devices`.
 
     Uses `mesh_utils.create_device_mesh` so the logical axes are laid out
-    along the physical ICI topology (it understands TPU 2D/3D torus wraps);
-    falls back to a plain reshape for CPU/virtual device sets where there is
-    no topology to exploit.
+    along the physical ICI topology (it understands TPU 2D/3D torus wraps).
+    CPU/virtual device sets have no topology to exploit and get a plain
+    reshape; on any other platform a layout `create_device_mesh` refuses
+    is an error, not a quiet reshape that would put collective-heavy
+    axes on whatever links device order happens to give.
     """
     devices = list(devices if devices is not None else jax.devices())
     spec = (spec or MeshSpec(dp=-1)).resolve(len(devices))
     shape = spec.sizes()
-    try:
-        dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
-    except (ValueError, NotImplementedError):
-        dev_array = np.asarray(devices).reshape(shape)
-    return Mesh(dev_array, AXES)
+    if devices[0].platform == "cpu":
+        return Mesh(np.asarray(devices).reshape(shape), AXES)
+    return Mesh(mesh_utils.create_device_mesh(shape, devices=devices), AXES)
 
 
 def build_hybrid_mesh(

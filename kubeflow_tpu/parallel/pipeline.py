@@ -63,7 +63,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from kubeflow_tpu.parallel.sharding import batch_axes, batch_shard_count
 
@@ -337,11 +336,11 @@ def spmd_pipeline(
     if loss_fn is None:
 
         @partial(
-            shard_map,
+            jax.shard_map,
             mesh=mesh,
             in_specs=(param_spec, x_spec, lp_spec),
             out_specs=x_spec,
-            check_rep=False,
+            check_vma=False,
         )
         def run(params, local_x, lp):
             outputs = run_schedule(params, local_x, lp)
@@ -354,11 +353,11 @@ def spmd_pipeline(
         return run(stage_params, x, loss_params)
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(param_spec, x_spec, tgt_spec, lp_spec),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     def run_loss(params, local_x, local_targets, lp):
         outputs = run_schedule(params, local_x, lp)

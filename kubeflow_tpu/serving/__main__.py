@@ -264,7 +264,12 @@ def main() -> None:
         ModelServerApp,
         Servable,
     )
+    from kubeflow_tpu.utils.compile_cache import enable_compile_cache
     from kubeflow_tpu.web.wsgi import serve
+
+    # Every bucket program is compiled at load; a restarted or paged-in
+    # server should find them compiled.
+    enable_compile_cache()
 
     servables = []
     for spec in args.model:

@@ -602,6 +602,23 @@ class LocalReplicaRuntime:
         return replica.stats()
 
 
+def _holds_accelerator() -> bool:
+    """Whether THIS process has initialised a non-CPU JAX backend (and
+    so holds the chip). Importing jax does not; touching a device
+    does."""
+    import sys
+
+    if "jax" not in sys.modules:
+        return False
+    import jax
+    from jax._src import xla_bridge
+
+    return (
+        xla_bridge.backends_are_initialized()
+        and jax.default_backend() != "cpu"
+    )
+
+
 class ProcessReplicaRuntime:
     """Replica fleet as REAL model-server processes
     (``python -m kubeflow_tpu.serving --apiserver ... --replica ...``) —
@@ -621,6 +638,15 @@ class ProcessReplicaRuntime:
     registered as an `HttpReplica` once it appears — in-process clients
     (the RL actors, the bench) then reach process replicas through the
     same drain-aware router surface as local ones.
+
+    A worker inherits this process's environment, so it runs on whatever
+    platform ``JAX_PLATFORMS`` (or JAX's default) gives it — the chip in
+    production, the CPU under the test suite's ``JAX_PLATFORMS=cpu``;
+    pass ``extra_env`` to place workers elsewhere. A chip belongs to one
+    process at a time, so the spawning side must itself stay off the
+    accelerator: `ensure` refuses to spawn from a process that has
+    already initialised a non-CPU backend, because the worker would
+    fail or hang waiting for a chip its own parent holds.
     """
 
     def __init__(
@@ -655,6 +681,14 @@ class ProcessReplicaRuntime:
 
         proc = self._procs.get(name)
         if proc is None or proc.poll() is not None:
+            if _holds_accelerator():
+                raise RuntimeError(
+                    "ProcessReplicaRuntime: this process has initialised "
+                    "an accelerator backend and holds the chip; a spawned "
+                    "serving worker could not open it. Keep the "
+                    "controller off JAX (or on JAX_PLATFORMS=cpu) and let "
+                    "the workers own the chips."
+                )
             if proc is not None and self.router is not None:
                 # The old incarnation's endpoint is dead with it —
                 # including any pooled keep-alive sockets into it.
@@ -670,11 +704,7 @@ class ProcessReplicaRuntime:
                     "--replica", name,
                     "--namespace", self._namespace,
                 ],
-                env={
-                    **os.environ,
-                    "JAX_PLATFORMS": "cpu",
-                    **self._extra_env,
-                },
+                env={**os.environ, **self._extra_env},
                 stdout=subprocess.DEVNULL,
                 stderr=subprocess.DEVNULL,
             )

@@ -26,6 +26,7 @@ the reference never achieved that (SURVEY.md §4.3).
 
 from __future__ import annotations
 
+import glob
 import logging
 import pathlib
 import shutil
@@ -62,14 +63,20 @@ def coordinator_reachable(address: str, timeout: float = 1.0) -> bool:
         return False
 
 
-def default_device_probe() -> bool:
-    """TPU runtime ready? (the `wait for nvidia driver` analog)."""
-    try:
-        import jax
+# Where the host exposes its TPU chips: /dev/accel<N> (v2–v5e), or VFIO
+# groups on the later generations.
+TPU_DEVICE_GLOBS = ("/dev/accel[0-9]*", "/dev/vfio/[0-9]*")
 
-        return len(jax.devices()) > 0
-    except Exception:
-        return False
+
+def default_device_probe() -> bool:
+    """TPU runtime ready? (the `wait for nvidia driver` analog).
+
+    Looks for the chips' device nodes and never asks JAX: the sidecar is
+    a second process beside the worker it gates, a process that
+    initialises the TPU backend holds the chip, and the probe would take
+    it from that worker. (`jax.devices()` also answers "ready" for the
+    CPU it falls back to.)"""
+    return any(glob.glob(pattern) for pattern in TPU_DEVICE_GLOBS)
 
 
 class SidecarController:

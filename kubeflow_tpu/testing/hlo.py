@@ -117,6 +117,44 @@ def replica_group_shapes(hlo: str) -> set[str]:
     return shapes
 
 
+def _walk_eqns(jaxpr):
+    """Every equation of `jaxpr`, sub-jaxprs (jit, custom_vjp, remat,
+    shard_map, scan, cond bodies) included."""
+    import jax
+
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _walk_eqns(sub)
+
+
+def jaxpr_kernel_names(jaxpr) -> list[str]:
+    """The `name=` of every `pallas_call` in `jaxpr`, in program order,
+    one entry per call — which kernels a program runs, read from the
+    equations themselves. (Counting kernel function names in
+    `str(jaxpr)` reads 0 under the installed jax: an equation prints its
+    `name`, which is None unless the call site passes one.) A call
+    without a name is reported as "" so it still counts."""
+    return [
+        eqn.params.get("name") or ""
+        for eqn in _walk_eqns(jaxpr)
+        if eqn.primitive.name == "pallas_call"
+    ]
+
+
+def pallas_kernel_names(fn, *args) -> list[str]:
+    """`jaxpr_kernel_names` of the program `fn(*args)` traces."""
+    import jax
+
+    return jaxpr_kernel_names(jax.make_jaxpr(fn)(*args).jaxpr)
+
+
+def tpu_kernel_calls(hlo: str) -> int:
+    """Compiled Pallas kernels in HLO text: one `tpu_custom_call`
+    custom-call per launch site (interpreted kernels leave none)."""
+    return hlo.count('custom_call_target="tpu_custom_call"')
+
+
 def scan_lengths(fn, *args) -> set[int]:
     """Trip counts of every `lax.scan`/`fori_loop` in `fn`'s jaxpr
     (recursively, so scans inside shard_map/checkpoint/vmap bodies are
@@ -125,21 +163,11 @@ def scan_lengths(fn, *args) -> set[int]:
     formula it is compared against."""
     import jax
 
-    lengths: set[int] = set()
-
-    def walk(jaxpr) -> None:
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "scan":
-                lengths.add(int(eqn.params["length"]))
-            elif eqn.primitive.name == "while":
-                # fori_loop with static bounds carries them as consts in
-                # the cond jaxpr only when not lowered to scan; nothing
-                # to read generically — scan is the differentiable form
-                # the pipeline uses.
-                pass
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                walk(sub)
-
-    closed = jax.make_jaxpr(fn)(*args)
-    walk(closed.jaxpr)
-    return lengths
+    # fori_loop with static bounds carries them as consts in the cond
+    # jaxpr only when not lowered to scan; nothing to read generically
+    # from a `while` — scan is the differentiable form the pipeline uses.
+    return {
+        int(eqn.params["length"])
+        for eqn in _walk_eqns(jax.make_jaxpr(fn)(*args).jaxpr)
+        if eqn.primitive.name == "scan"
+    }

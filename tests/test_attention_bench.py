@@ -51,11 +51,17 @@ def test_attention_bench_smoke_emits_parsable_metrics():
             assert f"{stem}_s{s}" in metrics, (stem, s, sorted(metrics))
     # The schedule accounting must show the overhaul: at S=256 with
     # 128-wide blocks the compact grid runs 3 of the rectangle's 4
-    # steps, and the packed lse is 1/128th the replicated bytes.
+    # steps. The lse packs to 1/128th the replicated bytes where the
+    # packed block is a legal TPU tile — at S=128 the one block spans
+    # the array; at S=256 a (1, 128) block of a (2, 128) array is not,
+    # so that length reports the replicated layout.
     grid = metrics["attention_causal_grid_steps_s256"]
     assert grid["value"] == 3 and grid["vs_baseline"] == 0.75, grid
-    lse = metrics["attention_lse_hbm_bytes_s256"]
+    lse = metrics["attention_lse_hbm_bytes_s128"]
     assert abs(lse["vs_baseline"] - 1 / 128) < 1e-6, lse
+    assert "lane-packed" in lse["unit"], lse
+    lse = metrics["attention_lse_hbm_bytes_s256"]
+    assert lse["vs_baseline"] == 1.0 and "lane-replicated" in lse["unit"]
     # Dense ran at these lengths, so the TFLOP/s rows carry a real ratio.
     assert metrics["attention_flash_fwd_tflops_s256"]["vs_baseline"] > 0
     # Fused one-pass backward (ISSUE 7): the bwd HBM-byte row's ratio is

@@ -161,16 +161,16 @@ def test_fused_flash_bwd_shared_delta_and_single_kv_pass():
 
     _engine_rule_clean("fused-kernel-streams")
 
-    fused = flash.flash_schedule(4096, 4096, block_q=256, block_k=256)
+    # The flagship deep triangle (nq = 16, default 1024-blocks, packed
+    # lse): the byte ratio only means something there.
+    fused = flash.flash_schedule(16384, 16384)
     assert fused["bwd_fused"], fused
     assert fused["bwd_total_grid_steps"] == fused["bwd_grid_steps"], (
         "fused backward no longer single-KV-pass: "
         f"{fused['bwd_total_grid_steps']} total vs "
         f"{fused['bwd_grid_steps']} per pass"
     )
-    two_pass = flash.flash_schedule(
-        4096, 4096, block_q=256, block_k=256, causal=False
-    )
+    two_pass = flash.flash_schedule(16384, 16384, causal=False)
     assert not two_pass["bwd_fused"]
     assert (
         two_pass["bwd_total_grid_steps"] == 2 * two_pass["bwd_grid_steps"]

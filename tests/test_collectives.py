@@ -3,7 +3,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from kubeflow_tpu.parallel import collectives as col
 
@@ -11,7 +11,7 @@ from kubeflow_tpu.parallel import collectives as col
 def _smap(mesh, fn, in_specs, out_specs):
     return jax.jit(
         shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
+            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
         )
     )
 

@@ -21,6 +21,7 @@ from kubeflow_tpu.ops.flash import (
     flash_attention,
     flash_schedule,
 )
+from kubeflow_tpu.testing.hlo import pallas_kernel_names
 
 
 def _qkv(key, b, s, h, d, dtype=jnp.float32):
@@ -152,6 +153,28 @@ def test_lse_packed_layout_cuts_hbm_bytes_128x():
     assert not sched_small["lse_packed"]
 
 
+@pytest.mark.parametrize(
+    "s,block,packed",
+    [
+        (4096, 1024, True),  # (8, 128) packed tile
+        (4096, 2048, True),
+        (512, 512, True),  # block spans the array: any 128-multiple
+        (4096, 512, False),  # (4, 128): second-minor dim not 8-aligned
+        (4096, 128, False),
+        (384, 128, False),
+    ],
+)
+def test_lse_packs_only_where_the_tpu_lowering_accepts_the_block(
+    s, block, packed
+):
+    """The packed (1, bq/128, 128) lse block is a legal TPU tile only
+    when bq/128 is a sublane multiple or the block is the whole
+    sequence. Interpret mode accepts every 128-multiple, so this is
+    pinned here and compiled for the chip in test_chip_compile.py."""
+    sched = flash_schedule(s, s, block_q=block, block_k=block)
+    assert sched["lse_packed"] == packed, sched
+
+
 def test_packed_lse_values_match_dense_logsumexp():
     """The packed tiles must hold the true per-row softmax statistics:
     unpacked lse == dense log-sum-exp of the scaled causal scores."""
@@ -198,30 +221,31 @@ def test_shared_delta_precompute_matches_rowsum():
 # -- fused one-pass dq/dkv backward (ISSUE 7) -------------------------------
 
 
+def _count(names, prefix):
+    return sum(n.startswith(prefix) for n in names)
+
+
 def _bwd_kernel_counts(attn, q, k, v):
-    """(fused, two_pass_dq, two_pass_dkv) kernel-trace counts in the
+    """(fused, two_pass_dq, two_pass_dkv) kernel-call counts in the
     grad jaxpr — the same mechanical engagement check the attention
-    bench gates on."""
-    jaxpr = str(
-        jax.make_jaxpr(
-            jax.grad(
-                lambda q, k, v: jnp.sum(
-                    attn(q, k, v).astype(jnp.float32) ** 2
-                ),
-                argnums=(0, 1, 2),
-            )
-        )(q, k, v)
+    bench gates on, read from the pallas_call equations' names."""
+    names = pallas_kernel_names(
+        jax.grad(
+            lambda q, k, v: jnp.sum(attn(q, k, v).astype(jnp.float32) ** 2),
+            argnums=(0, 1, 2),
+        ),
+        q, k, v,
     )
     return (
-        jaxpr.count("_dqkv_kernel_fused"),
-        jaxpr.count("_dq_kernel"),
-        jaxpr.count("_dkv_kernel"),
+        _count(names, "flash_bwd_fused"),
+        _count(names, "flash_dq_"),
+        _count(names, "flash_dkv_"),
     )
 
 
 @pytest.mark.parametrize(
     "s,block,packed",
-    [(512, 128, True), (256, 64, False), (384, 128, True)],
+    [(512, 128, False), (256, 64, False), (256, 256, True)],
 )
 def test_fused_bwd_engages_and_matches_dense(s, block, packed):
     """The compact causal grid now runs ONE backward kernel: the
@@ -384,13 +408,14 @@ def test_fused_under_remat_flash_policy_never_reruns_fwd():
         loss_plain, policy=checkpoint_policy("flash")
     )
     grads = lambda f: jax.grad(f, argnums=(0, 1, 2))
-    jaxpr_plain = str(jax.make_jaxpr(grads(loss_plain))(q, k, v))
-    jaxpr_ckpt = str(jax.make_jaxpr(grads(loss_ckpt))(q, k, v))
+    plain = pallas_kernel_names(grads(loss_plain), q, k, v)
+    ckpt = pallas_kernel_names(grads(loss_ckpt), q, k, v)
+    assert _count(plain, "flash_fwd_") >= 1
     assert (
-        jaxpr_ckpt.count("_fwd_kernel") == jaxpr_plain.count("_fwd_kernel")
+        _count(ckpt, "flash_fwd_") == _count(plain, "flash_fwd_")
     ), "remat_policy='flash' re-runs the flash forward in the backward"
-    assert jaxpr_ckpt.count("_dqkv_kernel_fused") == 1
-    assert "_dq_kernel" not in jaxpr_ckpt
+    assert _count(ckpt, "flash_bwd_fused") == 1
+    assert _count(ckpt, "flash_dq_") == 0
     # And the checkpointed grads equal the plain ones.
     for a, b, name in zip(
         grads(loss_ckpt)(q, k, v), grads(loss_plain)(q, k, v), "qkv"

@@ -184,7 +184,7 @@ def test_native_store_lease_fencing_parity():
     # Lease-kind writes must be exempt from fencing (the election
     # protocol has to stay able to transfer ownership) — this is the
     # exemption actually exercised, not just claimed.
-    lease = api.get("Lease", "native-ctl", "")
+    lease = api.get("Lease", "native-ctl", "").thaw()
     lease.spec = dict(lease.spec)
     lease.spec["renewTime"] = 0.0
     api.update(lease, lease_guard=("", "native-ctl", "zombie", 99))
@@ -195,7 +195,7 @@ def test_native_store_lease_fencing_parity():
 
     with pytest.raises(Conflict, match="fenced"):
         api.create(new_resource("Widget", "w2"), lease_guard=guard_a)
-    w1 = api.get("Widget", "w1")
+    w1 = api.get("Widget", "w1").thaw()
     w1.spec["v"] = 2
     with pytest.raises(Conflict, match="fenced"):
         api.update(w1, lease_guard=guard_a)

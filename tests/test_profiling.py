@@ -113,7 +113,7 @@ def test_phase_roofline_math_and_bounds():
     # ~0 TFLOP, 72 GB in 100 ms = 720 GB/s (90%) vs 0% compute -> HBM.
     hbm = roof.add("optimizer", ms=100.0, tflop=0.0, gb=72.0)
     assert hbm["bound_by"] == "HBM"
-    # 64% compute vs 69% bandwidth (the r05 backward) -> mixed, HBM
+    # 64% compute vs 69% bandwidth -> mixed, HBM
     # dominant.
     mixed = roof.add("bwd", ms=100.0, tflop=12.8, gb=55.2)
     assert mixed["bound_by"] == "mixed → HBM"

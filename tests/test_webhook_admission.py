@@ -55,7 +55,7 @@ def test_webhook_mutates_on_create_and_update(tls_paths):
     server, cfg = _webhook(tls_paths)
     try:
         api.create(cfg)
-        created = api.create(_pod())
+        created = api.create(_pod()).thaw()
         env = created.spec["containers"][0]["env"]
         assert {"name": "INJECTED", "value": "CREATE"} in env
         created.spec["containers"][0]["env"] = []  # client strips it
