@@ -25,7 +25,5 @@ from kubeflow_tpu.train.profiling import (
     PhaseStat,
     Profiler,
     ProfileSchedule,
-    annotate,
-    annotated_scope,
     time_phase,
 )

@@ -35,6 +35,7 @@ no preemption handling") — the full matrix lives in docs/resilience.md:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import logging
 import os
@@ -49,8 +50,39 @@ import numpy as np
 from kubeflow_tpu.train.checkpoint import Checkpointer
 from kubeflow_tpu.train.profiling import Profiler
 from kubeflow_tpu.train.trainer import Trainer, TrainState
+from kubeflow_tpu.utils import tracing
 
 log = logging.getLogger(__name__)
+
+# The spans whose seconds since the last record every `on_metrics` record
+# carries (`data_s`, `dispatch_s`, `readback_s`, `save_s`).
+_RECORD_SPANS = ("data", "dispatch", "readback", "save")
+
+
+class _Timings:
+    """fit()'s one timing path: a span of the process's tracer (the ring
+    always, the profile when one is being taken) whose duration is also
+    added up by name, without the `train.` prefix."""
+
+    def __init__(self) -> None:
+        self.totals: dict[str, dict[str, float]] = {}
+
+    @contextlib.contextmanager
+    def span(self, name: str, **attributes: Any):
+        span = None
+        try:
+            with tracing.tracer.span(f"train.{name}", **attributes) as span:
+                yield span
+        finally:
+            if span is not None:
+                total = self.totals.setdefault(
+                    name, {"count": 0, "seconds": 0.0}
+                )
+                total["count"] += 1
+                total["seconds"] += span.duration_ns / 1e9
+
+    def seconds(self, name: str) -> float:
+        return self.totals.get(name, {}).get("seconds", 0.0)
 
 
 class TrainingDiverged(RuntimeError):
@@ -142,6 +174,11 @@ class FitResult:
     rollbacks: int = 0
     # Elastic mesh resizes performed (ElasticResize runs; [] otherwise).
     resizes: list[ResizeEvent] = dataclasses.field(default_factory=list)
+    # This call's `train.*` spans added up by name, prefix dropped:
+    # {"data": {"count": 25, "seconds": 9.9}, "dispatch": ..., ...}.
+    timings: dict[str, dict[str, float]] = dataclasses.field(
+        default_factory=dict
+    )
 
 
 @dataclasses.dataclass
@@ -192,16 +229,19 @@ def fit(
     """
     rng = rng if rng is not None else jax.random.PRNGKey(0)
     guard = trainer.guard
+    timings = _Timings()
 
     resumed_from = None
     state = None
     if checkpointer is not None:
-        restored = checkpointer.restore_latest(trainer.abstract_state())
+        with timings.span("restore"):
+            restored = checkpointer.restore_latest(trainer.abstract_state())
         if restored is not None:
             state, resumed_from = restored.state, int(restored.step)
             _load_data_state(data, restored.data_state)
     if state is None:
-        state = trainer.init_state(rng)
+        with timings.span("init"):
+            state = trainer.init_state(rng)
 
     start_step = int(state.step)
     if start_step >= total_steps:
@@ -210,14 +250,17 @@ def fit(
             start_step, total_steps,
         )
         return FitResult(
-            state=state, history=[], steps_done=0, resumed_from=resumed_from
+            state=state, history=[], steps_done=0,
+            resumed_from=resumed_from, timings=timings.totals,
         )
 
-    step_fn = trainer.make_train_step()
+    with timings.span("init"):
+        step_fn = trainer.make_train_step()
     it = iter(data)
     history: list[dict] = []
     t_last = time.perf_counter()
     examples = 0
+    recorded = dict.fromkeys(_RECORD_SPANS, 0.0)
     rollbacks = 0
     resizes: list[ResizeEvent] = []
     preempt: dict = {"signum": None}
@@ -324,194 +367,221 @@ def fit(
     step = start_step
     try:
         while step < total_steps:
-            try:
-                batch = next(it)
-            except StopIteration:
-                raise ValueError(
-                    f"data iterable exhausted at step {step} "
-                    f"(needed {total_steps})"
-                ) from None
-            if profiler is not None:
-                profiler.before_step(step)
-            state, metrics = step_fn(state, batch)
-            if profiler is not None:
-                profiler.after_step(step)
-            step += 1
-            examples += trainer.config.batch_size
-            is_last = step == total_steps
-            preempted = preempt["signum"] is not None
-            want_save = checkpointer is not None and (
-                checkpointer.should_save(step) or is_last
-            )
-            # A preempted boundary always logs: the exit step must reach
-            # history/on_metrics before the loop returns.
-            want_log = step % log_every == 0 or is_last or preempted
-
-            # Guard verdicts are device scalars; read them only where
-            # the host syncs anyway (boundaries), never per step.
-            if guard is not None and (want_save or want_log or preempted):
-                if guard.diverged(state.guard):
-                    if preempted or rollbacks >= max_rollbacks:
-                        # Dying or out of budget: the last good
-                        # checkpoint stays the recovery point — never
-                        # save (or roll back under) a diverged state.
-                        raise TrainingDiverged(
-                            f"sustained divergence at step {step} after "
-                            f"{rollbacks} rollback(s)"
-                        )
-                    rollbacks += 1
-                    state, step = rollback(step)
-                    continue
-
-            saved = False
-            if want_save:
-                if guard is None:
-                    check_finite(metrics, step)
-                checkpointer.save(
-                    step, state,
-                    force=is_last or preempted,
-                    data_state=_data_state(data),
+            # The parent span of one iteration carries the number of the
+            # step it completes (the `step` of a record): a
+            # StepTraceAnnotation, so a profile's step view groups by it.
+            with timings.span("step", step_num=step + 1):
+                with timings.span("data"):
+                    try:
+                        batch = next(it)
+                    except StopIteration:
+                        raise ValueError(
+                            f"data iterable exhausted at step {step} "
+                            f"(needed {total_steps})"
+                        ) from None
+                if profiler is not None:
+                    profiler.before_step(step)
+                with timings.span("dispatch"):
+                    state, metrics = step_fn(state, batch)
+                if profiler is not None:
+                    profiler.after_step(step)
+                step += 1
+                examples += trainer.config.batch_size
+                is_last = step == total_steps
+                preempted = preempt["signum"] is not None
+                want_save = checkpointer is not None and (
+                    checkpointer.should_save(step) or is_last
                 )
-                saved = True
-            if want_log:
-                if guard is None:
-                    loss = check_finite(metrics, step)
-                else:
-                    # A skipped step may legitimately log a non-finite
-                    # loss — the update was rejected on device, so the
-                    # STATE stayed finite; nothing here can persist it.
-                    loss = float(metrics["loss"])
-                now = time.perf_counter()
-                rec = {
-                    "step": step,
-                    "loss": loss,
-                    # Absent in train_metrics="loss" mode (LM trainers
-                    # skip the per-step full-vocab argmax).
-                    "accuracy": float(metrics.get("accuracy", float("nan"))),
-                    "examples_per_sec": examples / (now - t_last),
-                }
-                if guard is not None:
-                    rec["grad_norm"] = float(metrics["grad_norm"])
-                    rec["guard_skipped_total"] = int(
-                        metrics["guard_skipped_total"]
-                    )
-                    rec["rollbacks"] = rollbacks
-                history.append(rec)
-                if on_metrics is not None:
-                    on_metrics(step, rec)
-                log.info(
-                    "step %d loss %.4f acc %.3f %.1f ex/s",
-                    rec["step"], rec["loss"], rec["accuracy"],
-                    rec["examples_per_sec"],
-                )
-                t_last, examples = now, 0
-            # -- elastic resize (docs/resilience.md) -------------------
-            # Polled at the boundary AFTER save/log so the transition
-            # always starts from a fully-accounted step. A proposal
-            # arriving with a preemption signal absorbs it: the gang
-            # reshapes instead of dying, and the loop keeps training —
-            # the whole point of shrink-to-fit over gang restart.
-            if elastic is not None and not is_last:
-                proposal = elastic.propose(step, preempted)
-                if proposal is not None and proposal.dp != _mesh_dp(trainer):
-                    t0 = time.perf_counter()
-                    from_dp = _mesh_dp(trainer)
-                    at_step = step
-                    new_mesh = elastic.mesh_factory(proposal.dp)
-                    new_trainer = trainer.resize(new_mesh)
-                    restored_step = None
-                    if proposal.source == "checkpoint":
-                        # Part of the old mesh is already gone (a host
-                        # died with its shards): the live state is not
-                        # recoverable — restore the newest verified
-                        # checkpoint INTO the new topology. Checkpoints
-                        # hold global arrays, so the restore is shape-
-                        # polymorphic on dp by construction.
-                        if checkpointer is None:
-                            raise RuntimeError(
-                                "resize with source='checkpoint' needs "
-                                "a checkpointer (the live state went "
-                                "down with the dead host)"
+                # A preempted boundary always logs: the exit step must
+                # reach history/on_metrics before the loop returns.
+                want_log = step % log_every == 0 or is_last or preempted
+
+                # Guard verdicts are device scalars; read them only where
+                # the host syncs anyway (boundaries), never per step.
+                if guard is not None and (want_save or want_log or preempted):
+                    with timings.span("readback"):
+                        diverged = guard.diverged(state.guard)
+                    if diverged:
+                        if preempted or rollbacks >= max_rollbacks:
+                            # Dying or out of budget: the last good
+                            # checkpoint stays the recovery point — never
+                            # save (or roll back under) a diverged state.
+                            raise TrainingDiverged(
+                                f"sustained divergence at step {step} after "
+                                f"{rollbacks} rollback(s)"
                             )
-                        restored = checkpointer.restore_latest(
-                            new_trainer.abstract_state()
+                        rollbacks += 1
+                        with timings.span("rollback"):
+                            state, step = rollback(step)
+                        continue
+
+                saved = False
+                if want_save:
+                    if guard is None:
+                        with timings.span("readback"):
+                            check_finite(metrics, step)
+                    with timings.span("save"):
+                        checkpointer.save(
+                            step, state,
+                            force=is_last or preempted,
+                            data_state=_data_state(data),
                         )
-                        if restored is None:
-                            raise RuntimeError(
-                                f"resize at step {step}: no valid "
-                                "checkpoint to restore into the new "
-                                "topology"
+                    saved = True
+                if want_log:
+                    with timings.span("readback"):
+                        if guard is None:
+                            loss = check_finite(metrics, step)
+                        else:
+                            # A skipped step may legitimately log a
+                            # non-finite loss — the update was rejected on
+                            # device, so the STATE stayed finite; nothing
+                            # here can persist it.
+                            loss = float(metrics["loss"])
+                        rec = {
+                            "step": step,
+                            "loss": loss,
+                            # Absent in train_metrics="loss" mode (LM
+                            # trainers skip the per-step full-vocab argmax).
+                            "accuracy": float(
+                                metrics.get("accuracy", float("nan"))
+                            ),
+                        }
+                        if guard is not None:
+                            rec["grad_norm"] = float(metrics["grad_norm"])
+                            rec["guard_skipped_total"] = int(
+                                metrics["guard_skipped_total"]
                             )
-                        state = restored.state
-                        restored_step = step = int(restored.step)
-                        data_state = restored.data_state
-                    else:
-                        # Happy path: re-shard the LIVE state across
-                        # device sets — no checkpoint round-trip, no
-                        # recomputed steps.
-                        state = new_trainer.reshard_state(state)
-                        data_state = _data_state(data)
-                    trainer = new_trainer
-                    data = elastic.data_factory(new_mesh, data)
-                    # Transplant the resumable-data state: content is a
-                    # pure function of (seed, salt, position), never the
-                    # mesh, so the (step -> position) identity mapping
-                    # holds across the resize — zero repeated or
-                    # skipped batches.
-                    _load_data_state(data, data_state)
-                    it = iter(data)
-                    step_fn = trainer.make_train_step()
-                    event = ResizeEvent(
-                        step=at_step,
-                        from_dp=from_dp,
-                        to_dp=proposal.dp,
-                        source=proposal.source,
-                        seconds=time.perf_counter() - t0,
-                        absorbed_signum=(
-                            preempt["signum"] if preempted else None
-                        ),
-                        restored_step=restored_step,
+                            rec["rollbacks"] = rollbacks
+                    now = time.perf_counter()
+                    rec["examples_per_sec"] = examples / (now - t_last)
+                    # Where the host's time went since the last record.
+                    for name in _RECORD_SPANS:
+                        total = timings.seconds(name)
+                        rec[f"{name}_s"] = total - recorded[name]
+                        recorded[name] = total
+                    history.append(rec)
+                    if on_metrics is not None:
+                        on_metrics(step, rec)
+                    log.info(
+                        "step %d loss %.4f acc %.3f %.1f ex/s",
+                        rec["step"], rec["loss"], rec["accuracy"],
+                        rec["examples_per_sec"],
                     )
-                    resizes.append(event)
+                    t_last, examples = now, 0
+                # -- elastic resize (docs/resilience.md) -----------------
+                # Polled at the boundary AFTER save/log so the transition
+                # always starts from a fully-accounted step. A proposal
+                # arriving with a preemption signal absorbs it: the gang
+                # reshapes instead of dying, and the loop keeps training —
+                # the whole point of shrink-to-fit over gang restart.
+                if elastic is not None and not is_last:
+                    proposal = elastic.propose(step, preempted)
+                    if (
+                        proposal is not None
+                        and proposal.dp != _mesh_dp(trainer)
+                    ):
+                        from_dp = _mesh_dp(trainer)
+                        at_step = step
+                        with timings.span("resize") as resize_span:
+                            new_mesh = elastic.mesh_factory(proposal.dp)
+                            new_trainer = trainer.resize(new_mesh)
+                            restored_step = None
+                            if proposal.source == "checkpoint":
+                                # Part of the old mesh is already gone (a
+                                # host died with its shards): the live
+                                # state is not recoverable — restore the
+                                # newest verified checkpoint INTO the new
+                                # topology. Checkpoints hold global
+                                # arrays, so the restore is shape-
+                                # polymorphic on dp by construction.
+                                if checkpointer is None:
+                                    raise RuntimeError(
+                                        "resize with source='checkpoint' "
+                                        "needs a checkpointer (the live "
+                                        "state went down with the dead "
+                                        "host)"
+                                    )
+                                restored = checkpointer.restore_latest(
+                                    new_trainer.abstract_state()
+                                )
+                                if restored is None:
+                                    raise RuntimeError(
+                                        f"resize at step {step}: no valid "
+                                        "checkpoint to restore into the "
+                                        "new topology"
+                                    )
+                                state = restored.state
+                                restored_step = step = int(restored.step)
+                                data_state = restored.data_state
+                            else:
+                                # Happy path: re-shard the LIVE state
+                                # across device sets — no checkpoint
+                                # round-trip, no recomputed steps.
+                                state = new_trainer.reshard_state(state)
+                                data_state = _data_state(data)
+                            trainer = new_trainer
+                            data = elastic.data_factory(new_mesh, data)
+                            # Transplant the resumable-data state: content
+                            # is a pure function of (seed, salt,
+                            # position), never the mesh, so the (step ->
+                            # position) identity mapping holds across the
+                            # resize — zero repeated or skipped batches.
+                            _load_data_state(data, data_state)
+                            it = iter(data)
+                            step_fn = trainer.make_train_step()
+                        event = ResizeEvent(
+                            step=at_step,
+                            from_dp=from_dp,
+                            to_dp=proposal.dp,
+                            source=proposal.source,
+                            seconds=resize_span.duration_ns / 1e9,
+                            absorbed_signum=(
+                                preempt["signum"] if preempted else None
+                            ),
+                            restored_step=restored_step,
+                        )
+                        resizes.append(event)
+                        log.warning(
+                            "elastic resize at step %d: dp %d -> %d "
+                            "(source=%s, absorbed_signum=%s, %.2fs)",
+                            event.step, event.from_dp, event.to_dp,
+                            event.source, event.absorbed_signum,
+                            event.seconds,
+                        )
+                        if elastic.on_resize is not None:
+                            elastic.on_resize(event)
+                        if preempted:
+                            # Absorbed: the preemption cost a resize, not
+                            # the gang.
+                            preempt["signum"] = None
+                            preempted = False
+                if preempted:
+                    if checkpointer is not None and not saved:
+                        # Emergency save at the boundary: the preemption
+                        # costs zero steps.
+                        with timings.span("save"):
+                            checkpointer.save(
+                                step, state, force=True,
+                                data_state=_data_state(data),
+                            )
                     log.warning(
-                        "elastic resize at step %d: dp %d -> %d "
-                        "(source=%s, absorbed_signum=%s, %.2fs)",
-                        event.step, event.from_dp, event.to_dp,
-                        event.source, event.absorbed_signum,
-                        event.seconds,
+                        "preemption signal %s honored at step %d: %s, "
+                        "exiting cleanly",
+                        preempt["signum"], step,
+                        "emergency save done" if checkpointer is not None
+                        else "NO checkpointer — progress not saved",
                     )
-                    if elastic.on_resize is not None:
-                        elastic.on_resize(event)
-                    if preempted:
-                        # Absorbed: the preemption cost a resize, not
-                        # the gang.
-                        preempt["signum"] = None
-                        preempted = False
-            if preempted:
-                if checkpointer is not None and not saved:
-                    # Emergency save at the boundary: the preemption
-                    # costs zero steps.
-                    checkpointer.save(
-                        step, state, force=True,
-                        data_state=_data_state(data),
+                    result = Preempted(
+                        state=state,
+                        history=history,
+                        steps_done=step - start_step,
+                        resumed_from=resumed_from,
+                        rollbacks=rollbacks,
+                        resizes=resizes,
+                        timings=timings.totals,
+                        signum=preempt["signum"],
                     )
-                log.warning(
-                    "preemption signal %s honored at step %d: %s, "
-                    "exiting cleanly",
-                    preempt["signum"], step,
-                    "emergency save done" if checkpointer is not None
-                    else "NO checkpointer — progress not saved",
-                )
-                result = Preempted(
-                    state=state,
-                    history=history,
-                    steps_done=step - start_step,
-                    resumed_from=resumed_from,
-                    rollbacks=rollbacks,
-                    resizes=resizes,
-                    signum=preempt["signum"],
-                )
-                break
+                    break
     finally:
         # Even on the exception path: restore signal disposition, make
         # enqueued saves durable (the last good checkpoint is the
@@ -527,7 +597,8 @@ def fit(
                 # failure here means the "saved" work is NOT safe —
                 # surface it instead of returning a result that claims
                 # zero lost steps.
-                checkpointer.wait()
+                with timings.span("save"):
+                    checkpointer.wait()
             else:
                 # An exception is already unwinding (TrainingDiverged,
                 # a KeyboardInterrupt escalation): that is the story —
@@ -536,7 +607,8 @@ def fit(
                 # the in-flight exception and break callers' typed
                 # handling.
                 try:
-                    checkpointer.wait()
+                    with timings.span("save"):
+                        checkpointer.wait()
                 except Exception:
                     log.exception(
                         "checkpoint wait failed while another "
@@ -552,4 +624,5 @@ def fit(
         resumed_from=resumed_from,
         rollbacks=rollbacks,
         resizes=resizes,
+        timings=timings.totals,
     )

@@ -10,8 +10,8 @@ with `logspath` at that directory serves it. Capture is windowed because
 tracing is expensive: profile steps [start, start+steps), not the whole
 run.
 
-Also here: `annotate` / `annotated_scope` — TraceAnnotation wrappers so
-named regions show up on the trace timeline — and the per-phase
+Named regions on the trace timeline are spans of `utils/tracing`
+(`tracing.tracer.span`), which `fit()` reports to. Also here: the per-phase
 roofline layer (`time_phase`, `PhaseRoofline`): the mechanical version
 of the hand-built phase table in docs/architecture.md Round 5. A bench
 times each phase of a step (attention fwd/bwd, MLP, optimizer) behind a
@@ -25,7 +25,6 @@ one-off spreadsheet.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import json
 import logging
 import pathlib
@@ -260,25 +259,6 @@ class PhaseRoofline:
                 f"({r['bw_frac'] * 100:.0f}%) | {r['bound_by']} |"
             )
         return "\n".join(lines)
-
-
-def annotate(name: str):
-    """Decorator: mark a function as a named region on the trace."""
-
-    def deco(fn):
-        @functools.wraps(fn)
-        def wrapped(*args, **kwargs):
-            with jax.profiler.TraceAnnotation(name):
-                return fn(*args, **kwargs)
-
-        return wrapped
-
-    return deco
-
-
-def annotated_scope(name: str):
-    """Context manager: named region on the trace timeline."""
-    return jax.profiler.TraceAnnotation(name)
 
 
 class MetricsLogger:

@@ -16,10 +16,10 @@ from kubeflow_tpu.train import (
     SyntheticImages,
     TrainConfig,
     Trainer,
-    annotated_scope,
     fit,
     time_phase,
 )
+from kubeflow_tpu.utils import tracing
 
 
 def test_schedule_validation():
@@ -59,7 +59,7 @@ def test_windowed_capture_writes_tb_profile_layout(tmp_path, devices):
 def test_close_is_crash_safe(tmp_path):
     profiler = Profiler(tmp_path, ProfileSchedule(start_step=0, num_steps=100))
     profiler.before_step(0)  # trace live
-    with annotated_scope("region"):
+    with tracing.tracer.span("region"):
         jnp.ones((4, 4)).sum().block_until_ready()
     profiler.close()  # must stop cleanly even though window isn't done
     assert profiler.trace_written

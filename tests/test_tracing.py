@@ -152,3 +152,124 @@ def test_http_client_propagates_active_trace():
     # tracer while this test runs.
     http = [s for s in tracing.tracer.export() if s["name"] == "http"]
     assert want in {s["traceId"] for s in http}
+
+
+# -- the span primitive on the profiler's clock (ISSUE 26) -------------------
+
+
+def test_durations_are_monotonic_under_a_stepped_wall_clock(monkeypatch):
+    """The wall clock may step (NTP, a suspended VM); start, end and the
+    duration come from `perf_counter_ns` and cannot go backwards."""
+    wall = iter([1000.0, 400.0, 300.0])  # steps back between reads
+    monkeypatch.setattr(tracing.time, "time", lambda: next(wall))
+    t = Tracer()
+    with t.span("outer"):
+        with t.span("inner"):
+            pass
+    inner, outer = t.export()
+    for rec in (inner, outer):
+        assert rec["endNs"] >= rec["startNs"]
+        assert rec["durationMs"] == (rec["endNs"] - rec["startNs"]) / 1e6
+        assert rec["end"] >= rec["start"]  # start + monotonic duration
+    assert outer["start"] == 1000.0 and inner["start"] == 400.0
+    assert outer["startNs"] <= inner["startNs"] <= inner["endNs"] <= outer["endNs"]
+
+
+def test_ids_are_unique_across_threads():
+    t = Tracer(capacity=20000)
+    barrier = threading.Barrier(8)
+
+    def worker():
+        barrier.wait()
+        for _ in range(1000):
+            with t.span("w"):
+                pass
+
+    threads = [threading.Thread(target=worker) for _ in range(8)]
+    for th in threads:
+        th.start()
+    for th in threads:
+        th.join(timeout=60)
+        assert not th.is_alive()
+    spans = t.export()
+    assert len(spans) == 8000
+    ids = [s["spanId"] for s in spans] + [s["traceId"] for s in spans]
+    assert len(set(ids)) == 16000
+    assert all(len(i) == 16 for i in ids)
+
+
+def _profile_events(logdir) -> dict[str, list[dict]]:
+    """Host-plane events of the one profile under `logdir`, by name, each
+    with its stats."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(f"{logdir}/plugins/profile/*/*.xplane.pb")
+    out: dict[str, list[dict]] = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                out.setdefault(ev.name, []).append({
+                    "start_ns": ev.start_ns, "duration_ns": ev.duration_ns,
+                    "stats": dict(ev.stats),
+                })
+    return out
+
+
+def test_span_lands_in_a_profile_taken_meanwhile(tmp_path):
+    """In a process that has imported jax a span is also a TraceAnnotation:
+    the same span is in the ring and, with its attributes, on the host
+    plane of the profile — with no flag set anywhere."""
+    import jax
+    import jax.numpy as jnp
+
+    t = Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with t.span("unit.step", step_num=7):
+            with t.span("unit.child", what="sum"):
+                jnp.ones((8, 8)).sum().block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    with t.span("unit.after"):  # no profile running: the ring only
+        pass
+    events = _profile_events(tmp_path)
+    (step,), (child,) = events["unit.step"], events["unit.child"]
+    assert int(step["stats"]["step_num"]) == 7
+    assert child["stats"]["what"] == "sum"
+    assert step["start_ns"] <= child["start_ns"]
+    assert (child["start_ns"] + child["duration_ns"]
+            <= step["start_ns"] + step["duration_ns"])
+    assert "unit.after" not in events
+    ring = {s["name"]: s for s in t.export()}
+    assert set(ring) == {"unit.step", "unit.child", "unit.after"}
+    # the annotation is entered before the ring's stamp and left after it
+    assert ring["unit.child"]["durationMs"] * 1e6 <= child["duration_ns"] + 1
+
+
+def test_a_process_without_jax_imports_none_through_tracing():
+    """A controller or web process pays nothing new: `utils/tracing` never
+    imports jax, and a span there writes no annotation."""
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "from kubeflow_tpu.utils import tracing\n"
+        "with tracing.tracer.span('work', k=1):\n"
+        "    pass\n"
+        "(rec,) = tracing.tracer.export()\n"
+        "assert rec['name'] == 'work' and rec['durationMs'] >= 0\n"
+        "assert not [m for m in sys.modules if m == 'jax' or "
+        "m.startswith(('jax.', 'jaxlib'))], 'jax was imported'\n"
+        "print('clean')\n"
+    )
+    out = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        timeout=120,
+    )
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip() == "clean"
