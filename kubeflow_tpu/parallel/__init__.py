@@ -15,6 +15,7 @@ from kubeflow_tpu.parallel.mesh import (
     local_mesh_spec,
     mesh_spec_of,
     resize_spec,
+    step_compiler_options,
 )
 from kubeflow_tpu.parallel.sharding import (
     LogicalRules,

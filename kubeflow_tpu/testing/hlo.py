@@ -37,6 +37,33 @@ def collective_counts(hlo: str) -> dict[str, int]:
     return {op: len(re.findall(rf"\b{op}", hlo)) for op in COLLECTIVE_OPS}
 
 
+_START_OPS = "|".join(op for op in COLLECTIVE_OPS if op != "dynamic-slice")
+
+
+def async_collective_counts(hlo: str) -> dict[str, int]:
+    """Collectives of compiled HLO text that the scheduler may run beside
+    compute, by the form they take:
+
+    - `start`: instructions whose opcode is `<collective>-start` (its
+      `-done` closes the interval);
+    - `fusion`: instructions of the entry computation that call an
+      `async_collective_fusion` computation (the TPU compiler's form: the
+      collective in one fusion with the compute it runs beside);
+    - `tagged`: collectives carrying an `async_collective_name` attribute
+      (the TPU compiler's final text keeps the plain opcode and names the
+      start it scheduled there).
+
+    All zero is a program whose collectives are synchronous."""
+    entry = re.search(r"^ENTRY .*?^\}", hlo, re.M | re.S)
+    return {
+        "start": len(re.findall(rf"\s(?:{_START_OPS})-start\(", hlo)),
+        "fusion": len(re.findall(
+            r"calls=%?async_collective_fusion", entry.group(0) if entry else ""
+        )),
+        "tagged": len(re.findall(r'async_collective_name="', hlo)),
+    }
+
+
 def assert_collectives(
     name: str,
     hlo: str,

@@ -25,6 +25,7 @@ from flax import core, struct
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from kubeflow_tpu.parallel import sharding as shlib
+from kubeflow_tpu.parallel.mesh import step_compiler_options
 
 
 def _ensure_partitionable_rng() -> None:
@@ -573,6 +574,7 @@ class Trainer:
             train_step,
             donate_argnums=0,
             out_shardings=(self.state_shardings(), None),
+            compiler_options=step_compiler_options(self.mesh),
         )
 
     def make_eval_step(self):

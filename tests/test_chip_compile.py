@@ -17,6 +17,7 @@ cannot be read back without one).
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -29,8 +30,11 @@ from kubeflow_tpu.ops.flash import (
     flash_schedule,
     ring_flash_attention,
 )
+from kubeflow_tpu.parallel import step_compiler_options
 from kubeflow_tpu.testing.hlo import (
+    async_collective_counts,
     collective_counts,
+    compiled_hlo,
     pallas_kernel_names,
     tpu_kernel_calls,
 )
@@ -183,3 +187,85 @@ def test_ring_flash_on_four_chips_moves_kv_by_permutes_only(topo):
         assert counts["collective-permute"] > 0, counts
         assert counts["all-gather"] == 0, counts
         assert names and tpu_kernel_calls(text) > 0
+
+
+def _olmo_1b_step(devices, dp, tp):
+    """(trainer, abstract arguments) of cell 2's step program
+    (`benchmarks/workloads/olmo-1b.train-2k-dp2tp2.json`: OLMo-1B's widths,
+    8 x 2048 tokens, adamw, no remat) cut to one layer, which already shows
+    every form, on described devices."""
+    from kubeflow_tpu.models.transformer import TransformerConfig, TransformerLM
+    from kubeflow_tpu.parallel import MeshSpec, build_mesh
+    from kubeflow_tpu.train import TrainConfig, Trainer
+
+    mesh = build_mesh(MeshSpec(dp=dp, tp=tp), list(devices)[: dp * tp])
+    cfg = TransformerConfig(
+        vocab_size=50304, d_model=2048, n_layers=1, n_heads=16,
+        head_dim=HEAD_DIM, d_ff=8192, remat_policy="none",
+    )
+    config = TrainConfig(
+        batch_size=8, optimizer="adamw", label_smoothing=0.0,
+        fsdp_params=False, train_metrics="loss",
+    )
+    trainer = Trainer(
+        TransformerLM(cfg, mesh=mesh), config, mesh,
+        example_input_shape=(2, 2048), example_input_dtype=jnp.int32,
+        input_key="tokens", label_key="labels",
+    )
+    tokens = jax.ShapeDtypeStruct(
+        (8, 2048), jnp.int32, sharding=trainer.batch_sharding(2)
+    )
+    return trainer, (trainer.abstract_state(), {"tokens": tokens, "labels": tokens})
+
+
+def _as_on_the_chip(monkeypatch):
+    """`jax.default_backend()` is still the CPU here: steer the two places
+    that ask it, so the step holds the compiled kernels as on the chip."""
+    from kubeflow_tpu.models import transformer
+    from kubeflow_tpu.ops import flash
+
+    monkeypatch.setattr(transformer, "kernels_compiled", lambda: True)
+    monkeypatch.setattr(flash, "kernels_compiled", lambda: True)
+
+
+def _replica_groups(text):
+    return set(re.findall(r"replica_groups=(\S+?),? ", text))
+
+
+def test_four_chip_step_compiles_with_asynchronous_collectives(
+    topo, monkeypatch
+):
+    """The mechanism's engagement counter where there is no chip: on a
+    `dp=2, tp=2` mesh of TPU devices `make_train_step()` hands the
+    compiler `step_compiler_options`, the installed libtpu accepts every
+    name, and the compiled step holds collectives in asynchronous form —
+    the same all-reduces over the same groups as without the options,
+    which holds none."""
+    from kubeflow_tpu.train import trainer as trainer_module
+
+    _as_on_the_chip(monkeypatch)
+    trainer, args = _olmo_1b_step(topo.devices, dp=2, tp=2)
+    assert step_compiler_options(trainer.mesh)
+    text = compiled_hlo(trainer.make_train_step(), *args)
+    monkeypatch.setattr(trainer_module, "step_compiler_options", lambda mesh: None)
+    plain = compiled_hlo(trainer.make_train_step(), *args)
+
+    assert sum(async_collective_counts(plain).values()) == 0
+    counts = async_collective_counts(text)
+    assert counts["fusion"] > 0 and counts["tagged"] > 0, counts
+    assert _replica_groups(text) == _replica_groups(plain) != set()
+    assert tpu_kernel_calls(text) == tpu_kernel_calls(plain) > 0
+
+
+def test_one_chip_step_gets_no_options_and_no_collective(topo, monkeypatch):
+    """One device: `jax.jit` receives what it received before the options
+    existed, and the program has nothing to schedule between chips."""
+    _as_on_the_chip(monkeypatch)
+    trainer, args = _olmo_1b_step(topo.devices, dp=1, tp=1)
+    assert step_compiler_options(trainer.mesh) is None
+    text = compiled_hlo(trainer.make_train_step(), *args)
+    counts = collective_counts(text)
+    del counts["dynamic-slice"]  # rides along for the CPU backend only
+    assert sum(counts.values()) == 0, counts
+    assert sum(async_collective_counts(text).values()) == 0
+    assert tpu_kernel_calls(text) > 0

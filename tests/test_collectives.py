@@ -2,10 +2,12 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 from jax import shard_map
 
 from kubeflow_tpu.parallel import collectives as col
+from kubeflow_tpu.testing.hlo import async_collective_counts
 
 
 def _smap(mesh, fn, in_specs, out_specs):
@@ -80,3 +82,61 @@ def test_all_to_all(mesh8):
 
     y2 = _smap(mesh8, g, P("tp", None), P("tp", None))(x)
     np.testing.assert_allclose(np.asarray(y2), x)
+
+
+# The forms an asynchronous collective takes in compiled HLO text, cut
+# down from real programs: the start/done pair (CPU, GPU, the TPU
+# compiler before its last passes), and the TPU compiler's final text,
+# where one all-reduce sits in an `async_collective_fusion` beside a
+# matmul and another keeps its opcode and names the start it was given.
+_HLO_START_DONE = """
+HloModule jit_f, is_scheduled=true
+
+ENTRY %main.4 (x: f32[8]) -> f32[8] {
+  %x = f32[8]{0} parameter(0)
+  %all-reduce-start = f32[8]{0} all-reduce-start(%x), channel_id=1, replica_groups={{0,1}}, to_apply=%add
+  %mul = f32[8]{0} multiply(%x, %x)
+  %all-reduce-done = f32[8]{0} all-reduce-done(%all-reduce-start)
+  ROOT %out = f32[8]{0} add(%all-reduce-done, %mul)
+}
+"""
+
+_HLO_TPU_FUSED = """
+HloModule jit_train_step, is_scheduled=true
+
+%async_collective_fusion.3 (p0: bf16[4,8], p1: bf16[4,8]) -> (bf16[8,8], bf16[4,8]) {
+  %p0 = bf16[4,8]{1,0} parameter(0)
+  %p1 = bf16[4,8]{1,0} parameter(1)
+  %all-reduce.7 = bf16[4,8]{1,0} all-reduce(%p0), channel_id=3, replica_groups=[2,2]<=[4], to_apply=%add, frontend_attributes={chain_id="0"}
+  %conv = bf16[8,8]{1,0} convolution(%p1, %p1), dim_labels=bf_io->bf
+  ROOT %t = (bf16[8,8]{1,0}, bf16[4,8]{1,0}) tuple(%conv, %all-reduce.7)
+}
+
+ENTRY %main.9 (a: bf16[4,8], b: bf16[4,8]) -> bf16[4,8] {
+  %a = bf16[4,8]{1,0} parameter(0)
+  %b = bf16[4,8]{1,0} parameter(1)
+  %fusion.3 = (bf16[8,8]{1,0}, bf16[4,8]{1,0}) fusion(%a, %b), kind=kOutput, calls=%async_collective_fusion.3
+  %gte = bf16[4,8]{1,0} get-tuple-element(%fusion.3), index=1
+  ROOT %all-reduce.8 = bf16[4,8]{1,0} all-reduce(%gte), channel_id=4, replica_groups={{0,2},{1,3}}, to_apply=%add, frontend_attributes={async_collective_name="all-reduce-start.1"}
+}
+"""
+
+_HLO_SYNCHRONOUS = """
+ENTRY %main.3 (x: f32[8]) -> f32[8] {
+  %x = f32[8]{0} parameter(0)
+  ROOT %all-reduce = f32[8]{0} all-reduce(%x), channel_id=1, replica_groups={{0,1}}, to_apply=%add
+}
+"""
+
+
+@pytest.mark.parametrize(
+    "hlo,want",
+    [
+        (_HLO_START_DONE, {"start": 1, "fusion": 0, "tagged": 0}),
+        (_HLO_TPU_FUSED, {"start": 0, "fusion": 1, "tagged": 1}),
+        (_HLO_SYNCHRONOUS, {"start": 0, "fusion": 0, "tagged": 0}),
+    ],
+    ids=["start-done", "tpu-fused", "synchronous"],
+)
+def test_async_collective_counts_by_form(hlo, want):
+    assert async_collective_counts(hlo) == want

@@ -4,7 +4,11 @@ import numpy as np
 import pytest
 
 from kubeflow_tpu.parallel import MeshSpec, build_mesh
-from kubeflow_tpu.parallel.mesh import AXES, local_mesh_spec
+from kubeflow_tpu.parallel.mesh import (
+    AXES,
+    local_mesh_spec,
+    step_compiler_options,
+)
 
 
 def test_resolve_wildcard():
@@ -43,6 +47,48 @@ def test_local_mesh_spec():
     assert local_mesh_spec(8, tp=2).fsdp == 4
     with pytest.raises(ValueError):
         local_mesh_spec(8, tp=3)
+
+
+class _StubTpu:
+    """All `step_compiler_options` may look at: a device's platform."""
+
+    platform = "tpu"
+
+
+@pytest.mark.parametrize(
+    "n,spec",
+    [
+        (1, MeshSpec()),
+        (4, MeshSpec(dp=-1)),
+        (8, MeshSpec(dp=-1)),
+        (8, MeshSpec(dp=2, fsdp=2, tp=2)),
+    ],
+    ids=["1", "4", "8", "8-dp2-fsdp2-tp2"],
+)
+def test_step_compiler_options_none_on_cpu_meshes(devices, n, spec):
+    assert step_compiler_options(build_mesh(spec, devices[:n])) is None
+
+
+@pytest.mark.parametrize(
+    "spec,engaged",
+    [
+        (MeshSpec(), False),  # one device: no peer
+        (MeshSpec(dp=4), False),  # peers, but only gradient tuples to reduce
+        (MeshSpec(dp=2, fsdp=2), False),
+        (MeshSpec(dp=2, tp=2), True),
+        (MeshSpec(sp=4), True),
+    ],
+    ids=["one-device", "dp4", "dp2-fsdp2", "dp2-tp2", "sp4"],
+)
+def test_step_compiler_options_follow_the_tpu_mesh(spec, engaged):
+    n = int(np.prod(spec.sizes()))
+    stubs = np.array([_StubTpu() for _ in range(n)], dtype=object)
+    options = step_compiler_options(jax.sharding.Mesh(stubs.reshape(spec.sizes()), AXES))
+    assert (options is not None) == engaged and options != {}
+    assert all(
+        type(k) is str and type(v) in (bool, int)
+        for k, v in (options or {}).items()
+    )
 
 
 def test_mesh_runs_sharded_compute(mesh8):
