@@ -8,7 +8,8 @@ axis names and resolved by the rules table:
   all-reduces per block);
 - the sequence axis shards over ``sp`` and attention runs on the ring
   (`kubeflow_tpu.ops.ring_attention`);
-- optional mixture-of-experts MLP shards experts over ``ep``;
+- an optional dropless expert layer holds a range of the experts, shards
+  them over ``ep`` and sums the shards' partial results;
 - embed-dim weight shards over ``fsdp`` (ZeRO-3).
 
 Blocks are rematerialized (`nn.remat`) — recompute beats HBM traffic on
@@ -29,6 +30,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from kubeflow_tpu.ops.attention import dense_attention, ring_attention
+from kubeflow_tpu.ops.moe import expert_mlp_on_mesh
 from kubeflow_tpu.parallel.sharding import batch_axes
 from kubeflow_tpu.ops.flash import (
     CHECKPOINT_LSE_NAME,
@@ -98,10 +100,35 @@ class TransformerConfig:
     # every measured S.
     flash_block_q_bwd: int | None = None
     flash_block_k_bwd: int | None = None
-    # MoE: 0 experts = dense MLP. Top-1 (switch) routing with capacity.
+    # Grouped K/V heads: query head h attends over kv head
+    # h // (n_heads / n_kv_heads). None = as many as query heads.
+    n_kv_heads: int | None = None
+    # The share of each head's dims (the first ones) that rope turns.
+    rope_fraction: float = 1.0
+    norm_eps: float = 1e-6
+    # CCA (compressed convolutional attention): q and k are mixed along
+    # the sequence by two causal convolutions (kernel sizes below: a
+    # depthwise one, then one grouped by head), get the mean of the
+    # pre-convolution q and k of their group added, and are L2-normalised
+    # (k times a learned temperature); the second half of the kv heads
+    # take their value from the token before. The latent is n_heads x
+    # head_dim wide, whatever d_model is.
+    cca: bool = False
+    cca_kernels: tuple[int, int] = (2, 2)
+    # Experts: 0 = the dense SwiGLU MLP. Otherwise a dropless top-1 layer
+    # of gated experts of width d_ff behind a router MLP whose hidden
+    # state is carried from layer to layer. `experts_held` = (first,
+    # count) is the contiguous range this program holds (None = all): the
+    # router still routes over `num_experts`, tokens routed elsewhere get
+    # nothing added here.
     num_experts: int = 0
-    capacity_factor: float = 1.25
-    aux_loss_coef: float = 0.01
+    experts_held: tuple[int, int] | None = None
+    router_hidden: int = 256
+    # For measuring an untrained model: every token's expert is drawn
+    # evenly at random, by position and layer and the same in every run,
+    # in place of the router's argmax; the gate stays the router's
+    # probability of that expert (`ExpertLayer`).
+    router_force_balance: bool = False
 
 
 def checkpoint_policy(name: str):
@@ -227,8 +254,15 @@ class RMSNorm(nn.Module):
         return rms_norm(x, scale, dtype=self.dtype, eps=self.eps)
 
 
-def rope(x, positions, theta: float):
-    """Rotary embeddings. x: [B, S, H, D], positions: [B, S]."""
+def rope(x, positions, theta: float, fraction: float = 1.0):
+    """Rotary embeddings. x: [B, S, H, D], positions: [B, S]. With
+    `fraction` < 1 only the first `fraction * D` dims of a head turn."""
+    turned = int(x.shape[-1] * fraction)
+    if turned != x.shape[-1]:
+        return jnp.concatenate(
+            [rope(x[..., :turned], positions, theta), x[..., turned:]],
+            axis=-1,
+        )
     d = x.shape[-1]
     freqs = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     angles = positions[..., None].astype(jnp.float32) * freqs  # [B, S, D/2]
@@ -249,6 +283,10 @@ def _attend(q, k, v, mesh: Mesh | None, cfg: "TransformerConfig"):
     """
     impl = cfg.attention_impl
     bq, bk = cfg.flash_block_q, cfg.flash_block_k
+    group = q.shape[2] // k.shape[2]
+    # Only the flash kernels pick a query head's kv head themselves; the
+    # ring and dense paths get K and V repeated over the group.
+    repeat = lambda x: x if group == 1 else jnp.repeat(x, group, axis=2)
     if impl not in ("auto", "flash", "dense"):
         raise ValueError(
             f"unknown attention_impl {impl!r}; expected 'auto', 'flash', "
@@ -270,9 +308,10 @@ def _attend(q, k, v, mesh: Mesh | None, cfg: "TransformerConfig"):
             from kubeflow_tpu.ops.flash import ring_flash_attention
 
             return ring_flash_attention(
-                q, k, v, mesh, causal=True, block_q=bq, block_k=bk
+                q, repeat(k), repeat(v), mesh, causal=True,
+                block_q=bq, block_k=bk,
             )
-        return ring_attention(q, k, v, mesh, causal=True)
+        return ring_attention(q, repeat(k), repeat(v), mesh, causal=True)
     # flash_usable is now unconditionally true for positive lengths
     # (ragged sequences pad inside the kernel wrapper instead of
     # silently falling back to the dense O(S²) path); the predicate
@@ -292,16 +331,16 @@ def _attend(q, k, v, mesh: Mesh | None, cfg: "TransformerConfig"):
         for a in batch_axes(mesh):
             bsz *= mesh.shape[a]
         tp = mesh.shape.get("tp", 1)
-        if q.shape[0] % bsz or q.shape[2] % tp:
+        if q.shape[0] % bsz or k.shape[2] % tp:
             if impl == "flash":
                 raise ValueError(
                     f"attention_impl='flash' on a mesh requires batch "
                     f"({q.shape[0]}) divisible by dp·fsdp ({bsz}) and heads "
-                    f"({q.shape[2]}) divisible by tp ({tp})"
+                    f"({k.shape[2]}) divisible by tp ({tp})"
                 )
             warnings.warn(
                 f"attention_impl='auto': batch ({q.shape[0]}) does not "
-                f"divide dp·fsdp ({bsz}) or heads ({q.shape[2]}) do not "
+                f"divide dp·fsdp ({bsz}) or heads ({k.shape[2]}) do not "
                 f"divide tp ({tp}); running DENSE O(S²) attention instead "
                 "of the flash kernels",
                 RuntimeWarning,
@@ -309,7 +348,7 @@ def _attend(q, k, v, mesh: Mesh | None, cfg: "TransformerConfig"):
             )
             use_flash = False
     if not use_flash:
-        return dense_attention(q, k, v, causal=True)
+        return dense_attention(q, repeat(k), repeat(v), causal=True)
     bwd = {
         "bwd_block_q": cfg.flash_block_q_bwd,
         "bwd_block_k": cfg.flash_block_k_bwd,
@@ -332,22 +371,108 @@ def _attend(q, k, v, mesh: Mesh | None, cfg: "TransformerConfig"):
     )(q, k, v)
 
 
+def _shift(x, steps: int):
+    """x[t - steps] at position t along the sequence axis (1), zeros
+    before the first token: a causal tap."""
+    if steps == 0:
+        return x
+    pad = [(0, 0)] * x.ndim
+    pad[1] = (steps, 0)
+    return jnp.pad(x, pad)[:, : x.shape[1]]
+
+
+def _replicated(init, rank: int):
+    return nn.with_logical_partitioning(init, (None,) * rank)
+
+
 class Attention(nn.Module):
+    """Causal self-attention from the configuration's numbers: `n_heads`
+    query heads over `n_kv_heads` K/V heads in a latent of n_heads x
+    head_dim, rope over `rope_fraction` of a head, and CCA's mixing of q,
+    k and v along the sequence when `cca` is on (OLMo's case is all of
+    them off and equal heads)."""
+
     config: TransformerConfig
     mesh: Mesh | None = None
+
+    def _conv_mix(self, u, name: str):
+        """Two causal convolutions along the sequence over u [B, S, H, d]:
+        depthwise, then grouped by head. Tap j multiplies the value j
+        tokens back. float32 in and out."""
+        cfg = self.config
+        k0, k1 = cfg.cca_kernels
+        heads, d = u.shape[2:]
+        w0 = self.param(
+            f"conv0_{name}",
+            nn.with_logical_partitioning(
+                nn.initializers.normal(k0 ** -0.5), (None, "heads", "kv")
+            ),
+            (k0, heads, d), jnp.float32,
+        )
+        w1 = self.param(
+            f"conv1_{name}",
+            nn.with_logical_partitioning(
+                nn.initializers.normal((k1 * d) ** -0.5),
+                (None, "heads", "kv", None),
+            ),
+            (k1, heads, d, d), jnp.float32,
+        )
+        c0 = sum(w0[j] * _shift(u, j) for j in range(k0))
+        c0 = c0.astype(cfg.dtype)
+        # The products leave in cfg.dtype like every other projection's
+        # (the CPU backend has no batched bf16 dot that leaves in f32).
+        return sum(
+            jnp.einsum(
+                "bshd,hde->bshe", _shift(c0, j), w1[j].astype(cfg.dtype)
+            ).astype(jnp.float32)
+            for j in range(k1)
+        )
+
+    def _cca_mix(self, q, k, v):
+        cfg = self.config
+        hk = k.shape[2]
+        group = q.shape[2] // hk
+        q32, k32 = q.astype(jnp.float32), k.astype(jnp.float32)
+        q_mean = q32.reshape(*q.shape[:2], hk, group, -1).mean(axis=3)
+        q_mix = self._conv_mix(q32, "q") + 0.5 * (
+            q32 + jnp.repeat(k32, group, axis=2)
+        )
+        k_mix = self._conv_mix(k32, "k") + 0.5 * (q_mean + k32)
+        tau = self.param(
+            "tau", nn.with_logical_partitioning(nn.initializers.ones, ("heads",)),
+            (hk,), jnp.float32,
+        )
+        # sqrt(d) * x / |x|: the RMS norm without a scale.
+        unit = functools.partial(
+            rms_norm, scale=1.0, dtype=jnp.float32, eps=cfg.norm_eps
+        )
+        q = unit(q_mix).astype(cfg.dtype)
+        k = (unit(k_mix) * tau[:, None]).astype(cfg.dtype)
+        # The second half of the kv heads carry the token before.
+        now = hk - hk // 2
+        v = jnp.concatenate(
+            [v[:, :, :now], _shift(v[:, :, now:], 1)], axis=2
+        )
+        return q, k, v
 
     @nn.compact
     def __call__(self, x, positions):
         cfg = self.config
         h, d = cfg.n_heads, cfg.head_dim
+        hk = cfg.n_kv_heads or h
         q = _dense((h, d), ("embed", "heads", "kv"), "wq", cfg.dtype)(x)
-        k = _dense((h, d), ("embed", "heads", "kv"), "wk", cfg.dtype)(x)
-        v = _dense((h, d), ("embed", "heads", "kv"), "wv", cfg.dtype)(x)
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
+        k = _dense((hk, d), ("embed", "heads", "kv"), "wk", cfg.dtype)(x)
+        v = _dense((hk, d), ("embed", "heads", "kv"), "wv", cfg.dtype)(x)
+        if cfg.cca:
+            with jax.named_scope("cca.mix"):
+                q, k, v = self._cca_mix(q, k, v)
+        q = rope(q, positions, cfg.rope_theta, cfg.rope_fraction)
+        k = rope(k, positions, cfg.rope_theta, cfg.rope_fraction)
         # Named so the "attn" remat policy can pin exactly this value as
         # the saved residual (everything else in the block recomputes).
-        out = checkpoint_name(_attend(q, k, v, self.mesh, cfg), "attn_out")
+        with jax.named_scope("cca.attend" if cfg.cca else "attend"):
+            out = _attend(q, k, v, self.mesh, cfg)
+        out = checkpoint_name(out, "attn_out")
         out = nn.DenseGeneral(
             cfg.d_model,
             axis=(-2, -1),
@@ -376,106 +501,187 @@ class SwiGLU(nn.Module):
         )
 
 
-class SwitchMoE(nn.Module):
-    """Top-1 (switch) MoE with capacity, einsum-dispatched for the MXU.
+FORCED_ROUTING_SEED = 42
 
-    Experts are a leading weight dimension with logical name "expert"
-    (→ ``ep`` mesh axis); dispatch/combine are einsums so XLA chooses the
-    all-to-all pattern. Load-balancing aux loss is sown under
-    ``intermediates/aux_loss`` and picked up by the trainer.
+
+def forced_experts(layer: int, seq_len: int, num_experts: int):
+    """[seq_len] int32: the expert `router_force_balance` gives each
+    position in `layer`, the argmax of standard normal scores from a
+    fixed key."""
+    key = jax.random.fold_in(jax.random.PRNGKey(FORCED_ROUTING_SEED), layer)
+    scores = jax.random.normal(key, (seq_len, num_experts), jnp.float32)
+    return jnp.argmax(scores, axis=-1).astype(jnp.int32)
+
+
+class ExpertLayer(nn.Module):
+    """Dropless top-1 layer of gated experts over the experts held here.
+
+    The router is an MLP over a hidden state that is carried from one
+    layer's router to the next (`router_state` in, the new state out):
+    `r = x W_in + carry * r_prev`, `p = softmax(W3 gelu(W2 gelu(W1
+    norm(r))))`, in float32 at full matmul precision. Each token goes to
+    its best expert e with gate `p[e]`; there is no capacity, no dropped
+    token, no balancing bias and no auxiliary loss.
+
+    Nothing therefore keeps an untrained router even, and a dropless
+    layer's work follows its tokens. `config.router_force_balance` is for
+    measuring such a model at the load a trained one has (what
+    Megatron-Core's `--moe-router-force-load-balancing` is for): e is
+    then the argmax of standard normal scores drawn for (position,
+    expert) from a fixed key and `layer`, the same for every row of the
+    batch, in every step and every run, so each expert gets about
+    tokens / num_experts whatever the weights are; the router still runs
+    and still learns through the gate `p[e]`.
+
+    `config.experts_held` = (first, count) says which experts this program
+    holds: only their weights exist, the router still scores all
+    `num_experts`, and a token routed to an absent expert gets zeros. The
+    held experts have the logical axis "expert" (-> `ep`): on a mesh every
+    `ep` shard computes what its own experts add for the tokens routed to
+    them and the shards' partial results are summed
+    (`ops/moe.expert_mlp_on_mesh`: rows ordered by expert, grouped
+    matmuls); with one shard there is no exchange. Sows, under
+    "counters": `moe_tokens_held` (tokens routed to a held expert),
+    `moe_load_max`, `moe_load_mean` (tokens on the fullest held expert
+    and the mean over them), and under "intermediates" `expert`, each
+    token's choice.
     """
 
     config: TransformerConfig
+    mesh: Mesh | None = None
+    layer: int = 0
 
-    @staticmethod
-    def _group_size(n_tok: int, target: int = 4096) -> int:
-        """Largest divisor of n_tok <= target. Grouping keeps the one-hot
-        dispatch tensors O(n_tok * group) instead of O(n_tok^2)."""
-        for g in range(min(target, n_tok), 0, -1):
-            if n_tok % g == 0:
-                return g
-        return n_tok
+    def _route(self, x, router_state):
+        cfg = self.config
+        rh, hi = cfg.router_hidden, jax.lax.Precision.HIGHEST
+        mat = lambda name, rows, cols, names: self.param(
+            name,
+            nn.with_logical_partitioning(
+                nn.initializers.variance_scaling(1.0, "fan_in", "normal"),
+                names,
+            ),
+            (rows, cols), jnp.float32,
+        )
+        carry = self.param(
+            "router_carry",
+            _replicated(nn.initializers.constant(0.5), 1), (rh,), jnp.float32,
+        )
+        scale = self.param(
+            "router_norm", _replicated(nn.initializers.ones, 1), (rh,),
+            jnp.float32,
+        )
+        r = jnp.dot(
+            x.astype(jnp.float32), mat("router_in", x.shape[-1], rh,
+                                       ("embed", None)), precision=hi,
+        ) + carry * router_state
+        z = rms_norm(r, scale, dtype=jnp.float32, eps=cfg.norm_eps)
+        z = nn.gelu(jnp.dot(z, mat("router_w1", rh, rh, (None, None)),
+                            precision=hi))
+        z = nn.gelu(jnp.dot(z, mat("router_w2", rh, rh, (None, None)),
+                            precision=hi))
+        logits = jnp.dot(
+            z, mat("router_out", rh, cfg.num_experts, (None, None)),
+            precision=hi,
+        )
+        probs = jax.nn.softmax(logits, axis=-1)
+        if cfg.router_force_balance:
+            expert = forced_experts(self.layer, x.shape[-2], cfg.num_experts)
+            expert = jnp.broadcast_to(expert, probs.shape[:-1])
+        else:
+            expert = jnp.argmax(probs, axis=-1).astype(jnp.int32)
+        gate = jnp.take_along_axis(probs, expert[..., None], axis=-1)[..., 0]
+        return expert, gate, r
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, router_state):
         cfg = self.config
-        b, s, dm = x.shape
-        n_tok = b * s
-        e = cfg.num_experts
-        g = self._group_size(n_tok)
-        n_groups = n_tok // g
-        cap = max(1, int(cfg.capacity_factor * g / e))
-        xg = x.reshape(n_groups, g, dm)
+        first, held = cfg.experts_held or (0, cfg.num_experts)
+        if first < 0 or held < 1 or first + held > cfg.num_experts:
+            raise ValueError(
+                f"experts_held {cfg.experts_held} is not a range of the "
+                f"{cfg.num_experts} experts"
+            )
+        with jax.named_scope("moe.route"):
+            expert, gate, router_state = self._route(x, router_state)
+            load = jnp.sum(
+                expert.reshape(-1)[:, None]
+                == first + jnp.arange(held, dtype=jnp.int32)[None, :],
+                axis=0,
+            ).astype(jnp.float32)
+        # For whoever asks for "intermediates": the choice made per token.
+        self.sow(
+            "intermediates", "expert", expert,
+            reduce_fn=lambda _, new: new, init_fn=lambda: 0,
+        )
+        # One value a layer, however often a remat traces the layer.
+        for name, value in (
+            ("moe_tokens_held", jnp.sum(load)),
+            ("moe_load_max", jnp.max(load)),
+            ("moe_load_mean", jnp.mean(load)),
+        ):
+            self.sow(
+                "counters", name, value,
+                reduce_fn=lambda _, new: new, init_fn=lambda: 0.0,
+            )
 
-        router = _dense(e, ("embed", "expert"), "router", jnp.float32)
-        probs = jax.nn.softmax(router(xg.astype(jnp.float32)), axis=-1)
-        expert_idx = jnp.argmax(probs, axis=-1)  # [G, g]
-        expert_gate = jnp.max(probs, axis=-1)
+        def weight(name, rows, cols, names):
+            return self.param(
+                name,
+                nn.with_logical_partitioning(
+                    nn.initializers.variance_scaling(
+                        1.0, "fan_in", "normal", in_axis=-2, out_axis=-1,
+                        batch_axis=(0,),
+                    ),
+                    ("expert", *names),
+                ),
+                (held, rows, cols), jnp.float32,
+            )
 
-        onehot = jax.nn.one_hot(expert_idx, e, dtype=jnp.float32)  # [G, g, E]
-        # Slot within the chosen expert, per group; -1 for unchosen experts
-        # and overflow tokens — one_hot maps -1 to all-zeros (token dropped).
-        pos = (jnp.cumsum(onehot, axis=1) * onehot - 1.0).astype(jnp.int32)
-        pos = jnp.where(pos < cap, pos, -1)
-        dispatch = jax.nn.one_hot(pos, cap, dtype=jnp.float32)  # [G, g, E, cap]
-
-        # Load-balancing aux loss (Switch Transformer eq. 4), mean over
-        # groups; sown to the dedicated "losses" collection.
-        frac_tokens = onehot.mean(axis=1)  # [G, E]
-        frac_probs = probs.mean(axis=1)
-        aux = e * jnp.mean(jnp.sum(frac_tokens * frac_probs, -1)) * cfg.aux_loss_coef
-        self.sow("losses", "moe_aux_loss", aux)
-
-        w_in = self.param(
-            "w_in",
-            nn.with_logical_partitioning(
-                nn.initializers.variance_scaling(1.0, "fan_in", "normal"),
-                ("expert", "embed", "mlp"),
-            ),
-            (e, dm, cfg.d_ff),
-            jnp.float32,
-        ).astype(cfg.dtype)
-        w_out = self.param(
-            "w_out",
-            nn.with_logical_partitioning(
-                nn.initializers.variance_scaling(1.0, "fan_in", "normal"),
-                ("expert", "mlp", "embed"),
-            ),
-            (e, cfg.d_ff, dm),
-            jnp.float32,
-        ).astype(cfg.dtype)
-
-        xin = jnp.einsum("gnec,gnd->gecd", dispatch.astype(cfg.dtype), xg)
-        hidden = nn.silu(jnp.einsum("gecd,edf->gecf", xin, w_in))
-        xout = jnp.einsum("gecf,efd->gecd", hidden, w_out)
-        combine = dispatch * expert_gate[..., None, None]
-        out = jnp.einsum("gnec,gecd->gnd", combine.astype(cfg.dtype), xout)
-        return out.reshape(b, s, dm)
+        dm, ff = x.shape[-1], cfg.d_ff
+        weights = (
+            weight("w_gate", dm, ff, ("embed", "mlp")),
+            weight("w_up", dm, ff, ("embed", "mlp")),
+            weight("w_down", ff, dm, ("mlp", "embed")),
+        )
+        out = expert_mlp_on_mesh(
+            self.mesh, x, expert, gate.astype(jnp.float32), weights, first
+        )
+        return out, router_state
 
 
 class Block(nn.Module):
+    """One layer: attention, then the dense MLP or the expert layer.
+    Takes and returns the router's carried state beside the residual
+    (None where there are no experts). `layer` is its place in the stack,
+    which only `router_force_balance` reads."""
+
     config: TransformerConfig
     mesh: Mesh | None = None
+    layer: int = 0
 
     @nn.compact
-    def __call__(self, x, positions):
+    def __call__(self, x, positions, router_state=None):
         cfg = self.config
+        norm = functools.partial(RMSNorm, cfg.dtype, cfg.norm_eps)
         x = x + Attention(cfg, self.mesh, name="attn")(
-            RMSNorm(cfg.dtype, name="ln_attn")(x), positions
+            norm(name="ln_attn")(x), positions
         )
-        mlp_cls: type[nn.Module]
-        mlp_name = "moe" if cfg.num_experts > 0 else "mlp"
-        mlp_cls = SwitchMoE if cfg.num_experts > 0 else SwiGLU
-        if cfg.remat and cfg.remat_policy == "mlp":
-            # The "mlp" policy's only checkpoint: the MLP recomputes in
-            # the backward, attention's residuals stay saved (the lifted
-            # transform keeps the param path, so weights are identical
-            # to the unwrapped module's).
-            mlp_cls = nn.remat(mlp_cls)
-        x = x + mlp_cls(cfg, name=mlp_name)(
-            RMSNorm(cfg.dtype, name="ln_mlp")(x)
+        # The "mlp" policy's only checkpoint: the MLP half recomputes in
+        # the backward, attention's residuals stay saved (the lifted
+        # transform keeps the param path, so weights are identical to
+        # the unwrapped module's).
+        wrap = (
+            nn.remat if cfg.remat and cfg.remat_policy == "mlp"
+            else (lambda cls: cls)
         )
-        return x
+        h = norm(name="ln_mlp")(x)
+        if cfg.num_experts > 0:
+            out, router_state = wrap(ExpertLayer)(
+                cfg, self.mesh, self.layer, name="moe"
+            )(h, router_state)
+        else:
+            out = wrap(SwiGLU)(cfg, name="mlp")(h)
+        return x + out, router_state
 
 
 class _PipelineStage(nn.Module):
@@ -495,7 +701,7 @@ class _PipelineStage(nn.Module):
     def __call__(self, x, positions):
         block_cls = _block_cls(self.config)
         for i in range(self.layers_per_stage):
-            x = block_cls(self.config, self.mesh, name=f"layer_{i}")(
+            x, _ = block_cls(self.config, self.mesh, name=f"layer_{i}")(
                 x, positions
             )
         return x
@@ -532,8 +738,9 @@ class PipelinedTransformerLM(nn.Module):
     `params/layer_{s * layers_per_stage + i}` (the equivalence test
     restacks one into the other; the interleaved slice-to-rank
     assignment is internal to `spmd_pipeline`, so stacked index `s` is
-    pipeline stage `s` under every schedule). MoE stages are not
-    supported (the aux-loss channel would accumulate bubble garbage)."""
+    pipeline stage `s` under every schedule). Expert layers are not
+    supported (the router's state would have to ride the pipeline's
+    hand-off beside the residual, and the bubbles would count tokens)."""
 
     config: TransformerConfig
     n_stages: int
@@ -792,7 +999,14 @@ class TransformerLM(nn.Module):
             jnp.arange(tokens.shape[1], dtype=jnp.int32), tokens.shape
         )
         block_cls = _block_cls(cfg)
+        # The first layer's router has no state before it: zeros.
+        router_state = (
+            jnp.zeros((*tokens.shape, cfg.router_hidden), jnp.float32)
+            if cfg.num_experts > 0 else None
+        )
         for i in range(cfg.n_layers):
-            x = block_cls(cfg, self.mesh, name=f"layer_{i}")(x, positions)
-        x = RMSNorm(cfg.dtype, name="ln_final")(x)
+            x, router_state = block_cls(
+                cfg, self.mesh, layer=i, name=f"layer_{i}"
+            )(x, positions, router_state)
+        x = RMSNorm(cfg.dtype, cfg.norm_eps, name="ln_final")(x)
         return lm_head(x, embed, dtype=cfg.dtype)

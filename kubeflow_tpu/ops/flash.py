@@ -872,8 +872,30 @@ def _clamp_i(i, j, bq: int, bk: int, causal: bool):
     return jnp.maximum(i, (j * bk) // bq)
 
 
-def _qkv_specs(bq: int, bk: int, d: int, causal: bool):
-    kv = lambda b, i, j: (b, _clamp_j(i, j, bq, bk, causal), 0)
+def _kv_row(group: int):
+    """Grid row of q (batch·head, head-minor) -> row of k/v. With grouped
+    K/V heads `group` query heads share one: q row b·H + h reads k/v row
+    b·H/group + h // group = (b·H + h) // group, so the kernels never see
+    a repeated copy of K or V. Equal heads keep the identity map."""
+    if group == 1:
+        return lambda b: b
+    return lambda b: b // group
+
+
+def _sum_groups(dk, group: int, dtype):
+    """Per-query-head dK or dV [B·H, S, d] -> per-kv-head [B·H/group, S, d]:
+    the kernels write one partial a query head, summed here in float32."""
+    if group == 1:
+        return dk
+    bh, sk, d = dk.shape
+    return dk.reshape(bh // group, group, sk, d).astype(jnp.float32).sum(
+        axis=1
+    ).astype(dtype)
+
+
+def _qkv_specs(bq: int, bk: int, d: int, causal: bool, group: int = 1):
+    row = _kv_row(group)
+    kv = lambda b, i, j: (row(b), _clamp_j(i, j, bq, bk, causal), 0)
     return [
         pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
         pl.BlockSpec((1, bk, d), kv),
@@ -900,6 +922,8 @@ def _flash_fwd_impl(
     scale = 1.0 / math.sqrt(d)
     steps, _, compact = _grid_steps(causal, sq, sk, bq, bk)
     nq = sq // bq
+    group = bh // k.shape[0]
+    row = _kv_row(group)
     kernel_kw = dict(
         scale=scale, causal=causal, bq=bq, bk=bk, kv_len=kv_len,
         packed=packed,
@@ -915,7 +939,7 @@ def _flash_fwd_impl(
     ]
     cost = pl.CostEstimate(
         flops=4 * bh * steps * bq * bk * d,
-        bytes_accessed=bh * (sq + 2 * sk) * d * q.dtype.itemsize,
+        bytes_accessed=(bh * sq + 2 * k.shape[0] * sk) * d * q.dtype.itemsize,
         transcendentals=bh * steps * bq * bk,
     )
     if compact:
@@ -925,8 +949,12 @@ def _flash_fwd_impl(
             grid=(bh, steps),
             in_specs=[
                 pl.BlockSpec((1, bq, d), lambda b, t, rs, cs: (b, rs[t], 0)),
-                pl.BlockSpec((1, bk, d), lambda b, t, rs, cs: (b, cs[t], 0)),
-                pl.BlockSpec((1, bk, d), lambda b, t, rs, cs: (b, cs[t], 0)),
+                pl.BlockSpec(
+                    (1, bk, d), lambda b, t, rs, cs: (row(b), cs[t], 0)
+                ),
+                pl.BlockSpec(
+                    (1, bk, d), lambda b, t, rs, cs: (row(b), cs[t], 0)
+                ),
             ],
             out_specs=[
                 pl.BlockSpec((1, bq, d), lambda b, t, rs, cs: (b, rs[t], 0)),
@@ -948,7 +976,7 @@ def _flash_fwd_impl(
     return pl.pallas_call(
         functools.partial(_fwd_kernel, **kernel_kw),
         grid=(bh, nq, sk // bk),
-        in_specs=_qkv_specs(bq, bk, d, causal),
+        in_specs=_qkv_specs(bq, bk, d, causal, group),
         out_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec(_lse_block(bq, packed), lambda b, i, j: (b, i, 0)),
@@ -1033,12 +1061,22 @@ def _flash_bwd_kernels(
         packed=packed,
     )
 
+    group = bh // k.shape[0]
+    row = _kv_row(group)
+    # dK and dV leave every kernel as one partial a QUERY head (the
+    # accumulators and output blocks ride the q grid row); the group's
+    # partials are summed after the call.
+    grouped = lambda dq, dk, dv: (
+        dq, _sum_groups(dk, group, k.dtype), _sum_groups(dv, group, v.dtype)
+    )
+
     def _row_specs(qidx, kidx):
-        # q/do/lse/delta ride the q-block index, k/v the k-block index.
+        # q/do/lse/delta ride the q-block index, k/v the k-block index
+        # (and, with grouped heads, the kv head of the q grid row).
         return [
             pl.BlockSpec((1, bq, d), lambda *a: (a[0], qidx(*a[1:]), 0)),
-            pl.BlockSpec((1, bk, d), lambda *a: (a[0], kidx(*a[1:]), 0)),
-            pl.BlockSpec((1, bk, d), lambda *a: (a[0], kidx(*a[1:]), 0)),
+            pl.BlockSpec((1, bk, d), lambda *a: (row(a[0]), kidx(*a[1:]), 0)),
+            pl.BlockSpec((1, bk, d), lambda *a: (row(a[0]), kidx(*a[1:]), 0)),
             pl.BlockSpec((1, bq, d), lambda *a: (a[0], qidx(*a[1:]), 0)),
             pl.BlockSpec(
                 _lse_block(bq, packed), lambda *a: (a[0], qidx(*a[1:]), 0)
@@ -1102,7 +1140,7 @@ def _flash_bwd_kernels(
             interpret=interpret,
             name="flash_bwd_fused",
         )(rows_c, cols_c, q, k, v, do, lse, delta)
-        return dq, dk, dv
+        return grouped(dq, dk, dv)
 
     if compact:
         rows, cols = _tri_tables(nq, "row")
@@ -1152,7 +1190,7 @@ def _flash_bwd_kernels(
             interpret=interpret,
             name="flash_dkv_compact",
         )(rows_c, cols_c, q, k, v, do, lse, delta)
-        return dq, dk, dv
+        return grouped(dq, dk, dv)
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, **kw),
@@ -1190,7 +1228,7 @@ def _flash_bwd_kernels(
         interpret=interpret,
         name="flash_dkv_rect",
     )(q, k, v, do, lse, delta)
-    return dq, dk, dv
+    return grouped(dq, dk, dv)
 
 
 def _flash_bwd_impl(
@@ -1278,7 +1316,10 @@ def flash_attention(
     interpret: bool | None = None,
     return_lse: bool = False,
 ):
-    """Blockwise attention on the MXU. q, k, v: [B, S, H, D] → [B, S, H, D].
+    """Blockwise attention on the MXU. q: [B, S, H, D]; k, v: [B, S, Hkv, D]
+    with H a multiple of Hkv (query head h attends over kv head
+    h // (H/Hkv); the kernels' index maps pick it, K and V are never
+    repeated) → [B, S, H, D].
 
     Numerically matches ``dense_attention`` (same online-softmax math) while
     never materializing the [S, S] score matrix in HBM — at S=8192 the
@@ -1306,6 +1347,11 @@ def flash_attention(
     """
     b, sq, h, d = q.shape
     sk = k.shape[1]
+    if k.shape != v.shape or h % k.shape[2]:
+        raise ValueError(
+            f"flash attention: {h} query heads over k {k.shape} / v "
+            f"{v.shape}: k and v must agree and their heads divide q's"
+        )
     interp = _auto_interpret(interpret)
     sp_q = _pad_to_tileable(block_q, sq)
     sp_k = _pad_to_tileable(block_k, sk)
@@ -1318,7 +1364,7 @@ def flash_attention(
     # [B, S, H, D] → [B*H, S, D]: head-major layout keeps each grid step's
     # blocks contiguous in HBM.
     to_bhsd = lambda x: x.transpose(0, 2, 1, 3).reshape(
-        b * h, x.shape[1], d
+        b * x.shape[2], x.shape[1], d
     )
     # The backward kernels carry bigger VMEM footprints (extra f32
     # accumulators, and the fused one-pass kernel's dq ring), so wide

@@ -452,6 +452,10 @@ def fit(
                                 metrics["guard_skipped_total"]
                             )
                             rec["rollbacks"] = rollbacks
+                        # What the model counted in this step (the
+                        # expert layer's routed tokens), summed over layers.
+                        for name, value in metrics.get("counters", {}).items():
+                            rec[name] = float(value)
                     now = time.perf_counter()
                     rec["examples_per_sec"] = examples / (now - t_last)
                     # Where the host's time went since the last record.

@@ -202,6 +202,19 @@ def make_optimizer(config: TrainConfig) -> optax.GradientTransformation:
     raise ValueError(f"unknown optimizer {config.optimizer!r}")
 
 
+def _summed_by_name(counters) -> dict:
+    """A "counters" collection (module path -> name -> value) as
+    {name: sum over every module that sowed it}."""
+    out: dict = {}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(counters)[0]:
+        name = next(
+            p.key for p in reversed(path)
+            if isinstance(p, jax.tree_util.DictKey)
+        )
+        out[name] = out.get(name, 0.0) + leaf
+    return out
+
+
 def softmax_cross_entropy(logits, labels, label_smoothing: float = 0.0):
     """Fused gather-based cross entropy (equals
     `optax.softmax_cross_entropy(logits, smoothed_onehot).mean()`).
@@ -404,7 +417,8 @@ class Trainer:
 
         def train_step(state: TrainState, batch):
             def forward_loss(params, mb, stats_in):
-                """(loss, (batch_stats, accuracy)) for one (micro)batch.
+                """(loss, (batch_stats, accuracy, counters)) for one
+                (micro)batch.
 
                 Metrics that survive accumulation are SCALARS computed
                 in here (accuracy is an argmax reduced to a mean, never
@@ -415,11 +429,11 @@ class Trainer:
                 tick's updated stats (sequential BN semantics), not the
                 step's starting stats."""
                 variables = {"params": params}
-                # "losses" is the dedicated channel for scalar auxiliary
-                # losses (MoE load balancing etc.) — kept separate from
-                # flax's general-purpose "intermediates" so diagnostics
-                # never leak into the objective.
-                mutable = ["losses"]
+                # "counters" is the channel for scalars a model counts
+                # as it runs (the expert layer's routed tokens): summed
+                # by name over the modules that sow them and reported
+                # beside the loss, never part of the objective.
+                mutable = ["counters"]
                 if stats_in:
                     variables["batch_stats"] = stats_in
                     mutable.append("batch_stats")
@@ -464,17 +478,14 @@ class Trainer:
                         if has_acc
                         else jnp.zeros(())
                     )
-                for aux in jax.tree_util.tree_leaves(
-                    new_vars.get("losses", {})
-                ):
-                    loss = loss + aux
                 return loss, (
-                    new_vars.get("batch_stats", stats_in), acc
+                    new_vars.get("batch_stats", stats_in), acc,
+                    _summed_by_name(new_vars.get("counters", {})),
                 )
 
             accum = cfg.accum_steps
             if accum == 1:
-                (loss, (bstats, acc)), grads = jax.value_and_grad(
+                (loss, (bstats, acc, counters)), grads = jax.value_and_grad(
                     forward_loss, has_aux=True
                 )(state.params, batch, state.batch_stats)
             else:
@@ -512,23 +523,28 @@ class Trainer:
                         # one's, so the step's final stats reflect
                         # EVERY microbatch (sequential-small-batch
                         # semantics), not just the last.
-                        loss, (bs, acc) = tick(params, mb, bs)
-                        return (lsum + loss, asum + acc, bs), None
+                        loss, (bs, acc, counted) = tick(params, mb, bs)
+                        return (lsum + loss, asum + acc, bs), counted
 
                     carry0 = (jnp.zeros(()), jnp.zeros(()),
                               state.batch_stats)
-                    (lsum, asum, bstats), _ = jax.lax.scan(
+                    (lsum, asum, bstats), counted = jax.lax.scan(
                         body, carry0, microbatches
                     )
                     # Mean over equal-sized microbatches == the
                     # full-batch mean, so grads match accum_steps=1.
-                    return lsum / accum, (bstats, asum / accum)
+                    return lsum / accum, (
+                        bstats, asum / accum,
+                        {k: v.sum(axis=0) for k, v in counted.items()},
+                    )
 
-                (loss, (bstats, acc)), grads = jax.value_and_grad(
+                (loss, (bstats, acc, counters)), grads = jax.value_and_grad(
                     accum_loss, has_aux=True
                 )(state.params)
 
-            metrics = {"loss": loss}
+            # What the model counted (the expert layer's routed tokens),
+            # summed over the modules that sowed it; {} for most models.
+            metrics = {"loss": loss, "counters": counters}
             if has_acc:
                 metrics["accuracy"] = acc
             if guard is None:

@@ -125,6 +125,44 @@ def test_flash_forward_backward_compiles_on_the_reported_schedule(
     assert tpu_kernel_calls(text) == len(names)
 
 
+def test_grouped_head_flash_compiles_at_the_zaya_cell_shape(one_chip):
+    """8 query heads over 2 K/V heads at S = 8192 (`zaya1-8b-ep2.train-8k`):
+    the kv head is picked in the index maps, the backward stays fused."""
+    q = jax.ShapeDtypeStruct((2, 8192, 8, HEAD_DIM), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((2, 8192, 2, HEAD_DIM), jnp.bfloat16,
+                              sharding=one_chip)
+    text, names = _compile(jax.grad(_loss, argnums=(0, 1, 2)), q, kv, kv)
+    assert names == ["flash_fwd_compact", "flash_delta", "flash_bwd_fused"]
+    assert tpu_kernel_calls(text) == len(names)
+
+
+def test_grouped_matmul_kernels_compile_at_the_zaya_cell_shape(
+    one_chip, monkeypatch
+):
+    """The expert layer's three kernels at the cell's size: 16,384 tokens
+    over 8 held experts of 2048 x 2048, float32 weights, bfloat16 rows."""
+    from kubeflow_tpu.ops import moe
+
+    monkeypatch.setattr(moe, "kernels_compiled", lambda: True)
+    shape = lambda dims, dtype: jax.ShapeDtypeStruct(
+        dims, dtype, sharding=one_chip
+    )
+    weight = shape((8, 2048, 2048), jnp.float32)
+
+    def loss(x, gate, w_gate, w_up, w_down, expert):
+        out = moe.expert_mlp(x, expert, gate, w_gate, w_up, w_down, 0)
+        return out.astype(jnp.float32).sum()
+
+    text, names = _compile(
+        jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
+        shape((16384, 2048), jnp.bfloat16), shape((16384,), jnp.float32),
+        weight, weight, weight, shape((16384,), jnp.int32),
+    )
+    assert sorted(set(names)) == ["moe_gmm_dlhs", "moe_gmm_dw", "moe_gmm_fwd"]
+    assert len(names) == 9 == tpu_kernel_calls(text)
+
+
 def test_sub_1024_blocks_select_the_replicated_lse_and_compile(one_chip):
     """A packed lse block below 1024 rows is (1, bq/128 < 8, 128): the
     lowering refuses it, so such sizes must select the replicated
@@ -255,6 +293,42 @@ def test_four_chip_step_compiles_with_asynchronous_collectives(
     assert counts["fusion"] > 0 and counts["tagged"] > 0, counts
     assert _replica_groups(text) == _replica_groups(plain) != set()
     assert tpu_kernel_calls(text) == tpu_kernel_calls(plain) > 0
+
+
+def test_equal_heads_step_is_the_program_it_was_before_grouped_heads(
+    monkeypatch,
+):
+    """Cell 2's step (equal heads, `dp=2, tp=2`, flash under `shard_map`)
+    was not run again on the chip when the kernels learned grouped K/V
+    heads, so it is held here: with the two things that change put back
+    as they stood (k and v ride q's grid row; dK and dV returned as the
+    kernels wrote them), the traced step — every kernel's body and every
+    block's index map with it — is the same text."""
+    from kubeflow_tpu.ops import flash
+
+    from kubeflow_tpu.testing.hlo import _walk_eqns
+
+    _as_on_the_chip(monkeypatch)
+    trainer, args = _olmo_1b_step(jax.devices(), dp=2, tp=2)
+
+    def traced():
+        # An equation prints its kernel's body, not its blocks' index
+        # maps: those are read from the calls' grid mappings.
+        jaxpr = trainer.make_train_step().trace(*args).jaxpr
+        maps = [
+            str(block.index_map_jaxpr)
+            for eqn in _walk_eqns(jaxpr.jaxpr)
+            if eqn.primitive.name == "pallas_call"
+            for block in eqn.params["grid_mapping"].block_mappings
+        ]
+        return "\n".join([str(jaxpr), *maps])
+
+    now = traced()
+    assert "flash_bwd_fused" in now and "shard_map" in now
+    jax.clear_caches()
+    monkeypatch.setattr(flash, "_kv_row", lambda group: lambda b: b)
+    monkeypatch.setattr(flash, "_sum_groups", lambda dk, group, dtype: dk)
+    assert traced() == now
 
 
 def test_one_chip_step_gets_no_options_and_no_collective(topo, monkeypatch):

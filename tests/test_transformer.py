@@ -120,11 +120,19 @@ def test_moe_train_step(mesh8):
     state = trainer.init_state(jax.random.PRNGKey(0))
     data = SyntheticTokens(mesh8, batch_size=8, seq_len=16, vocab_size=64)
     step = trainer.make_train_step()
-    state, metrics = step(state, next(iter(data)))
+    before = jax.device_get(state.params["layer_1"]["moe"])
+    for batch, _ in zip(data, range(2)):  # the warm-up's first rate is 0
+        state, metrics = step(state, batch)
     assert np.isfinite(float(metrics["loss"]))
-    # Expert weights exist with the expert dimension leading.
-    moe_w = state.params["layer_0"]["moe"]["w_in"]
-    assert moe_w.shape[0] == 4
+    # Expert weights exist with the expert dimension leading, and the
+    # router's carried state reaches the second layer's weights.
+    moe = state.params["layer_1"]["moe"]
+    assert moe["w_gate"].shape == (4, 32, 32) == moe["w_up"].shape
+    assert not np.allclose(before["router_carry"], moe["router_carry"])
+    # Dropless: every token of every layer is on some held expert.
+    counted = {k: float(v) for k, v in metrics["counters"].items()}
+    assert counted["moe_tokens_held"] == 2 * 8 * 16
+    assert counted["moe_load_max"] >= counted["moe_load_mean"] == 2 * 32
 
 
 def test_flash_impl_matches_dense(mesh8):
