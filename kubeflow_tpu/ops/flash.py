@@ -16,6 +16,23 @@ on top:
   DMA+step per masked block. The rectangular grid (with `_clamp_i` /
   `_clamp_j` DMA elision) remains as the fallback for non-causal,
   cross-shaped, or uneven-block configurations.
+- **Two bodies a step, chosen by its place in the triangle.** On the
+  compact grid a step is either ON the diagonal (j == i) or BELOW it
+  (j < i), and reads which from the tables it already prefetches
+  (`_step_tiles`, under `pl.when`). Below the diagonal no score is
+  masked and none is -inf, so that body builds no mask and guards no
+  exp. On the diagonal the block's q rows are cut into static row
+  bands (`_diag_bands`, from the block size alone): band r runs every
+  matmul of the step against keys [0, (r+1)·t) only, under a mask that
+  is a constant (`_band_mask`: no i·bq, no j·bk), so with n bands
+  n(n+1)/2 of the block's n² sub-tiles are computed where the whole
+  square was computed and half of it masked away. Grid, DMA blocks,
+  step count and HBM bytes are unchanged: only the arithmetic inside a
+  step. The rectangular grid and any call with `kv_len` (a padded tail
+  can mask keys the triangle does not) keep the body that masks by
+  position. `flash_schedule` reports how often each engages
+  (`diag_steps`, `interior_steps`, `diag_tile`,
+  `computed_pairs_over_needed`).
 - **Lane-packed LSE.** The saved log-sum-exp is stored as
   [BH, S/128, 128] tiles — 128 per-row values per lane row — instead of
   the lane-replicated [BH, S, 128] buffer Mosaic's tiling would
@@ -429,6 +446,44 @@ def _bwd_hbm_bytes(
     return delta + dq_pass + dkv_pass
 
 
+def _diag_accounting(
+    causal: bool, seq_q: int, seq_k: int, sq: int, sk: int, bq: int, bk: int
+) -> dict:
+    """What the kernels of one grid do with the triangle, per grid row:
+    how many steps run the banded body on the diagonal and how many the
+    mask-free body below it (both 0 where the kernels mask by position:
+    the rectangular grid, or a padded tail), the band's rows, and the
+    (q, k) pairs the steps compute over the pairs attention needs
+    (`seq_q`, `seq_k` are the lengths before padding)."""
+    steps, _, compact = _grid_steps(causal, sq, sk, bq, bk)
+    nq, nk = sq // bq, sk // bk
+    needed = seq_q * seq_k
+    if causal:  # query r sees keys 0..r
+        m = min(seq_q, seq_k)
+        needed = m * (m + 1) // 2 + (seq_q - m) * seq_k
+    bands = _bands(compact, bq, None if sk == seq_k else seq_k)
+    if bands:
+        tile = bq // bands
+        diag, interior = nq, steps - nq
+        computed = interior * bq * bk + diag * tile * tile * (
+            bands * (bands + 1) // 2
+        )
+    else:
+        tile = diag = interior = 0
+        # Steps that run: all of a compact or non-causal grid; of a
+        # causal rectangle, those not predicated off.
+        ran = steps if compact or not causal else sum(
+            min(nk, (i * bq + bq - 1) // bk + 1) for i in range(nq)
+        )
+        computed = ran * bq * bk
+    return {
+        "diag_steps": diag,
+        "interior_steps": interior,
+        "diag_tile": tile,
+        "computed_pairs_over_needed": computed / needed,
+    }
+
+
 def flash_schedule(
     seq_q: int,
     seq_k: int,
@@ -500,17 +555,173 @@ def flash_schedule(
         "lse_shape": lse_shape,
         "lse_bytes": int(np.prod(lse_shape)) * 4,
         "lse_replicated_bytes": sp_q * _LANES * 4,
+        # The diagonal (see `_step_tiles`): the forward's grid, and under
+        # `bwd_` the backward's (the same figures with equal blocks).
+        **_diag_accounting(causal, seq_q, seq_k, sp_q, sp_k, bq, bk),
+        **{
+            f"bwd_{key}": value
+            for key, value in _diag_accounting(
+                causal, seq_q, seq_k, sp_q, sp_k, bq_bwd, bk_bwd
+            ).items()
+        },
     }
 
 
 # -- kernels -----------------------------------------------------------------
+#
+# Every kernel's step is the same arithmetic over a list of (rows, cols)
+# tiles of its (bq, bk) block, in static slices; what differs with the
+# step's place in the grid is the list. `_step_tiles` picks it:
+#
+#   the whole block, masked by position   the rectangular grid, and any
+#       (`guard=True`)                     call with `kv_len`: the mask is
+#                                          computed from (i, j), and a row
+#                                          may be masked out altogether.
+#   the whole block, no mask, no guard     compact grid, below the
+#                                          diagonal (j < i): no score is
+#                                          masked and none is -inf.
+#   row bands under a static mask          compact grid, on the diagonal
+#                                          (j == i): `_diag_plan`.
+
+_WHOLE = slice(None)
+
+# Row bands a diagonal block is cut into, chosen from the block size
+# alone: the most of these whose band is still a whole number of lane
+# tiles (a band's keys end with its own rows, so its edge has to fall
+# on a multiple of 128); 1, the single tile with the static mask, where
+# no such cut exists. Two, not four: timed on the v5e at bq = 1024, one
+# layer's kernels alone, ms a call with 0 (the body that masks by
+# position) / 1 / 2 / 4 bands (my chip run, PR 29; PERF.md §6):
+#   (B·H, S) = (128, 2048)  forward 1.922 / 1.896 / 1.846 / 1.955,
+#                           fused backward 3.432 / 3.141 / 2.765 / 2.792
+#   (32, 8192)              forward 5.217 / 5.084 / 5.038 / 5.148,
+#                           fused backward 10.027 / 9.256 / 8.876 / 8.904
+# Four bands compute 10 of 16 sub-tiles against two bands' 3 of 4, but
+# their (256, ·) matmuls reload the MXU's weights four times as often
+# for the same rows, and the scheduler packs them no tighter.
+_DIAG_BANDS = (2, 1)
+
+
+def _diag_bands(bq: int) -> int:
+    for n in _DIAG_BANDS:
+        if bq % (n * _LANES) == 0:
+            return n
+    return 1
+
+
+def _bands(compact: bool, bq: int, kv_len: int | None) -> int:
+    """Bands of a diagonal block where a kernel knows the diagonal: on
+    the compact grid, unless a padded tail (`kv_len`) can mask keys the
+    triangle does not. 0 elsewhere: the body that masks by position."""
+    return _diag_bands(bq) if compact and kv_len is None else 0
+
+
+def _diag_plan(bq: int, n: int):
+    """The tiles of a block ON the diagonal of a square grid: band r of
+    `n` (rows [r·t, (r+1)·t), t = bq/n) against keys [0, (r+1)·t) and
+    nothing beyond, under `_band_mask`. n(n+1)/2 of the block's n²
+    (t, t) sub-tiles are computed."""
+    t = bq // n
+    return [
+        (slice(r * t, (r + 1) * t), slice(0, (r + 1) * t)) for r in range(n)
+    ]
+
+
+def _band_mask(s):
+    """The causal mask of a band of `_diag_plan`: its scores' last
+    column is its last row's own position, so row a keeps columns up to
+    a + (columns - rows). Static: on the diagonal of a square grid the
+    mask is the same constant in every block."""
+    rows, cols = s.shape
+    keep = lax.broadcasted_iota(jnp.int32, s.shape, 0) + (cols - rows) >= (
+        lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    )
+    return jnp.where(keep, s, _NEG_INF)
+
+
+def _step_tiles(i, j, run, tiles, *, causal, bq, bk, kv_len, compact):
+    """Run `tiles(plan, mask, guard)` for grid step (i, j): by the
+    step's place where the kernel can tell it from the grid (`_bands`),
+    else the whole block masked by position, under `run`."""
+    whole = [(_WHOLE, _WHOLE)]
+    bands = _bands(compact, bq, kv_len)
+    if bands:
+        pl.when(j < i)(lambda: tiles(whole, None, False))
+        pl.when(j == i)(
+            lambda: tiles(_diag_plan(bq, bands), _band_mask, False)
+        )
+        return
+
+    def mask(s):
+        if causal:
+            s = _causal_mask(s, i, j, bq, bk)
+        if kv_len is not None:
+            s = _kv_tail_mask(s, j, bk, kv_len)
+        return s
+
+    masked = causal or kv_len is not None
+    pl.when(run)(lambda: tiles(whole, mask if masked else None, True))
+
+
+def _dot_nt(a, b):
+    return lax.dot_general(
+        a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )
+
+
+def _dot_nn(a, b):
+    return lax.dot_general(
+        a, b, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+    )
+
+
+def _dot_tn(a, b):
+    return lax.dot_general(
+        a, b, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+    )
+
+
+def _fwd_tiles(
+    q_ref, k_ref, v_ref, m_scr, l_scr, acc, plan, mask, guard, scale
+):
+    """One online-softmax update of its rows of (m, l, acc) a tile."""
+    q_blk = q_ref[0].astype(jnp.float32) * scale
+    k_blk = k_ref[0].astype(jnp.float32)
+    v_blk = v_ref[0].astype(jnp.float32)
+    for rows, cols in plan:
+        s = _dot_nt(q_blk[rows], k_blk[cols])
+        if mask is not None:
+            s = mask(s)
+        m_prev = m_scr[rows, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        # Rows with every key masked so far keep m=-inf; exp(-inf - -inf)
+        # is nan, so the correction needs the guard, and P too wherever a
+        # whole row can be masked (`guard`). On the compact grid no row
+        # is: below the diagonal nothing is masked, and on it every row
+        # has its own position, so m is finite and exp(-inf - m) is 0.
+        safe_m = jnp.where(m_new == _NEG_INF, 0.0, m_new)
+        corr = jnp.where(m_prev == _NEG_INF, 0.0, jnp.exp(m_prev - safe_m))
+        # The accumulators are scaled before P is formed: the order the
+        # v5e's scheduler packs tightest (8,191 -> 7,423 bundles a step
+        # for the same operations; PERF.md §6, PR 29).
+        l_new = l_scr[rows, :1] * corr
+        acc_new = acc[rows, :] * corr
+        p = jnp.exp(s - safe_m)
+        if guard:
+            p = jnp.where(s == _NEG_INF, 0.0, p)
+        lanes = (s.shape[0], _LANES)
+        l_scr[rows, :] = jnp.broadcast_to(
+            l_new + jnp.sum(p, axis=-1, keepdims=True), lanes
+        )
+        acc[rows, :] = acc_new + _dot_nn(p, v_blk[cols])
+        m_scr[rows, :] = jnp.broadcast_to(m_new, lanes)
 
 
 def _fwd_body(
     i, j, first, last, run, q_ref, k_ref, v_ref, o_ref, lse_ref,
     m_scr, l_scr, acc,
     *, scale: float, causal: bool, bq: int, bk: int,
-    kv_len: int | None, packed: bool,
+    kv_len: int | None, packed: bool, compact: bool = False,
 ):
     @pl.when(first)
     def _init():
@@ -518,35 +729,13 @@ def _fwd_body(
         l_scr[:] = jnp.zeros_like(l_scr)
         acc[:] = jnp.zeros_like(acc)
 
-    @pl.when(run)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32) * scale
-        k = k_ref[0].astype(jnp.float32)
-        s = lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        if causal:
-            s = _causal_mask(s, i, j, bq, bk)
-        if kv_len is not None:
-            s = _kv_tail_mask(s, j, bk, kv_len)
-        m_prev = m_scr[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        # Rows with every key masked so far keep m=-inf; exp(-inf - -inf)
-        # is nan, so both the correction and P need the guard.
-        safe_m = jnp.where(m_new == _NEG_INF, 0.0, m_new)
-        corr = jnp.where(m_prev == _NEG_INF, 0.0, jnp.exp(m_prev - safe_m))
-        p = jnp.where(s == _NEG_INF, 0.0, jnp.exp(s - safe_m))
-        l_scr[:] = jnp.broadcast_to(
-            l_scr[:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True),
-            l_scr.shape,
-        )
-        acc[:] = acc[:] * corr + lax.dot_general(
-            p,
-            v_ref[0].astype(jnp.float32),
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+    _step_tiles(
+        i, j, run,
+        functools.partial(
+            _fwd_tiles, q_ref, k_ref, v_ref, m_scr, l_scr, acc, scale=scale
+        ),
+        causal=causal, bq=bq, bk=bk, kv_len=kv_len, compact=compact,
+    )
 
     @pl.when(last)
     def _finalize():
@@ -591,7 +780,8 @@ def _fwd_kernel_compact(
     j = cols_ref[t]
     _fwd_body(
         i, j, j == 0, j == i, True,
-        q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc, **kw
+        q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc,
+        compact=True, **kw,
     )
 
 
@@ -608,40 +798,63 @@ def _delta_kernel(o_ref, do_ref, delta_ref, *, packed: bool):
     delta_ref[0] = _pack_rows(rep) if packed else rep
 
 
+def _bwd_tiles(
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, plan, mask, guard,
+    scale, packed, dk_acc=None, dv_acc=None, dq=None,
+):
+    """The (s, p, ds) recurrence, computed once a tile of `plan` and fed
+    to whichever gradients the kernel accumulates: dV += pᵀ·dO and dK +=
+    dSᵀ·(scale·q) on the tile's key rows of `dv_acc` / `dk_acc`, and
+    `dq(rows, dS·K)`. q is loaded pre-scaled, so dK carries the
+    1/sqrt(d) factor and dq takes it once more where the kernel writes
+    it out."""
+    q_blk = q_ref[0].astype(jnp.float32) * scale
+    k_blk = k_ref[0].astype(jnp.float32)
+    v_blk = v_ref[0].astype(jnp.float32)
+    do_blk = do_ref[0].astype(jnp.float32)
+    lse_blk = _read_rows(lse_ref[0], packed)
+    delta_blk = _read_rows(delta_ref[0], packed)
+    for rows, cols in plan:
+        q, k, do = q_blk[rows], k_blk[cols], do_blk[rows]
+        s = _dot_nt(q, k)
+        if mask is not None:
+            s = mask(s)
+        # A masked score is -inf and lse is finite wherever a row has a
+        # key, so exp gives 0; `guard` covers the rows that have none
+        # (lse = -inf too: nan).
+        p = jnp.exp(s - lse_blk[rows])
+        if guard:
+            p = jnp.where(s == _NEG_INF, 0.0, p)
+        if dv_acc is not None:
+            dv_acc[cols, :] = dv_acc[cols, :] + _dot_tn(p, do)
+        ds = p * (_dot_nt(do, v_blk[cols]) - delta_blk[rows])
+        if dk_acc is not None:
+            dk_acc[cols, :] = dk_acc[cols, :] + _dot_tn(ds, q)
+        if dq is not None:
+            dq(rows, _dot_nn(ds, k))
+
+
 def _dq_body(
     i, j, first, last, run, q_ref, k_ref, v_ref, do_ref, lse_ref,
     delta_ref, dq_ref, dq_acc,
     *, scale: float, causal: bool, bq: int, bk: int,
-    kv_len: int | None, packed: bool,
+    kv_len: int | None, packed: bool, compact: bool = False,
 ):
     @pl.when(first)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    @pl.when(run)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32) * scale
-        k = k_ref[0].astype(jnp.float32)
-        s = lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        if causal:
-            s = _causal_mask(s, i, j, bq, bk)
-        if kv_len is not None:
-            s = _kv_tail_mask(s, j, bk, kv_len)
-        lse = _read_rows(lse_ref[0], packed)
-        p = jnp.where(s == _NEG_INF, 0.0, jnp.exp(s - lse))
-        do = do_ref[0].astype(jnp.float32)
-        dp = lax.dot_general(
-            do,
-            v_ref[0].astype(jnp.float32),
-            (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - _read_rows(delta_ref[0], packed))
-        dq_acc[:] = dq_acc[:] + lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+    def dq(rows, dq_rows):
+        dq_acc[rows, :] = dq_acc[rows, :] + dq_rows
+
+    _step_tiles(
+        i, j, run,
+        functools.partial(
+            _bwd_tiles, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+            scale=scale, packed=packed, dq=dq,
+        ),
+        causal=causal, bq=bq, bk=bk, kv_len=kv_len, compact=compact,
+    )
 
     @pl.when(last)
     def _finalize():
@@ -674,7 +887,7 @@ def _dq_kernel_compact(
     _dq_body(
         i, j, j == 0, j == i, True,
         q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc,
-        **kw,
+        compact=True, **kw,
     )
 
 
@@ -682,40 +895,21 @@ def _dkv_body(
     i, j, first, last, run, q_ref, k_ref, v_ref, do_ref, lse_ref,
     delta_ref, dk_ref, dv_ref, dk_acc, dv_acc,
     *, scale: float, causal: bool, bq: int, bk: int,
-    kv_len: int | None, packed: bool,
+    kv_len: int | None, packed: bool, compact: bool = False,
 ):
     @pl.when(first)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    @pl.when(run)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32) * scale
-        k = k_ref[0].astype(jnp.float32)
-        s = lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        if causal:
-            s = _causal_mask(s, i, j, bq, bk)
-        if kv_len is not None:
-            s = _kv_tail_mask(s, j, bk, kv_len)
-        lse = _read_rows(lse_ref[0], packed)
-        p = jnp.where(s == _NEG_INF, 0.0, jnp.exp(s - lse))
-        do = do_ref[0].astype(jnp.float32)
-        dv_acc[:] = dv_acc[:] + lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        dp = lax.dot_general(
-            do,
-            v_ref[0].astype(jnp.float32),
-            (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - _read_rows(delta_ref[0], packed))
-        dk_acc[:] = dk_acc[:] + lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+    _step_tiles(
+        i, j, run,
+        functools.partial(
+            _bwd_tiles, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+            scale=scale, packed=packed, dk_acc=dk_acc, dv_acc=dv_acc,
+        ),
+        causal=causal, bq=bq, bk=bk, kv_len=kv_len, compact=compact,
+    )
 
     @pl.when(last)
     def _finalize():
@@ -756,7 +950,7 @@ def _dkv_kernel_compact(
     _dkv_body(
         i, j, i == j, i == nq - 1, True,
         q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-        dk_acc, dv_acc, **kw,
+        dk_acc, dv_acc, compact=True, **kw,
     )
 
 
@@ -793,48 +987,31 @@ def _dqkv_kernel_fused(
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    q = q_ref[0].astype(jnp.float32) * scale
-    k = k_ref[0].astype(jnp.float32)
-    s = lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    if causal:
-        s = _causal_mask(s, i, j, bq, bk)
-    if kv_len is not None:
-        s = _kv_tail_mask(s, j, bk, kv_len)
-    lse = _read_rows(lse_ref[0], packed)
-    p = jnp.where(s == _NEG_INF, 0.0, jnp.exp(s - lse))
-    do = do_ref[0].astype(jnp.float32)
-    dv_acc[:] = dv_acc[:] + lax.dot_general(
-        p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    dp = lax.dot_general(
-        do,
-        v_ref[0].astype(jnp.float32),
-        (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    ds = p * (dp - _read_rows(delta_ref[0], packed))
-    dk_acc[:] = dk_acc[:] + lax.dot_general(
-        ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    # dq contribution for row i from column j; q was loaded pre-scaled,
-    # so the ring carries the 1/sqrt(d) factor once more at flush (same
-    # algebra as `_dq_body`'s finalize).
-    dq_i = lax.dot_general(
-        ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    slot = pl.ds(i * bq, bq)
+    def dq(rows, dq_rows):
+        # dq contribution of column j to (a band of) row block i; q was
+        # loaded pre-scaled, so the ring carries the 1/sqrt(d) factor
+        # once more at flush (same algebra as `_dq_body`'s finalize).
+        start = rows.start or 0
+        slot = pl.ds(i * bq + start, (rows.stop or bq) - start)
 
-    @pl.when(j == 0)
-    def _seed():
-        # Column 0 is every row's first contribution — a store, not an
-        # accumulate, so the ring never needs a zeroing pass.
-        dq_ring[slot, :] = dq_i
+        @pl.when(j == 0)
+        def _seed():
+            # Column 0 is every row's first contribution — a store, not
+            # an accumulate, so the ring never needs a zeroing pass.
+            dq_ring[slot, :] = dq_rows
 
-    @pl.when(j > 0)
-    def _accum():
-        dq_ring[slot, :] = dq_ring[slot, :] + dq_i
+        @pl.when(j > 0)
+        def _accum():
+            dq_ring[slot, :] = dq_ring[slot, :] + dq_rows
+
+    _step_tiles(
+        i, j, True,
+        functools.partial(
+            _bwd_tiles, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+            scale=scale, packed=packed, dk_acc=dk_acc, dv_acc=dv_acc, dq=dq,
+        ),
+        causal=causal, bq=bq, bk=bk, kv_len=kv_len, compact=True,
+    )
 
     @pl.when(last)
     def _flush():

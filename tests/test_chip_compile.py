@@ -26,6 +26,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from kubeflow_tpu.ops.flash import (
+    _FUSED_VMEM_BUDGET,
     flash_attention,
     flash_schedule,
     ring_flash_attention,
@@ -117,6 +118,12 @@ def test_flash_forward_backward_compiles_on_the_reported_schedule(
     )
     sched = flash_schedule(s, s, head_dim=HEAD_DIM, dtype_bytes=2)
     assert sched["bwd_fused"] == (s <= 16384)
+    # Both bodies of a step are in what compiles: banded on the s/1024
+    # diagonal steps, mask-free on the rest (ISSUE 29); the footprint
+    # model that admits the fused kernel is still an upper bound.
+    assert sched["bwd_diag_steps"] == s // 1024 and sched["bwd_diag_tile"] == 512
+    assert sched["bwd_interior_steps"] == sched["bwd_grid_steps"] - s // 1024
+    assert (sched["bwd_fused_vmem_bytes"] <= _FUSED_VMEM_BUDGET) == (s <= 16384)
     want = (
         ["flash_bwd_fused"] if sched["bwd_fused"]
         else ["flash_dq_compact", "flash_dkv_compact"]
@@ -183,8 +190,10 @@ def test_sub_1024_blocks_select_the_replicated_lse_and_compile(one_chip):
 @pytest.mark.parametrize(
     "s,padded,block,packed",
     [
-        (2000, 2000, 1000, False),  # tiles by 1000-row blocks, unpadded
-        (2001, 2048, 1024, True),  # no aligned divisor: pads, masks the tail
+        # tiles by 1000-row blocks, unpadded: one band a diagonal block
+        (2000, 2000, 1000, False),
+        # no aligned divisor: pads, masks the tail and by position
+        (2001, 2048, 1024, True),
     ],
 )
 def test_ragged_sequences_compile(one_chip, s, padded, block, packed):
@@ -192,6 +201,7 @@ def test_ragged_sequences_compile(one_chip, s, padded, block, packed):
     assert (sched["padded_seq_q"], sched["block_q"], sched["lse_packed"]) == (
         padded, block, packed
     )
+    assert sched["diag_tile"] == (1000 if padded == s else 0)
     text, names = _compile(
         jax.grad(_loss, argnums=(0, 1, 2)), *_qkv(2, 8, s, one_chip)
     )

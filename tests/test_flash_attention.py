@@ -153,17 +153,28 @@ def test_ring_flash_forward_matches_dense(causal, sp):
     )
 
 
-@pytest.mark.parametrize("causal", [True, False])
-def test_ring_flash_grads_match_dense(causal):
+@pytest.mark.parametrize(
+    "causal,s,block",
+    [
+        (True, 16, 1024),
+        (False, 16, 1024),
+        # Chunks of 512 in 256-blocks: the diagonal hop runs the compact
+        # grid with its diagonal blocks cut in two bands and one block
+        # below them; the full hop runs the rectangular grid.
+        (True, 1024, 256),
+    ],
+)
+def test_ring_flash_grads_match_dense(causal, s, block):
     from kubeflow_tpu.ops.attention import dense_attention
     from kubeflow_tpu.ops.flash import ring_flash_attention
 
     mesh = _ring_mesh(2)
-    q, k, v = _qkv(jax.random.PRNGKey(1), b=2, s=16, h=2, d=128)
+    q, k, v = _qkv(jax.random.PRNGKey(1), b=2, s=s, h=2, d=128)
 
     def ring_loss(q, k, v):
         out = ring_flash_attention(
-            q, k, v, mesh, causal=causal, heads_axis=None, interpret=True
+            q, k, v, mesh, causal=causal, heads_axis=None, interpret=True,
+            block_q=block, block_k=block,
         )
         return jnp.sum(out.astype(jnp.float32) ** 2)
 

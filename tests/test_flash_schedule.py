@@ -10,10 +10,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from kubeflow_tpu.ops import flash
 from kubeflow_tpu.ops.attention import dense_attention
 from kubeflow_tpu.ops.flash import (
     _LANES,
     _bwd_fused,
+    _diag_bands,
+    _diag_plan,
     _flash_bwd_kernels,
     _flash_delta_impl,
     _flash_fwd_impl,
@@ -505,3 +508,204 @@ def test_bf16_compact_packed_path():
     np.testing.assert_allclose(
         out.astype(np.float32), ref.astype(np.float32), atol=3e-2, rtol=3e-2
     )
+
+
+# -- the diagonal inside a block (ISSUE 29) ----------------------------------
+
+
+@pytest.fixture
+def mask_calls(monkeypatch):
+    """Which masks a kernel body builds while it is traced: `_band_mask`
+    is the banded body on the diagonal, `_causal_mask` / `_kv_tail_mask`
+    the body that masks by position. Counted at trace time, so a case
+    has to bring a shape no other test of this process has traced."""
+    calls = []
+
+    def counted(name):
+        real = getattr(flash, name)
+
+        def mask(*args):
+            calls.append(name)
+            return real(*args)
+
+        return mask
+
+    for name in ("_band_mask", "_causal_mask", "_kv_tail_mask"):
+        monkeypatch.setattr(flash, name, counted(name))
+    return calls
+
+
+@pytest.mark.parametrize(
+    "bq,bands",
+    [(2048, 2), (1024, 2), (512, 2), (768, 2), (256, 2), (384, 1),
+     (128, 1), (64, 1), (1000, 1)],
+)
+def test_bands_come_from_the_block_size_alone(bq, bands):
+    """Two bands where a band's rows are whole lane tiles, else one; band
+    r's keys end with its own rows, so n(n+1)/2 of the n² sub-tiles are
+    computed and the static mask keeps column c of row a where
+    c <= a + r·t."""
+    assert _diag_bands(bq) == bands
+    for n in (bands, 4):  # the plan itself takes any number of bands
+        t = bq // n
+        plan = _diag_plan(bq, n)
+        assert plan == [
+            (slice(r * t, (r + 1) * t), slice(0, (r + 1) * t))
+            for r in range(n)
+        ]
+        pairs = sum(
+            (rows.stop - rows.start) * (cols.stop - cols.start)
+            for rows, cols in plan
+        )
+        assert pairs == t * t * n * (n + 1) // 2
+    last = np.asarray(flash._band_mask(jnp.zeros((8, 24))))
+    want = np.where(np.arange(8)[:, None] + 16 >= np.arange(24), 0.0, -np.inf)
+    np.testing.assert_array_equal(last, want)
+
+
+@pytest.mark.parametrize("two_pass", [False, True])
+@pytest.mark.parametrize("group", [1, 4])
+@pytest.mark.parametrize(
+    "s,block,bands", [(256, 128, 1), (512, 256, 2), (1024, 512, 4)]
+)
+def test_diagonal_and_interior_bodies_match_dense(
+    s, block, bands, group, two_pass, monkeypatch, mask_calls
+):
+    """Two diagonal steps and one below the diagonal a grid row, with
+    the diagonal blocks cut into 1, 2 and 4 bands, equal and grouped
+    heads, through the fused backward and (KFTPU_FLASH_FUSED_BWD=0) the
+    two-pass kernels: forward and all three gradients against dense
+    attention, and only the static mask is ever built. The rule gives
+    1 and 2 bands; 4, which the chip timed no faster, is put in its
+    place here so the plan stays held for any number."""
+    monkeypatch.setenv("KFTPU_FLASH_FUSED_BWD", "0" if two_pass else "1")
+    if bands == 4:
+        assert _diag_bands(block) == 2
+        monkeypatch.setattr(flash, "_DIAG_BANDS", (4, 2, 1))
+    d = 24 + 8 * two_pass  # a shape of this case alone (see `mask_calls`)
+    sched = flash_schedule(
+        s, s, block_q=block, block_k=block, head_dim=d, dtype_bytes=4
+    )
+    assert (sched["diag_steps"], sched["interior_steps"]) == (2, 1)
+    assert sched["diag_tile"] == block // bands
+    assert sched["bwd_fused"] == (not two_pass)
+
+    ks = jax.random.split(jax.random.PRNGKey(s + group), 3)
+    q = jax.random.normal(ks[0], (1, s, 4, d))
+    k = jax.random.normal(ks[1], (1, s, 4 // group, d))
+    v = jax.random.normal(ks[2], (1, s, 4 // group, d))
+    attn = lambda q, k, v: flash_attention(
+        q, k, v, causal=True, block_q=block, block_k=block, interpret=True
+    )
+    dense = lambda q, k, v: dense_attention(
+        q, jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2),
+        causal=True,
+    )
+    fused, dq2, dkv2 = _bwd_kernel_counts(attn, q, k, v)
+    assert (fused, dq2, dkv2) == ((0, 1, 1) if two_pass else (1, 0, 0))
+    np.testing.assert_allclose(
+        attn(q, k, v), dense(q, k, v), atol=2e-5, rtol=2e-5
+    )
+    for g, w, name in zip(
+        _grads(attn, q, k, v), _grads(dense, q, k, v), "qkv"
+    ):
+        assert g.shape == w.shape
+        np.testing.assert_allclose(
+            g, w, atol=1e-4, rtol=1e-4, err_msg=f"d{name} mismatch"
+        )
+    assert set(mask_calls) == {"_band_mask"}, mask_calls
+
+
+@pytest.mark.parametrize(
+    "s,causal,masks",
+    [
+        # 502 = 2 x 251 has no 8-aligned divisor: pads to 512, two
+        # 256-blocks, the tail masked through kv_len.
+        (502, True, {"_causal_mask", "_kv_tail_mask"}),
+        (512, False, set()),  # rectangular grid, nothing masked
+    ],
+)
+def test_ragged_and_noncausal_keep_the_body_that_masks_by_position(
+    s, causal, masks, mask_calls
+):
+    """A padded tail can mask keys the triangle does not, and the
+    rectangular grid has no diagonal of square blocks: at a block size
+    whose diagonal would be cut in two, both run the whole block under
+    the position mask, and still match dense."""
+    sched = flash_schedule(s, s, block_q=256, block_k=256, causal=causal)
+    assert sched["block_q"] == 256 and sched["padded_seq_q"] == 512
+    assert sched["diag_steps"] == sched["interior_steps"] == 0
+    assert sched["diag_tile"] == 0
+
+    q, k, v = _qkv(jax.random.PRNGKey(s), 1, s, 2, 40)
+    attn = lambda q, k, v: flash_attention(
+        q, k, v, causal=causal, block_q=256, block_k=256, interpret=True
+    )
+    dense = lambda q, k, v: dense_attention(q, k, v, causal=causal)
+    np.testing.assert_allclose(
+        attn(q, k, v), dense(q, k, v), atol=2e-5, rtol=2e-5
+    )
+    for g, w, name in zip(
+        _grads(attn, q, k, v), _grads(dense, q, k, v), "qkv"
+    ):
+        np.testing.assert_allclose(
+            g, w, atol=1e-4, rtol=1e-4, err_msg=f"d{name} mismatch"
+        )
+    assert set(mask_calls) == masks, mask_calls
+
+
+@pytest.mark.parametrize(
+    "s,diag,interior,pairs,pairs_whole_blocks",
+    [(2048, 2, 1, 1.249, 1.50), (8192, 8, 28, 1.062, 1.125),
+     (16384, 16, 120, 1.031, 1.0625)],
+)
+def test_schedule_counts_the_steps_on_and_below_the_diagonal(
+    s, diag, interior, pairs, pairs_whole_blocks, monkeypatch
+):
+    """The engagement counter is static, like the schedule: at the
+    benchmark cells' shapes (1024-blocks, two bands of 512) a grid row
+    has `diag` banded steps and `interior` mask-free ones, together the
+    whole grid, and computes `pairs` times the pairs attention needs
+    where whole blocks computed `pairs_whole_blocks` times."""
+    sched = flash_schedule(s, s)
+    for prefix in ("", "bwd_"):
+        assert sched[prefix + "diag_steps"] == diag
+        assert sched[prefix + "interior_steps"] == interior
+        assert sched[prefix + "diag_tile"] == 512
+        assert diag + interior == sched[prefix + "grid_steps"]
+        assert sched[prefix + "computed_pairs_over_needed"] == pytest.approx(
+            pairs, abs=1e-3
+        )
+    exact = (interior * 1024**2 + diag * 3 * 512**2) / (s * (s + 1) // 2)
+    assert sched["computed_pairs_over_needed"] == exact
+    monkeypatch.setattr(flash, "_bands", lambda compact, bq, kv_len: 0)
+    whole = flash_schedule(s, s)
+    assert whole["diag_steps"] == whole["interior_steps"] == 0
+    assert whole["computed_pairs_over_needed"] == pytest.approx(
+        pairs_whole_blocks, abs=1e-3
+    )
+
+
+def test_schedule_pairs_where_the_bodies_do_not_engage():
+    """Non-causal: every pair is needed and computed. Uneven blocks: the
+    causal rectangle computes the blocks it does not predicate off. A
+    padded length: the compact grid over the padded blocks, against the
+    pairs of the true length. The backward's figures follow its blocks."""
+    assert flash_schedule(512, 512, causal=False)[
+        "computed_pairs_over_needed"
+    ] == 1.0
+    uneven = flash_schedule(256, 256, block_q=64, block_k=128)
+    assert not uneven["compact"] and uneven["diag_steps"] == 0
+    # rows 0-63 and 64-127 see one 128-block of keys, the rest two.
+    assert uneven["computed_pairs_over_needed"] == (
+        (2 * 1 + 2 * 2) * 64 * 128 / (256 * 257 // 2)
+    )
+    ragged = flash_schedule(2001, 2001)
+    assert ragged["padded_seq_q"] == 2048 and ragged["diag_steps"] == 0
+    assert ragged["computed_pairs_over_needed"] == (
+        3 * 1024**2 / (2001 * 2002 // 2)
+    )
+    split = flash_schedule(2048, 2048, bwd_block_q=512, bwd_block_k=512)
+    assert (split["diag_steps"], split["bwd_diag_steps"]) == (2, 4)
+    assert (split["diag_tile"], split["bwd_diag_tile"]) == (512, 256)
+    assert split["bwd_interior_steps"] == 6
