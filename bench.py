@@ -119,7 +119,7 @@ def main() -> None:
     parser.add_argument("--seq-len", type=int, default=2048)
     parser.add_argument(
         "--remat-policy",
-        choices=("auto", "none", "full", "dots", "attn", "mlp", "flash"),
+        choices=("auto", "none", "full", "mlp", "flash"),
         default="auto",
         help="lm only: per-block checkpoint policy. auto = none (no "
         "remat at all — every activation saved) at S<=8192 with the "
@@ -129,27 +129,19 @@ def main() -> None:
         "half; attention residuals saved so the flash forward never "
         "re-runs in the backward) — at 16k no-remat's saved "
         "activations crowd out the batch (51.9%% mlp vs 50.8%% none "
-        "at bs=2). dots spills at long S; full re-runs flash fwd in "
+        "at bs=2). full re-runs flash fwd in "
         "bwd; flash pins only each attention's output + packed lse "
         "(strictly less state than mlp, same no-recompute property — "
         "the long-context candidate to sweep against mlp)",
     )
     parser.add_argument(
         "--flash-block-q", type=int, default=None,
-        help="lm only: flash kernel Q tile (default: model default 1024; "
-        "long-S sweeps want smaller tiles — see docs/architecture.md)",
+        help="attention only: flash kernel Q tile (default: the "
+        "kernel's own, 1024)",
     )
     parser.add_argument(
         "--flash-block-k", type=int, default=None,
-        help="lm only: flash kernel K tile",
-    )
-    parser.add_argument(
-        "--flash-block-q-bwd", type=int, default=None,
-        help="lm only: backward-pass Q tile (default: same as forward)",
-    )
-    parser.add_argument(
-        "--flash-block-k-bwd", type=int, default=None,
-        help="lm only: backward-pass K tile",
+        help="attention only: flash kernel K tile",
     )
     parser.add_argument(
         "--head-dim", type=int, default=128,
@@ -3117,8 +3109,8 @@ def bench_attention(args) -> None:
                     f"exactly the fused kernel (traced {bwd_kernels}) — "
                     "schedule accounting and dispatch have drifted"
                 )
-            nq_bwd = sched["padded_seq_q"] // sched["bwd_block_q"]
-            if nq_bwd >= 8 and bwd_ratio > 0.62:
+            nq = sched["padded_seq_q"] // sched["block_q"]
+            if nq >= 8 and bwd_ratio > 0.62:
                 raise SystemExit(
                     f"attention s={s}: fused backward models only "
                     f"{bwd_ratio:.3f}x the two-pass HBM bytes (expected "
@@ -3386,7 +3378,8 @@ def bench_pipeline(args) -> None:
     pp, dp, n_mb, seq = 2, 2, 8, 128
     cfg = TransformerConfig(
         vocab_size=256, d_model=64, n_layers=4, n_heads=4, head_dim=16,
-        d_ff=128, dtype=jnp.float32, remat=False, attention_impl="dense",
+        d_ff=128, dtype=jnp.float32, remat_policy="none",
+        attention_impl="dense",
     )
     mesh = build_mesh(MeshSpec(pp=pp, dp=dp), devices[:pp * dp])
     # One microbatch = 2 examples per batch shard.
@@ -3584,16 +3577,6 @@ def bench_lm(args) -> None:
             if args.remat_policy == "auto"
             else args.remat_policy
         ),
-        **(
-            {"flash_block_q": args.flash_block_q}
-            if args.flash_block_q else {}
-        ),
-        **(
-            {"flash_block_k": args.flash_block_k}
-            if args.flash_block_k else {}
-        ),
-        flash_block_q_bwd=args.flash_block_q_bwd,
-        flash_block_k_bwd=args.flash_block_k_bwd,
     )
     # Measured-best per-chip batches under the mlp remat policy: 8 @2k,
     # 2 @8k (bs=4 is -2.8 MFU pts), 2 @16k (fits since the lse-residual

@@ -332,9 +332,7 @@ def phase_train(
                 f"{n_calls} tpu_custom_call(s)"
             )
         fused = flash_schedule(
-            seq_len, seq_len, head_dim=model["head_dim"], dtype_bytes=2,
-            block_q=trainer.model.config.flash_block_q,
-            block_k=trainer.model.config.flash_block_k,
+            seq_len, seq_len, head_dim=model["head_dim"], dtype_bytes=2
         )["bwd_fused"]
         if fused != (schedule == "fused"):
             raise AssertionError(

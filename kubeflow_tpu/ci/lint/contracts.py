@@ -158,7 +158,7 @@ def _build_pipeline(interleave: int) -> Program:
     _require_devices(2)
     cfg = TransformerConfig(
         vocab_size=64, d_model=16, n_layers=4, n_heads=2, head_dim=8,
-        d_ff=16, remat=False, dtype=jax.numpy.float32,
+        d_ff=16, remat_policy="none", dtype=jax.numpy.float32,
         attention_impl="dense",
     )
     mesh = build_mesh(MeshSpec(dp=1, pp=2), jax.devices()[:2])
@@ -200,7 +200,6 @@ def _build_fused_flash_grad() -> Program:
     import jax
     import jax.numpy as jnp
 
-    from kubeflow_tpu.models.transformer import checkpoint_policy
     from kubeflow_tpu.ops import flash
     from kubeflow_tpu.testing.hlo import pallas_kernel_names
 
@@ -219,9 +218,11 @@ def _build_fused_flash_grad() -> Program:
         )
 
     grads = lambda f: jax.grad(f, argnums=(0, 1, 2))
-    grad_ckpt = grads(
-        jax.checkpoint(loss, policy=checkpoint_policy("flash"))
+    # What `remat_policy="flash"` pins round a block (`_block_cls`).
+    pinned = jax.checkpoint_policies.save_only_these_names(
+        flash.CHECKPOINT_OUT_NAME, flash.CHECKPOINT_LSE_NAME
     )
+    grad_ckpt = grads(jax.checkpoint(loss, policy=pinned))
     kernels_plain = pallas_kernel_names(grads(loss), q, k, v)
     kernels_ckpt = pallas_kernel_names(grad_ckpt, q, k, v)
     fwd_count = lambda names: sum(n.startswith("flash_fwd_") for n in names)
@@ -247,16 +248,16 @@ def _build_fused_flash_grad() -> Program:
             "fwd_count_ckpt": fwd_count(kernels_ckpt),
             "bwd_fused": sched["bwd_fused"],
             "single_kv_pass": (
-                sched["bwd_total_grid_steps"] == sched["bwd_grid_steps"]
+                sched["bwd_total_grid_steps"] == sched["grid_steps"]
             ),
             "deep_fused": deep["bwd_fused"],
             "deep_single_kv_pass": (
-                deep["bwd_total_grid_steps"] == deep["bwd_grid_steps"]
+                deep["bwd_total_grid_steps"] == deep["grid_steps"]
             ),
             "noncausal_two_pass": (
                 not noncausal["bwd_fused"]
                 and noncausal["bwd_total_grid_steps"]
-                == 2 * noncausal["bwd_grid_steps"]
+                == 2 * noncausal["grid_steps"]
             ),
             "byte_model_ok": (
                 deep["bwd_hbm_bytes_fused"]

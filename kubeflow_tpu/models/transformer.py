@@ -20,26 +20,17 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import warnings
 from typing import Any
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from kubeflow_tpu.ops.attention import dense_attention, ring_attention
+from kubeflow_tpu.ops.attention import attend
+from kubeflow_tpu.ops.flash import CHECKPOINT_LSE_NAME, CHECKPOINT_OUT_NAME
 from kubeflow_tpu.ops.moe import expert_mlp_on_mesh
 from kubeflow_tpu.parallel.sharding import batch_axes
-from kubeflow_tpu.ops.flash import (
-    CHECKPOINT_LSE_NAME,
-    CHECKPOINT_OUT_NAME,
-    flash_attention,
-    flash_kernel_tileable,
-    flash_usable,
-    kernels_compiled,
-)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -52,54 +43,23 @@ class TransformerConfig:
     d_ff: int = 2048
     rope_theta: float = 10_000.0
     dtype: Any = jnp.bfloat16
-    remat: bool = True
-    # Rematerialization policy for the per-block checkpoint:
-    #   "none" — no remat anywhere, every activation saved (fastest
-    #            WHEN it fits HBM: +7% over "mlp" at S<=8192 with the
-    #            bench's measured-best batches);
-    #   "mlp"  — remat only the MLP half; attention residuals (q/k/v,
-    #            o, lse) stay saved so the flash forward never re-runs
-    #            in the backward (the long-context winner at 16k);
-    #   "full" — save only block boundaries, recompute everything
-    #            (lowest memory);
-    #   "dots" — save matmul outputs, recompute elementwise/norm only
-    #            (jax.checkpoint_policies.dots_with_no_batch_dims_saveable;
-    #            spills at long S);
-    #   "attn" — pin only the attention output (measured-neutral: the
-    #            custom-VJP's lse residual is out of the policy's
-    #            reach). See docs/architecture.md LM roofline.
-    #   "flash" — pin the flash kernel's named outputs (attention output
-    #            AND its log-sum-exp, `flash_attn_out`/`flash_attn_lse`)
-    #            so the backward never re-runs the forward attention
-    #            kernel; everything else (projections, norms, MLP)
-    #            recomputes as under "full". With the lane-packed lse the
-    #            pinned state is O(S·d) + O(S) per layer — strictly less
-    #            than "mlp" saves (which pins q/k/v/o/lse) while dodging
-    #            the same flash-forward recompute. Requires the flash
-    #            kernel path; under the dense fallback nothing is named,
-    #            so it degrades to "full" (use "attn" there).
+    # What the backward recomputes (`_block_cls`), by what a cell shows:
+    #   "none"  — nothing: every activation saved. Fastest wherever it
+    #             fits (three of the benchmark's cells run it).
+    #   "flash" — the block, but for the flash kernels' named output and
+    #             log-sum-exp, so the forward kernel never re-runs in
+    #             the backward (`zaya1-8b-ep2.train-8k`, which does not
+    #             fit otherwise). Under dense attention nothing is
+    #             named: the same program as "full".
+    #   "mlp"   — the MLP half only; attention's residuals stay saved.
+    #             No cell: less memory than "none", more than "flash".
+    #   "full"  — the whole block from its input. No cell: least memory.
     remat_policy: str = "full"
-    # Attention kernel for the non-ring path: "auto" uses the Pallas flash
-    # kernel on TPU when the shapes divide into flash blocks, else the
-    # XLA-fused dense reference. "flash"/"dense" force one implementation.
+    # "auto" runs the flash kernels wherever they compile (any backend
+    # but the CPU), else the dense reference; "flash" / "dense" force
+    # one (`ops/attention.attend`). Tiles and the backward's schedule
+    # are `ops/flash.py`'s, from the shapes.
     attention_impl: str = "auto"
-    # Flash kernel tile sizes (clamped to the sequence). The (1024, 1024)
-    # default is short-S-tuned; long sequences want a smaller K tile so
-    # the running (o, lse) state and K/V tiles fit VMEM together — sweep
-    # via `bench.py --workload lm --flash-block-q/-k` (docs/architecture.md
-    # records the winning configs per S).
-    flash_block_q: int = 1024
-    flash_block_k: int = 1024
-    # Backward-pass tiles (None = same as forward). NOTE: the fused
-    # one-pass dq/dkv backward (ops/flash.py, ISSUE 7) requires SQUARE
-    # bwd tiles (the compact triangular grid) and engages while its dq
-    # ring fits VMEM — asymmetric bwd tiles forfeit both the compact
-    # enumeration and the fusion, and smaller squares raise the
-    # streamed bytes (docs/architecture.md Round-6 dead-end log), so
-    # the (1024, 1024) default is also the fused-backward winner at
-    # every measured S.
-    flash_block_q_bwd: int | None = None
-    flash_block_k_bwd: int | None = None
     # Grouped K/V heads: query head h attends over kv head
     # h // (n_heads / n_kv_heads). None = as many as query heads.
     n_kv_heads: int | None = None
@@ -131,69 +91,32 @@ class TransformerConfig:
     router_force_balance: bool = False
 
 
-def checkpoint_policy(name: str):
-    """`jax.checkpoint` policy object for a named remat policy.
-
-    Shared by `_block_cls` (per-block remat) and the trainer's
-    whole-step remat (`TrainConfig.step_remat`) so the two layers can't
-    drift. Only the policies that ARE `jax.checkpoint` policies live
-    here — "none" (no checkpoint) and "mlp" (a structural split, not a
-    policy) are handled by `_block_cls` directly.
-    """
-    if name == "full":
-        return None  # checkpoint with no policy: save block boundaries only
-    if name == "dots":
-        return jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-    if name == "attn":
-        return jax.checkpoint_policies.save_only_these_names("attn_out")
-    if name == "flash":
-        return jax.checkpoint_policies.save_only_these_names(
-            CHECKPOINT_OUT_NAME, CHECKPOINT_LSE_NAME
-        )
-    raise ValueError(
-        f"no jax.checkpoint policy for remat_policy {name!r}; expected "
-        "'full', 'dots', 'attn', or 'flash'"
-    )
-
-
 def _block_cls(cfg: "TransformerConfig"):
     """Block, wrapped per the config's remat policy."""
-    if not cfg.remat or cfg.remat_policy == "none":
-        # No rematerialization anywhere: every activation is saved. The
-        # fastest policy WHEN the activations fit HBM — measured +7%
-        # tokens/s over "mlp" at S=2048/bs=8 through S=8192/bs=2 on
-        # 1xv5e (the recompute tax "mlp" still pays on its MLP half);
-        # "mlp" retakes the lead at S=16384 where the saved activations
-        # crowd out the batch (docs/architecture.md roofline).
+    if cfg.remat_policy in ("none", "mlp"):
+        # No checkpoint round the block ("mlp": `Block` remats its MLP
+        # half itself), so attention's residuals (q/k/v, o, lse) are saved.
         return Block
-    if cfg.remat_policy in ("dots", "attn", "flash"):
-        # Policy-driven checkpoints. "attn" saves only the named
-        # attention output — the classic save-what's-costly-and-small
-        # trade, but the flash custom-VJP's lse residual is out of its
-        # reach, so the flash FORWARD still re-runs in the backward to
-        # rebuild it (measured-neutral). "flash" fixes exactly that: the
-        # kernel names both its output and its (lane-packed) lse, the
+    if cfg.remat_policy == "full":
+        return nn.remat(Block, static_argnums=())
+    if cfg.remat_policy == "flash":
+        # The kernel names its output and its (lane-packed) lse, the
         # policy pins both, and the backward's partial eval dead-codes
-        # the forward kernel entirely — q/k/v recompute from the cheap
-        # projections, o/lse come from the saved residuals.
+        # the forward kernel: q/k/v recompute from the cheap projections,
+        # o/lse come from the saved residuals. Any other checkpoint whose
+        # boundary crosses the flash custom_vjp re-runs the forward kernel
+        # to rebuild lse.
         return nn.remat(
             Block,
             static_argnums=(),
-            policy=checkpoint_policy(cfg.remat_policy),
+            policy=jax.checkpoint_policies.save_only_these_names(
+                CHECKPOINT_OUT_NAME, CHECKPOINT_LSE_NAME
+            ),
         )
-    if cfg.remat_policy == "mlp":
-        # Long-context policy that actually dodges the flash recompute:
-        # NO checkpoint wraps the block — attention's residuals (q/k/v,
-        # o, lse) are saved — and Block itself remats only its MLP half.
-        # Any policy whose checkpoint boundary crosses the flash
-        # custom_vjp ("full", "dots", "attn") re-runs the flash FORWARD
-        # inside the backward to rebuild lse; at S=16k attention is
-        # ~half the layer's FLOPs, so that recompute is the long-context
-        # tax. Costs O(S·d) more activation memory per layer.
-        return Block
-    if cfg.remat_policy != "full":
-        raise ValueError(f"unknown remat_policy {cfg.remat_policy!r}")
-    return nn.remat(Block, static_argnums=())
+    raise ValueError(
+        f"unknown remat_policy {cfg.remat_policy!r}; expected 'none', "
+        "'full', 'mlp' or 'flash'"
+    )
 
 
 def _dense(features, names, name=None, dtype=jnp.bfloat16):
@@ -271,104 +194,6 @@ def rope(x, positions, theta: float, fraction: float = 1.0):
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
-
-
-def _attend(q, k, v, mesh: Mesh | None, cfg: "TransformerConfig"):
-    """Dispatch: ring when the sp axis is real, else flash/dense.
-
-    The flash kernel is a Pallas call, which does not auto-partition under
-    pjit — with a mesh it runs inside shard_map over the batch/tp axes
-    (embarrassingly parallel: each shard attends over its own batch rows and
-    heads; the sequence axis is unsharded on this path).
-    """
-    impl = cfg.attention_impl
-    bq, bk = cfg.flash_block_q, cfg.flash_block_k
-    group = q.shape[2] // k.shape[2]
-    # Only the flash kernels pick a query head's kv head themselves; the
-    # ring and dense paths get K and V repeated over the group.
-    repeat = lambda x: x if group == 1 else jnp.repeat(x, group, axis=2)
-    if impl not in ("auto", "flash", "dense"):
-        raise ValueError(
-            f"unknown attention_impl {impl!r}; expected 'auto', 'flash', "
-            "or 'dense'"
-        )
-    if mesh is not None and mesh.shape.get("sp", 1) > 1:
-        # Ring (sequence-parallel) path. Where the kernels compile (any
-        # backend but the CPU) and the local chunks are flash-tileable,
-        # every ring hop runs the Pallas kernel (ring flash: per-device
-        # attention memory O(C·D), not O(C²)) — the long-context
-        # composition; otherwise the dense-hop ring.
-        chunk = q.shape[1] // mesh.shape["sp"]
-        if (
-            impl in ("auto", "flash")
-            and kernels_compiled()
-            and flash_kernel_tileable(chunk, bq)
-            and flash_kernel_tileable(chunk, bk)
-        ):
-            from kubeflow_tpu.ops.flash import ring_flash_attention
-
-            return ring_flash_attention(
-                q, repeat(k), repeat(v), mesh, causal=True,
-                block_q=bq, block_k=bk,
-            )
-        return ring_attention(q, repeat(k), repeat(v), mesh, causal=True)
-    # flash_usable is now unconditionally true for positive lengths
-    # (ragged sequences pad inside the kernel wrapper instead of
-    # silently falling back to the dense O(S²) path); the predicate
-    # stays as the dispatch contract.
-    use_flash = impl == "flash" or (
-        impl == "auto"
-        and kernels_compiled()
-        and flash_usable(q.shape[1], k.shape[1], bq, bk)
-    )
-    if use_flash and mesh is not None:
-        # The shard_map wrapper needs batch % (dp·fsdp) == 0 and
-        # heads % tp == 0 — stricter than pjit auto-partitioning, so the
-        # auto path falls back to dense rather than erroring, and says
-        # so: O(S²) attention on an accelerator is never silent.
-
-        bsz = 1
-        for a in batch_axes(mesh):
-            bsz *= mesh.shape[a]
-        tp = mesh.shape.get("tp", 1)
-        if q.shape[0] % bsz or k.shape[2] % tp:
-            if impl == "flash":
-                raise ValueError(
-                    f"attention_impl='flash' on a mesh requires batch "
-                    f"({q.shape[0]}) divisible by dp·fsdp ({bsz}) and heads "
-                    f"({k.shape[2]}) divisible by tp ({tp})"
-                )
-            warnings.warn(
-                f"attention_impl='auto': batch ({q.shape[0]}) does not "
-                f"divide dp·fsdp ({bsz}) or heads ({k.shape[2]}) do not "
-                f"divide tp ({tp}); running DENSE O(S²) attention instead "
-                "of the flash kernels",
-                RuntimeWarning,
-                stacklevel=2,
-            )
-            use_flash = False
-    if not use_flash:
-        return dense_attention(q, repeat(k), repeat(v), causal=True)
-    bwd = {
-        "bwd_block_q": cfg.flash_block_q_bwd,
-        "bwd_block_k": cfg.flash_block_k_bwd,
-    }
-    if mesh is None:
-        return flash_attention(
-            q, k, v, causal=True, block_q=bq, block_k=bk, **bwd
-        )
-
-    heads = "tp" if mesh.shape.get("tp", 1) > 1 else None
-    spec = P(batch_axes(mesh), None, heads, None)
-    return jax.shard_map(
-        functools.partial(
-            flash_attention, causal=True, block_q=bq, block_k=bk, **bwd
-        ),
-        mesh=mesh,
-        in_specs=(spec, spec, spec),
-        out_specs=spec,
-        check_vma=False,
-    )(q, k, v)
 
 
 def _shift(x, steps: int):
@@ -468,11 +293,8 @@ class Attention(nn.Module):
                 q, k, v = self._cca_mix(q, k, v)
         q = rope(q, positions, cfg.rope_theta, cfg.rope_fraction)
         k = rope(k, positions, cfg.rope_theta, cfg.rope_fraction)
-        # Named so the "attn" remat policy can pin exactly this value as
-        # the saved residual (everything else in the block recomputes).
         with jax.named_scope("cca.attend" if cfg.cca else "attend"):
-            out = _attend(q, k, v, self.mesh, cfg)
-        out = checkpoint_name(out, "attn_out")
+            out = attend(q, k, v, mesh=self.mesh, impl=cfg.attention_impl)
         out = nn.DenseGeneral(
             cfg.d_model,
             axis=(-2, -1),
@@ -671,7 +493,7 @@ class Block(nn.Module):
         # transform keeps the param path, so weights are identical to
         # the unwrapped module's).
         wrap = (
-            nn.remat if cfg.remat and cfg.remat_policy == "mlp"
+            nn.remat if cfg.remat_policy == "mlp"
             else (lambda cls: cls)
         )
         h = norm(name="ln_mlp")(x)

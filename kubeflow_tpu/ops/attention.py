@@ -1,4 +1,5 @@
-"""Attention: dense reference and ring (sequence-parallel) implementation.
+"""Attention: the model's one entry point (`attend`), the dense reference
+and the ring (sequence-parallel) implementation.
 
 Long context is first-class here where the reference had nothing (SURVEY.md
 §5 "Long-context / sequence parallelism: Absent"). The design is blockwise
@@ -16,12 +17,19 @@ from __future__ import annotations
 
 import functools
 import math
+import warnings
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
+from kubeflow_tpu.ops.flash import (
+    flash_attention,
+    flash_kernel_tileable,
+    kernels_compiled,
+    ring_flash_attention,
+)
 from kubeflow_tpu.parallel.collectives import axis_size
 from kubeflow_tpu.parallel.sharding import batch_axes
 
@@ -135,6 +143,81 @@ def ring_attention(
     body = functools.partial(_ring_body, axis=sp_axis, causal=causal)
     return jax.shard_map(
         body,
+        mesh=mesh,
+        in_specs=(spec, spec, spec),
+        out_specs=spec,
+        check_vma=False,
+    )(q, k, v)
+
+
+def attend(q, k, v, *, mesh: Mesh | None, impl: str):
+    """Causal self-attention of q [B, S, H, D] over k, v [B, S, Hkv, D]
+    (H a multiple of Hkv): ring when the mesh's `sp` axis is real, else
+    the flash kernels or the dense reference, as `impl` says (`auto`:
+    the kernels wherever they compile; `flash` / `dense` force one).
+
+    The flash kernel is a Pallas call, which does not auto-partition under
+    pjit — with a mesh it runs inside shard_map over the batch/tp axes
+    (embarrassingly parallel: each shard attends over its own batch rows and
+    heads; the sequence axis is unsharded on this path).
+    """
+    if impl not in ("auto", "flash", "dense"):
+        raise ValueError(
+            f"unknown attention_impl {impl!r}; expected 'auto', 'flash', "
+            "or 'dense'"
+        )
+    group = q.shape[2] // k.shape[2]
+    # Only the flash kernels pick a query head's kv head themselves; the
+    # ring and dense paths get K and V repeated over the group.
+    repeat = lambda x: x if group == 1 else jnp.repeat(x, group, axis=2)
+    if mesh is not None and mesh.shape.get("sp", 1) > 1:
+        # Ring (sequence-parallel) path. Where the kernels compile (any
+        # backend but the CPU) and the local chunks are flash-tileable,
+        # every ring hop runs the Pallas kernel (ring flash: per-device
+        # attention memory O(C·D), not O(C²)) — the long-context
+        # composition; otherwise the dense-hop ring.
+        chunk = q.shape[1] // mesh.shape["sp"]
+        if (
+            impl in ("auto", "flash")
+            and kernels_compiled()
+            and flash_kernel_tileable(chunk)
+        ):
+            return ring_flash_attention(
+                q, repeat(k), repeat(v), mesh, causal=True
+            )
+        return ring_attention(q, repeat(k), repeat(v), mesh, causal=True)
+    use_flash = impl == "flash" or (impl == "auto" and kernels_compiled())
+    if use_flash and mesh is not None:
+        # The shard_map wrapper needs batch % (dp·fsdp) == 0 and
+        # heads % tp == 0 — stricter than pjit auto-partitioning, so the
+        # auto path falls back to dense rather than erroring, and says
+        # so: O(S²) attention on an accelerator is never silent.
+        bsz = math.prod(mesh.shape[a] for a in batch_axes(mesh))
+        tp = mesh.shape.get("tp", 1)
+        if q.shape[0] % bsz or k.shape[2] % tp:
+            if impl == "flash":
+                raise ValueError(
+                    f"attention_impl='flash' on a mesh requires batch "
+                    f"({q.shape[0]}) divisible by dp·fsdp ({bsz}) and heads "
+                    f"({k.shape[2]}) divisible by tp ({tp})"
+                )
+            warnings.warn(
+                f"attention_impl='auto': batch ({q.shape[0]}) does not "
+                f"divide dp·fsdp ({bsz}) or heads ({k.shape[2]}) do not "
+                f"divide tp ({tp}); running DENSE O(S²) attention instead "
+                "of the flash kernels",
+                RuntimeWarning,
+                stacklevel=2,
+            )
+            use_flash = False
+    if not use_flash:
+        return dense_attention(q, repeat(k), repeat(v), causal=True)
+    if mesh is None:
+        return flash_attention(q, k, v, causal=True)
+    heads = "tp" if mesh.shape.get("tp", 1) > 1 else None
+    spec = P(batch_axes(mesh), None, heads, None)
+    return jax.shard_map(
+        functools.partial(flash_attention, causal=True),
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,

@@ -67,8 +67,6 @@ on top:
   (`_FUSED_VMEM_BUDGET`) and fusion is gated by `_bwd_fused` (the same
   predicate `flash_schedule` reports as `bwd_fused`); past the budget —
   or on the rectangular fallback — the two-pass kernels run unchanged.
-  `KFTPU_FLASH_FUSED_BWD=0` force-disables fusion (operational escape
-  hatch).
 - **Internal padding.** Sequence lengths with no 8-aligned divisor pad
   to the next lane multiple inside `flash_attention`; the tail is
   masked in-kernel (`kv_len`) and sliced off the output, so ragged
@@ -104,7 +102,6 @@ from __future__ import annotations
 
 import functools
 import math
-import os
 
 import jax
 import jax.numpy as jnp
@@ -136,8 +133,8 @@ def kernels_compiled() -> bool:
     CPU backend (the test suite) interprets; any accelerator compiles,
     so a platform string this code has never seen reaches the TPU
     compiler and is refused there, never quietly interpreted or routed
-    to dense attention. `models/transformer._attend` dispatches on the
-    same predicate."""
+    to dense attention. `ops/attention.attend` dispatches on the same
+    predicate."""
     return jax.default_backend() != "cpu"
 
 
@@ -167,9 +164,9 @@ def _kv_tail_mask(s, j, bk, kv_len: int):
 #   replicated [BH, S, 128]     — every lane carries the row's value (the
 #              layout Mosaic's (8, 128) tiling forces when the q block is
 #              not lane-aligned).
-# The packed layout needs S and every q-block size in play (fwd and bwd)
-# to be multiples of 128 so block boundaries land on packed-row
-# boundaries, and each packed (bq/128, 128) block must itself be a
+# The packed layout needs S and the q block to be multiples of 128 so
+# block boundaries land on packed-row boundaries, and each packed
+# (bq/128, 128) block must itself be a
 # legal TPU tile: its second-minor dim a sublane multiple, or the whole
 # array's. Outside the kernels the canonical form is per-row
 # [BH, S, 1] ("rows"), to which both layouts convert with free reshapes.
@@ -187,15 +184,15 @@ def _lse_block(bq: int, packed: bool) -> tuple[int, ...]:
     return (1, bq, _LANES)
 
 
-def _lse_is_packed(sq: int, *q_blocks: int) -> bool:
+def _lse_is_packed(sq: int, bq: int) -> bool:
     """The predicate the TPU lowering enforces on the packed lse block
     (1, bq/128, 128): bq/128 divides by 8, or the block spans the
     sequence. Interpret mode accepts any 128-multiple, which is how
     128..896-wide blocks passed every CPU test and were refused by the
     chip's compiler."""
     tile = _SUBLANES * _LANES
-    return sq % _LANES == 0 and all(
-        b % tile == 0 or (b == sq and b % _LANES == 0) for b in q_blocks
+    return sq % _LANES == 0 and (
+        bq % tile == 0 or (bq == sq and bq % _LANES == 0)
     )
 
 
@@ -339,17 +336,6 @@ def _tri_tables(nq: int, order: str):
 # boundary the first, too-small model (ring + accumulators + inputs
 # against 12 MiB) drew.
 _FUSED_VMEM_BUDGET = 32 * 1024 * 1024
-# Operational escape hatch: KFTPU_FLASH_FUSED_BWD=0 pins the two-pass
-# backward everywhere (e.g. if a toolchain rejects the fused kernel).
-# Read at TRACE time — jit caches a traced backward by shapes/static
-# args, so this is a set-before-first-use process knob (a rollback
-# lever for launch scripts), not a runtime toggle: flipping it after a
-# shape has been traced does not retrace that shape.
-_FUSED_ENV = "KFTPU_FLASH_FUSED_BWD"
-
-
-def _fused_enabled() -> bool:
-    return os.environ.get(_FUSED_ENV, "1") != "0"
 
 
 def _lse_bytes_of(sq: int, packed: bool) -> int:
@@ -389,15 +375,11 @@ def _bwd_fused(
 ) -> bool:
     """Whether the backward runs the fused one-pass kernel: compact
     causal grid (square blocks, self-attention) AND the modelled
-    footprint fits the VMEM the call asks the compiler for. Shared verbatim by `flash_schedule` (reported as
-    `bwd_fused`) and the `_flash_bwd_kernels` dispatch, so the
-    accounting benches/tests gate on is the schedule that actually
-    runs."""
-    if not _fused_enabled():
-        return False
-    if not _compactable(causal, sq, sk, bq, bk):
-        return False
-    return (
+    footprint fits the VMEM the call asks the compiler for. Shared
+    verbatim by `flash_schedule` (reported as `bwd_fused`) and the
+    `_flash_bwd_kernels` dispatch, so the accounting benches/tests gate
+    on is the schedule that actually runs."""
+    return _compactable(causal, sq, sk, bq, bk) and (
         _fused_vmem_bytes(sq, bq, bk, d, itemsize, packed)
         <= _FUSED_VMEM_BUDGET
     )
@@ -490,8 +472,6 @@ def flash_schedule(
     *,
     block_q: int = 1024,
     block_k: int = 1024,
-    bwd_block_q: int | None = None,
-    bwd_block_k: int | None = None,
     causal: bool = True,
     head_dim: int = 128,
     dtype_bytes: int = 2,
@@ -502,51 +482,40 @@ def flash_schedule(
     (`_grid_steps`, `_lse_is_packed`, `_pad_to_tileable`, `_bwd_fused`),
     exposed so benches and regression tests can assert grid-step counts
     and lse/backward HBM bytes without launching a kernel. All
-    byte/step figures are per (batch*head) grid row; `head_dim` and
+    byte/step figures are per (batch*head) grid row, and the backward's
+    kernels walk the forward's grid (the same blocks); `head_dim` and
     `dtype_bytes` (2 = bf16, the training dtype) parameterize the
     backward byte/VMEM models only."""
     sp_q = _pad_to_tileable(block_q, seq_q)
     sp_k = _pad_to_tileable(block_k, seq_k)
     bq = _pick_block(block_q, sp_q)
     bk = _pick_block(block_k, sp_k)
-    bq_bwd = _pick_block(bwd_block_q or block_q, sp_q)
-    bk_bwd = _pick_block(bwd_block_k or block_k, sp_k)
     steps, rect, compact = _grid_steps(causal, sp_q, sp_k, bq, bk)
-    # The backward kernels run their own grids with the (possibly
-    # narrower) bwd blocks — dq and dkv each walk this many steps.
-    bwd_steps, bwd_rect, bwd_compact = _grid_steps(
-        causal, sp_q, sp_k, bq_bwd, bk_bwd
-    )
-    packed = _lse_is_packed(sp_q, bq, bq_bwd)
+    packed = _lse_is_packed(sp_q, bq)
     lse_shape = _lse_layout_shape(1, sp_q, packed)[1:]
     fused = _bwd_fused(
-        causal, sp_q, sp_k, bq_bwd, bk_bwd, head_dim, dtype_bytes, packed
+        causal, sp_q, sp_k, bq, bk, head_dim, dtype_bytes, packed
     )
     bwd_bytes = lambda f: _bwd_hbm_bytes(
-        causal, sp_q, sp_k, bq_bwd, bk_bwd, head_dim, dtype_bytes, packed, f
+        causal, sp_q, sp_k, bq, bk, head_dim, dtype_bytes, packed, f
     )
     return {
         "padded_seq_q": sp_q,
         "padded_seq_k": sp_k,
         "block_q": bq,
         "block_k": bk,
-        "bwd_block_q": bq_bwd,
-        "bwd_block_k": bk_bwd,
         "compact": compact,
         "grid_steps": steps,
         "rect_grid_steps": rect,
-        "bwd_compact": bwd_compact,
-        "bwd_grid_steps": bwd_steps,
-        "bwd_rect_grid_steps": bwd_rect,
         # Fused one-pass backward: whether it engages at these
         # shapes/dtype, the total bwd grid steps actually walked (one
         # triangle pass fused, two passes otherwise — the single-KV-pass
         # gate), and the modeled HBM bytes per bh row for BOTH paths so
         # benches can assert the fused path's ~halving.
         "bwd_fused": fused,
-        "bwd_total_grid_steps": bwd_steps if fused else 2 * bwd_steps,
+        "bwd_total_grid_steps": steps if fused else 2 * steps,
         "bwd_fused_vmem_bytes": _fused_vmem_bytes(
-            sp_q, bq_bwd, bk_bwd, head_dim, dtype_bytes, packed
+            sp_q, bq, bk, head_dim, dtype_bytes, packed
         ),
         "bwd_hbm_bytes": bwd_bytes(fused),
         "bwd_hbm_bytes_fused": bwd_bytes(True),
@@ -555,15 +524,8 @@ def flash_schedule(
         "lse_shape": lse_shape,
         "lse_bytes": int(np.prod(lse_shape)) * 4,
         "lse_replicated_bytes": sp_q * _LANES * 4,
-        # The diagonal (see `_step_tiles`): the forward's grid, and under
-        # `bwd_` the backward's (the same figures with equal blocks).
+        # The diagonal (see `_step_tiles`), in forward and backward alike.
         **_diag_accounting(causal, seq_q, seq_k, sp_q, sp_k, bq, bk),
-        **{
-            f"bwd_{key}": value
-            for key, value in _diag_accounting(
-                causal, seq_q, seq_k, sp_q, sp_k, bq_bwd, bk_bwd
-            ).items()
-        },
     }
 
 
@@ -1422,19 +1384,16 @@ def _flash_bwd_impl(
 # -- custom VJP --------------------------------------------------------------
 
 
-def _residual_packed(sq: int, block_q: int, bwd_block_q: int) -> bool:
-    return _lse_is_packed(
-        sq, _pick_block(block_q, sq), _pick_block(bwd_block_q, sq)
-    )
+def _residual_packed(sq: int, block_q: int) -> bool:
+    return _lse_is_packed(sq, _pick_block(block_q, sq))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
-def _flash_bhsd(q, k, v, causal, block_q, block_k, bwd_block_q, bwd_block_k,
-                interpret, kv_len):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_bhsd(q, k, v, causal, block_q, block_k, interpret, kv_len):
     """Returns (o, lse). The lse output carries NO cotangent path (its
     incoming gradient is discarded in the VJP) — it exists so callers
     and `remat_policy="flash"` can hold the softmax statistics."""
-    packed = _residual_packed(q.shape[1], block_q, bwd_block_q)
+    packed = _residual_packed(q.shape[1], block_q)
     o, lse = _flash_fwd_impl(
         q, k, v, causal, block_q, block_k, interpret, kv_len, packed
     )
@@ -1445,9 +1404,8 @@ def _flash_bhsd(q, k, v, causal, block_q, block_k, bwd_block_q, bwd_block_k,
     return o, lse
 
 
-def _flash_vjp_fwd(q, k, v, causal, block_q, block_k, bwd_block_q,
-                   bwd_block_k, interpret, kv_len):
-    packed = _residual_packed(q.shape[1], block_q, bwd_block_q)
+def _flash_vjp_fwd(q, k, v, causal, block_q, block_k, interpret, kv_len):
+    packed = _residual_packed(q.shape[1], block_q)
     o, lse = _flash_fwd_impl(
         q, k, v, causal, block_q, block_k, interpret, kv_len, packed
     )
@@ -1465,15 +1423,15 @@ def _flash_vjp_fwd(q, k, v, causal, block_q, block_k, bwd_block_q,
     return (o, lse), (q, k, v, o, lse)
 
 
-def _flash_vjp_bwd(causal, block_q, block_k, bwd_block_q, bwd_block_k,
-                   interpret, kv_len, residuals, cts):
+def _flash_vjp_bwd(causal, block_q, block_k, interpret, kv_len, residuals,
+                   cts):
     q, k, v, o, lse = residuals
     do, _ = cts  # the lse output is statistics-only; its cotangent drops
-    packed = _residual_packed(q.shape[1], block_q, bwd_block_q)
+    packed = _residual_packed(q.shape[1], block_q)
     lse_layout = _rows_to_layout(_lse_rows(lse, q.shape[1]), packed)
     return _flash_bwd_impl(
-        q, k, v, o, lse_layout, do, causal, bwd_block_q, bwd_block_k,
-        interpret, kv_len, packed,
+        q, k, v, o, lse_layout, do, causal, block_q, block_k, interpret,
+        kv_len, packed,
     )
 
 
@@ -1488,8 +1446,6 @@ def flash_attention(
     causal: bool = True,
     block_q: int = 1024,
     block_k: int = 1024,
-    bwd_block_q: int | None = None,
-    bwd_block_k: int | None = None,
     interpret: bool | None = None,
     return_lse: bool = False,
 ):
@@ -1543,14 +1499,9 @@ def flash_attention(
     to_bhsd = lambda x: x.transpose(0, 2, 1, 3).reshape(
         b * x.shape[2], x.shape[1], d
     )
-    # The backward kernels carry bigger VMEM footprints (extra f32
-    # accumulators, and the fused one-pass kernel's dq ring), so wide
-    # forward tiles can be paired with safer backward tiles; default =
-    # same blocks both ways. Note the fused backward needs SQUARE bwd
-    # blocks (compact grid) — asymmetric pairs fall back to two-pass.
     o, lse = _flash_bhsd(
         to_bhsd(q), to_bhsd(k), to_bhsd(v), causal, block_q, block_k,
-        bwd_block_q or block_q, bwd_block_k or block_k, interp, kv_len,
+        interp, kv_len,
     )
     o = o.reshape(b, h, sp_q, d).transpose(0, 2, 1, 3)
     if sp_q != sq:
@@ -1561,20 +1512,10 @@ def flash_attention(
     return o, lse_rows
 
 
-def flash_usable(seq_q: int, seq_k: int, block_q: int = 1024,
-                 block_k: int = 1024) -> bool:
-    """True when `flash_attention` can run these shapes — which, since
-    ragged lengths pad internally, is any positive pair. Kept as the
-    dispatch predicate (`models/transformer._attend`) so call sites
-    don't hard-code the padding contract."""
-    del block_q, block_k
-    return seq_q >= 1 and seq_k >= 1
-
-
 def flash_kernel_tileable(seq: int, block: int = 1024) -> bool:
     """True when `seq` divides into 8-aligned flash blocks WITHOUT
     padding. The ring path needs this (chunks must stay congruent across
-    hops, so it cannot pad); everything else should use `flash_usable`."""
+    hops, so it cannot pad); `flash_attention` pads what does not."""
     return _tileable(block, seq)
 
 
@@ -1604,17 +1545,13 @@ def _unflat_heads(x, b, h):
     return x.reshape(b, h, s, d).transpose(0, 2, 1, 3)
 
 
-def _ring_packed(chunk: int, bq: int) -> bool:
-    return _lse_is_packed(chunk, _pick_block(bq, chunk))
-
-
 def _hop_branches(qf, kf, vf, bq, bk, interpret):
     """(full, diagonal, skip) branch thunks for one ring hop — the hop
     kind is data-dependent (axis_index), the kernel's causal flag is
     static, so lax.switch picks among three static traces. Each branch
     returns (o, lse) with lse in per-row [BH, C, 1] form."""
     bh, c, d = qf.shape
-    packed = _ring_packed(c, bq)
+    packed = _residual_packed(c, bq)
 
     def full_blk():
         o, lse = _flash_fwd_impl(qf, kf, vf, False, bq, bk, interpret,
@@ -1718,7 +1655,7 @@ def _ring_flash_body_bwd(axis, causal, bq, bk, interpret, residuals, do):
     my = lax.axis_index(axis)
     qf, of, dof = _flat_heads(q), _flat_heads(o), _flat_heads(do)
     bh = b * h
-    packed = _ring_packed(c, bq)
+    packed = _residual_packed(c, bq)
     lse_layout = _rows_to_layout(lse_rows, packed)
     # Shared delta across the whole ring: delta = rowsum(do ∘ o) depends
     # only on the GLOBAL output and its cotangent, which every hop

@@ -95,25 +95,15 @@ class TrainConfig:
     # the MaxText default. The second moment stays f32 (it accumulates
     # squares; bf16 there costs real precision). "float32" opts out.
     adam_mu_dtype: str = "bfloat16"
-    # Whole-step rematerialization: wrap the loss forward in
-    # jax.checkpoint with the named policy ("full", "dots", "attn",
-    # "flash" — resolved by models.transformer.checkpoint_policy). This
-    # is the trainer-level knob for models WITHOUT their own per-block
-    # remat (or with remat_policy="none"): e.g. step_remat="flash" pins
-    # only each attention's output + lse across the whole step, so the
-    # backward recomputes the cheap dense layers but never re-runs a
-    # flash forward kernel. None (default) = no step-level checkpoint;
-    # per-block policies in the model compose underneath either way.
-    step_remat: str | None = None
     # Per-microbatch gradient accumulation: split each batch into
     # `accum_steps` microbatches and run them through a `lax.scan` whose
     # per-tick forward is wrapped in `jax.checkpoint`, differentiating
     # through the scan — the backward walks the microbatches in reverse,
     # recomputing each tick's forward, so activation memory is bounded
     # by ONE microbatch in flight instead of the whole batch. Composes
-    # with `step_remat` and the model's per-block `remat_policy` (those
-    # govern what the per-tick recompute itself saves — e.g. "flash"
-    # still pins attention outputs + lse within a tick). Works on any
+    # with the model's per-block `remat_policy` (which governs what
+    # the per-tick recompute itself saves — e.g. "flash" still pins
+    # attention outputs + lse within a tick). Works on any
     # mesh, pp or not; grads and loss equal the full-batch step's (mean
     # of equal-sized microbatch means). 1 = off.
     accum_steps: int = 1
@@ -138,13 +128,6 @@ class TrainConfig:
             )
         if self.optimizer not in ("sgd", "adamw"):
             raise ValueError(f"unknown optimizer {self.optimizer!r}")
-        if self.step_remat is not None and self.step_remat not in (
-            "full", "dots", "attn", "flash"
-        ):
-            raise ValueError(
-                f"step_remat must be None, 'full', 'dots', 'attn', or "
-                f"'flash', got {self.step_remat!r}"
-            )
         if self.adam_mu_dtype not in ("bfloat16", "float32"):
             raise ValueError(
                 f"adam_mu_dtype must be 'bfloat16' or 'float32', got "
@@ -454,14 +437,6 @@ class Trainer:
                             mutable=mutable,
                         )
 
-                if cfg.step_remat is not None:
-                    from kubeflow_tpu.models.transformer import (
-                        checkpoint_policy,
-                    )
-
-                    forward = jax.checkpoint(
-                        forward, policy=checkpoint_policy(cfg.step_remat)
-                    )
                 out, new_vars = forward(variables)
                 if cfg.loss_in_model:
                     loss = out
@@ -510,9 +485,9 @@ class Trainer:
                 # Per-tick checkpoint: differentiating through the scan
                 # re-runs ONE microbatch's forward per backward tick —
                 # activation memory is bounded by microbatches in
-                # flight, not the whole batch. step_remat / the model's
-                # remat_policy still govern what that per-tick
-                # recompute itself saves.
+                # flight, not the whole batch. The model's remat_policy
+                # still governs what that per-tick recompute itself
+                # saves.
                 tick = jax.checkpoint(forward_loss)
 
                 def accum_loss(params):

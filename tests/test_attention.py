@@ -77,3 +77,80 @@ def test_ring_with_sharded_inputs(devices):
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref), rtol=2e-4, atol=2e-4
     )
+
+
+# -- attend: the model's one entry point ------------------------------------
+#
+# (impl, kernels compile?, mesh, (B, S, H, Hkv)) -> the kernels of the traced
+# program, or what is raised. Nothing runs: the dispatch is read from the
+# `pallas_call`s of a jaxpr.
+ATTEND_CASES = {
+    "auto_on_the_cpu": ("auto", False, None, (2, 256, 4, 4), []),
+    "auto_compiled": ("auto", True, None, (2, 256, 4, 4), ["flash_fwd_compact"]),
+    "dense_forced": ("dense", True, None, (2, 256, 4, 4), []),
+    "auto_dp_tp_grouped": (
+        "auto", True, {"dp": 2, "tp": 2}, (4, 256, 8, 4), ["flash_fwd_compact"]
+    ),
+    "sp_ring_flash": (
+        # a hop is a switch over a chunk from before (the whole rectangle),
+        # the device's own (the triangle) and one from after (skipped)
+        "auto", True, {"sp": 2}, (2, 256, 4, 2),
+        ["flash_fwd_rect", "flash_fwd_compact"] * 2,
+    ),
+    "sp_ring_dense_hops": ("auto", False, {"sp": 2}, (2, 256, 4, 2), []),
+    "auto_batch_not_dividing_dp": (
+        "auto", True, {"dp": 2}, (3, 256, 4, 4), RuntimeWarning
+    ),
+    "flash_batch_not_dividing_dp": (
+        "flash", True, {"dp": 2}, (3, 256, 4, 4), ValueError
+    ),
+    "unknown_impl": ("pallas", True, None, (2, 256, 4, 4), ValueError),
+}
+
+
+@pytest.mark.parametrize("case", ATTEND_CASES)
+def test_attend_dispatch(devices, monkeypatch, case):
+    from kubeflow_tpu.ops import attention
+    from kubeflow_tpu.testing.hlo import _walk_eqns
+
+    impl, compiled, spec, (b, s, h, hkv), want = ATTEND_CASES[case]
+    monkeypatch.setattr(attention, "kernels_compiled", lambda: compiled)
+    mesh = spec and build_mesh(
+        MeshSpec(**spec), devices[: int(np.prod(list(spec.values())))]
+    )
+    q = jax.ShapeDtypeStruct((b, s, h, 8), jnp.float32)
+    kv = jax.ShapeDtypeStruct((b, s, hkv, 8), jnp.float32)
+    trace = lambda: jax.make_jaxpr(
+        lambda q, k, v: attention.attend(q, k, v, mesh=mesh, impl=impl)
+    )(q, kv, kv)
+
+    if want is ValueError:
+        with pytest.raises(ValueError, match="attention_impl"):
+            trace()
+        return
+    if want is RuntimeWarning:
+        with pytest.warns(RuntimeWarning, match="DENSE"):
+            jaxpr = trace()
+        want = []
+    else:
+        jaxpr = trace()
+    assert jaxpr.out_avals[0].shape == q.shape
+    outer = {e.primitive.name for e in jaxpr.jaxpr.eqns}
+    calls = [
+        e for e in _walk_eqns(jaxpr.jaxpr) if e.primitive.name == "pallas_call"
+    ]
+    assert [e.params["name"] for e in calls] == want
+    if spec:
+        # Whatever runs on a mesh runs inside the seam's own shard_map...
+        assert ("shard_map" in outer) == (bool(want) or "sp" in spec)
+    if case == "auto_dp_tp_grouped":
+        # ...and the kernels pick a query head's K/V head themselves: K and
+        # V go in as they came, a device's 2 rows of 2 heads, not its 4.
+        (call,) = calls
+        shapes = [v.aval.shape for v in call.invars if len(v.aval.shape) == 3]
+        assert shapes == [(2 * 4, s, 8), (2 * 2, s, 8), (2 * 2, s, 8)]
+    if "sp" in (spec or {}):
+        permutes = [
+            e for e in _walk_eqns(jaxpr.jaxpr) if e.primitive.name == "ppermute"
+        ]
+        assert permutes

@@ -79,15 +79,14 @@ def test_auto_attention_says_so_when_it_falls_to_dense(devices, monkeypatch):
     silently on a backend where the kernels would have compiled."""
     import jax.numpy as jnp
 
-    from kubeflow_tpu.models import transformer
+    from kubeflow_tpu.ops import attention
     from kubeflow_tpu.parallel import MeshSpec, build_mesh
 
-    monkeypatch.setattr(transformer, "kernels_compiled", lambda: True)
+    monkeypatch.setattr(attention, "kernels_compiled", lambda: True)
     mesh = build_mesh(MeshSpec(tp=2), devices[:2])
-    cfg = transformer.TransformerConfig(n_heads=3, head_dim=8)
     q = jnp.ones((2, 16, 3, 8))
     with pytest.warns(RuntimeWarning, match="DENSE"):
-        out = transformer._attend(q, q, q, mesh, cfg)
+        out = attention.attend(q, q, q, mesh=mesh, impl="auto")
     assert out.shape == q.shape
 
 

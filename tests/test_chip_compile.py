@@ -121,8 +121,8 @@ def test_flash_forward_backward_compiles_on_the_reported_schedule(
     # Both bodies of a step are in what compiles: banded on the s/1024
     # diagonal steps, mask-free on the rest (ISSUE 29); the footprint
     # model that admits the fused kernel is still an upper bound.
-    assert sched["bwd_diag_steps"] == s // 1024 and sched["bwd_diag_tile"] == 512
-    assert sched["bwd_interior_steps"] == sched["bwd_grid_steps"] - s // 1024
+    assert sched["diag_steps"] == s // 1024 and sched["diag_tile"] == 512
+    assert sched["interior_steps"] == sched["grid_steps"] - s // 1024
     assert (sched["bwd_fused_vmem_bytes"] <= _FUSED_VMEM_BUDGET) == (s <= 16384)
     want = (
         ["flash_bwd_fused"] if sched["bwd_fused"]
@@ -269,10 +269,9 @@ def _olmo_1b_step(devices, dp, tp):
 def _as_on_the_chip(monkeypatch):
     """`jax.default_backend()` is still the CPU here: steer the two places
     that ask it, so the step holds the compiled kernels as on the chip."""
-    from kubeflow_tpu.models import transformer
-    from kubeflow_tpu.ops import flash
+    from kubeflow_tpu.ops import attention, flash
 
-    monkeypatch.setattr(transformer, "kernels_compiled", lambda: True)
+    monkeypatch.setattr(attention, "kernels_compiled", lambda: True)
     monkeypatch.setattr(flash, "kernels_compiled", lambda: True)
 
 

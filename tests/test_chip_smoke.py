@@ -4,7 +4,7 @@ The script has no CPU mode — run with no TPU it must fail. What can be
 checked here is that every phase's control flow, entry points and checks
 are right before a chip call is spent on them: the tests pass small
 dims and `interpret=True` themselves, and steer the one dispatch that
-asks for the backend (`_attend`'s ring branch) in the test.
+asks for the backend (`attend`'s ring branch) in the test.
 """
 
 import json
@@ -108,13 +108,13 @@ def test_serve_phase_json_and_binary_match_module_apply():
 def test_four_chip_phases_on_virtual_devices(devices, monkeypatch):
     """The `--chips 4` phases at tiny size on the 8 virtual CPU devices:
     meshes, sharding rules and the parity and placement checks."""
-    import kubeflow_tpu.models.transformer as transformer
+    import kubeflow_tpu.ops.attention as attention
     from kubeflow_tpu.models.resnet import tiny_resnet
 
-    # `_attend` takes the ring-FLASH branch only where kernels compile;
+    # `attend` takes the ring-FLASH branch only where kernels compile;
     # steer it here so the sp case runs the kernels (interpreted) and
     # not the dense-hop ring.
-    monkeypatch.setattr(transformer, "kernels_compiled", lambda: True)
+    monkeypatch.setattr(attention, "kernels_compiled", lambda: True)
     rows = chip_smoke.phase_sharded_train(
         model=TINY_LM,
         cases=(("dp2_tp2", dict(dp=2, tp=2), 64, 4),

@@ -165,15 +165,15 @@ def test_fused_flash_bwd_shared_delta_and_single_kv_pass():
     # lse): the byte ratio only means something there.
     fused = flash.flash_schedule(16384, 16384)
     assert fused["bwd_fused"], fused
-    assert fused["bwd_total_grid_steps"] == fused["bwd_grid_steps"], (
+    assert fused["bwd_total_grid_steps"] == fused["grid_steps"], (
         "fused backward no longer single-KV-pass: "
         f"{fused['bwd_total_grid_steps']} total vs "
-        f"{fused['bwd_grid_steps']} per pass"
+        f"{fused['grid_steps']} per pass"
     )
     two_pass = flash.flash_schedule(16384, 16384, causal=False)
     assert not two_pass["bwd_fused"]
     assert (
-        two_pass["bwd_total_grid_steps"] == 2 * two_pass["bwd_grid_steps"]
+        two_pass["bwd_total_grid_steps"] == 2 * two_pass["grid_steps"]
     )
     assert (
         fused["bwd_hbm_bytes_fused"]

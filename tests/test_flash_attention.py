@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 
 from kubeflow_tpu.ops.attention import dense_attention
-from kubeflow_tpu.ops.flash import flash_attention, flash_usable
+from kubeflow_tpu.ops.flash import flash_attention
 
 
 def _qkv(key, b, s, h, d, dtype=jnp.float32):
@@ -83,28 +83,14 @@ def test_indivisible_seq_pads_and_matches_dense():
     # divisor is odd) used to raise; it now pads internally to the next
     # lane multiple, masks the tail, and matches dense numerics.
     q, k, v = _qkv(jax.random.PRNGKey(4), 1, 1025, 1, 16)
-    assert flash_usable(1025, 1025)
     out = flash_attention(q, k, v, interpret=True)
     ref = dense_attention(q, k, v, causal=True)
     np.testing.assert_allclose(out, ref, atol=2e-2, rtol=2e-2)
 
 
-def test_usable_predicate():
-    # Internal padding makes every positive shape flash-runnable; the
-    # predicate stays as the dispatch contract for _attend.
-    assert flash_usable(256, 256)
-    assert flash_usable(4096, 4096)
-    assert flash_usable(64, 64)  # block clamps to seq (8-aligned)
-    assert flash_usable(320, 256)  # clamps to one 320-row block
-    assert flash_usable(1664, 1664)  # degrades to the 128-divisor
-    assert flash_usable(1344, 1344)  # degrades to the sublane divisor 672
-    # Shapes with no 8-aligned divisor now pad instead of routing to
-    # dense — the old silent O(S²) fallback for ragged lengths.
-    assert flash_usable(100, 100)
-    assert flash_usable(321, 321)
-    assert flash_usable(1025, 1025)
-    # The ring path cannot pad (chunks must stay congruent across
-    # hops); its stricter predicate keeps the old semantics.
+def test_ring_chunks_must_tile_without_padding():
+    # `flash_attention` pads a length with no 8-aligned divisor; the ring
+    # path cannot (chunks must stay congruent across hops), so it asks.
     from kubeflow_tpu.ops.flash import flash_kernel_tileable
 
     assert flash_kernel_tileable(256)

@@ -261,7 +261,7 @@ def test_fused_bwd_engages_and_matches_dense(s, block, packed):
     )
     assert sched["bwd_fused"], sched
     assert sched["lse_packed"] == packed
-    assert sched["bwd_total_grid_steps"] == sched["bwd_grid_steps"]
+    assert sched["bwd_total_grid_steps"] == sched["grid_steps"]
 
     q, k, v = _qkv(jax.random.PRNGKey(10), 2, s, 2, 32)
     attn = lambda q, k, v: flash_attention(
@@ -314,7 +314,7 @@ def test_noncausal_and_uneven_blocks_stay_two_pass():
     sched = flash_schedule(256, 256, causal=False, head_dim=16,
                            dtype_bytes=4)
     assert not sched["bwd_fused"]
-    assert sched["bwd_total_grid_steps"] == 2 * sched["bwd_grid_steps"]
+    assert sched["bwd_total_grid_steps"] == 2 * sched["grid_steps"]
     assert not flash_schedule(
         256, 256, block_q=64, block_k=128, causal=True
     )["bwd_fused"]
@@ -338,9 +338,8 @@ def test_noncausal_and_uneven_blocks_stay_two_pass():
 
 def test_fused_vmem_budget_gates_engagement(monkeypatch):
     """The dq ring costs S·d·4 bytes of VMEM, so fusion must fall back
-    past the budget (32k × d=128 is a 16 MiB ring on a ~16 MiB core)
-    — and the KFTPU_FLASH_FUSED_BWD=0 escape hatch pins two-pass
-    everywhere."""
+    past the budget (32k × d=128 is a 16 MiB ring on a ~16 MiB core);
+    the budget is all that decides on the compact grid."""
     assert flash_schedule(16384, 16384)["bwd_fused"]
     big = flash_schedule(32768, 32768)
     assert big["compact"] and not big["bwd_fused"]
@@ -359,7 +358,7 @@ def test_fused_vmem_budget_gates_engagement(monkeypatch):
             True, 1024, 1024, True, None, True, True,
         )
 
-    monkeypatch.setenv("KFTPU_FLASH_FUSED_BWD", "0")
+    monkeypatch.setattr(flash, "_FUSED_VMEM_BUDGET", 0)
     assert not flash_schedule(16384, 16384)["bwd_fused"]
     assert not _bwd_fused(True, 16384, 16384, 1024, 1024, 128, 2, True)
 
@@ -393,8 +392,6 @@ def test_fused_under_remat_flash_policy_never_reruns_fwd():
     have changed the residual set. Asserted from the grad jaxpr: the
     checkpointed grad traces the forward kernel exactly as often as the
     un-checkpointed grad, and runs the fused backward."""
-    from kubeflow_tpu.models.transformer import checkpoint_policy
-
     s, block = 256, 128
     q, k, v = _qkv(jax.random.PRNGKey(13), 1, s, 2, 32)
 
@@ -408,7 +405,10 @@ def test_fused_under_remat_flash_policy_never_reruns_fwd():
         return jnp.sum(attn(q, k, v).astype(jnp.float32) ** 2)
 
     loss_ckpt = jax.checkpoint(
-        loss_plain, policy=checkpoint_policy("flash")
+        loss_plain,
+        policy=jax.checkpoint_policies.save_only_these_names(
+            flash.CHECKPOINT_OUT_NAME, flash.CHECKPOINT_LSE_NAME
+        ),
     )
     grads = lambda f: jax.grad(f, argnums=(0, 1, 2))
     plain = pallas_kernel_names(grads(loss_plain), q, k, v)
@@ -573,12 +573,13 @@ def test_diagonal_and_interior_bodies_match_dense(
 ):
     """Two diagonal steps and one below the diagonal a grid row, with
     the diagonal blocks cut into 1, 2 and 4 bands, equal and grouped
-    heads, through the fused backward and (KFTPU_FLASH_FUSED_BWD=0) the
-    two-pass kernels: forward and all three gradients against dense
+    heads, through the fused backward and (with no VMEM budget for it)
+    the two-pass kernels: forward and all three gradients against dense
     attention, and only the static mask is ever built. The rule gives
     1 and 2 bands; 4, which the chip timed no faster, is put in its
     place here so the plan stays held for any number."""
-    monkeypatch.setenv("KFTPU_FLASH_FUSED_BWD", "0" if two_pass else "1")
+    if two_pass:
+        monkeypatch.setattr(flash, "_FUSED_VMEM_BUDGET", 0)
     if bands == 4:
         assert _diag_bands(block) == 2
         monkeypatch.setattr(flash, "_DIAG_BANDS", (4, 2, 1))
@@ -668,14 +669,13 @@ def test_schedule_counts_the_steps_on_and_below_the_diagonal(
     whole grid, and computes `pairs` times the pairs attention needs
     where whole blocks computed `pairs_whole_blocks` times."""
     sched = flash_schedule(s, s)
-    for prefix in ("", "bwd_"):
-        assert sched[prefix + "diag_steps"] == diag
-        assert sched[prefix + "interior_steps"] == interior
-        assert sched[prefix + "diag_tile"] == 512
-        assert diag + interior == sched[prefix + "grid_steps"]
-        assert sched[prefix + "computed_pairs_over_needed"] == pytest.approx(
-            pairs, abs=1e-3
-        )
+    assert sched["diag_steps"] == diag
+    assert sched["interior_steps"] == interior
+    assert sched["diag_tile"] == 512
+    assert diag + interior == sched["grid_steps"]
+    assert sched["computed_pairs_over_needed"] == pytest.approx(
+        pairs, abs=1e-3
+    )
     exact = (interior * 1024**2 + diag * 3 * 512**2) / (s * (s + 1) // 2)
     assert sched["computed_pairs_over_needed"] == exact
     monkeypatch.setattr(flash, "_bands", lambda compact, bq, kv_len: 0)
@@ -690,7 +690,7 @@ def test_schedule_pairs_where_the_bodies_do_not_engage():
     """Non-causal: every pair is needed and computed. Uneven blocks: the
     causal rectangle computes the blocks it does not predicate off. A
     padded length: the compact grid over the padded blocks, against the
-    pairs of the true length. The backward's figures follow its blocks."""
+    pairs of the true length."""
     assert flash_schedule(512, 512, causal=False)[
         "computed_pairs_over_needed"
     ] == 1.0
@@ -705,7 +705,3 @@ def test_schedule_pairs_where_the_bodies_do_not_engage():
     assert ragged["computed_pairs_over_needed"] == (
         3 * 1024**2 / (2001 * 2002 // 2)
     )
-    split = flash_schedule(2048, 2048, bwd_block_q=512, bwd_block_k=512)
-    assert (split["diag_steps"], split["bwd_diag_steps"]) == (2, 4)
-    assert (split["diag_tile"], split["bwd_diag_tile"]) == (512, 256)
-    assert split["bwd_interior_steps"] == 6

@@ -258,7 +258,7 @@ def test_pipelined_transformer_matches_flat():
 
     cfg = TransformerConfig(
         vocab_size=64, d_model=16, n_layers=4, n_heads=2, head_dim=8,
-        d_ff=32, remat=False, dtype=jnp.float32, attention_impl="dense",
+        d_ff=32, remat_policy="none", dtype=jnp.float32, attention_impl="dense",
     )
     mesh = build_mesh(MeshSpec(dp=2, pp=2), jax.devices()[:4])
     tokens = jax.random.randint(jax.random.PRNGKey(0), (4, 8), 0, 64)
@@ -302,7 +302,7 @@ def test_pipelined_transformer_trains():
 
     cfg = TransformerConfig(
         vocab_size=32, d_model=16, n_layers=2, n_heads=2, head_dim=8,
-        d_ff=32, remat=False, dtype=jnp.float32, attention_impl="dense",
+        d_ff=32, remat_policy="none", dtype=jnp.float32, attention_impl="dense",
     )
     mesh = build_mesh(MeshSpec(dp=2, pp=2), jax.devices()[:4])
     model = PipelinedTransformerLM(cfg, n_stages=2, num_microbatches=2,
@@ -335,7 +335,7 @@ def test_pipelined_transformer_validation():
     )
 
     cfg = TransformerConfig(vocab_size=16, d_model=8, n_layers=3,
-                            n_heads=1, head_dim=8, d_ff=16, remat=False)
+                            n_heads=1, head_dim=8, d_ff=16, remat_policy="none")
     tokens = jnp.zeros((4, 4), jnp.int32)
     with pytest.raises(ValueError, match="stages"):
         PipelinedTransformerLM(cfg, n_stages=2, num_microbatches=2).init(
@@ -360,7 +360,7 @@ def test_pipeline_composes_with_tp_and_fsdp():
 
     cfg = TransformerConfig(
         vocab_size=32, d_model=16, n_layers=2, n_heads=2, head_dim=8,
-        d_ff=32, remat=False, dtype=jnp.float32, attention_impl="dense",
+        d_ff=32, remat_policy="none", dtype=jnp.float32, attention_impl="dense",
     )
     mesh = build_mesh(MeshSpec(fsdp=2, pp=2, tp=2), jax.devices()[:8])
     model = PipelinedTransformerLM(cfg, n_stages=2, num_microbatches=2,
@@ -398,7 +398,7 @@ def _tiny_lm_cfg(**kw):
 
     base = dict(
         vocab_size=64, d_model=16, n_layers=4, n_heads=2, head_dim=8,
-        d_ff=32, remat=False, dtype=jnp.float32, attention_impl="dense",
+        d_ff=32, remat_policy="none", dtype=jnp.float32, attention_impl="dense",
     )
     base.update(kw)
     return TransformerConfig(**base)

@@ -14,7 +14,7 @@ import pytest
 from benchmarks.drivers import train_moe
 from benchmarks.reference import zaya
 from kubeflow_tpu.models.transformer import Block, TransformerLM
-from kubeflow_tpu.ops import moe
+from kubeflow_tpu.ops import flash as flash_kernels, moe
 from kubeflow_tpu.ops.attention import dense_attention
 from kubeflow_tpu.ops.flash import flash_attention
 from kubeflow_tpu.parallel import MeshSpec, build_mesh
@@ -32,7 +32,8 @@ B, S = 2, 32
 
 
 def _config(numbers=NUMBERS, **how):
-    how = {"dtype": jnp.float32, "attention_impl": "dense", "remat": False, **how}
+    how = {"dtype": jnp.float32, "attention_impl": "dense", "remat_policy": "none",
+           **how}
     return train_moe.transformer_config(numbers, **how)
 
 
@@ -80,7 +81,7 @@ def test_the_kernel_paths_and_remat_policies_give_the_dense_logits(
     _, flat, tokens, labels = seeded
     params = train_moe.to_program_tree(flat)
     want = jax.grad(_program_loss(_config()))(params, tokens, labels)
-    cfg = _config(attention_impl=impl, remat=True, remat_policy=remat)
+    cfg = _config(attention_impl=impl, remat_policy=remat)
     got = jax.grad(_program_loss(cfg))(params, tokens, labels)
     for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
         np.testing.assert_allclose(a, b, atol=2e-6, rtol=2e-4)
@@ -281,17 +282,18 @@ def test_a_forced_selection_is_even_whatever_the_weights_and_matches_the_referen
     assert float(jnp.abs(ref_flat["layers.0.router_out"]).max()) > 0
 
 
-@pytest.mark.parametrize("seq,bq,bk,fused", [
-    (256, 128, 128, "1"),   # compact grid, fused backward
-    (384, 128, 128, "0"),   # compact grid, two-pass backward
-    (256, 128, 64, "1"),    # rectangular grid
-    (200, 128, 128, "1"),   # padded inside the wrapper
+@pytest.mark.parametrize("seq,bq,bk,two_pass", [
+    (256, 128, 128, False),  # compact grid, fused backward
+    (384, 128, 128, True),   # compact grid, two-pass backward
+    (256, 128, 64, False),   # rectangular grid
+    (200, 128, 128, False),  # padded inside the wrapper
 ])
-def test_grouped_head_flash_matches_dense(monkeypatch, seq, bq, bk, fused):
+def test_grouped_head_flash_matches_dense(monkeypatch, seq, bq, bk, two_pass):
     """4 query heads over 2 K/V heads through the kernels' index maps
     (interpreted), forward and backward, against dense attention over
     repeated K and V."""
-    monkeypatch.setenv("KFTPU_FLASH_FUSED_BWD", fused)
+    if two_pass:  # what the backward runs past the fused kernel's VMEM
+        monkeypatch.setattr(flash_kernels, "_FUSED_VMEM_BUDGET", 0)
     ks = jax.random.split(jax.random.PRNGKey(seq), 4)
     q = jax.random.normal(ks[0], (2, seq, 4, 8))
     k = jax.random.normal(ks[1], (2, seq, 2, 8))
