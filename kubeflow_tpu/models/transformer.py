@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any
 
 import flax.linen as nn
@@ -30,6 +31,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from kubeflow_tpu.ops.attention import attend
 from kubeflow_tpu.ops.flash import CHECKPOINT_LSE_NAME, CHECKPOINT_OUT_NAME
 from kubeflow_tpu.ops.moe import expert_mlp_on_mesh
+from kubeflow_tpu.ops.rope import rope
 from kubeflow_tpu.parallel.sharding import batch_axes
 
 
@@ -119,16 +121,32 @@ def _block_cls(cfg: "TransformerConfig"):
     )
 
 
-def _dense(features, names, name=None, dtype=jnp.bfloat16):
+def _dot_folded(x, kernel, dimension_numbers, precision=None):
+    """`DenseGeneral`'s contraction as one plain matmul: the contracted
+    axes (x's last, the kernel's first) folded into one and the kernel's
+    feature axes into one. A projection onto heads, [E] x [E, H, D],
+    leaves as [..., H·D], and one from heads, [H, D] x [H, D, E], reads
+    its [..., H, D] argument as [..., H·D]: the kernels keep the shapes
+    (and the sharding names) they are stored under, and no
+    [B, S, H, D] array is formed on the way to or from the attention
+    kernels, which read [B, S, H·D] (`ops/flash.py`)."""
+    n = len(dimension_numbers[0][1])
+    kernel = kernel.reshape(math.prod(kernel.shape[:n]), -1)
+    x = x.reshape(*x.shape[: x.ndim - n], kernel.shape[0])
+    return jnp.dot(x, kernel, precision=precision)
+
+
+def _dense(features, names, name=None, dtype=jnp.bfloat16, axis=-1):
     return nn.DenseGeneral(
         features,
-        axis=-1,
+        axis=axis,
         use_bias=False,
         dtype=dtype,
         param_dtype=jnp.float32,
         kernel_init=nn.with_logical_partitioning(
             nn.initializers.variance_scaling(1.0, "fan_in", "normal"), names
         ),
+        dot_general=_dot_folded,
         name=name,
     )
 
@@ -175,25 +193,6 @@ class RMSNorm(nn.Module):
             jnp.float32,
         )
         return rms_norm(x, scale, dtype=self.dtype, eps=self.eps)
-
-
-def rope(x, positions, theta: float, fraction: float = 1.0):
-    """Rotary embeddings. x: [B, S, H, D], positions: [B, S]. With
-    `fraction` < 1 only the first `fraction * D` dims of a head turn."""
-    turned = int(x.shape[-1] * fraction)
-    if turned != x.shape[-1]:
-        return jnp.concatenate(
-            [rope(x[..., :turned], positions, theta), x[..., turned:]],
-            axis=-1,
-        )
-    d = x.shape[-1]
-    freqs = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    angles = positions[..., None].astype(jnp.float32) * freqs  # [B, S, D/2]
-    cos = jnp.cos(angles)[:, :, None, :]
-    sin = jnp.sin(angles)[:, :, None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
-    return out.astype(x.dtype)
 
 
 def _shift(x, steps: int):
@@ -285,29 +284,31 @@ class Attention(nn.Module):
         cfg = self.config
         h, d = cfg.n_heads, cfg.head_dim
         hk = cfg.n_kv_heads or h
+        # q, k and v stay [B, S, H·d] from the projections' matmuls to the
+        # attention kernels (`_dot_folded`); only CCA's mixing splits the
+        # heads out, and `attend` takes them as a reshape.
         q = _dense((h, d), ("embed", "heads", "kv"), "wq", cfg.dtype)(x)
         k = _dense((hk, d), ("embed", "heads", "kv"), "wk", cfg.dtype)(x)
         v = _dense((hk, d), ("embed", "heads", "kv"), "wv", cfg.dtype)(x)
+        heads = lambda u: u.reshape(*u.shape[:2], -1, d)
         if cfg.cca:
             with jax.named_scope("cca.mix"):
-                q, k, v = self._cca_mix(q, k, v)
-        q = rope(q, positions, cfg.rope_theta, cfg.rope_fraction)
-        k = rope(k, positions, cfg.rope_theta, cfg.rope_fraction)
+                q, k, v = self._cca_mix(heads(q), heads(k), heads(v))
+            q, k, v = (u.reshape(*u.shape[:2], -1) for u in (q, k, v))
+        turn = functools.partial(
+            rope, positions=positions, theta=cfg.rope_theta,
+            fraction=cfg.rope_fraction, head_dim=d, mesh=self.mesh,
+        )
+        q, k = turn(q), turn(k)
         with jax.named_scope("cca.attend" if cfg.cca else "attend"):
-            out = attend(q, k, v, mesh=self.mesh, impl=cfg.attention_impl)
-        out = nn.DenseGeneral(
-            cfg.d_model,
+            out = attend(
+                heads(q), heads(k), heads(v), mesh=self.mesh,
+                impl=cfg.attention_impl,
+            )
+        return _dense(
+            cfg.d_model, ("heads", "kv", "embed"), "wo", cfg.dtype,
             axis=(-2, -1),
-            use_bias=False,
-            dtype=cfg.dtype,
-            param_dtype=jnp.float32,
-            kernel_init=nn.with_logical_partitioning(
-                nn.initializers.variance_scaling(1.0, "fan_in", "normal"),
-                ("heads", "kv", "embed"),
-            ),
-            name="wo",
         )(out)
-        return out
 
 
 class SwiGLU(nn.Module):

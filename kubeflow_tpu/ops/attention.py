@@ -214,12 +214,24 @@ def attend(q, k, v, *, mesh: Mesh | None, impl: str):
         return dense_attention(q, repeat(k), repeat(v), causal=True)
     if mesh is None:
         return flash_attention(q, k, v, causal=True)
-    heads = "tp" if mesh.shape.get("tp", 1) > 1 else None
-    spec = P(batch_axes(mesh), None, heads, None)
+    # The shards' boundary is crossed with the heads folded into the last
+    # axis, [B, S, H·D], as the projections write q, k and v and as the
+    # kernels read them (`ops/flash.py`): a [B, S, H, D] array there is
+    # given a layout of its own on the TPU and costs a relayout each way.
+    d = q.shape[-1]
+    fold = lambda x: x.reshape(*x.shape[:2], -1)
+
+    def shard(q, k, v):
+        heads = lambda x: x.reshape(*x.shape[:2], -1, d)
+        return fold(flash_attention(heads(q), heads(k), heads(v), causal=True))
+
+    spec = P(
+        batch_axes(mesh), None, "tp" if mesh.shape.get("tp", 1) > 1 else None
+    )
     return jax.shard_map(
-        functools.partial(flash_attention, causal=True),
+        shard,
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
         check_vma=False,
-    )(q, k, v)
+    )(fold(q), fold(k), fold(v)).reshape(q.shape)

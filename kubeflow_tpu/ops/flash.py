@@ -72,6 +72,16 @@ on top:
   masked in-kernel (`kv_len`) and sliced off the output, so ragged
   lengths run the kernel instead of silently falling back to the dense
   O(S²) path.
+- **The projections' layout.** Where the head size is whole lanes
+  (d % 128 == 0: `_head_layout`, `flash_schedule`'s `layout`) the
+  kernels read q, k, v, dO and write o, dq, dk, dv as [B, S, H·d], the
+  arrays the projections' matmuls write and read: a head is a column
+  block of 128·k lanes, picked by the block specs' index maps (`_specs`),
+  and `flash_attention` traces no transpose. Any other d folds the heads
+  into the batch ([B·H, S, d]) by the four transposes in and four out
+  that every call used to pay (10.7 ms of a 395 ms step at 16 heads,
+  6 layers; PERF.md §6, PR 31). One spec builder, one set of bodies,
+  bit-identical results.
 - grid steps run sequentially on TPU, so the running max / normalizer /
   output accumulator live in VMEM scratch and carry across k-steps —
   HBM traffic is O(S·d), never O(S²); Q/K/V blocks stream HBM→VMEM via
@@ -499,6 +509,7 @@ def flash_schedule(
     bwd_bytes = lambda f: _bwd_hbm_bytes(
         causal, sp_q, sp_k, bq, bk, head_dim, dtype_bytes, packed, f
     )
+    layout = _head_layout(head_dim)
     return {
         "padded_seq_q": sp_q,
         "padded_seq_k": sp_k,
@@ -526,6 +537,11 @@ def flash_schedule(
         "lse_replicated_bytes": sp_q * _LANES * 4,
         # The diagonal (see `_step_tiles`), in forward and backward alike.
         **_diag_accounting(causal, seq_q, seq_k, sp_q, sp_k, bq, bk),
+        # Where the kernels read a head (`_specs`), and the head-major
+        # transposes round them: q, k, v, o forward, dO, dq, dk, dv
+        # backward, or none.
+        "layout": layout,
+        "transposes_per_call": 0 if layout == "seq_major" else 8,
     }
 
 
@@ -1011,64 +1027,169 @@ def _clamp_i(i, j, bq: int, bk: int, causal: bool):
     return jnp.maximum(i, (j * bk) // bq)
 
 
+# -- block specs: where a head's blocks lie ----------------------------------
+#
+# A kernel's grid rows are (batch·head), head-minor, and every block is
+# (1, blk, d). What a row indexes is an array [rows / heads, S, heads·d]:
+#
+#   seq_major   heads = H: q, o, dO, dq are [B, S, H·d] and k, v, dk, dv
+#               [B, S, Hkv·d], the arrays the projections write (a
+#               reshape of [B, S, H, d], no copy). Grid row g = b·H + h
+#               addresses column block h of batch row b, fetched by a
+#               strided DMA: blk pieces of d lanes, H·d apart. Legal on
+#               the chip where d is whole lanes (`_head_layout`).
+#   head_major  heads = 1: the heads are folded into the batch,
+#               [B·H, S, d], by a transpose outside the kernels. The same
+#               builder at heads = 1: what any other d runs, and the ring
+#               path, whose rotating chunks are flat already.
+#
+# The block is the same bytes in VMEM either way, so the kernel bodies,
+# grids, tables, scratch and the VMEM model know nothing of the layout.
+# The lse and delta arrays are the kernels' own residuals, which nobody
+# transposes: always [B·H, ...], row g.
+#
+# What the strided fetch costs: a (1024, 128) bf16 block out of
+# [S, H·128] is 64 tiles of 4 KB, H tiles apart, where head_major's is
+# one run of 256 KB. One layer's kernels alone on the v5e, the same
+# build in both layouts, ms a call head_major / seq_major (my chip run,
+# PR 31, 2026-09-28; bf16, d = 128, 1024-blocks, min of 4 rounds of 25;
+# head_major equals the parent's to 0.3 %):
+#   (B, S, H) = (8, 2048, 16)   forward 1.841 / 1.853, fused backward
+#                               2.756 / 2.760, delta 0.240 / 0.261
+#   (2, 8192, 16)               forward 5.023 / 5.068, backward 8.857 /
+#                               8.890, delta 0.243 / 0.263
+#   (2, 8192, 8 over 2 K/V)     forward 2.538 / 2.563, backward with the
+#                               group's sum 4.440 / 4.530
+#   (4, 2048, 8)                forward 0.487 / 0.488, backward 0.717 /
+#                               0.720
+# 0.1-0.9 % with equal heads, 2 % with grouped heads: the kernels are
+# bound by their matmuls, not by how a block arrives.
+
+
+def _head_layout(d: int) -> str:
+    """The layout `flash_attention` hands the kernels, from the head size
+    alone: a head is a legal column block of [B, S, H·d] when d is a
+    whole number of lanes."""
+    return "seq_major" if d % _LANES == 0 else "head_major"
+
+
 def _kv_row(group: int):
     """Grid row of q (batch·head, head-minor) -> row of k/v. With grouped
     K/V heads `group` query heads share one: q row b·H + h reads k/v row
     b·H/group + h // group = (b·H + h) // group, so the kernels never see
     a repeated copy of K or V. Equal heads keep the identity map."""
     if group == 1:
-        return lambda b: b
-    return lambda b: b // group
+        return lambda g: g
+    return lambda g: g // group
 
 
-def _sum_groups(dk, group: int, dtype):
-    """Per-query-head dK or dV [B·H, S, d] -> per-kv-head [B·H/group, S, d]:
-    the kernels write one partial a query head, summed here in float32."""
-    if group == 1:
+def _head_block(heads: int):
+    """(row, idx) -> index of block idx along the sequence of head-row
+    `row`, in an array [rows / heads, S, heads·d] of (1, blk, d) blocks."""
+    if heads == 1:
+        return lambda row, idx: (row, idx, 0)
+    return lambda row, idx: (row // heads, idx, row % heads)
+
+
+def _head_counts(q, k, heads: int):
+    """(d, grid rows, kv heads in a row of k, query heads a kv head) of
+    q [Bq, S, heads·d] over k [Bk, S, kv_heads·d]."""
+    d = q.shape[2] // heads
+    rows = q.shape[0] * heads
+    kv_heads = k.shape[2] // d
+    return d, rows, kv_heads, rows // (k.shape[0] * kv_heads)
+
+
+def _specs(heads: int, kv_heads: int, group: int, d: int):
+    """The one builder of the kernels' block specs: (q_spec, kv_spec,
+    stat_spec). Each takes the block's rows (for the lse / delta
+    statistics the block's whole shape) and `idx`, the map from the
+    grid's indices after the leading row (and any prefetched tables) to
+    the block's number along the sequence."""
+    q_at, kv_at = _head_block(heads), _head_block(kv_heads)
+    kv_row = _kv_row(group)
+
+    def q_spec(blk, idx):
+        return pl.BlockSpec((1, blk, d), lambda g, *a: q_at(g, idx(*a)))
+
+    def kv_spec(blk, idx):
+        return pl.BlockSpec(
+            (1, blk, d), lambda g, *a: kv_at(kv_row(g), idx(*a))
+        )
+
+    def stat_spec(block, idx):
+        return pl.BlockSpec(block, lambda g, *a: (g, idx(*a), 0))
+
+    return q_spec, kv_spec, stat_spec
+
+
+def _sum_groups(dk, like, d: int):
+    """Per-query-head dK or dV, as the kernels write them in q's layout
+    [Bq, S, heads·d], -> per-kv-head in `like`'s [Bk, S, kv_heads·d],
+    added in float32 in the group's order. A group's partials are
+    adjacent rows (head_major: a split of the leading axis) or adjacent
+    column blocks (seq_major), taken as whole-lane slices: splitting the
+    lanes into [.., kv_heads, group, d] would be a relayout of all of dK."""
+    if dk.shape == like.shape:
         return dk
-    bh, sk, d = dk.shape
-    return dk.reshape(bh // group, group, sk, d).astype(jnp.float32).sum(
-        axis=1
-    ).astype(dtype)
-
-
-def _qkv_specs(bq: int, bk: int, d: int, causal: bool, group: int = 1):
-    row = _kv_row(group)
-    kv = lambda b, i, j: (row(b), _clamp_j(i, j, bq, bk, causal), 0)
-    return [
-        pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-        pl.BlockSpec((1, bk, d), kv),
-        pl.BlockSpec((1, bk, d), kv),
-    ]
+    (bq, s, w), (bk, _, wk) = dk.shape, like.shape
+    group = (bq // bk) * (w // wk)
+    if w == wk:
+        rows = dk.reshape(bk, group, s, w)
+        partials = [[rows[:, g] for g in range(group)]]
+    else:
+        partials = [
+            [
+                lax.slice_in_dim(dk, i * d, (i + 1) * d, axis=2)
+                for i in range(j * group, (j + 1) * group)
+            ]
+            for j in range(wk // d)
+        ]
+    return jnp.concatenate(
+        [
+            functools.reduce(
+                jnp.add, (x.astype(jnp.float32) for x in head)
+            ).astype(like.dtype)
+            for head in partials
+        ],
+        axis=-1,
+    )
 
 
 # -- pallas_call wrappers ----------------------------------------------------
 
 
+_T_ROW = lambda t, rows, cols: rows[t]  # compact grids: step t's q block
+_T_COL = lambda t, rows, cols: cols[t]  # ... and its k block
+
+
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "causal", "block_q", "block_k", "interpret", "kv_len", "packed"
+        "causal", "block_q", "block_k", "interpret", "kv_len", "packed",
+        "heads",
     ),
 )
 def _flash_fwd_impl(
-    q, k, v, causal, block_q, block_k, interpret, kv_len=None, packed=False
+    q, k, v, causal, block_q, block_k, interpret, kv_len=None, packed=False,
+    heads=1,
 ):
-    bh, sq, d = q.shape
-    sk = k.shape[1]
+    """q [Bq, S, heads·d] over k, v [Bk, S, kv_heads·d] (`_specs`) ->
+    (o in q's layout, lse [Bq·heads, ...] in the kernel lse layout)."""
+    d, bh, kv_heads, group = _head_counts(q, k, heads)
+    sq, sk = q.shape[1], k.shape[1]
     bq = _pick_block(block_q, sq)
     bk = _pick_block(block_k, sk)
     scale = 1.0 / math.sqrt(d)
     steps, _, compact = _grid_steps(causal, sq, sk, bq, bk)
     nq = sq // bq
-    group = bh // k.shape[0]
-    row = _kv_row(group)
+    q_spec, kv_spec, stat_spec = _specs(heads, kv_heads, group, d)
     kernel_kw = dict(
         scale=scale, causal=causal, bq=bq, bk=bk, kv_len=kv_len,
         packed=packed,
     )
     out_shape = [
-        jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
+        jax.ShapeDtypeStruct(q.shape, q.dtype),
         jax.ShapeDtypeStruct(_lse_layout_shape(bh, sq, packed), jnp.float32),
     ]
     scratch = [
@@ -1078,7 +1199,7 @@ def _flash_fwd_impl(
     ]
     cost = pl.CostEstimate(
         flops=4 * bh * steps * bq * bk * d,
-        bytes_accessed=(bh * sq + 2 * k.shape[0] * sk) * d * q.dtype.itemsize,
+        bytes_accessed=(q.size + 2 * k.size) * q.dtype.itemsize,
         transcendentals=bh * steps * bq * bk,
     )
     if compact:
@@ -1087,20 +1208,11 @@ def _flash_fwd_impl(
             num_scalar_prefetch=2,
             grid=(bh, steps),
             in_specs=[
-                pl.BlockSpec((1, bq, d), lambda b, t, rs, cs: (b, rs[t], 0)),
-                pl.BlockSpec(
-                    (1, bk, d), lambda b, t, rs, cs: (row(b), cs[t], 0)
-                ),
-                pl.BlockSpec(
-                    (1, bk, d), lambda b, t, rs, cs: (row(b), cs[t], 0)
-                ),
+                q_spec(bq, _T_ROW), kv_spec(bk, _T_COL), kv_spec(bk, _T_COL)
             ],
             out_specs=[
-                pl.BlockSpec((1, bq, d), lambda b, t, rs, cs: (b, rs[t], 0)),
-                pl.BlockSpec(
-                    _lse_block(bq, packed),
-                    lambda b, t, rs, cs: (b, rs[t], 0),
-                ),
+                q_spec(bq, _T_ROW),
+                stat_spec(_lse_block(bq, packed), _T_ROW),
             ],
             scratch_shapes=scratch,
         )
@@ -1112,13 +1224,16 @@ def _flash_fwd_impl(
             interpret=interpret,
             name="flash_fwd_compact",
         )(rows, cols, q, k, v)
+    row_i = lambda i, j: i
+    clamped_j = lambda i, j: _clamp_j(i, j, bq, bk, causal)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, **kernel_kw),
         grid=(bh, nq, sk // bk),
-        in_specs=_qkv_specs(bq, bk, d, causal, group),
+        in_specs=[
+            q_spec(bq, row_i), kv_spec(bk, clamped_j), kv_spec(bk, clamped_j)
+        ],
         out_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec(_lse_block(bq, packed), lambda b, i, j: (b, i, 0)),
+            q_spec(bq, row_i), stat_spec(_lse_block(bq, packed), row_i)
         ],
         out_shape=out_shape,
         scratch_shapes=scratch,
@@ -1129,20 +1244,21 @@ def _flash_fwd_impl(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("block_q", "interpret", "packed")
+    jax.jit, static_argnames=("block_q", "interpret", "packed", "heads")
 )
-def _flash_delta_impl(o, do, block_q, interpret, packed):
-    """The shared-delta precompute: one O(S·d) pass over (o, do)."""
-    bh, sq, d = o.shape
+def _flash_delta_impl(o, do, block_q, interpret, packed, heads=1):
+    """The shared-delta precompute: one O(S·d) pass over (o, do), both in
+    q's layout; delta comes out [Bq·heads, ...] like the lse."""
+    d, bh, _, _ = _head_counts(o, o, heads)
+    sq = o.shape[1]
     bq = _pick_block(block_q, sq)
-    spec = pl.BlockSpec((1, bq, d), lambda b, i: (b, i, 0))
+    q_spec, _, stat_spec = _specs(heads, heads, 1, d)
+    block_i = lambda i: i
     return pl.pallas_call(
         functools.partial(_delta_kernel, packed=packed),
         grid=(bh, sq // bq),
-        in_specs=[spec, spec],
-        out_specs=pl.BlockSpec(
-            _lse_block(bq, packed), lambda b, i: (b, i, 0)
-        ),
+        in_specs=[q_spec(bq, block_i), q_spec(bq, block_i)],
+        out_specs=stat_spec(_lse_block(bq, packed), block_i),
         out_shape=jax.ShapeDtypeStruct(
             _lse_layout_shape(bh, sq, packed), jnp.float32
         ),
@@ -1155,12 +1271,12 @@ def _flash_delta_impl(o, do, block_q, interpret, packed):
     jax.jit,
     static_argnames=(
         "causal", "block_q", "block_k", "interpret", "kv_len", "packed",
-        "fused",
+        "fused", "heads",
     ),
 )
 def _flash_bwd_kernels(
     q, k, v, do, lse, delta, causal, block_q, block_k, interpret,
-    kv_len=None, packed=False, fused=None,
+    kv_len=None, packed=False, fused=None, heads=1,
 ):
     """Backward kernels over a precomputed (lse, delta) pair (both in
     the kernel lse layout): the fused one-pass dq/dkv kernel when
@@ -1168,9 +1284,11 @@ def _flash_bwd_kernels(
     the two-pass dq + dkv kernels. `fused=None` auto-selects via the
     same predicate `flash_schedule` reports; tests pass True/False to
     pin a path (True on a non-compactable or over-budget shape is an
-    error — the fused kernel only exists on the compact grid)."""
-    bh, sq, d = q.shape
-    sk = k.shape[1]
+    error — the fused kernel only exists on the compact grid). q, do and
+    the returned dq are [Bq, S, heads·d]; k, v and the returned dk, dv
+    [Bk, S, kv_heads·d] (`_specs`)."""
+    d, bh, kv_heads, group = _head_counts(q, k, heads)
+    sq, sk = q.shape[1], k.shape[1]
     bq = _pick_block(block_q, sq)
     bk = _pick_block(block_k, sk)
     scale = 1.0 / math.sqrt(d)
@@ -1199,30 +1317,26 @@ def _flash_bwd_kernels(
         scale=scale, causal=causal, bq=bq, bk=bk, kv_len=kv_len,
         packed=packed,
     )
-
-    group = bh // k.shape[0]
-    row = _kv_row(group)
-    # dK and dV leave every kernel as one partial a QUERY head (the
-    # accumulators and output blocks ride the q grid row); the group's
-    # partials are summed after the call.
+    q_spec, kv_spec, stat_spec = _specs(heads, kv_heads, group, d)
+    # dK and dV leave every kernel as one partial a QUERY head, in q's
+    # layout (the accumulators and output blocks ride the q grid row);
+    # the group's partials are summed after the call.
+    dq_shape = jax.ShapeDtypeStruct(q.shape, q.dtype)
+    dkv_shape = [
+        jax.ShapeDtypeStruct(q.shape[:1] + (sk, q.shape[2]), x.dtype)
+        for x in (k, v)
+    ]
     grouped = lambda dq, dk, dv: (
-        dq, _sum_groups(dk, group, k.dtype), _sum_groups(dv, group, v.dtype)
+        dq, _sum_groups(dk, k, d), _sum_groups(dv, v, d)
     )
 
-    def _row_specs(qidx, kidx):
+    def _in_specs(qidx, kidx):
         # q/do/lse/delta ride the q-block index, k/v the k-block index
         # (and, with grouped heads, the kv head of the q grid row).
+        stat = _lse_block(bq, packed)
         return [
-            pl.BlockSpec((1, bq, d), lambda *a: (a[0], qidx(*a[1:]), 0)),
-            pl.BlockSpec((1, bk, d), lambda *a: (row(a[0]), kidx(*a[1:]), 0)),
-            pl.BlockSpec((1, bk, d), lambda *a: (row(a[0]), kidx(*a[1:]), 0)),
-            pl.BlockSpec((1, bq, d), lambda *a: (a[0], qidx(*a[1:]), 0)),
-            pl.BlockSpec(
-                _lse_block(bq, packed), lambda *a: (a[0], qidx(*a[1:]), 0)
-            ),
-            pl.BlockSpec(
-                _lse_block(bq, packed), lambda *a: (a[0], qidx(*a[1:]), 0)
-            ),
+            q_spec(bq, qidx), kv_spec(bk, kidx), kv_spec(bk, kidx),
+            q_spec(bq, qidx), stat_spec(stat, qidx), stat_spec(stat, qidx),
         ]
 
     if fused:
@@ -1244,19 +1358,14 @@ def _flash_bwd_kernels(
             ),
             transcendentals=bh * steps * bq * bk,
         )
-        col_idx = lambda b, t, rs, cs: (b, cs[t], 0)
         dq, dk, dv = pl.pallas_call(
             functools.partial(_dqkv_kernel_fused, nq=nq, **kw),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2,
                 grid=(bh, steps),
-                in_specs=_row_specs(
-                    lambda t, rs, cs: rs[t], lambda t, rs, cs: cs[t]
-                ),
+                in_specs=_in_specs(_T_ROW, _T_COL),
                 out_specs=[
-                    pl.BlockSpec((1, bq, d), col_idx),
-                    pl.BlockSpec((1, bk, d), col_idx),
-                    pl.BlockSpec((1, bk, d), col_idx),
+                    q_spec(bq, _T_COL), q_spec(bk, _T_COL), q_spec(bk, _T_COL)
                 ],
                 scratch_shapes=[
                     pltpu.VMEM((nq * bq, d), jnp.float32),  # dq ring
@@ -1264,11 +1373,7 @@ def _flash_bwd_kernels(
                     pltpu.VMEM((bk, d), jnp.float32),
                 ],
             ),
-            out_shape=[
-                jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
-                jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
-                jax.ShapeDtypeStruct((bh, sk, d), v.dtype),
-            ],
+            out_shape=[dq_shape, *dkv_shape],
             cost_estimate=cost,
             # The one kernel whose footprint grows with S: past the
             # compiler's 16 MiB default from S=8k on, so it names the
@@ -1288,15 +1393,11 @@ def _flash_bwd_kernels(
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2,
                 grid=(bh, steps),
-                in_specs=_row_specs(
-                    lambda t, rs, cs: rs[t], lambda t, rs, cs: cs[t]
-                ),
-                out_specs=pl.BlockSpec(
-                    (1, bq, d), lambda b, t, rs, cs: (b, rs[t], 0)
-                ),
+                in_specs=_in_specs(_T_ROW, _T_COL),
+                out_specs=q_spec(bq, _T_ROW),
                 scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
             ),
-            out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
+            out_shape=dq_shape,
             interpret=interpret,
             name="flash_dq_compact",
         )(rows, cols, q, k, v, do, lse, delta)
@@ -1306,26 +1407,14 @@ def _flash_bwd_kernels(
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2,
                 grid=(bh, steps),
-                in_specs=_row_specs(
-                    lambda t, rs, cs: rs[t], lambda t, rs, cs: cs[t]
-                ),
-                out_specs=[
-                    pl.BlockSpec(
-                        (1, bk, d), lambda b, t, rs, cs: (b, cs[t], 0)
-                    ),
-                    pl.BlockSpec(
-                        (1, bk, d), lambda b, t, rs, cs: (b, cs[t], 0)
-                    ),
-                ],
+                in_specs=_in_specs(_T_ROW, _T_COL),
+                out_specs=[q_spec(bk, _T_COL), q_spec(bk, _T_COL)],
                 scratch_shapes=[
                     pltpu.VMEM((bk, d), jnp.float32),
                     pltpu.VMEM((bk, d), jnp.float32),
                 ],
             ),
-            out_shape=[
-                jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
-                jax.ShapeDtypeStruct((bh, sk, d), v.dtype),
-            ],
+            out_shape=dkv_shape,
             interpret=interpret,
             name="flash_dkv_compact",
         )(rows_c, cols_c, q, k, v, do, lse, delta)
@@ -1334,12 +1423,11 @@ def _flash_bwd_kernels(
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, **kw),
         grid=(bh, nq, nk),
-        in_specs=_row_specs(
-            lambda i, j: i,
-            lambda i, j: _clamp_j(i, j, bq, bk, causal),
+        in_specs=_in_specs(
+            lambda i, j: i, lambda i, j: _clamp_j(i, j, bq, bk, causal)
         ),
-        out_specs=pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
+        out_specs=q_spec(bq, lambda i, j: i),
+        out_shape=dq_shape,
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
         name="flash_dq_rect",
@@ -1348,18 +1436,11 @@ def _flash_bwd_kernels(
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, **kw),
         grid=(bh, nk, nq),
-        in_specs=_row_specs(
-            lambda j, i: _clamp_i(i, j, bq, bk, causal),
-            lambda j, i: j,
+        in_specs=_in_specs(
+            lambda j, i: _clamp_i(i, j, bq, bk, causal), lambda j, i: j
         ),
-        out_specs=[
-            pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, sk, d), v.dtype),
-        ],
+        out_specs=[q_spec(bk, lambda j, i: j), q_spec(bk, lambda j, i: j)],
+        out_shape=dkv_shape,
         scratch_shapes=[
             pltpu.VMEM((bk, d), jnp.float32),
             pltpu.VMEM((bk, d), jnp.float32),
@@ -1372,12 +1453,12 @@ def _flash_bwd_kernels(
 
 def _flash_bwd_impl(
     q, k, v, o, lse, do, causal, block_q, block_k, interpret,
-    kv_len=None, packed=False,
+    kv_len=None, packed=False, heads=1,
 ):
-    delta = _flash_delta_impl(o, do, block_q, interpret, packed)
+    delta = _flash_delta_impl(o, do, block_q, interpret, packed, heads)
     return _flash_bwd_kernels(
         q, k, v, do, lse, delta, causal, block_q, block_k, interpret,
-        kv_len, packed,
+        kv_len, packed, None, heads,
     )
 
 
@@ -1388,26 +1469,24 @@ def _residual_packed(sq: int, block_q: int) -> bool:
     return _lse_is_packed(sq, _pick_block(block_q, sq))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_bhsd(q, k, v, causal, block_q, block_k, interpret, kv_len):
-    """Returns (o, lse). The lse output carries NO cotangent path (its
-    incoming gradient is discarded in the VJP) — it exists so callers
-    and `remat_policy="flash"` can hold the softmax statistics."""
-    packed = _residual_packed(q.shape[1], block_q)
-    o, lse = _flash_fwd_impl(
-        q, k, v, causal, block_q, block_k, interpret, kv_len, packed
-    )
-    if not packed:
-        lse = lse[:, :, :1]
-    o = checkpoint_name(o, CHECKPOINT_OUT_NAME)
-    lse = checkpoint_name(lse, CHECKPOINT_LSE_NAME)
-    return o, lse
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_core(q, k, v, causal, block_q, block_k, interpret, kv_len, heads):
+    """q [Bq, S, heads·d], k, v [Bk, S, kv_heads·d] (`_specs`) ->
+    (o, lse), o in q's layout and lse [Bq·heads, ...]. The lse output
+    carries NO cotangent path (its incoming gradient is discarded in the
+    VJP) — it exists so callers and `remat_policy="flash"` can hold the
+    softmax statistics."""
+    return _flash_vjp_fwd(
+        q, k, v, causal, block_q, block_k, interpret, kv_len, heads
+    )[0]
 
 
-def _flash_vjp_fwd(q, k, v, causal, block_q, block_k, interpret, kv_len):
+def _flash_vjp_fwd(
+    q, k, v, causal, block_q, block_k, interpret, kv_len, heads
+):
     packed = _residual_packed(q.shape[1], block_q)
     o, lse = _flash_fwd_impl(
-        q, k, v, causal, block_q, block_k, interpret, kv_len, packed
+        q, k, v, causal, block_q, block_k, interpret, kv_len, packed, heads
     )
     # Residual slimming: in the packed layout the lse residual is already
     # exactly the information (1/128th the old lane-replicated buffer);
@@ -1423,19 +1502,35 @@ def _flash_vjp_fwd(q, k, v, causal, block_q, block_k, interpret, kv_len):
     return (o, lse), (q, k, v, o, lse)
 
 
-def _flash_vjp_bwd(causal, block_q, block_k, interpret, kv_len, residuals,
-                   cts):
+def _flash_vjp_bwd(causal, block_q, block_k, interpret, kv_len, heads,
+                   residuals, cts):
     q, k, v, o, lse = residuals
     do, _ = cts  # the lse output is statistics-only; its cotangent drops
     packed = _residual_packed(q.shape[1], block_q)
     lse_layout = _rows_to_layout(_lse_rows(lse, q.shape[1]), packed)
     return _flash_bwd_impl(
         q, k, v, o, lse_layout, do, causal, block_q, block_k, interpret,
-        kv_len, packed,
+        kv_len, packed, heads,
     )
 
 
-_flash_bhsd.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
+_flash_core.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
+
+
+def _fold_heads(x, layout: str):
+    """[B, S, H, d] -> the kernels' [B, S, H·d] (a reshape: the array the
+    projection wrote) or [B·H, S, d] (a transpose: a copy)."""
+    b, s, h, d = x.shape
+    if layout == "seq_major":
+        return x.reshape(b, s, h * d)
+    return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+
+
+def _unfold_heads(x, b: int, h: int, layout: str):
+    """The inverse of `_fold_heads`."""
+    if layout == "seq_major":
+        return x.reshape(b, x.shape[1], h, x.shape[2] // h)
+    return x.reshape(b, h, *x.shape[1:]).transpose(0, 2, 1, 3)
 
 
 def flash_attention(
@@ -1459,6 +1554,17 @@ def flash_attention(
     dense path OOMs a 16 GB v5e chip outright; this runs. ``interpret=None``
     autodetects: Pallas interpreter on the CPU backend (tests), compiled
     on anything else.
+
+    **Layout.** Where D is whole lanes (D % 128 == 0: `_head_layout`, reported
+    as `flash_schedule()`'s `layout`) the kernels read q, k, v and dO and
+    write o, dq, dk, dv where the projections' matmuls read and write
+    them: [B, S, H, D] goes in and out as [B, S, H·D], a reshape, and a
+    head is a column block of it. The custom VJP's residuals are then the
+    projections' own arrays. Any other D folds the heads into the batch by
+    a transpose on the way in and on the way out (four forward, four
+    backward: `transposes_per_call`), the same kernels and the same spec
+    builder at one head a row. Same blocks, same order, same bodies:
+    outputs and gradients are bit-identical between the two.
 
     Sequence lengths that don't divide into 8-aligned blocks are padded
     internally to the next lane multiple; the tail is masked in-kernel
@@ -1494,16 +1600,13 @@ def flash_attention(
             x, ((0, 0), (0, s - x.shape[1]), (0, 0), (0, 0))
         )
         q, k, v = pad(q, sp_q), pad(k, sp_k), pad(v, sp_k)
-    # [B, S, H, D] → [B*H, S, D]: head-major layout keeps each grid step's
-    # blocks contiguous in HBM.
-    to_bhsd = lambda x: x.transpose(0, 2, 1, 3).reshape(
-        b * x.shape[2], x.shape[1], d
+    layout = _head_layout(d)
+    o, lse = _flash_core(
+        _fold_heads(q, layout), _fold_heads(k, layout),
+        _fold_heads(v, layout), causal, block_q, block_k, interp, kv_len,
+        h if layout == "seq_major" else 1,
     )
-    o, lse = _flash_bhsd(
-        to_bhsd(q), to_bhsd(k), to_bhsd(v), causal, block_q, block_k,
-        interp, kv_len,
-    )
-    o = o.reshape(b, h, sp_q, d).transpose(0, 2, 1, 3)
+    o = _unfold_heads(o, b, h, layout)
     if sp_q != sq:
         o = o[:, :sq]
     if not return_lse:
@@ -1536,13 +1639,11 @@ def flash_kernel_tileable(seq: int, block: int = 1024) -> bool:
 
 
 def _flat_heads(x):
-    b, s, h, d = x.shape
-    return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+    return _fold_heads(x, "head_major")
 
 
 def _unflat_heads(x, b, h):
-    bh, s, d = x.shape
-    return x.reshape(b, h, s, d).transpose(0, 2, 1, 3)
+    return _unfold_heads(x, b, h, "head_major")
 
 
 def _hop_branches(qf, kf, vf, bq, bk, interpret):

@@ -144,6 +144,58 @@ def test_grouped_head_flash_compiles_at_the_zaya_cell_shape(one_chip):
     assert tpu_kernel_calls(text) == len(names)
 
 
+# (B, S, H, Hkv) of a layer's attention in each cell: `olmo-1b-cut.train-2k`,
+# `-8k`, `zaya1-8b-ep2.train-8k`, and a shard of `olmo-1b.train-2k-dp2tp2`.
+CELL_SHAPES = [
+    (8, 2048, 16, 16), (2, 8192, 16, 16), (2, 8192, 8, 2), (4, 2048, 8, 8)
+]
+
+
+@pytest.mark.parametrize("b,s,h,hkv", CELL_SHAPES)
+def test_seq_major_kernels_compile_at_the_cells_shapes(one_chip, b, s, h, hkv):
+    """At the cells' head size the kernels read [B, S, H·128] (ISSUE 31):
+    a head is a column block, fetched by a strided DMA, and the chip's
+    compiler takes every such block spec, forward and fused backward, on
+    the schedule `flash_schedule` reports."""
+    sched = flash_schedule(s, s, head_dim=HEAD_DIM, dtype_bytes=2)
+    assert sched["layout"] == "seq_major"
+    assert sched["transposes_per_call"] == 0 and sched["bwd_fused"]
+    q = jax.ShapeDtypeStruct((b, s, h, HEAD_DIM), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((b, s, hkv, HEAD_DIM), jnp.bfloat16,
+                              sharding=one_chip)
+    text, names = _compile(jax.grad(_loss, argnums=(0, 1, 2)), q, kv, kv)
+    assert names == ["flash_fwd_compact", "flash_delta", "flash_bwd_fused"]
+    assert tpu_kernel_calls(text) == len(names)
+
+
+@pytest.mark.parametrize(
+    "b,s,width,fraction",
+    [(8, 2048, 2048, 1.0), (2, 8192, 2048, 1.0), (2, 8192, 1024, 0.5),
+     (2, 8192, 256, 0.5), (4, 2048, 1024, 1.0)],
+)
+def test_rope_kernel_compiles_at_the_cells_shapes(
+    one_chip, b, s, width, fraction
+):
+    """q and k of each cell (zaya's turn half of a head; its K is two
+    heads wide), forward and the VJP's turn by the negated angle."""
+    from kubeflow_tpu.ops.rope import rope
+
+    x = jax.ShapeDtypeStruct((b, s, width), jnp.bfloat16, sharding=one_chip)
+    positions = jax.ShapeDtypeStruct((b, s), jnp.int32, sharding=one_chip)
+
+    def loss(x, positions):
+        y = rope(
+            x, positions, 10000.0, fraction, head_dim=HEAD_DIM,
+            interpret=False,
+        ).astype(jnp.float32)
+        return (y * y).sum()
+
+    text, names = _compile(jax.grad(loss), x, positions)
+    assert names == ["rope_turn_fwd", "rope_turn_bwd"]
+    assert tpu_kernel_calls(text) == 2
+
+
 def test_grouped_matmul_kernels_compile_at_the_zaya_cell_shape(
     one_chip, monkeypatch
 ):
@@ -268,7 +320,8 @@ def _olmo_1b_step(devices, dp, tp):
 
 def _as_on_the_chip(monkeypatch):
     """`jax.default_backend()` is still the CPU here: steer the two places
-    that ask it, so the step holds the compiled kernels as on the chip."""
+    that ask it (`ops/rope.py` asks `flash`'s), so the step holds the
+    compiled kernels as on the chip."""
     from kubeflow_tpu.ops import attention, flash
 
     monkeypatch.setattr(attention, "kernels_compiled", lambda: True)
@@ -309,10 +362,13 @@ def test_equal_heads_step_is_the_program_it_was_before_grouped_heads(
 ):
     """Cell 2's step (equal heads, `dp=2, tp=2`, flash under `shard_map`)
     was not run again on the chip when the kernels learned grouped K/V
-    heads, so it is held here: with the two things that change put back
-    as they stood (k and v ride q's grid row; dK and dV returned as the
-    kernels wrote them), the traced step — every kernel's body and every
-    block's index map with it — is the same text."""
+    heads, so it is held here: with the two things grouping changes put
+    back as they stood (k and v ride q's grid row; dK and dV returned as
+    the kernels wrote them), the traced step — every kernel's body and
+    every block's index map with it — is the same text. Since ISSUE 31
+    that program reads q, k and v as [B, S, H·128]: rope's kernel and
+    the flash kernels under `shard_map`, a shard's 8 heads the column
+    blocks of a row, and no transpose round them."""
     from kubeflow_tpu.ops import flash
 
     from kubeflow_tpu.testing.hlo import _walk_eqns
@@ -324,20 +380,50 @@ def test_equal_heads_step_is_the_program_it_was_before_grouped_heads(
         # An equation prints its kernel's body, not its blocks' index
         # maps: those are read from the calls' grid mappings.
         jaxpr = trainer.make_train_step().trace(*args).jaxpr
+        calls = [
+            eqn for eqn in _walk_eqns(jaxpr.jaxpr)
+            if eqn.primitive.name == "pallas_call"
+        ]
         maps = [
             str(block.index_map_jaxpr)
-            for eqn in _walk_eqns(jaxpr.jaxpr)
-            if eqn.primitive.name == "pallas_call"
+            for eqn in calls
             for block in eqn.params["grid_mapping"].block_mappings
         ]
-        return "\n".join([str(jaxpr), *maps])
+        shapes = {
+            eqn.params["name"]: [v.aval.shape for v in eqn.invars]
+            for eqn in calls
+        }
+        return "\n".join([str(jaxpr), *maps]), shapes
 
-    now = traced()
+    now, shapes = traced()
     assert "flash_bwd_fused" in now and "shard_map" in now
+    # A shard: 4 of 8 batch rows, 8 of 16 heads, folded into the lanes.
+    assert shapes["rope_turn_fwd"][0] == (4, 2048, 8 * HEAD_DIM)
+    assert shapes["flash_fwd_compact"][2:] == [(4, 2048, 8 * HEAD_DIM)] * 3
+    assert shapes["flash_bwd_fused"][2:6] == [(4, 2048, 8 * HEAD_DIM)] * 4
     jax.clear_caches()
-    monkeypatch.setattr(flash, "_kv_row", lambda group: lambda b: b)
-    monkeypatch.setattr(flash, "_sum_groups", lambda dk, group, dtype: dk)
-    assert traced() == now
+    monkeypatch.setattr(flash, "_kv_row", lambda group: lambda g: g)
+    monkeypatch.setattr(flash, "_sum_groups", lambda dk, like, d: dk)
+    assert traced()[0] == now
+
+
+def test_one_chip_step_holds_no_relayout_of_q(topo, monkeypatch):
+    """`olmo-1b-cut`'s layer (8 x 2048 tokens, 16 heads of 128) compiled
+    for the chip: between the projections' matmuls and the attention
+    kernels no instruction of the step copies or transposes a q-sized
+    array, in any of the shapes a relayout of q, k, v, o or their
+    gradients has had ([B, S, H·D], [B, S, H, D], [B, H, S, D],
+    [B·H, S, D]; the parent's step had five, two of them in float32)."""
+    _as_on_the_chip(monkeypatch)
+    trainer, args = _olmo_1b_step(topo.devices, dp=1, tp=1)
+    text = compiled_hlo(trainer.make_train_step(), *args)
+    entry = re.search(r"^ENTRY .*?^\}", text, re.M | re.S).group(0)
+    q_sized = r"(?:8,2048,2048|8,2048,16,128|8,16,2048,128|128,2048,128)"
+    relayouts = re.findall(
+        rf"= \w+\[{q_sized}\]\S* (?:copy|transpose)\(", entry
+    )
+    assert relayouts == [], relayouts
+    assert tpu_kernel_calls(entry) == 7  # rope x 4, flash fwd, delta, bwd
 
 
 def test_one_chip_step_gets_no_options_and_no_collective(topo, monkeypatch):

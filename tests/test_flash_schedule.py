@@ -705,3 +705,134 @@ def test_schedule_pairs_where_the_bodies_do_not_engage():
     assert ragged["computed_pairs_over_needed"] == (
         3 * 1024**2 / (2001 * 2002 // 2)
     )
+
+
+# -- where the kernels read a head (ISSUE 31) ---------------------------------
+
+
+def _through(layout, monkeypatch, q, k, v, **kw):
+    """(o, lse, dq, dk, dv) of `flash_attention` with the heads handed to
+    the kernels in `layout`, whatever the head size would select."""
+    monkeypatch.setattr(flash, "_head_layout", lambda d: layout)
+
+    def loss(q, k, v):
+        o, lse = flash_attention(
+            q, k, v, interpret=True, return_lse=True, **kw
+        )
+        o32 = o.astype(jnp.float32)
+        return jnp.sum(o32 * jnp.cos(o32)), (o, lse)
+
+    grads, (o, lse) = jax.grad(loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    return (o, lse, *grads)
+
+
+@pytest.mark.parametrize(
+    "name,s,h,hkv,d,block,causal,budget,kernels",
+    [
+        # equal heads, fused backward, replicated lse (64-row blocks)
+        ("equal", 256, 4, 4, 16, 64, True, None, ["flash_bwd_fused"]),
+        # zaya's grouping, 8 query heads over 2, packed lse (one block)
+        ("grouped", 256, 8, 2, 16, 256, True, None, ["flash_bwd_fused"]),
+        # a ragged length: 321 pads to 384, the tail masked by position
+        ("ragged", 321, 4, 2, 16, 128, True, None, ["flash_bwd_fused"]),
+        # no VMEM for the dq ring: the compact two-pass kernels
+        ("two_pass", 384, 4, 2, 24, 128, True, 0,
+         ["flash_dq_compact", "flash_dkv_compact"]),
+        # not causal: the rectangular grid, forward and backward
+        ("rect", 256, 6, 3, 16, 128, False, None,
+         ["flash_dq_rect", "flash_dkv_rect"]),
+        # whole lanes, the size both layouts are legal at on the chip
+        ("lanes", 128, 4, 2, 128, 128, True, None, ["flash_bwd_fused"]),
+    ],
+)
+def test_seq_major_is_bit_identical_to_head_major_and_matches_dense(
+    name, s, h, hkv, d, block, causal, budget, kernels, monkeypatch
+):
+    """The same blocks in the same order into the same bodies: output,
+    lse and all three gradients of the kernels reading [B, S, H·d] equal
+    those of the kernels reading the transposed [B·H, S, d] bit for bit,
+    and both match dense attention."""
+    if budget is not None:
+        monkeypatch.setattr(flash, "_FUSED_VMEM_BUDGET", budget)
+    ks = jax.random.split(jax.random.PRNGKey(31), 3)
+    q = jax.random.normal(ks[0], (2, s, h, d))
+    k = jax.random.normal(ks[1], (2, s, hkv, d))
+    v = jax.random.normal(ks[2], (2, s, hkv, d))
+    kw = dict(causal=causal, block_q=block, block_k=block)
+    seq = _through("seq_major", monkeypatch, q, k, v, **kw)
+    head = _through("head_major", monkeypatch, q, k, v, **kw)
+    for a, b, what in zip(seq, head, ("o", "lse", "dq", "dk", "dv")):
+        assert a.shape == b.shape and a.dtype == b.dtype, what
+        np.testing.assert_array_equal(a, b, err_msg=f"{name}: {what}")
+
+    names = pallas_kernel_names(
+        jax.grad(
+            lambda q, k, v: flash_attention(
+                q, k, v, interpret=True, **kw
+            ).sum(),
+            argnums=(0, 1, 2),
+        ),
+        q, k, v,
+    )
+    assert names[2:] == kernels, names
+
+    group = h // hkv
+    rep = lambda x: jnp.repeat(x, group, axis=2)
+    dense = lambda q, k, v: dense_attention(q, rep(k), rep(v), causal=causal)
+    np.testing.assert_allclose(seq[0], dense(q, k, v), atol=2e-4, rtol=2e-4)
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, rep(k)) / np.sqrt(d)
+    if causal:
+        scores = jnp.where(jnp.tril(jnp.ones((s, s), bool)), scores, -jnp.inf)
+    np.testing.assert_allclose(
+        seq[1], jax.scipy.special.logsumexp(scores, axis=-1),
+        atol=2e-4, rtol=2e-4,
+    )
+    want = _grads(dense, q, k, v)
+    got = _grads(
+        lambda q, k, v: flash_attention(q, k, v, interpret=True, **kw),
+        q, k, v,
+    )
+    for g, w, what in zip(got, want, "qkv"):
+        np.testing.assert_allclose(
+            g, w, atol=5e-4, rtol=5e-4, err_msg=f"{name}: d{what}"
+        )
+
+
+def _transposes_outside_kernels(jaxpr) -> int:
+    """`transpose` equations of a program, its kernels' bodies apart (a
+    packed lse is packed by (128, 128) transposes in registers)."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            continue
+        n += eqn.primitive.name == "transpose"
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n += _transposes_outside_kernels(sub)
+    return n
+
+
+@pytest.mark.parametrize(
+    "d,layout,transposes",
+    [(64, "head_major", 8), (128, "seq_major", 0), (256, "seq_major", 0)],
+)
+def test_layout_comes_from_the_head_size_alone(d, layout, transposes):
+    """A head is a legal column block of [B, S, H·d] where d is whole
+    lanes: there the kernels read the projections' arrays and
+    `flash_attention` traces no transpose, forward or backward; any
+    other d keeps the four transposes in and the four out. The static
+    counter (`flash_schedule`) says which, and the traced program agrees
+    with it."""
+    sched = flash_schedule(256, 256, block_q=128, block_k=128, head_dim=d)
+    assert sched["layout"] == layout
+    assert sched["transposes_per_call"] == transposes
+    q = jax.ShapeDtypeStruct((2, 256, 4, d), jnp.float32)
+    kv = jax.ShapeDtypeStruct((2, 256, 2, d), jnp.float32)
+    attn = lambda q, k, v: flash_attention(
+        q, k, v, block_q=128, block_k=128, interpret=True
+    )
+    fwd = jax.make_jaxpr(attn)(q, kv, kv)
+    both = jax.make_jaxpr(
+        jax.grad(lambda q, k, v: attn(q, k, v).sum(), argnums=(0, 1, 2))
+    )(q, kv, kv)
+    assert _transposes_outside_kernels(fwd.jaxpr) == transposes // 2
+    assert _transposes_outside_kernels(both.jaxpr) == transposes
