@@ -10,6 +10,9 @@ axis names and resolved by the rules table:
   (`kubeflow_tpu.ops.ring_attention`);
 - an optional dropless expert layer holds a range of the experts, shards
   them over ``ep`` and sums the shards' partial results;
+- a stack may be written as a pattern of single sublayers (a state-space
+  mixer, an expert layer or attention alone) in place of attention + MLP
+  blocks (`TransformerConfig.layer_pattern`);
 - embed-dim weight shards over ``fsdp`` (ZeRO-3).
 
 Blocks are rematerialized (`nn.remat`) — recompute beats HBM traffic on
@@ -32,6 +35,11 @@ from kubeflow_tpu.ops.attention import attend
 from kubeflow_tpu.ops.flash import CHECKPOINT_LSE_NAME, CHECKPOINT_OUT_NAME
 from kubeflow_tpu.ops.moe import expert_mlp_on_mesh
 from kubeflow_tpu.ops.rope import rope
+from kubeflow_tpu.ops.ssd import (
+    CHECKPOINT_OUT_NAME as SSD_OUT_NAME,
+    CHECKPOINT_STATES_NAME as SSD_STATES_NAME,
+    ssd_scan,
+)
 from kubeflow_tpu.parallel.sharding import batch_axes
 
 
@@ -77,42 +85,79 @@ class TransformerConfig:
     # head_dim wide, whatever d_model is.
     cca: bool = False
     cca_kernels: tuple[int, int] = (2, 2)
-    # Experts: 0 = the dense SwiGLU MLP. Otherwise a dropless top-1 layer
-    # of gated experts of width d_ff behind a router MLP whose hidden
-    # state is carried from layer to layer. `experts_held` = (first,
-    # count) is the contiguous range this program holds (None = all): the
-    # router still routes over `num_experts`, tokens routed elsewhere get
-    # nothing added here.
+    # Experts: 0 = the dense MLP. Otherwise a dropless layer of experts of
+    # width d_ff, `experts_per_token` of them a token. `router` "mlp" is a
+    # softmax router MLP whose hidden state is carried from layer to
+    # layer (top-1); "sigmoid" scores every expert by a sigmoid of one
+    # matmul and weighs the chosen by their scores normalised to
+    # `routed_scaling`. `experts_held` = (first, count) is the contiguous
+    # range this program holds (None = all): the router still routes over
+    # `num_experts`, a token's rows to experts held elsewhere add nothing.
     num_experts: int = 0
     experts_held: tuple[int, int] | None = None
     router_hidden: int = 256
-    # For measuring an untrained model: every token's expert is drawn
+    experts_per_token: int = 1
+    router: str = "mlp"
+    routed_scaling: float = 1.0
+    # The experts: "swiglu" is silu(x Wg) * (x Wu) Wd, three matrices;
+    # "relu2" is relu(x W1)^2 W2, two.
+    mlp_act: str = "swiglu"
+    # A latent expert space: the experts work in `moe_latent` dims between
+    # a projection into it and one back (0 = in d_model). A shared expert
+    # of width `moe_shared_ff` that every token passes (0 = none).
+    moe_latent: int = 0
+    moe_shared_ff: int = 0
+    # For measuring an untrained model: every token's experts are drawn
     # evenly at random, by position and layer and the same in every run,
-    # in place of the router's argmax; the gate stays the router's
-    # probability of that expert (`ExpertLayer`).
+    # in place of the router's choice; the weights stay the router's
+    # (`ExpertLayer`).
     router_force_balance: bool = False
+    # A stack of single sublayers, one letter a layer, each `x + f(norm(x))`:
+    # "M" a state-space mixer, "E" the expert layer, "*" attention. None =
+    # `n_layers` blocks of attention then MLP (or experts).
+    layer_pattern: str | None = None
+    # The head's own matrix where the published model does not tie it to
+    # the embedding.
+    tie_embeddings: bool = True
+    # The state-space mixer (Mamba-2): `ssm_heads` heads of `ssm_head_dim`
+    # channels, a state of `ssm_state` a channel, B and C shared by the
+    # heads of each of `ssm_groups` groups, a causal depthwise convolution
+    # of `ssm_conv` taps, the scan in chunks of `ssm_chunk` positions.
+    # `ssm_dt` = (min, max, floor) of the time steps the bias is drawn for.
+    ssm_heads: int = 0
+    ssm_head_dim: int = 64
+    ssm_state: int = 128
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
+    ssm_dt: tuple[float, float, float] = (1e-3, 1e-1, 1e-4)
 
 
-def _block_cls(cfg: "TransformerConfig"):
-    """Block, wrapped per the config's remat policy."""
+def _block_cls(cfg: "TransformerConfig", cls=None):
+    """Block (or `cls`, a layer of a pattern), wrapped per the config's
+    remat policy."""
+    cls = cls or Block
     if cfg.remat_policy in ("none", "mlp"):
-        # No checkpoint round the block ("mlp": `Block` remats its MLP
-        # half itself), so attention's residuals (q/k/v, o, lse) are saved.
-        return Block
+        # No checkpoint round the block ("mlp": `Block` and `Sublayer`
+        # remat their MLP themselves), so attention's residuals (q/k/v,
+        # o, lse) are saved.
+        return cls
     if cfg.remat_policy == "full":
-        return nn.remat(Block, static_argnums=())
+        return nn.remat(cls, static_argnums=())
     if cfg.remat_policy == "flash":
         # The kernel names its output and its (lane-packed) lse, the
         # policy pins both, and the backward's partial eval dead-codes
         # the forward kernel: q/k/v recompute from the cheap projections,
         # o/lse come from the saved residuals. Any other checkpoint whose
         # boundary crosses the flash custom_vjp re-runs the forward kernel
-        # to rebuild lse.
+        # to rebuild lse. The chunked scan names its output and its chunk
+        # states the same way (`ops/ssd.py`).
         return nn.remat(
-            Block,
+            cls,
             static_argnums=(),
             policy=jax.checkpoint_policies.save_only_these_names(
-                CHECKPOINT_OUT_NAME, CHECKPOINT_LSE_NAME
+                CHECKPOINT_OUT_NAME, CHECKPOINT_LSE_NAME,
+                SSD_OUT_NAME, SSD_STATES_NAME,
             ),
         )
     raise ValueError(
@@ -152,7 +197,8 @@ def _dense(features, names, name=None, dtype=jnp.bfloat16, axis=-1):
 
 
 def lm_head(x, embed, *, dtype):
-    """Tied output head: bf16 operands, f32 accumulation, stated
+    """Output head over `embed` [V, d] (the tied embedding, or the head's
+    own matrix): bf16 operands, f32 accumulation, stated
     explicitly rather than via an f32×f32 einsum. XLA's
     allow_excess_precision can demote the latter to the same MXU path
     (measured neutral on v5e with that flag set), but the flag is
@@ -212,7 +258,7 @@ def _replicated(init, rank: int):
 class Attention(nn.Module):
     """Causal self-attention from the configuration's numbers: `n_heads`
     query heads over `n_kv_heads` K/V heads in a latent of n_heads x
-    head_dim, rope over `rope_fraction` of a head, and CCA's mixing of q,
+    head_dim, rope over `rope_fraction` of a head (0: none), and CCA's mixing of q,
     k and v along the sequence when `cca` is on (OLMo's case is all of
     them off and equal heads)."""
 
@@ -295,11 +341,12 @@ class Attention(nn.Module):
             with jax.named_scope("cca.mix"):
                 q, k, v = self._cca_mix(heads(q), heads(k), heads(v))
             q, k, v = (u.reshape(*u.shape[:2], -1) for u in (q, k, v))
-        turn = functools.partial(
-            rope, positions=positions, theta=cfg.rope_theta,
-            fraction=cfg.rope_fraction, head_dim=d, mesh=self.mesh,
-        )
-        q, k = turn(q), turn(k)
+        if cfg.rope_fraction > 0:  # 0: no rotation (Nemotron-H's attention)
+            turn = functools.partial(
+                rope, positions=positions, theta=cfg.rope_theta,
+                fraction=cfg.rope_fraction, head_dim=d, mesh=self.mesh,
+            )
+            q, k = turn(q), turn(k)
         with jax.named_scope("cca.attend" if cfg.cca else "attend"):
             out = attend(
                 heads(q), heads(k), heads(v), mesh=self.mesh,
@@ -324,37 +371,71 @@ class SwiGLU(nn.Module):
         )
 
 
+class ReluSquaredMLP(nn.Module):
+    """`relu(x W1)^2 W2`, no gate matrix (Nemotron-H's `relu2`)."""
+
+    config: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.config
+        hidden = _dense(cfg.d_ff, ("embed", "mlp"), "wi", cfg.dtype)(x)
+        return _dense(cfg.d_model, ("mlp", "embed"), "wo", cfg.dtype)(
+            jnp.square(nn.relu(hidden))
+        )
+
+
+def _mlp_cls(cfg: TransformerConfig):
+    if cfg.mlp_act not in ("swiglu", "relu2"):
+        raise ValueError(
+            f"unknown mlp_act {cfg.mlp_act!r}; expected 'swiglu' or 'relu2'"
+        )
+    return SwiGLU if cfg.mlp_act == "swiglu" else ReluSquaredMLP
+
+
 FORCED_ROUTING_SEED = 42
 
 
-def forced_experts(layer: int, seq_len: int, num_experts: int):
-    """[seq_len] int32: the expert `router_force_balance` gives each
-    position in `layer`, the argmax of standard normal scores from a
-    fixed key."""
+def forced_experts(layer: int, seq_len: int, num_experts: int, k: int = 1):
+    """[seq_len] int32 (k = 1) or [seq_len, k]: the experts
+    `router_force_balance` gives each position in `layer`, the k largest
+    of standard normal scores from a fixed key (the argmax at k = 1)."""
     key = jax.random.fold_in(jax.random.PRNGKey(FORCED_ROUTING_SEED), layer)
     scores = jax.random.normal(key, (seq_len, num_experts), jnp.float32)
-    return jnp.argmax(scores, axis=-1).astype(jnp.int32)
+    if k == 1:
+        return jnp.argmax(scores, axis=-1).astype(jnp.int32)
+    return jax.lax.top_k(scores, k)[1].astype(jnp.int32)
 
 
 class ExpertLayer(nn.Module):
-    """Dropless top-1 layer of gated experts over the experts held here.
+    """Dropless layer of experts over the experts held here.
 
-    The router is an MLP over a hidden state that is carried from one
-    layer's router to the next (`router_state` in, the new state out):
-    `r = x W_in + carry * r_prev`, `p = softmax(W3 gelu(W2 gelu(W1
-    norm(r))))`, in float32 at full matmul precision. Each token goes to
-    its best expert e with gate `p[e]`; there is no capacity, no dropped
-    token, no balancing bias and no auxiliary loss.
+    `config.router` "mlp": the router is an MLP over a hidden state that
+    is carried from one layer's router to the next (`router_state` in,
+    the new state out): `r = x W_in + carry * r_prev`, `p = softmax(W3
+    gelu(W2 gelu(W1 norm(r))))`, in float32 at full matmul precision. Each
+    token goes to its best expert e with gate `p[e]`; there is no
+    capacity, no dropped token, no balancing bias and no auxiliary loss.
+
+    "sigmoid": `p = sigmoid(x W_r)` in float32 at full precision, the
+    `experts_per_token` largest of `p + b` are chosen (`b`, `router_bias`,
+    a per-expert correction that gets no gradient: what a balancing rule
+    would move, and nothing here does) and weigh `routed_scaling * p_e /
+    sum of the chosen p`. No state is carried (`router_state` passes
+    through). With `moe_latent` the experts read `x W_latent_in` and their
+    sum goes through `W_latent_out`; with `moe_shared_ff` a shared expert
+    of that width, which every token passes, is added. `mlp_act` says
+    which expert: gated, or `relu(.)^2`.
 
     Nothing therefore keeps an untrained router even, and a dropless
     layer's work follows its tokens. `config.router_force_balance` is for
     measuring such a model at the load a trained one has (what
     Megatron-Core's `--moe-router-force-load-balancing` is for): e is
-    then the argmax of standard normal scores drawn for (position,
-    expert) from a fixed key and `layer`, the same for every row of the
-    batch, in every step and every run, so each expert gets about
-    tokens / num_experts whatever the weights are; the router still runs
-    and still learns through the gate `p[e]`.
+    then the argmax (the k largest) of standard normal scores drawn for
+    (position, expert) from a fixed key and `layer`, the same for every
+    row of the batch, in every step and every run, so each expert gets
+    about tokens x k / num_experts whatever the weights are; the router
+    still runs and still learns through the weights.
 
     `config.experts_held` = (first, count) says which experts this program
     holds: only their weights exist, the router still scores all
@@ -364,10 +445,11 @@ class ExpertLayer(nn.Module):
     them and the shards' partial results are summed
     (`ops/moe.expert_mlp_on_mesh`: rows ordered by expert, grouped
     matmuls); with one shard there is no exchange. Sows, under
-    "counters": `moe_tokens_held` (tokens routed to a held expert),
-    `moe_load_max`, `moe_load_mean` (tokens on the fullest held expert
-    and the mean over them), and under "intermediates" `expert`, each
-    token's choice.
+    "counters": `moe_tokens_held`, `moe_load_max`, `moe_load_mean`, which
+    count ROWS, token-expert pairs routed to a held expert (in all, on
+    the fullest held expert, the mean over them): tokens at one expert a
+    token, k times as many at most at k; and under "intermediates"
+    `expert`, each token's choice.
     """
 
     config: TransformerConfig
@@ -415,6 +497,37 @@ class ExpertLayer(nn.Module):
         gate = jnp.take_along_axis(probs, expert[..., None], axis=-1)[..., 0]
         return expert, gate, r
 
+    def _route_sigmoid(self, x):
+        cfg = self.config
+        k, n = cfg.experts_per_token, cfg.num_experts
+        w = self.param(
+            "router",
+            nn.with_logical_partitioning(
+                nn.initializers.variance_scaling(1.0, "fan_in", "normal"),
+                ("embed", None),
+            ),
+            (x.shape[-1], n), jnp.float32,
+        )
+        bias = self.param(
+            "router_bias", _replicated(nn.initializers.zeros, 1), (n,),
+            jnp.float32,
+        )
+        probs = jax.nn.sigmoid(jnp.dot(
+            x.astype(jnp.float32), w, precision=jax.lax.Precision.HIGHEST
+        ))
+        if cfg.router_force_balance:
+            expert = forced_experts(self.layer, x.shape[-2], n, k)
+            expert = jnp.broadcast_to(
+                expert.reshape(x.shape[-2], k), (*probs.shape[:-1], k)
+            )
+        else:
+            _, expert = jax.lax.top_k(probs + jax.lax.stop_gradient(bias), k)
+        chosen = jnp.take_along_axis(probs, expert, axis=-1)
+        gate = cfg.routed_scaling * chosen / (
+            jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20
+        )
+        return expert.astype(jnp.int32), gate
+
     @nn.compact
     def __call__(self, x, router_state):
         cfg = self.config
@@ -424,8 +537,18 @@ class ExpertLayer(nn.Module):
                 f"experts_held {cfg.experts_held} is not a range of the "
                 f"{cfg.num_experts} experts"
             )
+        if cfg.router not in ("mlp", "sigmoid") or (
+            cfg.router == "mlp" and cfg.experts_per_token != 1
+        ):
+            raise ValueError(
+                f"router {cfg.router!r} with {cfg.experts_per_token} experts "
+                "a token: expected 'mlp' (one a token) or 'sigmoid'"
+            )
         with jax.named_scope("moe.route"):
-            expert, gate, router_state = self._route(x, router_state)
+            if cfg.router == "mlp":
+                expert, gate, router_state = self._route(x, router_state)
+            else:
+                expert, gate = self._route_sigmoid(x)
             load = jnp.sum(
                 expert.reshape(-1)[:, None]
                 == first + jnp.arange(held, dtype=jnp.int32)[None, :],
@@ -460,16 +583,116 @@ class ExpertLayer(nn.Module):
                 (held, rows, cols), jnp.float32,
             )
 
-        dm, ff = x.shape[-1], cfg.d_ff
+        inner = x
+        if cfg.moe_latent:
+            with jax.named_scope("moe.latent_in"):
+                inner = _dense(
+                    cfg.moe_latent, ("embed", None), "latent_in", cfg.dtype
+                )(x)
+        dm, ff = inner.shape[-1], cfg.d_ff
+        into = ("w_gate", "w_up") if _mlp_cls(cfg) is SwiGLU else ("w_in",)
         weights = (
-            weight("w_gate", dm, ff, ("embed", "mlp")),
-            weight("w_up", dm, ff, ("embed", "mlp")),
+            *(weight(name, dm, ff, ("embed", "mlp")) for name in into),
             weight("w_down", ff, dm, ("mlp", "embed")),
         )
         out = expert_mlp_on_mesh(
-            self.mesh, x, expert, gate.astype(jnp.float32), weights, first
+            self.mesh, inner, expert, gate.astype(jnp.float32), weights, first
         )
+        if cfg.moe_latent:
+            with jax.named_scope("moe.latent_out"):
+                out = _dense(
+                    cfg.d_model, (None, "embed"), "latent_out", cfg.dtype
+                )(out)
+        if cfg.moe_shared_ff:
+            with jax.named_scope("moe.shared"):
+                out = out + _mlp_cls(cfg)(
+                    dataclasses.replace(cfg, d_ff=cfg.moe_shared_ff),
+                    name="shared",
+                )(x)
         return out, router_state
+
+
+def _inverse_softplus(x):
+    return x + jnp.log(-jnp.expm1(-x))
+
+
+class StateSpaceMixer(nn.Module):
+    """Mamba-2's mixer over u [B, S, d_model], from the configuration's
+    numbers: H = `ssm_heads` heads of P = `ssm_head_dim`, G = `ssm_groups`
+    groups, a state of N = `ssm_state`, `d_in = H P`.
+
+    `[z | xBC | dt] = u W_in` (one matrix, [d_model, 2 d_in + 2 G N + H],
+    no bias); `xBC <- silu(conv(xBC))`, a causal depthwise convolution of
+    `ssm_conv` taps with a bias (tap j multiplies the value j tokens
+    back); `dt <- softplus(dt + dt_bias)`, `a = -exp(A_log)`; the scan
+    (`ops/ssd.ssd_scan`: `s_t = exp(dt_t a) s_(t-1) + dt_t x_t (x) B_t`,
+    `y_t = s_t C_t`) plus the skip `D x`; `y <- RMSNorm_group(y silu(z))`
+    over each group's `d_in / G` channels with a learned scale; `y W_out`.
+    x, B, C and y stay [B, S, heads·dims], as the scan's kernels read
+    them. `W_in` is one matrix as published, so on a mesh it is not split
+    over `tp` (the scan is, by whole groups)."""
+
+    config: TransformerConfig
+    mesh: Mesh | None = None
+
+    @nn.compact
+    def __call__(self, u):
+        cfg = self.config
+        h, p, g, n = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state
+        if h < 1 or h % g:
+            raise ValueError(f"{h} state-space heads do not divide into {g} groups")
+        d_in, gn, taps = h * p, g * n, cfg.ssm_conv
+        f32 = jnp.float32
+        vector = lambda name, init, size: self.param(
+            name, _replicated(init, 1), (size,), f32
+        )
+        with jax.named_scope("ssm.in_proj"):
+            proj = _dense(
+                2 * d_in + 2 * gn + h, ("embed", None), "in_proj", cfg.dtype
+            )(u)
+        z, xbc, dt = jnp.split(proj, [d_in, 2 * d_in + 2 * gn], axis=-1)
+        with jax.named_scope("ssm.conv"):
+            w = self.param(
+                "conv_kernel",
+                _replicated(nn.initializers.normal(taps ** -0.5), 2),
+                (taps, d_in + 2 * gn), f32,
+            )
+            xbc = xbc.astype(f32)
+            mixed = sum(w[j] * _shift(xbc, j) for j in range(taps))
+            xbc = nn.silu(
+                mixed + vector("conv_bias", nn.initializers.zeros, d_in + 2 * gn)
+            ).astype(cfg.dtype)
+        x, b, c = jnp.split(xbc, [d_in, d_in + gn], axis=-1)
+
+        def steps(key, shape, dtype):
+            lo, hi, floor = cfg.ssm_dt
+            drawn = jnp.exp(jax.random.uniform(
+                key, shape, dtype, math.log(lo), math.log(hi)
+            ))
+            return _inverse_softplus(jnp.maximum(drawn, floor))
+
+        dt = nn.softplus(dt.astype(f32) + vector("dt_bias", steps, h))
+        a = -jnp.exp(vector(
+            "A_log", lambda *_: jnp.log(jnp.linspace(1.0, 16.0, h, dtype=f32)), h
+        ))
+        with jax.named_scope("ssm.scan"):
+            y = ssd_scan(
+                x, dt, a, b, c, groups=g, chunk=cfg.ssm_chunk, mesh=self.mesh
+            )
+            skip = jnp.repeat(vector("D", nn.initializers.ones, h), p)
+            y = y.astype(f32) + skip * x.astype(f32)
+        with jax.named_scope("ssm.gate_norm"):
+            y = y * nn.silu(z.astype(f32))
+            grouped = y.reshape(*y.shape[:-1], g, d_in // g)
+            grouped = grouped * jax.lax.rsqrt(
+                jnp.mean(grouped * grouped, axis=-1, keepdims=True) + cfg.norm_eps
+            )
+            y = (
+                grouped.reshape(y.shape)
+                * vector("norm_scale", nn.initializers.ones, d_in)
+            ).astype(cfg.dtype)
+        with jax.named_scope("ssm.out_proj"):
+            return _dense(cfg.d_model, (None, "embed"), "out_proj", cfg.dtype)(y)
 
 
 class Block(nn.Module):
@@ -505,6 +728,57 @@ class Block(nn.Module):
         else:
             out = wrap(SwiGLU)(cfg, name="mlp")(h)
         return x + out, router_state
+
+
+LAYER_KINDS = {
+    "M": "a state-space mixer", "E": "the expert layer", "*": "attention",
+}
+
+
+class Sublayer(nn.Module):
+    """One layer of a `layer_pattern`: `x + f(RMSNorm(x))`, f by `kind`
+    (`LAYER_KINDS`). `Block`'s signature, so one loop builds either
+    stack; only the "mlp"-router expert layer touches `router_state`."""
+
+    config: TransformerConfig
+    mesh: Mesh | None = None
+    layer: int = 0
+    kind: str = "*"
+
+    @nn.compact
+    def __call__(self, x, positions, router_state=None):
+        cfg, kind = self.config, self.kind
+        h = RMSNorm(cfg.dtype, cfg.norm_eps, name="ln")(x)
+        # As in `Block`: the "mlp" policy's only checkpoint.
+        wrap = nn.remat if cfg.remat_policy == "mlp" else (lambda cls: cls)
+        if kind == "M":
+            out = StateSpaceMixer(cfg, self.mesh, name="ssm")(h)
+        elif kind == "E":
+            out, router_state = wrap(ExpertLayer)(
+                cfg, self.mesh, self.layer, name="moe"
+            )(h, router_state)
+        elif kind == "*":
+            out = Attention(cfg, self.mesh, name="attn")(h, positions)
+        else:
+            raise ValueError(
+                f"unknown layer kind {kind!r} in layer_pattern; expected one "
+                f"of {sorted(LAYER_KINDS)}"
+            )
+        return x + out, router_state
+
+
+def _layer_classes(cfg: TransformerConfig) -> list:
+    """The stack's layers in order, each a class with `Block`'s
+    constructor and call, wrapped per the remat policy."""
+    if cfg.layer_pattern is None:
+        return [_block_cls(cfg)] * cfg.n_layers
+    if len(cfg.layer_pattern) != cfg.n_layers:
+        raise ValueError(
+            f"layer_pattern {cfg.layer_pattern!r} names "
+            f"{len(cfg.layer_pattern)} layers, n_layers is {cfg.n_layers}"
+        )
+    sublayer = _block_cls(cfg, Sublayer)
+    return [functools.partial(sublayer, kind=k) for k in cfg.layer_pattern]
 
 
 class _PipelineStage(nn.Module):
@@ -574,8 +848,11 @@ class PipelinedTransformerLM(nn.Module):
     @nn.compact
     def __call__(self, tokens, train: bool = False, labels=None):
         cfg = self.config
-        if cfg.num_experts > 0:
-            raise ValueError("pipelined transformer does not support MoE")
+        if cfg.num_experts > 0 or cfg.layer_pattern or not cfg.tie_embeddings:
+            raise ValueError(
+                "pipelined transformer does not support MoE, a layer "
+                "pattern or an untied head"
+            )
         if cfg.n_layers % self.n_stages:
             raise ValueError(
                 f"n_layers ({cfg.n_layers}) must divide into "
@@ -801,7 +1078,9 @@ class PipelinedTransformerLM(nn.Module):
 
 
 class TransformerLM(nn.Module):
-    """Embed → N blocks → norm → logits. apply(tokens[, train]) → [B,S,V]."""
+    """Embed → N layers → norm → logits. apply(tokens[, train]) → [B,S,V].
+    The layers are `Block`s, or the single sublayers `layer_pattern`
+    names; the head is the embedding's matrix or, untied, its own."""
 
     config: TransformerConfig
     mesh: Mesh | None = None
@@ -821,15 +1100,25 @@ class TransformerLM(nn.Module):
         positions = jnp.broadcast_to(
             jnp.arange(tokens.shape[1], dtype=jnp.int32), tokens.shape
         )
-        block_cls = _block_cls(cfg)
         # The first layer's router has no state before it: zeros.
         router_state = (
             jnp.zeros((*tokens.shape, cfg.router_hidden), jnp.float32)
-            if cfg.num_experts > 0 else None
+            if cfg.num_experts > 0 and cfg.router == "mlp" else None
         )
-        for i in range(cfg.n_layers):
-            x, router_state = block_cls(
+        for i, layer_cls in enumerate(_layer_classes(cfg)):
+            x, router_state = layer_cls(
                 cfg, self.mesh, layer=i, name=f"layer_{i}"
             )(x, positions, router_state)
         x = RMSNorm(cfg.dtype, cfg.norm_eps, name="ln_final")(x)
-        return lm_head(x, embed, dtype=cfg.dtype)
+        head = embed
+        if not cfg.tie_embeddings:
+            head = self.param(
+                "lm_head",
+                nn.with_logical_partitioning(
+                    nn.initializers.normal(cfg.d_model ** -0.5),
+                    ("vocab", "embed"),
+                ),
+                (cfg.vocab_size, cfg.d_model),
+                jnp.float32,
+            )
+        return lm_head(x, head, dtype=cfg.dtype)

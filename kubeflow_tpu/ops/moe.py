@@ -1,18 +1,21 @@
-"""Dropless top-1 expert dispatch and grouped matmuls (Pallas TPU).
+"""Dropless top-k expert dispatch and grouped matmuls (Pallas TPU).
 
-What `models/transformer.ExpertLayer` runs on the experts it holds: tokens
-are ordered by expert into a row buffer, three grouped matmuls (the gated
-expert MLP) run over it, and each token's row is gathered back. No
-capacity and no dropped token at any imbalance; the work follows the
-tokens routed here, not `experts x capacity`.
+What `models/transformer.ExpertLayer` runs on the experts it holds: a
+token has one row for each of its k experts; the rows are ordered by
+expert into a row buffer, grouped matmuls (the expert MLP: gated, three
+matrices, or `relu(.)^2`, two) run over it, and each token's rows are
+gathered back and added up by their weights. No capacity and no dropped
+token at any imbalance; the work follows the rows routed here, not
+`experts x capacity`.
 
 - **The plan** (`plan_dispatch`). Each held expert's tokens occupy a run
   of whole row tiles (`block_rows` rows; an expert with no token still
   gets one tile of zero rows, so every expert's weight gradient is
-  written). The buffer is sized for the worst case, `ceil(N / block_rows)
-  + experts` tiles; `tile_expert[t]` names tile t's expert and `n_tiles`
-  how many tiles are in use. Tokens routed to experts held elsewhere get
-  no row. A tile never straddles two experts, so the kernels need no
+  written). The buffer is sized for the worst case: a token's k experts
+  differ, so at most `min(k, experts)` of its rows are held here, and
+  `ceil(N min(k, experts) / block_rows) + experts` tiles hold them;
+  `tile_expert[t]` names tile t's expert and `n_tiles` how many tiles
+  are in use. Pairs routed to experts held elsewhere get no row. A tile never straddles two experts, so the kernels need no
   masks: a grouped matmul is a tiled matmul whose weight block is picked
   by a scalar-prefetched table.
 - **`moe_gmm_fwd` / `moe_gmm_dlhs`**: `out[rows of e] = lhs[rows of e] @
@@ -25,7 +28,12 @@ tokens routed here, not `experts x capacity`.
 - **`moe_gmm_dw`**: `dw[e] = lhs[rows of e].T @ g[rows of e]`, accumulated
   in float32 over an expert's tiles and written once.
 - **`take_rows`**: a row gather whose transpose is the inverse gather
-  (each token has at most one row), so neither direction scatters.
+  (a row names one token-expert pair and a pair at most one row), so
+  neither direction scatters. **`spread_rows`** is the dispatch at k > 1,
+  `rows[r] = x[token of r]`: its transpose is the sum of a token's
+  gathers. The combine is `take_rows` the other way, then the weighted
+  sum over a token's rows. Both read `min(k, experts held)` rows a
+  token, the most it can have here (`plan_dispatch`'s `token_rows`).
 
 `grouped_matmul` ties the three kernels together with a `custom_vjp`; the
 weights go in at their own dtype (float32 parameters) and are cast for
@@ -60,7 +68,13 @@ _VMEM_LIMIT = 48 * 1024 * 1024
 
 
 def _tile(dim: int, cap: int) -> int:
-    """The largest tile <= cap that divides `dim` (halving from cap)."""
+    """The largest tile <= cap that divides `dim`: of whole 128-lane
+    columns where `dim` is made of them (2688 = 21 x 128 tiles by 896),
+    else by halving from cap."""
+    if dim % 128 == 0:
+        return 128 * max(
+            m for m in range(1, min(dim, cap) // 128 + 1) if (dim // 128) % m == 0
+        )
     t = min(dim, cap)
     while dim % t:
         t //= 2
@@ -68,16 +82,31 @@ def _tile(dim: int, cap: int) -> int:
 
 
 def plan_dispatch(expert, lo, n_held: int, block_rows: int = BLOCK_ROWS):
-    """Where each token's row is, from its expert id.
+    """Where each token-expert pair's row is, from the expert ids.
 
-    expert: [N] int32, ids over ALL experts; this shard holds
-    `lo .. lo + n_held - 1`. Returns a dict: `dst` [N] (the token's row,
-    or `rows` = out of range where its expert is held elsewhere), `src`
-    [rows] (the row's token, or N for a row of padding), `tile_expert`
-    [tiles] (local expert of each row tile), `n_tiles` [1] (tiles in use).
+    expert: [N] or [N, k] int32 (a token's k experts all differ), ids
+    over ALL experts; this shard holds `lo .. lo + n_held - 1`. Pair
+    `j * N + n` is token n's j-th expert: the pairs of one j lie
+    together, so that [k·N, d] rows of pairs split into [k, N, d] with
+    no relayout (on the TPU an array's two minor dimensions are tiled:
+    [N·k, d] -> [N, k, d] is a copy, 55 ms a step in the Nemotron cell).
+    Returns a dict: `dst` [k·N] (the pair's row, or `rows` = out of range
+    where its expert is held elsewhere), `src` [rows] (the row's pair, or
+    k·N for a row of padding), `tile_expert` [tiles] (local expert of
+    each row tile), `n_tiles` [1] (tiles in use). For [N, k] also a
+    token's rows side by side: of its k pairs at most `h = min(k,
+    n_held)` are held here, so `token_rows` [h, N] lists them (`rows`
+    where it has fewer), `token_pair` [h, N] the pair each came from
+    (k·N where none), `row_token` [rows] a row's token (N for padding)
+    and `row_place` [rows] its place in `token_rows` (h·N for padding):
+    the gathers back to tokens read h rows a token, not k (8 for 22 in
+    the Nemotron cell).
     """
+    by_pairs = expert.ndim == 2
+    k = expert.shape[1] if by_pairs else 1
+    expert = expert.T.reshape(-1) if by_pairs else expert
     n = expert.shape[0]
-    tiles = -(-n // block_rows) + n_held
+    tiles = -(-(n // k) * min(k, n_held) // block_rows) + n_held
     rows = tiles * block_rows
     local = expert - lo
     held = (local >= 0) & (local < n_held)
@@ -98,10 +127,28 @@ def plan_dispatch(expert, lo, n_held: int, block_rows: int = BLOCK_ROWS):
     tile_expert = jnp.minimum(
         jnp.searchsorted(ends, jnp.arange(tiles), side="right"), n_held - 1
     ).astype(jnp.int32)
-    return {
+    plan = {
         "dst": dst, "src": src, "tile_expert": tile_expert,
         "n_tiles": ends[-1:].astype(jnp.int32),
     }
+    if by_pairs:
+        h, tokens = min(k, n_held), n // k
+        here = held.reshape(k, tokens).astype(jnp.int32)
+        # A pair's rank among its token's held pairs; h = none (dropped).
+        slot = jnp.where(here > 0, jnp.cumsum(here, axis=0) - here, h)
+        token = jnp.broadcast_to(jnp.arange(tokens, dtype=jnp.int32), slot.shape)
+        table = lambda fill, values: jnp.full((h, tokens), fill, jnp.int32).at[
+            slot, token
+        ].set(values.reshape(k, tokens), mode="drop")
+        row_slot = jnp.take(slot.reshape(-1), src, mode="fill", fill_value=h)
+        real = row_slot < h
+        plan.update(
+            token_rows=table(rows, dst),
+            token_pair=table(n, jnp.arange(n, dtype=jnp.int32)),
+            row_token=jnp.where(real, src % tokens, tokens),
+            row_place=jnp.where(real, row_slot * tokens + src % tokens, h * tokens),
+        )
+    return plan
 
 
 @jax.custom_vjp
@@ -124,6 +171,27 @@ def _take_rows_bwd(res, g):
 
 
 take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+@jax.custom_vjp
+def spread_rows(x, row_token, token_rows):
+    """`rows[r] = x[row_token[r]]`: a token's row once for each of its
+    pairs that has one (`plan_dispatch`'s tables); zeros for a row of
+    padding. The transpose adds a token's rows up, by gathers."""
+    del token_rows
+    return jnp.take(x, row_token, axis=0, mode="fill", fill_value=0)
+
+
+def _spread_rows_fwd(x, row_token, token_rows):
+    return spread_rows(x, row_token, token_rows), token_rows
+
+
+def _spread_rows_bwd(token_rows, g):
+    mine = jnp.take(g, token_rows, axis=0, mode="fill", fill_value=0)
+    return jnp.sum(mine.astype(jnp.float32), axis=0).astype(g.dtype), None, None
+
+
+spread_rows.defvjp(_spread_rows_fwd, _spread_rows_bwd)
 
 
 # -- kernels -----------------------------------------------------------------
@@ -297,50 +365,70 @@ grouped_matmul.defvjp(_grouped_fwd, _grouped_bwd)
 
 
 def expert_mlp(
-    x, expert, gate, w_gate, w_up, w_down, lo, *,
+    x, expert, gate, weights, lo, *,
     block_rows: int = BLOCK_ROWS, interpret: bool | None = None,
 ):
     """What the experts held here add for the tokens routed to them.
 
-    x: [N, d] tokens; expert: [N] int32 over all experts; gate: [N]; the
-    weights of the `w_gate.shape[0]` experts from `lo` on. Returns [N, d]:
-    `gate * (silu(x @ w_gate[e]) * (x @ w_up[e])) @ w_down[e]` for a token
-    whose expert e is held, zeros for the others.
+    x: [N, d] tokens; expert: [N] or [N, k] int32 over all experts; gate:
+    the same shape; `weights` of the `weights[0].shape[0]` experts from
+    `lo` on, `(w_gate, w_up, w_down)` for a gated expert, `silu(x @
+    w_gate[e]) * (x @ w_up[e]) @ w_down[e]`, or `(w_in, w_out)` for
+    `relu(x @ w_in[e])^2 @ w_out[e]`. Returns [N, d]: the sum over a
+    token's experts e that are held of `gate * expert_e(x)`, zeros for a
+    token with none.
     """
     if interpret is None:
         interpret = not kernels_compiled()
-    n_held = w_gate.shape[0]
+    n_held = weights[0].shape[0]
+    one = expert.ndim == 1  # a row a token: the gathers are each other's inverse
     with jax.named_scope("moe.dispatch"):
         plan = plan_dispatch(expert, lo, n_held, block_rows)
-        rows = take_rows(x, plan["src"], plan["dst"])
+        if one:
+            rows = take_rows(x, plan["src"], plan["dst"])
+        else:
+            rows = spread_rows(x, plan["row_token"], plan["token_rows"])
     mm = lambda a, w: grouped_matmul(
         a, w, plan["tile_expert"], plan["n_tiles"], block_rows, interpret
     )
     with jax.named_scope("moe.experts"):
-        hidden = jax.nn.silu(mm(rows, w_gate)) * mm(rows, w_up)
+        if len(weights) == 3:
+            w_gate, w_up, w_down = weights
+            hidden = jax.nn.silu(mm(rows, w_gate)) * mm(rows, w_up)
+        else:
+            w_in, w_down = weights
+            hidden = jnp.square(jax.nn.relu(mm(rows, w_in)))
         out = mm(hidden, w_down)
     with jax.named_scope("moe.combine"):
-        back = take_rows(out, plan["dst"], plan["src"])
-        return (back * gate[:, None]).astype(x.dtype)
+        if one:
+            back = take_rows(out, plan["dst"], plan["src"])
+            return (back * gate[:, None]).astype(x.dtype)
+        back = take_rows(out, plan["token_rows"].reshape(-1), plan["row_place"])
+        back = back.reshape(*plan["token_rows"].shape, back.shape[-1])
+        weight = jnp.take(
+            gate.T.reshape(-1), plan["token_pair"], mode="fill", fill_value=0
+        )
+        return jnp.sum(back * weight[:, :, None], axis=0).astype(x.dtype)
 
 
 def expert_mlp_on_mesh(mesh: Mesh | None, x, expert, gate, weights, first: int):
     """`expert_mlp` for x [B, S, d] on a mesh (or off it, `mesh` None):
-    `weights` = (w_gate, w_up, w_down) of the experts held from `first`
-    on. Tokens stay where the batch and `sp` axes put them; every `ep`
-    shard holds a run of the experts and adds their part for the tokens
-    routed to them, every `tp` shard a slice of each expert's width, and
+    `weights` of the experts held from `first` on (three matrices an
+    expert or two, as `expert_mlp` takes them); `expert` and `gate`
+    [B, S] or [B, S, k]. Tokens stay where the batch and `sp` axes put
+    them; every `ep` shard holds a run of the experts and adds their
+    part for the tokens routed to them, every `tp` shard a slice of each expert's width, and
     the partial results are summed over both. One shard: no exchange."""
 
-    def local(x, expert, gate, w_gate, w_up, w_down, lo):
+    def local(x, expert, gate, weights, lo):
+        pairs = lambda u: u.reshape(-1, *u.shape[2:])
         out = expert_mlp(
-            x.reshape(-1, x.shape[-1]), expert.reshape(-1), gate.reshape(-1),
-            w_gate, w_up, w_down, lo,
+            x.reshape(-1, x.shape[-1]), pairs(expert), pairs(gate), weights, lo
         )
         return out.reshape(x.shape)
 
     if mesh is None:
-        return local(x, expert, gate, *weights, first)
+        return local(x, expert, gate, weights, first)
     size = lambda a: mesh.shape.get(a, 1)
     batch = batch_axes(mesh)
     rows = 1
@@ -359,20 +447,21 @@ def expert_mlp_on_mesh(mesh: Mesh | None, x, expert, gate, weights, first: int):
     seq, ep, tp = (a if size(a) > 1 else None for a in ("sp", "ep", "tp"))
     partial_over = tuple(a for a in (ep, tp) if a)
 
-    def shard(x, expert, gate, w_gate, w_up, w_down):
+    def shard(x, expert, gate, *weights):
         lo = first
         if ep:
-            lo = first + lax.axis_index("ep") * w_gate.shape[0]
-        out = local(x, expert, gate, w_gate, w_up, w_down, lo)
+            lo = first + lax.axis_index("ep") * weights[0].shape[0]
+        out = local(x, expert, gate, weights, lo)
         return lax.psum(out, partial_over) if partial_over else out
 
-    tokens = P(batch, seq)
+    tokens = P(batch, seq, *([None] * (expert.ndim - 2)))
+    into, out_of = P(ep, None, tp), P(ep, tp, None)
     return jax.shard_map(
         shard,
         mesh=mesh,
         in_specs=(
             P(batch, seq, None), tokens, tokens,
-            P(ep, None, tp), P(ep, None, tp), P(ep, tp, None),
+            *([into] * (len(weights) - 1)), out_of,
         ),
         out_specs=P(batch, seq, None),
         check_vma=False,

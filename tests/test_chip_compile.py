@@ -145,9 +145,11 @@ def test_grouped_head_flash_compiles_at_the_zaya_cell_shape(one_chip):
 
 
 # (B, S, H, Hkv) of a layer's attention in each cell: `olmo-1b-cut.train-2k`,
-# `-8k`, `zaya1-8b-ep2.train-8k`, and a shard of `olmo-1b.train-2k-dp2tp2`.
+# `-8k`, `zaya1-8b-ep2.train-8k`, a shard of `olmo-1b.train-2k-dp2tp2`, and
+# `nemotron-3-super-tp2ep64.train-8k`'s 16 query heads over one K/V head.
 CELL_SHAPES = [
-    (8, 2048, 16, 16), (2, 8192, 16, 16), (2, 8192, 8, 2), (4, 2048, 8, 8)
+    (8, 2048, 16, 16), (2, 8192, 16, 16), (2, 8192, 8, 2), (4, 2048, 8, 8),
+    (1, 8192, 16, 1),
 ]
 
 
@@ -210,7 +212,7 @@ def test_grouped_matmul_kernels_compile_at_the_zaya_cell_shape(
     weight = shape((8, 2048, 2048), jnp.float32)
 
     def loss(x, gate, w_gate, w_up, w_down, expert):
-        out = moe.expert_mlp(x, expert, gate, w_gate, w_up, w_down, 0)
+        out = moe.expert_mlp(x, expert, gate, (w_gate, w_up, w_down), 0)
         return out.astype(jnp.float32).sum()
 
     text, names = _compile(
@@ -220,6 +222,60 @@ def test_grouped_matmul_kernels_compile_at_the_zaya_cell_shape(
     )
     assert sorted(set(names)) == ["moe_gmm_dlhs", "moe_gmm_dw", "moe_gmm_fwd"]
     assert len(names) == 9 == tpu_kernel_calls(text)
+
+
+def test_latent_top_k_expert_kernels_compile_at_the_nemotron_cell_shape(
+    one_chip, monkeypatch
+):
+    """The latent expert layer's kernels at `nemotron-3-super-tp2ep64
+    .train-8k`'s size: 8,192 tokens with 22 experts each of 512, 8 held,
+    two matrices an expert of 1024 x 2688 (21 lane columns: tiles of 896,
+    `moe._tile`), float32 weights, bfloat16 rows."""
+    from kubeflow_tpu.ops import moe
+
+    monkeypatch.setattr(moe, "kernels_compiled", lambda: True)
+    assert moe._tile(2688, moe._TILE_COLS) == 896 == moe._tile(2688, moe._TILE_DW_ROWS)
+    assert moe._tile(2048, moe._TILE_COLS) == 2048  # zaya's choice stands
+    shape = lambda dims, dtype: jax.ShapeDtypeStruct(
+        dims, dtype, sharding=one_chip
+    )
+
+    def loss(x, gate, w_in, w_down, expert):
+        out = moe.expert_mlp(x, expert, gate, (w_in, w_down), 0)
+        return out.astype(jnp.float32).sum()
+
+    text, names = _compile(
+        jax.grad(loss, argnums=(0, 1, 2, 3)),
+        shape((8192, 1024), jnp.bfloat16), shape((8192, 22), jnp.float32),
+        shape((8, 1024, 2688), jnp.float32), shape((8, 2688, 1024), jnp.float32),
+        shape((8192, 22), jnp.int32),
+    )
+    assert sorted(set(names)) == ["moe_gmm_dlhs", "moe_gmm_dw", "moe_gmm_fwd"]
+    assert len(names) == 6 == tpu_kernel_calls(text)
+
+
+def test_scan_kernels_compile_at_the_nemotron_cell_shape(one_chip):
+    """The chunked state-space scan, forward and backward, at the cell's
+    size: one sequence of 8,192, 64 heads of 64 in 4 groups, a state of
+    128, chunks of 128 (`ops/ssd.py`)."""
+    from kubeflow_tpu.ops import ssd
+
+    shape = lambda dims, dtype: jax.ShapeDtypeStruct(
+        dims, dtype, sharding=one_chip
+    )
+    x = shape((1, 8192, 64 * 64), jnp.bfloat16)
+    bc = shape((1, 8192, 4 * 128), jnp.bfloat16)
+
+    def loss(x, dt, a, b, c):
+        y = ssd.ssd_scan(x, dt, a, b, c, groups=4, chunk=128, interpret=False)
+        return y.astype(jnp.float32).sum()
+
+    text, names = _compile(
+        jax.grad(loss, argnums=(0, 1, 2, 3, 4)), x,
+        shape((1, 8192, 64), jnp.float32), shape((64,), jnp.float32), bc, bc,
+    )
+    assert names == ["ssd_fwd", "ssd_bwd"]
+    assert tpu_kernel_calls(text) == 2
 
 
 def test_sub_1024_blocks_select_the_replicated_lse_and_compile(one_chip):

@@ -207,7 +207,7 @@ def test_expert_mlp_matches_a_dense_loop_at_any_imbalance():
     }
     for name, (expert, lo, held) in cases.items():
         mine = tuple(m[lo:lo + held] for m in w)
-        ours = lambda *a: moe.expert_mlp(a[0], expert, *a[1:], lo, block_rows=16)
+        ours = lambda *a: moe.expert_mlp(a[0], expert, a[1], a[2:], lo, block_rows=16)
         theirs = lambda *a: dense(*a, expert, lo)
         np.testing.assert_allclose(
             ours(x, gate, *mine), theirs(x, gate, *mine), atol=1e-5, err_msg=name
