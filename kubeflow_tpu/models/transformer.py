@@ -33,7 +33,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from kubeflow_tpu.ops.attention import attend
 from kubeflow_tpu.ops.flash import CHECKPOINT_LSE_NAME, CHECKPOINT_OUT_NAME
-from kubeflow_tpu.ops.moe import expert_mlp_on_mesh
+from kubeflow_tpu.ops.moe import expert_mlp_on_mesh, row_tiles, tiles_in_use
 from kubeflow_tpu.ops.rope import rope
 from kubeflow_tpu.ops.ssd import (
     CHECKPOINT_OUT_NAME as SSD_OUT_NAME,
@@ -443,13 +443,19 @@ class ExpertLayer(nn.Module):
     held experts have the logical axis "expert" (-> `ep`): on a mesh every
     `ep` shard computes what its own experts add for the tokens routed to
     them and the shards' partial results are summed
-    (`ops/moe.expert_mlp_on_mesh`: rows ordered by expert, grouped
-    matmuls); with one shard there is no exchange. Sows, under
-    "counters": `moe_tokens_held`, `moe_load_max`, `moe_load_mean`, which
-    count ROWS, token-expert pairs routed to a held expert (in all, on
-    the fullest held expert, the mean over them): tokens at one expert a
-    token, k times as many at most at k; and under "intermediates"
-    `expert`, each token's choice.
+    (`ops/moe.expert_mlp_on_mesh`: rows ordered by expert in a buffer
+    sized for the worst case, of which every pass touches the row tiles
+    in use and no more; the form of the expert, `mlp_act`, and
+    `experts_per_token` pick the kernels, `ops/moe.py`'s docstring); with
+    one shard there is no exchange. Sows, under "counters":
+    `moe_tokens_held`, `moe_load_max`, `moe_load_mean`, which count ROWS,
+    token-expert pairs routed to a held expert (in all, on the fullest
+    held expert, the mean over them): tokens at one expert a token, k
+    times as many at most at k; `moe_row_tiles`, the row tiles of that
+    buffer, and `moe_row_tiles_live`, those in use (both as one shard
+    holding all of `experts_held` would count them): their ratio is the
+    share of the buffer the layer's passes touch; and under
+    "intermediates" `expert`, each token's choice.
     """
 
     config: TransformerConfig
@@ -564,6 +570,11 @@ class ExpertLayer(nn.Module):
             ("moe_tokens_held", jnp.sum(load)),
             ("moe_load_max", jnp.max(load)),
             ("moe_load_mean", jnp.mean(load)),
+            ("moe_row_tiles", float(row_tiles(
+                expert.size // cfg.experts_per_token, cfg.experts_per_token,
+                held,
+            ))),
+            ("moe_row_tiles_live", jnp.sum(tiles_in_use(load))),
         ):
             self.sow(
                 "counters", name, value,

@@ -4,49 +4,80 @@ What `models/transformer.ExpertLayer` runs on the experts it holds: a
 token has one row for each of its k experts; the rows are ordered by
 expert into a row buffer, grouped matmuls (the expert MLP: gated, three
 matrices, or `relu(.)^2`, two) run over it, and each token's rows are
-gathered back and added up by their weights. No capacity and no dropped
-token at any imbalance; the work follows the rows routed here, not
-`experts x capacity`.
+brought back and added up by their weights. No capacity and no dropped
+token at any imbalance. The buffer is sized for the worst case, and
+`n_tiles`, the count of row tiles in use that the plan carries, bounds
+every pass over it: the work follows the rows routed here, not `experts
+x capacity` and not the buffer's length.
 
 - **The plan** (`plan_dispatch`). Each held expert's tokens occupy a run
   of whole row tiles (`block_rows` rows; an expert with no token still
   gets one tile of zero rows, so every expert's weight gradient is
-  written). The buffer is sized for the worst case: a token's k experts
-  differ, so at most `min(k, experts)` of its rows are held here, and
-  `ceil(N min(k, experts) / block_rows) + experts` tiles hold them;
-  `tile_expert[t]` names tile t's expert and `n_tiles` how many tiles
-  are in use. Pairs routed to experts held elsewhere get no row. A tile never straddles two experts, so the kernels need no
-  masks: a grouped matmul is a tiled matmul whose weight block is picked
-  by a scalar-prefetched table.
+  written: `tiles_in_use`). The buffer is sized for the worst case
+  (`row_tiles`): a token's k experts differ, so at most `min(k,
+  experts)` of its rows are held here, and `ceil(N min(k, experts) /
+  block_rows) + experts` tiles hold them; `tile_expert[t]` names tile
+  t's expert and `n_tiles` how many tiles are in use. Pairs routed to
+  experts held elsewhere get no row. A tile never straddles two experts,
+  so the kernels need no masks: a grouped matmul is a tiled matmul whose
+  weight block is picked by a scalar-prefetched table.
 - **`moe_gmm_fwd` / `moe_gmm_dlhs`**: `out[rows of e] = lhs[rows of e] @
   w[e]` (or `@ w[e].T` for the operand's gradient). Grid (column tiles,
   row tiles, contraction tiles); consecutive row tiles of one expert keep
   the weight block's index, so an expert's weights are fetched once a
-  column tile. Tiles past `n_tiles` cost a grid step, no matmul and no
-  fetch (their indices are clamped to the last tile in use), and write
-  zeros.
+  column tile. A tile past `n_tiles` costs a grid step and nothing else:
+  no matmul, no fetch and no write (every index is clamped to the last
+  tile in use, whose output block stays resident and is written back
+  once). The two-matrix expert's `relu(.)^2` is the first matmul's
+  epilogue, on the float32 accumulator (it also leaves the
+  pre-activation z), and its derivative `2 relu(z) dh` is formed in VMEM
+  by the `dlhs` and `dw` calls that consume it.
 - **`moe_gmm_dw`**: `dw[e] = lhs[rows of e].T @ g[rows of e]`, accumulated
   in float32 over an expert's tiles and written once.
-- **`take_rows`**: a row gather whose transpose is the inverse gather
-  (a row names one token-expert pair and a pair at most one row), so
-  neither direction scatters. **`spread_rows`** is the dispatch at k > 1,
-  `rows[r] = x[token of r]`: its transpose is the sum of a token's
-  gathers. The combine is `take_rows` the other way, then the weighted
-  sum over a token's rows. Both read `min(k, experts held)` rows a
-  token, the most it can have here (`plan_dispatch`'s `token_rows`).
+- **The rows' movers.** With one expert a token (`expert` [N]) the
+  dispatch and the combine are XLA gathers that are each other's inverse
+  (a row names one token and a token at most one row), over a buffer that
+  is half in use. With k > 1 (`expert` [N, k]) they are two kernels that
+  stop at the tiles in use: `moe_rows_take`, row side, `out[r] = scale[r]
+  x[row_token[r]]` by one DMA a row from an operand left in HBM (the
+  dispatch; the combine's transpose with the row's weight as `scale`,
+  which also forms the weights' gradient `<rows[r], g[row_token[r]]>`),
+  and `moe_rows_sum`, token side, `y[n] = sum_j w[j, n]
+  rows[token_rows[j, n]]` in float32 over the slots a token has (the
+  combine; the dispatch's transpose with no weights); `[h, N, d]` is
+  never materialised. What a DMA fetches a row at a time is kept
+  *packed* (`_Packed`): `[M, d]` float32 as `[8 M, 128]` at d = 1024, a
+  row in whole tiles of sublanes (a one-row slice of a tiled array is
+  not a legal DMA); the matmul before a `moe_rows_sum` writes its result
+  that way.
 
-`grouped_matmul` ties the three kernels together with a `custom_vjp`; the
-weights go in at their own dtype (float32 parameters) and are cast for
-the kernels in the forward and again in the backward, so no bfloat16 copy
-of an expert's weights is kept as a residual. On the CPU backend the
-kernels run under the Pallas interpreter (tests); anywhere else they are
-compiled (`ops/flash.kernels_compiled`). Every `pallas_call` has a
-`name=` starting `moe_gmm_`: what a device trace keys their time on.
+**Who may read what.** A result of a `moe_gmm_fwd` / `moe_gmm_dlhs` /
+`moe_rows_take` call holds unwritten rows past `n_tiles` unless it was
+asked to write zeros there (`zero_dead`). Only the kernels here and
+gathers by the plan's indices (which name rows in use only) read such a
+result; where an XLA pass over the whole buffer reads one (the gated
+expert's `silu(a) b` and its derivative, the sum of its two operand
+gradients), the kernel keeps writing zeros. The form of the expert picks
+that (`len(weights)`), the rank of `expert` picks the movers; nothing
+else does.
+
+`_experts_in` (dispatch, then the matmuls that read the rows) and
+`_experts_out` (the last matmul, then the combine) tie the kernels
+together with a `custom_vjp` each; the weights go in at their own dtype
+(float32 parameters) and are cast for the kernels in the forward and
+again in the backward, so no bfloat16 copy of an expert's weights is kept
+as a residual. On the CPU backend the kernels run under the Pallas
+interpreter (tests), which fills unwritten memory with NaN; anywhere else
+they are compiled (`ops/flash.kernels_compiled`). Every `pallas_call` has
+a `name=` starting `moe_gmm_` or `moe_rows_`: what a device trace keys
+their time on.
 """
 
 from __future__ import annotations
 
 import functools
+import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -60,11 +91,16 @@ from kubeflow_tpu.parallel.sharding import batch_axes
 
 BLOCK_ROWS = 256
 # Tile caps: a weight block of 2048 x 2048 bf16 is 8 MiB (16 double-
-# buffered), which keeps an expert of this width to one fetch a pass.
-_TILE_CONTRACT = 2048
-_TILE_COLS = 2048
-_TILE_DW_ROWS = 1024  # dw's float32 accumulator is (this, _TILE_COLS)
+# buffered), which keeps an expert of this width to one fetch a pass; a
+# side may be as long as 4096 where the other is short (1024 x 2688: one
+# block an expert, one grid step a row tile).
+_TILE_SIDE = 4096
+_TILE_WEIGHT = 2048 * 2048
+_TILE_DW = 3 * 1024 * 1024  # elements of dw's float32 accumulator
 _VMEM_LIMIT = 48 * 1024 * 1024
+_SUBLANES = 8  # a float32 tile's height: the least a DMA may slice
+_SUM_TOKENS = 256  # tokens a grid step of `moe_rows_sum`
+_SUM_VMEM = 8 * 1024 * 1024  # its gathered rows, [slots, tokens, d] float32
 
 
 def _tile(dim: int, cap: int) -> int:
@@ -81,147 +117,250 @@ def _tile(dim: int, cap: int) -> int:
     return t
 
 
+def _gmm_tiles(contract: int, cols: int, packed: bool = False) -> tuple[int, int]:
+    """(contraction tile, column tile) of a grouped matmul; a packed
+    result is a whole row wide."""
+    to = cols if packed else _tile(cols, _TILE_SIDE)
+    return _tile(contract, min(_TILE_SIDE, max(128, _TILE_WEIGHT // to))), to
+
+
+def row_tiles(tokens: int, k: int, n_held: int, block_rows: int = BLOCK_ROWS) -> int:
+    """Row tiles of the buffer for `tokens` tokens of k experts each over
+    `n_held` held experts: the worst case, static."""
+    return -(-tokens * min(k, n_held) // block_rows) + n_held
+
+
+def tiles_in_use(counts, block_rows: int = BLOCK_ROWS):
+    """Row tiles each held expert occupies, from the rows routed to it
+    (`counts` [n_held]): whole tiles, and one for an expert with none.
+    Their sum is `n_tiles`."""
+    return jnp.maximum(-(-counts // block_rows), 1)
+
+
 def plan_dispatch(expert, lo, n_held: int, block_rows: int = BLOCK_ROWS):
     """Where each token-expert pair's row is, from the expert ids.
 
     expert: [N] or [N, k] int32 (a token's k experts all differ), ids
-    over ALL experts; this shard holds `lo .. lo + n_held - 1`. Pair
-    `j * N + n` is token n's j-th expert: the pairs of one j lie
-    together, so that [k·N, d] rows of pairs split into [k, N, d] with
-    no relayout (on the TPU an array's two minor dimensions are tiled:
-    [N·k, d] -> [N, k, d] is a copy, 55 ms a step in the Nemotron cell).
-    Returns a dict: `dst` [k·N] (the pair's row, or `rows` = out of range
-    where its expert is held elsewhere), `src` [rows] (the row's pair, or
-    k·N for a row of padding), `tile_expert` [tiles] (local expert of
-    each row tile), `n_tiles` [1] (tiles in use). For [N, k] also a
-    token's rows side by side: of its k pairs at most `h = min(k,
-    n_held)` are held here, so `token_rows` [h, N] lists them (`rows`
-    where it has fewer), `token_pair` [h, N] the pair each came from
-    (k·N where none), `row_token` [rows] a row's token (N for padding)
-    and `row_place` [rows] its place in `token_rows` (h·N for padding):
-    the gathers back to tokens read h rows a token, not k (8 for 22 in
-    the Nemotron cell).
+    over ALL experts; this shard holds `lo .. lo + n_held - 1`. An
+    expert's rows are in the order of their tokens. Everything comes from
+    `mine` [n_held, N] (is token n routed to held expert e) by cumulative
+    sums and selects over [n_held, N]; the one scatter is of a row's
+    token. Returns a dict: `tile_expert` [tiles] (local expert of each
+    row tile), `n_tiles` [1] (tiles in use), and for [N]: `dst` [N] (the
+    token's row, or `rows` = out of range where its expert is held
+    elsewhere), `src` [rows] (the row's token, or N for a row of
+    padding). For [N, k] a token's rows side by side: of its k pairs at
+    most `h = min(k, n_held)` are held here, in the order of their
+    experts, so `token_rows` [h, N] lists them (`rows` where it has
+    fewer), `token_count` [N] says how many it has, `row_token` [rows] is
+    a row's token (N for padding), and `hit` [k, n_held, N] (pair j of
+    token n is held expert e) with `place` [h, n_held, N] (held expert e
+    is token n's slot s) carry a pair's weight to its slot
+    (`slot_weights`): the movers read h slots a token, not k (8 for 22
+    in the Nemotron cell).
     """
     by_pairs = expert.ndim == 2
-    k = expert.shape[1] if by_pairs else 1
-    expert = expert.T.reshape(-1) if by_pairs else expert
-    n = expert.shape[0]
-    tiles = -(-(n // k) * min(k, n_held) // block_rows) + n_held
+    pairs = expert.T if by_pairs else expert[None, :]
+    k, tokens = pairs.shape
+    tiles = row_tiles(tokens, k, n_held, block_rows)
     rows = tiles * block_rows
-    local = expert - lo
-    held = (local >= 0) & (local < n_held)
-    onehot = (
-        local[:, None] == jnp.arange(n_held, dtype=local.dtype)[None, :]
-    ).astype(jnp.int32)
-    running = jnp.cumsum(onehot, axis=0)
-    counts = running[-1]
-    safe = jnp.clip(local, 0, n_held - 1)
-    rank = jnp.take_along_axis(running, safe[:, None], axis=1)[:, 0] - 1
-    group_tiles = jnp.maximum(-(-counts // block_rows), 1)
+    hit = (pairs - lo)[:, None, :] == jnp.arange(
+        n_held, dtype=pairs.dtype
+    )[None, :, None]
+    mine = jnp.any(hit, axis=0)
+    ones = mine.astype(jnp.int32)
+    group_tiles = tiles_in_use(jnp.sum(ones, axis=1), block_rows)
     ends = jnp.cumsum(group_tiles)
     first_row = (ends - group_tiles) * block_rows
-    dst = jnp.where(held, first_row[safe] + rank, rows).astype(jnp.int32)
-    src = jnp.full((rows,), n, jnp.int32).at[dst].set(
-        jnp.arange(n, dtype=jnp.int32), mode="drop"
-    )
+    row = jnp.where(
+        mine, first_row[:, None] + jnp.cumsum(ones, axis=1) - 1, rows
+    ).astype(jnp.int32)
+    token = jnp.arange(tokens, dtype=jnp.int32)
     tile_expert = jnp.minimum(
         jnp.searchsorted(ends, jnp.arange(tiles), side="right"), n_held - 1
     ).astype(jnp.int32)
-    plan = {
-        "dst": dst, "src": src, "tile_expert": tile_expert,
-        "n_tiles": ends[-1:].astype(jnp.int32),
-    }
-    if by_pairs:
-        h, tokens = min(k, n_held), n // k
-        here = held.reshape(k, tokens).astype(jnp.int32)
-        # A pair's rank among its token's held pairs; h = none (dropped).
-        slot = jnp.where(here > 0, jnp.cumsum(here, axis=0) - here, h)
-        token = jnp.broadcast_to(jnp.arange(tokens, dtype=jnp.int32), slot.shape)
-        table = lambda fill, values: jnp.full((h, tokens), fill, jnp.int32).at[
-            slot, token
-        ].set(values.reshape(k, tokens), mode="drop")
-        row_slot = jnp.take(slot.reshape(-1), src, mode="fill", fill_value=h)
-        real = row_slot < h
-        plan.update(
-            token_rows=table(rows, dst),
-            token_pair=table(n, jnp.arange(n, dtype=jnp.int32)),
-            row_token=jnp.where(real, src % tokens, tokens),
-            row_place=jnp.where(real, row_slot * tokens + src % tokens, h * tokens),
-        )
+    plan = {"tile_expert": tile_expert, "n_tiles": ends[-1:].astype(jnp.int32)}
+    row_token = lambda at, of: jnp.full((rows,), tokens, jnp.int32).at[at].set(
+        of, mode="drop"
+    )
+    if not by_pairs:
+        dst = jnp.min(row, axis=0)
+        plan.update(dst=dst, src=row_token(dst, token))
+        return plan
+    h = min(k, n_held)
+    slot = jnp.cumsum(ones, axis=0) - 1
+    place = mine[None] & (
+        slot[None] == jnp.arange(h, dtype=jnp.int32)[:, None, None]
+    )
+    plan.update(
+        hit=hit, place=place,
+        token_rows=jnp.min(jnp.where(place, row[None], rows), axis=1),
+        token_count=jnp.sum(ones, axis=0),
+        row_token=row_token(row.reshape(-1), jnp.tile(token, n_held)),
+    )
     return plan
 
 
-@jax.custom_vjp
-def take_rows(x, index, inverse):
-    """`out[r] = x[index[r]]`, zeros where `index[r]` is out of range.
-    `inverse[n]` is the r with `index[r] == n` (out of range where there
-    is none; `index` names no row twice), which makes the transpose the
-    same gather the other way round."""
-    del inverse
-    return jnp.take(x, index, axis=0, mode="fill", fill_value=0)
+def slot_weights(gate, plan):
+    """`gate` [N, k] by a token's slots, [h, N] (zeros where it has
+    fewer): selects and sums, so its transpose is too."""
+    held = jnp.sum(jnp.where(plan["hit"], gate.T[:, None, :], 0.0), axis=0)
+    return jnp.sum(jnp.where(plan["place"], held[None], 0.0), axis=1)
 
 
-def _take_rows_fwd(x, index, inverse):
-    return take_rows(x, index, inverse), (index, inverse)
-
-
-def _take_rows_bwd(res, g):
-    index, inverse = res
-    return take_rows(g, inverse, index), None, None
-
-
-take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
-
-
-@jax.custom_vjp
-def spread_rows(x, row_token, token_rows):
-    """`rows[r] = x[row_token[r]]`: a token's row once for each of its
-    pairs that has one (`plan_dispatch`'s tables); zeros for a row of
-    padding. The transpose adds a token's rows up, by gathers."""
-    del token_rows
-    return jnp.take(x, row_token, axis=0, mode="fill", fill_value=0)
-
-
-def _spread_rows_fwd(x, row_token, token_rows):
-    return spread_rows(x, row_token, token_rows), token_rows
-
-
-def _spread_rows_bwd(token_rows, g):
-    mine = jnp.take(g, token_rows, axis=0, mode="fill", fill_value=0)
-    return jnp.sum(mine.astype(jnp.float32), axis=0).astype(g.dtype), None, None
-
-
-spread_rows.defvjp(_spread_rows_fwd, _spread_rows_bwd)
+def moe_schedule(
+    tokens: int, k: int, held: int, width_in: int, width_out: int, *,
+    live_tiles: int | None = None, block_rows: int = BLOCK_ROWS,
+) -> dict:
+    """What one `moe_gmm_fwd` call of `width_in` -> `width_out` and the
+    rows' movers touch, from the shapes and the count of tiles in use
+    (all of them where `live_tiles` is None): static, for tests and for
+    reading a trace. `*_bytes` count a row tile's operands and results
+    once at 2 bytes (4 where packed) and every held expert's weights once
+    a column tile."""
+    tiles = row_tiles(tokens, k, held, block_rows)
+    live = tiles if live_tiles is None else live_tiles
+    tc, to = _gmm_tiles(width_in, width_out)
+    grid = (width_out // to, tiles, width_in // tc)
+    weights = 2 * held * width_in * width_out
+    rows = lambda t: t * block_rows
+    return {
+        "tiles": tiles, "rows": rows(tiles),
+        "live_tiles": live, "rows_touched": rows(live),
+        "gmm_grid": grid, "gmm_grid_steps": math.prod(grid),
+        "gmm_dead_steps": grid[0] * (tiles - live) * grid[2],
+        "gmm_bytes": weights + 2 * rows(live) * (width_in + width_out),
+        "gmm_bytes_zeroing": weights + 2 * rows(live) * width_in
+        + 2 * rows(tiles) * width_out,
+        "movers": "moe_rows" if k > 1 else "xla_gather",
+        "rows_take_grid_steps": tiles,
+        "rows_take_bytes": rows(live) * width_in * (4 + 2),
+        "rows_sum_grid_steps": tokens // _sum_tokens(tokens, min(k, held), width_in),
+        "rows_sum_bytes": rows(live) * width_in * 4 + tokens * width_in * 2,
+    }
 
 
 # -- kernels -----------------------------------------------------------------
 
 
-def _gmm_kernel(te_ref, nt_ref, lhs_ref, rhs_ref, out_ref, acc, *,
-                transpose_rhs: bool):
+def _params(semantics):
+    return pltpu.CompilerParams(
+        dimension_semantics=semantics, vmem_limit_bytes=_VMEM_LIMIT
+    )
+
+
+def _live(t, nt):
+    """Tiles past the ones in use re-address the last one: no fetch, and
+    an output block that stays where it is."""
+    return jnp.minimum(t, nt[0] - 1)
+
+
+def _live_step(t, c, steps, nt):
+    """The contraction block of step c: the last one on a tile past the
+    ones in use, which is where the last tile in use left off."""
+    return jnp.where(t < nt[0], c, steps - 1)
+
+
+def _relu2_slope(g, z):
+    """`g * d relu(z)^2 / dz` in float32, rounded once to g's dtype."""
+    slope = 2.0 * jnp.maximum(z.astype(jnp.float32), 0.0)
+    return (g.astype(jnp.float32) * slope).astype(g.dtype)
+
+
+class _Packed(NamedTuple):
+    """How rows of width d lie *packed*, `[M, d]` float32 as `[M sublanes,
+    lanes]`: a row is `pieces` runs of `lanes` (128 where d is made of
+    them, the strided loads' need; else all of d, which only the
+    interpreter takes), one a sublane, in `sublanes` sublanes: whole
+    float32 tiles, which a DMA may slice from a tiled array where a
+    single row it may not; those past `pieces` are never read."""
+
+    lanes: int
+    pieces: int
+    sublanes: int
+
+    @classmethod
+    def of(cls, d: int) -> "_Packed":
+        lanes = 128 if d % 128 == 0 else d
+        pieces = d // lanes
+        return cls(lanes, pieces, -(-pieces // _SUBLANES) * _SUBLANES)
+
+    def shape(self, m: int) -> tuple[int, int]:
+        return (m * self.sublanes, self.lanes)
+
+    def piece(self, ref, s: int, rows: int):
+        """Piece s of each of a packed block's `rows` rows: [rows, lanes]."""
+        return ref[pl.ds(s, rows, stride=self.sublanes), :]
+
+    def fetch(self, src_hbm, row, buf, place, sem):
+        """The DMA of packed row `row` of `src_hbm` to place `place` of
+        `buf`."""
+        at = lambda i: pl.ds(
+            pl.multiple_of(i * self.sublanes, self.sublanes), self.sublanes
+        )
+        return pltpu.make_async_copy(src_hbm.at[at(row)], buf.at[at(place)], sem)
+
+    def wait(self, src_hbm, buf, sem, count):
+        """Until `count` fetched rows have landed."""
+        def one(_, carry):
+            self.fetch(src_hbm, 0, buf, 0, sem).wait()
+            return carry
+
+        lax.fori_loop(0, count, one, 0)
+
+    def store(self, ref, value):
+        """value [R, d] into a packed block."""
+        for s in range(self.pieces):
+            ref[pl.ds(s, value.shape[0], stride=self.sublanes), :] = value[
+                :, s * self.lanes:(s + 1) * self.lanes
+            ].astype(ref.dtype)
+
+
+def _gmm_kernel(te_ref, nt_ref, *refs, transpose_rhs: bool, relu2: bool,
+                slope: bool, zero_dead: bool, packed: bool):
     del te_ref
+    lhs_ref, rhs_ref, *refs = refs
+    z_ref = refs.pop(0) if slope else None
+    out_ref, *refs = refs
+    pre_ref = refs.pop(0) if relu2 else None
+    (acc,) = refs
     t, c = pl.program_id(1), pl.program_id(2)
+    live = t < nt_ref[0]
 
     @pl.when(c == 0)
     def _init():
         acc[...] = jnp.zeros_like(acc)
 
-    @pl.when(t < nt_ref[0])
+    @pl.when(live)
     def _compute():
         dims = (((1,), (1,)), ((), ())) if transpose_rhs else (
             ((1,), (0,)), ((), ())
         )
+        lhs = lhs_ref[...]
+        if slope:
+            lhs = _relu2_slope(lhs, z_ref[...])
         acc[...] += lax.dot_general(
-            lhs_ref[...], rhs_ref[0], dims,
-            preferred_element_type=jnp.float32,
+            lhs, rhs_ref[0], dims, preferred_element_type=jnp.float32,
         )
 
-    @pl.when(c == pl.num_programs(2) - 1)
+    last = c == pl.num_programs(2) - 1
+
+    @pl.when(last if zero_dead else last & live)
     def _write():
-        out_ref[...] = acc[...].astype(out_ref.dtype)
+        value = acc[...]
+        if relu2:
+            pre_ref[...] = value.astype(pre_ref.dtype)
+            value = jnp.square(jnp.maximum(value, 0.0))
+        if packed:
+            _Packed.of(value.shape[1]).store(out_ref, value)
+        else:
+            out_ref[...] = value.astype(out_ref.dtype)
 
 
-def _dw_kernel(te_ref, nt_ref, lhs_ref, g_ref, out_ref, acc):
+def _dw_kernel(te_ref, nt_ref, *refs, slope: bool):
+    lhs_ref, g_ref, *refs = refs
+    z_ref = refs.pop(0) if slope else None
+    out_ref, acc = refs
     t = pl.program_id(2)
     n_tiles = nt_ref[0]
     e = te_ref[t]
@@ -237,8 +376,11 @@ def _dw_kernel(te_ref, nt_ref, lhs_ref, g_ref, out_ref, acc):
 
     @pl.when(used)
     def _compute():
+        g = g_ref[...]
+        if slope:
+            g = _relu2_slope(g, z_ref[...])
         acc[...] += lax.dot_general(
-            lhs_ref[...], g_ref[...], (((0,), (0,)), ((), ())),
+            lhs_ref[...], g, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
@@ -247,81 +389,102 @@ def _dw_kernel(te_ref, nt_ref, lhs_ref, g_ref, out_ref, acc):
         out_ref[0] = acc[...].astype(out_ref.dtype)
 
 
-def _params(semantics):
-    return pltpu.CompilerParams(
-        dimension_semantics=semantics, vmem_limit_bytes=_VMEM_LIMIT
-    )
-
-
 @functools.partial(
-    jax.jit, static_argnames=("block_rows", "transpose_rhs", "interpret")
+    jax.jit,
+    static_argnames=(
+        "block_rows", "transpose_rhs", "relu2", "zero_dead", "packed",
+        "interpret",
+    ),
 )
-def _gmm(lhs, rhs, tile_expert, n_tiles, *, block_rows, transpose_rhs,
+def _gmm(lhs, rhs, tile_expert, n_tiles, z=None, *, block_rows,
+         transpose_rhs=False, relu2=False, zero_dead=False, packed=False,
          interpret):
+    """One grouped matmul over the row buffer. `relu2`: the result is
+    `relu(.)^2` of the product and a second result is the product itself.
+    `z`: the operand is `lhs * 2 relu(z)`, formed in VMEM. `zero_dead`:
+    tiles past `n_tiles` are written, with zeros (an XLA pass reads the
+    result); else they are left alone. `packed`: the result is float32
+    and packed (`_Packed`: a `moe_rows_sum` reads it)."""
     rows, contract = lhs.shape
     cols = rhs.shape[1] if transpose_rhs else rhs.shape[2]
-    tc, to = _tile(contract, _TILE_CONTRACT), _tile(cols, _TILE_COLS)
-    # Tiles past the ones in use re-address the last one: no fetch.
-    live = lambda t, nt: jnp.minimum(t, nt[0] - 1)
+    tc, to = _gmm_tiles(contract, cols, packed)
+    steps = contract // tc
+    at = lambda t, c, nt: _live_step(t, c, steps, nt)
     if transpose_rhs:
         rhs_spec = pl.BlockSpec(
-            (1, to, tc), lambda o, t, c, te, nt: (te[live(t, nt)], o, c)
+            (1, to, tc),
+            lambda o, t, c, te, nt: (te[_live(t, nt)], o, at(t, c, nt)),
         )
     else:
         rhs_spec = pl.BlockSpec(
-            (1, tc, to), lambda o, t, c, te, nt: (te[live(t, nt)], c, o)
+            (1, tc, to),
+            lambda o, t, c, te, nt: (te[_live(t, nt)], at(t, c, nt), o),
         )
-    return pl.pallas_call(
-        functools.partial(_gmm_kernel, transpose_rhs=transpose_rhs),
+    operand = pl.BlockSpec(
+        (block_rows, tc), lambda o, t, c, te, nt: (_live(t, nt), at(t, c, nt))
+    )
+    where = (lambda t, nt: t) if zero_dead else _live
+    result = pl.BlockSpec(
+        (block_rows, to), lambda o, t, c, te, nt: (where(t, nt), o)
+    )
+    out_shape = jax.ShapeDtypeStruct((rows, cols), lhs.dtype)
+    if packed:
+        form = _Packed.of(cols)
+        result = pl.BlockSpec(
+            form.shape(block_rows), lambda o, t, c, te, nt: (where(t, nt), 0)
+        )
+        out_shape = jax.ShapeDtypeStruct(form.shape(rows), jnp.float32)
+    slope = z is not None
+    out = pl.pallas_call(
+        functools.partial(
+            _gmm_kernel, transpose_rhs=transpose_rhs, relu2=relu2,
+            slope=slope, zero_dead=zero_dead, packed=packed,
+        ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(cols // to, rows // block_rows, contract // tc),
-            in_specs=[
-                pl.BlockSpec(
-                    (block_rows, tc),
-                    lambda o, t, c, te, nt: (live(t, nt), c),
-                ),
-                rhs_spec,
-            ],
-            out_specs=pl.BlockSpec(
-                (block_rows, to), lambda o, t, c, te, nt: (t, o)
-            ),
+            grid=(cols // to, rows // block_rows, steps),
+            in_specs=[operand, rhs_spec] + [operand] * slope,
+            out_specs=[result] * (1 + relu2),
             scratch_shapes=[pltpu.VMEM((block_rows, to), jnp.float32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((rows, cols), lhs.dtype),
+        out_shape=[out_shape] * (1 + relu2),
         compiler_params=_params(("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
         name="moe_gmm_dlhs" if transpose_rhs else "moe_gmm_fwd",
-    )(tile_expert, n_tiles, lhs, rhs)
+    )(tile_expert, n_tiles, lhs, rhs, *([z] * slope))
+    return tuple(out) if relu2 else out[0]
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("n_experts", "block_rows", "out_dtype", "interpret"),
 )
-def _gmm_dw(lhs, g, tile_expert, n_tiles, *, n_experts, block_rows,
+def _gmm_dw(lhs, g, tile_expert, n_tiles, z=None, *, n_experts, block_rows,
             out_dtype, interpret):
+    """`dw[e] = lhs[rows of e].T @ g[rows of e]`; with `z`, g is `g * 2
+    relu(z)`, formed in VMEM."""
     rows, k = lhs.shape
     cols = g.shape[1]
-    tk, to = _tile(k, _TILE_DW_ROWS), _tile(cols, _TILE_COLS)
-    live = lambda t, nt: jnp.minimum(t, nt[0] - 1)
+    to = _tile(cols, _TILE_SIDE)
+    tk = _tile(k, min(_TILE_SIDE, max(128, _TILE_DW // to)))
+    g_spec = pl.BlockSpec(
+        (block_rows, to), lambda i, o, t, te, nt: (_live(t, nt), o)
+    )
+    slope = z is not None
     return pl.pallas_call(
-        _dw_kernel,
+        functools.partial(_dw_kernel, slope=slope),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(k // tk, cols // to, rows // block_rows),
             in_specs=[
                 pl.BlockSpec(
                     (block_rows, tk),
-                    lambda i, o, t, te, nt: (live(t, nt), i),
+                    lambda i, o, t, te, nt: (_live(t, nt), i),
                 ),
-                pl.BlockSpec(
-                    (block_rows, to),
-                    lambda i, o, t, te, nt: (live(t, nt), o),
-                ),
-            ],
+                g_spec,
+            ] + [g_spec] * slope,
             out_specs=pl.BlockSpec(
-                (1, tk, to), lambda i, o, t, te, nt: (te[t], i, o)
+                (1, tk, to), lambda i, o, t, te, nt: (te[_live(t, nt)], i, o)
             ),
             scratch_shapes=[pltpu.VMEM((tk, to), jnp.float32)],
         ),
@@ -329,39 +492,380 @@ def _gmm_dw(lhs, g, tile_expert, n_tiles, *, n_experts, block_rows,
         compiler_params=_params(("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="moe_gmm_dw",
-    )(tile_expert, n_tiles, lhs, g)
+    )(tile_expert, n_tiles, lhs, g, *([z] * slope))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def grouped_matmul(lhs, w, tile_expert, n_tiles, block_rows, interpret):
-    """`out[rows of expert e] = lhs[rows of e] @ w[e]` over a row buffer
-    laid out by `plan_dispatch`. lhs: [rows, K]; w: [experts, K, N] in any
-    float dtype (cast to lhs's for the MXU, float32 accumulation)."""
-    return _gmm(
-        lhs, w.astype(lhs.dtype), tile_expert, n_tiles,
-        block_rows=block_rows, transpose_rhs=False, interpret=interpret,
+# -- the rows' movers at k > 1 -------------------------------------------------
+
+
+def _pack(x):
+    """[M, d] packed (`_Packed`), float32. A relayout on the TPU; only
+    token-sized arrays go through it."""
+    m, d = x.shape
+    form = _Packed.of(d)
+    x = x.astype(jnp.float32).reshape(m, form.pieces, form.lanes)
+    x = jnp.pad(x, ((0, 0), (0, form.sublanes - form.pieces), (0, 0)))
+    return x.reshape(form.shape(m))
+
+
+def _turned(vector):
+    """(1, n) -> (n, 1) or back, by the diagonal of its broadcast (a
+    lane vector for the sublanes, which no transpose of one row gives)."""
+    n, along = max(vector.shape), vector.shape.index(1)
+    eye = lax.broadcasted_iota(jnp.int32, (n, n), 0) == lax.broadcasted_iota(
+        jnp.int32, (n, n), 1
+    )
+    return jnp.sum(
+        jnp.where(eye, vector, jnp.zeros_like(vector)), axis=1 - along,
+        keepdims=True,
     )
 
 
-def _grouped_fwd(lhs, w, tile_expert, n_tiles, block_rows, interpret):
-    out = grouped_matmul(lhs, w, tile_expert, n_tiles, block_rows, interpret)
-    return out, (lhs, w, tile_expert, n_tiles)
+def _compilable(form: _Packed, interpret: bool):
+    if not interpret and form.lanes != 128:
+        raise ValueError(
+            "more than one expert a token: the rows' movers are compiled "
+            f"for widths made of 128 lanes, not {form.lanes * form.pieces}"
+        )
 
 
-def _grouped_bwd(block_rows, interpret, res, g):
-    lhs, w, tile_expert, n_tiles = res
-    d_lhs = _gmm(
-        g, w.astype(g.dtype), tile_expert, n_tiles,
-        block_rows=block_rows, transpose_rhs=True, interpret=interpret,
+def _take_kernel(nt_ref, index_ref, x_hbm, token_ref, scale_ref, *refs,
+                 tokens: int, dot: bool):
+    *refs, buf, sem = refs
+    other_ref, out_ref, dot_ref = refs if dot else (None, *refs, None)
+    rows, d = out_ref.shape
+    form = _Packed.of(d)
+    lanes = form.lanes
+
+    @pl.when(pl.program_id(0) < nt_ref[0])
+    def _tile_in_use():
+        def issue(r, count):
+            token = index_ref[0, 0, r]
+            real = token < tokens
+
+            @pl.when(real)
+            def _():
+                form.fetch(x_hbm, token, buf, r, sem).start()
+
+            return count + real.astype(jnp.int32)
+
+        form.wait(x_hbm, buf, sem, lax.fori_loop(0, rows, issue, 0))
+        keep = _turned(token_ref[0]) < tokens  # a row of padding: zeros
+        scale = _turned(scale_ref[0])
+        inner = jnp.zeros((rows, lanes), jnp.float32)
+        for s in range(form.pieces):
+            piece = jnp.where(keep, form.piece(buf, s, rows), 0.0)
+            out_ref[:, s * lanes:(s + 1) * lanes] = (piece * scale).astype(
+                out_ref.dtype
+            )
+            if dot:
+                inner += piece * form.piece(other_ref, s, rows)
+        if dot:
+            dot_ref[0] = _turned(jnp.sum(inner, axis=1, keepdims=True))
+
+
+@functools.partial(
+    jax.jit, static_argnames=("width", "block_rows", "out_dtype", "interpret")
+)
+def _rows_take(x, row_token, n_tiles, scale=None, other=None, *, width,
+               block_rows, out_dtype, interpret):
+    """Row side: `out[r] = scale[r] x[row_token[r]]` over the tiles in
+    use, zeros for a row of padding; tiles past `n_tiles` are left alone.
+    x: [N, `width`] packed, left in HBM and fetched a row at a time;
+    `scale` [rows] float32 (ones where None). With `other` ([rows,
+    width] packed) also `<other[r], x[row_token[r]]>` [rows]."""
+    d, form = width, _Packed.of(width)
+    _compilable(form, interpret)
+    tokens = x.shape[0] // form.sublanes
+    rows = row_token.shape[0]
+    tiles = rows // block_rows
+    by_tile = lambda v: v.reshape(tiles, 1, block_rows)
+    if scale is None:
+        scale = jnp.ones((rows,), jnp.float32)
+    lane_vector = pl.BlockSpec(
+        (1, 1, block_rows), lambda t, nt: (_live(t, nt), 0, 0)
     )
-    d_w = _gmm_dw(
-        lhs, g, tile_expert, n_tiles, n_experts=w.shape[0],
-        block_rows=block_rows, out_dtype=w.dtype, interpret=interpret,
-    )
-    return d_lhs, d_w, None, None
+    dot = other is not None
+    out = pl.pallas_call(
+        functools.partial(_take_kernel, tokens=tokens, dot=dot),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(tiles,),
+            in_specs=[
+                pl.BlockSpec(
+                    (1, 1, block_rows), lambda t, nt: (_live(t, nt), 0, 0),
+                    memory_space=pltpu.SMEM,
+                ),
+                pl.BlockSpec(memory_space=pl.ANY),
+                lane_vector, lane_vector,
+            ] + [
+                pl.BlockSpec(
+                    form.shape(block_rows), lambda t, nt: (_live(t, nt), 0)
+                )
+            ] * dot,
+            out_specs=[
+                pl.BlockSpec((block_rows, d), lambda t, nt: (_live(t, nt), 0))
+            ] + [lane_vector] * dot,
+            scratch_shapes=[
+                pltpu.VMEM(form.shape(block_rows), jnp.float32),
+                pltpu.SemaphoreType.DMA(()),
+            ],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((rows, d), out_dtype)] + [
+            jax.ShapeDtypeStruct((tiles, 1, block_rows), jnp.float32)
+        ] * dot,
+        compiler_params=_params(("arbitrary",)),
+        interpret=interpret,
+        name="moe_rows_take",
+    )(n_tiles, by_tile(row_token), x, by_tile(row_token), by_tile(scale),
+      *([other] * dot))
+    return (out[0], out[1].reshape(rows)) if dot else out[0]
 
 
-grouped_matmul.defvjp(_grouped_fwd, _grouped_bwd)
+def _sum_tokens(tokens: int, slots: int, d: int) -> int:
+    """Tokens a grid step of `moe_rows_sum` takes: the most, up to
+    `_SUM_TOKENS` and in whole sublane tiles, that divide `tokens` and
+    keep the gathered rows inside `_SUM_VMEM`."""
+    for t in (_SUM_TOKENS, 128, 64, 32, 16, 8):
+        if tokens % t == 0 and slots * t * d * 4 <= _SUM_VMEM:
+            return t
+    return tokens
+
+
+def _sum_kernel(index_ref, count_ref, rows_hbm, held_ref, *refs,
+                weighted: bool):
+    *w_ref, out_ref, buf, acc, sem = refs
+    tokens, d = out_ref.shape
+    form = _Packed.of(d)
+    lanes = form.lanes
+    slots = buf.shape[0]
+
+    def per_token(n, carry):
+        issued, most = carry
+        count = count_ref[0, 0, n]
+
+        def per_slot(j, _):
+            form.fetch(rows_hbm, index_ref[0, j, n], buf.at[j], n, sem).start()
+            return _
+
+        lax.fori_loop(0, count, per_slot, 0)
+        return issued + count, jnp.maximum(most, count)
+
+    issued, most = lax.fori_loop(0, tokens, per_token, (0, 0))
+    form.wait(rows_hbm, buf.at[0], sem, issued)
+    held = _turned(held_ref[0])
+    acc[...] = jnp.zeros_like(acc)
+    for j in range(slots):
+
+        @pl.when(j < most)  # a token's slots fill from 0: most are empty
+        def _slot():
+            has = held > j
+            weight = _turned(w_ref[0][0, j:j + 1, :]) if weighted else None
+            for s in range(form.pieces):
+                piece = jnp.where(has, form.piece(buf.at[j], s, tokens), 0.0)
+                acc[:, s * lanes:(s + 1) * lanes] += (
+                    piece * weight if weighted else piece
+                )
+
+    out_ref[...] = acc[...].astype(out_ref.dtype)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("width", "out_dtype", "interpret")
+)
+def _rows_sum(rows, token_rows, token_count, weight=None, *, width, out_dtype,
+              interpret):
+    """Token side: `y[n] = sum_j weight[j, n] rows[token_rows[j, n]]` in
+    float32 over the `token_count[n]` slots token n has (weights of one
+    where None). rows: [rows, `width`] packed, left in HBM; only rows the
+    plan names are fetched."""
+    slots, tokens = token_rows.shape
+    d, form = width, _Packed.of(width)
+    _compilable(form, interpret)
+    step = _sum_tokens(tokens, slots, d)
+    by_step = lambda v: v.reshape(-1, tokens // step, step).swapaxes(0, 1)
+    weighted = weight is not None
+    return pl.pallas_call(
+        functools.partial(_sum_kernel, weighted=weighted),
+        grid=(tokens // step,),
+        in_specs=[
+            pl.BlockSpec(
+                (1, slots, step), lambda i: (i, 0, 0), memory_space=pltpu.SMEM
+            ),
+            pl.BlockSpec(
+                (1, 1, step), lambda i: (i, 0, 0), memory_space=pltpu.SMEM
+            ),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec((1, 1, step), lambda i: (i, 0, 0)),
+        ] + [pl.BlockSpec((1, slots, step), lambda i: (i, 0, 0))] * weighted,
+        out_specs=pl.BlockSpec((step, d), lambda i: (i, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((slots, *form.shape(step)), jnp.float32),
+            pltpu.VMEM((step, d), jnp.float32),
+            pltpu.SemaphoreType.DMA(()),
+        ],
+        out_shape=jax.ShapeDtypeStruct((tokens, d), out_dtype),
+        compiler_params=_params(("arbitrary",)),
+        interpret=interpret,
+        name="moe_rows_sum",
+    )(by_step(token_rows), by_step(token_count), rows, by_step(token_count),
+      *([by_step(weight)] if weighted else []))
+
+
+# -- dispatch, matmuls, combine ------------------------------------------------
+
+
+class _How(NamedTuple):
+    """What the two halves of `expert_mlp` are built from: static."""
+
+    block_rows: int
+    interpret: bool
+    by_pairs: bool  # k > 1: the rows move by `moe_rows_*`, packed
+    relu2: bool  # two matrices an expert: the activation is the kernels'
+    # Else XLA's own passes read the matmuls' results, which hold zeros
+    # past the tiles in use.
+
+
+def _take(x, index):
+    return jnp.take(x, index, axis=0, mode="fill", fill_value=0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _experts_in(x, weights, plan, how: _How):
+    """x [N, d] to its rows, then `rows @ w[e]` for each w of `weights`
+    ([experts, d, f], any float dtype): a tuple of [rows, f], `relu(.)^2`
+    of it where `how.relu2`."""
+    return _experts_in_fwd(x, weights, plan, how)[0]
+
+
+def _experts_in_fwd(x, weights, plan, how):
+    te, nt = plan["tile_expert"], plan["n_tiles"]
+    with jax.named_scope("moe.dispatch"):
+        if how.by_pairs:
+            rows = _rows_take(
+                _pack(x), plan["row_token"], nt, width=x.shape[1],
+                block_rows=how.block_rows, out_dtype=x.dtype,
+                interpret=how.interpret,
+            )
+        else:
+            rows = _take(x, plan["src"])
+    with jax.named_scope("moe.experts"):
+        out = tuple(
+            _gmm(
+                rows, w.astype(rows.dtype), te, nt, block_rows=how.block_rows,
+                relu2=how.relu2, zero_dead=not how.relu2,
+                interpret=how.interpret,
+            )
+            for w in weights
+        )
+    z = None
+    if how.relu2:
+        ((hidden, z),) = out
+        out = (hidden,)
+    return out, (rows, weights, plan, z)
+
+
+def _experts_in_bwd(how, res, gs):
+    rows, weights, plan, z = res
+    te, nt = plan["tile_expert"], plan["n_tiles"]
+    kernel = dict(block_rows=how.block_rows, interpret=how.interpret)
+    with jax.named_scope("moe.experts"):
+        d_rows = [
+            _gmm(
+                g, w.astype(g.dtype), te, nt, z, transpose_rhs=True,
+                zero_dead=not how.relu2 and not how.by_pairs,
+                packed=how.by_pairs, **kernel,
+            )
+            for w, g in zip(weights, gs)
+        ]
+        d_weights = tuple(
+            _gmm_dw(
+                rows, g, te, nt, z, n_experts=w.shape[0], out_dtype=w.dtype,
+                **kernel,
+            )
+            for w, g in zip(weights, gs)
+        )
+    with jax.named_scope("moe.dispatch"):
+        if how.by_pairs:
+            dx = sum(
+                _rows_sum(
+                    r, plan["token_rows"], plan["token_count"],
+                    width=rows.shape[1],
+                    out_dtype=jnp.float32 if len(d_rows) > 1 else rows.dtype,
+                    interpret=how.interpret,
+                )
+                for r in d_rows
+            ).astype(rows.dtype)
+        else:
+            dx = _take(sum(d_rows), plan["dst"])
+    return dx, d_weights, None
+
+
+_experts_in.defvjp(_experts_in_fwd, _experts_in_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _experts_out(hidden, w_down, weight, plan, how: _How):
+    """`hidden @ w_down[e]` [rows, d], then each token's rows by their
+    weights (`weight` float32: [N], or [h, N] by a token's slots), added
+    up in float32: [N, d]."""
+    return _experts_out_fwd(hidden, w_down, weight, plan, how)[0]
+
+
+def _experts_out_fwd(hidden, w_down, weight, plan, how):
+    with jax.named_scope("moe.experts"):
+        out = _gmm(
+            hidden, w_down.astype(hidden.dtype), plan["tile_expert"],
+            plan["n_tiles"], block_rows=how.block_rows, packed=how.by_pairs,
+            interpret=how.interpret,
+        )
+    with jax.named_scope("moe.combine"):
+        if how.by_pairs:
+            y = _rows_sum(
+                out, plan["token_rows"], plan["token_count"], weight,
+                width=w_down.shape[2], out_dtype=hidden.dtype,
+                interpret=how.interpret,
+            )
+        else:
+            out = _take(out, plan["dst"])  # a token's row: what is kept
+            y = (out * weight[:, None]).astype(hidden.dtype)
+    return y, (hidden, w_down, weight, plan, out)
+
+
+def _experts_out_bwd(how, res, g):
+    hidden, w_down, weight, plan, out = res
+    te, nt = plan["tile_expert"], plan["n_tiles"]
+    kernel = dict(block_rows=how.block_rows, interpret=how.interpret)
+    with jax.named_scope("moe.combine"):
+        if how.by_pairs:
+            slots = plan["token_rows"].reshape(-1)
+            by_row = jnp.zeros(plan["row_token"].shape, jnp.float32).at[
+                slots
+            ].set(weight.reshape(-1), mode="drop")
+            d_out, inner = _rows_take(
+                _pack(g), plan["row_token"], nt, by_row, out,
+                width=g.shape[1], out_dtype=hidden.dtype, **kernel,
+            )
+            d_weight = _take(inner, slots).reshape(weight.shape)
+        else:
+            g = g.astype(jnp.float32)
+            d_weight = jnp.sum(g * out, axis=1)
+            d_out = _take(
+                (g * weight[:, None]).astype(hidden.dtype), plan["src"]
+            )
+    with jax.named_scope("moe.experts"):
+        d_hidden = _gmm(
+            d_out, w_down.astype(d_out.dtype), te, nt, transpose_rhs=True,
+            zero_dead=not how.relu2, **kernel,
+        )
+        d_w = _gmm_dw(
+            hidden, d_out, te, nt, n_experts=w_down.shape[0],
+            out_dtype=w_down.dtype, **kernel,
+        )
+    return d_hidden, d_w, d_weight.astype(weight.dtype), None
+
+
+_experts_out.defvjp(_experts_out_fwd, _experts_out_bwd)
 
 
 def expert_mlp(
@@ -380,35 +884,17 @@ def expert_mlp(
     """
     if interpret is None:
         interpret = not kernels_compiled()
-    n_held = weights[0].shape[0]
-    one = expert.ndim == 1  # a row a token: the gathers are each other's inverse
-    with jax.named_scope("moe.dispatch"):
-        plan = plan_dispatch(expert, lo, n_held, block_rows)
-        if one:
-            rows = take_rows(x, plan["src"], plan["dst"])
-        else:
-            rows = spread_rows(x, plan["row_token"], plan["token_rows"])
-    mm = lambda a, w: grouped_matmul(
-        a, w, plan["tile_expert"], plan["n_tiles"], block_rows, interpret
-    )
-    with jax.named_scope("moe.experts"):
-        if len(weights) == 3:
-            w_gate, w_up, w_down = weights
-            hidden = jax.nn.silu(mm(rows, w_gate)) * mm(rows, w_up)
-        else:
-            w_in, w_down = weights
-            hidden = jnp.square(jax.nn.relu(mm(rows, w_in)))
-        out = mm(hidden, w_down)
-    with jax.named_scope("moe.combine"):
-        if one:
-            back = take_rows(out, plan["dst"], plan["src"])
-            return (back * gate[:, None]).astype(x.dtype)
-        back = take_rows(out, plan["token_rows"].reshape(-1), plan["row_place"])
-        back = back.reshape(*plan["token_rows"].shape, back.shape[-1])
-        weight = jnp.take(
-            gate.T.reshape(-1), plan["token_pair"], mode="fill", fill_value=0
-        )
-        return jnp.sum(back * weight[:, :, None], axis=0).astype(x.dtype)
+    *into, w_down = weights
+    how = _How(block_rows, interpret, expert.ndim == 2, len(into) == 1)
+    plan = plan_dispatch(expert, lo, w_down.shape[0], block_rows)
+    hidden = _experts_in(x, tuple(into), plan, how)
+    if how.relu2:
+        (hidden,) = hidden
+    else:
+        with jax.named_scope("moe.experts"):
+            hidden = jax.nn.silu(hidden[0]) * hidden[1]
+    weight = slot_weights(gate, plan) if how.by_pairs else gate
+    return _experts_out(hidden, w_down, weight, plan, how)
 
 
 def expert_mlp_on_mesh(mesh: Mesh | None, x, expert, gate, weights, first: int):

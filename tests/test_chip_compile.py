@@ -220,6 +220,7 @@ def test_grouped_matmul_kernels_compile_at_the_zaya_cell_shape(
         shape((16384, 2048), jnp.bfloat16), shape((16384,), jnp.float32),
         weight, weight, weight, shape((16384,), jnp.int32),
     )
+    # one expert a token: the rows move by XLA's gathers
     assert sorted(set(names)) == ["moe_gmm_dlhs", "moe_gmm_dw", "moe_gmm_fwd"]
     assert len(names) == 9 == tpu_kernel_calls(text)
 
@@ -234,8 +235,11 @@ def test_latent_top_k_expert_kernels_compile_at_the_nemotron_cell_shape(
     from kubeflow_tpu.ops import moe
 
     monkeypatch.setattr(moe, "kernels_compiled", lambda: True)
-    assert moe._tile(2688, moe._TILE_COLS) == 896 == moe._tile(2688, moe._TILE_DW_ROWS)
-    assert moe._tile(2048, moe._TILE_COLS) == 2048  # zaya's choice stands
+    # one weight block an expert either way round; zaya's choice stands
+    assert moe._gmm_tiles(1024, 2688) == (1024, 2688)
+    assert moe._gmm_tiles(2688, 1024, packed=True) == (2688, 1024)
+    assert moe._gmm_tiles(2048, 2048) == (2048, 2048)
+    assert moe._tile(2688, 2048) == 896
     shape = lambda dims, dtype: jax.ShapeDtypeStruct(
         dims, dtype, sharding=one_chip
     )
@@ -250,8 +254,50 @@ def test_latent_top_k_expert_kernels_compile_at_the_nemotron_cell_shape(
         shape((8, 1024, 2688), jnp.float32), shape((8, 2688, 1024), jnp.float32),
         shape((8192, 22), jnp.int32),
     )
-    assert sorted(set(names)) == ["moe_gmm_dlhs", "moe_gmm_dw", "moe_gmm_fwd"]
-    assert len(names) == 6 == tpu_kernel_calls(text)
+    # 22 experts a token: the rows move by the two `moe_rows_*` kernels,
+    # each in both directions (the forward's combine is not in a gradient
+    # of a sum, so one `moe_rows_sum` of the two is traced and dropped)
+    assert sorted(set(names)) == [
+        "moe_gmm_dlhs", "moe_gmm_dw", "moe_gmm_fwd", "moe_rows_sum",
+        "moe_rows_take",
+    ]
+    assert [names.count(n) for n in sorted(set(names))] == [2, 2, 2, 2, 2]
+    assert tpu_kernel_calls(text) == 9
+
+
+@pytest.mark.parametrize("tokens,k,held,d", [
+    (8192, 22, 8, 1024),  # nemotron-3-super-tp2ep64.train-8k's
+    (16384, 1, 8, 2048),  # zaya1-8b-ep2.train-8k's, which runs the gathers
+])
+def test_row_movers_compile_at_the_cells_shapes(one_chip, tokens, k, held, d):
+    """`moe_rows_take` (alone, and with the weights' gradient) and
+    `moe_rows_sum` (weighted and not) at the two expert cells' sizes: one
+    DMA a packed row, indices a tile at a time in SMEM."""
+    from kubeflow_tpu.ops import moe
+
+    shape = lambda dims, dtype: jax.ShapeDtypeStruct(
+        dims, dtype, sharding=one_chip
+    )
+    slots = min(k, held)
+    rows = moe.BLOCK_ROWS * moe.row_tiles(tokens, k, held)
+    pack = lambda m: shape(moe._Packed.of(d).shape(m), jnp.float32)
+    how = dict(width=d, out_dtype=jnp.bfloat16, interpret=False)
+
+    def movers(x, buffer, row_token, n_tiles, scale, token_rows, count, weight):
+        take = lambda *more: moe._rows_take(
+            x, row_token, n_tiles, *more, block_rows=moe.BLOCK_ROWS, **how
+        )
+        add = lambda *more: moe._rows_sum(buffer, token_rows, count, *more, **how)
+        return take(), take(scale, buffer), add(), add(weight)
+
+    text, names = _compile(
+        movers, pack(tokens), pack(rows), shape((rows,), jnp.int32),
+        shape((1,), jnp.int32), shape((rows,), jnp.float32),
+        shape((slots, tokens), jnp.int32), shape((tokens,), jnp.int32),
+        shape((slots, tokens), jnp.float32),
+    )
+    assert names == ["moe_rows_take"] * 2 + ["moe_rows_sum"] * 2
+    assert tpu_kernel_calls(text) == 4
 
 
 def test_scan_kernels_compile_at_the_nemotron_cell_shape(one_chip):

@@ -384,17 +384,33 @@ def test_top_k_dispatch_matches_a_dense_loop_over_experts(k, act):
 def test_the_plan_gives_a_token_k_rows_and_one_at_k_1():
     expert = jnp.array([[0, 3], [3, 1], [2, 0], [5, 4]], jnp.int32)
     plan = moe.plan_dispatch(expert, 0, 4, block_rows=2)
-    dst, src = np.asarray(plan["dst"]), np.asarray(plan["src"])
-    rows = src.shape[0]
-    pairs = np.asarray(expert).T.reshape(-1)  # pair j * N + n: token n's j-th
-    held = pairs < 4
-    assert (dst[~held] == rows).all() and (src[dst[held]] == np.flatnonzero(held)).all()
-    # rows in the order of the experts, a run of whole tiles each
-    assert [int(pairs[p]) for p in src if p < 8] == [0, 0, 1, 2, 3, 3]
+    row_token = np.asarray(plan["row_token"])
+    rows = row_token.shape[0]
+    assert rows == 2 * moe.row_tiles(4, 2, 4, 2) and int(plan["n_tiles"][0]) == 4
+    # rows in the order of the experts, a run of whole tiles each, an
+    # expert's in the order of its tokens; N marks a row of padding
+    assert row_token[:8].tolist() == [0, 2, 1, 4, 2, 4, 0, 1]
+    assert (row_token[8:] == 4).all()
+    assert np.asarray(plan["tile_expert"])[:4].tolist() == [0, 1, 2, 3]
+    # a token's rows side by side, in the order of its experts
+    token_rows, count = np.asarray(plan["token_rows"]), np.asarray(plan["token_count"])
+    assert count.tolist() == [2, 2, 2, 0]
+    assert token_rows.tolist() == [[0, 2, 1, rows], [6, 7, 4, rows]]
+    gate = jnp.arange(8, dtype=jnp.float32).reshape(4, 2) + 1
+    np.testing.assert_array_equal(
+        moe.slot_weights(gate, plan), [[1, 4, 6, 0], [2, 3, 5, 0]]
+    )
     one = moe.plan_dispatch(expert[:, 0], 0, 4, block_rows=2)
     flat = moe.plan_dispatch(expert[:, :1], 0, 4, block_rows=2)
-    for key in one:
-        np.testing.assert_array_equal(one[key], flat[key])
+    for ours, theirs in (("dst", "token_rows"), ("src", "row_token"),
+                         ("tile_expert",) * 2, ("n_tiles",) * 2):
+        np.testing.assert_array_equal(
+            one[ours], np.asarray(flat[theirs]).reshape(one[ours].shape)
+        )
+    dst, src = np.asarray(one["dst"]), np.asarray(one["src"])
+    held = np.asarray(expert[:, 0]) < 4
+    assert (dst[~held] == src.shape[0]).all()
+    assert (src[dst[held]] == np.flatnonzero(held)).all()
 
 
 def test_forced_experts_at_k_1_is_the_argmax_it_was():
