@@ -34,13 +34,31 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from kubeflow_tpu.ops.attention import attend
 from kubeflow_tpu.ops.flash import CHECKPOINT_LSE_NAME, CHECKPOINT_OUT_NAME
 from kubeflow_tpu.ops.moe import expert_mlp_on_mesh, row_tiles, tiles_in_use
-from kubeflow_tpu.ops.rope import rope
+from kubeflow_tpu.ops.rope import rope, yarn_inv_freq
 from kubeflow_tpu.ops.ssd import (
     CHECKPOINT_OUT_NAME as SSD_OUT_NAME,
     CHECKPOINT_STATES_NAME as SSD_STATES_NAME,
     ssd_scan,
 )
 from kubeflow_tpu.parallel.sharding import batch_axes
+
+
+@dataclasses.dataclass(frozen=True)
+class AttentionKind:
+    """One kind of attention layer of a stack that mixes them
+    (`TransformerConfig.attention_kinds`): its query heads (over the
+    stack's `n_kv_heads` K/V heads of `head_dim`), its window (None: every
+    earlier key; W: the last W keys, the query's own among them), and its
+    rope: `rope_theta` over the first `rope_fraction` of a head, plain or,
+    with `rope_yarn` = (factor, original_max, beta_fast, beta_slow,
+    attention_factor), yarn's blended frequencies with cos and sin times
+    the attention factor (`ops/rope.yarn_inv_freq`)."""
+
+    n_heads: int
+    window: int | None = None
+    rope_theta: float = 10_000.0
+    rope_fraction: float = 1.0
+    rope_yarn: tuple[float, int, float, float, float] | None = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -131,6 +149,65 @@ class TransformerConfig:
     ssm_conv: int = 4
     ssm_chunk: int = 128
     ssm_dt: tuple[float, float, float] = (1e-3, 1e-1, 1e-4)
+    # Layers that differ, in one stack of `Block`s. `attention_kinds` is
+    # the table of attention layers the stack has and `attention_pattern`
+    # says which of them each layer is (an index a layer; () with one kind
+    # or none: every layer the same). With no table the one kind is the
+    # stack's `n_heads`, `rope_theta`, `rope_fraction`, no window.
+    attention_kinds: tuple[AttentionKind, ...] = ()
+    attention_pattern: tuple[int, ...] = ()
+    # A sigmoid gate a query head and position on attention's output,
+    # from the layer's normed input, before the output projection.
+    attention_gate: bool = False
+    # Leading layers whose feed-forward half is the dense MLP, of width
+    # `dense_d_ff`, in a stack whose other layers have experts.
+    dense_layers: int = 0
+    dense_d_ff: int = 0
+
+
+def _own_kind(cfg: TransformerConfig) -> AttentionKind:
+    """The one attention kind of a stack that names none."""
+    return AttentionKind(cfg.n_heads, None, cfg.rope_theta, cfg.rope_fraction)
+
+
+def _attention_kinds(cfg: TransformerConfig) -> list[AttentionKind]:
+    """The attention kind of each of the stack's layers, checked: a
+    configuration that cannot be built is refused with its numbers."""
+    kinds = cfg.attention_kinds or (_own_kind(cfg),)
+    pattern = cfg.attention_pattern or (0,) * cfg.n_layers
+    if len(pattern) != cfg.n_layers or not all(
+        0 <= k < len(kinds) for k in pattern
+    ):
+        raise ValueError(
+            f"attention_pattern {pattern} names {len(pattern)} layers of "
+            f"{len(kinds)} kind(s); n_layers is {cfg.n_layers}"
+        )
+    if len(kinds) > 1 and not cfg.attention_pattern:
+        raise ValueError(
+            f"{len(kinds)} attention kinds and no attention_pattern to say "
+            "which layer is which"
+        )
+    hk = cfg.n_kv_heads
+    for kind in kinds:
+        if kind.n_heads < 1 or (hk and kind.n_heads % hk):
+            raise ValueError(
+                f"{kind.n_heads} query heads are not a multiple of the "
+                f"{hk} K/V heads"
+            )
+        if kind.window is not None and kind.window < 1:
+            raise ValueError(
+                f"a window of {kind.window} key(s): it counts the query's "
+                "own position, so it is at least 1"
+            )
+    if not 0 <= cfg.dense_layers <= cfg.n_layers or (
+        cfg.dense_layers and not (cfg.num_experts and cfg.dense_d_ff > 0)
+    ):
+        raise ValueError(
+            f"{cfg.dense_layers} leading dense layer(s) of width "
+            f"{cfg.dense_d_ff} in a stack of {cfg.n_layers} with "
+            f"{cfg.num_experts} experts"
+        )
+    return [kinds[k] for k in pattern]
 
 
 def _block_cls(cfg: "TransformerConfig", cls=None):
@@ -264,6 +341,9 @@ class Attention(nn.Module):
 
     config: TransformerConfig
     mesh: Mesh | None = None
+    # Which of the stack's attention kinds this layer is (None: the one
+    # the configuration's own numbers give).
+    kind: AttentionKind | None = None
 
     def _conv_mix(self, u, name: str):
         """Two causal convolutions along the sequence over u [B, S, H, d]:
@@ -325,10 +405,45 @@ class Attention(nn.Module):
         )
         return q, k, v
 
+    def _gate(self, x, out, heads: int):
+        """`out` [B, S, H, D] times a sigmoid gate a head and position,
+        `sigmoid(x wg)` in float32 at full precision. The gate is widened
+        to a head's lanes by a matmul with a constant [H, H·D] of ones, so
+        the product is taken on [B, S, H·D], where the kernel left its
+        output: neither the gate nor `out` is formed as [B, S, H, D]
+        (a relayout on the chip: PERF.md §6, PR 31)."""
+        cfg = self.config
+        wg = self.param(
+            "wg",
+            nn.with_logical_partitioning(
+                nn.initializers.variance_scaling(1.0, "fan_in", "normal"),
+                ("embed", "heads"),
+            ),
+            (x.shape[-1], heads), jnp.float32,
+        )
+        gate = jax.nn.sigmoid(jnp.dot(
+            x.astype(jnp.float32), wg, precision=jax.lax.Precision.HIGHEST
+        ))
+        # A gate stuck at 0 or 1 is the failure this shows; the step sums
+        # a counter over the layers that sow it.
+        self.sow(
+            "counters", "attn_gate_mean", jnp.mean(gate) / cfg.n_layers,
+            reduce_fn=lambda _, new: new, init_fn=lambda: 0.0,
+        )
+        width = heads * cfg.head_dim
+        lanes_of = (
+            jax.lax.broadcasted_iota(jnp.int32, (heads, width), 1)
+            // cfg.head_dim
+            == jax.lax.broadcasted_iota(jnp.int32, (heads, width), 0)
+        ).astype(cfg.dtype)
+        wide = jnp.dot(gate.astype(cfg.dtype), lanes_of)  # [B, S, H·D]
+        return (out.reshape(wide.shape) * wide).reshape(out.shape)
+
     @nn.compact
     def __call__(self, x, positions):
         cfg = self.config
-        h, d = cfg.n_heads, cfg.head_dim
+        kind = self.kind or _own_kind(cfg)
+        h, d = kind.n_heads, cfg.head_dim
         hk = cfg.n_kv_heads or h
         # q, k and v stay [B, S, H·d] from the projections' matmuls to the
         # attention kernels (`_dot_folded`); only CCA's mixing splits the
@@ -341,17 +456,35 @@ class Attention(nn.Module):
             with jax.named_scope("cca.mix"):
                 q, k, v = self._cca_mix(heads(q), heads(k), heads(v))
             q, k, v = (u.reshape(*u.shape[:2], -1) for u in (q, k, v))
-        if cfg.rope_fraction > 0:  # 0: no rotation (Nemotron-H's attention)
+        if kind.rope_fraction > 0:  # 0: no rotation (Nemotron-H's attention)
+            how = {}
+            if kind.rope_yarn is not None:
+                factor, original, fast, slow, attention_factor = kind.rope_yarn
+                how = dict(
+                    inv_freq=yarn_inv_freq(
+                        kind.rope_theta, int(d * kind.rope_fraction),
+                        factor=factor, original_max=original, beta_fast=fast,
+                        beta_slow=slow,
+                    ),
+                    scale=attention_factor,
+                )
             turn = functools.partial(
-                rope, positions=positions, theta=cfg.rope_theta,
-                fraction=cfg.rope_fraction, head_dim=d, mesh=self.mesh,
+                rope, positions=positions, theta=kind.rope_theta,
+                fraction=kind.rope_fraction, head_dim=d, mesh=self.mesh,
+                **how,
             )
             q, k = turn(q), turn(k)
-        with jax.named_scope("cca.attend" if cfg.cca else "attend"):
+        scope = "cca.attend" if cfg.cca else "attend"
+        if cfg.attention_kinds:  # a stack that mixes them tells them apart
+            scope = "attend.full" if kind.window is None else "attend.window"
+        with jax.named_scope(scope):
             out = attend(
                 heads(q), heads(k), heads(v), mesh=self.mesh,
-                impl=cfg.attention_impl,
+                impl=cfg.attention_impl, window=kind.window,
             )
+        if cfg.attention_gate:
+            with jax.named_scope("attn.gate"):
+                out = self._gate(x, out, h)
         return _dense(
             cfg.d_model, ("heads", "kv", "embed"), "wo", cfg.dtype,
             axis=(-2, -1),
@@ -715,12 +848,17 @@ class Block(nn.Module):
     config: TransformerConfig
     mesh: Mesh | None = None
     layer: int = 0
+    # What `_layer_classes` hands a layer of a stack whose layers differ:
+    # its attention kind, and whether its feed-forward half is the dense
+    # MLP of `dense_d_ff` where the stack's other layers have experts.
+    attention: AttentionKind | None = None
+    dense: bool = False
 
     @nn.compact
     def __call__(self, x, positions, router_state=None):
         cfg = self.config
         norm = functools.partial(RMSNorm, cfg.dtype, cfg.norm_eps)
-        x = x + Attention(cfg, self.mesh, name="attn")(
+        x = x + Attention(cfg, self.mesh, self.attention, name="attn")(
             norm(name="ln_attn")(x), positions
         )
         # The "mlp" policy's only checkpoint: the MLP half recomputes in
@@ -732,12 +870,16 @@ class Block(nn.Module):
             else (lambda cls: cls)
         )
         h = norm(name="ln_mlp")(x)
-        if cfg.num_experts > 0:
+        if cfg.num_experts > 0 and not self.dense:
             out, router_state = wrap(ExpertLayer)(
                 cfg, self.mesh, self.layer, name="moe"
             )(h, router_state)
         else:
-            out = wrap(SwiGLU)(cfg, name="mlp")(h)
+            dense = (
+                dataclasses.replace(cfg, d_ff=cfg.dense_d_ff)
+                if self.dense else cfg
+            )
+            out = wrap(SwiGLU)(dense, name="mlp")(h)
         return x + out, router_state
 
 
@@ -782,7 +924,20 @@ def _layer_classes(cfg: TransformerConfig) -> list:
     """The stack's layers in order, each a class with `Block`'s
     constructor and call, wrapped per the remat policy."""
     if cfg.layer_pattern is None:
-        return [_block_cls(cfg)] * cfg.n_layers
+        block = _block_cls(cfg)
+        if not (cfg.attention_kinds or cfg.dense_layers):
+            return [block] * cfg.n_layers
+        return [
+            functools.partial(
+                block, attention=kind, dense=i < cfg.dense_layers
+            )
+            for i, kind in enumerate(_attention_kinds(cfg))
+        ]
+    if cfg.attention_kinds or cfg.dense_layers:
+        raise ValueError(
+            "attention kinds by layer and leading dense layers are built "
+            "for a stack of blocks, not for a layer_pattern"
+        )
     if len(cfg.layer_pattern) != cfg.n_layers:
         raise ValueError(
             f"layer_pattern {cfg.layer_pattern!r} names "
@@ -859,10 +1014,13 @@ class PipelinedTransformerLM(nn.Module):
     @nn.compact
     def __call__(self, tokens, train: bool = False, labels=None):
         cfg = self.config
-        if cfg.num_experts > 0 or cfg.layer_pattern or not cfg.tie_embeddings:
+        if (
+            cfg.num_experts > 0 or cfg.layer_pattern or not cfg.tie_embeddings
+            or cfg.attention_kinds
+        ):
             raise ValueError(
                 "pipelined transformer does not support MoE, a layer "
-                "pattern or an untied head"
+                "pattern, attention kinds by layer or an untied head"
             )
         if cfg.n_layers % self.n_stages:
             raise ValueError(

@@ -34,13 +34,19 @@ from kubeflow_tpu.parallel.collectives import axis_size
 from kubeflow_tpu.parallel.sharding import batch_axes
 
 
-def dense_attention(q, k, v, *, causal: bool = True):
-    """Reference attention. q,k,v: [B, S, H, D] (or [B,S,G,H,D] grouped)."""
+def dense_attention(
+    q, k, v, *, causal: bool = True, window: int | None = None
+):
+    """Reference attention. q,k,v: [B, S, H, D] (or [B,S,G,H,D] grouped).
+    `window` (causal only): a query sees the last `window` keys, its own
+    among them, the band mask."""
     d = q.shape[-1]
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / math.sqrt(d)
     if causal:
         s_q, s_k = scores.shape[-2], scores.shape[-1]
         mask = jnp.tril(jnp.ones((s_q, s_k), bool), k=s_k - s_q)
+        if window is not None:
+            mask &= ~jnp.tril(jnp.ones((s_q, s_k), bool), k=s_k - s_q - window)
         scores = jnp.where(mask, scores, -jnp.inf)
     weights = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", weights.astype(q.dtype), v)
@@ -150,11 +156,17 @@ def ring_attention(
     )(q, k, v)
 
 
-def attend(q, k, v, *, mesh: Mesh | None, impl: str):
+def attend(
+    q, k, v, *, mesh: Mesh | None, impl: str, window: int | None = None
+):
     """Causal self-attention of q [B, S, H, D] over k, v [B, S, Hkv, D]
     (H a multiple of Hkv): ring when the mesh's `sp` axis is real, else
     the flash kernels or the dense reference, as `impl` says (`auto`:
     the kernels wherever they compile; `flash` / `dense` force one).
+    With `window`, position i sees keys i - window < j <= i: the band
+    in the kernels' compact grid, the band mask on the dense path; a
+    window that reaches every key is the causal call. The ring refuses
+    one: a band crosses few of its hops, and nothing has run that.
 
     The flash kernel is a Pallas call, which does not auto-partition under
     pjit — with a mesh it runs inside shard_map over the batch/tp axes
@@ -166,6 +178,13 @@ def attend(q, k, v, *, mesh: Mesh | None, impl: str):
             f"unknown attention_impl {impl!r}; expected 'auto', 'flash', "
             "or 'dense'"
         )
+    if window is not None and window < 1:
+        raise ValueError(
+            f"attend: a window of {window} key(s); it counts the query's "
+            "own position, so it is at least 1"
+        )
+    if window is not None and window >= k.shape[1]:
+        window = None  # every earlier key: the causal call
     group = q.shape[2] // k.shape[2]
     # Only the flash kernels pick a query head's kv head themselves; the
     # ring and dense paths get K and V repeated over the group.
@@ -176,6 +195,11 @@ def attend(q, k, v, *, mesh: Mesh | None, impl: str):
         # every ring hop runs the Pallas kernel (ring flash: per-device
         # attention memory O(C·D), not O(C²)) — the long-context
         # composition; otherwise the dense-hop ring.
+        if window is not None:
+            raise ValueError(
+                f"attend: window={window} on a mesh whose 'sp' axis is "
+                f"{mesh.shape['sp']}: the ring path has no window"
+            )
         chunk = q.shape[1] // mesh.shape["sp"]
         if (
             impl in ("auto", "flash")
@@ -211,9 +235,11 @@ def attend(q, k, v, *, mesh: Mesh | None, impl: str):
             )
             use_flash = False
     if not use_flash:
-        return dense_attention(q, repeat(k), repeat(v), causal=True)
+        return dense_attention(
+            q, repeat(k), repeat(v), causal=True, window=window
+        )
     if mesh is None:
-        return flash_attention(q, k, v, causal=True)
+        return flash_attention(q, k, v, causal=True, window=window)
     # The shards' boundary is crossed with the heads folded into the last
     # axis, [B, S, H·D], as the projections write q, k and v and as the
     # kernels read them (`ops/flash.py`): a [B, S, H, D] array there is
@@ -223,7 +249,9 @@ def attend(q, k, v, *, mesh: Mesh | None, impl: str):
 
     def shard(q, k, v):
         heads = lambda x: x.reshape(*x.shape[:2], -1, d)
-        return fold(flash_attention(heads(q), heads(k), heads(v), causal=True))
+        return fold(flash_attention(
+            heads(q), heads(k), heads(v), causal=True, window=window
+        ))
 
     spec = P(
         batch_axes(mesh), None, "tp" if mesh.shape.get("tp", 1) > 1 else None

@@ -33,6 +33,21 @@ on top:
   position. `flash_schedule` reports how often each engages
   (`diag_steps`, `interior_steps`, `diag_tile`,
   `computed_pairs_over_needed`).
+- **A window's band.** With `window=W` (position i sees keys
+  i - W < j <= i) the same compact grid enumerates only the block
+  pairs that band touches, i - reach <= j <= i with reach =
+  ceil((W - 1) / block) (`_band_reach`, `_tri_tables`), and a step is
+  told from the tables by its distance i - j from the diagonal: ON the
+  diagonal, where the band's far edge crosses (`_window_kinds`), or
+  between them (a whole block, no mask). A block an edge cuts is run
+  in row bands against the key lane tiles its rows can see
+  (`_window_plan`), under a mask that is a constant of the distance
+  (`_window_mask`); at W = 512 in blocks of 1024 that is 15 grid steps
+  a head where the triangle has 36, and 1.25 times the band's pairs
+  computed where the triangle under a mask would compute 8.9 times.
+  The fused backward's dq ring holds the reach + 1 row blocks that are
+  live at once (`_ring_rows`), whatever S is. The calls are named
+  `flash_*_window*` (`_kernel_name`); W >= S is the causal call.
 - **Lane-packed LSE.** The saved log-sum-exp is stored as
   [BH, S/128, 128] tiles — 128 per-row values per lane row — instead of
   the lane-replicated [BH, S, 128] buffer Mosaic's tiling would
@@ -103,7 +118,9 @@ every other backend they are compiled, and a device the TPU compiler does
 not know fails loudly instead of interpreting.
 
 Every `pallas_call` carries a stable `name=` (`flash_fwd_*`,
-`flash_delta`, `flash_bwd_fused`, `flash_dq_*`, `flash_dkv_*`): that is
+`flash_delta`, `flash_bwd_fused`, `flash_dq_*`, `flash_dkv_*`, and a
+window's `flash_fwd_window`, `flash_bwd_window_fused`,
+`flash_dq_window`, `flash_dkv_window`): that is
 what `testing/hlo.pallas_kernel_names` reads out of a jaxpr to tell which
 schedule was traced, and what a profiler trace keys kernel time on.
 """
@@ -158,6 +175,14 @@ def _causal_mask(s, i, j, bq, bk):
     q_pos = i * bq + lax.broadcasted_iota(jnp.int32, s.shape, 0)
     k_pos = j * bk + lax.broadcasted_iota(jnp.int32, s.shape, 1)
     return jnp.where(q_pos >= k_pos, s, _NEG_INF)
+
+
+def _window_pos_mask(s, i, j, bq, bk, window: int):
+    """Mask keys a window of `window` positions (the query's own among
+    them) no longer reaches: the band's far edge, by position."""
+    q_pos = i * bq + lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    k_pos = j * bk + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    return jnp.where(q_pos - k_pos < window, s, _NEG_INF)
 
 
 def _kv_tail_mask(s, j, bk, kv_len: int):
@@ -296,30 +321,55 @@ def _pad_to_tileable(block: int, s: int) -> int:
     return -(-s // _LANES) * _LANES
 
 
-def _compactable(causal: bool, sq: int, sk: int, bq: int, bk: int) -> bool:
+def _band_reach(window: int | None, nq: int, bq: int) -> int:
+    """How many blocks below the diagonal a row of blocks touches: all of
+    them without a window; with one, query i·bq sees back to key
+    i·bq - window + 1, which lies ceil((window - 1) / bq) blocks down."""
+    if window is None:
+        return nq - 1
+    return min(nq - 1, (window + bq - 2) // bq)
+
+
+def _band_steps(nq: int, reach: int) -> int:
+    """Block pairs (i, j) with i - reach <= j <= i: the triangle's
+    nq·(nq+1)/2 at reach = nq - 1."""
+    return (reach + 1) * nq - reach * (reach + 1) // 2
+
+
+def _compactable(
+    causal: bool, sq: int, sk: int, bq: int, bk: int,
+    window: int | None = None,
+) -> bool:
     """Whether the triangular grid applies: causal self-attention with
-    square blocks, so block row i runs exactly blocks j <= i."""
+    square blocks, so block row i runs exactly blocks j <= i (with a
+    window: i - reach <= j <= i, the band)."""
     if not (causal and sq == sk and bq == bk):
         return False
     nq = sq // bq
-    return nq * (nq + 1) // 2 <= _MAX_COMPACT_STEPS
+    return _band_steps(nq, _band_reach(window, nq, bq)) <= _MAX_COMPACT_STEPS
 
 
-def _grid_steps(causal: bool, sq: int, sk: int, bq: int, bk: int):
+def _grid_steps(
+    causal: bool, sq: int, sk: int, bq: int, bk: int,
+    window: int | None = None,
+):
     """(steps, rectangular_steps, compact) per (batch*head) grid row."""
     nq, nk = sq // bq, sk // bk
     rect = nq * nk
-    if _compactable(causal, sq, sk, bq, bk):
-        return nq * (nq + 1) // 2, rect, True
+    if _compactable(causal, sq, sk, bq, bk, window):
+        return _band_steps(nq, _band_reach(window, nq, bq)), rect, True
     return rect, rect, False
 
 
-def _tri_tables(nq: int, order: str):
+def _tri_tables(nq: int, order: str, reach: int | None = None):
     """Scalar-prefetch lookup tables for the compact causal grid: the
-    flat step index t → (i, j) over the lower triangle. "row" order
-    (fwd / dq: j contiguous per i) or "col" order (dkv: i contiguous
-    per j)."""
+    flat step index t → (i, j) over the lower triangle (with `reach`,
+    over its band i - reach <= j <= i only). "row" order (fwd / dq: j
+    contiguous per i) or "col" order (dkv: i contiguous per j)."""
     i, j = np.tril_indices(nq)
+    if reach is not None:
+        band = i - j <= reach
+        i, j = i[band], j[band]
     if order == "col":
         o = np.lexsort((i, j))
         i, j = i[o], j[o]
@@ -379,9 +429,17 @@ def _fused_vmem_bytes(
     )
 
 
+def _ring_rows(sq: int, bq: int, window: int | None) -> int:
+    """Rows of the fused backward's dq ring: a slot for every q block
+    that is live at once. Without a window every row is live from the
+    first column, so the whole (padded) sequence; with one a row is live
+    for the reach + 1 columns its band crosses, whatever S is."""
+    return (_band_reach(window, sq // bq, bq) + 1) * bq
+
+
 def _bwd_fused(
     causal: bool, sq: int, sk: int, bq: int, bk: int, d: int,
-    itemsize: int, packed: bool,
+    itemsize: int, packed: bool, window: int | None = None,
 ) -> bool:
     """Whether the backward runs the fused one-pass kernel: compact
     causal grid (square blocks, self-attention) AND the modelled
@@ -389,15 +447,17 @@ def _bwd_fused(
     verbatim by `flash_schedule` (reported as `bwd_fused`) and the
     `_flash_bwd_kernels` dispatch, so the accounting benches/tests gate
     on is the schedule that actually runs."""
-    return _compactable(causal, sq, sk, bq, bk) and (
-        _fused_vmem_bytes(sq, bq, bk, d, itemsize, packed)
+    return _compactable(causal, sq, sk, bq, bk, window) and (
+        _fused_vmem_bytes(
+            _ring_rows(sq, bq, window), bq, bk, d, itemsize, packed
+        )
         <= _FUSED_VMEM_BUDGET
     )
 
 
 def _bwd_hbm_bytes(
     causal: bool, sq: int, sk: int, bq: int, bk: int, d: int,
-    itemsize: int, packed: bool, fused: bool,
+    itemsize: int, packed: bool, fused: bool, window: int | None = None,
 ) -> int:
     """Modeled backward HBM bytes per (batch·head) grid row, including
     the shared-delta precompute. Counts what each kernel's BlockSpec
@@ -406,7 +466,7 @@ def _bwd_hbm_bytes(
     elides the re-fetch); blocks whose index changes stream once per
     step. DMA elision on the predicated rectangular fallback is not
     modeled (it is not the path this model exists to tune)."""
-    steps, _, _ = _grid_steps(causal, sq, sk, bq, bk)
+    steps, _, _ = _grid_steps(causal, sq, sk, bq, bk, window)
     lse_bytes = _lse_bytes_of(sq, packed)
     lse_blk = _lse_block_bytes(bq, packed)
     # delta = rowsum(dO ∘ O): one pass over (o, do), one lse-layout write.
@@ -439,22 +499,40 @@ def _bwd_hbm_bytes(
 
 
 def _diag_accounting(
-    causal: bool, seq_q: int, seq_k: int, sq: int, sk: int, bq: int, bk: int
+    causal: bool, seq_q: int, seq_k: int, sq: int, sk: int, bq: int, bk: int,
+    window: int | None = None,
 ) -> dict:
-    """What the kernels of one grid do with the triangle, per grid row:
-    how many steps run the banded body on the diagonal and how many the
-    mask-free body below it (both 0 where the kernels mask by position:
-    the rectangular grid, or a padded tail), the band's rows, and the
-    (q, k) pairs the steps compute over the pairs attention needs
-    (`seq_q`, `seq_k` are the lengths before padding)."""
-    steps, _, compact = _grid_steps(causal, sq, sk, bq, bk)
+    """What the kernels of one grid do with the triangle (with a window:
+    its band), per grid row: how many steps run a banded body on the
+    diagonal, how many one the window's far edge cuts and how many the
+    mask-free body between them (all 0 where the kernels mask by
+    position: the rectangular grid, or a padded tail), the diagonal
+    band's rows, and the (q, k) pairs the steps compute over the pairs
+    attention needs (`seq_q`, `seq_k` are the lengths before padding)."""
+    steps, _, compact = _grid_steps(causal, sq, sk, bq, bk, window)
     nq, nk = sq // bq, sk // bk
     needed = seq_q * seq_k
-    if causal:  # query r sees keys 0..r
+    if causal:  # query r sees keys 0..r (with a window: the last `window`)
         m = min(seq_q, seq_k)
         needed = m * (m + 1) // 2 + (seq_q - m) * seq_k
+        if window is not None:
+            w = min(window, m)
+            needed = w * (w + 1) // 2 + (m - w) * w
     bands = _bands(compact, bq, None if sk == seq_k else seq_k)
-    if bands:
+    edge = 0
+    if bands and window is not None:
+        kinds = _window_kinds(bq, bk, window, nq)
+        pairs = lambda plan: sum(
+            (r.stop - r.start) * (c.stop - c.start) for r, c in plan
+        )
+        diag = nq
+        tile = _window_tile(bq, window)
+        edge = sum(nq - d for d in kinds if d)
+        interior = steps - diag - edge
+        computed = interior * bq * bk + sum(
+            (nq - d) * pairs(plan) for d, (plan, _) in kinds.items()
+        )
+    elif bands:
         tile = bq // bands
         diag, interior = nq, steps - nq
         computed = interior * bq * bk + diag * tile * tile * (
@@ -465,11 +543,14 @@ def _diag_accounting(
         # Steps that run: all of a compact or non-causal grid; of a
         # causal rectangle, those not predicated off.
         ran = steps if compact or not causal else sum(
-            min(nk, (i * bq + bq - 1) // bk + 1) for i in range(nq)
+            min(nk, (i * bq + bq - 1) // bk + 1)
+            - (0 if window is None else max(0, (i * bq - window + 1) // bk))
+            for i in range(nq)
         )
         computed = ran * bq * bk
     return {
         "diag_steps": diag,
+        "edge_steps": edge,
         "interior_steps": interior,
         "diag_tile": tile,
         "computed_pairs_over_needed": computed / needed,
@@ -485,6 +566,7 @@ def flash_schedule(
     causal: bool = True,
     head_dim: int = 128,
     dtype_bytes: int = 2,
+    window: int | None = None,
 ) -> dict:
     """Static accounting for the schedule `flash_attention` would run.
 
@@ -495,19 +577,21 @@ def flash_schedule(
     byte/step figures are per (batch*head) grid row, and the backward's
     kernels walk the forward's grid (the same blocks); `head_dim` and
     `dtype_bytes` (2 = bf16, the training dtype) parameterize the
-    backward byte/VMEM models only."""
+    backward byte/VMEM models only. `window` as `flash_attention` takes
+    it: a window that reaches the whole sequence is the causal call."""
+    window = _checked_window(window, causal, seq_k)
     sp_q = _pad_to_tileable(block_q, seq_q)
     sp_k = _pad_to_tileable(block_k, seq_k)
     bq = _pick_block(block_q, sp_q)
     bk = _pick_block(block_k, sp_k)
-    steps, rect, compact = _grid_steps(causal, sp_q, sp_k, bq, bk)
+    steps, rect, compact = _grid_steps(causal, sp_q, sp_k, bq, bk, window)
     packed = _lse_is_packed(sp_q, bq)
     lse_shape = _lse_layout_shape(1, sp_q, packed)[1:]
     fused = _bwd_fused(
-        causal, sp_q, sp_k, bq, bk, head_dim, dtype_bytes, packed
+        causal, sp_q, sp_k, bq, bk, head_dim, dtype_bytes, packed, window
     )
     bwd_bytes = lambda f: _bwd_hbm_bytes(
-        causal, sp_q, sp_k, bq, bk, head_dim, dtype_bytes, packed, f
+        causal, sp_q, sp_k, bq, bk, head_dim, dtype_bytes, packed, f, window
     )
     layout = _head_layout(head_dim)
     return {
@@ -526,7 +610,8 @@ def flash_schedule(
         "bwd_fused": fused,
         "bwd_total_grid_steps": steps if fused else 2 * steps,
         "bwd_fused_vmem_bytes": _fused_vmem_bytes(
-            sp_q, bq, bk, head_dim, dtype_bytes, packed
+            _ring_rows(sp_q, bq, window), bq, bk, head_dim, dtype_bytes,
+            packed,
         ),
         "bwd_hbm_bytes": bwd_bytes(fused),
         "bwd_hbm_bytes_fused": bwd_bytes(True),
@@ -535,8 +620,14 @@ def flash_schedule(
         "lse_shape": lse_shape,
         "lse_bytes": int(np.prod(lse_shape)) * 4,
         "lse_replicated_bytes": sp_q * _LANES * 4,
-        # The diagonal (see `_step_tiles`), in forward and backward alike.
-        **_diag_accounting(causal, seq_q, seq_k, sp_q, sp_k, bq, bk),
+        # The diagonal (see `_step_tiles`), in forward and backward alike;
+        # with a window the band: `band_steps` block pairs a grid row,
+        # `diag_steps` + `edge_steps` + `interior_steps` of them.
+        "window": window,
+        "band_steps": steps if window is not None and compact else 0,
+        **_diag_accounting(
+            causal, seq_q, seq_k, sp_q, sp_k, bq, bk, window
+        ),
         # Where the kernels read a head (`_specs`), and the head-major
         # transposes round them: q, k, v, o forward, dO, dq, dk, dv
         # backward, or none.
@@ -605,7 +696,7 @@ def _diag_plan(bq: int, n: int):
     ]
 
 
-def _band_mask(s):
+def _band_mask(s, rows=None, cols=None):
     """The causal mask of a band of `_diag_plan`: its scores' last
     column is its last row's own position, so row a keeps columns up to
     a + (columns - rows). Static: on the diagonal of a square grid the
@@ -617,12 +708,122 @@ def _band_mask(s):
     return jnp.where(keep, s, _NEG_INF)
 
 
-def _step_tiles(i, j, run, tiles, *, causal, bq, bk, kv_len, compact):
+# Rows of a band of a block that one of a window's edges cuts (the
+# diagonal block, and the block or two where the band ends): the first of
+# these cuts of the block whose band is no taller than a quarter of the
+# window, else the finest, in whole lane tiles. A band runs its matmuls
+# against the keys its rows can see and no others (`_window_plan`), so the
+# finer the bands the fewer pairs computed for nothing, and the more
+# often the MXU's weights are reloaded for as many rows. Timed on the v5e
+# at (B, S, H) = (1, 8192, 72 over 8 K/V), d = 128, window 512, ms a
+# call, forward / forward + fused backward (my chip run, PR 34):
+#   blocks of 1024, bands of 128 / 256 / 512 / 1024 rows (1.25 / 1.5 /
+#   2.0 / 2.97 times the band's pairs computed):
+#       4.36 / 5.33 / 5.28 / 4.88        10.10 / 11.09 / 11.53 / 12.80
+#   blocks of 512, bands of 128 / 256 / 512:
+#       5.21 / 6.92 / 6.94               13.20 / 14.82 / 15.28
+#   blocks of 256, bands of 128 / 256:  10.26 / 10.99    22.89 / 23.45
+# Unlike the causal diagonal (`_DIAG_BANDS`: two bands beat four) the
+# window's edge blocks are most of its steps, so the finest cut wins;
+# smaller blocks only add grid steps. The global layers' call at 48
+# heads beside it: 8.28 / 22.38.
+_WINDOW_BANDS = (2, 4, 8)
+
+
+def _window_tile(bq: int, window: int) -> int:
+    cuts = [n for n in _WINDOW_BANDS if bq % (n * _LANES) == 0]
+    for n in cuts:
+        if bq // n <= max(_LANES, window // 4):
+            return bq // n
+    return bq // cuts[-1] if cuts else bq
+
+
+def _window_plan(bq: int, bk: int, t: int, delta: int, window: int):
+    """The tiles of a block whose first query stands `delta` positions
+    after its first key, under a window: band r (rows [r·t, (r+1)·t))
+    against the keys its rows see, key > query - window and key <= query,
+    widened to whole lane tiles; a band that sees none is left out."""
+    unit = _LANES if bk % _LANES == 0 else bk
+    plan = []
+    for r0 in range(0, bq, t):
+        lo = max(0, delta + r0 - window + 1)
+        hi = min(bk, delta + r0 + t)
+        if lo < hi:
+            plan.append((
+                slice(r0, r0 + t),
+                slice(lo // unit * unit, min(bk, -(-hi // unit) * unit)),
+            ))
+    return plan
+
+
+def _window_mask(delta: int, window: int):
+    """The mask of a tile of `_window_plan`: s[a, c] is query
+    delta + rows.start + a against key cols.start + c, kept where
+    0 <= query - key < window. Static, and only the side that cuts the
+    tile is built."""
+
+    def mask(s, rows, cols):
+        off = delta + rows.start - cols.start  # query - key at s[0, 0]
+        a = lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        c = lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        keep = None
+        if s.shape[1] - 1 > off:  # a key after a query
+            keep = a + off >= c
+        if off + s.shape[0] - 1 >= window:  # a key the window has left
+            far = a + (off - window) < c
+            keep = far if keep is None else keep & far
+        return s if keep is None else jnp.where(keep, s, _NEG_INF)
+
+    return mask
+
+
+def _window_kinds(bq: int, bk: int, window: int, nq: int) -> dict:
+    """{d: (plan, guard)} for the block distances d = i - j of a band
+    that an edge cuts: 0, the diagonal, and those the window's far edge
+    crosses; every distance between them is a whole block with nothing
+    masked. `guard`: a row of a tile may see no key of it."""
+    t = _window_tile(bq, window)
+    kinds = {}
+    for d in range(_band_reach(window, nq, bq) + 1):
+        if d and (d + 1) * bq <= window:
+            continue  # below the diagonal and wholly inside the window
+        plan = _window_plan(bq, bk, t, d * bq, window)
+        guard = any(
+            (r.stop - r.start) + (d * bq + r.start - c.start) - window
+            >= c.stop - c.start
+            for r, c in plan
+        )
+        kinds[d] = (plan, guard)
+    return kinds
+
+
+def _step_tiles(
+    i, j, run, tiles, *, causal, bq, bk, kv_len, compact, window=None, nq=0
+):
     """Run `tiles(plan, mask, guard)` for grid step (i, j): by the
     step's place where the kernel can tell it from the grid (`_bands`),
     else the whole block masked by position, under `run`."""
     whole = [(_WHOLE, _WHOLE)]
     bands = _bands(compact, bq, kv_len)
+    if bands and window is not None:
+        # The band's three kinds of step, told apart by the distance
+        # from the diagonal: cut by the diagonal (and, under a window
+        # shorter than a block, by the far edge too), cut by the far
+        # edge, or between them.
+        kinds = _window_kinds(bq, bk, window, nq)
+        inner = [
+            d for d in range(1, _band_reach(window, nq, bq) + 1)
+            if d not in kinds
+        ]
+        if inner:
+            pl.when((i - j >= inner[0]) & (i - j <= inner[-1]))(
+                lambda: tiles(whole, None, False)
+            )
+        for d, (plan, guard) in kinds.items():
+            pl.when(i - j == d)(functools.partial(
+                tiles, plan, _window_mask(d * bq, window), guard
+            ))
+        return
     if bands:
         pl.when(j < i)(lambda: tiles(whole, None, False))
         pl.when(j == i)(
@@ -630,9 +831,11 @@ def _step_tiles(i, j, run, tiles, *, causal, bq, bk, kv_len, compact):
         )
         return
 
-    def mask(s):
+    def mask(s, rows=None, cols=None):
         if causal:
             s = _causal_mask(s, i, j, bq, bk)
+        if window is not None:
+            s = _window_pos_mask(s, i, j, bq, bk, window)
         if kv_len is not None:
             s = _kv_tail_mask(s, j, bk, kv_len)
         return s
@@ -669,7 +872,7 @@ def _fwd_tiles(
     for rows, cols in plan:
         s = _dot_nt(q_blk[rows], k_blk[cols])
         if mask is not None:
-            s = mask(s)
+            s = mask(s, rows, cols)
         m_prev = m_scr[rows, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         # Rows with every key masked so far keep m=-inf; exp(-inf - -inf)
@@ -700,6 +903,7 @@ def _fwd_body(
     m_scr, l_scr, acc,
     *, scale: float, causal: bool, bq: int, bk: int,
     kv_len: int | None, packed: bool, compact: bool = False,
+    window: int | None = None, nq: int = 0,
 ):
     @pl.when(first)
     def _init():
@@ -713,6 +917,7 @@ def _fwd_body(
             _fwd_tiles, q_ref, k_ref, v_ref, m_scr, l_scr, acc, scale=scale
         ),
         causal=causal, bq=bq, bk=bk, kv_len=kv_len, compact=compact,
+        window=window, nq=nq,
     )
 
     @pl.when(last)
@@ -728,6 +933,31 @@ def _fwd_body(
         lse_ref[0] = _pack_rows(lse_rep) if packed else lse_rep
 
 
+def _rect_run(i, j, kw):
+    """Whether rectangular grid step (i, j) holds a pair attention needs:
+    not above the diagonal, and not below the window's band."""
+    if not kw["causal"]:
+        return True
+    run = j * kw["bk"] <= i * kw["bq"] + kw["bq"] - 1
+    if kw.get("window") is not None:
+        run &= (j + 1) * kw["bk"] - 1 > i * kw["bq"] - kw["window"]
+    return run
+
+
+def _band_first(i, window, nq: int, bq: int):
+    """The first k block of q block i's row on the compact grid."""
+    if window is None:
+        return 0
+    return jnp.maximum(i - _band_reach(window, nq, bq), 0)
+
+
+def _band_last(j, window, nq: int, bq: int):
+    """The last q block of k block j's column on the compact grid."""
+    if window is None:
+        return nq - 1
+    return jnp.minimum(j + _band_reach(window, nq, bq), nq - 1)
+
+
 def _fwd_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc, **kw
 ):
@@ -737,11 +967,8 @@ def _fwd_kernel(
     i = pl.program_id(1)
     j = pl.program_id(2)
     nk = pl.num_programs(2)
-    run = True
-    if kw["causal"]:
-        run = j * kw["bk"] <= i * kw["bq"] + kw["bq"] - 1
     _fwd_body(
-        i, j, j == 0, j == nk - 1, run,
+        i, j, j == 0, j == nk - 1, _rect_run(i, j, kw),
         q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc, **kw
     )
 
@@ -756,8 +983,9 @@ def _fwd_kernel_compact(
     t = pl.program_id(1)
     i = rows_ref[t]
     j = cols_ref[t]
+    first = _band_first(i, kw["window"], kw["nq"], kw["bq"])
     _fwd_body(
-        i, j, j == 0, j == i, True,
+        i, j, j == first, j == i, True,
         q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc,
         compact=True, **kw,
     )
@@ -796,7 +1024,7 @@ def _bwd_tiles(
         q, k, do = q_blk[rows], k_blk[cols], do_blk[rows]
         s = _dot_nt(q, k)
         if mask is not None:
-            s = mask(s)
+            s = mask(s, rows, cols)
         # A masked score is -inf and lse is finite wherever a row has a
         # key, so exp gives 0; `guard` covers the rows that have none
         # (lse = -inf too: nan).
@@ -817,6 +1045,7 @@ def _dq_body(
     delta_ref, dq_ref, dq_acc,
     *, scale: float, causal: bool, bq: int, bk: int,
     kv_len: int | None, packed: bool, compact: bool = False,
+    window: int | None = None, nq: int = 0,
 ):
     @pl.when(first)
     def _init():
@@ -832,6 +1061,7 @@ def _dq_body(
             scale=scale, packed=packed, dq=dq,
         ),
         causal=causal, bq=bq, bk=bk, kv_len=kv_len, compact=compact,
+        window=window, nq=nq,
     )
 
     @pl.when(last)
@@ -845,11 +1075,8 @@ def _dq_kernel(
     i = pl.program_id(1)
     j = pl.program_id(2)
     nk = pl.num_programs(2)
-    run = True
-    if kw["causal"]:
-        run = j * kw["bk"] <= i * kw["bq"] + kw["bq"] - 1
     _dq_body(
-        i, j, j == 0, j == nk - 1, run,
+        i, j, j == 0, j == nk - 1, _rect_run(i, j, kw),
         q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc,
         **kw,
     )
@@ -862,8 +1089,9 @@ def _dq_kernel_compact(
     t = pl.program_id(1)
     i = rows_ref[t]
     j = cols_ref[t]
+    first = _band_first(i, kw["window"], kw["nq"], kw["bq"])
     _dq_body(
-        i, j, j == 0, j == i, True,
+        i, j, j == first, j == i, True,
         q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc,
         compact=True, **kw,
     )
@@ -874,6 +1102,7 @@ def _dkv_body(
     delta_ref, dk_ref, dv_ref, dk_acc, dv_acc,
     *, scale: float, causal: bool, bq: int, bk: int,
     kv_len: int | None, packed: bool, compact: bool = False,
+    window: int | None = None, nq: int = 0,
 ):
     @pl.when(first)
     def _init():
@@ -887,6 +1116,7 @@ def _dkv_body(
             scale=scale, packed=packed, dk_acc=dk_acc, dv_acc=dv_acc,
         ),
         causal=causal, bq=bq, bk=bk, kv_len=kv_len, compact=compact,
+        window=window, nq=nq,
     )
 
     @pl.when(last)
@@ -904,11 +1134,8 @@ def _dkv_kernel(
     j = pl.program_id(1)  # k block (outer)
     i = pl.program_id(2)  # q block (inner)
     nq = pl.num_programs(2)
-    run = True
-    if kw["causal"]:
-        run = j * kw["bk"] <= i * kw["bq"] + kw["bq"] - 1
     _dkv_body(
-        i, j, i == 0, i == nq - 1, run,
+        i, j, i == 0, i == nq - 1, _rect_run(i, j, kw),
         q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
         dk_acc, dv_acc, **kw,
     )
@@ -924,9 +1151,9 @@ def _dkv_kernel_compact(
     t = pl.program_id(1)
     i = rows_ref[t]
     j = cols_ref[t]
-    nq = kw.pop("nq")
+    last = _band_last(j, kw["window"], kw["nq"], kw["bq"])
     _dkv_body(
-        i, j, i == j, i == nq - 1, True,
+        i, j, i == j, i == last, True,
         q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
         dk_acc, dv_acc, compact=True, **kw,
     )
@@ -936,10 +1163,11 @@ def _dqkv_kernel_fused(
     rows_ref, cols_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dq_ref, dk_ref, dv_ref, dq_ring, dk_acc, dv_acc,
     *, scale: float, causal: bool, bq: int, bk: int,
-    kv_len: int | None, packed: bool, nq: int,
+    kv_len: int | None, packed: bool, nq: int, window: int | None = None,
 ):
     """Fused one-pass backward over the compact causal grid, column-major
-    (for each kv block j, q blocks i = j..nq-1 are contiguous).
+    (for each kv block j, q blocks i = j..nq-1 are contiguous; under a
+    window i = j..j+reach, the band's column).
 
     Each step computes the (s, p, ds) recurrence ONCE and feeds all
     three gradients: dk/dv accumulate in per-column scratch exactly like
@@ -953,12 +1181,22 @@ def _dqkv_kernel_fused(
 
     Input streams are q/do/lse/delta (per step) and k/v (once per
     column). O is NOT an input — delta carries the rowsum(dO ∘ O)
-    precompute (shared-delta contract, see `_delta_kernel`)."""
+    precompute (shared-delta contract, see `_delta_kernel`).
+
+    Under a window a row is live for the reach + 1 columns its band
+    crosses and no longer, so the ring has that many slots (`_ring_rows`),
+    row i in slot i mod (reach + 1): row j + reach enters at column j
+    into the slot row j - 1 left when column j - 1 flushed it. A row's
+    first column is where the band ends, and not every band of its rows
+    has work there (`_window_plan`), so the slot is zeroed as the row
+    enters and every contribution accumulates."""
     t = pl.program_id(1)
     i = rows_ref[t]
     j = cols_ref[t]
     first = i == j  # column j's first step (the diagonal block)
-    last = i == nq - 1  # column j's last step
+    last = i == _band_last(j, window, nq, bq)  # column j's last step
+    slots = _ring_rows(nq * bq, bq, window) // bq
+    slot_of = lambda row: row if window is None else lax.rem(row, slots)
 
     @pl.when(first)
     def _init():
@@ -970,7 +1208,10 @@ def _dqkv_kernel_fused(
         # loaded pre-scaled, so the ring carries the 1/sqrt(d) factor
         # once more at flush (same algebra as `_dq_body`'s finalize).
         start = rows.start or 0
-        slot = pl.ds(i * bq + start, (rows.stop or bq) - start)
+        slot = pl.ds(slot_of(i) * bq + start, (rows.stop or bq) - start)
+        if window is not None:
+            dq_ring[slot, :] = dq_ring[slot, :] + dq_rows
+            return
 
         @pl.when(j == 0)
         def _seed():
@@ -982,6 +1223,13 @@ def _dqkv_kernel_fused(
         def _accum():
             dq_ring[slot, :] = dq_ring[slot, :] + dq_rows
 
+    if window is not None:
+        @pl.when(j == _band_first(i, window, nq, bq))
+        def _enter():
+            dq_ring[pl.ds(slot_of(i) * bq, bq), :] = jnp.zeros(
+                (bq, dq_ring.shape[1]), dq_ring.dtype
+            )
+
     _step_tiles(
         i, j, True,
         functools.partial(
@@ -989,6 +1237,7 @@ def _dqkv_kernel_fused(
             scale=scale, packed=packed, dk_acc=dk_acc, dv_acc=dv_acc, dq=dq,
         ),
         causal=causal, bq=bq, bk=bk, kv_len=kv_len, compact=True,
+        window=window, nq=nq,
     )
 
     @pl.when(last)
@@ -997,9 +1246,9 @@ def _dqkv_kernel_fused(
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
         # Row j retired at this column's diagonal step; its completed
         # slot flushes into the column-indexed dq output block.
-        dq_ref[0] = (dq_ring[pl.ds(j * bq, bq), :] * scale).astype(
-            dq_ref.dtype
-        )
+        dq_ref[0] = (
+            dq_ring[pl.ds(slot_of(j) * bq, bq), :] * scale
+        ).astype(dq_ref.dtype)
 
 
 # -- clamped index maps (rectangular fallback only) --------------------------
@@ -1159,6 +1408,33 @@ def _sum_groups(dk, like, d: int):
 # -- pallas_call wrappers ----------------------------------------------------
 
 
+def _kernel_name(base: str, grid: str, window: int | None) -> str:
+    """A `pallas_call`'s name: what a jaxpr and a device trace tell the
+    schedules apart by. The causal calls keep `<base>_<grid>`; a window's
+    say so (`flash_fwd_window`, `flash_bwd_window_fused`,
+    `flash_dq_window`, `flash_dkv_window`, and `..._window_rect` off the
+    compact grid), so a trace tells a model's window layers' calls from
+    its global layers'."""
+    if window is None:
+        return f"{base}_{grid}"
+    return f"{base}_window" + ("" if grid == "compact" else f"_{grid}")
+
+
+def _checked_window(window, causal: bool, seq_k: int) -> int | None:
+    """`window` as the kernels take it: None for a window that reaches
+    every earlier key (the causal call, the same program), else at least
+    the query's own position, and only under the causal mask."""
+    if window is None:
+        return None
+    if window < 1 or not causal:
+        raise ValueError(
+            f"flash attention: a window of {window} key(s) "
+            f"(causal={causal}): a window counts the query's own position, "
+            "so it is at least 1, and looks back only"
+        )
+    return None if window >= seq_k else int(window)
+
+
 _T_ROW = lambda t, rows, cols: rows[t]  # compact grids: step t's q block
 _T_COL = lambda t, rows, cols: cols[t]  # ... and its k block
 
@@ -1167,12 +1443,12 @@ _T_COL = lambda t, rows, cols: cols[t]  # ... and its k block
     jax.jit,
     static_argnames=(
         "causal", "block_q", "block_k", "interpret", "kv_len", "packed",
-        "heads",
+        "heads", "window",
     ),
 )
 def _flash_fwd_impl(
     q, k, v, causal, block_q, block_k, interpret, kv_len=None, packed=False,
-    heads=1,
+    heads=1, window=None,
 ):
     """q [Bq, S, heads·d] over k, v [Bk, S, kv_heads·d] (`_specs`) ->
     (o in q's layout, lse [Bq·heads, ...] in the kernel lse layout)."""
@@ -1181,12 +1457,12 @@ def _flash_fwd_impl(
     bq = _pick_block(block_q, sq)
     bk = _pick_block(block_k, sk)
     scale = 1.0 / math.sqrt(d)
-    steps, _, compact = _grid_steps(causal, sq, sk, bq, bk)
+    steps, _, compact = _grid_steps(causal, sq, sk, bq, bk, window)
     nq = sq // bq
     q_spec, kv_spec, stat_spec = _specs(heads, kv_heads, group, d)
     kernel_kw = dict(
         scale=scale, causal=causal, bq=bq, bk=bk, kv_len=kv_len,
-        packed=packed,
+        packed=packed, window=window, nq=nq,
     )
     out_shape = [
         jax.ShapeDtypeStruct(q.shape, q.dtype),
@@ -1203,7 +1479,7 @@ def _flash_fwd_impl(
         transcendentals=bh * steps * bq * bk,
     )
     if compact:
-        rows, cols = _tri_tables(nq, "row")
+        rows, cols = _tri_tables(nq, "row", _band_reach(window, nq, bq))
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(bh, steps),
@@ -1222,7 +1498,7 @@ def _flash_fwd_impl(
             out_shape=out_shape,
             cost_estimate=cost,
             interpret=interpret,
-            name="flash_fwd_compact",
+            name=_kernel_name("flash_fwd", "compact", window),
         )(rows, cols, q, k, v)
     row_i = lambda i, j: i
     clamped_j = lambda i, j: _clamp_j(i, j, bq, bk, causal)
@@ -1239,7 +1515,7 @@ def _flash_fwd_impl(
         scratch_shapes=scratch,
         cost_estimate=cost,
         interpret=interpret,
-        name="flash_fwd_rect",
+        name=_kernel_name("flash_fwd", "rect", window),
     )(q, k, v)
 
 
@@ -1271,12 +1547,12 @@ def _flash_delta_impl(o, do, block_q, interpret, packed, heads=1):
     jax.jit,
     static_argnames=(
         "causal", "block_q", "block_k", "interpret", "kv_len", "packed",
-        "fused", "heads",
+        "fused", "heads", "window",
     ),
 )
 def _flash_bwd_kernels(
     q, k, v, do, lse, delta, causal, block_q, block_k, interpret,
-    kv_len=None, packed=False, fused=None, heads=1,
+    kv_len=None, packed=False, fused=None, heads=1, window=None,
 ):
     """Backward kernels over a precomputed (lse, delta) pair (both in
     the kernel lse layout): the fused one-pass dq/dkv kernel when
@@ -1292,20 +1568,24 @@ def _flash_bwd_kernels(
     bq = _pick_block(block_q, sq)
     bk = _pick_block(block_k, sk)
     scale = 1.0 / math.sqrt(d)
-    steps, _, compact = _grid_steps(causal, sq, sk, bq, bk)
+    steps, _, compact = _grid_steps(causal, sq, sk, bq, bk, window)
     nq, nk = sq // bq, sk // bk
+    reach = _band_reach(window, nq, bq)
+    ring_rows = _ring_rows(sq, bq, window)
     if fused is None:
         fused = _bwd_fused(
-            causal, sq, sk, bq, bk, d, q.dtype.itemsize, packed
+            causal, sq, sk, bq, bk, d, q.dtype.itemsize, packed, window
         )
     elif fused:
-        if not _compactable(causal, sq, sk, bq, bk):
+        if not _compactable(causal, sq, sk, bq, bk, window):
             raise ValueError(
                 "fused flash backward requires the compact causal grid "
                 f"(causal self-attention, square blocks); got "
                 f"causal={causal} sq={sq} sk={sk} bq={bq} bk={bk}"
             )
-        vmem = _fused_vmem_bytes(sq, bq, bk, d, q.dtype.itemsize, packed)
+        vmem = _fused_vmem_bytes(
+            ring_rows, bq, bk, d, q.dtype.itemsize, packed
+        )
         if vmem > _FUSED_VMEM_BUDGET:
             raise ValueError(
                 "fused flash backward forced on an over-budget shape: "
@@ -1315,7 +1595,7 @@ def _flash_bwd_kernels(
             )
     kw = dict(
         scale=scale, causal=causal, bq=bq, bk=bk, kv_len=kv_len,
-        packed=packed,
+        packed=packed, window=window, nq=nq,
     )
     q_spec, kv_spec, stat_spec = _specs(heads, kv_heads, group, d)
     # dK and dV leave every kernel as one partial a QUERY head, in q's
@@ -1345,13 +1625,13 @@ def _flash_bwd_kernels(
         # three outputs ride the column index. The cost estimate counts
         # the 5 block matmuls (the two-pass path re-derives s/dp and
         # pays 7) and the modeled one-pass HBM bytes.
-        rows_c, cols_c = _tri_tables(nq, "col")
+        rows_c, cols_c = _tri_tables(nq, "col", reach)
         cost = pl.CostEstimate(
             flops=10 * bh * steps * bq * bk * d,
             bytes_accessed=bh * (
                 _bwd_hbm_bytes(
                     causal, sq, sk, bq, bk, d, q.dtype.itemsize, packed,
-                    True,
+                    True, window,
                 )
                 - 2 * sq * d * q.dtype.itemsize  # delta precompute's share
                 - _lse_bytes_of(sq, packed)
@@ -1359,7 +1639,7 @@ def _flash_bwd_kernels(
             transcendentals=bh * steps * bq * bk,
         )
         dq, dk, dv = pl.pallas_call(
-            functools.partial(_dqkv_kernel_fused, nq=nq, **kw),
+            functools.partial(_dqkv_kernel_fused, **kw),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2,
                 grid=(bh, steps),
@@ -1368,7 +1648,7 @@ def _flash_bwd_kernels(
                     q_spec(bq, _T_COL), q_spec(bk, _T_COL), q_spec(bk, _T_COL)
                 ],
                 scratch_shapes=[
-                    pltpu.VMEM((nq * bq, d), jnp.float32),  # dq ring
+                    pltpu.VMEM((ring_rows, d), jnp.float32),  # dq ring
                     pltpu.VMEM((bk, d), jnp.float32),
                     pltpu.VMEM((bk, d), jnp.float32),
                 ],
@@ -1382,12 +1662,12 @@ def _flash_bwd_kernels(
                 vmem_limit_bytes=_FUSED_VMEM_BUDGET
             ),
             interpret=interpret,
-            name="flash_bwd_fused",
+            name=_kernel_name("flash_bwd", "fused", window),
         )(rows_c, cols_c, q, k, v, do, lse, delta)
         return grouped(dq, dk, dv)
 
     if compact:
-        rows, cols = _tri_tables(nq, "row")
+        rows, cols = _tri_tables(nq, "row", reach)
         dq = pl.pallas_call(
             functools.partial(_dq_kernel_compact, **kw),
             grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -1399,11 +1679,11 @@ def _flash_bwd_kernels(
             ),
             out_shape=dq_shape,
             interpret=interpret,
-            name="flash_dq_compact",
+            name=_kernel_name("flash_dq", "compact", window),
         )(rows, cols, q, k, v, do, lse, delta)
-        rows_c, cols_c = _tri_tables(nq, "col")
+        rows_c, cols_c = _tri_tables(nq, "col", reach)
         dk, dv = pl.pallas_call(
-            functools.partial(_dkv_kernel_compact, nq=nq, **kw),
+            functools.partial(_dkv_kernel_compact, **kw),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2,
                 grid=(bh, steps),
@@ -1416,7 +1696,7 @@ def _flash_bwd_kernels(
             ),
             out_shape=dkv_shape,
             interpret=interpret,
-            name="flash_dkv_compact",
+            name=_kernel_name("flash_dkv", "compact", window),
         )(rows_c, cols_c, q, k, v, do, lse, delta)
         return grouped(dq, dk, dv)
 
@@ -1430,7 +1710,7 @@ def _flash_bwd_kernels(
         out_shape=dq_shape,
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
-        name="flash_dq_rect",
+        name=_kernel_name("flash_dq", "rect", window),
     )(q, k, v, do, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -1446,19 +1726,19 @@ def _flash_bwd_kernels(
             pltpu.VMEM((bk, d), jnp.float32),
         ],
         interpret=interpret,
-        name="flash_dkv_rect",
+        name=_kernel_name("flash_dkv", "rect", window),
     )(q, k, v, do, lse, delta)
     return grouped(dq, dk, dv)
 
 
 def _flash_bwd_impl(
     q, k, v, o, lse, do, causal, block_q, block_k, interpret,
-    kv_len=None, packed=False, heads=1,
+    kv_len=None, packed=False, heads=1, window=None,
 ):
     delta = _flash_delta_impl(o, do, block_q, interpret, packed, heads)
     return _flash_bwd_kernels(
         q, k, v, do, lse, delta, causal, block_q, block_k, interpret,
-        kv_len, packed, None, heads,
+        kv_len, packed, None, heads, window,
     )
 
 
@@ -1469,24 +1749,27 @@ def _residual_packed(sq: int, block_q: int) -> bool:
     return _lse_is_packed(sq, _pick_block(block_q, sq))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash_core(q, k, v, causal, block_q, block_k, interpret, kv_len, heads):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _flash_core(
+    q, k, v, causal, block_q, block_k, interpret, kv_len, heads, window=None
+):
     """q [Bq, S, heads·d], k, v [Bk, S, kv_heads·d] (`_specs`) ->
     (o, lse), o in q's layout and lse [Bq·heads, ...]. The lse output
     carries NO cotangent path (its incoming gradient is discarded in the
     VJP) — it exists so callers and `remat_policy="flash"` can hold the
     softmax statistics."""
     return _flash_vjp_fwd(
-        q, k, v, causal, block_q, block_k, interpret, kv_len, heads
+        q, k, v, causal, block_q, block_k, interpret, kv_len, heads, window
     )[0]
 
 
 def _flash_vjp_fwd(
-    q, k, v, causal, block_q, block_k, interpret, kv_len, heads
+    q, k, v, causal, block_q, block_k, interpret, kv_len, heads, window
 ):
     packed = _residual_packed(q.shape[1], block_q)
     o, lse = _flash_fwd_impl(
-        q, k, v, causal, block_q, block_k, interpret, kv_len, packed, heads
+        q, k, v, causal, block_q, block_k, interpret, kv_len, packed, heads,
+        window,
     )
     # Residual slimming: in the packed layout the lse residual is already
     # exactly the information (1/128th the old lane-replicated buffer);
@@ -1503,14 +1786,14 @@ def _flash_vjp_fwd(
 
 
 def _flash_vjp_bwd(causal, block_q, block_k, interpret, kv_len, heads,
-                   residuals, cts):
+                   window, residuals, cts):
     q, k, v, o, lse = residuals
     do, _ = cts  # the lse output is statistics-only; its cotangent drops
     packed = _residual_packed(q.shape[1], block_q)
     lse_layout = _rows_to_layout(_lse_rows(lse, q.shape[1]), packed)
     return _flash_bwd_impl(
         q, k, v, o, lse_layout, do, causal, block_q, block_k, interpret,
-        kv_len, packed, heads,
+        kv_len, packed, heads, window,
     )
 
 
@@ -1543,6 +1826,7 @@ def flash_attention(
     block_k: int = 1024,
     interpret: bool | None = None,
     return_lse: bool = False,
+    window: int | None = None,
 ):
     """Blockwise attention on the MXU. q: [B, S, H, D]; k, v: [B, S, Hkv, D]
     with H a multiple of Hkv (query head h attends over kv head
@@ -1573,6 +1857,16 @@ def flash_attention(
     the compact triangular grid (see module docstring): ~half the grid
     steps of the rectangular schedule at large S.
 
+    ``window=W`` (causal only) lets position i see keys i - W < j <= i,
+    W of them with its own. On the compact grid the tables then
+    enumerate only the block pairs that band touches, and a step is on
+    the diagonal, where the band ends (masked from the far side, by
+    constants) or between them (no mask): `_step_tiles`,
+    `flash_schedule(..., window=)`. The fused backward's dq ring holds
+    the rows live at once, reach + 1 blocks, whatever S is. The calls are
+    named `flash_*_window*` (`_kernel_name`). ``W >= S`` is the causal
+    call: the same program as ``window=None``.
+
     ``return_lse=True`` additionally returns the log-sum-exp as
     [B, H, S] (float32). The lse return is statistics-only: no gradient
     flows through it.
@@ -1592,6 +1886,7 @@ def flash_attention(
             f"{v.shape}: k and v must agree and their heads divide q's"
         )
     interp = _auto_interpret(interpret)
+    window = _checked_window(window, causal, sk)
     sp_q = _pad_to_tileable(block_q, sq)
     sp_k = _pad_to_tileable(block_k, sk)
     kv_len = sk if sp_k != sk else None
@@ -1604,7 +1899,7 @@ def flash_attention(
     o, lse = _flash_core(
         _fold_heads(q, layout), _fold_heads(k, layout),
         _fold_heads(v, layout), causal, block_q, block_k, interp, kv_len,
-        h if layout == "seq_major" else 1,
+        h if layout == "seq_major" else 1, window,
     )
     o = _unfold_heads(o, b, h, layout)
     if sp_q != sq:

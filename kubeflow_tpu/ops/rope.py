@@ -41,21 +41,73 @@ from kubeflow_tpu.ops import flash
 from kubeflow_tpu.parallel.sharding import batch_axes
 
 # Rows of a block: 256 x (16 heads x 128) bf16 is 1 MiB, in and out
-# double-buffered 4 MiB of VMEM.
+# double-buffered 4 MiB of VMEM. A wider row halves them until a block is
+# at most `_BLOCK_BYTES` (72 heads x 128: 64 rows; at 256 the four buffers
+# are 18 MiB, past the compiler's 16 MiB of scoped VMEM).
 _BLOCK_ROWS = 256
+_BLOCK_BYTES = 2 * 1024 * 1024
 
 
-def rope_tables(positions, theta: float, head_dim: int, fraction: float = 1.0):
+def _block_rows(width: int, itemsize: int) -> int:
+    rows = _BLOCK_ROWS
+    while rows > 8 and rows * width * itemsize > _BLOCK_BYTES:
+        rows //= 2
+    return rows
+
+
+def yarn_inv_freq(
+    theta: float, turned: int, *, factor: float, original_max: int,
+    beta_fast: float = 32.0, beta_slow: float = 1.0,
+):
+    """The `turned / 2` frequencies of a yarn-scaled rotation, as the
+    published `rope_type: yarn` computes them: pair t turns by
+    `theta^(-2t/turned)` where it makes more than `beta_fast` rotations
+    over the `original_max` positions the model was trained at
+    (extrapolated: kept), by that over `factor` where it makes fewer than
+    `beta_slow` (interpolated: stretched), and by a linear blend between
+    the two pairs where those counts fall. A vector for `rope_tables`:
+    yarn is data, the kernels turn by whatever tables they are handed."""
+    pair = jnp.arange(0, turned, 2, dtype=jnp.float32)
+    extrapolated = 1.0 / theta ** (pair / turned)
+    interpolated = extrapolated / factor
+    at = lambda rotations: turned * math.log(
+        original_max / (rotations * 2 * math.pi)
+    ) / (2 * math.log(theta))
+    low = max(math.floor(at(beta_fast)), 0)
+    high = min(math.ceil(at(beta_slow)), turned - 1)
+    if low == high:
+        high += 0.001  # the published guard against a ramp of no width
+    ramp = jnp.clip((pair / 2 - low) / (high - low), 0.0, 1.0)
+    return interpolated * ramp + extrapolated * (1.0 - ramp)
+
+
+def rope_tables(
+    positions, theta: float, head_dim: int, fraction: float = 1.0, *,
+    inv_freq=None, scale: float = 1.0,
+):
     """(cos, sin) [B, S, D] float32 over one head's lanes, so that
     `x * cos + partner(x) * sin` turns the first `fraction * D` lanes and
     keeps the rest: cos is 1 and sin 0 on lanes that stay, and sin carries
-    the sign of the pair's first half."""
+    the sign of the pair's first half. The turned pairs' frequencies are
+    `inv_freq` (a vector, one a pair: `yarn_inv_freq`) where given, else
+    the plain `theta^(-2t/turned)`; `scale` multiplies cos and sin of the
+    lanes that turn (yarn's attention factor), never the lanes that stay."""
     turned = int(head_dim * fraction)
-    freqs = 1.0 / theta ** (
-        jnp.arange(0, turned, 2, dtype=jnp.float32) / turned
-    )
+    if inv_freq is None:
+        freqs = 1.0 / theta ** (
+            jnp.arange(0, turned, 2, dtype=jnp.float32) / turned
+        )
+    else:
+        freqs = jnp.asarray(inv_freq, jnp.float32)
+        if freqs.shape != (turned // 2,):
+            raise ValueError(
+                f"rope: {freqs.shape} frequencies for {turned} turned lanes "
+                f"of a head of {head_dim}: one a pair is {turned // 2}"
+            )
     angles = positions[..., None].astype(jnp.float32) * freqs  # [B, S, t/2]
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     still = jnp.zeros((*angles.shape[:-1], head_dim - turned), jnp.float32)
     return (
         jnp.concatenate([cos, cos, still + 1.0], axis=-1),
@@ -106,7 +158,7 @@ def _turn_kernel(x_ref, cos_ref, sin_ref, o_ref, *, half: int, sign: float):
 def _turn(x, cos, sin, half: int, sign: float, interpret: bool, name: str):
     b, s, width = x.shape
     d = cos.shape[-1]
-    rows = flash._pick_block(_BLOCK_ROWS, s)
+    rows = flash._pick_block(_block_rows(width, x.dtype.itemsize), s)
     at = lambda i, j: (i, j, 0)
     return pl.pallas_call(
         functools.partial(_turn_kernel, half=half, sign=sign),
@@ -156,13 +208,18 @@ def rope(
     head_dim: int,
     mesh: Mesh | None = None,
     interpret: bool | None = None,
+    inv_freq=None,
+    scale: float = 1.0,
 ):
     """Rotary embeddings of x [B, S, H·D] (`head_dim` = D) at `positions`
     [B, S]; with `fraction` < 1 only the first `fraction * D` lanes of a
-    head turn. The kernel or the plain form, from the shapes and the
-    backend (module docstring); `interpret` forces the kernel, as
-    `flash_attention`'s does."""
-    cos, sin = rope_tables(positions, theta, head_dim, fraction)
+    head turn; `inv_freq` and `scale` as `rope_tables` takes them. The
+    kernel or the plain form, from the shapes and the backend (module
+    docstring); `interpret` forces the kernel, as `flash_attention`'s
+    does."""
+    cos, sin = rope_tables(
+        positions, theta, head_dim, fraction, inv_freq=inv_freq, scale=scale
+    )
     half = int(head_dim * fraction) // 2
     b, s, width = x.shape
     use_kernel = (

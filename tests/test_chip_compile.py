@@ -265,6 +265,74 @@ def test_latent_top_k_expert_kernels_compile_at_the_nemotron_cell_shape(
     assert tpu_kernel_calls(text) == 9
 
 
+@pytest.mark.parametrize("h, window, fwd, bwd", [
+    (72, 512, "flash_fwd_window", "flash_bwd_window_fused"),
+    (48, None, "flash_fwd_compact", "flash_bwd_fused"),
+])
+def test_band_and_global_kernels_compile_at_the_laguna_cell_shapes(
+    one_chip, h, window, fwd, bwd
+):
+    """`laguna-s-2.1-ep32.train-8k`'s two attention calls: one 8k
+    sequence, 72 query heads over 8 K/V heads under a window of 512 (a
+    group of 9: the band's tables, its three kinds of step, a dq ring of
+    two blocks) and 48 over 8 over the triangle (a group of 6)."""
+    q = jax.ShapeDtypeStruct((1, 8192, h, HEAD_DIM), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 8192, 8, HEAD_DIM), jnp.bfloat16, sharding=one_chip)
+    text, names = _compile(
+        jax.grad(lambda q, k, v: _loss(q, k, v, window=window), argnums=(0, 1, 2)),
+        q, kv, kv,
+    )
+    assert names == [fwd, "flash_delta", bwd]
+    assert tpu_kernel_calls(text) == 3
+    sched = flash_schedule(8192, 8192, head_dim=HEAD_DIM, window=window)
+    assert sched["bwd_fused"] and sched["lse_packed"]
+    assert sched["grid_steps"] == (15 if window else 36)
+
+
+def test_gated_top_10_expert_kernels_compile_at_the_laguna_cell_shape(
+    one_chip, monkeypatch
+):
+    """The expert layer's kernels at `laguna-s-2.1-ep32.train-8k`'s size:
+    8,192 tokens with 10 experts each of 256, 8 held, three matrices an
+    expert of 3072 x 1024, float32 weights, bfloat16 rows: the gated
+    expert (zaya's) with the rows' movers (nemotron's), packed rows of 24
+    x 128 lanes."""
+    from kubeflow_tpu.ops import moe
+
+    monkeypatch.setattr(moe, "kernels_compiled", lambda: True)
+    # the accepted cells' tiles stand
+    assert moe._gmm_tiles(3072, 1024) == (3072, 1024)
+    assert moe._gmm_tiles(1024, 3072, packed=True) == (1024, 3072)
+    assert moe._gmm_tiles(1024, 2688) == (1024, 2688)
+    assert moe._gmm_tiles(2048, 2048) == (2048, 2048)
+    assert moe._Packed.of(3072) == (128, 24, 24)
+    shape = lambda dims, dtype: jax.ShapeDtypeStruct(
+        dims, dtype, sharding=one_chip
+    )
+    into = shape((8, 3072, 1024), jnp.float32)
+
+    def loss(x, gate, w_gate, w_up, w_down, expert):
+        out = moe.expert_mlp(x, expert, gate, (w_gate, w_up, w_down), 0)
+        return out.astype(jnp.float32).sum()
+
+    text, names = _compile(
+        jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
+        shape((8192, 3072), jnp.bfloat16), shape((8192, 10), jnp.float32),
+        into, into, shape((8, 1024, 3072), jnp.float32),
+        shape((8192, 10), jnp.int32),
+    )
+    assert sorted(set(names)) == [
+        "moe_gmm_dlhs", "moe_gmm_dw", "moe_gmm_fwd", "moe_rows_sum",
+        "moe_rows_take",
+    ]
+    # three matmuls forward and three of each kind back; the rows taken
+    # once forward and once back, and summed once forward (traced, and
+    # dropped from a gradient of a sum) and once for each of the two
+    # matrices that read the rows
+    assert [names.count(n) for n in sorted(set(names))] == [3, 3, 3, 3, 2]
+    assert tpu_kernel_calls(text) == 13
+
+
 @pytest.mark.parametrize("tokens,k,held,d", [
     (8192, 22, 8, 1024),  # nemotron-3-super-tp2ep64.train-8k's
     (16384, 1, 8, 2048),  # zaya1-8b-ep2.train-8k's, which runs the gathers

@@ -197,3 +197,124 @@ def test_ring_flash_rejects_indivisible_sequence():
     q, k, v = _qkv(jax.random.PRNGKey(3), b=1, s=18, h=2, d=128)
     with pytest.raises(ValueError, match="divide"):
         ring_flash_attention(q, k, v, mesh, interpret=True)
+
+
+# -- a window: the band in the compact grid (ISSUE 34) -----------------------
+
+
+def _band(attn_kw, q, k, v):
+    group = q.shape[2] // k.shape[2]
+    rep = lambda x: jnp.repeat(x, group, axis=2)
+    flash = lambda q, k, v: flash_attention(q, k, v, interpret=True, **attn_kw)
+    dense = lambda q, k, v: dense_attention(
+        q, rep(k), rep(v), window=attn_kw["window"]
+    )
+    weigh = lambda f: lambda *a: jnp.sum(f(*a) * jnp.cos(f(*a)))
+    grads = lambda f: jax.grad(weigh(f), argnums=(0, 1, 2))(q, k, v)
+    return flash(q, k, v), dense(q, k, v), grads(flash), grads(dense)
+
+
+@pytest.mark.parametrize("group", [1, 6, 9])
+@pytest.mark.parametrize("window, block", [
+    (16, 16),   # a block
+    (5, 16),    # under a block
+    (24, 16),   # a block and a half
+    (33, 16),   # two blocks and one key: three blocks back
+    (1, 16),    # the query's own position alone
+    (8, 64),    # one block holds the whole sequence: both edges in it
+])
+def test_band_kernels_match_the_dense_band_mask(window, block, group):
+    """Forward and gradients of the band kernels (interpreter) against the
+    dense band mask, at windows that are a block, under a block and not a
+    multiple of one, at 1, 6 and 9 query heads a K/V head."""
+    s, hk = 64, 1 if group > 1 else 2
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(7), 3)
+    q = jax.random.normal(kq, (1, s, hk * group, 16))
+    k = jax.random.normal(kk, (1, s, hk, 16))
+    v = jax.random.normal(kv, (1, s, hk, 16))
+    out, ref, g_flash, g_dense = _band(
+        dict(window=window, block_q=block, block_k=block), q, k, v
+    )
+    np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+    for gf, gd, name in zip(g_flash, g_dense, "qkv"):
+        np.testing.assert_allclose(
+            gf, gd, atol=5e-5, rtol=5e-5, err_msg=f"d{name} mismatch"
+        )
+
+
+@pytest.mark.parametrize("s, window, blocks", [
+    (67, 9, (16, 16)),     # a padded tail: the body that masks by position
+    (128, 40, (64, 32)),   # uneven blocks: the rectangular grid
+    (512, 200, (128, 128)),  # lane tiles: bands of whole lanes, three kinds
+])
+def test_band_off_the_static_path_matches_the_dense_band_mask(s, window, blocks):
+    q, k, v = _qkv(jax.random.PRNGKey(8), 1, s, 2, 16)
+    out, ref, g_flash, g_dense = _band(
+        dict(window=window, block_q=blocks[0], block_k=blocks[1]), q, k, v
+    )
+    np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+    for gf, gd in zip(g_flash, g_dense):
+        np.testing.assert_allclose(gf, gd, atol=5e-5, rtol=5e-5)
+
+
+@pytest.mark.parametrize("window", [64, 65, 4096])
+def test_a_window_that_reaches_every_key_is_the_causal_call_bit_for_bit(window):
+    from kubeflow_tpu.testing.hlo import pallas_kernel_names
+
+    q, k, v = _qkv(jax.random.PRNGKey(9), 1, 64, 2, 16)
+    how = dict(block_q=16, block_k=16, interpret=True)
+    loss = lambda **kw: lambda q, k, v: jnp.sum(
+        jnp.sin(flash_attention(q, k, v, **how, **kw))
+    )
+    both = jax.value_and_grad(loss(window=window), argnums=(0, 1, 2))(q, k, v)
+    causal = jax.value_and_grad(loss(), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(jax.tree_util.tree_leaves(both), jax.tree_util.tree_leaves(causal)):
+        assert np.array_equal(a, b)
+    # ... because it is the same program
+    names = pallas_kernel_names(jax.grad(loss(window=window)), q, k, v)
+    assert names == ["flash_fwd_compact", "flash_delta", "flash_bwd_fused"]
+    names = pallas_kernel_names(jax.grad(loss(window=63)), q, k, v)
+    assert names == ["flash_fwd_window", "flash_delta", "flash_bwd_window_fused"]
+
+
+@pytest.mark.parametrize("kw, message", [
+    (dict(window=0), "a window of 0 key"),
+    (dict(window=8, causal=False), "looks back only"),
+])
+def test_a_window_that_is_none_is_refused(kw, message):
+    q, k, v = _qkv(jax.random.PRNGKey(10), 1, 32, 1, 16)
+    with pytest.raises(ValueError, match=message):
+        flash_attention(q, k, v, interpret=True, **kw)
+
+
+def test_attend_takes_a_window_on_both_paths_and_refuses_it_on_the_ring(devices):
+    from kubeflow_tpu.ops.attention import attend
+    from kubeflow_tpu.parallel import MeshSpec, build_mesh
+
+    kq, kk = jax.random.split(jax.random.PRNGKey(11))
+    q = jax.random.normal(kq, (2, 64, 6, 16))
+    k = jax.random.normal(kk, (2, 64, 2, 16))
+    dense = attend(q, k, k, mesh=None, impl="dense", window=12)
+    ref = dense_attention(
+        q, jnp.repeat(k, 3, axis=2), jnp.repeat(k, 3, axis=2), window=12
+    )
+    np.testing.assert_allclose(dense, ref, atol=1e-6)
+    np.testing.assert_allclose(
+        attend(q, k, k, mesh=None, impl="flash", window=12), ref,
+        atol=2e-5, rtol=2e-5,
+    )
+    on_mesh = attend(
+        q, k, k, mesh=build_mesh(MeshSpec(dp=2), devices[:2]), impl="flash",
+        window=12,
+    )
+    np.testing.assert_allclose(on_mesh, ref, atol=2e-5, rtol=2e-5)
+    # every key: the causal call
+    np.testing.assert_allclose(
+        attend(q, k, k, mesh=None, impl="dense", window=64),
+        attend(q, k, k, mesh=None, impl="dense"), atol=0,
+    )
+    ring = build_mesh(MeshSpec(sp=2), devices[:2])
+    with pytest.raises(ValueError, match="the ring path has no window"):
+        attend(q, k, k, mesh=ring, impl="auto", window=12)
+    with pytest.raises(ValueError, match="a window of 0 key"):
+        attend(q, k, k, mesh=None, impl="dense", window=0)

@@ -836,3 +836,113 @@ def test_layout_comes_from_the_head_size_alone(d, layout, transposes):
     )(q, kv, kv)
     assert _transposes_outside_kernels(fwd.jaxpr) == transposes // 2
     assert _transposes_outside_kernels(both.jaxpr) == transposes
+
+
+# -- a window's band (ISSUE 34) ---------------------------------------------
+
+
+def _band_by_hand(s, block, window, tile):
+    """(steps, diagonal, edge, interior, computed pairs, needed pairs) of
+    the band's compact grid, counted pair of positions by pair."""
+    nq = s // block
+    steps = diag = edge = interior = computed = 0
+    for i in range(nq):
+        for j in range(i + 1):
+            seen = [
+                (a, c) for a in range(i * block, (i + 1) * block)
+                for c in range(j * block, (j + 1) * block)
+                if 0 <= a - c < window
+            ]
+            if not seen:
+                continue
+            steps += 1
+            whole = len(seen) == block * block
+            diag += i == j
+            edge += i != j and not whole
+            interior += whole
+            if whole:
+                computed += block * block
+                continue
+            # a band of `tile` rows against the lane tiles its rows see
+            unit = 128 if block % 128 == 0 else block
+            for r0 in range(i * block, (i + 1) * block, tile):
+                cols = [c for a, c in seen if r0 <= a < r0 + tile]
+                if cols:
+                    lo = min(cols) // unit * unit
+                    hi = -(-(max(cols) + 1) // unit) * unit
+                    computed += tile * (hi - lo)
+    needed = sum(min(a + 1, window) for a in range(s))
+    return steps, diag, edge, interior, computed, needed
+
+
+@pytest.mark.parametrize("s, block, window, tile", [
+    (64, 16, 16, 16), (64, 16, 5, 16), (64, 16, 24, 16), (64, 16, 33, 16),
+    (64, 16, 1, 16),
+    (512, 256, 128, 128),   # bands of 128 in blocks of 256
+    (1024, 256, 300, 128),  # diagonal, an interior block, an edge
+    (1024, 512, 600, 128),  # the far edge crosses two distances
+])
+def test_the_schedules_band_fields_match_a_count_by_hand(s, block, window, tile):
+    sched = flash_schedule(s, s, block_q=block, block_k=block, window=window)
+    steps, diag, edge, interior, computed, needed = _band_by_hand(
+        s, block, window, tile
+    )
+    assert sched["compact"] and sched["window"] == window
+    assert sched["grid_steps"] == sched["band_steps"] == steps
+    assert (sched["diag_steps"], sched["edge_steps"], sched["interior_steps"]) == (
+        diag, edge, interior
+    )
+    assert sched["diag_tile"] == tile
+    assert sched["computed_pairs_over_needed"] == pytest.approx(computed / needed)
+    assert sched["bwd_total_grid_steps"] == steps and sched["bwd_fused"]
+
+
+def test_the_band_at_the_laguna_cells_shape():
+    """S = 8192 under a window of 512 in blocks of 1024: 15 of the
+    triangle's 36 block pairs, bands of 128 rows, 1.25 times the band's
+    pairs computed where the triangle under a mask would compute 8.9
+    times; the fused backward's ring holds two blocks, not eight."""
+    sched = flash_schedule(8192, 8192, window=512)
+    assert (sched["block_q"], sched["band_steps"], sched["grid_steps"]) == (1024, 15, 15)
+    assert (sched["diag_steps"], sched["edge_steps"], sched["interior_steps"]) == (8, 7, 0)
+    assert sched["diag_tile"] == 128
+    assert 1.2 < sched["computed_pairs_over_needed"] < 1.3
+    causal = flash_schedule(8192, 8192)
+    assert causal["grid_steps"] == 36 and causal["band_steps"] == 0
+    assert causal["window"] is None and causal["edge_steps"] == 0
+    assert causal["bwd_fused_vmem_bytes"] - sched["bwd_fused_vmem_bytes"] == (
+        (8 - 2) * 1024 * 128 * 4
+    )
+    # a window that reaches every key is the causal schedule
+    assert flash_schedule(8192, 8192, window=8192) == causal
+
+
+@pytest.mark.parametrize("window", [5, 24, 33])
+@pytest.mark.parametrize("group", [1, 6])
+def test_two_pass_band_backward_matches_the_fused_one(window, group):
+    """The fused backward's ring of reach + 1 slots against the two-pass
+    kernels on the same band."""
+    block, s, d = 16, 64, 16
+    kq, kk, kv, kd = jax.random.split(jax.random.PRNGKey(12), 4)
+    q = jax.random.normal(kq, (group, s, d))
+    k = jax.random.normal(kk, (1, s, d))
+    v = jax.random.normal(kv, (1, s, d))
+    do = jax.random.normal(kd, (group, s, d))
+    how = dict(window=window)
+    o, lse = _flash_fwd_impl(q, k, v, True, block, block, True, None, False, **how)
+    delta = _flash_delta_impl(o, do, block, True, False)
+    fused = _flash_bwd_kernels(
+        q, k, v, do, lse, delta, True, block, block, True, None, False, True, **how
+    )
+    two = _flash_bwd_kernels(
+        q, k, v, do, lse, delta, True, block, block, True, None, False, False, **how
+    )
+    for a, b in zip(fused, two):
+        np.testing.assert_allclose(a, b, atol=2e-5, rtol=2e-5)
+    names = pallas_kernel_names(
+        lambda *a: _flash_bwd_kernels(
+            *a, True, block, block, True, None, False, False, **how
+        ),
+        q, k, v, do, lse, delta,
+    )
+    assert names == ["flash_dq_window", "flash_dkv_window"]

@@ -158,3 +158,76 @@ def test_tables_are_one_head_wide_and_keep_the_lanes_that_stay():
     out = rope_folded(x, cos, sin, 4)
     np.testing.assert_array_equal(out[..., 8:16], x[..., 8:16])
     np.testing.assert_array_equal(out[..., 24:], x[..., 24:])
+
+
+# -- yarn: frequencies as data (ISSUE 34) ------------------------------------
+
+
+@pytest.mark.parametrize("theta, turned, factor, original, fast, slow", [
+    (500000.0, 64, 128.0, 8192, 32.0, 1.0),  # Laguna-S-2.1's global layers
+    (10000.0, 128, 4.0, 4096, 32.0, 1.0),
+    (500000.0, 8, 8.0, 16, 4.0, 1.0),        # tests/test_laguna.py's tiny one
+])
+def test_yarn_frequencies_are_the_published_formula(
+    theta, turned, factor, original, fast, slow
+):
+    import math
+
+    from kubeflow_tpu.ops.rope import yarn_inv_freq
+
+    got = yarn_inv_freq(
+        theta, turned, factor=factor, original_max=original, beta_fast=fast,
+        beta_slow=slow,
+    )
+    c = lambda r: turned * math.log(original / (2 * math.pi * r)) / (
+        2 * math.log(theta)
+    )
+    low, high = max(math.floor(c(fast)), 0), min(math.ceil(c(slow)), turned - 1)
+    want = []
+    for t in range(turned // 2):
+        extrap = theta ** (-2 * t / turned)
+        ramp = min(max((t - low) / (high - low), 0.0), 1.0)
+        want.append((extrap / factor) * ramp + extrap * (1 - ramp))
+    np.testing.assert_allclose(got, want, rtol=1e-6)
+    # fast pairs keep their frequency, slow ones are stretched by `factor`
+    assert got[0] == pytest.approx(1.0)
+    assert got[-1] == pytest.approx(theta ** (-(turned - 2) / turned) / factor, rel=1e-6)
+
+
+@pytest.mark.parametrize("kernel", [False, True])
+def test_tables_from_frequencies_and_a_scale_turn_like_the_formula(kernel):
+    """Half a head under yarn's tables times an attention factor: the
+    folded form and the kernel against the [B, S, H, D] formula written
+    out, the lanes that stay unscaled."""
+    from kubeflow_tpu.ops.rope import yarn_inv_freq
+
+    b, s, h, d, scale = 2, 64, 3, 128, 1.4852030263919618
+    x, positions = _x(b, s, h, d, jnp.float32)
+    inv_freq = yarn_inv_freq(500000.0, 64, factor=128.0, original_max=8192)
+    got = rope(
+        x.reshape(b, s, h * d), positions, 500000.0, 0.5, head_dim=d,
+        inv_freq=inv_freq, scale=scale, interpret=True if kernel else None,
+    ).reshape(x.shape)
+    angles = positions[..., None].astype(jnp.float32) * inv_freq
+    cos = scale * jnp.cos(angles)[:, :, None, :]
+    sin = scale * jnp.sin(angles)[:, :, None, :]
+    x1, x2 = x[..., :32], x[..., 32:64]
+    want = jnp.concatenate(
+        [x1 * cos - x2 * sin, x1 * sin + x2 * cos, x[..., 64:]], axis=-1
+    )
+    np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)
+    cos_t, sin_t = rope_tables(
+        positions, 500000.0, d, 0.5, inv_freq=inv_freq, scale=scale
+    )
+    assert np.all(cos_t[..., 64:] == 1.0) and np.all(sin_t[..., 64:] == 0.0)
+    with pytest.raises(ValueError, match="one a pair is 32"):
+        rope_tables(positions, 500000.0, d, 0.5, inv_freq=inv_freq[:8])
+
+
+def test_a_wide_row_takes_fewer_rows_a_block():
+    """72 heads of 128 in bfloat16: 64 rows a block, under the compiler's
+    scoped VMEM with both buffers; the accepted cells' widths keep 256."""
+    from kubeflow_tpu.ops.rope import _block_rows
+
+    assert _block_rows(16 * 128, 2) == 256 and _block_rows(8 * 128, 2) == 256
+    assert _block_rows(48 * 128, 2) == 128 and _block_rows(72 * 128, 2) == 64
