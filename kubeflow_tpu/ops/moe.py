@@ -28,10 +28,16 @@ x capacity` and not the buffer's length.
   column tile. A tile past `n_tiles` costs a grid step and nothing else:
   no matmul, no fetch and no write (every index is clamped to the last
   tile in use, whose output block stays resident and is written back
-  once). The two-matrix expert's `relu(.)^2` is the first matmul's
-  epilogue, on the float32 accumulator (it also leaves the
-  pre-activation z), and its derivative `2 relu(z) dh` is formed in VMEM
-  by the `dlhs` and `dw` calls that consume it.
+  once). The expert's activation is the epilogue, on the float32
+  accumulator, of the last matmul that reads the rows, which also
+  leaves the product itself: `relu(z)^2` of the two-matrix expert's
+  one, `silu(a) b` of the gated expert's second, which takes the
+  first's result a as one more operand. Its slope (`2 relu(z) dh`; `da
+  = dh b silu'(a)`, `db = dh silu(a)`) is formed in VMEM, in float32,
+  by the `dlhs` and `dw` calls that consume it, and the gated expert's
+  second `dlhs` adds onto the first's result in place: the gated expert
+  meets no XLA pass over its buffer, for the activation, its derivative
+  or the sum of its two operand gradients.
 - **`moe_gmm_dw`**: `dw[e] = lhs[rows of e].T @ g[rows of e]`, accumulated
   in float32 over an expert's tiles and written once.
 - **The rows' movers.** With one expert a token (`expert` [N]) the
@@ -51,15 +57,12 @@ x capacity` and not the buffer's length.
   not a legal DMA); the matmul before a `moe_rows_sum` writes its result
   that way.
 
-**Who may read what.** A result of a `moe_gmm_fwd` / `moe_gmm_dlhs` /
-`moe_rows_take` call holds unwritten rows past `n_tiles` unless it was
-asked to write zeros there (`zero_dead`). Only the kernels here and
-gathers by the plan's indices (which name rows in use only) read such a
-result; where an XLA pass over the whole buffer reads one (the gated
-expert's `silu(a) b` and its derivative, the sum of its two operand
-gradients), the kernel keeps writing zeros. The form of the expert picks
-that (`len(weights)`), the rank of `expert` picks the movers; nothing
-else does.
+**Who may read what.** Only the kernels here and gathers by the plan's
+indices (which name rows in use only) read a result of a `moe_gmm_fwd`
+/ `moe_gmm_dlhs` / `moe_rows_take` call; nothing is written past the
+tiles in use. The form of the expert (`len(weights)`) picks the
+activation, the rank of `expert` picks the movers; nothing else decides
+anything.
 
 `_experts_in` (dispatch, then the matmuls that read the rows) and
 `_experts_out` (the last matmul, then the combine) tie the kernels
@@ -101,6 +104,9 @@ _VMEM_LIMIT = 48 * 1024 * 1024
 _SUBLANES = 8  # a float32 tile's height: the least a DMA may slice
 _SUM_TOKENS = 256  # tokens a grid step of `moe_rows_sum`
 _SUM_VMEM = 8 * 1024 * 1024  # its gathered rows, [slots, tokens, d] float32
+# The forms of expert by their count of matrices: what the last matmul on
+# the rows makes of its product (`_activate`, `_slope`).
+_FORMS = {2: "relu2", 3: "gated"}
 
 
 def _tile(dim: int, cap: int) -> int:
@@ -217,7 +223,9 @@ def moe_schedule(
     (all of them where `live_tiles` is None): static, for tests and for
     reading a trace. `*_bytes` count a row tile's operands and results
     once at 2 bytes (4 where packed) and every held expert's weights once
-    a column tile."""
+    a column tile. `activation` says for each form of expert who forms
+    its activation and slope, `results_past_live` how many kernel results
+    are written past the tiles in use."""
     tiles = row_tiles(tokens, k, held, block_rows)
     live = tiles if live_tiles is None else live_tiles
     tc, to = _gmm_tiles(width_in, width_out)
@@ -230,8 +238,8 @@ def moe_schedule(
         "gmm_grid": grid, "gmm_grid_steps": math.prod(grid),
         "gmm_dead_steps": grid[0] * (tiles - live) * grid[2],
         "gmm_bytes": weights + 2 * rows(live) * (width_in + width_out),
-        "gmm_bytes_zeroing": weights + 2 * rows(live) * width_in
-        + 2 * rows(tiles) * width_out,
+        "activation": {form: "kernel" for form in _FORMS.values()},
+        "results_past_live": 0,
         "movers": "moe_rows" if k > 1 else "xla_gather",
         "rows_take_grid_steps": tiles,
         "rows_take_bytes": rows(live) * width_in * (4 + 2),
@@ -261,9 +269,24 @@ def _live_step(t, c, steps, nt):
     return jnp.where(t < nt[0], c, steps - 1)
 
 
-def _relu2_slope(g, z):
-    """`g * d relu(z)^2 / dz` in float32, rounded once to g's dtype."""
-    slope = 2.0 * jnp.maximum(z.astype(jnp.float32), 0.0)
+def _activate(pre, gate=None):
+    """The expert's activation of the float32 product `pre`: `relu(pre)^2`,
+    or `silu(gate) pre` of the gated expert."""
+    if gate is None:
+        return jnp.square(jnp.maximum(pre, 0.0))
+    return jax.nn.silu(gate.astype(jnp.float32)) * pre
+
+
+def _slope(g, saved, wrt: int):
+    """`g` times the activation's derivative by its product `wrt`, from
+    the products the forward kept: `(z,)` of `relu(z)^2`, `(a, b)` of
+    `silu(a) b`. Float32, rounded once to g's dtype."""
+    first, *second = (v.astype(jnp.float32) for v in saved)
+    if not second:
+        slope = 2.0 * jnp.maximum(first, 0.0)
+    else:
+        s = jax.nn.sigmoid(first)
+        slope = first * s if wrt else second[0] * s * (1.0 + first * (1.0 - s))
     return (g.astype(jnp.float32) * slope).astype(g.dtype)
 
 
@@ -308,21 +331,26 @@ class _Packed(NamedTuple):
 
         lax.fori_loop(0, count, one, 0)
 
-    def store(self, ref, value):
-        """value [R, d] into a packed block."""
+    def store(self, ref, value, onto=None):
+        """value [R, d] into a packed block, added to the packed block
+        `onto` where one is given."""
+        rows = value.shape[0]
         for s in range(self.pieces):
-            ref[pl.ds(s, value.shape[0], stride=self.sublanes), :] = value[
-                :, s * self.lanes:(s + 1) * self.lanes
-            ].astype(ref.dtype)
+            piece = value[:, s * self.lanes:(s + 1) * self.lanes]
+            if onto is not None:
+                piece = piece + self.piece(onto, s, rows)
+            ref[pl.ds(s, rows, stride=self.sublanes), :] = piece.astype(ref.dtype)
 
 
-def _gmm_kernel(te_ref, nt_ref, *refs, transpose_rhs: bool, relu2: bool,
-                slope: bool, zero_dead: bool, packed: bool):
+def _gmm_kernel(te_ref, nt_ref, *refs, transpose_rhs: bool, saved: int,
+                wrt: int, act: bool, gated: bool, onto: bool, packed: bool):
     del te_ref
     lhs_ref, rhs_ref, *refs = refs
-    z_ref = refs.pop(0) if slope else None
+    saved_refs, refs = refs[:saved], refs[saved:]
+    gate_ref = refs.pop(0) if gated else None
+    onto_ref = refs.pop(0) if onto else None
     out_ref, *refs = refs
-    pre_ref = refs.pop(0) if relu2 else None
+    pre_ref = refs.pop(0) if act else None
     (acc,) = refs
     t, c = pl.program_id(1), pl.program_id(2)
     live = t < nt_ref[0]
@@ -337,30 +365,28 @@ def _gmm_kernel(te_ref, nt_ref, *refs, transpose_rhs: bool, relu2: bool,
             ((1,), (0,)), ((), ())
         )
         lhs = lhs_ref[...]
-        if slope:
-            lhs = _relu2_slope(lhs, z_ref[...])
+        if saved:
+            lhs = _slope(lhs, [ref[...] for ref in saved_refs], wrt)
         acc[...] += lax.dot_general(
             lhs, rhs_ref[0], dims, preferred_element_type=jnp.float32,
         )
 
-    last = c == pl.num_programs(2) - 1
-
-    @pl.when(last if zero_dead else last & live)
+    @pl.when((c == pl.num_programs(2) - 1) & live)
     def _write():
         value = acc[...]
-        if relu2:
+        if act:
             pre_ref[...] = value.astype(pre_ref.dtype)
-            value = jnp.square(jnp.maximum(value, 0.0))
+            value = _activate(value, gate_ref[...] if gated else None)
         if packed:
-            _Packed.of(value.shape[1]).store(out_ref, value)
+            _Packed.of(value.shape[1]).store(out_ref, value, onto_ref)
         else:
+            if onto:
+                value += onto_ref[...].astype(jnp.float32)
             out_ref[...] = value.astype(out_ref.dtype)
 
 
-def _dw_kernel(te_ref, nt_ref, *refs, slope: bool):
-    lhs_ref, g_ref, *refs = refs
-    z_ref = refs.pop(0) if slope else None
-    out_ref, acc = refs
+def _dw_kernel(te_ref, nt_ref, lhs_ref, g_ref, *refs, wrt: int):
+    *saved_refs, out_ref, acc = refs
     t = pl.program_id(2)
     n_tiles = nt_ref[0]
     e = te_ref[t]
@@ -377,8 +403,8 @@ def _dw_kernel(te_ref, nt_ref, *refs, slope: bool):
     @pl.when(used)
     def _compute():
         g = g_ref[...]
-        if slope:
-            g = _relu2_slope(g, z_ref[...])
+        if saved_refs:
+            g = _slope(g, [ref[...] for ref in saved_refs], wrt)
         acc[...] += lax.dot_general(
             lhs_ref[...], g, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -392,19 +418,21 @@ def _dw_kernel(te_ref, nt_ref, *refs, slope: bool):
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "block_rows", "transpose_rhs", "relu2", "zero_dead", "packed",
-        "interpret",
+        "wrt", "block_rows", "transpose_rhs", "act", "packed", "interpret",
     ),
 )
-def _gmm(lhs, rhs, tile_expert, n_tiles, z=None, *, block_rows,
-         transpose_rhs=False, relu2=False, zero_dead=False, packed=False,
+def _gmm(lhs, rhs, tile_expert, n_tiles, saved=(), gate=None, onto=None, *,
+         wrt=0, block_rows, transpose_rhs=False, act=False, packed=False,
          interpret):
-    """One grouped matmul over the row buffer. `relu2`: the result is
-    `relu(.)^2` of the product and a second result is the product itself.
-    `z`: the operand is `lhs * 2 relu(z)`, formed in VMEM. `zero_dead`:
-    tiles past `n_tiles` are written, with zeros (an XLA pass reads the
-    result); else they are left alone. `packed`: the result is float32
-    and packed (`_Packed`: a `moe_rows_sum` reads it)."""
+    """One grouped matmul over the row buffer; nothing is written past
+    the tiles in use. `act`: the result is the expert's activation of the
+    product (`_activate`: `relu(.)^2`, or `silu(gate) .` with `gate`, the
+    gate's product [rows, cols]) and a second result is the product
+    itself. `saved`: the operand is `lhs` times the activation's slope by
+    its product `wrt` (`_slope`), formed in VMEM. `onto`: a result of
+    this call's own kind, which the product is added to in place.
+    `packed`: the result is float32 and packed (`_Packed`: a
+    `moe_rows_sum` reads it)."""
     rows, contract = lhs.shape
     cols = rhs.shape[1] if transpose_rhs else rhs.shape[2]
     tc, to = _gmm_tiles(contract, cols, packed)
@@ -423,46 +451,52 @@ def _gmm(lhs, rhs, tile_expert, n_tiles, z=None, *, block_rows,
     operand = pl.BlockSpec(
         (block_rows, tc), lambda o, t, c, te, nt: (_live(t, nt), at(t, c, nt))
     )
-    where = (lambda t, nt: t) if zero_dead else _live
-    result = pl.BlockSpec(
-        (block_rows, to), lambda o, t, c, te, nt: (where(t, nt), o)
+    plain = pl.BlockSpec(
+        (block_rows, to), lambda o, t, c, te, nt: (_live(t, nt), o)
     )
-    out_shape = jax.ShapeDtypeStruct((rows, cols), lhs.dtype)
+    result, out_shape = plain, jax.ShapeDtypeStruct((rows, cols), lhs.dtype)
     if packed:
         form = _Packed.of(cols)
         result = pl.BlockSpec(
-            form.shape(block_rows), lambda o, t, c, te, nt: (where(t, nt), 0)
+            form.shape(block_rows), lambda o, t, c, te, nt: (_live(t, nt), 0)
         )
         out_shape = jax.ShapeDtypeStruct(form.shape(rows), jnp.float32)
-    slope = z is not None
+    gated, added = gate is not None, onto is not None
+    operands = (
+        tile_expert, n_tiles, lhs, rhs, *saved, *([gate] * gated),
+        *([onto] * added),
+    )
     out = pl.pallas_call(
         functools.partial(
-            _gmm_kernel, transpose_rhs=transpose_rhs, relu2=relu2,
-            slope=slope, zero_dead=zero_dead, packed=packed,
+            _gmm_kernel, transpose_rhs=transpose_rhs, saved=len(saved),
+            wrt=wrt, act=act, gated=gated, onto=added, packed=packed,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(cols // to, rows // block_rows, steps),
-            in_specs=[operand, rhs_spec] + [operand] * slope,
-            out_specs=[result] * (1 + relu2),
+            in_specs=[operand, rhs_spec] + [operand] * len(saved)
+            + [plain] * gated + [result] * added,
+            out_specs=[result] * (1 + act),
             scratch_shapes=[pltpu.VMEM((block_rows, to), jnp.float32)],
         ),
-        out_shape=[out_shape] * (1 + relu2),
+        out_shape=[out_shape] * (1 + act),
+        input_output_aliases={len(operands) - 1: 0} if added else {},
         compiler_params=_params(("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
         name="moe_gmm_dlhs" if transpose_rhs else "moe_gmm_fwd",
-    )(tile_expert, n_tiles, lhs, rhs, *([z] * slope))
-    return tuple(out) if relu2 else out[0]
+    )(*operands)
+    return tuple(out) if act else out[0]
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("n_experts", "block_rows", "out_dtype", "interpret"),
+    static_argnames=("wrt", "n_experts", "block_rows", "out_dtype", "interpret"),
 )
-def _gmm_dw(lhs, g, tile_expert, n_tiles, z=None, *, n_experts, block_rows,
-            out_dtype, interpret):
-    """`dw[e] = lhs[rows of e].T @ g[rows of e]`; with `z`, g is `g * 2
-    relu(z)`, formed in VMEM."""
+def _gmm_dw(lhs, g, tile_expert, n_tiles, saved=(), *, wrt=0, n_experts,
+            block_rows, out_dtype, interpret):
+    """`dw[e] = lhs[rows of e].T @ g[rows of e]`; with `saved`, g is g
+    times the activation's slope by its product `wrt` (`_slope`), formed
+    in VMEM."""
     rows, k = lhs.shape
     cols = g.shape[1]
     to = _tile(cols, _TILE_SIDE)
@@ -470,9 +504,8 @@ def _gmm_dw(lhs, g, tile_expert, n_tiles, z=None, *, n_experts, block_rows,
     g_spec = pl.BlockSpec(
         (block_rows, to), lambda i, o, t, te, nt: (_live(t, nt), o)
     )
-    slope = z is not None
     return pl.pallas_call(
-        functools.partial(_dw_kernel, slope=slope),
+        functools.partial(_dw_kernel, wrt=wrt),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(k // tk, cols // to, rows // block_rows),
@@ -482,7 +515,7 @@ def _gmm_dw(lhs, g, tile_expert, n_tiles, z=None, *, n_experts, block_rows,
                     lambda i, o, t, te, nt: (_live(t, nt), i),
                 ),
                 g_spec,
-            ] + [g_spec] * slope,
+            ] + [g_spec] * len(saved),
             out_specs=pl.BlockSpec(
                 (1, tk, to), lambda i, o, t, te, nt: (te[_live(t, nt)], i, o)
             ),
@@ -492,7 +525,7 @@ def _gmm_dw(lhs, g, tile_expert, n_tiles, z=None, *, n_experts, block_rows,
         compiler_params=_params(("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="moe_gmm_dw",
-    )(tile_expert, n_tiles, lhs, g, *([z] * slope))
+    )(tile_expert, n_tiles, lhs, g, *saved)
 
 
 # -- the rows' movers at k > 1 -------------------------------------------------
@@ -721,9 +754,6 @@ class _How(NamedTuple):
     block_rows: int
     interpret: bool
     by_pairs: bool  # k > 1: the rows move by `moe_rows_*`, packed
-    relu2: bool  # two matrices an expert: the activation is the kernels'
-    # Else XLA's own passes read the matmuls' results, which hold zeros
-    # past the tiles in use.
 
 
 def _take(x, index):
@@ -732,72 +762,60 @@ def _take(x, index):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _experts_in(x, weights, plan, how: _How):
-    """x [N, d] to its rows, then `rows @ w[e]` for each w of `weights`
-    ([experts, d, f], any float dtype): a tuple of [rows, f], `relu(.)^2`
-    of it where `how.relu2`."""
+    """x [N, d] to its rows, then the expert's activation of `rows @
+    w[e]` for each w of `weights` ([experts, d, f], any float dtype; one
+    for `relu(.)^2`, gate and up for `silu(a) b`): [rows, f]."""
     return _experts_in_fwd(x, weights, plan, how)[0]
 
 
 def _experts_in_fwd(x, weights, plan, how):
     te, nt = plan["tile_expert"], plan["n_tiles"]
+    kernel = dict(block_rows=how.block_rows, interpret=how.interpret)
     with jax.named_scope("moe.dispatch"):
         if how.by_pairs:
             rows = _rows_take(
                 _pack(x), plan["row_token"], nt, width=x.shape[1],
-                block_rows=how.block_rows, out_dtype=x.dtype,
-                interpret=how.interpret,
+                out_dtype=x.dtype, **kernel,
             )
         else:
             rows = _take(x, plan["src"])
     with jax.named_scope("moe.experts"):
-        out = tuple(
-            _gmm(
-                rows, w.astype(rows.dtype), te, nt, block_rows=how.block_rows,
-                relu2=how.relu2, zero_dead=not how.relu2,
-                interpret=how.interpret,
-            )
-            for w in weights
+        *w_gate, w_in = (w.astype(rows.dtype) for w in weights)
+        gate = [_gmm(rows, w, te, nt, **kernel) for w in w_gate]
+        hidden, pre = _gmm(
+            rows, w_in, te, nt, gate=gate[0] if gate else None, act=True,
+            **kernel,
         )
-    z = None
-    if how.relu2:
-        ((hidden, z),) = out
-        out = (hidden,)
-    return out, (rows, weights, plan, z)
+    return hidden, (rows, weights, plan, (*gate, pre))
 
 
-def _experts_in_bwd(how, res, gs):
-    rows, weights, plan, z = res
+def _experts_in_bwd(how, res, g):
+    rows, weights, plan, saved = res
     te, nt = plan["tile_expert"], plan["n_tiles"]
     kernel = dict(block_rows=how.block_rows, interpret=how.interpret)
     with jax.named_scope("moe.experts"):
-        d_rows = [
-            _gmm(
-                g, w.astype(g.dtype), te, nt, z, transpose_rhs=True,
-                zero_dead=not how.relu2 and not how.by_pairs,
-                packed=how.by_pairs, **kernel,
+        d_rows = None  # each matrix's part is added onto the one before
+        for wrt, w in enumerate(weights):
+            d_rows = _gmm(
+                g, w.astype(g.dtype), te, nt, saved, onto=d_rows, wrt=wrt,
+                transpose_rhs=True, packed=how.by_pairs, **kernel,
             )
-            for w, g in zip(weights, gs)
-        ]
         d_weights = tuple(
             _gmm_dw(
-                rows, g, te, nt, z, n_experts=w.shape[0], out_dtype=w.dtype,
-                **kernel,
+                rows, g, te, nt, saved, wrt=wrt, n_experts=w.shape[0],
+                out_dtype=w.dtype, **kernel,
             )
-            for w, g in zip(weights, gs)
+            for wrt, w in enumerate(weights)
         )
     with jax.named_scope("moe.dispatch"):
         if how.by_pairs:
-            dx = sum(
-                _rows_sum(
-                    r, plan["token_rows"], plan["token_count"],
-                    width=rows.shape[1],
-                    out_dtype=jnp.float32 if len(d_rows) > 1 else rows.dtype,
-                    interpret=how.interpret,
-                )
-                for r in d_rows
-            ).astype(rows.dtype)
+            dx = _rows_sum(
+                d_rows, plan["token_rows"], plan["token_count"],
+                width=rows.shape[1], out_dtype=rows.dtype,
+                interpret=how.interpret,
+            )
         else:
-            dx = _take(sum(d_rows), plan["dst"])
+            dx = _take(d_rows, plan["dst"])
     return dx, d_weights, None
 
 
@@ -856,7 +874,7 @@ def _experts_out_bwd(how, res, g):
     with jax.named_scope("moe.experts"):
         d_hidden = _gmm(
             d_out, w_down.astype(d_out.dtype), te, nt, transpose_rhs=True,
-            zero_dead=not how.relu2, **kernel,
+            **kernel,
         )
         d_w = _gmm_dw(
             hidden, d_out, te, nt, n_experts=w_down.shape[0],
@@ -884,15 +902,12 @@ def expert_mlp(
     """
     if interpret is None:
         interpret = not kernels_compiled()
+    if len(weights) not in _FORMS:
+        raise ValueError(f"an expert of {len(weights)} matrices: {_FORMS}")
     *into, w_down = weights
-    how = _How(block_rows, interpret, expert.ndim == 2, len(into) == 1)
+    how = _How(block_rows, interpret, expert.ndim == 2)
     plan = plan_dispatch(expert, lo, w_down.shape[0], block_rows)
     hidden = _experts_in(x, tuple(into), plan, how)
-    if how.relu2:
-        (hidden,) = hidden
-    else:
-        with jax.named_scope("moe.experts"):
-            hidden = jax.nn.silu(hidden[0]) * hidden[1]
     weight = slot_weights(gate, plan) if how.by_pairs else gate
     return _experts_out(hidden, w_down, weight, plan, how)
 

@@ -1,7 +1,8 @@
 """The expert layer's passes stop at the row tiles in use (`ops/moe.py`):
 the dense loop over experts at every imbalance, k and form of expert; no
-result past `n_tiles` is ever consumed (the Pallas interpreter fills
-unwritten memory with NaN); the static schedule and the two counters."""
+result past `n_tiles` is ever written or consumed (the Pallas interpreter
+fills unwritten memory with NaN) and no XLA operation passes over the row
+buffer; the static schedule and the two counters."""
 
 import jax
 import jax.numpy as jnp
@@ -73,6 +74,16 @@ def _dense(x, gate, *w, expert, lo):
     return out
 
 
+def _setup(k, form, routing="uneven"):
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    expert = _experts(routing, k)
+    x = jax.random.normal(ks[0], (N, D))
+    gate = jax.random.uniform(ks[1], expert.shape, minval=0.1)
+    target = jax.random.normal(ks[2], (N, D))
+    mine = tuple(m[LO:LO + HELD] for m in _weights(form))
+    return expert, x, gate, target, mine
+
+
 @pytest.mark.parametrize("form", ["relu2", "gated"])
 @pytest.mark.parametrize("k", [0, 1, 2, 8])  # 0: `expert` [N]; 8 > held
 @pytest.mark.parametrize(
@@ -81,12 +92,7 @@ def _dense(x, gate, *w, expert, lo):
 def test_value_and_every_gradient_match_a_dense_loop(routing, k, form):
     """Dead tiles hold NaN under the interpreter: finite and equal means
     nothing past `n_tiles` was read."""
-    ks = jax.random.split(jax.random.PRNGKey(0), 3)
-    expert = _experts(routing, k)
-    x = jax.random.normal(ks[0], (N, D))
-    gate = jax.random.uniform(ks[1], expert.shape, minval=0.1)
-    target = jax.random.normal(ks[2], (N, D))
-    mine = tuple(m[LO:LO + HELD] for m in _weights(form))
+    expert, x, gate, target, mine = _setup(k, form, routing)
     ours = lambda x, gate, *w: moe.expert_mlp(
         x, expert, gate, w, LO, block_rows=ROWS
     )
@@ -104,52 +110,178 @@ def test_value_and_every_gradient_match_a_dense_loop(routing, k, form):
         np.testing.assert_allclose(a, b, atol=6e-5, rtol=3e-4)
 
 
-def test_results_past_the_tiles_in_use_are_never_written_nor_read():
-    """The poison is there: a grouped matmul's and a row mover's result is
-    NaN past `n_tiles` (the interpreter's unwritten memory) unless it was
-    asked for zeros, and what reads them stays finite."""
+@pytest.mark.parametrize("form", ["relu2", "gated"])
+@pytest.mark.parametrize("k", [0, 2])  # one expert a token, and k > 1
+def test_results_past_the_tiles_in_use_are_never_written_nor_read(
+    k, form, monkeypatch
+):
+    """The poison is there: every result of a grouped matmul and of a row
+    mover, forward and backward, is NaN past `n_tiles` (the interpreter's
+    unwritten memory), and the value and every gradient that read them
+    stay finite and match the dense loop."""
+    expert, x, gate, target, mine = _setup(k, form)
+    plan = moe.plan_dispatch(expert, LO, HELD, ROWS)
+    tiles = moe.row_tiles(N, max(k, 1), HELD, ROWS)
+    live = int(plan["n_tiles"][0]) * ROWS
+    assert HELD <= live // ROWS < tiles
+    results = []
+
+    def recorded(name):
+        kernel = getattr(moe, name)
+
+        def call(*args, **kw):
+            out = kernel(*args, **kw)
+            each = out if isinstance(out, tuple) else (out,)
+            results.extend((name, np.asarray(r)) for r in each)
+            return out
+
+        monkeypatch.setattr(moe, name, call)
+
+    for name in ("_gmm", "_rows_take"):
+        recorded(name)
+    ours = lambda x, gate, *w: moe.expert_mlp(
+        x, expert, gate, w, LO, block_rows=ROWS
+    )
+    theirs = lambda *a: _dense(*a, expert=expert, lo=LO)
+    every = tuple(range(2 + len(mine)))
+    loss = lambda f: lambda *a: (f(*a) * target).sum()
+    value, got = jax.value_and_grad(loss(ours), every)(x, gate, *mine)
+    want_value, want = jax.value_and_grad(loss(theirs), every)(x, gate, *mine)
+    np.testing.assert_allclose(value, want_value, rtol=1e-5)
+    for a, b in zip(got, want):
+        assert np.isfinite(np.asarray(a)).all()
+        np.testing.assert_allclose(a, b, atol=6e-5, rtol=3e-4)
+    # forward: the matmuls that read the rows (a result each, the last
+    # one two) and the one after; backward: one `dlhs` a matrix. At k > 1
+    # the rows are taken once each way, and their weights' products.
+    into = len(mine) - 1
+    assert [n for n, _ in results].count("_gmm") == (into + 1) + 1 + 1 + into
+    assert [n for n, _ in results].count("_rows_take") == (3 if k else 0)
+    for name, r in results:
+        if r.ndim == 1:  # the weights' gradient a row: dead rows unwritten too
+            assert np.isfinite(r[:live]).all() and np.isnan(r[live:]).all()
+            continue
+        by_row = r.reshape(tiles * ROWS, -1, r.shape[1])  # sublanes where packed
+        assert np.isfinite(by_row[:live, 0]).all(), name
+        assert np.isnan(by_row[live:]).all(), name
+
+
+@pytest.mark.parametrize("form", ["relu2", "gated"])
+def test_the_activation_and_its_slope_are_the_matmuls_own(form):
+    """The kernels one by one: the rows taken, the epilogue (the
+    activation beside the product itself), the packed result, the slope
+    formed on the way in by either product, and a `dlhs` added onto the
+    one before in place."""
     expert = _experts("uneven", 2)
     plan = moe.plan_dispatch(expert, LO, HELD, ROWS)
     live = int(plan["n_tiles"][0]) * ROWS
-    tiles = moe.row_tiles(N, 2, HELD, ROWS)
-    assert HELD <= live // ROWS < tiles
     x = jax.random.normal(jax.random.PRNGKey(1), (N, D))
-    w = _weights("relu2")[0][LO:LO + HELD]
+    *w_gate, w = (m[LO:LO + HELD] for m in _weights(form)[:-1])
     rows = moe._rows_take(
         moe._pack(x), plan["row_token"], plan["n_tiles"], width=D,
         block_rows=ROWS, out_dtype=x.dtype, interpret=True,
     )
-    assert np.isfinite(np.asarray(rows[:live])).all()
-    assert np.isnan(np.asarray(rows[live:])).all()
     token = np.asarray(plan["row_token"])[:live]
     np.testing.assert_array_equal(
         rows[:live], np.where((token < N)[:, None], np.asarray(x)[token % N], 0)
     )
-    mm = lambda **how: moe._gmm(
-        rows, w, plan["tile_expert"], plan["n_tiles"], block_rows=ROWS,
+    mm = lambda lhs, w, *more, **how: moe._gmm(
+        lhs, w, plan["tile_expert"], plan["n_tiles"], *more, block_rows=ROWS,
         interpret=True, **how,
     )
-    left, zeroed = np.asarray(mm()), np.asarray(mm(zero_dead=True))
-    assert np.isnan(left[live:]).all() and not zeroed[live:].any()
-    np.testing.assert_array_equal(left[:live], zeroed[:live])
-    hidden, pre = mm(relu2=True)
+    by_row = lambda w: w[plan["tile_expert"][: live // ROWS]].repeat(ROWS, axis=0)
+    left = np.asarray(mm(rows, w))
     np.testing.assert_allclose(
-        hidden[:live], np.square(np.maximum(left[:live], 0)), rtol=1e-6
+        left[:live], jnp.einsum("rd,rdf->rf", rows[:live], by_row(w)), atol=1e-5
     )
+    gate = [mm(rows, m) for m in w_gate]
+    hidden, pre = mm(rows, w, gate=gate[0] if gate else None, act=True)
     np.testing.assert_array_equal(pre[:live], left[:live])
+    if form == "relu2":
+        act = lambda z: jnp.square(jax.nn.relu(z))
+        saved, products = (pre,), (pre[:live],)
+    else:
+        act = lambda a, b: jax.nn.silu(a) * b
+        saved, products = (gate[0], pre), (gate[0][:live], pre[:live])
+    np.testing.assert_allclose(hidden[:live], act(*products), rtol=1e-5, atol=1e-6)
     # packed: a row's one piece of F lanes in the first of eight sublanes
-    packed = np.asarray(mm(packed=True)).reshape(-1, 8, F)[:live, 0]
+    packed = np.asarray(mm(rows, w, packed=True)).reshape(-1, 8, F)[:live, 0]
     np.testing.assert_array_equal(packed, left[:live])
-    # the slope of relu^2 formed on the way in
-    slope = moe._gmm(
-        hidden, w, plan["tile_expert"], plan["n_tiles"], pre,
-        block_rows=ROWS, transpose_rhs=True, interpret=True,
+    # the slope by each product, formed on the way in; the second call
+    # adds onto the first's result, plain and packed alike
+    g = jax.random.normal(jax.random.PRNGKey(3), hidden.shape)
+    slopes = jax.vjp(act, *products)[1](g[:live])
+    d_rows = d_packed = None
+    want = 0.0
+    for wrt, (m, slope) in enumerate(zip((*w_gate, w), slopes)):
+        d_rows = mm(g, m, saved, onto=d_rows, wrt=wrt, transpose_rhs=True)
+        d_packed = mm(
+            g, m, saved, onto=d_packed, wrt=wrt, transpose_rhs=True, packed=True
+        )
+        want = want + jnp.einsum("rf,rdf->rd", slope, by_row(m))
+        np.testing.assert_allclose(d_rows[:live], want, atol=1e-4, rtol=1e-4)
+        np.testing.assert_allclose(
+            np.asarray(d_packed).reshape(-1, 8, D)[:live, 0], want,
+            atol=1e-4, rtol=1e-4,
+        )
+        dw = moe._gmm_dw(
+            rows, g, plan["tile_expert"], plan["n_tiles"], saved, wrt=wrt,
+            n_experts=HELD, block_rows=ROWS, out_dtype=jnp.float32,
+            interpret=True,
+        )
+        onehot = jax.nn.one_hot(
+            plan["tile_expert"][: live // ROWS].repeat(ROWS), HELD
+        )
+        np.testing.assert_allclose(
+            dw, jnp.einsum("re,rd,rf->edf", onehot, rows[:live], slope),
+            atol=1e-4, rtol=1e-4,
+        )
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold, a
+    `pallas_call`'s body apart."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name != "pallas_call":
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from _equations(sub)
+
+
+@pytest.mark.parametrize("form", ["relu2", "gated"])
+@pytest.mark.parametrize("k", [0, 2])
+def test_no_xla_operation_passes_over_the_row_buffer(k, form):
+    """In the gradient of `expert_mlp`, rows bfloat16 and weights float32
+    as the cells have them, nothing outside a `pallas_call` computes on
+    an array of the row buffer's shape, `[rows, f]`, `[rows, d]` or
+    `[rows, d]` packed: with one expert a token the gathers by the
+    plan's indices, which name rows in use only, are all that touches
+    one; at k > 1 nothing is."""
+    expert, x, gate, target, mine = _setup(k, form)
+    x = x.astype(jnp.bfloat16)
+    rows = moe.row_tiles(N, max(k, 1), HELD, ROWS) * ROWS
+    buffer = {(rows, F), (rows, D), moe._Packed.of(D).shape(rows)}
+    assert (N, D) not in buffer
+
+    def loss(x, gate, *w):
+        out = moe.expert_mlp(x, expert, gate, w, LO, block_rows=ROWS)
+        return (out * target).sum()
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, tuple(range(2 + len(mine)))))(
+        x, gate, *mine
     )
-    want = jnp.einsum(
-        "rf,rdf->rd", hidden[:live] * 2 * jnp.maximum(pre[:live], 0),
-        w[plan["tile_expert"][: live // ROWS]].repeat(ROWS, axis=0),
-    )
-    np.testing.assert_allclose(slope[:live], want, atol=1e-4, rtol=1e-4)
+    holders = {"pallas_call", "pjit", "jit", "custom_vjp_call", "closed_call"}
+    touching, kernels = set(), 0
+    for eqn in _equations(jaxpr.jaxpr):
+        name = eqn.primitive.name
+        kernels += name == "pallas_call"
+        shapes = {
+            getattr(v.aval, "shape", None) for v in (*eqn.invars, *eqn.outvars)
+        }
+        if name not in holders and shapes & buffer:
+            touching.add(name)
+    assert kernels >= 3 * len(mine)
+    assert touching == (set() if k else {"gather"})
 
 
 def test_the_movers_at_a_width_of_several_lane_pieces():
@@ -221,11 +353,37 @@ def test_the_schedule_at_the_nemotron_cells_shape():
     assert live["rows_sum_grid_steps"] == 32
     weights = 2 * 8 * 1024 * 2688
     assert live["gmm_bytes"] == weights + 2 * 4096 * (1024 + 2688)
-    assert live["gmm_bytes_zeroing"] - live["gmm_bytes"] == 2 * 63488 * 2688
-    # zaya's: one expert a token, XLA's gathers, the tiles it had
-    zaya = moe.moe_schedule(16384, 1, 8, 2048, 2048)
+    assert live["activation"]["relu2"] == "kernel"
+    assert live["results_past_live"] == 0
+
+
+def test_the_schedule_at_the_gated_cells_shapes():
+    """Both forms' activation is the kernels' and no result is written
+    past the tiles in use. laguna's: 8,192 tokens, 10 of 256 experts
+    each, 8 held, 3072 -> 1024: 264 row tiles, 16 in use in each of its
+    four expert layers. zaya's: 16,384 tokens, one of 16 experts each, 8
+    held, 2048 -> 2048: 72 tiles, about half in use, XLA's gathers."""
+    for layer in (1, 2, 3, 4):
+        plan = moe.plan_dispatch(forced_experts(layer, 8192, 256, 10), 0, 8)
+        assert int(plan["n_tiles"][0]) == 16, layer
+    laguna = moe.moe_schedule(8192, 10, 8, 3072, 1024, live_tiles=16)
+    assert laguna["tiles"] == 264 and laguna["rows"] == 67584
+    assert laguna["rows_touched"] == 4096 and laguna["movers"] == "moe_rows"
+    assert laguna["gmm_grid"] == (1, 264, 1) and laguna["gmm_dead_steps"] == 248
+    live = [  # the batch's two sequences make the same selection
+        int(moe.plan_dispatch(
+            jnp.tile(forced_experts(layer, 8192, 16), 2), 0, 8
+        )["n_tiles"][0])
+        for layer in range(8)
+    ]
+    assert sum(live) == 286 and 34 <= min(live) <= max(live) <= 38
+    zaya = moe.moe_schedule(16384, 1, 8, 2048, 2048, live_tiles=36)
     assert zaya["tiles"] == 72 and zaya["movers"] == "xla_gather"
-    assert zaya["gmm_grid"] == (1, 72, 1)
+    assert zaya["gmm_grid"] == (1, 72, 1) and zaya["gmm_dead_steps"] == 36
+    for sched in (laguna, zaya):
+        assert sched["activation"] == {"relu2": "kernel", "gated": "kernel"}
+        assert sched["results_past_live"] == 0
+        assert "gmm_bytes_zeroing" not in sched
 
 
 def test_the_tiles_in_use_follow_the_rows():
