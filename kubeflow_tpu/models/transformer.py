@@ -473,7 +473,8 @@ class Attention(nn.Module):
                 fraction=kind.rope_fraction, head_dim=d, mesh=self.mesh,
                 **how,
             )
-            q, k = turn(q), turn(k)
+            with jax.named_scope("rope"):
+                q, k = turn(q), turn(k)
         scope = "cca.attend" if cfg.cca else "attend"
         if cfg.attention_kinds:  # a stack that mixes them tells them apart
             scope = "attend.full" if kind.window is None else "attend.window"
@@ -1265,15 +1266,18 @@ class TransformerLM(nn.Module):
             (cfg.vocab_size, cfg.d_model),
             jnp.float32,
         )
-        x = embed.astype(cfg.dtype)[tokens]
-        positions = jnp.broadcast_to(
-            jnp.arange(tokens.shape[1], dtype=jnp.int32), tokens.shape
-        )
-        # The first layer's router has no state before it: zeros.
-        router_state = (
-            jnp.zeros((*tokens.shape, cfg.router_hidden), jnp.float32)
-            if cfg.num_experts > 0 and cfg.router == "mlp" else None
-        )
+        # `embed` and `head` name what runs outside every layer for a
+        # profile's reader (`train/profiling.program_scopes`).
+        with jax.named_scope("embed"):
+            x = embed.astype(cfg.dtype)[tokens]
+            positions = jnp.broadcast_to(
+                jnp.arange(tokens.shape[1], dtype=jnp.int32), tokens.shape
+            )
+            # The first layer's router has no state before it: zeros.
+            router_state = (
+                jnp.zeros((*tokens.shape, cfg.router_hidden), jnp.float32)
+                if cfg.num_experts > 0 and cfg.router == "mlp" else None
+            )
         for i, layer_cls in enumerate(_layer_classes(cfg)):
             x, router_state = layer_cls(
                 cfg, self.mesh, layer=i, name=f"layer_{i}"
@@ -1290,4 +1294,5 @@ class TransformerLM(nn.Module):
                 (cfg.vocab_size, cfg.d_model),
                 jnp.float32,
             )
-        return lm_head(x, head, dtype=cfg.dtype)
+        with jax.named_scope("head"):
+            return lm_head(x, head, dtype=cfg.dtype)

@@ -256,6 +256,7 @@ def fit(
 
     with timings.span("init"):
         step_fn = trainer.make_train_step()
+    noted = False  # the step's arguments, for `trainer.step_scopes()`
     it = iter(data)
     history: list[dict] = []
     t_last = time.perf_counter()
@@ -381,6 +382,9 @@ def fit(
                         ) from None
                 if profiler is not None:
                     profiler.before_step(step)
+                if not noted:
+                    trainer.note_step_arguments(state, batch)
+                    noted = True
                 with timings.span("dispatch"):
                     state, metrics = step_fn(state, batch)
                 if profiler is not None:
@@ -533,6 +537,7 @@ def fit(
                             _load_data_state(data, data_state)
                             it = iter(data)
                             step_fn = trainer.make_train_step()
+                            noted = False
                         event = ResizeEvent(
                             step=at_step,
                             from_dp=from_dp,

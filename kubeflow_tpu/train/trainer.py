@@ -26,6 +26,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from kubeflow_tpu.parallel import sharding as shlib
 from kubeflow_tpu.parallel.mesh import step_compiler_options
+from kubeflow_tpu.train import profiling
 
 
 def _ensure_partitionable_rng() -> None:
@@ -262,6 +263,7 @@ class Trainer:
         self.label_key = label_key
         self._shardings = None
         self._abstract = None
+        self._step_program: profiling.StepProgram | None = None
 
     # -- state construction ------------------------------------------------
 
@@ -442,17 +444,18 @@ class Trainer:
                     loss = out
                     acc = jnp.zeros(())
                 else:
-                    loss = softmax_cross_entropy(
-                        out, mb[label_key], cfg.label_smoothing
-                    )
-                    acc = (
-                        jnp.mean(
-                            (jnp.argmax(out, -1) == mb[label_key])
-                            .astype(jnp.float32)
+                    with jax.named_scope("loss"):
+                        loss = softmax_cross_entropy(
+                            out, mb[label_key], cfg.label_smoothing
                         )
-                        if has_acc
-                        else jnp.zeros(())
-                    )
+                        acc = (
+                            jnp.mean(
+                                (jnp.argmax(out, -1) == mb[label_key])
+                                .astype(jnp.float32)
+                            )
+                            if has_acc
+                            else jnp.zeros(())
+                        )
                 return loss, (
                     new_vars.get("batch_stats", stats_in), acc,
                     _summed_by_name(new_vars.get("counters", {})),
@@ -522,8 +525,13 @@ class Trainer:
             metrics = {"loss": loss, "counters": counters}
             if has_acc:
                 metrics["accuracy"] = acc
+            # The scopes name the device's time for a profile's reader
+            # (`profiling.program_scopes`); they change no operation.
             if guard is None:
-                state = state.apply_gradients(grads=grads, batch_stats=bstats)
+                with jax.named_scope(profiling.UPDATE_SCOPE):
+                    state = state.apply_gradients(
+                        grads=grads, batch_stats=bstats
+                    )
                 return state, metrics
 
             # Anomaly guard: screen this step's loss/grad-norm AND the
@@ -537,36 +545,72 @@ class Trainer:
             # checkpoint/data bookkeeping stays step-aligned. The
             # verdict never syncs to the host — the select + isfinite
             # cost extra HBM passes over the state, not a device fence.
-            grad_norm = optax.global_norm(grads)
-            applied = state.apply_gradients(grads=grads, batch_stats=bstats)
-            # batch_stats are screened too: a huge-but-finite poison
-            # batch can keep loss/grads/params finite (BN normalizes it
-            # away) while its batch variance overflows the f32 running
-            # stats to inf — accepted, that inf rides into every later
-            # checkpoint and breaks eval/serving (train=False).
-            update_finite = jnp.bool_(True)
-            for leaf in jax.tree_util.tree_leaves(
-                (applied.params, applied.batch_stats)
-            ):
-                if jnp.issubdtype(leaf.dtype, jnp.floating):
-                    update_finite &= jnp.all(jnp.isfinite(leaf))
-            gstate, ok = guard.apply(
-                state.guard, loss, grad_norm, update_finite=update_finite
-            )
-            applied = applied.replace(guard=gstate)
-            skipped = state.replace(step=state.step + 1, guard=gstate)
-            state = jax.tree_util.tree_map(
-                lambda a, b: jnp.where(ok, a, b), applied, skipped
-            )
+            with jax.named_scope(profiling.UPDATE_SCOPE):
+                applied = state.apply_gradients(
+                    grads=grads, batch_stats=bstats
+                )
+            with jax.named_scope("guard"):
+                grad_norm = optax.global_norm(grads)
+                # batch_stats are screened too: a huge-but-finite poison
+                # batch can keep loss/grads/params finite (BN normalizes it
+                # away) while its batch variance overflows the f32 running
+                # stats to inf — accepted, that inf rides into every later
+                # checkpoint and breaks eval/serving (train=False).
+                update_finite = jnp.bool_(True)
+                for leaf in jax.tree_util.tree_leaves(
+                    (applied.params, applied.batch_stats)
+                ):
+                    if jnp.issubdtype(leaf.dtype, jnp.floating):
+                        update_finite &= jnp.all(jnp.isfinite(leaf))
+                gstate, ok = guard.apply(
+                    state.guard, loss, grad_norm, update_finite=update_finite
+                )
+                applied = applied.replace(guard=gstate)
+                skipped = state.replace(step=state.step + 1, guard=gstate)
+                state = jax.tree_util.tree_map(
+                    lambda a, b: jnp.where(ok, a, b), applied, skipped
+                )
             metrics.update(guard.metrics(gstate, ok, grad_norm))
             return state, metrics
 
-        return jax.jit(
+        step = jax.jit(
             train_step,
             donate_argnums=0,
             out_shardings=(self.state_shardings(), None),
             compiler_options=step_compiler_options(self.mesh),
         )
+        # Under the name a profile's `XLA Modules` line prints: how a
+        # reader of the profile finds this step's table of scopes.
+        self._step_program = profiling.StepProgram(
+            step, root=type(self.model).__name__
+        )
+        profiling.step_programs()[f"jit_{train_step.__name__}"] = (
+            self._step_program
+        )
+        return step
+
+    def note_step_arguments(self, state: TrainState, batch) -> None:
+        """What `fit()` tells the trainer before its first step: the
+        shapes, dtypes and shardings the step really runs with, kept as
+        `ShapeDtypeStruct`s for `step_scopes()`."""
+        if self._step_program is not None:
+            self._step_program.note(state, batch)
+
+    def step_scopes(self, batch=None) -> dict[str, profiling.Scope]:
+        """Where each instruction of the compiled train step came from
+        (`profiling.program_scopes`): module path, phase, kind. Made on
+        demand and kept: the step `make_train_step()` built is lowered and
+        compiled at the arguments `fit()` noted: out of jit's own caches
+        where they still hold the real step, else compiled again
+        (`profiling.StepProgram.text`). A trainer that has not
+        stepped gives `abstract_state()` and needs a `batch` (arrays or
+        `ShapeDtypeStruct`s)."""
+        if self._step_program is None:
+            self.make_train_step()
+        program = self._step_program
+        if batch is not None:
+            program.note(self.abstract_state(), batch)
+        return program()
 
     def make_eval_step(self):
         cfg = self.config
