@@ -200,6 +200,7 @@ def _build_fused_flash_grad() -> Program:
     import jax
     import jax.numpy as jnp
 
+    from kubeflow_tpu.models.transformer import KERNEL_RESULTS
     from kubeflow_tpu.ops import flash
     from kubeflow_tpu.testing.hlo import pallas_kernel_names
 
@@ -218,10 +219,9 @@ def _build_fused_flash_grad() -> Program:
         )
 
     grads = lambda f: jax.grad(f, argnums=(0, 1, 2))
-    # What `remat_policy="flash"` pins round a block (`_block_cls`).
-    pinned = jax.checkpoint_policies.save_only_these_names(
-        flash.CHECKPOINT_OUT_NAME, flash.CHECKPOINT_LSE_NAME
-    )
+    # What `remat_policy="flash"` pins round a block whatever its plan
+    # admits beside (`_block_cls`).
+    pinned = jax.checkpoint_policies.save_only_these_names(*KERNEL_RESULTS)
     grad_ckpt = grads(jax.checkpoint(loss, policy=pinned))
     kernels_plain = pallas_kernel_names(grads(loss), q, k, v)
     kernels_ckpt = pallas_kernel_names(grad_ckpt, q, k, v)

@@ -29,18 +29,22 @@ from typing import Any
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from kubeflow_tpu.ops.attention import attend
 from kubeflow_tpu.ops.flash import CHECKPOINT_LSE_NAME, CHECKPOINT_OUT_NAME
-from kubeflow_tpu.ops.moe import expert_mlp_on_mesh, row_tiles, tiles_in_use
+from kubeflow_tpu.ops.moe import (
+    BLOCK_ROWS, expert_mlp_on_mesh, row_tiles, tiles_in_use,
+)
 from kubeflow_tpu.ops.rope import rope, yarn_inv_freq
 from kubeflow_tpu.ops.ssd import (
     CHECKPOINT_OUT_NAME as SSD_OUT_NAME,
     CHECKPOINT_STATES_NAME as SSD_STATES_NAME,
     ssd_scan,
 )
-from kubeflow_tpu.parallel.sharding import batch_axes
+from kubeflow_tpu.parallel.sharding import batch_axes, batch_shard_count
+from kubeflow_tpu.utils import memory
 
 
 @dataclasses.dataclass(frozen=True)
@@ -73,12 +77,20 @@ class TransformerConfig:
     dtype: Any = jnp.bfloat16
     # What the backward recomputes (`_block_cls`), by what a cell shows:
     #   "none"  — nothing: every activation saved. Fastest wherever it
-    #             fits (three of the benchmark's cells run it).
-    #   "flash" — the block, but for the flash kernels' named output and
-    #             log-sum-exp, so the forward kernel never re-runs in
-    #             the backward (`zaya1-8b-ep2.train-8k`, which does not
-    #             fit otherwise). Under dense attention nothing is
-    #             named: the same program as "full".
+    #             fits (the benchmark's three dense cells run it).
+    #   "flash" — the layer, but for the results its kernels name
+    #             (attention's output and log-sum-exp, the scan's output
+    #             and chunk states: no forward kernel runs again) and as
+    #             many of the results the layers name (`SAVED_RESULTS`:
+    #             the router's, the gate's, the residual after attention,
+    #             q, k and v, the projections into the mixer, the latent
+    #             and the MLPs' hidden axis) as the device's free memory
+    #             admits, which `remat_plan` reckons from the shapes and
+    #             what the trainer states (`utils/memory.py`). The
+    #             benchmark's three sparse cells run it. With nothing
+    #             stated (serving, `eval`, the CPU) the kernels' results
+    #             alone; under dense attention those are not named: the
+    #             same program as "full".
     #   "mlp"   — the MLP half only; attention's residuals stay saved.
     #             No cell: less memory than "none", more than "flash".
     #   "full"  — the whole block from its input. No cell: least memory.
@@ -210,9 +222,60 @@ def _attention_kinds(cfg: TransformerConfig) -> list[AttentionKind]:
     return [kinds[k] for k in pattern]
 
 
-def _block_cls(cfg: "TransformerConfig", cls=None):
+# What `remat_policy="flash"` always keeps: the results of the attention
+# and scan kernels, which name them themselves (`ops/flash.py`,
+# `ops/ssd.py`), so that no forward kernel runs again in the backward.
+KERNEL_RESULTS = (
+    CHECKPOINT_OUT_NAME, CHECKPOINT_LSE_NAME, SSD_OUT_NAME, SSD_STATES_NAME,
+)
+# Results the layers name where they form them (`checkpoint_name`: a name
+# lowers to no operation), for `remat_plan` to keep as many of as the
+# device's free memory admits. A name sits on the value the backward
+# READS: the product under a sigmoid, not the sigmoid (whose slope reads
+# its own result). q, k and v are named as the kernels read them, after
+# rope, and not at all under CCA: its mixing's backward reads the
+# projections' results, so they are formed again whatever is kept behind
+# it (zaya: ~0 ms spared for 0.40 GB; kept where the projections leave
+# them, -2.9 %: the [B, S, H, D] mixing pays a relayout of the kept
+# [B, S, H·D] arrays, forward and again; my chip runs, PR 38).
+ROUTE_RESULT = "moe_route"        # the router's product (or the router MLP's
+                                  # state and logits), the chosen experts,
+                                  # their scores and their weights
+GATE_RESULT = "attn_gate"         # the attention gate's product
+RESIDUAL_RESULT = "attn_residual"  # a `Block`'s stream after attention
+LATENT_RESULT = "moe_latent_in"   # the experts' input in the latent
+IN_PROJ_RESULT = "ssm_in_proj"    # the mixer's in-projection
+HIDDEN_RESULT = "mlp_hidden"      # a dense or shared MLP's hidden results
+QKV_RESULT = "attn_qkv"           # q, k and v as the kernels read them
+CONV_RESULT = "ssm_conv"          # the mixer's convolved x, B and C
+# The order they are admitted in: milliseconds of the backward's second
+# forward spared a GB held, the small ones first. Timed on the v5e in the
+# benchmark's three `flash` cells: `r` of the `[scopes]` line of a traced
+# run at the parent commit and with the name kept (my chip runs, PR 36
+# and PR 38; PERF.md §5, §6), ms a step spared / GB held:
+#   attn_gate      laguna 0.6 / 0.021 (the product; the widening stays)
+#   moe_route      nemotron 12.0 / 0.147, laguna 4.0 / 0.084, zaya 2.7 / 0.20
+#   attn_residual  laguna 10.5 / 0.25 (`wo`, K = 9,216); zaya 3.4 / 0.54
+#   moe_latent_in  nemotron 2.0 / 0.084
+#   ssm_in_proj    nemotron 18.0 / 0.77
+#   mlp_hidden     nemotron 10.1 / 0.44 (the shared expert), laguna 9.7 / 0.54
+#   attn_qkv       laguna 12.5 / 0.82 (rope's turn with it); none under CCA
+#   ssm_conv       nemotron 3.6-5.8 / 0.42 (`silu`'s slope still forms the
+#                  float32 pre-activation again)
+SAVED_RESULTS = (
+    GATE_RESULT, ROUTE_RESULT, RESIDUAL_RESULT, LATENT_RESULT, IN_PROJ_RESULT,
+    HIDDEN_RESULT, QKV_RESULT, CONV_RESULT,
+)
+
+
+def _block_cls(cfg: "TransformerConfig", cls=None, keep: tuple[str, ...] = ()):
     """Block (or `cls`, a layer of a pattern), wrapped per the config's
-    remat policy."""
+    remat policy. Under "flash" the checkpoint saves `KERNEL_RESULTS`,
+    so the backward's partial eval dead-codes the forward kernels (any
+    other checkpoint whose boundary crosses the flash custom_vjp re-runs
+    the forward kernel to rebuild lse), and `keep`, the names of
+    `SAVED_RESULTS` the plan admitted (`remat_plan`); everything else of
+    the layer is formed again from its input."""
     cls = cls or Block
     if cfg.remat_policy in ("none", "mlp"):
         # No checkpoint round the block ("mlp": `Block` and `Sublayer`
@@ -222,25 +285,195 @@ def _block_cls(cfg: "TransformerConfig", cls=None):
     if cfg.remat_policy == "full":
         return nn.remat(cls, static_argnums=())
     if cfg.remat_policy == "flash":
-        # The kernel names its output and its (lane-packed) lse, the
-        # policy pins both, and the backward's partial eval dead-codes
-        # the forward kernel: q/k/v recompute from the cheap projections,
-        # o/lse come from the saved residuals. Any other checkpoint whose
-        # boundary crosses the flash custom_vjp re-runs the forward kernel
-        # to rebuild lse. The chunked scan names its output and its chunk
-        # states the same way (`ops/ssd.py`).
         return nn.remat(
             cls,
             static_argnums=(),
             policy=jax.checkpoint_policies.save_only_these_names(
-                CHECKPOINT_OUT_NAME, CHECKPOINT_LSE_NAME,
-                SSD_OUT_NAME, SSD_STATES_NAME,
+                *KERNEL_RESULTS, *keep
             ),
         )
     raise ValueError(
         f"unknown remat_policy {cfg.remat_policy!r}; expected 'none', "
         "'full', 'mlp' or 'flash'"
     )
+
+
+@dataclasses.dataclass(frozen=True)
+class RematPlan:
+    """What `remat_policy="flash"` keeps beside `KERNEL_RESULTS`
+    (`remat_plan`): the names admitted, in `SAVED_RESULTS`' order;
+    per-device bytes of every name the configuration forms, over the
+    layers that form it (those not admitted were `refused`); the bytes
+    held by the admitted; and the step's predicted peak with them (None
+    where no limit was known)."""
+
+    names: tuple[str, ...] = ()
+    bytes: tuple[tuple[str, int], ...] = ()
+    saved_bytes: int = 0
+    predicted_peak: int | None = None
+
+    @property
+    def refused(self) -> tuple[str, ...]:
+        return tuple(n for n, _ in self.bytes if n not in self.names)
+
+
+def _lanes(width: int) -> int:
+    """What a minor dimension of `width` takes on the chip, where an
+    array's last axis lies in tiles of 128 lanes."""
+    return -(-width // 128) * 128
+
+
+def _result_bytes(cfg: "TransformerConfig", tokens: int) -> list[dict]:
+    """Bytes of each of `SAVED_RESULTS` as a layer forms it, named there
+    or not, a dict a layer, for `tokens` tokens a device, every width
+    whole: where a `tp` axis splits heads or the MLP's hidden axis a
+    device holds less (no cell runs `flash` there: an upper bound,
+    PERF.md §7)."""
+    act = jnp.dtype(cfg.dtype).itemsize
+
+    def attention(out: dict, kind: AttentionKind):
+        hk = cfg.n_kv_heads or kind.n_heads
+        out[QKV_RESULT] = tokens * act * (
+            _lanes(kind.n_heads * cfg.head_dim) + 2 * _lanes(hk * cfg.head_dim)
+        )
+        if cfg.attention_gate:
+            out[GATE_RESULT] = tokens * _lanes(kind.n_heads) * 4
+
+    def mlp(out: dict, d_ff: int):
+        arrays = 2 if _mlp_cls(cfg) is SwiGLU else 1
+        out[HIDDEN_RESULT] = arrays * tokens * _lanes(d_ff) * act
+
+    def experts(out: dict):
+        k = _lanes(cfg.experts_per_token)
+        out[ROUTE_RESULT] = tokens * 4 * (
+            # the carried state and the logits; or the product, the chosen
+            # experts, their scores and their weights
+            _lanes(cfg.router_hidden) + _lanes(cfg.num_experts)
+            if cfg.router == "mlp" else _lanes(cfg.num_experts) + 3 * k
+        )
+        if cfg.moe_latent:
+            out[LATENT_RESULT] = tokens * _lanes(cfg.moe_latent) * act
+        if cfg.moe_shared_ff:
+            mlp(out, cfg.moe_shared_ff)
+
+    def mixer(out: dict):
+        d_in = cfg.ssm_heads * cfg.ssm_head_dim
+        xbc = d_in + 2 * cfg.ssm_groups * cfg.ssm_state
+        out[IN_PROJ_RESULT] = tokens * _lanes(d_in + xbc + cfg.ssm_heads) * act
+        out[CONV_RESULT] = tokens * _lanes(xbc) * act
+
+    layers = []
+    if cfg.layer_pattern is None:
+        for i, kind in enumerate(_attention_kinds(cfg)):
+            out = {RESIDUAL_RESULT: tokens * _lanes(cfg.d_model) * act}
+            attention(out, kind)
+            if cfg.num_experts > 0 and i >= cfg.dense_layers:
+                experts(out)
+            else:
+                mlp(out, cfg.dense_d_ff if i < cfg.dense_layers else cfg.d_ff)
+            layers.append(out)
+    else:
+        form = {
+            "M": mixer, "E": experts,
+            "*": lambda out: attention(out, _own_kind(cfg)),
+        }
+        for kind in cfg.layer_pattern:
+            layers.append({})
+            form[kind](layers[-1])
+    return layers
+
+
+# Memory the plan leaves free under the device's limit beyond its own
+# prediction (the allocator's fragmentation, another program's code), and
+# what it counts for the step's own code (the chip's compiler's figure
+# for the three cells: 0.21-0.45 GB, PERF.md §6, PR 38).
+REMAT_MARGIN_BYTES = 1 << 29
+REMAT_CODE_BYTES = 1 << 28
+
+
+def _kept_always_bytes(cfg: "TransformerConfig", tokens: int) -> int:
+    """Bytes every layer's checkpoint holds whatever the plan:
+    its inputs (the residual stream; the router's carried state) and
+    `KERNEL_RESULTS` (attention's output and log-sum-exp, the scan's
+    output and chunk states)."""
+    act = jnp.dtype(cfg.dtype).itemsize
+    stream = tokens * _lanes(cfg.d_model) * act
+    if cfg.num_experts > 0 and cfg.router == "mlp":
+        stream += tokens * _lanes(cfg.router_hidden) * 4
+
+    def attention(kind: AttentionKind) -> int:
+        return tokens * (
+            _lanes(kind.n_heads * cfg.head_dim) * act + _lanes(kind.n_heads) * 4
+        )
+
+    if cfg.layer_pattern is None:
+        return sum(stream + attention(k) for k in _attention_kinds(cfg))
+    d_in = cfg.ssm_heads * cfg.ssm_head_dim
+    scan = (tokens + -(-tokens // cfg.ssm_chunk) * cfg.ssm_state) * d_in * act
+    return sum(
+        stream + {"M": scan, "*": attention(_own_kind(cfg))}.get(kind, 0)
+        for kind in cfg.layer_pattern
+    )
+
+
+def _step_floor(
+    cfg: "TransformerConfig", tokens: int, layers: list[dict],
+    stated: memory.StepMemory,
+) -> int:
+    """Per-device bytes of a step that keeps no name, at its fullest: an
+    UPPER bound of the chip's compiler's `memory_analysis()` (arguments,
+    temporaries and code) in the benchmark's three `flash` cells, by
+    0.2-1.0 GB (PERF.md §6, PR 38). The larger of two moments. The loss:
+    the state, what every checkpoint holds, the float32 logits and their
+    cotangent. The last layer's backward: the state, every gradient (as
+    if no update were fused into a gradient's matmul), one worst-case row
+    buffer of the expert layer at its widest, and what the widest layer
+    forms again. A result kept adds its bytes to either."""
+    act = jnp.dtype(cfg.dtype).itemsize
+    logits = tokens * _lanes(cfg.vocab_size) * 4
+    at_loss = _kept_always_bytes(cfg, tokens) + 2 * logits
+    rows = 0
+    if cfg.num_experts > 0:
+        _, held = cfg.experts_held or (0, cfg.num_experts)
+        rows = (
+            row_tiles(tokens, cfg.experts_per_token, held) * BLOCK_ROWS
+            * max(cfg.moe_latent or cfg.d_model, cfg.d_ff) * act
+        )
+    widest = max((sum(layer.values()) for layer in layers), default=0)
+    in_backward = stated.grad_bytes + rows + widest
+    return stated.state_bytes + max(at_loss, in_backward) + REMAT_CODE_BYTES
+
+
+def remat_plan(
+    cfg: "TransformerConfig",
+    tokens: int,
+    stated: memory.StepMemory | None,
+) -> RematPlan:
+    """What a step over `tokens` tokens a device keeps of `SAVED_RESULTS`
+    under `remat_policy="flash"`, beside `KERNEL_RESULTS`: each name in
+    its order whose bytes, over the layers that form it, still leave the
+    predicted peak (`_step_floor` and the names admitted so far)
+    `REMAT_MARGIN_BYTES` under the device's limit; a name that does not
+    fit is refused and the next one tried.
+    Arithmetic over shapes and constants: the same plan on every trace.
+    Nothing stated or no limit known (the CPU; a model applied outside a
+    trainer): no name, the policy as it was."""
+    if cfg.remat_policy != "flash" or stated is None or stated.limit_bytes is None:
+        return RematPlan()
+    layers = _result_bytes(cfg, tokens)
+    costs = {
+        name: cost for name in SAVED_RESULTS
+        # Under CCA q, k and v carry no name (`Attention`): no candidate.
+        if not (cfg.cca and name == QKV_RESULT)
+        and (cost := sum(layer.get(name, 0) for layer in layers))
+    }
+    floor = _step_floor(cfg, tokens, layers, stated)
+    names, held = [], 0
+    for name, cost in costs.items():
+        if floor + held + cost <= stated.limit_bytes - REMAT_MARGIN_BYTES:
+            names.append(name)
+            held += cost
+    return RematPlan(tuple(names), tuple(costs.items()), held, floor + held)
 
 
 def _dot_folded(x, kernel, dimension_numbers, precision=None):
@@ -421,9 +654,10 @@ class Attention(nn.Module):
             ),
             (x.shape[-1], heads), jnp.float32,
         )
-        gate = jax.nn.sigmoid(jnp.dot(
+        # The product is what is named (`SAVED_RESULTS`).
+        gate = jax.nn.sigmoid(checkpoint_name(jnp.dot(
             x.astype(jnp.float32), wg, precision=jax.lax.Precision.HIGHEST
-        ))
+        ), GATE_RESULT))
         # A gate stuck at 0 or 1 is the failure this shows; the step sums
         # a counter over the layers that sow it.
         self.sow(
@@ -475,6 +709,8 @@ class Attention(nn.Module):
             )
             with jax.named_scope("rope"):
                 q, k = turn(q), turn(k)
+        if not cfg.cca:  # CCA's backward forms them again whatever is kept
+            q, k, v = (checkpoint_name(u, QKV_RESULT) for u in (q, k, v))
         scope = "cca.attend" if cfg.cca else "attend"
         if cfg.attention_kinds:  # a stack that mixes them tells them apart
             scope = "attend.full" if kind.window is None else "attend.window"
@@ -500,6 +736,7 @@ class SwiGLU(nn.Module):
         cfg = self.config
         gate = _dense(cfg.d_ff, ("embed", "mlp"), "wi_gate", cfg.dtype)(x)
         up = _dense(cfg.d_ff, ("embed", "mlp"), "wi_up", cfg.dtype)(x)
+        gate, up = (checkpoint_name(u, HIDDEN_RESULT) for u in (gate, up))
         return _dense(cfg.d_model, ("mlp", "embed"), "wo", cfg.dtype)(
             nn.silu(gate) * up
         )
@@ -513,7 +750,10 @@ class ReluSquaredMLP(nn.Module):
     @nn.compact
     def __call__(self, x):
         cfg = self.config
-        hidden = _dense(cfg.d_ff, ("embed", "mlp"), "wi", cfg.dtype)(x)
+        hidden = checkpoint_name(
+            _dense(cfg.d_ff, ("embed", "mlp"), "wi", cfg.dtype)(x),
+            HIDDEN_RESULT,
+        )
         return _dense(cfg.d_model, ("mlp", "embed"), "wo", cfg.dtype)(
             jnp.square(nn.relu(hidden))
         )
@@ -619,15 +859,16 @@ class ExpertLayer(nn.Module):
             x.astype(jnp.float32), mat("router_in", x.shape[-1], rh,
                                        ("embed", None)), precision=hi,
         ) + carry * router_state
+        r = checkpoint_name(r, ROUTE_RESULT)
         z = rms_norm(r, scale, dtype=jnp.float32, eps=cfg.norm_eps)
         z = nn.gelu(jnp.dot(z, mat("router_w1", rh, rh, (None, None)),
                             precision=hi))
         z = nn.gelu(jnp.dot(z, mat("router_w2", rh, rh, (None, None)),
                             precision=hi))
-        logits = jnp.dot(
+        logits = checkpoint_name(jnp.dot(
             z, mat("router_out", rh, cfg.num_experts, (None, None)),
             precision=hi,
-        )
+        ), ROUTE_RESULT)
         probs = jax.nn.softmax(logits, axis=-1)
         if cfg.router_force_balance:
             expert = forced_experts(self.layer, x.shape[-2], cfg.num_experts)
@@ -652,9 +893,9 @@ class ExpertLayer(nn.Module):
             "router_bias", _replicated(nn.initializers.zeros, 1), (n,),
             jnp.float32,
         )
-        probs = jax.nn.sigmoid(jnp.dot(
+        probs = jax.nn.sigmoid(checkpoint_name(jnp.dot(
             x.astype(jnp.float32), w, precision=jax.lax.Precision.HIGHEST
-        ))
+        ), ROUTE_RESULT))
         if cfg.router_force_balance:
             expert = forced_experts(self.layer, x.shape[-2], n, k)
             expert = jnp.broadcast_to(
@@ -662,11 +903,16 @@ class ExpertLayer(nn.Module):
             )
         else:
             _, expert = jax.lax.top_k(probs + jax.lax.stop_gradient(bias), k)
-        chosen = jnp.take_along_axis(probs, expert, axis=-1)
+        expert = checkpoint_name(expert.astype(jnp.int32), ROUTE_RESULT)
+        # The gather is what the backward would form again (1.8 ms a layer
+        # at 22 of 512, PERF.md §6 PR 38): its result is named too.
+        chosen = checkpoint_name(
+            jnp.take_along_axis(probs, expert, axis=-1), ROUTE_RESULT
+        )
         gate = cfg.routed_scaling * chosen / (
             jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20
         )
-        return expert.astype(jnp.int32), gate
+        return expert, checkpoint_name(gate, ROUTE_RESULT)
 
     @nn.compact
     def __call__(self, x, router_state):
@@ -731,9 +977,9 @@ class ExpertLayer(nn.Module):
         inner = x
         if cfg.moe_latent:
             with jax.named_scope("moe.latent_in"):
-                inner = _dense(
+                inner = checkpoint_name(_dense(
                     cfg.moe_latent, ("embed", None), "latent_in", cfg.dtype
-                )(x)
+                )(x), LATENT_RESULT)
         dm, ff = inner.shape[-1], cfg.d_ff
         into = ("w_gate", "w_up") if _mlp_cls(cfg) is SwiGLU else ("w_in",)
         weights = (
@@ -792,9 +1038,9 @@ class StateSpaceMixer(nn.Module):
             name, _replicated(init, 1), (size,), f32
         )
         with jax.named_scope("ssm.in_proj"):
-            proj = _dense(
+            proj = checkpoint_name(_dense(
                 2 * d_in + 2 * gn + h, ("embed", None), "in_proj", cfg.dtype
-            )(u)
+            )(u), IN_PROJ_RESULT)
         z, xbc, dt = jnp.split(proj, [d_in, 2 * d_in + 2 * gn], axis=-1)
         with jax.named_scope("ssm.conv"):
             w = self.param(
@@ -804,9 +1050,9 @@ class StateSpaceMixer(nn.Module):
             )
             xbc = xbc.astype(f32)
             mixed = sum(w[j] * _shift(xbc, j) for j in range(taps))
-            xbc = nn.silu(
+            xbc = checkpoint_name(nn.silu(
                 mixed + vector("conv_bias", nn.initializers.zeros, d_in + 2 * gn)
-            ).astype(cfg.dtype)
+            ).astype(cfg.dtype), CONV_RESULT)
         x, b, c = jnp.split(xbc, [d_in, d_in + gn], axis=-1)
 
         def steps(key, shape, dtype):
@@ -859,8 +1105,11 @@ class Block(nn.Module):
     def __call__(self, x, positions, router_state=None):
         cfg = self.config
         norm = functools.partial(RMSNorm, cfg.dtype, cfg.norm_eps)
-        x = x + Attention(cfg, self.mesh, self.attention, name="attn")(
-            norm(name="ln_attn")(x), positions
+        x = checkpoint_name(
+            x + Attention(cfg, self.mesh, self.attention, name="attn")(
+                norm(name="ln_attn")(x), positions
+            ),
+            RESIDUAL_RESULT,
         )
         # The "mlp" policy's only checkpoint: the MLP half recomputes in
         # the backward, attention's residuals stay saved (the lifted
@@ -921,11 +1170,11 @@ class Sublayer(nn.Module):
         return x + out, router_state
 
 
-def _layer_classes(cfg: TransformerConfig) -> list:
+def _layer_classes(cfg: TransformerConfig, keep: tuple[str, ...] = ()) -> list:
     """The stack's layers in order, each a class with `Block`'s
     constructor and call, wrapped per the remat policy."""
     if cfg.layer_pattern is None:
-        block = _block_cls(cfg)
+        block = _block_cls(cfg, keep=keep)
         if not (cfg.attention_kinds or cfg.dense_layers):
             return [block] * cfg.n_layers
         return [
@@ -944,7 +1193,7 @@ def _layer_classes(cfg: TransformerConfig) -> list:
             f"layer_pattern {cfg.layer_pattern!r} names "
             f"{len(cfg.layer_pattern)} layers, n_layers is {cfg.n_layers}"
         )
-    sublayer = _block_cls(cfg, Sublayer)
+    sublayer = _block_cls(cfg, Sublayer, keep)
     return [functools.partial(sublayer, kind=k) for k in cfg.layer_pattern]
 
 
@@ -1278,7 +1527,25 @@ class TransformerLM(nn.Module):
                 jnp.zeros((*tokens.shape, cfg.router_hidden), jnp.float32)
                 if cfg.num_experts > 0 and cfg.router == "mlp" else None
             )
-        for i, layer_cls in enumerate(_layer_classes(cfg)):
+        # What the trainer stated round this trace decides what the
+        # layers' checkpoints keep (`remat_plan`); nothing stated, as in
+        # serving or `eval`: the kernels' results alone.
+        mesh_shape = dict(self.mesh.shape) if self.mesh is not None else {}
+        shards = batch_shard_count(self.mesh) if self.mesh is not None else 1
+        plan = remat_plan(
+            cfg, tokens.size // (shards * mesh_shape.get("sp", 1)),
+            memory.current(),
+        )
+        if plan.predicted_peak is not None:  # how far it engaged, for `fit()`
+            for name, value in (
+                ("remat_saved_bytes", plan.saved_bytes),
+                ("remat_names", len(plan.names)),
+            ):
+                self.sow(
+                    "counters", name, float(value),
+                    reduce_fn=lambda _, new: new, init_fn=lambda: 0.0,
+                )
+        for i, layer_cls in enumerate(_layer_classes(cfg, plan.names)):
             x, router_state = layer_cls(
                 cfg, self.mesh, layer=i, name=f"layer_{i}"
             )(x, positions, router_state)

@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any, Callable, Mapping
 
 import flax.linen as nn
@@ -27,6 +28,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from kubeflow_tpu.parallel import sharding as shlib
 from kubeflow_tpu.parallel.mesh import step_compiler_options
 from kubeflow_tpu.train import profiling
+from kubeflow_tpu.utils import memory
 
 
 def _ensure_partitionable_rng() -> None:
@@ -321,6 +323,27 @@ class Trainer:
     def batch_sharding(self, ndim: int = 1) -> NamedSharding:
         return shlib.batch_sharding(self.mesh, ndim)
 
+    def step_memory(self) -> memory.StepMemory:
+        """What the step holds on one device whatever the model does, by
+        `abstract_state()`'s shapes and shardings, and the limit of a
+        device this process addresses: what the step states to the model
+        it traces (`utils/memory.py`). Constants of the trainer and the
+        chip's kind, so every trace of the step, in every process of the
+        job, reads the same."""
+
+        def shard_bytes(tree) -> int:
+            return sum(
+                math.prod(a.sharding.shard_shape(a.shape)) * a.dtype.itemsize
+                for a in jax.tree_util.tree_leaves(tree)
+            )
+
+        abstract = self.abstract_state()
+        return memory.StepMemory(
+            state_bytes=shard_bytes(abstract),
+            grad_bytes=shard_bytes(abstract.params),
+            limit_bytes=memory.device_limit(self.mesh),
+        )
+
     # -- elastic resize ----------------------------------------------------
 
     def resize(self, mesh: Mesh) -> "Trainer":
@@ -399,6 +422,13 @@ class Trainer:
         batch_parts = tuple(shlib.batch_axes(mesh))
         # Accuracy needs logits; the loss-in-model path never sees them.
         has_acc = cfg.train_metrics == "full" and not cfg.loss_in_model
+        # Stated to the model for a step of one batch alone. Over
+        # microbatches the scan's backward holds the accumulated
+        # gradients, a tick's and more beside the state (2.5 times the
+        # gradients' bytes on top, by the chip's compiler for a described
+        # v5e, PERF.md §7): no count of them here is a bound, so nothing
+        # is stated and the model keeps what it kept before.
+        step_memory = self.step_memory() if cfg.accum_steps == 1 else None
 
         def train_step(state: TrainState, batch):
             def forward_loss(params, mb, stats_in):
@@ -439,7 +469,8 @@ class Trainer:
                             mutable=mutable,
                         )
 
-                out, new_vars = forward(variables)
+                with memory.stated(step_memory):
+                    out, new_vars = forward(variables)
                 if cfg.loss_in_model:
                     loss = out
                     acc = jnp.zeros(())

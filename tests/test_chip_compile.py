@@ -608,3 +608,64 @@ def test_one_chip_step_gets_no_options_and_no_collective(topo, monkeypatch):
     assert sum(counts.values()) == 0, counts
     assert sum(async_collective_counts(text).values()) == 0
     assert tpu_kernel_calls(text) > 0
+
+
+def test_a_cell_shaped_step_stays_under_the_remat_plans_predicted_peak(
+    topo, monkeypatch
+):
+    """The nemotron cell's widths at its 8,192 tokens, cut to one period's
+    mixer, expert layer and attention (`M`, `E`, `*`), under
+    `remat_policy="flash"` with a v5e's limit stated: the plan admits every
+    name the three layers form, and what the chip's compiler counts for the
+    step (arguments, temporaries and code) stays under the plan's predicted
+    peak. The model is an upper bound, never a promise of room."""
+    from kubeflow_tpu.models.transformer import (
+        TransformerConfig, TransformerLM, remat_plan,
+    )
+    from kubeflow_tpu.ops import moe, ssd
+    from kubeflow_tpu.parallel import MeshSpec, build_mesh
+    from kubeflow_tpu.train import TrainConfig, Trainer
+    from kubeflow_tpu.utils import memory
+
+    _as_on_the_chip(monkeypatch)
+    monkeypatch.setattr(moe, "kernels_compiled", lambda: True)
+    monkeypatch.setattr(ssd, "kernels_compiled", lambda: True)
+    monkeypatch.setattr(memory, "device_limit", lambda mesh: 16_909_336_064)
+    cfg = TransformerConfig(
+        vocab_size=16384, d_model=4096, n_layers=3, layer_pattern="ME*",
+        n_heads=16, n_kv_heads=1, head_dim=HEAD_DIM, rope_fraction=0.0,
+        norm_eps=1e-5, tie_embeddings=False, remat_policy="flash",
+        d_ff=2688, num_experts=512, experts_held=(0, 8), experts_per_token=22,
+        router="sigmoid", routed_scaling=5.0, mlp_act="relu2", moe_latent=1024,
+        moe_shared_ff=5376, router_force_balance=True, ssm_heads=64,
+        ssm_head_dim=64, ssm_state=128, ssm_groups=4, ssm_conv=4, ssm_chunk=128,
+    )
+    mesh = build_mesh(MeshSpec(), list(topo.devices)[:1])
+    trainer = Trainer(
+        TransformerLM(cfg, mesh=mesh),
+        TrainConfig(batch_size=1, optimizer="adamw", label_smoothing=0.0,
+                    fsdp_params=False, train_metrics="loss"),
+        mesh, example_input_shape=(2, 8192), example_input_dtype=jnp.int32,
+        input_key="tokens", label_key="labels",
+    )
+    tokens = jax.ShapeDtypeStruct(
+        (1, 8192), jnp.int32, sharding=trainer.batch_sharding(2)
+    )
+    plan = remat_plan(cfg, 8192, trainer.step_memory())
+    assert plan.names == (
+        "moe_route", "moe_latent_in", "ssm_in_proj", "mlp_hidden", "attn_qkv",
+        "ssm_conv",
+    ) and plan.refused == ()
+    compiled = trainer.make_train_step().lower(
+        trainer.abstract_state(), {"tokens": tokens, "labels": tokens}
+    ).compile()
+    counted = compiled.memory_analysis()
+    used = (
+        counted.argument_size_in_bytes + counted.output_size_in_bytes
+        - counted.alias_size_in_bytes + counted.temp_size_in_bytes
+        + counted.generated_code_size_in_bytes
+    )
+    state = trainer.step_memory().state_bytes
+    assert state + plan.saved_bytes < used <= plan.predicted_peak, (
+        used, plan
+    )

@@ -1,0 +1,496 @@
+"""`remat_policy="flash"` keeps named results inside a byte budget
+(`models/transformer.remat_plan`): the plan at the benchmark's three
+sparse cells' shapes, the budgets at which it admits nothing, that every
+trace of a trainer's step and every process of a job reads one plan,
+per-device bytes on a mesh, nothing stated under accumulation, and from a small
+model's gradient that a result kept is not formed again.
+
+The cells' configurations come from the benchmark's files (as
+`tests/test_nemotron.py` imports `benchmarks.*`): run from the repo root.
+"""
+
+import dataclasses
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from kubeflow_tpu.models import transformer
+from kubeflow_tpu.models.transformer import (
+    KERNEL_RESULTS,
+    SAVED_RESULTS,
+    RematPlan,
+    TransformerConfig,
+    TransformerLM,
+    remat_plan,
+)
+from kubeflow_tpu.parallel import MeshSpec, build_mesh
+from kubeflow_tpu.testing.hlo import _walk_eqns
+from kubeflow_tpu.train import TrainConfig, Trainer, fit
+from kubeflow_tpu.utils import memory
+from kubeflow_tpu.utils.memory import StepMemory
+
+# `bytes_limit` of a v5e's allocator (my chip runs, PR 38: PERF.md §6).
+V5E_LIMIT = 16_909_336_064
+
+
+def _cell(name: str):
+    """(configuration, tokens a step, the trainer) of a benchmark cell,
+    built as its driver builds it, on the CPU."""
+    from benchmarks.lib import loader
+
+    cell = loader.load_cell(name, loader.load_benchmark())
+    driver, work = cell["driver"], cell["workload"]
+    numbers = driver.model_numbers(cell["config"])
+    numbers["router_force_balance"] = bool(work.get("router_force_balance"))
+    cfg = driver.transformer_config(
+        numbers, attention_impl=work["attention_impl"],
+        remat_policy=work["remat"],
+    )
+    mesh = build_mesh(MeshSpec(**work["mesh"]), jax.devices()[:1])
+    opt = work["optimizer"]
+    trainer = Trainer(
+        TransformerLM(cfg, mesh=mesh),
+        TrainConfig(
+            batch_size=work["batch"], optimizer="adamw",
+            adam_mu_dtype=opt["mu_dtype"], label_smoothing=0.0,
+            fsdp_params=False, train_metrics="loss",
+        ),
+        mesh, example_input_shape=(2, work["seq_len"]),
+        example_input_dtype=jnp.int32, input_key="tokens", label_key="labels",
+    )
+    return cfg, work["batch"] * work["seq_len"], trainer
+
+
+CELL_PLANS = {
+    # cell: (state bytes, gradient bytes, {name: bytes} in the order of
+    # admission, refused, predicted peak) at a v5e's limit.
+    "nemotron-3-super-tp2ep64.train-8k": (
+        9_190_158_732, 3_676_063_488,
+        {
+            "moe_route": 146_800_640, "moe_latent_in": 83_886_080,
+            "ssm_in_proj": 765_460_480, "mlp_hidden": 440_401_920,
+            "attn_qkv": 37_748_736, "ssm_conv": 419_430_400,
+        },
+        (), 15_628_695_692,
+    ),
+    "laguna-s-2.1-ep32.train-8k": (
+        8_110_182_412, 3_244_072_960,
+        {
+            "attn_gate": 20_971_520, "moe_route": 83_886_080,
+            "attn_residual": 251_658_240, "mlp_hidden": 536_870_912,
+            "attn_qkv": 822_083_584,
+        },
+        (), 14_344_794_124,
+    ),
+    "zaya1-8b-ep2.train-8k": (
+        9_223_475_372, 3_689_390_144,
+        # CCA: q, k and v are no candidate (its backward forms them again).
+        {"moe_route": 201_326_592, "attn_residual": 536_870_912},
+        (), 15_548_485_804,
+    ),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CELL_PLANS))
+def test_the_plan_at_a_cells_shapes_under_a_v5es_limit(name):
+    """Names in the order of admission, bytes by name, bytes held and the
+    predicted peak, pinned; the trainer states the state's and the
+    gradients' bytes from `abstract_state()`."""
+    state, grads, costs, refused, peak = CELL_PLANS[name]
+    cfg, tokens, trainer = _cell(name)
+    stated = trainer.step_memory()
+    assert stated == StepMemory(state, grads, None)  # the CPU: no limit
+    plan = remat_plan(cfg, tokens, dataclasses.replace(stated, limit_bytes=V5E_LIMIT))
+    assert plan.names == tuple(n for n in costs if n not in refused)
+    assert plan.refused == refused
+    assert dict(plan.bytes) == costs
+    assert [n for n, _ in plan.bytes] == [n for n in SAVED_RESULTS if n in costs]
+    assert plan.saved_bytes == sum(costs[n] for n in plan.names)
+    floor = transformer._step_floor(
+        cfg, tokens, transformer._result_bytes(cfg, tokens), stated
+    )
+    assert plan.predicted_peak == floor + plan.saved_bytes == peak
+    assert plan.predicted_peak <= V5E_LIMIT - transformer.REMAT_MARGIN_BYTES
+    assert floor >= state + grads
+
+
+SMALL = TransformerConfig(
+    vocab_size=64, d_model=32, n_layers=2, n_heads=2, head_dim=16, d_ff=64,
+    remat_policy="flash", attention_impl="dense", dtype=jnp.float32,
+)
+ROOMY = StepMemory(state_bytes=1 << 20, grad_bytes=1 << 19, limit_bytes=1 << 40)
+
+
+@pytest.mark.parametrize("stated, policy", [
+    (None, "flash"),                                      # nothing stated
+    (dataclasses.replace(ROOMY, limit_bytes=None), "flash"),   # the CPU
+    (dataclasses.replace(ROOMY, limit_bytes=1 << 21), "flash"),  # no room
+    (ROOMY, "full"), (ROOMY, "none"), (ROOMY, "mlp"),     # another policy
+])
+def test_no_budget_gives_the_kernels_four_names(stated, policy):
+    cfg = dataclasses.replace(SMALL, remat_policy=policy)
+    plan = remat_plan(cfg, 16, stated)
+    assert plan.names == () and plan.saved_bytes == 0
+    if policy == "flash" and stated is not None and stated.limit_bytes:
+        assert plan.refused == ("attn_residual", "mlp_hidden", "attn_qkv")
+        assert plan.predicted_peak > stated.limit_bytes
+    else:
+        assert plan == RematPlan()
+
+
+def test_a_name_that_does_not_fit_is_refused_and_the_next_tried():
+    cfg = dataclasses.replace(
+        SMALL, d_model=512, head_dim=128, num_experts=128, router="sigmoid",
+        experts_per_token=2, moe_shared_ff=128,
+    )
+    costs = dict(remat_plan(cfg, 4096, ROOMY).bytes)
+    assert list(costs) == ["moe_route", "attn_residual", "mlp_hidden", "attn_qkv"]
+    floor = remat_plan(cfg, 4096, ROOMY).predicted_peak - sum(costs.values())
+    # Room for the router's and the hidden results, not the d-wide ones.
+    room = costs["moe_route"] + costs["mlp_hidden"] + 1
+    assert room < costs["moe_route"] + costs["attn_residual"]
+    tight = dataclasses.replace(
+        ROOMY, limit_bytes=floor + room + transformer.REMAT_MARGIN_BYTES
+    )
+    plan = remat_plan(cfg, 4096, tight)
+    assert plan.names == ("moe_route", "mlp_hidden")
+    assert plan.refused == ("attn_residual", "attn_qkv")
+    assert plan.predicted_peak == floor + plan.saved_bytes
+
+
+def test_bytes_on_a_mesh_are_a_devices_tokens_at_whole_widths():
+    """A `dp` (or `sp`) shard holds its share of the tokens and every
+    name's bytes follow; a width a `tp` axis would split is counted
+    whole, so the predicted peak there is an upper bound (no cell runs
+    `flash` on a mesh: PERF.md §7)."""
+    cfg = dataclasses.replace(
+        SMALL, d_model=256, n_heads=4, head_dim=128, d_ff=1024, num_experts=256,
+        router="sigmoid", experts_per_token=2, moe_shared_ff=512,
+    )
+    one = remat_plan(cfg, 4096, ROOMY)
+    shard = remat_plan(cfg, 2048, ROOMY)
+    ratio = {n: b / dict(one.bytes)[n] for n, b in shard.bytes}
+    assert ratio == {
+        "moe_route": 0.5, "attn_residual": 0.5, "attn_qkv": 0.5,
+        "mlp_hidden": 0.5,
+    }
+    # Two layers of q, k and v, float32, four heads of 128 each, whole.
+    assert dict(one.bytes)["attn_qkv"] == 2 * 4096 * 4 * 3 * (4 * 128)
+
+
+def test_under_cca_q_k_v_are_no_candidate():
+    """CCA's mixing reads the projections' results in its backward, so
+    they are formed again whatever is kept behind it (zaya: ~0 ms for
+    0.40 GB, PERF.md §6 PR 38): not named, not counted, not admitted,
+    though the floor still counts them among what a layer forms again."""
+    plain = dataclasses.replace(SMALL, n_kv_heads=2, rope_fraction=0.5)
+    cca = dataclasses.replace(plain, cca=True)
+    assert "attn_qkv" in remat_plan(plain, 16, ROOMY).names
+    plan = remat_plan(cca, 16, ROOMY)
+    assert plan.names == ("attn_residual", "mlp_hidden") and plan.refused == ()
+    assert "attn_qkv" not in dict(plan.bytes)
+    assert (
+        plan.predicted_peak - plan.saved_bytes
+        == remat_plan(plain, 16, ROOMY).predicted_peak
+        - remat_plan(plain, 16, ROOMY).saved_bytes
+    )
+    named = {
+        e.params["name"] for e in _walk_eqns(_forward_jaxpr(cca))
+        if e.primitive.name == "name"
+    }
+    assert "attn_qkv" not in named and "attn_residual" in named
+
+
+def _text(jaxpr) -> str:
+    """A jaxpr's text without the addresses its function objects print."""
+    return re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr))
+
+
+def _trainer(cfg, mesh_spec=MeshSpec(), devices=1, batch=2, seq=8, accum_steps=1):
+    mesh = build_mesh(mesh_spec, jax.devices()[:devices])
+    return Trainer(
+        TransformerLM(cfg, mesh=mesh),
+        TrainConfig(batch_size=batch, optimizer="adamw", label_smoothing=0.0,
+                    fsdp_params=False, train_metrics="loss",
+                    accum_steps=accum_steps),
+        mesh, example_input_shape=(batch, seq), example_input_dtype=jnp.int32,
+        input_key="tokens", label_key="labels",
+    )
+
+
+def _forward_jaxpr(cfg):
+    model = TransformerLM(cfg)
+    tokens = jnp.zeros((2, 8), jnp.int32)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)
+    return jax.make_jaxpr(model.apply)(params, tokens).jaxpr
+
+
+def _calls(monkeypatch):
+    """Every `remat_plan` call a trace makes: (tokens, stated, plan)."""
+    seen, real = [], transformer.remat_plan
+
+    def spy(cfg, tokens, stated):
+        plan = real(cfg, tokens, stated)
+        seen.append((tokens, stated, plan))
+        return plan
+
+    monkeypatch.setattr(transformer, "remat_plan", spy)
+    return seen
+
+
+def test_every_trace_of_a_trainers_step_reads_one_plan(monkeypatch):
+    """`abstract_state()`'s shapes give the bytes the step states, so the
+    step `fit()` compiles and a later lowering of it (`step_scopes()`)
+    are one program; the model's own init and an `apply` outside the
+    trainer find nothing stated."""
+    monkeypatch.setattr(memory, "device_limit", lambda mesh: 1 << 40)
+    seen = _calls(monkeypatch)
+    trainer = _trainer(SMALL)
+    stated = trainer.step_memory()
+    assert stated.limit_bytes == 1 << 40 and stated.state_bytes > stated.grad_bytes > 0
+    abstract = trainer.abstract_state()
+    assert [s for _, s, _ in seen] == [None]  # the init's trace
+    tokens = jax.ShapeDtypeStruct((2, 8), jnp.int32)
+    batch = {"tokens": tokens, "labels": tokens}
+    step = trainer.make_train_step()
+    first = _text(step.trace(abstract, batch).jaxpr)
+    jax.clear_caches()
+    again = _text(trainer.make_train_step().trace(abstract, batch).jaxpr)
+    assert first == again
+    in_step = seen[1:]
+    assert in_step and all(call == in_step[0] for call in in_step)
+    assert in_step[0] == (16, stated, remat_plan(SMALL, 16, stated))
+    assert in_step[0][2].names == ("attn_residual", "mlp_hidden", "attn_qkv")
+    del seen[:]
+    trainer.model.apply(
+        {"params": jax.tree_util.tree_map(jnp.zeros_like, abstract.params)},
+        jnp.zeros((2, 8), jnp.int32),
+    )
+    assert [s for _, s, _ in seen] == [None]
+
+
+class _Device:
+    """A device as a process of a larger job sees it: its own report a
+    limit, another process's raise as this jaxlib's do."""
+
+    def __init__(self, process_index: int, limit: int | None = None):
+        self.process_index, self.limit = process_index, limit
+        self.client = self
+
+    def memory_stats(self):
+        if self.limit is None:
+            raise jax.errors.JaxRuntimeError(
+                "INVALID_ARGUMENT: MemoryStats is only supported for "
+                "addressable PjRt devices."
+            )
+        return {"bytes_limit": self.limit, "bytes_in_use": 12345}
+
+
+def _mesh_as_seen_by(process: int):
+    """A mesh of two chips, one a process: a device reports its limit to
+    the process that owns it alone."""
+    devices = np.array([
+        _Device(owner, V5E_LIMIT if owner == process else None)
+        for owner in (0, 1)
+    ])
+    return type("MeshStub", (), {
+        "devices": devices, "local_devices": [devices[process]],
+    })()
+
+
+def test_the_limit_is_read_off_a_device_this_process_addresses():
+    """Process 1 of two: the mesh's first device is process 0's and its
+    stats raise. Each process reads the limit off its own chip, one kind,
+    so both state the same and hold one plan. A described device (local,
+    but its stats raise) and the CPU (no limit in its stats) give None."""
+    second = _mesh_as_seen_by(1)
+    with pytest.raises(jax.errors.JaxRuntimeError):
+        second.devices.flat[0].memory_stats()
+    stated = [
+        StepMemory(1 << 20, 1 << 19, memory.device_limit(_mesh_as_seen_by(process)))
+        for process in (0, 1)
+    ]
+    assert stated[0] == stated[1] and stated[1].limit_bytes == V5E_LIMIT
+    assert remat_plan(SMALL, 16, stated[0]) == remat_plan(SMALL, 16, stated[1])
+    assert remat_plan(SMALL, 16, stated[1]).names
+    described = type("MeshStub", (), {"local_devices": [_Device(0)]})()
+    assert memory.device_limit(described) is None
+    assert memory.device_limit(_trainer(SMALL).mesh) is None  # the CPU
+
+
+def test_a_trainer_whose_meshs_first_device_is_anothers_states_its_own(monkeypatch):
+    """`Trainer.step_memory()` asks the mesh for a device of this
+    process: with the first device's stats unreadable (here the CPU's,
+    which report no limit) the step still states the local chip's limit,
+    and the plan is the one taken from it."""
+    trainer = _trainer(SMALL, MeshSpec(dp=2), devices=2)
+    assert trainer.step_memory().limit_bytes is None
+    monkeypatch.setattr(
+        type(trainer.mesh), "local_devices",
+        property(lambda mesh: [_Device(jax.process_index(), V5E_LIMIT)]),
+    )
+    stated = trainer.step_memory()
+    assert stated.limit_bytes == V5E_LIMIT
+    seen = _calls(monkeypatch)
+    tokens = jax.ShapeDtypeStruct(
+        (2, 8), jnp.int32, sharding=trainer.batch_sharding(2)
+    )
+    trainer.make_train_step().trace(
+        trainer.abstract_state(), {"tokens": tokens, "labels": tokens}
+    )
+    assert seen[-1] == (8, stated, remat_plan(SMALL, 8, stated))
+    assert seen[-1][2].names == ("attn_residual", "mlp_hidden", "attn_qkv")
+
+
+def test_under_accumulation_nothing_is_stated(monkeypatch):
+    """`accum_steps=2`: the scan's backward holds more beside the state
+    than the floor counts (the accumulated gradients, a tick's and the
+    scan's own: PERF.md §7), so the step states nothing and the layers
+    keep the kernels' results alone, whatever room the device has."""
+    monkeypatch.setattr(memory, "device_limit", lambda mesh: 1 << 40)
+    seen = _calls(monkeypatch)
+    once, twice = _trainer(SMALL, batch=4), _trainer(SMALL, batch=4, accum_steps=2)
+    assert twice.step_memory() == once.step_memory()
+    tokens = jax.ShapeDtypeStruct((4, 8), jnp.int32)
+    batch = {"tokens": tokens, "labels": tokens}
+    once.make_train_step().trace(once.abstract_state(), batch)
+    assert seen[-1][:2] == (32, once.step_memory()) and seen[-1][2].names
+    held = _text(twice.make_train_step().trace(twice.abstract_state(), batch).jaxpr)
+    assert seen[-1] == (16, None, RematPlan())
+    monkeypatch.setattr(memory, "device_limit", lambda mesh: None)  # the CPU
+    assert held == _text(
+        twice.make_train_step().trace(twice.abstract_state(), batch).jaxpr
+    )
+
+
+def test_the_model_counts_a_devices_tokens_and_shard_on_a_mesh(monkeypatch):
+    monkeypatch.setattr(memory, "device_limit", lambda mesh: 1 << 40)
+    seen = _calls(monkeypatch)
+    trainer = _trainer(SMALL, MeshSpec(dp=2, tp=2), devices=4, batch=4)
+    whole, quarter = _trainer(SMALL).step_memory(), trainer.step_memory()
+    # Matrices are split over `tp`, the norms' scales on every device.
+    assert whole.state_bytes / 2 < quarter.state_bytes < whole.state_bytes * 0.6
+    tokens = jax.ShapeDtypeStruct(
+        (4, 8), jnp.int32, sharding=trainer.batch_sharding(2)
+    )
+    trainer.make_train_step().trace(
+        trainer.abstract_state(), {"tokens": tokens, "labels": tokens}
+    )
+    assert seen[-1][:2] == (16, quarter)  # half the batch's 32 tokens
+
+
+def _dots(jaxpr, inside: bool):
+    """`dot_general`s of a gradient's jaxpr inside (or outside) its
+    checkpoints' equations."""
+    count = 0
+    for eqn in jaxpr.eqns:
+        again = eqn.primitive.name in ("checkpoint", "remat2")
+        if eqn.primitive.name == "dot_general" and not inside:
+            count += 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            if again:
+                if inside:
+                    count += sum(
+                        e.primitive.name == "dot_general" for e in _walk_eqns(sub)
+                    )
+            else:
+                count += _dots(sub, inside)
+    return count
+
+
+def _grad_jaxpr(cfg, stated):
+    model = TransformerLM(cfg)
+    tokens = jnp.arange(16, dtype=jnp.int32).reshape(2, 8) % cfg.vocab_size
+    params = model.init(jax.random.PRNGKey(0), tokens)
+
+    def loss(p):
+        with memory.stated(stated):
+            return model.apply(p, tokens).astype(jnp.float32).sum()
+
+    return jax.make_jaxpr(jax.grad(loss))(params).jaxpr
+
+
+def test_a_result_kept_is_not_formed_again():
+    """One layer of attention and SwiGLU: seven matmuls forward (q, k, v,
+    o, gate, up, down) and the scores' two. With nothing stated the
+    checkpoint forms all but the last again (the dense attention's two
+    with them); with every name kept only the scores' two, which no name
+    covers; with room for the residual alone, `wo` is spared and the
+    rest is formed again."""
+    cfg = dataclasses.replace(SMALL, n_layers=1)
+    plain = _grad_jaxpr(cfg, None)
+    assert _text(plain) == _text(_grad_jaxpr(
+        cfg, dataclasses.replace(ROOMY, limit_bytes=1 << 21)
+    ))
+    every = _grad_jaxpr(cfg, ROOMY)
+    costs = dict(remat_plan(cfg, 16, ROOMY).bytes)
+    floor = remat_plan(cfg, 16, ROOMY).predicted_peak - sum(costs.values())
+    residual_only = _grad_jaxpr(cfg, dataclasses.replace(
+        ROOMY, limit_bytes=floor + costs["attn_residual"]
+        + transformer.REMAT_MARGIN_BYTES,
+    ))
+    # The checkpoint's equation in a gradient holds what is formed again
+    # and the backward's own two matmuls for each of the nine.
+    assert _dots(plain, inside=True) == 8 + 18
+    assert _dots(residual_only, inside=True) == 7 + 18
+    assert _dots(every, inside=True) == 2 + 18
+    outside = {_dots(j, inside=False) for j in (plain, residual_only, every)}
+    assert len(outside) == 1  # the forward and the backward's own: unmoved
+
+
+FAMILIES = {
+    "blocks, gate, sigmoid experts, a leading dense layer": dict(
+        n_layers=2, num_experts=4, router="sigmoid", experts_per_token=2,
+        moe_shared_ff=32, attention_gate=True, dense_layers=1, dense_d_ff=96,
+        n_kv_heads=1,
+    ),
+    "blocks, CCA, the router MLP": dict(
+        n_layers=2, num_experts=4, router="mlp", router_hidden=16, cca=True,
+        n_kv_heads=2, rope_fraction=0.5,
+    ),
+    "a pattern of mixers, latent relu2 experts and attention": dict(
+        n_layers=3, layer_pattern="ME*", num_experts=4, router="sigmoid",
+        experts_per_token=2, moe_latent=16, moe_shared_ff=48, mlp_act="relu2",
+        ssm_heads=4, ssm_head_dim=8, ssm_state=16, ssm_groups=2, ssm_chunk=8,
+        rope_fraction=0.0, tie_embeddings=False, n_kv_heads=1,
+    ),
+}
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_the_plans_bytes_are_those_of_the_results_the_layers_name(family):
+    """What `_result_bytes` reckons from the configuration is what the
+    traced forward names: every `name` equation's result, its minor
+    dimension in whole lane tiles, summed by name."""
+    cfg = dataclasses.replace(SMALL, dtype=jnp.bfloat16, **FAMILIES[family])
+    named: dict = {}
+    for eqn in _walk_eqns(_forward_jaxpr(cfg)):
+        if eqn.primitive.name == "name" and eqn.params["name"] in SAVED_RESULTS:
+            aval = eqn.outvars[0].aval
+            size = int(np.prod(aval.shape[:-1])) * transformer._lanes(aval.shape[-1])
+            named[eqn.params["name"]] = (
+                named.get(eqn.params["name"], 0) + size * aval.dtype.itemsize
+            )
+    assert named == dict(remat_plan(cfg, 16, ROOMY).bytes)
+    assert set(KERNEL_RESULTS).isdisjoint(SAVED_RESULTS)
+
+
+def test_fit_records_how_far_the_plan_engaged(monkeypatch):
+    cfg = dataclasses.replace(SMALL, n_layers=1)
+    tokens = jnp.arange(16, dtype=jnp.int32).reshape(2, 8)
+    data = [{"tokens": tokens, "labels": tokens}] * 2
+    records: list = []
+    fit(_trainer(cfg), data, 1, log_every=1, handle_signals=False,
+        on_metrics=lambda step, rec: records.append(rec))
+    assert "remat_saved_bytes" not in records[0]  # the CPU: no limit known
+    monkeypatch.setattr(memory, "device_limit", lambda mesh: 1 << 40)
+    trainer = _trainer(cfg)
+    fit(trainer, data, 1, log_every=1, handle_signals=False,
+        on_metrics=lambda step, rec: records.append(rec))
+    plan = remat_plan(cfg, 16, trainer.step_memory())
+    assert records[1]["remat_saved_bytes"] == plan.saved_bytes > 0
+    assert records[1]["remat_names"] == len(plan.names) == 3
+    assert records[1]["loss"] == pytest.approx(records[0]["loss"], rel=1e-6)

@@ -176,11 +176,37 @@ def test_fused_cross_entropy_matches_onehot_formulation():
         assert abs(float(want) - float(got)) < 1e-5
 
 
-def test_remat_policies_agree():
-    """Remat policies ('none', 'dots', 'attn', 'mlp') are performance
+_REMAT_STACKS = {
+    "dense": {},
+    # `Block`s with a gate on attention, a leading dense layer, sigmoid
+    # top-2 experts beside a shared one.
+    "experts": dict(
+        num_experts=4, router="sigmoid", experts_per_token=2,
+        moe_shared_ff=32, attention_gate=True, dense_layers=1, dense_d_ff=48,
+    ),
+    # A `layer_pattern`: a mixer, latent `relu^2` experts, attention.
+    "pattern": dict(
+        n_layers=3, layer_pattern="ME*", num_experts=4, router="sigmoid",
+        experts_per_token=2, moe_latent=16, moe_shared_ff=48, mlp_act="relu2",
+        ssm_heads=4, ssm_head_dim=8, ssm_state=16, ssm_groups=2, ssm_chunk=8,
+        rope_fraction=0.0, tie_embeddings=False,
+    ),
+}
+
+
+@pytest.mark.parametrize("stack, policy, keep", [
+    ("dense", "none", None), ("dense", "mlp", None),
+    ("experts", "flash", "no names"), ("experts", "flash", "every name"),
+    ("pattern", "flash", "no names"), ("pattern", "flash", "every name"),
+])
+def test_remat_policies_agree(stack, policy, keep):
+    """Remat policies ('none', 'mlp', 'flash') are performance
     knobs, not semantics: same logits, same grads, same param tree as
     'full'. 'none' matters most — it is bench auto's short-context
-    default.
+    default. 'flash' is held at both ends of what its budget can admit
+    (`remat_plan`): the kernels' results alone, and every named result
+    kept, for a stack of blocks with experts and a gate and for a
+    `layer_pattern` stack.
 
     Tolerance is STRUCTURAL, not exact-value (the pre-PR-5 flake): in
     the production bf16 dtype, a policy changes which activations the
@@ -193,22 +219,40 @@ def test_remat_policies_agree():
     f32-level agreement when the ulp noise is excluded (the f32 variant
     of this check lives in the loop below via the loss, which sums a
     shared forward and must agree to f32 precision)."""
-    cfg_full = TransformerConfig(
-        vocab_size=64, d_model=32, n_layers=2, n_heads=2, head_dim=16,
-        d_ff=64, remat_policy="full", attention_impl="dense",
-    )
+    from kubeflow_tpu.models.transformer import remat_plan
+    from kubeflow_tpu.utils import memory
+
+    cfg_full = TransformerConfig(**{
+        **dict(
+            vocab_size=64, d_model=32, n_layers=2, n_heads=2, head_dim=16,
+            d_ff=64, remat_policy="full", attention_impl="dense",
+        ),
+        **_REMAT_STACKS[stack],
+    })
     # Pinned inputs/init: the comparison is across policies within ONE
     # process, so any residual disagreement is the policies', not RNG.
     tokens = jnp.arange(2 * 8, dtype=jnp.int32).reshape(2, 8) % 64
+    stated = None
+    if keep is not None:
+        stated = memory.StepMemory(
+            state_bytes=0, grad_bytes=0,
+            limit_bytes=1 << (40 if keep == "every name" else 20),
+        )
+        plan = remat_plan(
+            dataclasses.replace(cfg_full, remat_policy=policy), 16, stated
+        )
+        assert (plan.refused == ()) == (keep == "every name")
+        assert (plan.names == ()) == (keep == "no names")
 
     out = {}
-    for name in ("full", "none", "mlp"):
+    for name in ("full", policy):
         cfg = dataclasses.replace(cfg_full, remat_policy=name)
         model = TransformerLM(cfg)
         params = model.init(jax.random.PRNGKey(0), tokens)
 
         def loss(p):
-            return model.apply(p, tokens).astype(jnp.float32).sum()
+            with memory.stated(stated):
+                return model.apply(p, tokens).astype(jnp.float32).sum()
 
         out[name] = (loss(params), jax.grad(loss)(params))
 
@@ -217,32 +261,32 @@ def test_remat_policies_agree():
     ref_paths = [
         p for p, _ in jax.tree_util.tree_leaves_with_path(ref_grads)
     ]
-    for name in ("none", "mlp"):
-        # The loss reads the forward only — no recompute involved — so
-        # it must agree to f32 accumulation noise.
-        assert jnp.allclose(ref_loss, out[name][0], atol=1e-4), name
-        # The lifted transforms must not move params ('mlp' wraps a
-        # submodule — a renamed path would orphan every checkpoint).
-        paths = [
-            p for p, _ in jax.tree_util.tree_leaves_with_path(out[name][1])
-        ]
-        assert paths == ref_paths, name
-        for path, a, b in zip(
-            ref_paths,
-            jax.tree_util.tree_leaves(ref_grads),
-            jax.tree_util.tree_leaves(out[name][1]),
-        ):
-            # <= 8 bf16 ulps of the leaf's OWN scale (measured policy
-            # disagreement tops out at ~3 ulps here): generous for ulp
-            # noise, far below any real semantic drift — a dropped term
-            # or a moved stop-gradient shows up at O(1) of the leaf's
-            # scale, which this bound catches even on tiny leaves (no
-            # absolute floor that could mask a mangled small leaf).
-            scale = max(float(jnp.max(jnp.abs(a))), 1e-6)
-            max_err = float(jnp.max(jnp.abs(a - b)))
-            assert max_err <= 8 * bf16_eps * scale, (
-                name, path, max_err, scale
-            )
+    name = policy
+    # The loss reads the forward only — no recompute involved — so
+    # it must agree to f32 accumulation noise.
+    assert jnp.allclose(ref_loss, out[name][0], atol=1e-4), name
+    # The lifted transforms must not move params ('mlp' wraps a
+    # submodule — a renamed path would orphan every checkpoint).
+    paths = [
+        p for p, _ in jax.tree_util.tree_leaves_with_path(out[name][1])
+    ]
+    assert paths == ref_paths, name
+    for path, a, b in zip(
+        ref_paths,
+        jax.tree_util.tree_leaves(ref_grads),
+        jax.tree_util.tree_leaves(out[name][1]),
+    ):
+        # <= 8 bf16 ulps of the leaf's OWN scale (measured policy
+        # disagreement tops out at ~3 ulps here): generous for ulp
+        # noise, far below any real semantic drift — a dropped term
+        # or a moved stop-gradient shows up at O(1) of the leaf's
+        # scale, which this bound catches even on tiny leaves (no
+        # absolute floor that could mask a mangled small leaf).
+        scale = max(float(jnp.max(jnp.abs(a))), 1e-6)
+        max_err = float(jnp.max(jnp.abs(a - b)))
+        assert max_err <= 8 * bf16_eps * scale, (
+            name, path, max_err, scale
+        )
 
 
 def test_flash_remat_policy_skips_forward_rerun():
