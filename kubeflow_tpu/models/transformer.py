@@ -175,6 +175,34 @@ class TransformerConfig:
     # `dense_d_ff`, in a stack whose other layers have experts.
     dense_layers: int = 0
     dense_d_ff: int = 0
+    # Latent attention (DeepSeek-V2's MLA; `kv_latent` > 0 turns it on): q
+    # comes through a bottleneck of `q_latent` with a norm in it, k and v
+    # through one of `kv_latent`; a head's q and k are `head_dim` dims of
+    # their own plus `rope_head_dim` that rope turns, and the rope part of
+    # k is ONE key for all heads, projected beside the latent; v is
+    # `v_head_dim` wide (0: `head_dim`; the flash kernels want them equal).
+    # The layer's `AttentionKind` gives the rope (yarn too) over the whole
+    # rope part. `softmax_scale`: the scores' factor where it is not
+    # (width of q·k)^-1/2 (yarn's mscale squared times it).
+    q_latent: int = 0
+    kv_latent: int = 0
+    rope_head_dim: int = 0
+    v_head_dim: int = 0
+    softmax_scale: float | None = None
+    # Residual streams (manifold-constrained hyper-connections, arXiv
+    # 2512.24880; 0 = the plain `x + f(norm(x))`): a `Block` carries
+    # `residual_streams` streams [B, S, n·d] and round each sublayer mixes
+    # them by three maps of the normed streams: into the sublayer's input
+    # (a sigmoid a stream), its output back onto each stream (twice a
+    # sigmoid) and stream to stream, an n x n matrix made doubly
+    # stochastic by `hc_iters` Sinkhorn iterations (rows, then columns,
+    # each sum + `hc_eps`) of exp of the product clipped to +-`hc_clamp`
+    # (`StreamMaps`). Every stream enters as the embedding's row; their
+    # sum leaves to the final norm.
+    residual_streams: int = 0
+    hc_iters: int = 20
+    hc_clamp: float = 30.0
+    hc_eps: float = 1e-6
 
 
 def _own_kind(cfg: TransformerConfig) -> AttentionKind:
@@ -211,6 +239,26 @@ def _attention_kinds(cfg: TransformerConfig) -> list[AttentionKind]:
                 f"a window of {kind.window} key(s): it counts the query's "
                 "own position, so it is at least 1"
             )
+    if cfg.kv_latent or cfg.q_latent or cfg.rope_head_dim:
+        if (
+            min(cfg.kv_latent, cfg.q_latent, cfg.rope_head_dim) < 1
+            or cfg.rope_head_dim % 2 or cfg.cca or (hk and hk != cfg.n_heads)
+            or any(k.window is not None or k.n_heads != cfg.n_heads for k in kinds)
+        ):
+            raise ValueError(
+                f"latent attention with ranks {cfg.q_latent} / "
+                f"{cfg.kv_latent} and a rope part of {cfg.rope_head_dim}: it "
+                "takes both ranks, an even rope part, equal heads "
+                f"({cfg.n_heads} over {hk}), no window and no CCA"
+            )
+    if cfg.residual_streams < 0 or cfg.hc_iters < 1 or (
+        cfg.residual_streams and cfg.layer_pattern is not None
+    ):
+        raise ValueError(
+            f"{cfg.residual_streams} residual streams mixed by "
+            f"{cfg.hc_iters} iterations: streams are carried by a stack of "
+            "blocks, not by a layer_pattern"
+        )
     if not 0 <= cfg.dense_layers <= cfg.n_layers or (
         cfg.dense_layers and not (cfg.num_experts and cfg.dense_d_ff > 0)
     ):
@@ -247,7 +295,12 @@ LATENT_RESULT = "moe_latent_in"   # the experts' input in the latent
 IN_PROJ_RESULT = "ssm_in_proj"    # the mixer's in-projection
 HIDDEN_RESULT = "mlp_hidden"      # a dense or shared MLP's hidden results
 QKV_RESULT = "attn_qkv"           # q, k and v as the kernels read them
+                                  # (latent attention: q's and k's two
+                                  # parts and v)
 CONV_RESULT = "ssm_conv"          # the mixer's convolved x, B and C
+ATTN_LATENT_RESULT = "attn_latent"  # latent attention's down-projections
+STREAM_OUT_RESULT = "hc_out"      # a sublayer's output under residual streams
+HC_RESULT = "hc_maps"             # the residual streams' maps' products
 # The order they are admitted in: milliseconds of the backward's second
 # forward spared a GB held, the small ones first. Timed on the v5e in the
 # benchmark's three `flash` cells: `r` of the `[scopes]` line of a traced
@@ -262,9 +315,18 @@ CONV_RESULT = "ssm_conv"          # the mixer's convolved x, B and C
 #   attn_qkv       laguna 12.5 / 0.82 (rope's turn with it); none under CCA
 #   ssm_conv       nemotron 3.6-5.8 / 0.42 (`silu`'s slope still forms the
 #                  float32 pre-activation again)
+#   hc_maps        xing: the float32 `highest` product of 14,336 and the
+#                  norm's pass over the streams, 96 bytes a token a sublayer
+#   attn_latent    xing: 1,344 dims a token, the cheapest thing of the
+#                  layer to keep: 2.2 ms / 0.11 GB (PERF.md §6, PR 39)
+#   hc_out         xing: the streams' map of a sublayer's output onto them
+#                  has that output in its gradient, so the output
+#                  projections (`attn/wo`, `mlp/wo`, the shared expert's, the
+#                  experts' combine) run again for it alone: ~22 / 0.59
 SAVED_RESULTS = (
-    GATE_RESULT, ROUTE_RESULT, RESIDUAL_RESULT, LATENT_RESULT, IN_PROJ_RESULT,
-    HIDDEN_RESULT, QKV_RESULT, CONV_RESULT,
+    HC_RESULT, GATE_RESULT, ROUTE_RESULT, RESIDUAL_RESULT, LATENT_RESULT,
+    IN_PROJ_RESULT, HIDDEN_RESULT, ATTN_LATENT_RESULT, STREAM_OUT_RESULT,
+    QKV_RESULT, CONV_RESULT,
 )
 
 
@@ -336,6 +398,15 @@ def _result_bytes(cfg: "TransformerConfig", tokens: int) -> list[dict]:
         out[QKV_RESULT] = tokens * act * (
             _lanes(kind.n_heads * cfg.head_dim) + 2 * _lanes(hk * cfg.head_dim)
         )
+        if cfg.kv_latent:  # q's and k's two parts and v; the two latents
+            h, r = kind.n_heads, cfg.rope_head_dim
+            out[QKV_RESULT] = tokens * act * (
+                2 * _lanes(h * cfg.head_dim) + _lanes(h * r) + _lanes(r)
+                + _lanes(h * (cfg.v_head_dim or cfg.head_dim))
+            )
+            out[ATTN_LATENT_RESULT] = tokens * act * (
+                _lanes(cfg.q_latent) + _lanes(cfg.kv_latent + r)
+            )
         if cfg.attention_gate:
             out[GATE_RESULT] = tokens * _lanes(kind.n_heads) * 4
 
@@ -364,8 +435,12 @@ def _result_bytes(cfg: "TransformerConfig", tokens: int) -> list[dict]:
 
     layers = []
     if cfg.layer_pattern is None:
+        n = cfg.residual_streams
         for i, kind in enumerate(_attention_kinds(cfg)):
-            out = {RESIDUAL_RESULT: tokens * _lanes(cfg.d_model) * act}
+            out = {RESIDUAL_RESULT: tokens * _stream_lanes(cfg) * act}
+            if n:  # n² + 2n products a token, float32, round both sublayers
+                out[HC_RESULT] = 2 * tokens * (n * n + 2 * n) * 4
+                out[STREAM_OUT_RESULT] = 2 * tokens * _lanes(cfg.d_model) * act
             attention(out, kind)
             if cfg.num_experts > 0 and i >= cfg.dense_layers:
                 experts(out)
@@ -391,19 +466,26 @@ REMAT_MARGIN_BYTES = 1 << 29
 REMAT_CODE_BYTES = 1 << 28
 
 
+def _stream_lanes(cfg: "TransformerConfig") -> int:
+    """Lanes of what a `Block` takes and returns: the residual stream, or
+    `residual_streams` of them side by side."""
+    return _lanes(max(cfg.residual_streams, 1) * cfg.d_model)
+
+
 def _kept_always_bytes(cfg: "TransformerConfig", tokens: int) -> int:
     """Bytes every layer's checkpoint holds whatever the plan:
     its inputs (the residual stream; the router's carried state) and
     `KERNEL_RESULTS` (attention's output and log-sum-exp, the scan's
     output and chunk states)."""
     act = jnp.dtype(cfg.dtype).itemsize
-    stream = tokens * _lanes(cfg.d_model) * act
+    stream = tokens * _stream_lanes(cfg) * act
     if cfg.num_experts > 0 and cfg.router == "mlp":
         stream += tokens * _lanes(cfg.router_hidden) * 4
 
     def attention(kind: AttentionKind) -> int:
+        width = (cfg.kv_latent and cfg.v_head_dim) or cfg.head_dim
         return tokens * (
-            _lanes(kind.n_heads * cfg.head_dim) * act + _lanes(kind.n_heads) * 4
+            _lanes(kind.n_heads * width) * act + _lanes(kind.n_heads) * 4
         )
 
     if cfg.layer_pattern is None:
@@ -416,22 +498,26 @@ def _kept_always_bytes(cfg: "TransformerConfig", tokens: int) -> int:
     )
 
 
-def _step_floor(
+def _peak_bytes(
     cfg: "TransformerConfig", tokens: int, layers: list[dict],
-    stated: memory.StepMemory,
+    stated: memory.StepMemory, names: tuple[str, ...] = (),
 ) -> int:
-    """Per-device bytes of a step that keeps no name, at its fullest: an
+    """Per-device bytes of a step that keeps `names`, at its fullest: an
     UPPER bound of the chip's compiler's `memory_analysis()` (arguments,
-    temporaries and code) in the benchmark's three `flash` cells, by
-    0.2-1.0 GB (PERF.md §6, PR 38). The larger of two moments. The loss:
-    the state, what every checkpoint holds, the float32 logits and their
-    cotangent. The last layer's backward: the state, every gradient (as
-    if no update were fused into a gradient's matmul), one worst-case row
-    buffer of the expert layer at its widest, and what the widest layer
-    forms again. A result kept adds its bytes to either."""
+    temporaries and code) in the benchmark's four `flash` cells, by
+    0.2-1.1 GB, and of the stream family's cuts to two and three layers
+    (PERF.md §6, PR 38 and PR 39). Both moments hold the state, the code,
+    one worst-case row buffer of the expert layer at its widest and what
+    the widest layer forms again (kept or not, a result is alive there
+    once; with residual streams, the streams in float32 for the mixes and
+    their float32 cotangent). The larger of the two. The top layer's
+    backward: what every checkpoint holds, every result kept, and the
+    loss's float32 logits and their cotangent, which may not be freed
+    yet. The bottom layer's: every gradient (as if no update were fused
+    into a gradient's matmul) and the results the other layers kept (as
+    if none were freed yet)."""
     act = jnp.dtype(cfg.dtype).itemsize
     logits = tokens * _lanes(cfg.vocab_size) * 4
-    at_loss = _kept_always_bytes(cfg, tokens) + 2 * logits
     rows = 0
     if cfg.num_experts > 0:
         _, held = cfg.experts_held or (0, cfg.num_experts)
@@ -439,9 +525,18 @@ def _step_floor(
             row_tiles(tokens, cfg.experts_per_token, held) * BLOCK_ROWS
             * max(cfg.moe_latent or cfg.d_model, cfg.d_ff) * act
         )
-    widest = max((sum(layer.values()) for layer in layers), default=0)
-    in_backward = stated.grad_bytes + rows + widest
-    return stated.state_bytes + max(at_loss, in_backward) + REMAT_CODE_BYTES
+    widest = max(layers, key=lambda layer: sum(layer.values()), default={})
+    again = sum(widest.values())
+    if cfg.residual_streams:
+        again += 2 * tokens * _stream_lanes(cfg) * 4
+    kept = lambda layer: sum(layer.get(name, 0) for name in names)
+    kept_all = sum(kept(layer) for layer in layers)
+    at_top = _kept_always_bytes(cfg, tokens) + kept_all + 2 * logits
+    at_bottom = stated.grad_bytes + kept_all - kept(widest)
+    return (
+        stated.state_bytes + REMAT_CODE_BYTES + rows + again
+        + max(at_top, at_bottom)
+    )
 
 
 def remat_plan(
@@ -451,10 +546,10 @@ def remat_plan(
 ) -> RematPlan:
     """What a step over `tokens` tokens a device keeps of `SAVED_RESULTS`
     under `remat_policy="flash"`, beside `KERNEL_RESULTS`: each name in
-    its order whose bytes, over the layers that form it, still leave the
-    predicted peak (`_step_floor` and the names admitted so far)
-    `REMAT_MARGIN_BYTES` under the device's limit; a name that does not
-    fit is refused and the next one tried.
+    its order that, with the names admitted so far, still leaves the
+    predicted peak (`_peak_bytes`) `REMAT_MARGIN_BYTES` under the
+    device's limit; a name that does not fit is refused and the next one
+    tried.
     Arithmetic over shapes and constants: the same plan on every trace.
     Nothing stated or no limit known (the CPU; a model applied outside a
     trainer): no name, the policy as it was."""
@@ -467,13 +562,15 @@ def remat_plan(
         if not (cfg.cca and name == QKV_RESULT)
         and (cost := sum(layer.get(name, 0) for layer in layers))
     }
-    floor = _step_floor(cfg, tokens, layers, stated)
-    names, held = [], 0
-    for name, cost in costs.items():
-        if floor + held + cost <= stated.limit_bytes - REMAT_MARGIN_BYTES:
+    peak = lambda names: _peak_bytes(cfg, tokens, layers, stated, tuple(names))
+    names = []
+    for name in costs:
+        if peak([*names, name]) <= stated.limit_bytes - REMAT_MARGIN_BYTES:
             names.append(name)
-            held += cost
-    return RematPlan(tuple(names), tuple(costs.items()), held, floor + held)
+    return RematPlan(
+        tuple(names), tuple(costs.items()), sum(costs[n] for n in names),
+        peak(names),
+    )
 
 
 def _dot_folded(x, kernel, dimension_numbers, precision=None):
@@ -570,7 +667,9 @@ class Attention(nn.Module):
     query heads over `n_kv_heads` K/V heads in a latent of n_heads x
     head_dim, rope over `rope_fraction` of a head (0: none), and CCA's mixing of q,
     k and v along the sequence when `cca` is on (OLMo's case is all of
-    them off and equal heads)."""
+    them off and equal heads); with `kv_latent`, latent attention
+    (`_latent_qkv`): low-rank q and k/v with a norm between, two-part
+    scores over values of their own width."""
 
     config: TransformerConfig
     mesh: Mesh | None = None
@@ -664,14 +763,88 @@ class Attention(nn.Module):
             "counters", "attn_gate_mean", jnp.mean(gate) / cfg.n_layers,
             reduce_fn=lambda _, new: new, init_fn=lambda: 0.0,
         )
-        width = heads * cfg.head_dim
+        width = heads * out.shape[-1]
         lanes_of = (
             jax.lax.broadcasted_iota(jnp.int32, (heads, width), 1)
-            // cfg.head_dim
+            // out.shape[-1]
             == jax.lax.broadcasted_iota(jnp.int32, (heads, width), 0)
         ).astype(cfg.dtype)
         wide = jnp.dot(gate.astype(cfg.dtype), lanes_of)  # [B, S, H·D]
         return (out.reshape(wide.shape) * wide).reshape(out.shape)
+
+    @nn.nowrap
+    def _by_parts(self, x, name: str, heads: int, widths: tuple[int, ...]):
+        """x [B, S, E] times ONE stored matrix [E, H, sum(widths)] (the
+        published layout: a head's parts side by side), as one product a
+        part, each [B, S, H·w]: the parts leave the matmuls apart, as the
+        kernels read them, where splitting a head's lanes of the joint
+        product would be a relayout of the activation (192 = 128 + 64 is
+        no whole tile; PERF.md §6, PR 31). The slices are of the weight."""
+        cfg = self.config
+        init = nn.initializers.variance_scaling(1.0, "fan_in", "normal")
+        shape = (x.shape[-1], heads, sum(widths))
+        kernel = self.param(
+            name,
+            nn.with_logical_partitioning(
+                # drawn as the [E, H·w] matrix it is
+                lambda key, shape, dtype: init(
+                    key, (shape[0], shape[1] * shape[2]), dtype
+                ).reshape(shape),
+                (None, "heads", "kv"),
+            ),
+            shape, jnp.float32,
+        )
+        out, at = [], 0
+        for w in widths:
+            part = kernel[:, :, at:at + w].reshape(shape[0], heads * w)
+            out.append(jnp.dot(x, part.astype(cfg.dtype)))
+            at += w
+        return out
+
+    @nn.nowrap
+    def _latent_qkv(self, x, positions, kind: AttentionKind):
+        """Latent attention's operands (DeepSeek-V2's equations, whose
+        keys the configuration carries): `c_q = norm(x wq_a)`, a head's
+        `[q | q_rope] = c_q wq_b`; `[c | k_rope] = x wkv_a`, `c_kv =
+        norm(c)`, a head's `[k | v] = c_kv wkv_b`; rope turns `q_rope` and
+        the ONE `k_rope` all heads share. -> q, k [B, S, H·D], v
+        [B, S, H·Dv], q_rope [B, S, H·R], k_rope [B, S, R]."""
+        cfg = self.config
+        h, d, r = kind.n_heads, cfg.head_dim, cfg.rope_head_dim
+        norm = functools.partial(RMSNorm, cfg.dtype, cfg.norm_eps)
+        # The products are what is named: a norm's backward reads its input.
+        named = lambda u: checkpoint_name(u, ATTN_LATENT_RESULT)
+        with jax.named_scope("attn.latent_q"):
+            c_q = norm(name="q_norm")(named(_dense(
+                cfg.q_latent, ("embed", None), "wq_a", cfg.dtype
+            )(x)))
+            q, q_rope = self._by_parts(c_q, "wq_b", h, (d, r))
+        with jax.named_scope("attn.latent_kv"):
+            joint = named(_dense(
+                cfg.kv_latent + r, ("embed", None), "wkv_a", cfg.dtype
+            )(x))
+            c_kv = norm(name="kv_norm")(joint[..., : cfg.kv_latent])
+            k_rope = joint[..., cfg.kv_latent:]
+            k, v = self._by_parts(
+                c_kv, "wkv_b", h, (d, cfg.v_head_dim or d)
+            )
+        how = {}
+        if kind.rope_yarn is not None:
+            factor, original, fast, slow, attention_factor = kind.rope_yarn
+            how = dict(
+                inv_freq=yarn_inv_freq(
+                    kind.rope_theta, r, factor=factor, original_max=original,
+                    beta_fast=fast, beta_slow=slow,
+                ),
+                scale=attention_factor,
+            )
+        turn = functools.partial(
+            rope, positions=positions, theta=kind.rope_theta, head_dim=r,
+            mesh=self.mesh, **how,
+        )
+        with jax.named_scope("rope"):
+            q_rope, k_rope = turn(q_rope), turn(k_rope)
+        return q, k, v, q_rope, k_rope
 
     @nn.compact
     def __call__(self, x, positions):
@@ -679,13 +852,26 @@ class Attention(nn.Module):
         kind = self.kind or _own_kind(cfg)
         h, d = kind.n_heads, cfg.head_dim
         hk = cfg.n_kv_heads or h
+        heads = lambda u, width=d: u.reshape(*u.shape[:2], -1, width)
+        if cfg.kv_latent:
+            q, k, v, *pair = self._latent_qkv(x, positions, kind)
+            q, k, v, *pair = (
+                checkpoint_name(u, QKV_RESULT) for u in (q, k, v, *pair)
+            )
+            with jax.named_scope("attend"):
+                out = attend(
+                    heads(q), heads(k), heads(v, v.shape[-1] // h),
+                    mesh=self.mesh, impl=cfg.attention_impl,
+                    q_rope=heads(pair[0], cfg.rope_head_dim), k_rope=pair[1],
+                    scale=cfg.softmax_scale,
+                )
+            return self._out(x, out, h)
         # q, k and v stay [B, S, H·d] from the projections' matmuls to the
         # attention kernels (`_dot_folded`); only CCA's mixing splits the
         # heads out, and `attend` takes them as a reshape.
         q = _dense((h, d), ("embed", "heads", "kv"), "wq", cfg.dtype)(x)
         k = _dense((hk, d), ("embed", "heads", "kv"), "wk", cfg.dtype)(x)
         v = _dense((hk, d), ("embed", "heads", "kv"), "wv", cfg.dtype)(x)
-        heads = lambda u: u.reshape(*u.shape[:2], -1, d)
         if cfg.cca:
             with jax.named_scope("cca.mix"):
                 q, k, v = self._cca_mix(heads(q), heads(k), heads(v))
@@ -719,6 +905,13 @@ class Attention(nn.Module):
                 heads(q), heads(k), heads(v), mesh=self.mesh,
                 impl=cfg.attention_impl, window=kind.window,
             )
+        return self._out(x, out, h)
+
+    @nn.nowrap
+    def _out(self, x, out, h: int):
+        """Attention's output [B, S, H, D] through the gate (where the
+        stack has one) and the output projection."""
+        cfg = self.config
         if cfg.attention_gate:
             with jax.named_scope("attn.gate"):
                 out = self._gate(x, out, h)
@@ -1086,11 +1279,157 @@ class StateSpaceMixer(nn.Module):
             return _dense(cfg.d_model, (None, "embed"), "out_proj", cfg.dtype)(y)
 
 
+def _sinkhorn(m, iters: int, eps: float):
+    """m [B, n, n, S] made doubly stochastic: every row divided by (its
+    sum + eps), then every column, `iters` times."""
+    for _ in range(iters):
+        m = m / (jnp.sum(m, axis=2, keepdims=True) + eps)
+        m = m / (jnp.sum(m, axis=1, keepdims=True) + eps)
+    return m
+
+
+def _split3(w):
+    """A float32 array as three bfloat16 pieces whose sum is it (24 bits
+    of mantissa in three times 8), side by side along axis 1."""
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    hi = w.astype(bf16)
+    rest = w - hi.astype(f32)
+    mid = rest.astype(bf16)
+    low = (rest - mid.astype(f32)).astype(bf16)
+    return jnp.concatenate([hi, mid, low], axis=1)
+
+
+def _thirds(t, axis: int):
+    return sum(jnp.split(t, 3, axis=axis))
+
+
+@jax.custom_vjp
+def _exact_product(x, phi):
+    """`einsum("kc,bsk->bcs", phi, x)` at full precision for x in
+    bfloat16 and phi in float32, in ONE pass of the MXU: x is exact in
+    bfloat16 already, so only phi is split in three (`_split3`), and its
+    pieces ride the output's lanes, where 3 x 24 columns cost what 24 do
+    (a tile is 128). `precision=HIGHEST` splits both operands, six
+    passes: 0.92 ms against 0.15 at [8192, 14336] x [14336, 24] by the
+    MXU's peak. Backward the same way: phi's gradient from the
+    cotangent split in three, exact; x's in one plain pass, since it is
+    rounded to bfloat16 where it lands."""
+    return _exact_product_fwd(x, phi)[0]
+
+
+def _exact_product_fwd(x, phi):
+    t = jnp.einsum(
+        "kc,bsk->bcs", _split3(phi), x, preferred_element_type=jnp.float32
+    )
+    return _thirds(t, 1), (x, phi)
+
+
+def _exact_product_bwd(residuals, dt):
+    x, phi = residuals
+    dx = jnp.einsum(
+        "bcs,kc->bsk", dt.astype(x.dtype), phi.astype(x.dtype),
+        preferred_element_type=jnp.float32,
+    ).astype(x.dtype)
+    dphi = jnp.einsum(
+        "bcs,bsk->kc", _split3(dt), x, preferred_element_type=jnp.float32
+    )
+    return dx, _thirds(dphi, 1)
+
+
+_exact_product.defvjp(_exact_product_fwd, _exact_product_bwd)
+
+
+class StreamMaps(nn.Module):
+    """The three maps round one sublayer of a stack with n =
+    `residual_streams` streams X [B, S, n·d] (mHC's symbols): `x =
+    RMSNorm(X)` over all n·d dims, no learned scale; `[t_pre | t_post |
+    t_res] = x phi`, `phi` [n·d, n² + 2n], float32 at full precision;
+    `Hp = sigmoid(a_pre t_pre + b_pre)` [n], the weights of the
+    sublayer's input; `Ho = 2 sigmoid(a_post t_post + b_post)` [n], of
+    its output onto each stream; `Hr = sinkhorn(exp(clip(a_res t_res +
+    b_res, +-hc_clamp)))` [n, n], stream to stream. Returned with the
+    sequence in the lanes, [B, n, S], [B, n, S], [B, n, n, S] float32 (a
+    trailing axis of 4 would pad a tile 32 times over). The norm is a
+    scalar a token, so it multiplies the product, not the streams: X is
+    read, never written; bfloat16 streams take the product in one pass
+    (`_exact_product`). The products are named (`HC_RESULT`) and the
+    iterations sit in a checkpoint of their own, so their backward forms
+    them again from the products and saves none of the 2·`hc_iters`
+    intermediates. Sows `hc_sinkhorn_err` (the largest |row or column
+    sum - 1| of Hr) and `hc_res_diag_mean` (Hr's mean diagonal), each
+    over the stack's sublayers' count so that the step's sum is a mean."""
+
+    config: TransformerConfig
+
+    @nn.compact
+    def __call__(self, streams):
+        cfg = self.config
+        n, f32 = cfg.residual_streams, jnp.float32
+        width, maps = streams.shape[-1], n * n + 2 * n
+        phi = self.param(
+            "phi",
+            nn.with_logical_partitioning(
+                nn.initializers.normal(width ** -0.5), ("embed", None)
+            ),
+            (width, maps), f32,
+        )
+        # b_res 2 on the diagonal: the seed's stream-to-stream map leans
+        # to the identity without being it.
+        bias = self.param(
+            "b",
+            _replicated(lambda *_: jnp.concatenate(
+                [jnp.zeros(2 * n, f32), 2.0 * jnp.eye(n, dtype=f32).reshape(-1)]
+            ), 1),
+            (maps,), f32,
+        )
+        a = self.param("a", _replicated(nn.initializers.ones, 1), (3,), f32)
+
+        @jax.checkpoint
+        def of_products(t, a, bias):
+            z = jnp.repeat(a, jnp.array([n, n, n * n]), total_repeat_length=maps)
+            z = z[:, None] * t + bias[:, None]
+            pre, post, res = z[:, :n], z[:, n:2 * n], z[:, 2 * n:]
+            m = jnp.exp(jnp.clip(res, -cfg.hc_clamp, cfg.hc_clamp))
+            m = _sinkhorn(
+                m.reshape(-1, n, n, m.shape[-1]), cfg.hc_iters, cfg.hc_eps
+            )
+            return jax.nn.sigmoid(pre), 2.0 * jax.nn.sigmoid(post), m
+
+        with jax.named_scope("hc.maps"):
+            x = streams.astype(f32)
+            inv_rms = jax.lax.rsqrt(
+                jnp.mean(x * x, axis=-1) + cfg.norm_eps
+            )  # [B, S]
+            if streams.dtype == jnp.bfloat16:
+                t = _exact_product(streams, phi)
+            else:
+                t = jnp.einsum(
+                    "kc,bsk->bcs", phi, x, precision=jax.lax.Precision.HIGHEST
+                )
+            t = t * inv_rms[:, None, :]
+            hp, ho, hr = of_products(checkpoint_name(t, HC_RESULT), a, bias)
+            sums = jnp.concatenate([hr.sum(axis=2), hr.sum(axis=1)], axis=1)
+            sublayers = 2 * cfg.n_layers
+            for name, value in (
+                ("hc_sinkhorn_err", jnp.max(jnp.abs(sums - 1.0))),
+                ("hc_res_diag_mean", jnp.mean(
+                    jnp.trace(hr, axis1=1, axis2=2) / n
+                )),
+            ):
+                self.sow(
+                    "counters", name, value / sublayers,
+                    reduce_fn=lambda _, new: new, init_fn=lambda: 0.0,
+                )
+        return hp, ho, hr
+
+
 class Block(nn.Module):
     """One layer: attention, then the dense MLP or the expert layer.
     Takes and returns the router's carried state beside the residual
     (None where there are no experts). `layer` is its place in the stack,
-    which only `router_force_balance` reads."""
+    which only `router_force_balance` reads. With `residual_streams` the
+    residual is that many streams side by side, [B, S, n·d], and each
+    sublayer is taken round by their maps (`_mixed`)."""
 
     config: TransformerConfig
     mesh: Mesh | None = None
@@ -1101,16 +1440,40 @@ class Block(nn.Module):
     attention: AttentionKind | None = None
     dense: bool = False
 
+    @nn.nowrap
+    def _mixed(self, streams, name: str, sublayer):
+        """One sublayer round n streams [B, S, n·d]: its input is
+        `sum_i Hp[i] X[i]`, and `X'[i] = sum_j Hr[i, j] X[j] + Ho[i] y`
+        with y its output (`StreamMaps`). The sums in float32, the
+        streams stored in `dtype`. `sublayer(h)` -> (y, what it returns
+        beside)."""
+        cfg = self.config
+        n, d, f32 = cfg.residual_streams, cfg.d_model, jnp.float32
+        hp, ho, hr = StreamMaps(cfg, name=f"hc_{name}")(streams)
+        column = lambda m: m[..., None]  # [B, S] -> a factor a token
+        with jax.named_scope("hc.pre"):
+            one = [
+                streams[..., i * d:(i + 1) * d].astype(f32) for i in range(n)
+            ]
+            h = sum(column(hp[:, i]) * one[i] for i in range(n))
+        y, beside = sublayer(h.astype(cfg.dtype))
+        with jax.named_scope("hc.post"):
+            # `Ho`'s gradient reads y: named, or the sublayer's last
+            # product runs again for it alone.
+            y = checkpoint_name(y, STREAM_OUT_RESULT).astype(f32)
+            mixed = jnp.concatenate([
+                (
+                    sum(column(hr[:, i, j]) * one[j] for j in range(n))
+                    + column(ho[:, i]) * y
+                ).astype(cfg.dtype)
+                for i in range(n)
+            ], axis=-1)
+        return mixed, beside
+
     @nn.compact
     def __call__(self, x, positions, router_state=None):
         cfg = self.config
         norm = functools.partial(RMSNorm, cfg.dtype, cfg.norm_eps)
-        x = checkpoint_name(
-            x + Attention(cfg, self.mesh, self.attention, name="attn")(
-                norm(name="ln_attn")(x), positions
-            ),
-            RESIDUAL_RESULT,
-        )
         # The "mlp" policy's only checkpoint: the MLP half recomputes in
         # the backward, attention's residuals stay saved (the lifted
         # transform keeps the param path, so weights are identical to
@@ -1119,17 +1482,32 @@ class Block(nn.Module):
             nn.remat if cfg.remat_policy == "mlp"
             else (lambda cls: cls)
         )
-        h = norm(name="ln_mlp")(x)
-        if cfg.num_experts > 0 and not self.dense:
-            out, router_state = wrap(ExpertLayer)(
-                cfg, self.mesh, self.layer, name="moe"
-            )(h, router_state)
-        else:
+
+        def attention(x):
+            return Attention(cfg, self.mesh, self.attention, name="attn")(
+                norm(name="ln_attn")(x), positions
+            )
+
+        def feed_forward(x, router_state):
+            h = norm(name="ln_mlp")(x)
+            if cfg.num_experts > 0 and not self.dense:
+                return wrap(ExpertLayer)(
+                    cfg, self.mesh, self.layer, name="moe"
+                )(h, router_state)
             dense = (
                 dataclasses.replace(cfg, d_ff=cfg.dense_d_ff)
                 if self.dense else cfg
             )
-            out = wrap(SwiGLU)(dense, name="mlp")(h)
+            return wrap(SwiGLU)(dense, name="mlp")(h), router_state
+
+        if cfg.residual_streams:
+            x, _ = self._mixed(x, "attn", lambda h: (attention(h), None))
+            x = checkpoint_name(x, RESIDUAL_RESULT)
+            return self._mixed(
+                x, "mlp", lambda h: feed_forward(h, router_state)
+            )
+        x = checkpoint_name(x + attention(x), RESIDUAL_RESULT)
+        out, router_state = feed_forward(x, router_state)
         return x + out, router_state
 
 
@@ -1266,11 +1644,12 @@ class PipelinedTransformerLM(nn.Module):
         cfg = self.config
         if (
             cfg.num_experts > 0 or cfg.layer_pattern or not cfg.tie_embeddings
-            or cfg.attention_kinds
+            or cfg.attention_kinds or cfg.residual_streams
         ):
             raise ValueError(
                 "pipelined transformer does not support MoE, a layer "
-                "pattern, attention kinds by layer or an untied head"
+                "pattern, attention kinds by layer, residual streams or an "
+                "untied head"
             )
         if cfg.n_layers % self.n_stages:
             raise ValueError(
@@ -1545,10 +1924,22 @@ class TransformerLM(nn.Module):
                     "counters", name, float(value),
                     reduce_fn=lambda _, new: new, init_fn=lambda: 0.0,
                 )
-        for i, layer_cls in enumerate(_layer_classes(cfg, plan.names)):
+        layers = _layer_classes(cfg, plan.names)  # checked before anything
+        n = cfg.residual_streams
+        if n:  # every stream enters as the embedding's row
+            with jax.named_scope("hc.entry"):
+                x = jnp.tile(x, n)
+        for i, layer_cls in enumerate(layers):
             x, router_state = layer_cls(
                 cfg, self.mesh, layer=i, name=f"layer_{i}"
             )(x, positions, router_state)
+        if n:  # and their sum leaves
+            with jax.named_scope("hc.exit"):
+                x = sum(
+                    x[..., i * cfg.d_model:(i + 1) * cfg.d_model].astype(
+                        jnp.float32
+                    ) for i in range(n)
+                ).astype(cfg.dtype)
         x = RMSNorm(cfg.dtype, cfg.norm_eps, name="ln_final")(x)
         head = embed
         if not cfg.tie_embeddings:

@@ -35,13 +35,24 @@ from kubeflow_tpu.parallel.sharding import batch_axes
 
 
 def dense_attention(
-    q, k, v, *, causal: bool = True, window: int | None = None
+    q, k, v, *, causal: bool = True, window: int | None = None,
+    q_rope=None, k_rope=None, scale: float | None = None,
 ):
-    """Reference attention. q,k,v: [B, S, H, D] (or [B,S,G,H,D] grouped).
-    `window` (causal only): a query sees the last `window` keys, its own
-    among them, the band mask."""
+    """Reference attention. q,k,v: [B, S, H, D] (or [B,S,G,H,D] grouped);
+    v may be of another width than q and k. `window` (causal only): a
+    query sees the last `window` keys, its own among them, the band mask.
+    With `q_rope` [B, S, H, R] and `k_rope` [B, S, R] the scores are
+    `q·kᵀ + q_rope·k_ropeᵀ`, the rope key one for all heads (latent
+    attention); `scale` is their factor, (D + R)^-1/2 unless given."""
     d = q.shape[-1]
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / math.sqrt(d)
+    if q_rope is None:
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k)
+    else:
+        d += q_rope.shape[-1]
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) + jnp.einsum(
+            "bqhr,bkr->bhqk", q_rope, k_rope
+        )
+    scores = scores / math.sqrt(d) if scale is None else scores * scale
     if causal:
         s_q, s_k = scores.shape[-2], scores.shape[-1]
         mask = jnp.tril(jnp.ones((s_q, s_k), bool), k=s_k - s_q)
@@ -157,7 +168,8 @@ def ring_attention(
 
 
 def attend(
-    q, k, v, *, mesh: Mesh | None, impl: str, window: int | None = None
+    q, k, v, *, mesh: Mesh | None, impl: str, window: int | None = None,
+    q_rope=None, k_rope=None, scale: float | None = None,
 ):
     """Causal self-attention of q [B, S, H, D] over k, v [B, S, Hkv, D]
     (H a multiple of Hkv): ring when the mesh's `sp` axis is real, else
@@ -167,6 +179,14 @@ def attend(
     in the kernels' compact grid, the band mask on the dense path; a
     window that reaches every key is the causal call. The ring refuses
     one: a band crosses few of its hops, and nothing has run that.
+
+    With `q_rope` [B, S, H, R] and `k_rope` [B, S, R] (latent attention)
+    the scores have two parts, D dims a head and R against one rope key
+    for all heads, over values D wide; `scale` is the scores' factor
+    ((D + R)^-1/2, or D^-1/2 without a rope part, unless given). The ring,
+    a window and a `tp` axis refuse the rope part with their numbers: the
+    one key's gradient is a sum over the heads, which nothing has run
+    across shards.
 
     The flash kernel is a Pallas call, which does not auto-partition under
     pjit — with a mesh it runs inside shard_map over the batch/tp axes
@@ -189,6 +209,20 @@ def attend(
     # Only the flash kernels pick a query head's kv head themselves; the
     # ring and dense paths get K and V repeated over the group.
     repeat = lambda x: x if group == 1 else jnp.repeat(x, group, axis=2)
+    two_part = q_rope is not None
+    shape = dict(mesh.shape) if mesh is not None else {}
+    if two_part and (
+        window is not None or shape.get("sp", 1) > 1 or shape.get("tp", 1) > 1
+    ):
+        raise ValueError(
+            f"attend: a rope part of {q_rope.shape[-1]} dims with "
+            f"window={window} on a mesh {shape}: two-part scores run the "
+            "causal triangle on shards of the batch only"
+        )
+    # What the rope part adds to a call: nothing to one without it.
+    rope = {} if scale is None else dict(scale=scale)
+    if two_part:
+        rope.update(q_rope=q_rope, k_rope=k_rope)
     if mesh is not None and mesh.shape.get("sp", 1) > 1:
         # Ring (sequence-parallel) path. Where the kernels compile (any
         # backend but the CPU) and the local chunks are flash-tileable,
@@ -236,30 +270,35 @@ def attend(
             use_flash = False
     if not use_flash:
         return dense_attention(
-            q, repeat(k), repeat(v), causal=True, window=window
+            q, repeat(k), repeat(v), causal=True, window=window, **rope
         )
     if mesh is None:
-        return flash_attention(q, k, v, causal=True, window=window)
+        return flash_attention(q, k, v, causal=True, window=window, **rope)
     # The shards' boundary is crossed with the heads folded into the last
     # axis, [B, S, H·D], as the projections write q, k and v and as the
     # kernels read them (`ops/flash.py`): a [B, S, H, D] array there is
     # given a layout of its own on the TPU and costs a relayout each way.
     d = q.shape[-1]
     fold = lambda x: x.reshape(*x.shape[:2], -1)
+    heads = lambda x, width: x.reshape(*x.shape[:2], -1, width)
 
-    def shard(q, k, v):
-        heads = lambda x: x.reshape(*x.shape[:2], -1, d)
+    def shard(q, k, v, *pair):
+        how = {} if scale is None else dict(scale=scale)
+        if pair:  # the rope part: q_rope folded like q, the one key as it is
+            how.update(q_rope=heads(pair[0], pair[1].shape[-1]), k_rope=pair[1])
         return fold(flash_attention(
-            heads(q), heads(k), heads(v), causal=True, window=window
+            heads(q, d), heads(k, d), heads(v, d), causal=True, window=window,
+            **how,
         ))
 
     spec = P(
         batch_axes(mesh), None, "tp" if mesh.shape.get("tp", 1) > 1 else None
     )
+    pair = (fold(q_rope), k_rope) if two_part else ()
     return jax.shard_map(
         shard,
         mesh=mesh,
-        in_specs=(spec, spec, spec),
+        in_specs=(spec,) * (3 + len(pair)),
         out_specs=spec,
         check_vma=False,
-    )(fold(q), fold(k), fold(v)).reshape(q.shape)
+    )(fold(q), fold(k), fold(v), *pair).reshape(q.shape)

@@ -48,6 +48,21 @@ on top:
   The fused backward's dq ring holds the reach + 1 row blocks that are
   live at once (`_ring_rows`), whatever S is. The calls are named
   `flash_*_window*` (`_kernel_name`); W >= S is the causal call.
+- **Two-part scores.** With `q_rope` [B, S, H, R] and `k_rope`
+  [B, S, R] (latent attention: DeepSeek-V2's MLA) a head's scores are
+  q·kᵀ + q_rope·k_ropeᵀ: a part a head, as wide as v, beside a rope part
+  whose KEY is one for all heads. Every kernel takes q and k as tuples
+  of parts (`_parts`, `_sum_parts`): the score tile is the sum of two
+  products, dq and dk get a product a part (the fused backward's five
+  block products become eight), each part with a ring or accumulator
+  and an output of its own; v, o, dO, dv and `flash_delta` know nothing
+  of it. The rope key's block is fetched by the index map of a K/V
+  group that holds all heads (`_rope_specs`), so it lies in HBM once,
+  and dk_rope leaves one partial a head, summed like a group's
+  (`_sum_groups`). The 128-wide operands keep their layout; the 64-wide
+  q_rope goes head-major (`ROPE_LAYOUT`). The calls are named
+  `flash_*_mla*`.
+  A call without the pair traces the program it traced before.
 - **Lane-packed LSE.** The saved log-sum-exp is stored as
   [BH, S/128, 128] tiles — 128 per-row values per lane row — instead of
   the lane-replicated [BH, S, 128] buffer Mosaic's tiling would
@@ -118,9 +133,11 @@ every other backend they are compiled, and a device the TPU compiler does
 not know fails loudly instead of interpreting.
 
 Every `pallas_call` carries a stable `name=` (`flash_fwd_*`,
-`flash_delta`, `flash_bwd_fused`, `flash_dq_*`, `flash_dkv_*`, and a
+`flash_delta`, `flash_bwd_fused`, `flash_dq_*`, `flash_dkv_*`, a
 window's `flash_fwd_window`, `flash_bwd_window_fused`,
-`flash_dq_window`, `flash_dkv_window`): that is
+`flash_dq_window`, `flash_dkv_window`, and the two-part calls'
+`flash_fwd_mla`, `flash_bwd_mla_fused`, `flash_dq_mla`,
+`flash_dkv_mla`): that is
 what `testing/hlo.pallas_kernel_names` reads out of a jaxpr to tell which
 schedule was traced, and what a profiler trace keys kernel time on.
 """
@@ -407,7 +424,8 @@ def _lse_block_bytes(bq: int, packed: bool) -> int:
 
 
 def _fused_vmem_bytes(
-    sq: int, bq: int, bk: int, d: int, itemsize: int, packed: bool
+    sq: int, bq: int, bk: int, d: int, itemsize: int, packed: bool,
+    rope: int = 0,
 ) -> int:
     """Upper bound on the scoped VMEM the compiler allocates for the
     fused kernel: the dq ring (f32, one slot per q block — i.e. the
@@ -416,8 +434,12 @@ def _fused_vmem_bytes(
     body's live values — three (bq, bk) f32 temporaries of the
     s/p/dp/ds recurrence and the f32 casts of q and do. Checked against
     the compiler's own figures over bq ∈ {256..2048}, d ∈ {64..256},
-    bf16 and f32: 10–25% above each."""
-    return (
+    bf16 and f32: 10–25% above each. With a rope part of `rope` dims
+    (two-part scores) everything q and k have, the rope part has too,
+    in whole lane tiles: a second dq ring, a second dk accumulator, its
+    blocks in and out and their f32 casts."""
+    r = -(-rope // _LANES) * _LANES
+    return _rope_vmem_bytes(sq, bq, bk, r, itemsize) + (
         sq * d * 4  # dq ring scratch
         + 2 * bk * d * 4  # dk/dv accumulators
         + 2 * 2 * bq * d * itemsize  # q, do blocks (double-buffered)
@@ -426,6 +448,16 @@ def _fused_vmem_bytes(
         + 2 * 2 * _lse_block_bytes(bq, packed)  # lse, delta blocks
         + 3 * bq * bk * 4  # s/p, dp, ds temporaries
         + 2 * bq * d * 4  # f32 q, do
+    )
+
+
+def _rope_vmem_bytes(sq: int, bq: int, bk: int, r: int, itemsize: int) -> int:
+    return (
+        sq * r * 4  # the rope part's dq ring
+        + bk * r * 4  # its dk accumulator
+        + 2 * (bq + bk) * r * itemsize  # q_rope, k_rope blocks
+        + 2 * (bq + bk) * r * itemsize  # dq_rope, dk_rope output blocks
+        + (bq + bk) * r * 4  # f32 q_rope, k_rope
     )
 
 
@@ -439,7 +471,7 @@ def _ring_rows(sq: int, bq: int, window: int | None) -> int:
 
 def _bwd_fused(
     causal: bool, sq: int, sk: int, bq: int, bk: int, d: int,
-    itemsize: int, packed: bool, window: int | None = None,
+    itemsize: int, packed: bool, window: int | None = None, rope: int = 0,
 ) -> bool:
     """Whether the backward runs the fused one-pass kernel: compact
     causal grid (square blocks, self-attention) AND the modelled
@@ -449,7 +481,7 @@ def _bwd_fused(
     on is the schedule that actually runs."""
     return _compactable(causal, sq, sk, bq, bk, window) and (
         _fused_vmem_bytes(
-            _ring_rows(sq, bq, window), bq, bk, d, itemsize, packed
+            _ring_rows(sq, bq, window), bq, bk, d, itemsize, packed, rope
         )
         <= _FUSED_VMEM_BUDGET
     )
@@ -458,9 +490,12 @@ def _bwd_fused(
 def _bwd_hbm_bytes(
     causal: bool, sq: int, sk: int, bq: int, bk: int, d: int,
     itemsize: int, packed: bool, fused: bool, window: int | None = None,
+    rope: int = 0,
 ) -> int:
     """Modeled backward HBM bytes per (batch·head) grid row, including
-    the shared-delta precompute. Counts what each kernel's BlockSpec
+    the shared-delta precompute. With a rope part of `rope` dims q and dq
+    are d + rope wide and k and dk too (a grid row fetches the one rope
+    key's block a column as it fetches its head's k), v, dO and dv stay d. Counts what each kernel's BlockSpec
     pipeline actually moves: blocks whose index map is constant across
     consecutive grid steps are fetched once per row/column (Mosaic
     elides the re-fetch); blocks whose index changes stream once per
@@ -475,25 +510,25 @@ def _bwd_hbm_bytes(
         # One walk, column-major: k/v resident per column; q/do/lse/delta
         # stream per step; dq+dk+dv written once each.
         return delta + (
-            2 * sk * d * itemsize  # k, v (once per column)
-            + steps * 2 * bq * d * itemsize  # q, do per step
+            (2 * d + rope) * sk * itemsize  # k, v (once per column)
+            + steps * (2 * d + rope) * bq * itemsize  # q, do per step
             + steps * 2 * lse_blk  # lse, delta rows per step
-            + 3 * sq * d * itemsize  # dq, dk, dv writes
+            + (3 * d + 2 * rope) * sq * itemsize  # dq, dk, dv writes
         )
     # Two passes over the same grid: the dq kernel (row-major) streams
     # k/v per step with q/do/lse/delta resident per row; the dkv kernel
     # (column-major) streams q/do/lse/delta per step with k/v resident.
     dq_pass = (
-        2 * sq * d * itemsize  # q, do (once per row)
+        (2 * d + rope) * sq * itemsize  # q, do (once per row)
         + 2 * lse_bytes  # lse, delta (once per row)
-        + steps * 2 * bk * d * itemsize  # k, v per step
-        + sq * d * itemsize  # dq write
+        + steps * (2 * d + rope) * bk * itemsize  # k, v per step
+        + (d + rope) * sq * itemsize  # dq write
     )
     dkv_pass = (
-        2 * sk * d * itemsize  # k, v (once per column)
-        + steps * 2 * bq * d * itemsize  # q, do per step
+        (2 * d + rope) * sk * itemsize  # k, v (once per column)
+        + steps * (2 * d + rope) * bq * itemsize  # q, do per step
         + steps * 2 * lse_blk  # lse, delta rows per step
-        + 2 * sk * d * itemsize  # dk, dv writes
+        + (2 * d + rope) * sk * itemsize  # dk, dv writes
     )
     return delta + dq_pass + dkv_pass
 
@@ -567,6 +602,7 @@ def flash_schedule(
     head_dim: int = 128,
     dtype_bytes: int = 2,
     window: int | None = None,
+    rope_dim: int = 0,
 ) -> dict:
     """Static accounting for the schedule `flash_attention` would run.
 
@@ -578,8 +614,11 @@ def flash_schedule(
     kernels walk the forward's grid (the same blocks); `head_dim` and
     `dtype_bytes` (2 = bf16, the training dtype) parameterize the
     backward byte/VMEM models only. `window` as `flash_attention` takes
-    it: a window that reaches the whole sequence is the causal call."""
+    it: a window that reaches the whole sequence is the causal call.
+    `rope_dim` > 0 is the call with two-part scores (`q_rope`, `k_rope`):
+    `head_dim` is then the part a head of q and k, and v's width."""
     window = _checked_window(window, causal, seq_k)
+    _checked_rope(rope_dim, window)
     sp_q = _pad_to_tileable(block_q, seq_q)
     sp_k = _pad_to_tileable(block_k, seq_k)
     bq = _pick_block(block_q, sp_q)
@@ -588,10 +627,12 @@ def flash_schedule(
     packed = _lse_is_packed(sp_q, bq)
     lse_shape = _lse_layout_shape(1, sp_q, packed)[1:]
     fused = _bwd_fused(
-        causal, sp_q, sp_k, bq, bk, head_dim, dtype_bytes, packed, window
+        causal, sp_q, sp_k, bq, bk, head_dim, dtype_bytes, packed, window,
+        rope_dim,
     )
     bwd_bytes = lambda f: _bwd_hbm_bytes(
-        causal, sp_q, sp_k, bq, bk, head_dim, dtype_bytes, packed, f, window
+        causal, sp_q, sp_k, bq, bk, head_dim, dtype_bytes, packed, f, window,
+        rope_dim,
     )
     layout = _head_layout(head_dim)
     return {
@@ -611,7 +652,7 @@ def flash_schedule(
         "bwd_total_grid_steps": steps if fused else 2 * steps,
         "bwd_fused_vmem_bytes": _fused_vmem_bytes(
             _ring_rows(sp_q, bq, window), bq, bk, head_dim, dtype_bytes,
-            packed,
+            packed, rope_dim,
         ),
         "bwd_hbm_bytes": bwd_bytes(fused),
         "bwd_hbm_bytes_fused": bwd_bytes(True),
@@ -633,6 +674,14 @@ def flash_schedule(
         # backward, or none.
         "layout": layout,
         "transposes_per_call": 0 if layout == "seq_major" else 8,
+        # The widths a step's products have: a head's scores contract
+        # `qk_dim` + `rope_dim` dims (the second against the one rope key
+        # all heads share), its values are `v_dim` wide; the rope
+        # operand's own layout (`ROPE_LAYOUT`), None without one.
+        "qk_dim": head_dim,
+        "rope_dim": rope_dim,
+        "v_dim": head_dim,
+        "rope_layout": ROPE_LAYOUT if rope_dim else None,
     }
 
 
@@ -862,15 +911,44 @@ def _dot_tn(a, b):
     )
 
 
+# -- two-part scores ----------------------------------------------------------
+#
+# A kernel's q and k are tuples of refs, the PARTS of a head's scores:
+# the part a head alone, and with latent attention (DeepSeek-V2's MLA) a
+# rope part besides, whose key is ONE for all heads, so that
+# s = q·kᵀ + q_rope·k_ropeᵀ. The bodies sum a product a part wherever
+# they formed one: the scores, dq and dk (each part its own accumulator
+# and output); v, o, dO and dv know nothing of it. With one part the
+# loops run once and the traced body is the one it was.
+
+
+def _parts(refs, scale=None):
+    """The float32 blocks of an operand's parts (q's pre-scaled)."""
+    blocks = tuple(ref[0].astype(jnp.float32) for ref in refs)
+    if scale is None:
+        return blocks
+    return tuple(blk * scale for blk in blocks)
+
+
+def _sum_parts(dot, a, b):
+    """dot(a₀, b₀) + dot(a₁, b₁) + ...: the scores of two-part q and k."""
+    s = dot(a[0], b[0])
+    for x, y in zip(a[1:], b[1:]):
+        s = s + dot(x, y)
+    return s
+
+
 def _fwd_tiles(
-    q_ref, k_ref, v_ref, m_scr, l_scr, acc, plan, mask, guard, scale
+    q_refs, k_refs, v_ref, m_scr, l_scr, acc, plan, mask, guard, scale
 ):
     """One online-softmax update of its rows of (m, l, acc) a tile."""
-    q_blk = q_ref[0].astype(jnp.float32) * scale
-    k_blk = k_ref[0].astype(jnp.float32)
+    q_blk = _parts(q_refs, scale)
+    k_blk = _parts(k_refs)
     v_blk = v_ref[0].astype(jnp.float32)
     for rows, cols in plan:
-        s = _dot_nt(q_blk[rows], k_blk[cols])
+        s = _sum_parts(
+            _dot_nt, [q[rows] for q in q_blk], [k[cols] for k in k_blk]
+        )
         if mask is not None:
             s = mask(s, rows, cols)
         m_prev = m_scr[rows, :1]
@@ -899,7 +977,7 @@ def _fwd_tiles(
 
 
 def _fwd_body(
-    i, j, first, last, run, q_ref, k_ref, v_ref, o_ref, lse_ref,
+    i, j, first, last, run, q_refs, k_refs, v_ref, o_ref, lse_ref,
     m_scr, l_scr, acc,
     *, scale: float, causal: bool, bq: int, bk: int,
     kv_len: int | None, packed: bool, compact: bool = False,
@@ -914,7 +992,7 @@ def _fwd_body(
     _step_tiles(
         i, j, run,
         functools.partial(
-            _fwd_tiles, q_ref, k_ref, v_ref, m_scr, l_scr, acc, scale=scale
+            _fwd_tiles, q_refs, k_refs, v_ref, m_scr, l_scr, acc, scale=scale
         ),
         causal=causal, bq=bq, bk=bk, kv_len=kv_len, compact=compact,
         window=window, nq=nq,
@@ -958,36 +1036,44 @@ def _band_last(j, window, nq: int, bq: int):
     return jnp.minimum(j + _band_reach(window, nq, bq), nq - 1)
 
 
-def _fwd_kernel(
-    q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc, **kw
-):
+def _qk_refs(refs, n_in: int, rope: bool):
+    """A kernel's refs with q's and k's parts gathered: `refs` are its
+    `n_in` inputs (q, k, ... ), then with `rope` the pair (q_rope,
+    k_rope), then outputs and scratch -> (q_refs, k_refs, the inputs
+    after k, outputs and scratch)."""
+    (q_ref, k_ref, *ins), rest = refs[:n_in], refs[n_in:]
+    if not rope:
+        return (q_ref,), (k_ref,), ins, rest
+    (qr_ref, kr_ref), rest = rest[:2], rest[2:]
+    return (q_ref, qr_ref), (k_ref, kr_ref), ins, rest
+
+
+def _fwd_kernel(*refs, rope: bool = False, **kw):
     """Rectangular grid: (bh, nq, nk), k innermost; causal blocks above
     the diagonal are predicated off (they still cost a grid step — the
     compact kernel below is the one that doesn't pay them)."""
+    q_refs, k_refs, (v_ref,), rest = _qk_refs(refs, 3, rope)
     i = pl.program_id(1)
     j = pl.program_id(2)
     nk = pl.num_programs(2)
     _fwd_body(
         i, j, j == 0, j == nk - 1, _rect_run(i, j, kw),
-        q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc, **kw
+        q_refs, k_refs, v_ref, *rest, **kw
     )
 
 
-def _fwd_kernel_compact(
-    rows_ref, cols_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-    m_scr, l_scr, acc, **kw
-):
+def _fwd_kernel_compact(rows_ref, cols_ref, *refs, rope: bool = False, **kw):
     """Compact causal grid: (bh, T) over lower-triangular block pairs;
     the scalar-prefetched tables recover (i, j). Every enumerated block
     runs — skipped blocks simply don't exist in the grid."""
+    q_refs, k_refs, (v_ref,), rest = _qk_refs(refs, 3, rope)
     t = pl.program_id(1)
     i = rows_ref[t]
     j = cols_ref[t]
     first = _band_first(i, kw["window"], kw["nq"], kw["bq"])
     _fwd_body(
         i, j, j == first, j == i, True,
-        q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc,
-        compact=True, **kw,
+        q_refs, k_refs, v_ref, *rest, compact=True, **kw,
     )
 
 
@@ -1005,7 +1091,7 @@ def _delta_kernel(o_ref, do_ref, delta_ref, *, packed: bool):
 
 
 def _bwd_tiles(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, plan, mask, guard,
+    q_refs, k_refs, v_ref, do_ref, lse_ref, delta_ref, plan, mask, guard,
     scale, packed, dk_acc=None, dv_acc=None, dq=None,
 ):
     """The (s, p, ds) recurrence, computed once a tile of `plan` and fed
@@ -1013,16 +1099,19 @@ def _bwd_tiles(
     dSᵀ·(scale·q) on the tile's key rows of `dv_acc` / `dk_acc`, and
     `dq(rows, dS·K)`. q is loaded pre-scaled, so dK carries the
     1/sqrt(d) factor and dq takes it once more where the kernel writes
-    it out."""
-    q_blk = q_ref[0].astype(jnp.float32) * scale
-    k_blk = k_ref[0].astype(jnp.float32)
+    it out. `dk_acc` is a tuple, an accumulator a part of k, and `dq`
+    gets a tuple, a product a part."""
+    q_blk = _parts(q_refs, scale)
+    k_blk = _parts(k_refs)
     v_blk = v_ref[0].astype(jnp.float32)
     do_blk = do_ref[0].astype(jnp.float32)
     lse_blk = _read_rows(lse_ref[0], packed)
     delta_blk = _read_rows(delta_ref[0], packed)
     for rows, cols in plan:
-        q, k, do = q_blk[rows], k_blk[cols], do_blk[rows]
-        s = _dot_nt(q, k)
+        q = [blk[rows] for blk in q_blk]
+        k = [blk[cols] for blk in k_blk]
+        do = do_blk[rows]
+        s = _sum_parts(_dot_nt, q, k)
         if mask is not None:
             s = mask(s, rows, cols)
         # A masked score is -inf and lse is finite wherever a row has a
@@ -1035,29 +1124,36 @@ def _bwd_tiles(
             dv_acc[cols, :] = dv_acc[cols, :] + _dot_tn(p, do)
         ds = p * (_dot_nt(do, v_blk[cols]) - delta_blk[rows])
         if dk_acc is not None:
-            dk_acc[cols, :] = dk_acc[cols, :] + _dot_tn(ds, q)
+            for acc, part in zip(dk_acc, q):
+                acc[cols, :] = acc[cols, :] + _dot_tn(ds, part)
         if dq is not None:
-            dq(rows, _dot_nn(ds, k))
+            dq(rows, tuple(_dot_nn(ds, part) for part in k))
+
+
+def _halves(refs):
+    """Outputs then scratch, a ref a part each: (outputs, scratch)."""
+    return refs[: len(refs) // 2], refs[len(refs) // 2:]
 
 
 def _dq_body(
-    i, j, first, last, run, q_ref, k_ref, v_ref, do_ref, lse_ref,
-    delta_ref, dq_ref, dq_acc,
+    i, j, first, last, run, q_refs, k_refs, ins, dq_refs, dq_accs,
     *, scale: float, causal: bool, bq: int, bk: int,
     kv_len: int | None, packed: bool, compact: bool = False,
     window: int | None = None, nq: int = 0,
 ):
     @pl.when(first)
     def _init():
-        dq_acc[:] = jnp.zeros_like(dq_acc)
+        for dq_acc in dq_accs:
+            dq_acc[:] = jnp.zeros_like(dq_acc)
 
     def dq(rows, dq_rows):
-        dq_acc[rows, :] = dq_acc[rows, :] + dq_rows
+        for dq_acc, part in zip(dq_accs, dq_rows):
+            dq_acc[rows, :] = dq_acc[rows, :] + part
 
     _step_tiles(
         i, j, run,
         functools.partial(
-            _bwd_tiles, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+            _bwd_tiles, q_refs, k_refs, *ins,
             scale=scale, packed=packed, dq=dq,
         ),
         causal=causal, bq=bq, bk=bk, kv_len=kv_len, compact=compact,
@@ -1066,54 +1162,60 @@ def _dq_body(
 
     @pl.when(last)
     def _finalize():
-        dq_ref[0] = (dq_acc[:] * scale).astype(dq_ref.dtype)
+        for dq_ref, dq_acc in zip(dq_refs, dq_accs):
+            dq_ref[0] = (dq_acc[:] * scale).astype(dq_ref.dtype)
 
 
-def _dq_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc, **kw
-):
+def _dq_kernel(*refs, rope: bool = False, **kw):
+    """Inputs q, k, v, dO, lse, delta (and the rope pair); a dq output
+    and an accumulator a part."""
+    q_refs, k_refs, ins, rest = _qk_refs(refs, 6, rope)
     i = pl.program_id(1)
     j = pl.program_id(2)
     nk = pl.num_programs(2)
     _dq_body(
         i, j, j == 0, j == nk - 1, _rect_run(i, j, kw),
-        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc,
-        **kw,
+        q_refs, k_refs, ins, *_halves(rest), **kw,
     )
 
 
-def _dq_kernel_compact(
-    rows_ref, cols_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-    dq_ref, dq_acc, **kw
-):
+def _dq_kernel_compact(rows_ref, cols_ref, *refs, rope: bool = False, **kw):
+    q_refs, k_refs, ins, rest = _qk_refs(refs, 6, rope)
     t = pl.program_id(1)
     i = rows_ref[t]
     j = cols_ref[t]
     first = _band_first(i, kw["window"], kw["nq"], kw["bq"])
     _dq_body(
         i, j, j == first, j == i, True,
-        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc,
-        compact=True, **kw,
+        q_refs, k_refs, ins, *_halves(rest), compact=True, **kw,
     )
 
 
+def _dkv_refs(refs):
+    """(dk, dv[, dk_rope]) outputs then as many accumulators ->
+    (dk outputs a part, dv output, dk accumulators a part, dv's)."""
+    outs, accs = _halves(refs)
+    return (outs[0], *outs[2:]), outs[1], (accs[0], *accs[2:]), accs[1]
+
+
 def _dkv_body(
-    i, j, first, last, run, q_ref, k_ref, v_ref, do_ref, lse_ref,
-    delta_ref, dk_ref, dv_ref, dk_acc, dv_acc,
+    i, j, first, last, run, q_refs, k_refs, ins, dk_refs, dv_ref, dk_accs,
+    dv_acc,
     *, scale: float, causal: bool, bq: int, bk: int,
     kv_len: int | None, packed: bool, compact: bool = False,
     window: int | None = None, nq: int = 0,
 ):
     @pl.when(first)
     def _init():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
+        for dk_acc in dk_accs:
+            dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
     _step_tiles(
         i, j, run,
         functools.partial(
-            _bwd_tiles, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-            scale=scale, packed=packed, dk_acc=dk_acc, dv_acc=dv_acc,
+            _bwd_tiles, q_refs, k_refs, *ins,
+            scale=scale, packed=packed, dk_acc=dk_accs, dv_acc=dv_acc,
         ),
         causal=causal, bq=bq, bk=bk, kv_len=kv_len, compact=compact,
         window=window, nq=nq,
@@ -1123,47 +1225,42 @@ def _dkv_body(
     def _finalize():
         # dK = Σ dSᵀ·(scale·q); q was loaded pre-scaled, so the accumulator
         # already carries the 1/sqrt(d) factor. dV is scale-free.
-        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+        for dk_ref, dk_acc in zip(dk_refs, dk_accs):
+            dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _dkv_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-    dk_acc, dv_acc, **kw
-):
+def _dkv_kernel(*refs, rope: bool = False, **kw):
+    """Inputs as `_dq_kernel`'s; outputs dk, dv (and dk_rope), then as
+    many accumulators."""
+    q_refs, k_refs, ins, rest = _qk_refs(refs, 6, rope)
     j = pl.program_id(1)  # k block (outer)
     i = pl.program_id(2)  # q block (inner)
     nq = pl.num_programs(2)
     _dkv_body(
         i, j, i == 0, i == nq - 1, _rect_run(i, j, kw),
-        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-        dk_acc, dv_acc, **kw,
+        q_refs, k_refs, ins, *_dkv_refs(rest), **kw,
     )
 
 
-def _dkv_kernel_compact(
-    rows_ref, cols_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-    dk_ref, dv_ref, dk_acc, dv_acc, **kw
-):
+def _dkv_kernel_compact(rows_ref, cols_ref, *refs, rope: bool = False, **kw):
     """Column-major compact traversal: for each k block j, q blocks
     i = j..nq-1 are contiguous, so dk/dv accumulate across exactly the
     blocks that exist below the diagonal."""
+    q_refs, k_refs, ins, rest = _qk_refs(refs, 6, rope)
     t = pl.program_id(1)
     i = rows_ref[t]
     j = cols_ref[t]
     last = _band_last(j, kw["window"], kw["nq"], kw["bq"])
     _dkv_body(
         i, j, i == j, i == last, True,
-        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-        dk_acc, dv_acc, compact=True, **kw,
+        q_refs, k_refs, ins, *_dkv_refs(rest), compact=True, **kw,
     )
 
 
 def _dqkv_kernel_fused(
     rows_ref, cols_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-    dq_ref, dk_ref, dv_ref, dq_ring, dk_acc, dv_acc,
-    *, scale: float, causal: bool, bq: int, bk: int,
-    kv_len: int | None, packed: bool, nq: int, window: int | None = None,
+    dq_ref, dk_ref, dv_ref, dq_ring, dk_acc, dv_acc, **kw,
 ):
     """Fused one-pass backward over the compact causal grid, column-major
     (for each kv block j, q blocks i = j..nq-1 are contiguous; under a
@@ -1189,7 +1286,40 @@ def _dqkv_kernel_fused(
     into the slot row j - 1 left when column j - 1 flushed it. A row's
     first column is where the band ends, and not every band of its rows
     has work there (`_window_plan`), so the slot is zeroed as the row
-    enters and every contribution accumulates."""
+    enters and every contribution accumulates.
+
+    The body is `_dqkv_fused_body`, over q, k, dq, dk, the ring and dk's
+    accumulator as tuples of one part; `_dqkv_kernel_fused_mla` hands it
+    two."""
+    _dqkv_fused_body(
+        rows_ref, cols_ref, (q_ref,), (k_ref,),
+        (v_ref, do_ref, lse_ref, delta_ref), (dq_ref,), (dk_ref,), dv_ref,
+        (dq_ring,), (dk_acc,), dv_acc, **kw,
+    )
+
+
+def _dqkv_kernel_fused_mla(
+    rows_ref, cols_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+    qr_ref, kr_ref, dq_ref, dk_ref, dv_ref, dqr_ref, dkr_ref,
+    dq_ring, dk_acc, dv_acc, dqr_ring, dkr_acc, **kw,
+):
+    """`_dqkv_kernel_fused` with two-part scores: the rope pair last of
+    the inputs, its two gradients last of the outputs, its dq ring and dk
+    accumulator last of the scratch."""
+    _dqkv_fused_body(
+        rows_ref, cols_ref, (q_ref, qr_ref), (k_ref, kr_ref),
+        (v_ref, do_ref, lse_ref, delta_ref), (dq_ref, dqr_ref),
+        (dk_ref, dkr_ref), dv_ref, (dq_ring, dqr_ring), (dk_acc, dkr_acc),
+        dv_acc, **kw,
+    )
+
+
+def _dqkv_fused_body(
+    rows_ref, cols_ref, q_refs, k_refs, ins, dq_refs, dk_refs, dv_ref,
+    dq_rings, dk_accs, dv_acc,
+    *, scale: float, causal: bool, bq: int, bk: int,
+    kv_len: int | None, packed: bool, nq: int, window: int | None = None,
+):
     t = pl.program_id(1)
     i = rows_ref[t]
     j = cols_ref[t]
@@ -1200,7 +1330,8 @@ def _dqkv_kernel_fused(
 
     @pl.when(first)
     def _init():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
+        for dk_acc in dk_accs:
+            dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
     def dq(rows, dq_rows):
@@ -1210,31 +1341,35 @@ def _dqkv_kernel_fused(
         start = rows.start or 0
         slot = pl.ds(slot_of(i) * bq + start, (rows.stop or bq) - start)
         if window is not None:
-            dq_ring[slot, :] = dq_ring[slot, :] + dq_rows
+            for dq_ring, part in zip(dq_rings, dq_rows):
+                dq_ring[slot, :] = dq_ring[slot, :] + part
             return
 
         @pl.when(j == 0)
         def _seed():
             # Column 0 is every row's first contribution — a store, not
             # an accumulate, so the ring never needs a zeroing pass.
-            dq_ring[slot, :] = dq_rows
+            for dq_ring, part in zip(dq_rings, dq_rows):
+                dq_ring[slot, :] = part
 
         @pl.when(j > 0)
         def _accum():
-            dq_ring[slot, :] = dq_ring[slot, :] + dq_rows
+            for dq_ring, part in zip(dq_rings, dq_rows):
+                dq_ring[slot, :] = dq_ring[slot, :] + part
 
     if window is not None:
         @pl.when(j == _band_first(i, window, nq, bq))
         def _enter():
-            dq_ring[pl.ds(slot_of(i) * bq, bq), :] = jnp.zeros(
-                (bq, dq_ring.shape[1]), dq_ring.dtype
-            )
+            for dq_ring in dq_rings:
+                dq_ring[pl.ds(slot_of(i) * bq, bq), :] = jnp.zeros(
+                    (bq, dq_ring.shape[1]), dq_ring.dtype
+                )
 
     _step_tiles(
         i, j, True,
         functools.partial(
-            _bwd_tiles, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-            scale=scale, packed=packed, dk_acc=dk_acc, dv_acc=dv_acc, dq=dq,
+            _bwd_tiles, q_refs, k_refs, *ins,
+            scale=scale, packed=packed, dk_acc=dk_accs, dv_acc=dv_acc, dq=dq,
         ),
         causal=causal, bq=bq, bk=bk, kv_len=kv_len, compact=True,
         window=window, nq=nq,
@@ -1242,13 +1377,15 @@ def _dqkv_kernel_fused(
 
     @pl.when(last)
     def _flush():
-        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+        for dk_ref, dk_acc in zip(dk_refs, dk_accs):
+            dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
         # Row j retired at this column's diagonal step; its completed
         # slot flushes into the column-indexed dq output block.
-        dq_ref[0] = (
-            dq_ring[pl.ds(slot_of(j) * bq, bq), :] * scale
-        ).astype(dq_ref.dtype)
+        for dq_ref, dq_ring in zip(dq_refs, dq_rings):
+            dq_ref[0] = (
+                dq_ring[pl.ds(slot_of(j) * bq, bq), :] * scale
+            ).astype(dq_ref.dtype)
 
 
 # -- clamped index maps (rectangular fallback only) --------------------------
@@ -1318,8 +1455,29 @@ def _clamp_i(i, j, bq: int, bk: int, causal: bool):
 def _head_layout(d: int) -> str:
     """The layout `flash_attention` hands the kernels, from the head size
     alone: a head is a legal column block of [B, S, H·d] when d is a
-    whole number of lanes."""
+    whole number of lanes. Any other d folds the heads into the batch by
+    transposes; a head of 192 = 128 + 64 (latent attention) would, eight
+    a call, and is not handed over as one operand but as two parts, 128
+    a head beside a rope operand of 64 (`ROPE_LAYOUT`)."""
     return "seq_major" if d % _LANES == 0 else "head_major"
+
+
+# How the 64-wide rope part of q reaches the kernels where the 128-wide
+# operands are read seq_major. A head's 64 lanes are no legal column
+# block of [B, S, H·64], so the operand is folded head-major, [B·H, S, 64]:
+# one transpose in, one out for dq_rope. Padding it to 128 lanes a head
+# ([B, S, H·128], the upper 64 zeros, dq sliced back) was built and timed
+# beside it; two heads a 128-lane column block was not built: the two
+# heads' grid rows would share one dq_rope output block, which a row
+# writes whole. VMEM and the MXU see 128 lanes either way (a 64-lane
+# contraction fills half the array). On the v5e at (B, S, H) = (1, 8192,
+# 32), 128 + 64 over 128, bf16, the kernels with the transposes or the
+# pad round them, ms a call forward / forward + backward (my chip run,
+# PR 39; min of 3 rounds of 20): head-major 6.73 / 20.78, padded 6.84 /
+# 20.92; the one-part call at d = 128 beside them 5.07 / 14.09 (a third
+# product forward and three more backward, each on a half-filled array:
+# x 1.33 and x 1.56).
+ROPE_LAYOUT = "head_major"
 
 
 def _kv_row(group: int):
@@ -1408,16 +1566,43 @@ def _sum_groups(dk, like, d: int):
 # -- pallas_call wrappers ----------------------------------------------------
 
 
-def _kernel_name(base: str, grid: str, window: int | None) -> str:
+def _kernel_name(
+    base: str, grid: str, window: int | None, rope: bool = False
+) -> str:
     """A `pallas_call`'s name: what a jaxpr and a device trace tell the
     schedules apart by. The causal calls keep `<base>_<grid>`; a window's
     say so (`flash_fwd_window`, `flash_bwd_window_fused`,
     `flash_dq_window`, `flash_dkv_window`, and `..._window_rect` off the
     compact grid), so a trace tells a model's window layers' calls from
-    its global layers'."""
-    if window is None:
+    its global layers'; the calls with two-part scores are marked `mla`
+    the same way (`flash_fwd_mla`, `flash_bwd_mla_fused`, `flash_dq_mla`,
+    `flash_dkv_mla`, `..._mla_rect`)."""
+    if window is None and not rope:
         return f"{base}_{grid}"
-    return f"{base}_window" + ("" if grid == "compact" else f"_{grid}")
+    mark = "mla" if rope else "window"
+    return f"{base}_{mark}" + ("" if grid == "compact" else f"_{grid}")
+
+
+def _checked_rope(rope_dim: int, window: int | None) -> None:
+    """Two-part scores under a window were never built: refused with
+    their numbers."""
+    if rope_dim and window is not None:
+        raise ValueError(
+            f"flash attention: a rope part of {rope_dim} dims under a "
+            f"window of {window} keys: two-part scores run the causal "
+            "triangle only"
+        )
+
+
+def _rope_specs(rope, rows: int):
+    """(q_rope's spec builder, k_rope's, the block's lanes) for the pair
+    `rope` = (q_rope [B·H, S, r], k_rope [B, S, r]) of a call of `rows`
+    grid rows: `_specs` with one K/V head that every query head of a
+    batch row reads."""
+    _, k_rope = rope
+    r = k_rope.shape[2]
+    q_spec, k_spec, _ = _specs(1, 1, rows // k_rope.shape[0], r)
+    return q_spec, k_spec, r
 
 
 def _checked_window(window, causal: bool, seq_k: int) -> int | None:
@@ -1443,20 +1628,23 @@ _T_COL = lambda t, rows, cols: cols[t]  # ... and its k block
     jax.jit,
     static_argnames=(
         "causal", "block_q", "block_k", "interpret", "kv_len", "packed",
-        "heads", "window",
+        "heads", "window", "scale",
     ),
 )
 def _flash_fwd_impl(
     q, k, v, causal, block_q, block_k, interpret, kv_len=None, packed=False,
-    heads=1, window=None,
+    heads=1, window=None, rope=None, scale=None,
 ):
     """q [Bq, S, heads·d] over k, v [Bk, S, kv_heads·d] (`_specs`) ->
-    (o in q's layout, lse [Bq·heads, ...] in the kernel lse layout)."""
+    (o in q's layout, lse [Bq·heads, ...] in the kernel lse layout).
+    `rope` = (q_rope, k_rope) adds the second part of the scores
+    (`_rope_specs`); `scale` is the scores' factor (None: d^-1/2)."""
     d, bh, kv_heads, group = _head_counts(q, k, heads)
     sq, sk = q.shape[1], k.shape[1]
     bq = _pick_block(block_q, sq)
     bk = _pick_block(block_k, sk)
-    scale = 1.0 / math.sqrt(d)
+    if scale is None:
+        scale = 1.0 / math.sqrt(d)
     steps, _, compact = _grid_steps(causal, sq, sk, bq, bk, window)
     nq = sq // bq
     q_spec, kv_spec, stat_spec = _specs(heads, kv_heads, group, d)
@@ -1464,6 +1652,15 @@ def _flash_fwd_impl(
         scale=scale, causal=causal, bq=bq, bk=bk, kv_len=kv_len,
         packed=packed, window=window, nq=nq,
     )
+    rope_in = lambda qidx, kidx: []  # the rope pair's specs, last of the inputs
+    matmul_dims = 2 * d  # contracted by QK^T, produced by PV, a pair
+    two_part = rope is not None
+    if two_part:
+        kernel_kw["rope"] = True
+        rq_spec, rk_spec, r = _rope_specs(rope, bh)
+        rope_in = lambda qidx, kidx: [rq_spec(bq, qidx), rk_spec(bk, kidx)]
+        matmul_dims += r
+    rope = rope or ()
     out_shape = [
         jax.ShapeDtypeStruct(q.shape, q.dtype),
         jax.ShapeDtypeStruct(_lse_layout_shape(bh, sq, packed), jnp.float32),
@@ -1474,8 +1671,9 @@ def _flash_fwd_impl(
         pltpu.VMEM((bq, d), jnp.float32),
     ]
     cost = pl.CostEstimate(
-        flops=4 * bh * steps * bq * bk * d,
-        bytes_accessed=(q.size + 2 * k.size) * q.dtype.itemsize,
+        flops=2 * matmul_dims * bh * steps * bq * bk,
+        bytes_accessed=(q.size + 2 * k.size + sum(x.size for x in rope))
+        * q.dtype.itemsize,
         transcendentals=bh * steps * bq * bk,
     )
     if compact:
@@ -1484,7 +1682,8 @@ def _flash_fwd_impl(
             num_scalar_prefetch=2,
             grid=(bh, steps),
             in_specs=[
-                q_spec(bq, _T_ROW), kv_spec(bk, _T_COL), kv_spec(bk, _T_COL)
+                q_spec(bq, _T_ROW), kv_spec(bk, _T_COL), kv_spec(bk, _T_COL),
+                *rope_in(_T_ROW, _T_COL),
             ],
             out_specs=[
                 q_spec(bq, _T_ROW),
@@ -1498,15 +1697,16 @@ def _flash_fwd_impl(
             out_shape=out_shape,
             cost_estimate=cost,
             interpret=interpret,
-            name=_kernel_name("flash_fwd", "compact", window),
-        )(rows, cols, q, k, v)
+            name=_kernel_name("flash_fwd", "compact", window, two_part),
+        )(rows, cols, q, k, v, *rope)
     row_i = lambda i, j: i
     clamped_j = lambda i, j: _clamp_j(i, j, bq, bk, causal)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, **kernel_kw),
         grid=(bh, nq, sk // bk),
         in_specs=[
-            q_spec(bq, row_i), kv_spec(bk, clamped_j), kv_spec(bk, clamped_j)
+            q_spec(bq, row_i), kv_spec(bk, clamped_j), kv_spec(bk, clamped_j),
+            *rope_in(row_i, clamped_j),
         ],
         out_specs=[
             q_spec(bq, row_i), stat_spec(_lse_block(bq, packed), row_i)
@@ -1515,8 +1715,8 @@ def _flash_fwd_impl(
         scratch_shapes=scratch,
         cost_estimate=cost,
         interpret=interpret,
-        name=_kernel_name("flash_fwd", "rect", window),
-    )(q, k, v)
+        name=_kernel_name("flash_fwd", "rect", window, two_part),
+    )(q, k, v, *rope)
 
 
 @functools.partial(
@@ -1547,12 +1747,13 @@ def _flash_delta_impl(o, do, block_q, interpret, packed, heads=1):
     jax.jit,
     static_argnames=(
         "causal", "block_q", "block_k", "interpret", "kv_len", "packed",
-        "fused", "heads", "window",
+        "fused", "heads", "window", "scale",
     ),
 )
 def _flash_bwd_kernels(
     q, k, v, do, lse, delta, causal, block_q, block_k, interpret,
     kv_len=None, packed=False, fused=None, heads=1, window=None,
+    rope=None, scale=None,
 ):
     """Backward kernels over a precomputed (lse, delta) pair (both in
     the kernel lse layout): the fused one-pass dq/dkv kernel when
@@ -1562,19 +1763,23 @@ def _flash_bwd_kernels(
     pin a path (True on a non-compactable or over-budget shape is an
     error — the fused kernel only exists on the compact grid). q, do and
     the returned dq are [Bq, S, heads·d]; k, v and the returned dk, dv
-    [Bk, S, kv_heads·d] (`_specs`)."""
+    [Bk, S, kv_heads·d] (`_specs`). With `rope` = (q_rope, k_rope) a
+    fourth result, (dq_rope in q_rope's layout, dk_rope summed over the
+    heads that share the key)."""
     d, bh, kv_heads, group = _head_counts(q, k, heads)
     sq, sk = q.shape[1], k.shape[1]
     bq = _pick_block(block_q, sq)
     bk = _pick_block(block_k, sk)
-    scale = 1.0 / math.sqrt(d)
+    if scale is None:
+        scale = 1.0 / math.sqrt(d)
     steps, _, compact = _grid_steps(causal, sq, sk, bq, bk, window)
     nq, nk = sq // bq, sk // bk
     reach = _band_reach(window, nq, bq)
     ring_rows = _ring_rows(sq, bq, window)
+    r = 0 if rope is None else rope[1].shape[2]
     if fused is None:
         fused = _bwd_fused(
-            causal, sq, sk, bq, bk, d, q.dtype.itemsize, packed, window
+            causal, sq, sk, bq, bk, d, q.dtype.itemsize, packed, window, r
         )
     elif fused:
         if not _compactable(causal, sq, sk, bq, bk, window):
@@ -1584,7 +1789,7 @@ def _flash_bwd_kernels(
                 f"causal={causal} sq={sq} sk={sk} bq={bq} bk={bk}"
             )
         vmem = _fused_vmem_bytes(
-            ring_rows, bq, bk, d, q.dtype.itemsize, packed
+            ring_rows, bq, bk, d, q.dtype.itemsize, packed, r
         )
         if vmem > _FUSED_VMEM_BUDGET:
             raise ValueError(
@@ -1606,9 +1811,33 @@ def _flash_bwd_kernels(
         jax.ShapeDtypeStruct(q.shape[:1] + (sk, q.shape[2]), x.dtype)
         for x in (k, v)
     ]
-    grouped = lambda dq, dk, dv: (
-        dq, _sum_groups(dk, k, d), _sum_groups(dv, v, d)
-    )
+    # The rope part: its specs ride the indices q's and k's do, its dq and
+    # dk leave as q_rope's shape (one dk_rope partial a query head, summed
+    # after the call like a K/V group's), each with a scratch of its own.
+    rope_specs = lambda qidx, kidx: []
+    rope_out = lambda blk, idx: []
+    rope_shape, rope_scratch = lambda s_: [], lambda rows: []
+    two_part = rope is not None
+    if two_part:
+        rq_spec, rk_spec, _ = _rope_specs(rope, bh)
+        rope_specs = lambda qidx, kidx: [rq_spec(bq, qidx), rk_spec(bk, kidx)]
+        rope_out = lambda blk, idx: [rq_spec(blk, idx)]
+        rope_shape = lambda s_: [jax.ShapeDtypeStruct(
+            (rope[0].shape[0], s_, rope[0].shape[2]), rope[0].dtype
+        )]
+        rope_scratch = lambda rows: [pltpu.VMEM((rows, r), jnp.float32)]
+
+    def grouped(dq, dk, dv, dq_rope=None, dk_rope=None):
+        dk, dv = _sum_groups(dk, k, d), _sum_groups(dv, v, d)
+        if not two_part:
+            return dq, dk, dv
+        return dq, dk, dv, (dq_rope, _sum_groups(dk_rope, rope[1], r))
+
+    rope = rope or ()
+    # The two-pass kernels gather q's and k's parts themselves (`_qk_refs`);
+    # the fused one has an entry a form, its streams pinned by name.
+    parts_kw = dict(kw, rope=two_part)
+    fused_kernel = _dqkv_kernel_fused_mla if two_part else _dqkv_kernel_fused
 
     def _in_specs(qidx, kidx):
         # q/do/lse/delta ride the q-block index, k/v the k-block index
@@ -1617,6 +1846,7 @@ def _flash_bwd_kernels(
         return [
             q_spec(bq, qidx), kv_spec(bk, kidx), kv_spec(bk, kidx),
             q_spec(bq, qidx), stat_spec(stat, qidx), stat_spec(stat, qidx),
+            *rope_specs(qidx, kidx),
         ]
 
     if fused:
@@ -1627,33 +1857,37 @@ def _flash_bwd_kernels(
         # pays 7) and the modeled one-pass HBM bytes.
         rows_c, cols_c = _tri_tables(nq, "col", reach)
         cost = pl.CostEstimate(
-            flops=10 * bh * steps * bq * bk * d,
+            flops=(10 * d + 6 * r) * bh * steps * bq * bk,
             bytes_accessed=bh * (
                 _bwd_hbm_bytes(
                     causal, sq, sk, bq, bk, d, q.dtype.itemsize, packed,
-                    True, window,
+                    True, window, r,
                 )
                 - 2 * sq * d * q.dtype.itemsize  # delta precompute's share
                 - _lse_bytes_of(sq, packed)
             ),
             transcendentals=bh * steps * bq * bk,
         )
-        dq, dk, dv = pl.pallas_call(
-            functools.partial(_dqkv_kernel_fused, **kw),
+        grads = pl.pallas_call(
+            functools.partial(fused_kernel, **kw),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2,
                 grid=(bh, steps),
                 in_specs=_in_specs(_T_ROW, _T_COL),
                 out_specs=[
-                    q_spec(bq, _T_COL), q_spec(bk, _T_COL), q_spec(bk, _T_COL)
+                    q_spec(bq, _T_COL), q_spec(bk, _T_COL), q_spec(bk, _T_COL),
+                    *rope_out(bq, _T_COL), *rope_out(bk, _T_COL),
                 ],
                 scratch_shapes=[
                     pltpu.VMEM((ring_rows, d), jnp.float32),  # dq ring
                     pltpu.VMEM((bk, d), jnp.float32),
                     pltpu.VMEM((bk, d), jnp.float32),
+                    *rope_scratch(ring_rows), *rope_scratch(bk),
                 ],
             ),
-            out_shape=[dq_shape, *dkv_shape],
+            out_shape=[
+                dq_shape, *dkv_shape, *rope_shape(sq), *rope_shape(sk)
+            ],
             cost_estimate=cost,
             # The one kernel whose footprint grows with S: past the
             # compiler's 16 MiB default from S=8k on, so it names the
@@ -1662,83 +1896,93 @@ def _flash_bwd_kernels(
                 vmem_limit_bytes=_FUSED_VMEM_BUDGET
             ),
             interpret=interpret,
-            name=_kernel_name("flash_bwd", "fused", window),
-        )(rows_c, cols_c, q, k, v, do, lse, delta)
-        return grouped(dq, dk, dv)
+            name=_kernel_name("flash_bwd", "fused", window, two_part),
+        )(rows_c, cols_c, q, k, v, do, lse, delta, *rope)
+        return grouped(*grads)
 
     if compact:
         rows, cols = _tri_tables(nq, "row", reach)
-        dq = pl.pallas_call(
-            functools.partial(_dq_kernel_compact, **kw),
+        dq, *dq_rope = pl.pallas_call(
+            functools.partial(_dq_kernel_compact, **parts_kw),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2,
                 grid=(bh, steps),
                 in_specs=_in_specs(_T_ROW, _T_COL),
-                out_specs=q_spec(bq, _T_ROW),
-                scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+                out_specs=[q_spec(bq, _T_ROW), *rope_out(bq, _T_ROW)],
+                scratch_shapes=[
+                    pltpu.VMEM((bq, d), jnp.float32), *rope_scratch(bq)
+                ],
             ),
-            out_shape=dq_shape,
+            out_shape=[dq_shape, *rope_shape(sq)],
             interpret=interpret,
-            name=_kernel_name("flash_dq", "compact", window),
-        )(rows, cols, q, k, v, do, lse, delta)
+            name=_kernel_name("flash_dq", "compact", window, two_part),
+        )(rows, cols, q, k, v, do, lse, delta, *rope)
         rows_c, cols_c = _tri_tables(nq, "col", reach)
-        dk, dv = pl.pallas_call(
-            functools.partial(_dkv_kernel_compact, **kw),
+        dk, dv, *dk_rope = pl.pallas_call(
+            functools.partial(_dkv_kernel_compact, **parts_kw),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2,
                 grid=(bh, steps),
                 in_specs=_in_specs(_T_ROW, _T_COL),
-                out_specs=[q_spec(bk, _T_COL), q_spec(bk, _T_COL)],
+                out_specs=[
+                    q_spec(bk, _T_COL), q_spec(bk, _T_COL),
+                    *rope_out(bk, _T_COL),
+                ],
                 scratch_shapes=[
                     pltpu.VMEM((bk, d), jnp.float32),
                     pltpu.VMEM((bk, d), jnp.float32),
+                    *rope_scratch(bk),
                 ],
             ),
-            out_shape=dkv_shape,
+            out_shape=[*dkv_shape, *rope_shape(sk)],
             interpret=interpret,
-            name=_kernel_name("flash_dkv", "compact", window),
-        )(rows_c, cols_c, q, k, v, do, lse, delta)
-        return grouped(dq, dk, dv)
+            name=_kernel_name("flash_dkv", "compact", window, two_part),
+        )(rows_c, cols_c, q, k, v, do, lse, delta, *rope)
+        return grouped(dq, dk, dv, *dq_rope, *dk_rope)
 
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, **kw),
+    row_i, col_j = lambda i, j: i, lambda j, i: j
+    dq, *dq_rope = pl.pallas_call(
+        functools.partial(_dq_kernel, **parts_kw),
         grid=(bh, nq, nk),
         in_specs=_in_specs(
-            lambda i, j: i, lambda i, j: _clamp_j(i, j, bq, bk, causal)
+            row_i, lambda i, j: _clamp_j(i, j, bq, bk, causal)
         ),
-        out_specs=q_spec(bq, lambda i, j: i),
-        out_shape=dq_shape,
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+        out_specs=[q_spec(bq, row_i), *rope_out(bq, row_i)],
+        out_shape=[dq_shape, *rope_shape(sq)],
+        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32), *rope_scratch(bq)],
         interpret=interpret,
-        name=_kernel_name("flash_dq", "rect", window),
-    )(q, k, v, do, lse, delta)
+        name=_kernel_name("flash_dq", "rect", window, two_part),
+    )(q, k, v, do, lse, delta, *rope)
 
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, **kw),
+    dk, dv, *dk_rope = pl.pallas_call(
+        functools.partial(_dkv_kernel, **parts_kw),
         grid=(bh, nk, nq),
         in_specs=_in_specs(
-            lambda j, i: _clamp_i(i, j, bq, bk, causal), lambda j, i: j
+            lambda j, i: _clamp_i(i, j, bq, bk, causal), col_j
         ),
-        out_specs=[q_spec(bk, lambda j, i: j), q_spec(bk, lambda j, i: j)],
-        out_shape=dkv_shape,
+        out_specs=[
+            q_spec(bk, col_j), q_spec(bk, col_j), *rope_out(bk, col_j)
+        ],
+        out_shape=[*dkv_shape, *rope_shape(sk)],
         scratch_shapes=[
             pltpu.VMEM((bk, d), jnp.float32),
             pltpu.VMEM((bk, d), jnp.float32),
+            *rope_scratch(bk),
         ],
         interpret=interpret,
-        name=_kernel_name("flash_dkv", "rect", window),
-    )(q, k, v, do, lse, delta)
-    return grouped(dq, dk, dv)
+        name=_kernel_name("flash_dkv", "rect", window, two_part),
+    )(q, k, v, do, lse, delta, *rope)
+    return grouped(dq, dk, dv, *dq_rope, *dk_rope)
 
 
 def _flash_bwd_impl(
     q, k, v, o, lse, do, causal, block_q, block_k, interpret,
-    kv_len=None, packed=False, heads=1, window=None,
+    kv_len=None, packed=False, heads=1, window=None, rope=None, scale=None,
 ):
     delta = _flash_delta_impl(o, do, block_q, interpret, packed, heads)
     return _flash_bwd_kernels(
         q, k, v, do, lse, delta, causal, block_q, block_k, interpret,
-        kv_len, packed, None, heads, window,
+        kv_len, packed, None, heads, window, rope, scale,
     )
 
 
@@ -1749,27 +1993,33 @@ def _residual_packed(sq: int, block_q: int) -> bool:
     return _lse_is_packed(sq, _pick_block(block_q, sq))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+@functools.partial(
+    jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11)
+)
 def _flash_core(
-    q, k, v, causal, block_q, block_k, interpret, kv_len, heads, window=None
+    q, k, v, rope, causal, block_q, block_k, interpret, kv_len, heads,
+    window=None, scale=None,
 ):
     """q [Bq, S, heads·d], k, v [Bk, S, kv_heads·d] (`_specs`) ->
     (o, lse), o in q's layout and lse [Bq·heads, ...]. The lse output
     carries NO cotangent path (its incoming gradient is discarded in the
     VJP) — it exists so callers and `remat_policy="flash"` can hold the
-    softmax statistics."""
+    softmax statistics. `rope` is None, or (q_rope, k_rope) for two-part
+    scores (`_rope_specs`); `scale` the scores' factor (None: d^-1/2)."""
     return _flash_vjp_fwd(
-        q, k, v, causal, block_q, block_k, interpret, kv_len, heads, window
+        q, k, v, rope, causal, block_q, block_k, interpret, kv_len, heads,
+        window, scale,
     )[0]
 
 
 def _flash_vjp_fwd(
-    q, k, v, causal, block_q, block_k, interpret, kv_len, heads, window
+    q, k, v, rope, causal, block_q, block_k, interpret, kv_len, heads, window,
+    scale,
 ):
     packed = _residual_packed(q.shape[1], block_q)
     o, lse = _flash_fwd_impl(
         q, k, v, causal, block_q, block_k, interpret, kv_len, packed, heads,
-        window,
+        window, rope, scale,
     )
     # Residual slimming: in the packed layout the lse residual is already
     # exactly the information (1/128th the old lane-replicated buffer);
@@ -1782,22 +2032,42 @@ def _flash_vjp_fwd(
         lse = lse[:, :, :1]
     o = checkpoint_name(o, CHECKPOINT_OUT_NAME)
     lse = checkpoint_name(lse, CHECKPOINT_LSE_NAME)
-    return (o, lse), (q, k, v, o, lse)
+    return (o, lse), (q, k, v, rope, o, lse)
 
 
 def _flash_vjp_bwd(causal, block_q, block_k, interpret, kv_len, heads,
-                   window, residuals, cts):
-    q, k, v, o, lse = residuals
+                   window, scale, residuals, cts):
+    q, k, v, rope, o, lse = residuals
     do, _ = cts  # the lse output is statistics-only; its cotangent drops
     packed = _residual_packed(q.shape[1], block_q)
     lse_layout = _rows_to_layout(_lse_rows(lse, q.shape[1]), packed)
-    return _flash_bwd_impl(
+    dq, dk, dv, *drope = _flash_bwd_impl(
         q, k, v, o, lse_layout, do, causal, block_q, block_k, interpret,
-        kv_len, packed, heads, window,
+        kv_len, packed, heads, window, rope, scale,
     )
+    return dq, dk, dv, (drope[0] if drope else None)
 
 
 _flash_core.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
+
+
+def _checked_rope_pair(q, k, q_rope, k_rope, window):
+    """The rope pair of a two-part call, or a refusal with its numbers."""
+    b, sq, h, _ = q.shape
+    if (
+        q_rope is None or k_rope is None or k.shape[2] != h
+        or q_rope.shape[:3] != (b, sq, h)
+        or k_rope.shape != (b, k.shape[1], q_rope.shape[-1])
+    ):
+        raise ValueError(
+            "flash attention: two-part scores take q_rope [B, S, H, R] and "
+            "k_rope [B, S, R] (one rope key for all heads) beside q and k "
+            f"of equal heads; got q {q.shape}, k {k.shape}, q_rope "
+            f"{getattr(q_rope, 'shape', None)}, k_rope "
+            f"{getattr(k_rope, 'shape', None)}"
+        )
+    _checked_rope(q_rope.shape[-1], window)
+    return q_rope, k_rope
 
 
 def _fold_heads(x, layout: str):
@@ -1827,11 +2097,25 @@ def flash_attention(
     interpret: bool | None = None,
     return_lse: bool = False,
     window: int | None = None,
+    q_rope=None,
+    k_rope=None,
+    scale: float | None = None,
 ):
     """Blockwise attention on the MXU. q: [B, S, H, D]; k, v: [B, S, Hkv, D]
     with H a multiple of Hkv (query head h attends over kv head
     h // (H/Hkv); the kernels' index maps pick it, K and V are never
     repeated) → [B, S, H, D].
+
+    **Two-part scores** (latent attention). With ``q_rope`` [B, S, H, R]
+    and ``k_rope`` [B, S, R] a head's scores are ``q·kᵀ + q_rope·k_ropeᵀ``:
+    D dims a head (as wide as v) and R more against ONE rope key all H
+    heads share, which the kernels read once a K block and never repeated
+    a head; v and o stay D wide and nothing is padded on the value side.
+    Returns o; ``dk_rope`` is the sum over the heads. ``scale`` is the
+    scores' factor, (D + R)^-1/2 unless given. The 128-wide operands keep
+    their layout; the R-wide ``q_rope`` goes head-major (`ROPE_LAYOUT`).
+    The calls are named ``flash_*_mla*``. Needs equal heads (Hkv = H) and
+    no window.
 
     Numerically matches ``dense_attention`` (same online-softmax math) while
     never materializing the [S, S] score matrix in HBM — at S=8192 the
@@ -1883,23 +2167,34 @@ def flash_attention(
     if k.shape != v.shape or h % k.shape[2]:
         raise ValueError(
             f"flash attention: {h} query heads over k {k.shape} / v "
-            f"{v.shape}: k and v must agree and their heads divide q's"
+            f"{v.shape}: k and v must agree and their heads divide q's; a "
+            "q and k wider than v go as two parts, the part as wide as v "
+            "and a rope part (q_rope [B, S, H, R], k_rope [B, S, R])"
         )
     interp = _auto_interpret(interpret)
     window = _checked_window(window, causal, sk)
     sp_q = _pad_to_tileable(block_q, sq)
     sp_k = _pad_to_tileable(block_k, sk)
     kv_len = sk if sp_k != sk else None
+    rope = None
+    if q_rope is not None or k_rope is not None:
+        rope = _checked_rope_pair(q, k, q_rope, k_rope, window)
+        if scale is None:
+            scale = 1.0 / math.sqrt(d + q_rope.shape[-1])
     if sp_q != sq or sp_k != sk:
         pad = lambda x, s: jnp.pad(
-            x, ((0, 0), (0, s - x.shape[1]), (0, 0), (0, 0))
+            x, ((0, 0), (0, s - x.shape[1])) + ((0, 0),) * (x.ndim - 2)
         )
         q, k, v = pad(q, sp_q), pad(k, sp_k), pad(v, sp_k)
+        if rope:
+            rope = pad(rope[0], sp_q), pad(rope[1], sp_k)
     layout = _head_layout(d)
+    if rope:  # q_rope head-major, whatever q's layout (`ROPE_LAYOUT`)
+        rope = _fold_heads(rope[0], ROPE_LAYOUT), rope[1]
     o, lse = _flash_core(
         _fold_heads(q, layout), _fold_heads(k, layout),
-        _fold_heads(v, layout), causal, block_q, block_k, interp, kv_len,
-        h if layout == "seq_major" else 1, window,
+        _fold_heads(v, layout), rope, causal, block_q, block_k, interp,
+        kv_len, h if layout == "seq_major" else 1, window, scale,
     )
     o = _unfold_heads(o, b, h, layout)
     if sp_q != sq:

@@ -19,11 +19,13 @@ wide, the same for every head.
   partner by a lane rotation. Elementwise, so bound by HBM: x read once,
   y written once. Its VJP is the same kernel with the angle negated.
 
-`rope` picks: the kernel where kernels compile, D is whole lanes and the
-sequence tiles; `rope_folded` anywhere else (`ops/flash.kernels_compiled`,
-as `ops/attention.attend` does). A Pallas call does not partition itself
-under `jit`, so with a mesh the kernel runs in `shard_map` over the batch
-axes and, for the heads, `tp`.
+`rope` picks: the kernel where kernels compile, D is whole lanes (or
+divides a lane tile while H·D is whole tiles: latent attention's rope part
+of 64 a head, two heads a tile under tables tiled twice) and the sequence
+tiles; `rope_folded` anywhere else (`ops/flash.kernels_compiled`, as
+`ops/attention.attend` does), a head-less key [B, S, 64] among them. A
+Pallas call does not partition itself under `jit`, so with a mesh the
+kernel runs in `shard_map` over the batch axes and, for the heads, `tp`.
 """
 
 from __future__ import annotations
@@ -139,15 +141,20 @@ def rope_folded(x, cos, sin, half: int):
     return (x32 * wide(cos) + partner * wide(sin)).astype(x.dtype)
 
 
-def _turn_kernel(x_ref, cos_ref, sin_ref, o_ref, *, half: int, sign: float):
-    d = cos_ref.shape[-1]
+def _turn_kernel(
+    x_ref, cos_ref, sin_ref, o_ref, *, half: int, sign: float, d: int
+):
+    """A column of the tables' width at a time: one head of `d` lanes,
+    or, where `d` divides a lane tile, the heads of one tile (the tables
+    then hold a head's lanes that many times)."""
+    width = cos_ref.shape[-1]
     cos = cos_ref[0]
     sin = sin_ref[0] * sign
     lane = jax.lax.broadcasted_iota(jnp.int32, cos.shape, 1)
     roll = lambda u, shift: pltpu.roll(u, shift, 1)
-    for at in range(0, x_ref.shape[-1], d):
-        xh = x_ref[0, :, at:at + d].astype(jnp.float32)
-        o_ref[0, :, at:at + d] = (
+    for at in range(0, x_ref.shape[-1], width):
+        xh = x_ref[0, :, at:at + width].astype(jnp.float32)
+        o_ref[0, :, at:at + width] = (
             xh * cos + _partner(xh, half, d, lane, roll) * sin
         ).astype(o_ref.dtype)
 
@@ -157,11 +164,15 @@ def _turn_kernel(x_ref, cos_ref, sin_ref, o_ref, *, half: int, sign: float):
 )
 def _turn(x, cos, sin, half: int, sign: float, interpret: bool, name: str):
     b, s, width = x.shape
+    head = cos.shape[-1]
+    if head < flash._LANES:  # heads that share a lane tile
+        tile = lambda t: jnp.tile(t, flash._LANES // head)
+        cos, sin = tile(cos), tile(sin)
     d = cos.shape[-1]
     rows = flash._pick_block(_block_rows(width, x.dtype.itemsize), s)
     at = lambda i, j: (i, j, 0)
     return pl.pallas_call(
-        functools.partial(_turn_kernel, half=half, sign=sign),
+        functools.partial(_turn_kernel, half=half, sign=sign, d=head),
         grid=(b, s // rows),
         in_specs=[
             pl.BlockSpec((1, rows, width), at),
@@ -211,20 +222,23 @@ def rope(
     inv_freq=None,
     scale: float = 1.0,
 ):
-    """Rotary embeddings of x [B, S, H·D] (`head_dim` = D) at `positions`
-    [B, S]; with `fraction` < 1 only the first `fraction * D` lanes of a
-    head turn; `inv_freq` and `scale` as `rope_tables` takes them. The
-    kernel or the plain form, from the shapes and the backend (module
-    docstring); `interpret` forces the kernel, as `flash_attention`'s
-    does."""
+    """Rotary embeddings of x [B, S, H·D] (`head_dim` = D; H = 1 is a
+    head-less key [B, S, D]) at `positions` [B, S]; with `fraction` < 1
+    only the first `fraction * D` lanes of a head turn; `inv_freq` and
+    `scale` as `rope_tables` takes them. The kernel or the plain form,
+    from the shapes and the backend (module docstring); `interpret` forces
+    the kernel, as `flash_attention`'s does."""
     cos, sin = rope_tables(
         positions, theta, head_dim, fraction, inv_freq=inv_freq, scale=scale
     )
     half = int(head_dim * fraction) // 2
     b, s, width = x.shape
+    whole_lanes = head_dim % flash._LANES == 0 or (
+        flash._LANES % head_dim == 0 and width % flash._LANES == 0
+    )
     use_kernel = (
         (flash.kernels_compiled() if interpret is None else True)
-        and flash._head_layout(head_dim) == "seq_major"  # whole lanes
+        and whole_lanes
         and flash.flash_kernel_tileable(s, _BLOCK_ROWS)
     )
     heads = None

@@ -154,3 +154,76 @@ def test_attend_dispatch(devices, monkeypatch, case):
             e for e in _walk_eqns(jaxpr.jaxpr) if e.primitive.name == "ppermute"
         ]
         assert permutes
+
+
+# (mesh, window) -> the kernels traced with a rope part, or the refusal.
+ATTEND_ROPE_CASES = {
+    "no_mesh": (None, None, ["flash_fwd_mla"]),
+    "dp": ({"dp": 2}, None, ["flash_fwd_mla"]),
+    "tp": ({"tp": 2}, None, "on a mesh .*'tp': 2"),
+    "sp": ({"sp": 2}, None, "on a mesh .*'sp': 2"),
+    "window": (None, 16, "a rope part of 8 dims with window=16"),
+}
+
+
+@pytest.mark.parametrize("case", ATTEND_ROPE_CASES)
+def test_attend_takes_a_rope_part_on_shards_of_the_batch_only(
+    devices, monkeypatch, case
+):
+    """Two-part scores (latent attention) through the seam: the kernels'
+    `mla` calls alone or inside the batch's `shard_map`, the one rope key
+    going in as it came; a window, the ring and a `tp` axis are refused
+    with their numbers."""
+    from kubeflow_tpu.ops import attention
+    from kubeflow_tpu.testing.hlo import _walk_eqns
+
+    spec, window, want = ATTEND_ROPE_CASES[case]
+    monkeypatch.setattr(attention, "kernels_compiled", lambda: True)
+    mesh = spec and build_mesh(
+        MeshSpec(**spec), devices[: int(np.prod(list(spec.values())))]
+    )
+    b, s, h = 2, 64, 4
+    shape = lambda *dims: jax.ShapeDtypeStruct(dims, jnp.float32)
+    args = (shape(b, s, h, 16),) * 3 + (shape(b, s, h, 8), shape(b, s, 8))
+    trace = lambda: jax.make_jaxpr(
+        lambda q, k, v, qr, kr: attention.attend(
+            q, k, v, mesh=mesh, impl="auto", window=window, q_rope=qr,
+            k_rope=kr, scale=0.25,
+        )
+    )(*args)
+    if isinstance(want, str):
+        with pytest.raises(ValueError, match=want):
+            trace()
+        return
+    jaxpr = trace()
+    assert jaxpr.out_avals[0].shape == (b, s, h, 16)
+    calls = [
+        e for e in _walk_eqns(jaxpr.jaxpr) if e.primitive.name == "pallas_call"
+    ]
+    assert [e.params["name"] for e in calls] == want
+    rows = b // (spec or {}).get("dp", 1)
+    shapes = [v.aval.shape for v in calls[0].invars if len(v.aval.shape) == 3]
+    # q, k, v and q_rope a head a row; the rope key a batch row, once
+    assert shapes == [(rows * h, s, 16)] * 3 + [(rows * h, s, 8), (rows, s, 8)]
+
+
+def test_dense_attention_with_a_rope_part_is_the_joint_heads_attention():
+    keys = jax.random.split(jax.random.PRNGKey(5), 5)
+    b, s, h = 2, 16, 3
+    q, k = (jax.random.normal(kx, (b, s, h, 8)) for kx in keys[:2])
+    v = jax.random.normal(keys[2], (b, s, h, 12))  # of another width
+    q_rope = jax.random.normal(keys[3], (b, s, h, 4))
+    k_rope = jax.random.normal(keys[4], (b, s, 4))
+    joint_k = jnp.concatenate(
+        [k, jnp.broadcast_to(k_rope[:, :, None], (b, s, h, 4))], axis=-1
+    )
+    want = dense_attention(jnp.concatenate([q, q_rope], axis=-1), joint_k, v)
+    got = dense_attention(q, k, v, q_rope=q_rope, k_rope=k_rope)
+    np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)
+    np.testing.assert_allclose(
+        dense_attention(q, k, v, q_rope=q_rope, k_rope=k_rope, scale=0.5),
+        dense_attention(
+            jnp.concatenate([q, q_rope], axis=-1) * 0.5 * 12 ** 0.5, joint_k, v
+        ),
+        atol=1e-5, rtol=1e-5,
+    )

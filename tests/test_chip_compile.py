@@ -132,6 +132,34 @@ def test_flash_forward_backward_compiles_on_the_reported_schedule(
     assert tpu_kernel_calls(text) == len(names)
 
 
+@pytest.mark.parametrize("causal, grid", [(True, ""), (False, "_rect")])
+def test_two_part_two_pass_kernels_compile_past_the_fused_boundary(
+    one_chip, causal, grid
+):
+    """Latent attention's widths (128 + 64 over 128, 32 heads, one rope
+    key) at S = 16,384, where the second dq ring no longer fits the fused
+    backward's VMEM: the chip's compiler takes the two-pass kernels with
+    their 64-lane head-major rope blocks, on the compact grid and on the
+    rectangular one."""
+    b, h, s, r = 1, 32, 16384, 64
+    q, k, v = _qkv(b, h, s, one_chip)
+    q_rope = jax.ShapeDtypeStruct((b, s, h, r), jnp.bfloat16, sharding=one_chip)
+    k_rope = jax.ShapeDtypeStruct((b, s, r), jnp.bfloat16, sharding=one_chip)
+    loss = lambda q, k, v, q_rope, k_rope: _loss(
+        q, k, v, q_rope=q_rope, k_rope=k_rope, causal=causal
+    )
+    text, names = _compile(
+        jax.grad(loss, argnums=(0, 1, 2, 3, 4)), q, k, v, q_rope, k_rope
+    )
+    sched = flash_schedule(s, s, head_dim=HEAD_DIM, rope_dim=r, dtype_bytes=2)
+    assert not sched["bwd_fused"]
+    assert names == [
+        f"flash_fwd_mla{grid}", "flash_delta", f"flash_dq_mla{grid}",
+        f"flash_dkv_mla{grid}",
+    ]
+    assert tpu_kernel_calls(text) == len(names)
+
+
 def test_grouped_head_flash_compiles_at_the_zaya_cell_shape(one_chip):
     """8 query heads over 2 K/V heads at S = 8192 (`zaya1-8b-ep2.train-8k`):
     the kv head is picked in the index maps, the backward stays fused."""
@@ -659,6 +687,75 @@ def test_a_cell_shaped_step_stays_under_the_remat_plans_predicted_peak(
     compiled = trainer.make_train_step().lower(
         trainer.abstract_state(), {"tokens": tokens, "labels": tokens}
     ).compile()
+    counted = compiled.memory_analysis()
+    used = (
+        counted.argument_size_in_bytes + counted.output_size_in_bytes
+        - counted.alias_size_in_bytes + counted.temp_size_in_bytes
+        + counted.generated_code_size_in_bytes
+    )
+    state = trainer.step_memory().state_bytes
+    assert state + plan.saved_bytes < used <= plan.predicted_peak, (
+        used, plan
+    )
+
+
+def test_a_xing_shaped_step_compiles_with_the_two_part_kernels(topo, monkeypatch):
+    """Xing4.0-29B-A4B's published widths at its cell's 8,192 tokens, cut to
+    the leading dense layer and one expert layer, under
+    `remat_policy="flash"` with a v5e's limit stated: the chip's compiler
+    takes the two-part flash calls (128 + 64 over 128, one rope key; the
+    fused backward with its second dq ring), rope's kernel on heads of 64
+    and the streams' passes; the plan admits every name, and what the
+    compiler counts stays under the plan's predicted peak, as the other
+    families' cuts do (8.81 GB counted; the top layer's backward is the
+    fuller moment here: every checkpoint, every result kept, the streams
+    in float32 with their cotangent and no gradient yet)."""
+    from kubeflow_tpu.models.transformer import (
+        AttentionKind, TransformerConfig, TransformerLM, remat_plan,
+    )
+    from kubeflow_tpu.ops import moe
+    from kubeflow_tpu.parallel import MeshSpec, build_mesh
+    from kubeflow_tpu.train import TrainConfig, Trainer
+    from kubeflow_tpu.utils import memory
+
+    _as_on_the_chip(monkeypatch)
+    monkeypatch.setattr(moe, "kernels_compiled", lambda: True)
+    monkeypatch.setattr(memory, "device_limit", lambda mesh: 16_909_336_064)
+    kind = AttentionKind(32, None, 10_000.0, 1.0, (64.0, 4096, 32.0, 1.0, 1.0))
+    cfg = TransformerConfig(
+        vocab_size=16384, d_model=3584, n_layers=2, n_heads=32,
+        head_dim=HEAD_DIM, q_latent=768, kv_latent=512, rope_head_dim=64,
+        v_head_dim=128, softmax_scale=192 ** -0.5 * 2.0047,
+        attention_kinds=(kind,), attention_pattern=(0, 0), residual_streams=4,
+        tie_embeddings=False, remat_policy="flash", dense_layers=1,
+        dense_d_ff=9216, d_ff=1024, num_experts=64, experts_held=(0, 8),
+        experts_per_token=4, router="sigmoid", routed_scaling=2.0,
+        moe_shared_ff=1024, router_force_balance=True,
+    )
+    mesh = build_mesh(MeshSpec(), list(topo.devices)[:1])
+    trainer = Trainer(
+        TransformerLM(cfg, mesh=mesh),
+        TrainConfig(batch_size=1, optimizer="adamw", label_smoothing=0.0,
+                    fsdp_params=False, train_metrics="loss"),
+        mesh, example_input_shape=(2, 8192), example_input_dtype=jnp.int32,
+        input_key="tokens", label_key="labels",
+    )
+    tokens = jax.ShapeDtypeStruct(
+        (1, 8192), jnp.int32, sharding=trainer.batch_sharding(2)
+    )
+    plan = remat_plan(cfg, 8192, trainer.step_memory())
+    assert plan.names == (
+        "hc_maps", "moe_route", "attn_residual", "mlp_hidden", "attn_latent",
+        "hc_out", "attn_qkv",
+    ) and plan.refused == ()
+    compiled = trainer.make_train_step().lower(
+        trainer.abstract_state(), {"tokens": tokens, "labels": tokens}
+    ).compile()
+    text = compiled.as_text()
+    for name in ("flash_fwd_mla", "flash_bwd_mla_fused", "flash_delta",
+                 "rope_turn_fwd", "moe_gmm_fwd"):
+        assert re.search(rf"{name}[^\n]*tpu_custom_call|tpu_custom_call[^\n]*{name}", text), name
+    assert "flash_fwd_compact" not in text and "flash_bwd_fused" not in text
     counted = compiled.memory_analysis()
     used = (
         counted.argument_size_in_bytes + counted.output_size_in_bytes

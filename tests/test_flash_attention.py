@@ -318,3 +318,87 @@ def test_attend_takes_a_window_on_both_paths_and_refuses_it_on_the_ring(devices)
         attend(q, k, k, mesh=ring, impl="auto", window=12)
     with pytest.raises(ValueError, match="a window of 0 key"):
         attend(q, k, k, mesh=None, impl="dense", window=0)
+
+
+# -- two-part scores (latent attention) ---------------------------------------
+
+
+def _two_part(key, b, s, h, d, r, dtype=jnp.float32):
+    kq, kr, rest = jax.random.split(key, 3)
+    return (
+        *_qkv(rest, b, s, h, d, dtype),
+        jax.random.normal(kq, (b, s, h, r), dtype),
+        jax.random.normal(kr, (b, s, r), dtype),
+    )
+
+
+@pytest.mark.parametrize(
+    "s, h, d, r, block",
+    [
+        (256, 2, 128, 64, 128),  # the published widths: seq_major q, k, v
+        (200, 2, 128, 64, 128),  # ragged: padded inside, the tail masked
+        (64, 3, 16, 8, 32),      # narrow heads: the whole call head-major
+        (128, 2, 128, 128, 128),  # a rope part of whole lanes: head-major too
+    ],
+)
+def test_two_part_scores_match_dense_forward_and_all_five_gradients(
+    s, h, d, r, block
+):
+    """s = q·kᵀ + q_rope·k_ropeᵀ with ONE rope key for all heads: the
+    interpreted kernels against `dense_attention`, o and the gradients of
+    q, k, v, q_rope and k_rope (summed over the heads), in each layout
+    of the 128-wide operands, at a scale of the caller's."""
+    q, k, v, q_rope, k_rope = _two_part(jax.random.PRNGKey(7), 2, s, h, d, r)
+    scale = 1.3 * (d + r) ** -0.5
+    kernels = lambda q, k, v, qr, kr: flash_attention(
+        q, k, v, q_rope=qr, k_rope=kr, scale=scale, block_q=block,
+        block_k=block, interpret=True,
+    )
+    dense = lambda q, k, v, qr, kr: dense_attention(
+        q, k, v, q_rope=qr, k_rope=kr, scale=scale
+    )
+    args = (q, k, v, q_rope, k_rope)
+    np.testing.assert_allclose(kernels(*args), dense(*args), atol=2e-5, rtol=2e-5)
+    weigh = lambda f: lambda *a: jnp.sum(f(*a) * jnp.cos(f(*a)))
+    got = jax.grad(weigh(kernels), argnums=range(5))(*args)
+    want = jax.grad(weigh(dense), argnums=range(5))(*args)
+    for g, w, name in zip(got, want, ("q", "k", "v", "q_rope", "k_rope")):
+        assert g.shape == w.shape
+        np.testing.assert_allclose(g, w, atol=1e-4, rtol=1e-4, err_msg=name)
+
+
+def test_two_part_default_scale_is_the_joint_width():
+    q, k, v, q_rope, k_rope = _two_part(jax.random.PRNGKey(8), 1, 64, 2, 16, 8)
+    out = flash_attention(
+        q, k, v, q_rope=q_rope, k_rope=k_rope, block_q=32, block_k=32,
+        interpret=True,
+    )
+    joint = lambda a, b: jnp.concatenate(
+        [a, jnp.broadcast_to(b.reshape(1, 64, -1, 8), (1, 64, 2, 8))], axis=-1
+    )
+    # the same attention with q and k of 24 = 16 + 8, the key's rope part
+    # repeated a head, over v of 16
+    ref = dense_attention(joint(q, q_rope), joint(k, k_rope), v)
+    np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("how, message", [
+    (dict(window=16), "under a window of 16"),
+    (dict(k_rope=None), "two-part scores take q_rope"),
+    (dict(kv_heads=1), "two-part scores take q_rope"),
+])
+def test_two_part_scores_refuse_what_was_not_built(how, message):
+    q, k, v, q_rope, k_rope = _two_part(jax.random.PRNGKey(9), 1, 64, 2, 16, 8)
+    if "kv_heads" in how:
+        k, v = k[:, :, :1], v[:, :, :1]
+    with pytest.raises(ValueError, match=message):
+        flash_attention(
+            q, k, v, q_rope=q_rope, k_rope=how.get("k_rope", k_rope),
+            window=how.get("window"), interpret=True,
+        )
+
+
+def test_unequal_k_and_v_say_what_the_kernels_accept():
+    q, k, v = _qkv(jax.random.PRNGKey(10), 1, 64, 2, 24)
+    with pytest.raises(ValueError, match="go as two parts"):
+        flash_attention(q, k, v[..., :16], interpret=True)

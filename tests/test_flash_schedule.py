@@ -946,3 +946,70 @@ def test_two_pass_band_backward_matches_the_fused_one(window, group):
         q, k, v, do, lse, delta,
     )
     assert names == ["flash_dq_window", "flash_dkv_window"]
+
+
+# -- two-part scores (latent attention) ---------------------------------------
+
+
+def test_schedule_reports_the_widths_and_the_rope_parts_vmem():
+    """At the published widths (128 + 64 over 128) and S = 8192 the fused
+    backward still fits its budget with a second dq ring and dk
+    accumulator; at 16k it does not, and the two-pass kernels run."""
+    from kubeflow_tpu.ops.flash import _FUSED_VMEM_BUDGET, _rope_vmem_bytes
+
+    plain = flash_schedule(8192, 8192, head_dim=128)
+    sched = flash_schedule(8192, 8192, head_dim=128, rope_dim=64)
+    assert (sched["qk_dim"], sched["rope_dim"], sched["v_dim"]) == (128, 64, 128)
+    assert sched["layout"] == "seq_major" and sched["transposes_per_call"] == 0
+    assert sched["rope_layout"] == "head_major"
+    assert plain["rope_dim"] == 0 and plain["rope_layout"] is None
+    # the rope part costs VMEM by whole lane tiles: 64 dims take 128 lanes
+    extra = _rope_vmem_bytes(8192, 1024, 1024, 128, 2)
+    assert sched["bwd_fused_vmem_bytes"] - plain["bwd_fused_vmem_bytes"] == extra
+    assert extra == 8192 * 128 * 4 + 1024 * 128 * 4 + 3 * 2048 * 128 * 4
+    assert sched["bwd_fused"] and sched["bwd_fused_vmem_bytes"] <= _FUSED_VMEM_BUDGET
+    assert sched["grid_steps"] == plain["grid_steps"] == 36
+    long = flash_schedule(16384, 16384, head_dim=128, rope_dim=64)
+    assert not long["bwd_fused"] and flash_schedule(16384, 16384)["bwd_fused"]
+    # q and dq stream 192 lanes where they streamed 128, v and dO 128
+    assert sched["bwd_hbm_bytes"] > plain["bwd_hbm_bytes"]
+    assert flash_schedule(64, 64, head_dim=16, rope_dim=8)["rope_layout"] == "head_major"
+    with pytest.raises(ValueError, match="under a window of 512"):
+        flash_schedule(8192, 8192, rope_dim=64, window=512)
+
+
+@pytest.mark.parametrize("fused", [True, False], ids=["fused", "two-pass"])
+def test_two_part_backward_kernels_by_name_and_one_gradient(fused):
+    """The fused and the two-pass backward over two-part scores: their
+    names, and the same five gradients (dk_rope summed over the heads)."""
+    bh, s, d, r, block = 2, 256, 16, 8, 64
+    keys = jax.random.split(jax.random.PRNGKey(3), 6)
+    q, k, v, do = (jax.random.normal(kx, (bh, s, d)) for kx in keys[:4])
+    rope = (
+        jax.random.normal(keys[4], (bh, s, r)),
+        jax.random.normal(keys[5], (1, s, r)),
+    )
+    how = dict(rope=rope, scale=0.2)
+    o, lse = _flash_fwd_impl(q, k, v, True, block, block, True, None, False, **how)
+    delta = _flash_delta_impl(o, do, block, True, False)
+    run = lambda *a: _flash_bwd_kernels(
+        *a[:6], True, block, block, True, None, False, fused, rope=a[6:],
+        scale=0.2,
+    )
+    args = (q, k, v, do, lse, delta, *rope)
+    names = pallas_kernel_names(run, *args)
+    assert names == (
+        ["flash_bwd_mla_fused"] if fused else ["flash_dq_mla", "flash_dkv_mla"]
+    )
+    dq, dk, dv, (dq_rope, dk_rope) = run(*args)
+    assert dq_rope.shape == rope[0].shape and dk_rope.shape == rope[1].shape
+
+    def dense(q, k, v, qr, kr):
+        s_ = 0.2 * (jnp.einsum("hqd,hkd->hqk", q, k)
+                    + jnp.einsum("hqr,kr->hqk", qr, kr[0]))
+        s_ = jnp.where(jnp.tril(jnp.ones((s, s), bool)), s_, -jnp.inf)
+        return jnp.einsum("hqk,hkd->hqd", jax.nn.softmax(s_, -1), v)
+
+    _, vjp = jax.vjp(dense, q, k, v, *rope)
+    for got, want in zip((dq, dk, dv, dq_rope, dk_rope), vjp(do)):
+        np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)

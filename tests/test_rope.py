@@ -106,21 +106,30 @@ def test_kernel_matches_the_folded_form_forward_and_backward(
 
 
 @pytest.mark.parametrize(
-    "s,d,kernel",
+    "s,h,d,kernel",
     [
-        (512, 128, True),
-        (512, 256, True),  # two lane tiles a head
-        (512, 64, False),  # a head is not whole lanes
-        (250, 128, False),  # no 8-aligned block divides the sequence
+        (512, 2, 128, True),
+        (512, 2, 256, True),  # two lane tiles a head
+        (512, 4, 64, True),   # latent attention's rope part: two heads a tile
+        (512, 3, 64, False),  # ... but not where the heads leave half a tile
+        (512, 1, 64, False),  # the head-less rope key [B, S, 64]
+        (512, 2, 48, False),  # a head is no share of a tile
+        (250, 2, 128, False),  # no 8-aligned block divides the sequence
     ],
 )
-def test_kernel_engages_from_the_shapes(s, d, kernel):
-    x, positions = _x(1, s, 2, d, jnp.float32, seed=2)
-    x = x.reshape(1, s, 2 * d)
+def test_kernel_engages_from_the_shapes(s, h, d, kernel):
+    x, positions = _x(1, s, h, d, jnp.float32, seed=2)
+    x = x.reshape(1, s, h * d)
     forced = lambda x: rope(x, positions, THETA, head_dim=d, interpret=True)
     assert (pallas_kernel_names(forced, x) == ["rope_turn_fwd"]) == kernel
-    want = _rope_4d(x.reshape(1, s, 2, d), positions, THETA).reshape(x.shape)
+    want = _rope_4d(x.reshape(1, s, h, d), positions, THETA).reshape(x.shape)
     np.testing.assert_allclose(forced(x), want, atol=2e-6, rtol=2e-6)
+    if kernel:  # and its VJP is the plain form's gradient
+        plain = lambda x: rope(x, positions, THETA, head_dim=d)
+        np.testing.assert_allclose(
+            jax.grad(lambda x: _weighted(forced(x)))(x),
+            jax.grad(lambda x: _weighted(plain(x)))(x), atol=2e-6, rtol=2e-6,
+        )
 
 
 def test_kernel_runs_in_shard_map_over_batch_and_heads(devices):
