@@ -32,6 +32,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from kubeflow_tpu.ops import streams as streams_ops
 from kubeflow_tpu.ops.attention import attend
 from kubeflow_tpu.ops.flash import CHECKPOINT_LSE_NAME, CHECKPOINT_OUT_NAME
 from kubeflow_tpu.ops.moe import (
@@ -300,7 +301,8 @@ QKV_RESULT = "attn_qkv"           # q, k and v as the kernels read them
 CONV_RESULT = "ssm_conv"          # the mixer's convolved x, B and C
 ATTN_LATENT_RESULT = "attn_latent"  # latent attention's down-projections
 STREAM_OUT_RESULT = "hc_out"      # a sublayer's output under residual streams
-HC_RESULT = "hc_maps"             # the residual streams' maps' products
+HC_RESULT = streams_ops.CHECKPOINT_MAPS_NAME  # the residual streams' maps'
+                                  # raw products and the norm's scalar
 # The order they are admitted in: milliseconds of the backward's second
 # forward spared a GB held, the small ones first. Timed on the v5e in the
 # benchmark's three `flash` cells: `r` of the `[scopes]` line of a traced
@@ -315,8 +317,14 @@ HC_RESULT = "hc_maps"             # the residual streams' maps' products
 #   attn_qkv       laguna 12.5 / 0.82 (rope's turn with it); none under CCA
 #   ssm_conv       nemotron 3.6-5.8 / 0.42 (`silu`'s slope still forms the
 #                  float32 pre-activation again)
-#   hc_maps        xing: the float32 `highest` product of 14,336 and the
-#                  norm's pass over the streams, 96 bytes a token a sublayer
+#   hc_maps        xing: the RAW product of the streams with `phi` and the
+#                  norm's scalar, (24 + 1) x 4 = 100 bytes a token a
+#                  sublayer (PR 39 named the product TIMES the scalar, and
+#                  the multiply's backward formed the raw product again:
+#                  3.5 ms / 0.008 GB). Where the mixes are the row-block
+#                  kernels `hc_pre_fwd` runs again whatever is kept (h is
+#                  no candidate: 59 MB a sublayer), so the name spares
+#                  only the maps' wait on that pass (PERF.md §6, PR 40)
 #   attn_latent    xing: 1,344 dims a token, the cheapest thing of the
 #                  layer to keep: 2.2 ms / 0.11 GB (PERF.md §6, PR 39)
 #   hc_out         xing: the streams' map of a sublayer's output onto them
@@ -438,8 +446,9 @@ def _result_bytes(cfg: "TransformerConfig", tokens: int) -> list[dict]:
         n = cfg.residual_streams
         for i, kind in enumerate(_attention_kinds(cfg)):
             out = {RESIDUAL_RESULT: tokens * _stream_lanes(cfg) * act}
-            if n:  # n² + 2n products a token, float32, round both sublayers
-                out[HC_RESULT] = 2 * tokens * (n * n + 2 * n) * 4
+            if n:  # n² + 2n raw products and the norm's scalar a token,
+                # float32, round both sublayers
+                out[HC_RESULT] = 2 * tokens * (n * n + 2 * n + 1) * 4
                 out[STREAM_OUT_RESULT] = 2 * tokens * _lanes(cfg.d_model) * act
             attention(out, kind)
             if cfg.num_experts > 0 and i >= cfg.dense_layers:
@@ -1279,99 +1288,50 @@ class StateSpaceMixer(nn.Module):
             return _dense(cfg.d_model, (None, "embed"), "out_proj", cfg.dtype)(y)
 
 
-def _sinkhorn(m, iters: int, eps: float):
-    """m [B, n, n, S] made doubly stochastic: every row divided by (its
-    sum + eps), then every column, `iters` times."""
-    for _ in range(iters):
-        m = m / (jnp.sum(m, axis=2, keepdims=True) + eps)
-        m = m / (jnp.sum(m, axis=1, keepdims=True) + eps)
-    return m
-
-
-def _split3(w):
-    """A float32 array as three bfloat16 pieces whose sum is it (24 bits
-    of mantissa in three times 8), side by side along axis 1."""
-    bf16, f32 = jnp.bfloat16, jnp.float32
-    hi = w.astype(bf16)
-    rest = w - hi.astype(f32)
-    mid = rest.astype(bf16)
-    low = (rest - mid.astype(f32)).astype(bf16)
-    return jnp.concatenate([hi, mid, low], axis=1)
-
-
-def _thirds(t, axis: int):
-    return sum(jnp.split(t, 3, axis=axis))
-
-
-@jax.custom_vjp
-def _exact_product(x, phi):
-    """`einsum("kc,bsk->bcs", phi, x)` at full precision for x in
-    bfloat16 and phi in float32, in ONE pass of the MXU: x is exact in
-    bfloat16 already, so only phi is split in three (`_split3`), and its
-    pieces ride the output's lanes, where 3 x 24 columns cost what 24 do
-    (a tile is 128). `precision=HIGHEST` splits both operands, six
-    passes: 0.92 ms against 0.15 at [8192, 14336] x [14336, 24] by the
-    MXU's peak. Backward the same way: phi's gradient from the
-    cotangent split in three, exact; x's in one plain pass, since it is
-    rounded to bfloat16 where it lands."""
-    return _exact_product_fwd(x, phi)[0]
-
-
-def _exact_product_fwd(x, phi):
-    t = jnp.einsum(
-        "kc,bsk->bcs", _split3(phi), x, preferred_element_type=jnp.float32
-    )
-    return _thirds(t, 1), (x, phi)
-
-
-def _exact_product_bwd(residuals, dt):
-    x, phi = residuals
-    dx = jnp.einsum(
-        "bcs,kc->bsk", dt.astype(x.dtype), phi.astype(x.dtype),
-        preferred_element_type=jnp.float32,
-    ).astype(x.dtype)
-    dphi = jnp.einsum(
-        "bcs,bsk->kc", _split3(dt), x, preferred_element_type=jnp.float32
-    )
-    return dx, _thirds(dphi, 1)
-
-
-_exact_product.defvjp(_exact_product_fwd, _exact_product_bwd)
-
-
 class StreamMaps(nn.Module):
     """The three maps round one sublayer of a stack with n =
-    `residual_streams` streams X [B, S, n·d] (mHC's symbols): `x =
-    RMSNorm(X)` over all n·d dims, no learned scale; `[t_pre | t_post |
-    t_res] = x phi`, `phi` [n·d, n² + 2n], float32 at full precision;
-    `Hp = sigmoid(a_pre t_pre + b_pre)` [n], the weights of the
-    sublayer's input; `Ho = 2 sigmoid(a_post t_post + b_post)` [n], of
-    its output onto each stream; `Hr = sinkhorn(exp(clip(a_res t_res +
-    b_res, +-hc_clamp)))` [n, n], stream to stream. Returned with the
-    sequence in the lanes, [B, n, S], [B, n, S], [B, n, n, S] float32 (a
-    trailing axis of 4 would pad a tile 32 times over). The norm is a
-    scalar a token, so it multiplies the product, not the streams: X is
-    read, never written; bfloat16 streams take the product in one pass
-    (`_exact_product`). The products are named (`HC_RESULT`) and the
-    iterations sit in a checkpoint of their own, so their backward forms
-    them again from the products and saves none of the 2·`hc_iters`
-    intermediates. Sows `hc_sinkhorn_err` (the largest |row or column
-    sum - 1| of Hr) and `hc_res_diag_mean` (Hr's mean diagonal), each
-    over the stack's sublayers' count so that the step's sum is a mean."""
+    `residual_streams` streams X [B, S, n·d] (mHC's symbols), and the mix
+    into the sublayer: `x = RMSNorm(X)` over all n·d dims, no learned
+    scale; `[t_pre | t_post | t_res] = x phi`, `phi` [n·d, n² + 2n],
+    float32 at full precision; `Hp = sigmoid(a_pre t_pre + b_pre)` [n],
+    the weights of the sublayer's input `h = sum_i Hp[i] X[i]`; `Ho = 2
+    sigmoid(a_post t_post + b_post)` [n], of its output onto each stream;
+    `Hr = sinkhorn(exp(clip(a_res t_res + b_res, +-hc_clamp)))` [n, n],
+    stream to stream. Returns (h [B, S, d], the streams for the mix out,
+    Ho [B, n, S], Hr [B, n, n, S]): the maps float32 with the sequence in
+    the lanes (a trailing axis of 4 would pad a tile 32 times over). The
+    norm is a scalar a token, so it multiplies the product, not the
+    streams: X is read, never written. What is named (`HC_RESULT`) is the
+    RAW product and that scalar, so the backward forms neither again.
+
+    With `kernels` (`ops/streams.kernels_apply`: where kernels compile,
+    bfloat16 streams, d whole lane tiles, a sequence of whole 128-row
+    blocks, one device) the norm, the product, Hp and h are ONE pass
+    over X (`hc_pre_fwd`) and the backward is `ops/streams.mix_in`'s
+    rule: the maps' backward in XLA, then `hc_pre_bwd`, which takes Hp's
+    cotangent from dh itself and writes dX once; the streams returned
+    are then for `ops/streams.mix_out` ALONE (the two rules share that
+    write). Without, XLA's code (`ops/streams.mixed_in`). Sows
+    `hc_sinkhorn_err` (the largest |row or column sum - 1| of Hr) and
+    `hc_res_diag_mean` (Hr's mean diagonal), each over the stack's
+    sublayers' count so that the step's sum is a mean, and
+    `hc_kernel_sublayers`, 1 where the kernels ran."""
 
     config: TransformerConfig
+    kernels: bool = False
 
     @nn.compact
     def __call__(self, streams):
         cfg = self.config
         n, f32 = cfg.residual_streams, jnp.float32
-        width, maps = streams.shape[-1], n * n + 2 * n
+        spec = _stream_maps(cfg)
+        width = streams.shape[-1]
         phi = self.param(
             "phi",
             nn.with_logical_partitioning(
                 nn.initializers.normal(width ** -0.5), ("embed", None)
             ),
-            (width, maps), f32,
+            (width, spec.maps), f32,
         )
         # b_res 2 on the diagonal: the seed's stream-to-stream map leans
         # to the identity without being it.
@@ -1380,47 +1340,33 @@ class StreamMaps(nn.Module):
             _replicated(lambda *_: jnp.concatenate(
                 [jnp.zeros(2 * n, f32), 2.0 * jnp.eye(n, dtype=f32).reshape(-1)]
             ), 1),
-            (maps,), f32,
+            (spec.maps,), f32,
         )
         a = self.param("a", _replicated(nn.initializers.ones, 1), (3,), f32)
-
-        @jax.checkpoint
-        def of_products(t, a, bias):
-            z = jnp.repeat(a, jnp.array([n, n, n * n]), total_repeat_length=maps)
-            z = z[:, None] * t + bias[:, None]
-            pre, post, res = z[:, :n], z[:, n:2 * n], z[:, 2 * n:]
-            m = jnp.exp(jnp.clip(res, -cfg.hc_clamp, cfg.hc_clamp))
-            m = _sinkhorn(
-                m.reshape(-1, n, n, m.shape[-1]), cfg.hc_iters, cfg.hc_eps
-            )
-            return jax.nn.sigmoid(pre), 2.0 * jax.nn.sigmoid(post), m
-
+        mix = streams_ops.mix_in if self.kernels else streams_ops.mixed_in
+        h, streams, ho, hr = mix(streams, phi, a, bias, spec)
         with jax.named_scope("hc.maps"):
-            x = streams.astype(f32)
-            inv_rms = jax.lax.rsqrt(
-                jnp.mean(x * x, axis=-1) + cfg.norm_eps
-            )  # [B, S]
-            if streams.dtype == jnp.bfloat16:
-                t = _exact_product(streams, phi)
-            else:
-                t = jnp.einsum(
-                    "kc,bsk->bcs", phi, x, precision=jax.lax.Precision.HIGHEST
-                )
-            t = t * inv_rms[:, None, :]
-            hp, ho, hr = of_products(checkpoint_name(t, HC_RESULT), a, bias)
             sums = jnp.concatenate([hr.sum(axis=2), hr.sum(axis=1)], axis=1)
             sublayers = 2 * cfg.n_layers
             for name, value in (
-                ("hc_sinkhorn_err", jnp.max(jnp.abs(sums - 1.0))),
+                ("hc_sinkhorn_err", jnp.max(jnp.abs(sums - 1.0)) / sublayers),
                 ("hc_res_diag_mean", jnp.mean(
                     jnp.trace(hr, axis1=1, axis2=2) / n
-                )),
+                ) / sublayers),
+                ("hc_kernel_sublayers", float(self.kernels)),
             ):
                 self.sow(
-                    "counters", name, value / sublayers,
+                    "counters", name, value,
                     reduce_fn=lambda _, new: new, init_fn=lambda: 0.0,
                 )
-        return hp, ho, hr
+        return h, streams, ho, hr
+
+
+def _stream_maps(cfg: "TransformerConfig") -> streams_ops.Maps:
+    return streams_ops.Maps(
+        cfg.residual_streams, cfg.d_model, cfg.hc_iters, cfg.hc_clamp,
+        cfg.hc_eps, cfg.norm_eps,
+    )
 
 
 class Block(nn.Module):
@@ -1443,32 +1389,26 @@ class Block(nn.Module):
     @nn.nowrap
     def _mixed(self, streams, name: str, sublayer):
         """One sublayer round n streams [B, S, n·d]: its input is
-        `sum_i Hp[i] X[i]`, and `X'[i] = sum_j Hr[i, j] X[j] + Ho[i] y`
-        with y its output (`StreamMaps`). The sums in float32, the
+        `sum_i Hp[i] X[i]` (`StreamMaps`), and `X'[i] = sum_j Hr[i, j]
+        X[j] + Ho[i] y` with y its output. The sums in float32, the
         streams stored in `dtype`. `sublayer(h)` -> (y, what it returns
-        beside)."""
+        beside). Where `ops/streams.kernels_apply` says so the round's
+        passes over the streams are the row-block kernels with their own
+        backward (`hc_pre_fwd` and `hc_post_fwd` forward, `hc_post_bwd`
+        and `hc_pre_bwd` backward); anywhere else (the CPU, float32
+        streams, a d that is not whole lane tiles, a sequence that is not
+        whole blocks of 128 rows, a mesh of several devices) XLA's code,
+        differentiated by JAX."""
         cfg = self.config
-        n, d, f32 = cfg.residual_streams, cfg.d_model, jnp.float32
-        hp, ho, hr = StreamMaps(cfg, name=f"hc_{name}")(streams)
-        column = lambda m: m[..., None]  # [B, S] -> a factor a token
-        with jax.named_scope("hc.pre"):
-            one = [
-                streams[..., i * d:(i + 1) * d].astype(f32) for i in range(n)
-            ]
-            h = sum(column(hp[:, i]) * one[i] for i in range(n))
-        y, beside = sublayer(h.astype(cfg.dtype))
-        with jax.named_scope("hc.post"):
-            # `Ho`'s gradient reads y: named, or the sublayer's last
-            # product runs again for it alone.
-            y = checkpoint_name(y, STREAM_OUT_RESULT).astype(f32)
-            mixed = jnp.concatenate([
-                (
-                    sum(column(hr[:, i, j]) * one[j] for j in range(n))
-                    + column(ho[:, i]) * y
-                ).astype(cfg.dtype)
-                for i in range(n)
-            ], axis=-1)
-        return mixed, beside
+        spec = _stream_maps(cfg)
+        kernels = streams_ops.kernels_apply(streams, spec, self.mesh)
+        h, streams, ho, hr = StreamMaps(cfg, kernels, name=f"hc_{name}")(streams)
+        y, beside = sublayer(h)
+        # `Ho`'s gradient reads y: named, or the sublayer's last product
+        # runs again for it alone.
+        y = checkpoint_name(y, STREAM_OUT_RESULT)
+        mix = streams_ops.mix_out if kernels else streams_ops.mixed_out
+        return mix(streams, y, hr, ho, spec), beside
 
     @nn.compact
     def __call__(self, x, positions, router_state=None):
@@ -1926,9 +1866,10 @@ class TransformerLM(nn.Module):
                 )
         layers = _layer_classes(cfg, plan.names)  # checked before anything
         n = cfg.residual_streams
-        if n:  # every stream enters as the embedding's row
+        if n:  # every stream enters as the embedding's row (side by side
+            # in the lanes: `jnp.tile` goes by [B, S, n, d], a relayout)
             with jax.named_scope("hc.entry"):
-                x = jnp.tile(x, n)
+                x = jnp.concatenate([x] * n, axis=-1)
         for i, layer_cls in enumerate(layers):
             x, router_state = layer_cls(
                 cfg, self.mesh, layer=i, name=f"layer_{i}"

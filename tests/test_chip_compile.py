@@ -226,6 +226,44 @@ def test_rope_kernel_compiles_at_the_cells_shapes(
     assert tpu_kernel_calls(text) == 2
 
 
+def test_a_sublayers_stream_passes_compile_at_the_xing_cell_shape(one_chip):
+    """One round of four streams of 3,584 at 8,192 tokens, forward and
+    backward: `hc_pre_fwd`, `hc_post_fwd`, `hc_post_bwd` and `hc_pre_bwd`
+    over blocks of 128 whole rows of 14,336 (3.7 MB each: the calls state
+    a VMEM limit above the compiler's 16 MiB) with the maps' blocks
+    [128, 128] turned in VMEM, every call under an
+    `hc.*` scope in the forward AND in the hand-written backward, which is
+    what `hc_time_pct.train` reads. Two Sinkhorn iterations: the stack of
+    20 is XLA's and half of a step's compile (PERF.md §7)."""
+    from kubeflow_tpu.ops import streams
+
+    n, d, s = 4, 3584, 8192
+    spec = streams.Maps(n, d, 2, 30.0, 1e-6, 1e-6)
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip
+    )
+
+    def loss(x, phi, a, bias):
+        h, x, ho, hr = streams.mix_in(x, phi, a, bias, spec, interpret=False)
+        mixed = streams.mix_out(x, h, hr, ho, spec, interpret=False)
+        return (mixed.astype(jnp.float32) ** 2).sum()
+
+    text, names = _compile(
+        jax.grad(loss, argnums=(0, 1, 2, 3)),
+        sds((1, s, n * d), jnp.bfloat16), sds((n * d, spec.maps), jnp.float32),
+        sds((3,), jnp.float32), sds((spec.maps,), jnp.float32),
+    )
+    assert names == ["hc_pre_fwd", "hc_post_fwd", "hc_post_bwd", "hc_pre_bwd"]
+    assert tpu_kernel_calls(text) == len(names)
+    for name in names:
+        scope = "hc.post" if "post" in name else "hc.pre"
+        phase = "jvp" if name.endswith("fwd") else "transpose"
+        assert re.search(
+            rf'tpu_custom_call[^\n]*op_name="[^"]*{phase}\([^"]*{re.escape(scope)}'
+            rf'[^"]*/{name}', text
+        ), name
+
+
 def test_grouped_matmul_kernels_compile_at_the_zaya_cell_shape(
     one_chip, monkeypatch
 ):
@@ -705,7 +743,7 @@ def test_a_xing_shaped_step_compiles_with_the_two_part_kernels(topo, monkeypatch
     `remat_policy="flash"` with a v5e's limit stated: the chip's compiler
     takes the two-part flash calls (128 + 64 over 128, one rope key; the
     fused backward with its second dq ring), rope's kernel on heads of 64
-    and the streams' passes; the plan admits every name, and what the
+    and the streams' row-block kernels; the plan admits every name, and what the
     compiler counts stays under the plan's predicted peak, as the other
     families' cuts do (8.81 GB counted; the top layer's backward is the
     fuller moment here: every checkpoint, every result kept, the streams
@@ -753,7 +791,8 @@ def test_a_xing_shaped_step_compiles_with_the_two_part_kernels(topo, monkeypatch
     ).compile()
     text = compiled.as_text()
     for name in ("flash_fwd_mla", "flash_bwd_mla_fused", "flash_delta",
-                 "rope_turn_fwd", "moe_gmm_fwd"):
+                 "rope_turn_fwd", "moe_gmm_fwd", "hc_pre_fwd", "hc_post_fwd",
+                 "hc_post_bwd", "hc_pre_bwd"):
         assert re.search(rf"{name}[^\n]*tpu_custom_call|tpu_custom_call[^\n]*{name}", text), name
     assert "flash_fwd_compact" not in text and "flash_bwd_fused" not in text
     counted = compiled.memory_analysis()

@@ -10,6 +10,7 @@ The cells' configurations come from the benchmark's files (as
 """
 
 import dataclasses
+import functools
 import re
 
 import jax
@@ -26,8 +27,9 @@ from kubeflow_tpu.models.transformer import (
     TransformerLM,
     remat_plan,
 )
+from kubeflow_tpu.ops import streams as streams_ops
 from kubeflow_tpu.parallel import MeshSpec, build_mesh
-from kubeflow_tpu.testing.hlo import _walk_eqns
+from kubeflow_tpu.testing.hlo import _walk_eqns, jaxpr_kernel_names
 from kubeflow_tpu.train import TrainConfig, Trainer, fit
 from kubeflow_tpu.utils import memory
 from kubeflow_tpu.utils.memory import StepMemory
@@ -88,14 +90,15 @@ CELL_PLANS = {
     "xing4.0-29b-a4b-ep8.train-8k": (
         7_593_464_472, 3_037_385_784,
         # Four streams: a layer's input and `attn_residual` are 14,336 wide;
-        # q's and k's two parts and v; the latents; the maps' products.
+        # q's and k's two parts and v; the latents; the maps' raw products
+        # and the norm's scalar.
         {
-            "hc_maps": 7_864_320, "moe_route": 67_108_864,
+            "hc_maps": 8_192_000, "moe_route": 67_108_864,
             "attn_residual": 1_174_405_120, "mlp_hidden": 436_207_616,
             "attn_latent": 115_343_360, "hc_out": 587_202_560,
             "attn_qkv": 1_184_890_880,
         },
-        (), 16_144_601_752,
+        (), 16_144_994_968,
     ),
     "zaya1-8b-ep2.train-8k": (
         9_223_475_372, 3_689_390_144,
@@ -235,9 +238,9 @@ def _trainer(cfg, mesh_spec=MeshSpec(), devices=1, batch=2, seq=8, accum_steps=1
     )
 
 
-def _forward_jaxpr(cfg):
+def _forward_jaxpr(cfg, shape=(2, 8)):
     model = TransformerLM(cfg)
-    tokens = jnp.zeros((2, 8), jnp.int32)
+    tokens = jnp.zeros(shape, jnp.int32)
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)
     return jax.make_jaxpr(model.apply)(params, tokens).jaxpr
 
@@ -455,7 +458,7 @@ def test_the_streams_cell_is_near_the_limit_and_a_smaller_one_refuses(
     assert costs["attn_latent"] == 5 * tokens * (768 + 640) * 2
     assert costs["hc_out"] == 5 * 2 * tokens * cfg.d_model * 2
     assert costs["attn_qkv"] == 5 * tokens * 2 * (32 * (128 + 64 + 128 + 128) + 128)
-    assert costs["hc_maps"] == 5 * 2 * tokens * 24 * 4
+    assert costs["hc_maps"] == 5 * 2 * tokens * (24 + 1) * 4
 
 
 def test_a_result_kept_is_not_formed_again():
@@ -504,6 +507,12 @@ FAMILIES = {
         moe_shared_ff=32, dense_layers=1, dense_d_ff=96, q_latent=12,
         kv_latent=8, rope_head_dim=8, residual_streams=4, tie_embeddings=False,
     ),
+    "blocks of four streams as kernels, latent attention, sigmoid experts": dict(
+        n_layers=2, num_experts=4, router="sigmoid", experts_per_token=2,
+        moe_shared_ff=32, dense_layers=1, dense_d_ff=96, q_latent=12,
+        kv_latent=8, rope_head_dim=8, residual_streams=4, tie_embeddings=False,
+        d_model=128,
+    ),
     "a pattern of mixers, latent relu2 experts and attention": dict(
         n_layers=3, layer_pattern="ME*", num_experts=4, router="sigmoid",
         experts_per_token=2, moe_latent=16, moe_shared_ff=48, mlp_act="relu2",
@@ -514,13 +523,26 @@ FAMILIES = {
 
 
 @pytest.mark.parametrize("family", sorted(FAMILIES))
-def test_the_plans_bytes_are_those_of_the_results_the_layers_name(family):
+def test_the_plans_bytes_are_those_of_the_results_the_layers_name(
+    family, monkeypatch
+):
     """What `_result_bytes` reckons from the configuration is what the
     traced forward names: every `name` equation's result, its minor
-    dimension in whole lane tiles, summed by name."""
+    dimension in whole lane tiles, summed by name. The streams' mixes as
+    XLA's code and as the row-block kernels (the CPU is told they
+    compile) name the same results."""
     cfg = dataclasses.replace(SMALL, dtype=jnp.bfloat16, **FAMILIES[family])
+    kernels = "as kernels" in family
+    if kernels:
+        monkeypatch.setattr(streams_ops, "kernels_apply", functools.partial(
+            streams_ops.kernels_apply, compiled=True
+        ))
+    # the kernels take sequences of whole 128-row blocks
+    shape = (2, 128) if kernels else (2, 8)
+    forward = _forward_jaxpr(cfg, shape)
+    assert kernels == ("hc_pre_fwd" in jaxpr_kernel_names(forward))
     named: dict = {}
-    for eqn in _walk_eqns(_forward_jaxpr(cfg)):
+    for eqn in _walk_eqns(forward):
         if eqn.primitive.name == "name" and eqn.params["name"] in SAVED_RESULTS:
             aval = eqn.outvars[0].aval
             size = int(np.prod(aval.shape[:-1])) * transformer._lanes(aval.shape[-1])
@@ -531,7 +553,7 @@ def test_the_plans_bytes_are_those_of_the_results_the_layers_name(family):
             named[eqn.params["name"]] = (
                 named.get(eqn.params["name"], 0) + size * aval.dtype.itemsize
             )
-    assert named == dict(remat_plan(cfg, 16, ROOMY).bytes)
+    assert named == dict(remat_plan(cfg, shape[0] * shape[1], ROOMY).bytes)
     assert set(KERNEL_RESULTS).isdisjoint(SAVED_RESULTS)
 
 

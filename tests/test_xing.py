@@ -7,6 +7,7 @@ ratio: 2 heads of 16 + 8 over v of 16, ranks 12 / 8, n = 4, 8 experts two a
 token, 1 dense + 2 expert layers."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -19,6 +20,7 @@ from benchmarks.reference import xing as ref
 from kubeflow_tpu.models.transformer import (
     Block, StreamMaps, TransformerConfig, TransformerLM,
 )
+from kubeflow_tpu.ops import streams as streams_ops
 from kubeflow_tpu.testing.hlo import jaxpr_kernel_names
 from kubeflow_tpu.train.trainer import softmax_cross_entropy
 
@@ -230,9 +232,16 @@ def test_the_stream_map_is_doubly_stochastic_and_the_clamp_holds(a_res, rows_to)
     cfg = _config()
     n = cfg.residual_streams
     streams = jax.random.normal(jax.random.PRNGKey(1), (B, S, n * cfg.d_model))
-    (hp, ho, hr), mutated = _maps(
+    (h, same, ho, hr), mutated = _maps(
         cfg, streams, a=jnp.array([1.0, 1.0, a_res], jnp.float32)
     )
+    assert same is streams and h.shape == (B, S, cfg.d_model)
+    # h = sum_i Hp[i] X[i] over d > n lanes: Hp is what solves it a token
+    one = np.asarray(streams).reshape(B, S, n, cfg.d_model)
+    hp = np.stack([
+        [np.linalg.lstsq(one[b, s].T, np.asarray(h)[b, s], rcond=None)[0]
+         for s in range(S)] for b in range(B)
+    ]).transpose(0, 2, 1)
     assert hp.shape == (B, n, S) and ho.shape == hp.shape
     assert hr.shape == (B, n, n, S)
     assert np.all(np.isfinite(hr)) and np.all(hr >= 0)
@@ -241,6 +250,7 @@ def test_the_stream_map_is_doubly_stochastic_and_the_clamp_holds(a_res, rows_to)
         np.testing.assert_allclose(hr.sum(axis=2), 1.0, atol=rows_to)
     assert np.all((hp > 0) & (hp < 1)) and np.all((ho > 0) & (ho < 2))
     counters = mutated["counters"]
+    assert float(counters["hc_kernel_sublayers"]) == 0  # XLA's code here
     err = float(counters["hc_sinkhorn_err"]) * 2 * cfg.n_layers
     sums = np.concatenate([hr.sum(axis=2), hr.sum(axis=1)], axis=1)
     assert err == pytest.approx(np.abs(sums - 1).max(), rel=1e-4)
@@ -292,24 +302,56 @@ def test_identity_maps_on_equal_streams_are_todays_block_a_stream(dense):
 # -- one loss and one gradient under every policy ---------------------------------
 
 
-@pytest.fixture(scope="module")
-def small(seeded):
+@pytest.fixture(scope="module", params=["xla", "kernels"])
+def small(seeded, request):
     """The two-layer cut's seeded leaves, a batch, and its loss and
-    gradient under dense attention with nothing formed again."""
+    gradient under dense attention with nothing formed again: in float32,
+    where the streams' mixes are XLA's code, and in bfloat16 at a hidden
+    size of 128 and a sequence of 128 with `ops/streams.kernels_apply`
+    told the kernels compile (the CPU interprets them), where they are
+    the row-block kernels and their two rules."""
     key, _, tokens, labels = seeded
-    numbers, flat = _held(FEW, key, 0, 4)
+    kernels = request.param == "kernels"
+    numbers = dict(FEW, hidden_size=128) if kernels else FEW
+    if kernels:  # a sequence of whole 128-row blocks
+        tokens = jax.random.randint(
+            jax.random.PRNGKey(5), (B, 128 + 1), 0, NUMBERS["vocab_size"]
+        )
+        tokens, labels = tokens[:, :-1], tokens[:, 1:]
+    numbers, flat = _held(numbers, key, 0, 4)
     params = train_mla.to_program_tree(flat)
-    step = lambda **how: jax.jit(jax.value_and_grad(
-        _program_loss(_config(numbers, **how))
-    ))(params, tokens, labels)
-    return step, step()
+    how = dict(dtype=jnp.bfloat16) if kernels else {}
+
+    def step(**more):
+        patch = pytest.MonkeyPatch()
+        if kernels:
+            patch.setattr(streams_ops, "kernels_apply", functools.partial(
+                streams_ops.kernels_apply, compiled=True
+            ))
+        try:
+            loss = _program_loss(_config(numbers, **how, **more))
+            names = jaxpr_kernel_names(
+                jax.make_jaxpr(jax.grad(loss))(params, tokens, labels).jaxpr
+            )
+            assert kernels == any(n.startswith("hc_") for n in names), names
+            return jax.jit(jax.value_and_grad(loss))(params, tokens, labels)
+        finally:
+            patch.undo()
+
+    return step, step(), kernels
 
 
 @pytest.mark.parametrize("policy", ["full", "mlp", "flash"])
 def test_every_remat_policy_gives_one_loss_and_one_gradient(small, policy):
-    step, want = small
+    """The kernels' two rules share one write of dX (`mix_out`'s hands dX'
+    on, `mix_in`'s applies Hr): sound under `jax.checkpoint` whatever is
+    formed again, since a checkpoint runs the same two rules on the same
+    values."""
+    step, want, kernels = small
     # `flash` differs from `full` only where the kernels name their results
     impl = "flash" if policy == "flash" else "dense"
+    if kernels and impl == "flash":  # bfloat16: against the same kernels
+        want = step(attention_impl=impl)
     got = step(remat_policy=policy, attention_impl=impl)
     np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
     for a, b in zip(jax.tree_util.tree_leaves(got[1]),
@@ -375,6 +417,7 @@ def test_the_streams_counters_reach_fits_records():
     for record in result.history:
         assert 0 < record["hc_sinkhorn_err"] < 0.5  # three iterations
         assert 0.3 < record["hc_res_diag_mean"] < 0.8
+        assert record["hc_kernel_sublayers"] == 0  # the CPU: XLA's code
         assert record["moe_tokens_held"] > 0
 
 
@@ -382,7 +425,7 @@ def test_the_maps_product_in_one_pass_is_the_full_precision_product():
     """bfloat16 streams times float32 `phi`: `_exact_product` against the
     float32 product at `highest`, forward and both gradients (x's lands in
     bfloat16, so it is held to bfloat16's rounding)."""
-    from kubeflow_tpu.models.transformer import _exact_product
+    from kubeflow_tpu.ops.streams import exact_product as _exact_product
 
     b, s, k, c = 2, 64, 512, 24
     keys = jax.random.split(jax.random.PRNGKey(0), 3)
