@@ -35,6 +35,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from kubeflow_tpu.ops import streams as streams_ops
 from kubeflow_tpu.ops.attention import attend
 from kubeflow_tpu.ops.flash import CHECKPOINT_LSE_NAME, CHECKPOINT_OUT_NAME
+from kubeflow_tpu.ops.kda import (
+    CHECKPOINT_OUT_NAME as KDA_OUT_NAME,
+    CHECKPOINT_STATES_NAME as KDA_STATES_NAME,
+    kda_scan,
+)
 from kubeflow_tpu.ops.moe import (
     BLOCK_ROWS, expert_mlp_on_mesh, row_tiles, tiles_in_use,
 )
@@ -50,20 +55,24 @@ from kubeflow_tpu.utils import memory
 
 @dataclasses.dataclass(frozen=True)
 class AttentionKind:
-    """One kind of attention layer of a stack that mixes them
+    """One kind of mixer layer of a stack that mixes them
     (`TransformerConfig.attention_kinds`): its query heads (over the
     stack's `n_kv_heads` K/V heads of `head_dim`), its window (None: every
     earlier key; W: the last W keys, the query's own among them), and its
-    rope: `rope_theta` over the first `rope_fraction` of a head, plain or,
+    rope: `rope_theta` over the first `rope_fraction` of a head (0: no
+    turn, of the latent pair's rope part either), plain or,
     with `rope_yarn` = (factor, original_max, beta_fast, beta_slow,
     attention_factor), yarn's blended frequencies with cos and sin times
-    the attention factor (`ops/rope.yarn_inv_freq`)."""
+    the attention factor (`ops/rope.yarn_inv_freq`). `mixer` "delta": the
+    layer's mixer is no attention but the gated delta rule over `n_heads`
+    heads of `head_dim` (`DeltaMixer`), which knows no window and no rope."""
 
     n_heads: int
     window: int | None = None
     rope_theta: float = 10_000.0
     rope_fraction: float = 1.0
     rope_yarn: tuple[float, int, float, float, float] | None = None
+    mixer: str = "attention"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -155,6 +164,8 @@ class TransformerConfig:
     # heads of each of `ssm_groups` groups, a causal depthwise convolution
     # of `ssm_conv` taps, the scan in chunks of `ssm_chunk` positions.
     # `ssm_dt` = (min, max, floor) of the time steps the bias is drawn for.
+    # The last three are the delta-rule mixer's too (`DeltaMixer`: a layer
+    # whose `AttentionKind.mixer` is "delta"); its heads are its row's.
     ssm_heads: int = 0
     ssm_head_dim: int = 64
     ssm_state: int = 128
@@ -176,10 +187,12 @@ class TransformerConfig:
     # `dense_d_ff`, in a stack whose other layers have experts.
     dense_layers: int = 0
     dense_d_ff: int = 0
-    # Latent attention (DeepSeek-V2's MLA; `kv_latent` > 0 turns it on): q
-    # comes through a bottleneck of `q_latent` with a norm in it, k and v
-    # through one of `kv_latent`; a head's q and k are `head_dim` dims of
-    # their own plus `rope_head_dim` that rope turns, and the rope part of
+    # Latent attention (DeepSeek-V2's MLA; `kv_latent` > 0 turns it on, in
+    # every attention layer of the stack): q comes through a bottleneck of
+    # `q_latent` with a norm in it (0: one matrix from the layer's input,
+    # no norm), k and v through one of `kv_latent`; a head's q and k are
+    # `head_dim` dims of their own plus `rope_head_dim` that rope turns (or,
+    # at a `rope_fraction` of 0, does not), and the rope part of
     # k is ONE key for all heads, projected beside the latent; v is
     # `v_head_dim` wide (0: `head_dim`; the flash kernels want them equal).
     # The layer's `AttentionKind` gives the rope (yarn too) over the whole
@@ -240,17 +253,34 @@ def _attention_kinds(cfg: TransformerConfig) -> list[AttentionKind]:
                 f"a window of {kind.window} key(s): it counts the query's "
                 "own position, so it is at least 1"
             )
+        if kind.mixer not in ("attention", "delta") or (
+            kind.mixer == "delta" and (
+                kind.window is not None or cfg.layer_pattern is not None
+                or cfg.ssm_chunk & (cfg.ssm_chunk - 1)
+            )
+        ):
+            raise ValueError(
+                f"mixer {kind.mixer!r} with a window of {kind.window} in "
+                f"chunks of {cfg.ssm_chunk}: expected 'attention' or, in a "
+                "stack of blocks, 'delta' (no window, chunks a power of two)"
+            )
     if cfg.kv_latent or cfg.q_latent or cfg.rope_head_dim:
+        attention = [k for k in kinds if k.mixer == "attention"]
         if (
-            min(cfg.kv_latent, cfg.q_latent, cfg.rope_head_dim) < 1
+            min(cfg.kv_latent, cfg.rope_head_dim) < 1 or cfg.q_latent < 0
             or cfg.rope_head_dim % 2 or cfg.cca or (hk and hk != cfg.n_heads)
-            or any(k.window is not None or k.n_heads != cfg.n_heads for k in kinds)
+            or any(
+                k.window is not None or k.n_heads != cfg.n_heads
+                for k in attention
+            )
         ):
             raise ValueError(
                 f"latent attention with ranks {cfg.q_latent} / "
                 f"{cfg.kv_latent} and a rope part of {cfg.rope_head_dim}: it "
-                "takes both ranks, an even rope part, equal heads "
-                f"({cfg.n_heads} over {hk}), no window and no CCA"
+                "takes a K/V rank (a q rank of 0 projects q directly), an "
+                "even part beside the head's own (turned or, at a "
+                "rope_fraction of 0, not), equal heads in every attention "
+                f"row ({cfg.n_heads} over {hk}), no window and no CCA"
             )
     if cfg.residual_streams < 0 or cfg.hc_iters < 1 or (
         cfg.residual_streams and cfg.layer_pattern is not None
@@ -271,11 +301,13 @@ def _attention_kinds(cfg: TransformerConfig) -> list[AttentionKind]:
     return [kinds[k] for k in pattern]
 
 
-# What `remat_policy="flash"` always keeps: the results of the attention
-# and scan kernels, which name them themselves (`ops/flash.py`,
-# `ops/ssd.py`), so that no forward kernel runs again in the backward.
+# What `remat_policy="flash"` always keeps: the results of the attention,
+# scan and delta-rule kernels, which name them themselves (`ops/flash.py`,
+# `ops/ssd.py`, `ops/kda.py`), so that no forward kernel runs again in the
+# backward.
 KERNEL_RESULTS = (
     CHECKPOINT_OUT_NAME, CHECKPOINT_LSE_NAME, SSD_OUT_NAME, SSD_STATES_NAME,
+    KDA_OUT_NAME, KDA_STATES_NAME,
 )
 # Results the layers name where they form them (`checkpoint_name`: a name
 # lowers to no operation), for `remat_plan` to keep as many of as the
@@ -303,6 +335,9 @@ ATTN_LATENT_RESULT = "attn_latent"  # latent attention's down-projections
 STREAM_OUT_RESULT = "hc_out"      # a sublayer's output under residual streams
 HC_RESULT = streams_ops.CHECKPOINT_MAPS_NAME  # the residual streams' maps'
                                   # raw products and the norm's scalar
+KDA_PROJ_RESULT = "kda_proj"      # the delta mixer's q, k and v projections
+KDA_CONV_RESULT = "kda_conv"      # their convolutions' results, under silu
+KDA_DECAY_RESULT = "kda_decay"    # the decay's product, under softplus
 # The order they are admitted in: milliseconds of the backward's second
 # forward spared a GB held, the small ones first. Timed on the v5e in the
 # benchmark's three `flash` cells: `r` of the `[scopes]` line of a traced
@@ -331,11 +366,28 @@ HC_RESULT = streams_ops.CHECKPOINT_MAPS_NAME  # the residual streams' maps'
 #                  has that output in its gradient, so the output
 #                  projections (`attn/wo`, `mlp/wo`, the shared expert's, the
 #                  experts' combine) run again for it alone: ~22 / 0.59
+#   kda_proj       kimi: three products of K = 2,304 onto 4,096 lanes that
+#                  the convolutions' weight gradients read whatever else is
+#                  kept: see PERF.md §6, PR 41 for the cell's reading with
+#                  and without the three `kda_*` names
+#   kda_conv       kimi: the convolutions' results as `silu` reads them
+#                  (bfloat16), so neither the taps nor the float32 sums run
+#                  again; after `kda_proj`, which its input is
+#   kda_decay      kimi: two thin products (K = 2,304 then 128) spared for a
+#                  [tokens, 4,096] array: the dearest to hold, so the last
 SAVED_RESULTS = (
     HC_RESULT, GATE_RESULT, ROUTE_RESULT, RESIDUAL_RESULT, LATENT_RESULT,
-    IN_PROJ_RESULT, HIDDEN_RESULT, ATTN_LATENT_RESULT, STREAM_OUT_RESULT,
-    QKV_RESULT, CONV_RESULT,
+    IN_PROJ_RESULT, KDA_PROJ_RESULT, HIDDEN_RESULT, ATTN_LATENT_RESULT,
+    STREAM_OUT_RESULT, QKV_RESULT, CONV_RESULT, KDA_CONV_RESULT,
+    KDA_DECAY_RESULT,
 )
+# What a delta-rule layer's second forward and backward hold at once beyond
+# the named results, in [tokens, heads x head_dim] float32 arrays: the log
+# decay, its cumulative sum and the gradient of each, the convolutions'
+# float32 sums and what `silu` and the norms make of them, q, k, v and
+# their gradients (held so that the peak stays an upper bound of the chip's
+# compiler's figure for the kimi cell's step, 13.69 GB against 14.27, PR 41).
+KDA_WORK_ARRAYS = 12
 
 
 def _block_cls(cfg: "TransformerConfig", cls=None, keep: tuple[str, ...] = ()):
@@ -413,7 +465,8 @@ def _result_bytes(cfg: "TransformerConfig", tokens: int) -> list[dict]:
                 + _lanes(h * (cfg.v_head_dim or cfg.head_dim))
             )
             out[ATTN_LATENT_RESULT] = tokens * act * (
-                _lanes(cfg.q_latent) + _lanes(cfg.kv_latent + r)
+                (cfg.q_latent and _lanes(cfg.q_latent))
+                + _lanes(cfg.kv_latent + r)
             )
         if cfg.attention_gate:
             out[GATE_RESULT] = tokens * _lanes(kind.n_heads) * 4
@@ -435,6 +488,13 @@ def _result_bytes(cfg: "TransformerConfig", tokens: int) -> list[dict]:
         if cfg.moe_shared_ff:
             mlp(out, cfg.moe_shared_ff)
 
+    def delta(out: dict, kind: AttentionKind):
+        wide = tokens * _lanes(kind.n_heads * cfg.head_dim)
+        out[KDA_PROJ_RESULT] = out[KDA_CONV_RESULT] = 3 * wide * act
+        out[KDA_DECAY_RESULT] = wide * act
+        # never a candidate (no name): alive where the layer is formed again
+        out["kda_work"] = KDA_WORK_ARRAYS * wide * 4
+
     def mixer(out: dict):
         d_in = cfg.ssm_heads * cfg.ssm_head_dim
         xbc = d_in + 2 * cfg.ssm_groups * cfg.ssm_state
@@ -450,7 +510,7 @@ def _result_bytes(cfg: "TransformerConfig", tokens: int) -> list[dict]:
                 # float32, round both sublayers
                 out[HC_RESULT] = 2 * tokens * (n * n + 2 * n + 1) * 4
                 out[STREAM_OUT_RESULT] = 2 * tokens * _lanes(cfg.d_model) * act
-            attention(out, kind)
+            (delta if kind.mixer == "delta" else attention)(out, kind)
             if cfg.num_experts > 0 and i >= cfg.dense_layers:
                 experts(out)
             else:
@@ -484,14 +544,17 @@ def _stream_lanes(cfg: "TransformerConfig") -> int:
 def _kept_always_bytes(cfg: "TransformerConfig", tokens: int) -> int:
     """Bytes every layer's checkpoint holds whatever the plan:
     its inputs (the residual stream; the router's carried state) and
-    `KERNEL_RESULTS` (attention's output and log-sum-exp, the scan's
-    output and chunk states)."""
+    `KERNEL_RESULTS` (attention's output and log-sum-exp, the scan's or
+    the delta rule's output and chunk states)."""
     act = jnp.dtype(cfg.dtype).itemsize
     stream = tokens * _stream_lanes(cfg) * act
     if cfg.num_experts > 0 and cfg.router == "mlp":
         stream += tokens * _lanes(cfg.router_hidden) * 4
 
     def attention(kind: AttentionKind) -> int:
+        if kind.mixer == "delta":  # o, and a [d, H·d] state a chunk
+            wide = _lanes(kind.n_heads * cfg.head_dim) * act
+            return (tokens + -(-tokens // cfg.ssm_chunk) * cfg.head_dim) * wide
         width = (cfg.kv_latent and cfg.v_head_dim) or cfg.head_dim
         return tokens * (
             _lanes(kind.n_heads * width) * act + _lanes(kind.n_heads) * 4
@@ -667,6 +730,14 @@ def _shift(x, steps: int):
     return jnp.pad(x, pad)[:, : x.shape[1]]
 
 
+def _causal_conv(x, w):
+    """A causal depthwise convolution along the sequence of x [B, S, W] by
+    w [taps, W]: tap j multiplies the value j tokens back. float32 out.
+    The state-space and the delta-rule mixers' short convolution."""
+    x = x.astype(jnp.float32)
+    return sum(w[j] * _shift(x, j) for j in range(w.shape[0]))
+
+
 def _replicated(init, rank: int):
     return nn.with_logical_partitioning(init, (None,) * rank)
 
@@ -814,20 +885,24 @@ class Attention(nn.Module):
     def _latent_qkv(self, x, positions, kind: AttentionKind):
         """Latent attention's operands (DeepSeek-V2's equations, whose
         keys the configuration carries): `c_q = norm(x wq_a)`, a head's
-        `[q | q_rope] = c_q wq_b`; `[c | k_rope] = x wkv_a`, `c_kv =
-        norm(c)`, a head's `[k | v] = c_kv wkv_b`; rope turns `q_rope` and
-        the ONE `k_rope` all heads share. -> q, k [B, S, H·D], v
-        [B, S, H·Dv], q_rope [B, S, H·R], k_rope [B, S, R]."""
+        `[q | q_rope] = c_q wq_b` (with no q rank, `x wq` directly);
+        `[c | k_rope] = x wkv_a`, `c_kv = norm(c)`, a head's `[k | v] =
+        c_kv wkv_b`; rope turns `q_rope` and the ONE `k_rope` all heads
+        share, unless the layer's kind has a `rope_fraction` of 0. -> q, k
+        [B, S, H·D], v [B, S, H·Dv], q_rope [B, S, H·R], k_rope [B, S, R]."""
         cfg = self.config
         h, d, r = kind.n_heads, cfg.head_dim, cfg.rope_head_dim
         norm = functools.partial(RMSNorm, cfg.dtype, cfg.norm_eps)
         # The products are what is named: a norm's backward reads its input.
         named = lambda u: checkpoint_name(u, ATTN_LATENT_RESULT)
         with jax.named_scope("attn.latent_q"):
-            c_q = norm(name="q_norm")(named(_dense(
-                cfg.q_latent, ("embed", None), "wq_a", cfg.dtype
-            )(x)))
-            q, q_rope = self._by_parts(c_q, "wq_b", h, (d, r))
+            if cfg.q_latent:
+                c_q = norm(name="q_norm")(named(_dense(
+                    cfg.q_latent, ("embed", None), "wq_a", cfg.dtype
+                )(x)))
+                q, q_rope = self._by_parts(c_q, "wq_b", h, (d, r))
+            else:
+                q, q_rope = self._by_parts(x, "wq", h, (d, r))
         with jax.named_scope("attn.latent_kv"):
             joint = named(_dense(
                 cfg.kv_latent + r, ("embed", None), "wkv_a", cfg.dtype
@@ -837,6 +912,8 @@ class Attention(nn.Module):
             k, v = self._by_parts(
                 c_kv, "wkv_b", h, (d, cfg.v_head_dim or d)
             )
+        if kind.rope_fraction == 0:  # the pair's second part, not turned
+            return q, k, v, q_rope, k_rope
         how = {}
         if kind.rope_yarn is not None:
             factor, original, fast, slow, attention_factor = kind.rope_yarn
@@ -1250,8 +1327,7 @@ class StateSpaceMixer(nn.Module):
                 _replicated(nn.initializers.normal(taps ** -0.5), 2),
                 (taps, d_in + 2 * gn), f32,
             )
-            xbc = xbc.astype(f32)
-            mixed = sum(w[j] * _shift(xbc, j) for j in range(taps))
+            mixed = _causal_conv(xbc, w)
             xbc = checkpoint_name(nn.silu(
                 mixed + vector("conv_bias", nn.initializers.zeros, d_in + 2 * gn)
             ).astype(cfg.dtype), CONV_RESULT)
@@ -1286,6 +1362,128 @@ class StateSpaceMixer(nn.Module):
             ).astype(cfg.dtype)
         with jax.named_scope("ssm.out_proj"):
             return _dense(cfg.d_model, (None, "embed"), "out_proj", cfg.dtype)(y)
+
+
+class DeltaMixer(nn.Module):
+    """Kimi Delta Attention's mixer over x [B, S, d_model], from the
+    configuration's numbers and the layer's row: H = `kind.n_heads` heads of
+    d = `head_dim` key and value channels, D = H d.
+
+    `q~ = x wq`, `k~ = x wk`, `v~ = x wv` (no bias); each through a causal
+    depthwise convolution of `ssm_conv` taps (no bias) and `silu`; a
+    head's `q = d^-1/2 q^ / |q^|`, `k = k^ / |k^|`; the decay a channel `g
+    = -exp(A_log[h]) softplus((x wf_a) wf_b + dt_bias)`, the low rank d; `b
+    = sigmoid(x wb)` a head, float32 at full precision; the delta rule
+    (`ops/kda.kda_scan`: `S_t = (I - b_t k_t k_t^T) diag(e^(g_t)) S_(t-1) +
+    b_t k_t v_t^T`, `o_t = S_t^T q_t`); `y = RMSNorm_head(o) w_n
+    sigmoid((x wg_a) wg_b)`, the norm over each head's d with ONE learned
+    scale [d]; `y wo`. q, k, v, g and o stay [B, S, H·d], as the kernels
+    read them. Sows `kda_decay_mean` (the mean of `e^g`: the share of the
+    state that survives a token) and `kda_beta_mean`, each over the count
+    of the stack's delta layers so that the step's sum is a mean."""
+
+    config: TransformerConfig
+    mesh: Mesh | None = None
+    kind: AttentionKind | None = None
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.config
+        h, d, taps = self.kind.n_heads, cfg.head_dim, cfg.ssm_conv
+        wide, f32 = h * d, jnp.float32
+        vector = lambda name, init, size: self.param(
+            name, _replicated(init, 1), (size,), f32
+        )
+        # A head's sum over its d lanes and back, as products with the
+        # heads' indicator [H·d, H]: [.., H·d] -> [.., H, d] is a relayout
+        # on the TPU (a copy a pass), a product of 32 columns is not.
+        lanes_of = (
+            jnp.arange(wide)[:, None] // d == jnp.arange(h)[None]
+        ).astype(f32)
+        dot = functools.partial(jnp.dot, precision=jax.lax.Precision.HIGH)
+        over_head = lambda u: dot(u, lanes_of)
+        to_lanes = lambda r: dot(r, lanes_of.T)
+        with jax.named_scope("kda.proj"):
+            q, k, v = (
+                checkpoint_name(_dense(
+                    (h, d), ("embed", "heads", "kv"), name, cfg.dtype
+                )(x), KDA_PROJ_RESULT)
+                for name in ("wq", "wk", "wv")
+            )
+        with jax.named_scope("kda.conv"):
+            def conv(u, name):
+                w = self.param(
+                    f"conv_{name}",
+                    _replicated(nn.initializers.normal(taps ** -0.5), 2),
+                    (taps, wide), f32,
+                )
+                # What is named is what `silu`'s slope reads.
+                return nn.silu(checkpoint_name(
+                    _causal_conv(u, w).astype(cfg.dtype), KDA_CONV_RESULT
+                ).astype(f32))
+
+            def unit(u, scale):  # a head's u / |u|
+                return (u * to_lanes(scale * jax.lax.rsqrt(
+                    over_head(u * u) + cfg.norm_eps
+                ))).astype(cfg.dtype)
+
+            q, k = unit(conv(q, "q"), d ** -0.5), unit(conv(k, "k"), 1.0)
+            v = conv(v, "v").astype(cfg.dtype)
+        with jax.named_scope("kda.gates"):
+            def steps(key, shape, dtype):
+                lo, hi, floor = cfg.ssm_dt
+                drawn = jnp.exp(jax.random.uniform(
+                    key, shape, dtype, math.log(lo), math.log(hi)
+                ))
+                return _inverse_softplus(jnp.maximum(drawn, floor))
+
+            low = _dense(d, ("embed", None), "wf_a", cfg.dtype)(x)
+            decay = checkpoint_name(
+                _dense(wide, (None, "heads"), "wf_b", cfg.dtype)(low),
+                KDA_DECAY_RESULT,
+            )
+            rate = jnp.repeat(jnp.exp(vector(
+                "A_log", lambda *_: jnp.log(jnp.linspace(1.0, 16.0, h, dtype=f32)), h
+            )), d)
+            g = -rate * nn.softplus(
+                decay.astype(f32) + vector("dt_bias", steps, wide)
+            )
+            wb = self.param(
+                "wb",
+                nn.with_logical_partitioning(
+                    nn.initializers.variance_scaling(1.0, "fan_in", "normal"),
+                    ("embed", "heads"),
+                ),
+                (x.shape[-1], h), f32,
+            )
+            beta = jax.nn.sigmoid(jnp.dot(
+                x.astype(f32), wb, precision=jax.lax.Precision.HIGHEST
+            ))
+            layers = sum(
+                kind.mixer == "delta" for kind in _attention_kinds(cfg)
+            )
+            for name, value in (
+                ("kda_decay_mean", jnp.mean(jnp.exp(g))),
+                ("kda_beta_mean", jnp.mean(beta)),
+            ):
+                self.sow(
+                    "counters", name, value / layers,
+                    reduce_fn=lambda _, new: new, init_fn=lambda: 0.0,
+                )
+        with jax.named_scope("kda.scan"):
+            o = kda_scan(q, k, v, g, beta, chunk=cfg.ssm_chunk, mesh=self.mesh)
+        with jax.named_scope("kda.gate_norm"):
+            low = _dense(d, ("embed", None), "wg_a", cfg.dtype)(x)
+            gate = jax.nn.sigmoid(
+                _dense(wide, (None, "heads"), "wg_b", cfg.dtype)(low).astype(f32)
+            )
+            o = o.astype(f32)
+            o = o * to_lanes(jax.lax.rsqrt(
+                over_head(o * o) / d + cfg.norm_eps
+            )) * jnp.tile(vector("norm_scale", nn.initializers.ones, d), h)
+            y = (o * gate).astype(cfg.dtype)
+        with jax.named_scope("kda.out_proj"):
+            return _dense(cfg.d_model, ("heads", "embed"), "wo", cfg.dtype)(y)
 
 
 class StreamMaps(nn.Module):
@@ -1370,7 +1568,9 @@ def _stream_maps(cfg: "TransformerConfig") -> streams_ops.Maps:
 
 
 class Block(nn.Module):
-    """One layer: attention, then the dense MLP or the expert layer.
+    """One layer: its mixer (attention or, where the layer's row of
+    `attention_kinds` says "delta", `DeltaMixer`), then the dense MLP or
+    the expert layer.
     Takes and returns the router's carried state beside the residual
     (None where there are no experts). `layer` is its place in the stack,
     which only `router_force_balance` reads. With `residual_streams` the
@@ -1424,8 +1624,11 @@ class Block(nn.Module):
         )
 
         def attention(x):
+            h = norm(name="ln_attn")(x)
+            if self.attention is not None and self.attention.mixer == "delta":
+                return DeltaMixer(cfg, self.mesh, self.attention, name="kda")(h)
             return Attention(cfg, self.mesh, self.attention, name="attn")(
-                norm(name="ln_attn")(x), positions
+                h, positions
             )
 
         def feed_forward(x, router_state):

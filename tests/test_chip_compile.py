@@ -458,6 +458,31 @@ def test_scan_kernels_compile_at_the_nemotron_cell_shape(one_chip):
     assert tpu_kernel_calls(text) == 2
 
 
+def test_delta_rule_kernels_compile_at_the_kimi_cell_shape(one_chip):
+    """The chunked gated delta rule, forward and backward, at the cell's
+    size: one sequence of 8,192, 32 heads of 128, chunks of 64, 8 heads a
+    program (`ops/kda.py`): b's blocks [C, heads] and [heads, C] are whole
+    arrays' last two dimensions, and both kernels fit the scoped VMEM."""
+    from kubeflow_tpu.ops import kda
+
+    shape = lambda dims, dtype: jax.ShapeDtypeStruct(
+        dims, dtype, sharding=one_chip
+    )
+    x = shape((1, 8192, 32 * 128), jnp.bfloat16)
+
+    def loss(q, k, v, g, b):
+        o = kda.kda_scan(q, k, v, g, b, chunk=64, interpret=False)
+        return o.astype(jnp.float32).sum()
+
+    text, names = _compile(
+        jax.grad(loss, argnums=(0, 1, 2, 3, 4)), x, x, x,
+        shape((1, 8192, 32 * 128), jnp.float32),
+        shape((1, 8192, 32), jnp.float32),
+    )
+    assert names == ["kda_fwd", "kda_bwd"]
+    assert tpu_kernel_calls(text) == 2
+
+
 def test_sub_1024_blocks_select_the_replicated_lse_and_compile(one_chip):
     """A packed lse block below 1024 rows is (1, bq/128 < 8, 128): the
     lowering refuses it, so such sizes must select the replicated

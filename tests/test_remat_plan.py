@@ -100,6 +100,19 @@ CELL_PLANS = {
         },
         (), 16_144_994_968,
     ),
+    "kimi-linear-48b-a3b-ep32.train-8k": (
+        6_024_344_332, 2_409_737_728,
+        # Four delta-rule layers: their three projections, the
+        # convolutions' results under silu, the decay's product; the one
+        # latent layer's down-projection (no q rank) and its five operands.
+        {
+            "moe_route": 83_886_080, "attn_residual": 188_743_680,
+            "kda_proj": 805_306_368, "mlp_hidden": 436_207_616,
+            "attn_latent": 10_485_760, "attn_qkv": 236_978_176,
+            "kda_conv": 805_306_368, "kda_decay": 268_435_456,
+        },
+        (), 14_267_200_268,
+    ),
     "zaya1-8b-ep2.train-8k": (
         9_223_475_372, 3_689_390_144,
         # CCA: q, k and v are no candidate (its backward forms them again).
@@ -513,6 +526,16 @@ FAMILIES = {
         kv_latent=8, rope_head_dim=8, residual_streams=4, tie_embeddings=False,
         d_model=128,
     ),
+    "blocks of delta mixers beside un-rotated latent attention": dict(
+        n_layers=2, num_experts=4, router="sigmoid", experts_per_token=2,
+        moe_shared_ff=32, dense_layers=1, dense_d_ff=96, kv_latent=8,
+        rope_head_dim=8, tie_embeddings=False, ssm_chunk=8,
+        attention_kinds=(
+            transformer.AttentionKind(2, mixer="delta"),
+            transformer.AttentionKind(2, rope_fraction=0.0),
+        ),
+        attention_pattern=(0, 1),
+    ),
     "a pattern of mixers, latent relu2 experts and attention": dict(
         n_layers=3, layer_pattern="ME*", num_experts=4, router="sigmoid",
         experts_per_token=2, moe_latent=16, moe_shared_ff=48, mlp_act="relu2",
@@ -555,6 +578,30 @@ def test_the_plans_bytes_are_those_of_the_results_the_layers_name(
             )
     assert named == dict(remat_plan(cfg, shape[0] * shape[1], ROOMY).bytes)
     assert set(KERNEL_RESULTS).isdisjoint(SAVED_RESULTS)
+
+
+def test_a_delta_rule_layer_keeps_its_kernels_results_and_counts_its_work():
+    """`kda_out` and `kda_states` are kept whatever the plan (o, and a
+    [d, H·d] state a chunk); the layer's second forward holds
+    `KDA_WORK_ARRAYS` float32 arrays beside the named results, under no
+    name, so no plan admits them."""
+    from kubeflow_tpu.ops import kda
+
+    assert {kda.CHECKPOINT_OUT_NAME, kda.CHECKPOINT_STATES_NAME} <= set(KERNEL_RESULTS)
+    cfg, tokens, _ = _cell("kimi-linear-48b-a3b-ep32.train-8k")
+    layers = transformer._result_bytes(cfg, tokens)
+    wide = tokens * 4096
+    assert [("kda_proj" in layer, "attn_qkv" in layer) for layer in layers] == [
+        (True, False), (True, False), (True, False), (False, True), (True, False)
+    ]
+    assert layers[0]["kda_work"] == transformer.KDA_WORK_ARRAYS * wide * 4
+    assert "kda_work" not in SAVED_RESULTS and "mlp_hidden" in layers[0]
+    sched = kda.kda_schedule(8192, heads=32, head_dim=128, chunk=cfg.ssm_chunk)
+    stream = tokens * 2304 * 2
+    latent = tokens * (4096 * 2 + 128 * 4)  # o and a log-sum-exp a head
+    assert transformer._kept_always_bytes(cfg, tokens) == (
+        5 * stream + 4 * sched["saved_bytes_a_call"] + latent
+    )
 
 
 def test_fit_records_how_far_the_plan_engaged(monkeypatch):

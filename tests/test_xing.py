@@ -375,7 +375,7 @@ def test_the_kernels_are_the_two_part_calls(seeded):
 
 
 @pytest.mark.parametrize("change, message", [
-    (dict(q_latent=0), "latent attention with ranks 0 / 8"),
+    (dict(q_latent=-1), "latent attention with ranks -1 / 8"),
     (dict(rope_head_dim=7), "a rope part of 7"),
     (dict(n_kv_heads=1), "equal heads"),
     (dict(cca=True), "no CCA"),
