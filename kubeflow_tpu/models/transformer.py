@@ -32,6 +32,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from kubeflow_tpu.ops import shortconv
 from kubeflow_tpu.ops import streams as streams_ops
 from kubeflow_tpu.ops.attention import attend
 from kubeflow_tpu.ops.flash import CHECKPOINT_LSE_NAME, CHECKPOINT_OUT_NAME
@@ -383,11 +384,15 @@ SAVED_RESULTS = (
 )
 # What a delta-rule layer's second forward and backward hold at once beyond
 # the named results, in [tokens, heads x head_dim] float32 arrays: the log
-# decay, its cumulative sum and the gradient of each, the convolutions'
-# float32 sums and what `silu` and the norms make of them, q, k, v and
-# their gradients (held so that the peak stays an upper bound of the chip's
-# compiler's figure for the kimi cell's step, 13.69 GB against 14.27, PR 41).
-KDA_WORK_ARRAYS = 12
+# decay and its gradient, the gated norm's o and gate and the gradient of
+# each (held so that the peak stays an upper bound of the chip's compiler's
+# figure for the kimi cell's step: 12.84 GB against 13.46). The
+# convolutions' float32 sums and what `silu` and the norms made of them
+# were six more (13.69 against 14.27, PR 41) until they stayed in VMEM
+# (`ops/shortconv.py`, PR 42): where the plain convolutions run instead
+# (float32 streams, a mesh of several devices) a layer holds those six
+# more than is counted here; no cell runs `flash` there (PERF.md §7).
+KDA_WORK_ARRAYS = 6
 
 
 def _block_cls(cfg: "TransformerConfig", cls=None, keep: tuple[str, ...] = ()):
@@ -1327,10 +1332,12 @@ class StateSpaceMixer(nn.Module):
                 _replicated(nn.initializers.normal(taps ** -0.5), 2),
                 (taps, d_in + 2 * gn), f32,
             )
-            mixed = _causal_conv(xbc, w)
-            xbc = checkpoint_name(nn.silu(
-                mixed + vector("conv_bias", nn.initializers.zeros, d_in + 2 * gn)
-            ).astype(cfg.dtype), CONV_RESULT)
+            bias = vector("conv_bias", nn.initializers.zeros, d_in + 2 * gn)
+            if shortconv.kernels_apply(xbc, taps, 0, self.mesh):
+                xbc = shortconv.short_conv(xbc, w, bias)
+            else:
+                xbc = nn.silu(_causal_conv(xbc, w) + bias).astype(cfg.dtype)
+            xbc = checkpoint_name(xbc, CONV_RESULT)
         x, b, c = jnp.split(xbc, [d_in, d_in + gn], axis=-1)
 
         def steps(key, shape, dtype):
@@ -1411,24 +1418,31 @@ class DeltaMixer(nn.Module):
                 for name in ("wq", "wk", "wv")
             )
         with jax.named_scope("kda.conv"):
-            def conv(u, name):
+            kernels = shortconv.kernels_apply(q, taps, d, self.mesh)
+
+            def conv(u, name, scale=None):  # with a scale: a head's u / |u|
                 w = self.param(
                     f"conv_{name}",
                     _replicated(nn.initializers.normal(taps ** -0.5), 2),
                     (taps, wide), f32,
                 )
+                if kernels:
+                    # What is named is what the delta rule's backward reads.
+                    return checkpoint_name(shortconv.short_conv(
+                        u, w, sum_dtype=cfg.dtype, head_dim=d if scale else 0,
+                        scale=scale or 1.0, eps=cfg.norm_eps,
+                    ), KDA_CONV_RESULT)
                 # What is named is what `silu`'s slope reads.
-                return nn.silu(checkpoint_name(
+                u = nn.silu(checkpoint_name(
                     _causal_conv(u, w).astype(cfg.dtype), KDA_CONV_RESULT
                 ).astype(f32))
+                if scale:
+                    u = u * to_lanes(scale * jax.lax.rsqrt(
+                        over_head(u * u) + cfg.norm_eps
+                    ))
+                return u.astype(cfg.dtype)
 
-            def unit(u, scale):  # a head's u / |u|
-                return (u * to_lanes(scale * jax.lax.rsqrt(
-                    over_head(u * u) + cfg.norm_eps
-                ))).astype(cfg.dtype)
-
-            q, k = unit(conv(q, "q"), d ** -0.5), unit(conv(k, "k"), 1.0)
-            v = conv(v, "v").astype(cfg.dtype)
+            q, k, v = conv(q, "q", d ** -0.5), conv(k, "k", 1.0), conv(v, "v")
         with jax.named_scope("kda.gates"):
             def steps(key, shape, dtype):
                 lo, hi, floor = cfg.ssm_dt

@@ -483,6 +483,43 @@ def test_delta_rule_kernels_compile_at_the_kimi_cell_shape(one_chip):
     assert tpu_kernel_calls(text) == 2
 
 
+@pytest.mark.parametrize("width, how", [
+    (32 * 128, dict(sum_dtype=jnp.bfloat16, head_dim=128, scale=128 ** -0.5)),
+    (32 * 128, dict(sum_dtype=jnp.bfloat16)),
+    (4096 + 2 * 4 * 128, dict(bias=True)),
+], ids=["kimi q and k", "kimi v", "nemotron xBC"])
+def test_short_convolution_kernels_compile_at_the_cells_shapes(
+    one_chip, width, how
+):
+    """The recurrent mixers' convolution, `silu` and a head's norm, forward
+    and backward, at one sequence of 8,192 (`ops/shortconv.py`): blocks of
+    2,048 rows one lane tile wide with halos of 16 rows, the float32
+    scratch read at rows that are no multiple of eight (whole rows only:
+    the chip's compiler refuses such a read of a lane slice), inside the
+    default scoped VMEM."""
+    from kubeflow_tpu.ops import shortconv
+
+    shape = lambda dims, dtype: jax.ShapeDtypeStruct(
+        dims, dtype, sharding=one_chip
+    )
+    how = dict(how)
+    bias = shape((width,), jnp.float32) if how.pop("bias", False) else None
+
+    def loss(u, w, bias):
+        y = shortconv.short_conv(u, w, bias, eps=1e-5, interpret=False, **how)
+        return y.astype(jnp.float32).sum()
+
+    text, names = _compile(
+        # with the value: the backward reads u and the cotangent alone,
+        # so a gradient by itself holds no forward call at all
+        jax.value_and_grad(loss, argnums=(0, 1)),
+        shape((1, 8192, width), jnp.bfloat16), shape((4, width), jnp.float32),
+        bias,
+    )
+    assert names == ["shortconv_fwd", "shortconv_bwd"]
+    assert tpu_kernel_calls(text) == 2
+
+
 def test_sub_1024_blocks_select_the_replicated_lse_and_compile(one_chip):
     """A packed lse block below 1024 rows is (1, bq/128 < 8, 128): the
     lowering refuses it, so such sizes must select the replicated

@@ -8,6 +8,7 @@ convolutions of 4 taps, chunks of 16, 8 experts two a token, layers KDA
 (dense), KDA, MLA."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -278,3 +279,90 @@ def test_the_mixers_counters_are_means_over_its_layers():
     )
     assert 0.5 < total("kda_decay_mean") < 1.0
     assert 0.2 < total("kda_beta_mean") < 0.8
+
+
+# -- the convolutions, `silu` and a head's norms as kernels ---------------------
+
+WIDE = dict(
+    NUMBERS, num_hidden_layers=2, router_force_balance=True,
+    qk_nope_head_dim=128, v_head_dim=128,
+    linear_attn_config=dict(
+        NUMBERS["linear_attn_config"], head_dim=128, kda_layers=[1],
+        full_attn_layers=[2],
+    ),
+)
+WIDE_SHAPE = (2, 128)  # heads of one lane tile, a sequence of one row block
+
+
+def _wide_loss_and_gradient(policy, kernels: bool, dtype=jnp.bfloat16):
+    """Loss and gradient of a delta layer and a latent one at heads of 128
+    lanes, the convolutions as XLA's passes or as `shortconv_*` (which the
+    CPU is told compile, and interprets)."""
+    from kubeflow_tpu.ops import shortconv
+
+    key = jax.random.PRNGKey(5)
+    numbers, flat = _held(WIDE, key, 0, 4)
+    params = train_kda.to_program_tree(flat)
+    tokens = jax.random.randint(
+        jax.random.PRNGKey(6), (WIDE_SHAPE[0], WIDE_SHAPE[1] + 1), 0, 64
+    )
+    loss = _program_loss(_config(numbers, remat_policy=policy, dtype=dtype))
+    patch = pytest.MonkeyPatch()
+    try:
+        if kernels:
+            patch.setattr(shortconv, "kernels_apply", functools.partial(
+                shortconv.kernels_apply, compiled=True
+            ))
+        # The CPU has no bfloat16 x bfloat16 = float32 product of the plain
+        # delta rule's shapes: the rule itself in float32, either way.
+        from kubeflow_tpu.ops import kda
+
+        wide = lambda u: u.astype(jnp.float32)
+        patch.setattr(
+            "kubeflow_tpu.models.transformer.kda_scan",
+            lambda q, k, v, *a, **kw: kda.kda_scan(
+                wide(q), wide(k), wide(v), *a, **kw
+            ).astype(q.dtype),
+        )
+        args = (params, tokens[:, :-1], tokens[:, 1:])
+        names = jaxpr_kernel_names(jax.make_jaxpr(jax.grad(loss))(*args).jaxpr)
+        return names, jax.jit(jax.value_and_grad(loss))(*args)
+    finally:
+        patch.undo()
+
+
+@functools.cache
+def _wide_plain(policy):
+    return _wide_loss_and_gradient(policy, kernels=False)
+
+
+@pytest.mark.parametrize("policy", ["none", "full", "mlp", "flash"])
+def test_the_convolutions_as_kernels_give_the_plain_paths_loss_and_gradient(
+    policy,
+):
+    """Under every remat policy: three `shortconv_fwd` (q, k, v) and three
+    `shortconv_bwd` a delta layer, the forward's three run again where a
+    checkpoint keeps nothing of them ("full", and "flash" with no limit
+    known: the CPU's plan admits no name); the loss and every gradient
+    leaf are the plain expression's under the same policy within
+    bfloat16's rounding."""
+    plain_names, want = _wide_plain(policy)
+    assert not [n for n in plain_names if n.startswith("shortconv")]
+    names, got = _wide_loss_and_gradient(policy, kernels=True)
+    again = 3 if policy in ("full", "flash") else 0
+    assert names.count("shortconv_fwd") == 3 + again
+    assert names.count("shortconv_bwd") == 3
+    np.testing.assert_allclose(got[0], want[0], rtol=2e-3)
+    for a, b in zip(jax.tree_util.tree_leaves(got[1]),
+                    jax.tree_util.tree_leaves(want[1])):
+        gap = np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-6)
+        # the decay's leaves carry their own noise (PERF.md §4)
+        assert gap < 5e-2, gap
+
+
+def test_float32_streams_take_the_plain_convolutions_whatever_the_backend():
+    """What `shortconv.kernels_apply` refuses runs the plain expression:
+    the traced gradient is the one traced where no kernel compiles."""
+    told, _ = _wide_loss_and_gradient("none", kernels=True, dtype=jnp.float32)
+    plain, _ = _wide_loss_and_gradient("none", kernels=False, dtype=jnp.float32)
+    assert told == plain and "shortconv_fwd" not in told

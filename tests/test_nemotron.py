@@ -6,6 +6,7 @@ pattern `ME*E`, 4 state-space heads of 8 in 2 groups, 16 experts three a
 token, 4 query heads over 1 K/V head."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -558,3 +559,71 @@ def test_the_head_shares_of_attention_add_up_to_the_whole_layer(seeded):
     np.testing.assert_allclose(
         x + share(0) + share(1), want, atol=2e-5, rtol=2e-5
     )
+
+
+# -- the convolution, its bias and `silu` as kernels ------------------------------
+
+
+def _wide_mixers(policy, kernels: bool, dtype=jnp.bfloat16):
+    """Loss and gradient of two state-space mixers whose xBC is three lane
+    tiles wide (4 heads of 32, 2 groups of a state of 64) over sequences of
+    one row block, the convolution as XLA's passes or as `shortconv_*`
+    (which the CPU is told compile, and interprets)."""
+    from kubeflow_tpu.ops import shortconv
+
+    cfg = TransformerConfig(
+        vocab_size=64, d_model=32, n_layers=2, layer_pattern="MM", n_heads=4,
+        head_dim=8, d_ff=16, ssm_heads=4, ssm_head_dim=32, ssm_state=64,
+        ssm_groups=2, ssm_chunk=16, tie_embeddings=False, dtype=dtype,
+        attention_impl="dense", remat_policy=policy,
+    )
+    model = TransformerLM(cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(8), (2, 129), 0, 64)
+    params = model.init(jax.random.PRNGKey(7), tokens[:, :-1])["params"]
+    loss = _program_loss(cfg)
+    patch = pytest.MonkeyPatch()
+    try:
+        if kernels:
+            patch.setattr(shortconv, "kernels_apply", functools.partial(
+                shortconv.kernels_apply, compiled=True
+            ))
+        args = (params, tokens[:, :-1], tokens[:, 1:])
+        names = jaxpr_kernel_names(jax.make_jaxpr(jax.grad(loss))(*args).jaxpr)
+        return names, jax.jit(jax.value_and_grad(loss))(*args)
+    finally:
+        patch.undo()
+
+
+@functools.cache
+def _wide_plain(policy):
+    return _wide_mixers(policy, kernels=False)
+
+
+@pytest.mark.parametrize("policy", ["none", "full", "mlp", "flash"])
+def test_the_convolution_as_kernels_gives_the_plain_paths_loss_and_gradient(
+    policy,
+):
+    """Under every remat policy: one `shortconv_fwd` and one
+    `shortconv_bwd` a mixer, the forward's run again where a checkpoint
+    keeps nothing of it ("full", and "flash" with no limit known: the
+    CPU's plan admits no name); the loss and every gradient leaf, the
+    taps' and the bias's among them, are the plain expression's under the
+    same policy (XLA's own rounding moves with what it fuses: 4 % at one
+    leaf between "none" and "full")."""
+    plain_names, want = _wide_plain(policy)
+    assert not [n for n in plain_names if n.startswith("shortconv")]
+    names, got = _wide_mixers(policy, kernels=True)
+    again = 2 if policy in ("full", "flash") else 0
+    assert names.count("shortconv_fwd") == 2 + again
+    assert names.count("shortconv_bwd") == 2
+    np.testing.assert_allclose(got[0], want[0], rtol=2e-3)
+    for a, b in zip(jax.tree_util.tree_leaves(got[1]),
+                    jax.tree_util.tree_leaves(want[1])):
+        gap = np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-6)
+        assert gap < 1e-2, gap
+
+
+def test_float32_mixers_take_the_plain_convolution_whatever_the_backend():
+    told, _ = _wide_mixers("none", kernels=True, dtype=jnp.float32)
+    plain, _ = _wide_mixers("none", kernels=False, dtype=jnp.float32)
+    assert told == plain and "shortconv_fwd" not in told

@@ -164,6 +164,41 @@ def test_by_hand_covers_the_bodies_the_entry_calls_and_no_fused_one():
     assert not names & {"grad", "mu", "small", "sum", "turned", "wide"}
 
 
+# The recurrent mixers' short-convolution kernels as the chip's compiler
+# names them in the kimi and the nemotron cell's steps (their op_names,
+# operands cut): the forward call inside the mixer's own scope, the
+# hand-written backward under the same scope by the rule's own stack.
+SHORTCONV = '''HloModule jit_train_step, is_scheduled=true
+
+ENTRY %main (u: bf16[1,8192,4096], t: f32[8,4096]) -> bf16[1,8192,4096] {
+  %u = bf16[1,8192,4096]{2,1,0} parameter(0)
+  %t = f32[8,4096]{1,0} parameter(1)
+  %shortconv_fwd.7 = bf16[1,8192,4096]{2,1,0} custom-call(%u, %u, %t), custom_call_target="tpu_custom_call", metadata={op_name="jit(train_step)/jvp(TransformerLM)/layer_4/kda/kda.conv/jit(_fwd)/shortconv_fwd/pallas_call"}
+  %shortconv_fwd.9 = bf16[1,8192,4096]{2,1,0} custom-call(%u, %u, %t), custom_call_target="tpu_custom_call", metadata={op_name="jit(train_step)/jvp(TransformerLM)/layer_8/ssm/ssm.conv/jit(_fwd)/shortconv_fwd/pallas_call"}
+  %shortconv_bwd.14 = (bf16[1,8192,4096]{2,1,0}, f32[32,4096]{1,0}) custom-call(%u, %u, %u, %shortconv_fwd.7, %shortconv_fwd.7, %t), custom_call_target="tpu_custom_call", metadata={op_name="jit(train_step)/transpose(jvp(TransformerLM))/jvp(TransformerLM)/checkpoint/layer_4/kda/kda.conv/jit(_bwd)/shortconv_bwd/pallas_call"}
+  %shortconv_bwd.2 = (bf16[1,8192,4096]{2,1,0}, f32[40,4096]{1,0}) custom-call(%u, %u, %u, %shortconv_fwd.9, %shortconv_fwd.9, %t), custom_call_target="tpu_custom_call", metadata={op_name="jit(train_step)/transpose(jvp(TransformerLM))/jvp(TransformerLM)/checkpoint/layer_8/ssm/ssm.conv/jit(_bwd)/shortconv_bwd/pallas_call"}
+  %shortconv_fwd.11 = bf16[1,8192,4096]{2,1,0} custom-call(%u, %u, %t), custom_call_target="tpu_custom_call", metadata={op_name="jit(train_step)/transpose(jvp(TransformerLM))/jvp(TransformerLM)/checkpoint/rematted_computation/layer_4/kda/kda.conv/jit(_fwd)/shortconv_fwd/pallas_call"}
+  ROOT %du = bf16[1,8192,4096]{2,1,0} get-tuple-element(%shortconv_bwd.14), index=0
+}
+'''
+
+
+@pytest.mark.parametrize("name, want", [
+    ("shortconv_fwd.7", ("layer_4/kda/kda.conv/shortconv_fwd", "forward")),
+    ("shortconv_fwd.9", ("layer_8/ssm/ssm.conv/shortconv_fwd", "forward")),
+    ("shortconv_bwd.14", ("layer_4/kda/kda.conv/shortconv_bwd", "backward")),
+    ("shortconv_bwd.2", ("layer_8/ssm/ssm.conv/shortconv_bwd", "backward")),
+    # what a plan that keeps neither projection nor result would run again
+    ("shortconv_fwd.11", ("layer_4/kda/kda.conv/shortconv_fwd", "recompute")),
+])
+def test_the_short_convolution_kernels_sit_under_their_mixers_scopes(name, want):
+    """Kind `kernel` under `kda.conv` / `ssm.conv`, forward and backward:
+    what `kda_layer_time_pct.train`, `shortconv_time_pct.train` and the
+    `[scopes]` line read them by."""
+    scope = program_scopes(SHORTCONV, root="TransformerLM")[name]
+    assert (scope.path, scope.phase, scope.kind) == (*want, "kernel")
+
+
 @pytest.mark.parametrize("op_name, want", [
     ("jit(train_step)/jvp(TransformerLM)/layer_1/moe/moe.route/dot_general",
      ("layer_1/moe/moe.route", "forward")),

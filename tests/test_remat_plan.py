@@ -27,6 +27,7 @@ from kubeflow_tpu.models.transformer import (
     TransformerLM,
     remat_plan,
 )
+from kubeflow_tpu.ops import shortconv
 from kubeflow_tpu.ops import streams as streams_ops
 from kubeflow_tpu.parallel import MeshSpec, build_mesh
 from kubeflow_tpu.testing.hlo import _walk_eqns, jaxpr_kernel_names
@@ -111,7 +112,7 @@ CELL_PLANS = {
             "attn_latent": 10_485_760, "attn_qkv": 236_978_176,
             "kda_conv": 805_306_368, "kda_decay": 268_435_456,
         },
-        (), 14_267_200_268,
+        (), 13_461_893_900,
     ),
     "zaya1-8b-ep2.train-8k": (
         9_223_475_372, 3_689_390_144,
@@ -431,9 +432,11 @@ def _dots(jaxpr, inside: bool):
     return count
 
 
-def _grad_jaxpr(cfg, stated):
+def _grad_jaxpr(cfg, stated, shape=(2, 8)):
     model = TransformerLM(cfg)
-    tokens = jnp.arange(16, dtype=jnp.int32).reshape(2, 8) % cfg.vocab_size
+    tokens = jnp.arange(
+        shape[0] * shape[1], dtype=jnp.int32
+    ).reshape(shape) % cfg.vocab_size
     params = model.init(jax.random.PRNGKey(0), tokens)
 
     def loss(p):
@@ -536,6 +539,22 @@ FAMILIES = {
         ),
         attention_pattern=(0, 1),
     ),
+    "blocks of delta mixers as kernels beside un-rotated latent attention": dict(
+        n_layers=2, num_experts=4, router="sigmoid", experts_per_token=2,
+        moe_shared_ff=32, dense_layers=1, dense_d_ff=96, kv_latent=8,
+        rope_head_dim=8, tie_embeddings=False, ssm_chunk=8, head_dim=128,
+        attention_kinds=(
+            transformer.AttentionKind(2, mixer="delta"),
+            transformer.AttentionKind(2, rope_fraction=0.0),
+        ),
+        attention_pattern=(0, 1),
+    ),
+    "a pattern of mixers as kernels, relu2 experts and attention": dict(
+        n_layers=3, layer_pattern="ME*", num_experts=4, router="sigmoid",
+        experts_per_token=2, moe_latent=16, moe_shared_ff=48, mlp_act="relu2",
+        ssm_heads=4, ssm_head_dim=32, ssm_state=64, ssm_groups=2, ssm_chunk=8,
+        rope_fraction=0.0, tie_embeddings=False, n_kv_heads=1,
+    ),
     "a pattern of mixers, latent relu2 experts and attention": dict(
         n_layers=3, layer_pattern="ME*", num_experts=4, router="sigmoid",
         experts_per_token=2, moe_latent=16, moe_shared_ff=48, mlp_act="relu2",
@@ -552,18 +571,23 @@ def test_the_plans_bytes_are_those_of_the_results_the_layers_name(
     """What `_result_bytes` reckons from the configuration is what the
     traced forward names: every `name` equation's result, its minor
     dimension in whole lane tiles, summed by name. The streams' mixes as
-    XLA's code and as the row-block kernels (the CPU is told they
-    compile) name the same results."""
+    XLA's code and as the row-block kernels, the mixers' convolutions as
+    XLA's passes and as the `shortconv_*` pair (the CPU is told they
+    compile) name the same results: under the pair `kda_conv` sits on q,
+    k and v as the delta rule reads them, the pre-activations' bytes."""
     cfg = dataclasses.replace(SMALL, dtype=jnp.bfloat16, **FAMILIES[family])
     kernels = "as kernels" in family
     if kernels:
-        monkeypatch.setattr(streams_ops, "kernels_apply", functools.partial(
-            streams_ops.kernels_apply, compiled=True
-        ))
+        for ops in (streams_ops, shortconv):
+            monkeypatch.setattr(ops, "kernels_apply", functools.partial(
+                ops.kernels_apply, compiled=True
+            ))
     # the kernels take sequences of whole 128-row blocks
     shape = (2, 128) if kernels else (2, 8)
     forward = _forward_jaxpr(cfg, shape)
-    assert kernels == ("hc_pre_fwd" in jaxpr_kernel_names(forward))
+    assert kernels == bool(
+        {"hc_pre_fwd", "shortconv_fwd"} & set(jaxpr_kernel_names(forward))
+    )
     named: dict = {}
     for eqn in _walk_eqns(forward):
         if eqn.primitive.name == "name" and eqn.params["name"] in SAVED_RESULTS:
@@ -594,7 +618,8 @@ def test_a_delta_rule_layer_keeps_its_kernels_results_and_counts_its_work():
     assert [("kda_proj" in layer, "attn_qkv" in layer) for layer in layers] == [
         (True, False), (True, False), (True, False), (False, True), (True, False)
     ]
-    assert layers[0]["kda_work"] == transformer.KDA_WORK_ARRAYS * wide * 4
+    assert layers[0]["kda_work"] == 6 * wide * 4 == 805_306_368
+    assert layers[0]["kda_conv"] == 3 * wide * 2 == 201_326_592
     assert "kda_work" not in SAVED_RESULTS and "mlp_hidden" in layers[0]
     sched = kda.kda_schedule(8192, heads=32, head_dim=128, chunk=cfg.ssm_chunk)
     stream = tokens * 2304 * 2
@@ -602,6 +627,33 @@ def test_a_delta_rule_layer_keeps_its_kernels_results_and_counts_its_work():
     assert transformer._kept_always_bytes(cfg, tokens) == (
         5 * stream + 4 * sched["saved_bytes_a_call"] + latent
     )
+
+
+@pytest.mark.parametrize("family, calls", [
+    ("blocks of delta mixers as kernels beside un-rotated latent attention", 3),
+    ("a pattern of mixers as kernels, relu2 experts and attention", 1),
+])
+def test_a_convolution_kept_runs_no_forward_kernel_again(
+    family, calls, monkeypatch
+):
+    """`kda_proj` + `kda_conv` (`ssm_in_proj` + `ssm_conv`) kept: the
+    gradient holds the forward's `shortconv_fwd` calls (q, k and v; xBC)
+    and no second set, and one `shortconv_bwd` each, whose only reads are
+    the kept projection and the cotangent. With nothing stated the
+    checkpoint forms the convolutions again."""
+    monkeypatch.setattr(shortconv, "kernels_apply", functools.partial(
+        shortconv.kernels_apply, compiled=True
+    ))
+    cfg = dataclasses.replace(SMALL, dtype=jnp.bfloat16, **FAMILIES[family])
+    plan = remat_plan(cfg, 256, ROOMY)
+    assert plan.refused == () and (
+        {"kda_proj", "kda_conv"} <= set(plan.names)
+        or {"ssm_in_proj", "ssm_conv"} <= set(plan.names)
+    )
+    for stated, forwards in ((ROOMY, calls), (None, 2 * calls)):
+        names = jaxpr_kernel_names(_grad_jaxpr(cfg, stated, shape=(2, 128)))
+        assert names.count("shortconv_fwd") == forwards, (stated, names)
+        assert names.count("shortconv_bwd") == calls
 
 
 def test_fit_records_how_far_the_plan_engaged(monkeypatch):
