@@ -32,6 +32,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from kubeflow_tpu.ops import gatenorm
 from kubeflow_tpu.ops import shortconv
 from kubeflow_tpu.ops import streams as streams_ops
 from kubeflow_tpu.ops.attention import attend
@@ -339,6 +340,10 @@ HC_RESULT = streams_ops.CHECKPOINT_MAPS_NAME  # the residual streams' maps'
 KDA_PROJ_RESULT = "kda_proj"      # the delta mixer's q, k and v projections
 KDA_CONV_RESULT = "kda_conv"      # their convolutions' results, under silu
 KDA_DECAY_RESULT = "kda_decay"    # the decay's product, under softplus
+GATED_RESULT = "mixer_gated"      # a recurrent mixer's gated norm's result,
+                                  # which its out-projection reads
+KDA_GATE_RESULT = "kda_gate"      # the delta mixer's gate's product, under
+                                  # sigmoid
 # The order they are admitted in: milliseconds of the backward's second
 # forward spared a GB held, the small ones first. Timed on the v5e in the
 # benchmark's three `flash` cells: `r` of the `[scopes]` line of a traced
@@ -374,25 +379,37 @@ KDA_DECAY_RESULT = "kda_decay"    # the decay's product, under softplus
 #   kda_conv       kimi: the convolutions' results as `silu` reads them
 #                  (bfloat16), so neither the taps nor the float32 sums run
 #                  again; after `kda_proj`, which its input is
+#   mixer_gated    a recurrent mixer's gated norm's result, which the
+#                  out-projection's weight gradient reads: kept, no
+#                  `gatenorm_fwd` runs again: nemotron 3.0 / 0.34, kimi
+#                  0.43 / 0.27 (there `jax.checkpoint`'s `reduce_precision`
+#                  pass behind the kept kernel result eats most of it;
+#                  PERF.md §6, PR 43); behind the convolutions' results,
+#                  whose kernels' backward READS them
 #   kda_decay      kimi: two thin products (K = 2,304 then 128) spared for a
 #                  [tokens, 4,096] array: the dearest to hold, so the last
+#   kda_gate       kimi: the gate's two thin products likewise, which the
+#                  gated norm's backward reads: 0.77 / 0.27 with the kernels
+#                  (`wg_b` and `wg_a` under `recompute`, PERF.md §5, PR 43)
 SAVED_RESULTS = (
     HC_RESULT, GATE_RESULT, ROUTE_RESULT, RESIDUAL_RESULT, LATENT_RESULT,
     IN_PROJ_RESULT, KDA_PROJ_RESULT, HIDDEN_RESULT, ATTN_LATENT_RESULT,
     STREAM_OUT_RESULT, QKV_RESULT, CONV_RESULT, KDA_CONV_RESULT,
-    KDA_DECAY_RESULT,
+    GATED_RESULT, KDA_DECAY_RESULT, KDA_GATE_RESULT,
 )
 # What a delta-rule layer's second forward and backward hold at once beyond
 # the named results, in [tokens, heads x head_dim] float32 arrays: the log
-# decay and its gradient, the gated norm's o and gate and the gradient of
-# each (held so that the peak stays an upper bound of the chip's compiler's
-# figure for the kimi cell's step: 12.84 GB against 13.46). The
-# convolutions' float32 sums and what `silu` and the norms made of them
-# were six more (13.69 against 14.27, PR 41) until they stayed in VMEM
-# (`ops/shortconv.py`, PR 42): where the plain convolutions run instead
-# (float32 streams, a mesh of several devices) a layer holds those six
-# more than is counted here; no cell runs `flash` there (PERF.md §7).
-KDA_WORK_ARRAYS = 6
+# decay and its gradient (held so that the peak stays an upper bound of the
+# chip's compiler's figure for the kimi cell's step: 13.16 GB against
+# 13.60). The gated norm's o and gate and the gradient of each were four
+# more (12.84 against 13.46, PR 42) until they stayed in VMEM
+# (`ops/gatenorm.py`, PR 43), the convolutions' float32 sums and what
+# `silu` and the norms made of them six more (13.69 against 14.27, PR 41)
+# until those did (`ops/shortconv.py`, PR 42): where the plain expressions
+# run instead (float32 streams, a mesh of several devices) a layer holds
+# those ten more than is counted here; no cell runs `flash` there
+# (PERF.md §7).
+KDA_WORK_ARRAYS = 2
 
 
 def _block_cls(cfg: "TransformerConfig", cls=None, keep: tuple[str, ...] = ()):
@@ -496,7 +513,8 @@ def _result_bytes(cfg: "TransformerConfig", tokens: int) -> list[dict]:
     def delta(out: dict, kind: AttentionKind):
         wide = tokens * _lanes(kind.n_heads * cfg.head_dim)
         out[KDA_PROJ_RESULT] = out[KDA_CONV_RESULT] = 3 * wide * act
-        out[KDA_DECAY_RESULT] = wide * act
+        for name in (KDA_DECAY_RESULT, GATED_RESULT, KDA_GATE_RESULT):
+            out[name] = wide * act
         # never a candidate (no name): alive where the layer is formed again
         out["kda_work"] = KDA_WORK_ARRAYS * wide * 4
 
@@ -505,6 +523,7 @@ def _result_bytes(cfg: "TransformerConfig", tokens: int) -> list[dict]:
         xbc = d_in + 2 * cfg.ssm_groups * cfg.ssm_state
         out[IN_PROJ_RESULT] = tokens * _lanes(d_in + xbc + cfg.ssm_heads) * act
         out[CONV_RESULT] = tokens * _lanes(xbc) * act
+        out[GATED_RESULT] = tokens * _lanes(d_in) * act
 
     layers = []
     if cfg.layer_pattern is None:
@@ -1356,17 +1375,26 @@ class StateSpaceMixer(nn.Module):
                 x, dt, a, b, c, groups=g, chunk=cfg.ssm_chunk, mesh=self.mesh
             )
             skip = jnp.repeat(vector("D", nn.initializers.ones, h), p)
-            y = y.astype(f32) + skip * x.astype(f32)
+            kernels = gatenorm.kernels_apply(y, z, d_in // g, self.mesh)
+            if not kernels:
+                y = y.astype(f32) + skip * x.astype(f32)
         with jax.named_scope("ssm.gate_norm"):
-            y = y * nn.silu(z.astype(f32))
-            grouped = y.reshape(*y.shape[:-1], g, d_in // g)
-            grouped = grouped * jax.lax.rsqrt(
-                jnp.mean(grouped * grouped, axis=-1, keepdims=True) + cfg.norm_eps
-            )
-            y = (
-                grouped.reshape(y.shape)
-                * vector("norm_scale", nn.initializers.ones, d_in)
-            ).astype(cfg.dtype)
+            scale = vector("norm_scale", nn.initializers.ones, d_in)
+            if kernels:
+                # The skip's sum is the kernels': float32, in VMEM.
+                y = gatenorm.gated_norm(
+                    y, z, scale, group=d_in // g, eps=cfg.norm_eps,
+                    gate_first=True, skip=(x, skip),
+                )
+            else:
+                y = y * nn.silu(z.astype(f32))
+                grouped = y.reshape(*y.shape[:-1], g, d_in // g)
+                grouped = grouped * jax.lax.rsqrt(
+                    jnp.mean(grouped * grouped, axis=-1, keepdims=True)
+                    + cfg.norm_eps
+                )
+                y = (grouped.reshape(y.shape) * scale).astype(cfg.dtype)
+            y = checkpoint_name(y, GATED_RESULT)
         with jax.named_scope("ssm.out_proj"):
             return _dense(cfg.d_model, (None, "embed"), "out_proj", cfg.dtype)(y)
 
@@ -1488,14 +1516,23 @@ class DeltaMixer(nn.Module):
             o = kda_scan(q, k, v, g, beta, chunk=cfg.ssm_chunk, mesh=self.mesh)
         with jax.named_scope("kda.gate_norm"):
             low = _dense(d, ("embed", None), "wg_a", cfg.dtype)(x)
-            gate = jax.nn.sigmoid(
-                _dense(wide, (None, "heads"), "wg_b", cfg.dtype)(low).astype(f32)
+            # What is named is what the gated norm's backward reads.
+            gate = checkpoint_name(
+                _dense(wide, (None, "heads"), "wg_b", cfg.dtype)(low),
+                KDA_GATE_RESULT,
             )
-            o = o.astype(f32)
-            o = o * to_lanes(jax.lax.rsqrt(
-                over_head(o * o) / d + cfg.norm_eps
-            )) * jnp.tile(vector("norm_scale", nn.initializers.ones, d), h)
-            y = (o * gate).astype(cfg.dtype)
+            scale = jnp.tile(vector("norm_scale", nn.initializers.ones, d), h)
+            if gatenorm.kernels_apply(o, gate, d, self.mesh):
+                y = gatenorm.gated_norm(
+                    o, gate, scale, group=d, eps=cfg.norm_eps, gate_first=False
+                )
+            else:
+                o = o.astype(f32)
+                o = o * to_lanes(jax.lax.rsqrt(
+                    over_head(o * o) / d + cfg.norm_eps
+                )) * scale
+                y = (o * jax.nn.sigmoid(gate.astype(f32))).astype(cfg.dtype)
+            y = checkpoint_name(y, GATED_RESULT)
         with jax.named_scope("kda.out_proj"):
             return _dense(cfg.d_model, ("heads", "embed"), "wo", cfg.dtype)(y)
 

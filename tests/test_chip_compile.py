@@ -520,6 +520,39 @@ def test_short_convolution_kernels_compile_at_the_cells_shapes(
     assert tpu_kernel_calls(text) == 2
 
 
+@pytest.mark.parametrize("how", [
+    dict(group=1024, gate_first=True, skip=True),
+    dict(group=128, gate_first=False, skip=False),
+], ids=["nemotron: silu, groups of 1,024, the skip", "kimi: heads of 128, sigmoid"])
+def test_gated_norm_kernels_compile_at_the_cells_shapes(one_chip, how):
+    """The recurrent mixers' gated norm, forward and backward, at one
+    sequence of 8,192 by 4,096 lanes (`ops/gatenorm.py`): blocks one group
+    wide (256 rows of 1,024 lanes, seven of them twice over in the
+    backward; 2,048 rows of 128), inside the default scoped VMEM."""
+    from kubeflow_tpu.ops import gatenorm
+
+    shape = lambda dims, dtype: jax.ShapeDtypeStruct(
+        dims, dtype, sharding=one_chip
+    )
+    wide = shape((1, 8192, 4096), jnp.bfloat16)
+    vector = shape((4096,), jnp.float32)
+    skip = (wide, vector) if how["skip"] else None
+
+    def loss(o, gate, scale, skip):
+        y = gatenorm.gated_norm(
+            o, gate, scale, group=how["group"], eps=1e-5,
+            gate_first=how["gate_first"], skip=skip, interpret=False,
+        )
+        return y.astype(jnp.float32).sum()
+
+    text, names = _compile(
+        jax.value_and_grad(loss, argnums=(0, 1, 2, 3) if skip else (0, 1, 2)),
+        wide, wide, vector, skip,
+    )
+    assert names == ["gatenorm_fwd", "gatenorm_bwd"]
+    assert tpu_kernel_calls(text) == 2
+
+
 def test_sub_1024_blocks_select_the_replicated_lse_and_compile(one_chip):
     """A packed lse block below 1024 rows is (1, bq/128 < 8, 128): the
     lowering refuses it, so such sizes must select the replicated
@@ -782,7 +815,7 @@ def test_a_cell_shaped_step_stays_under_the_remat_plans_predicted_peak(
     plan = remat_plan(cfg, 8192, trainer.step_memory())
     assert plan.names == (
         "moe_route", "moe_latent_in", "ssm_in_proj", "mlp_hidden", "attn_qkv",
-        "ssm_conv",
+        "ssm_conv", "mixer_gated",
     ) and plan.refused == ()
     compiled = trainer.make_train_step().lower(
         trainer.abstract_state(), {"tokens": tokens, "labels": tokens}

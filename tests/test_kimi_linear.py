@@ -296,9 +296,10 @@ WIDE_SHAPE = (2, 128)  # heads of one lane tile, a sequence of one row block
 
 def _wide_loss_and_gradient(policy, kernels: bool, dtype=jnp.bfloat16):
     """Loss and gradient of a delta layer and a latent one at heads of 128
-    lanes, the convolutions as XLA's passes or as `shortconv_*` (which the
-    CPU is told compile, and interprets)."""
-    from kubeflow_tpu.ops import shortconv
+    lanes, the convolutions and the gated norm as XLA's passes or as
+    `shortconv_*` and `gatenorm_*` (which the CPU is told compile, and
+    interprets)."""
+    from kubeflow_tpu.ops import gatenorm, shortconv
 
     key = jax.random.PRNGKey(5)
     numbers, flat = _held(WIDE, key, 0, 4)
@@ -309,9 +310,9 @@ def _wide_loss_and_gradient(policy, kernels: bool, dtype=jnp.bfloat16):
     loss = _program_loss(_config(numbers, remat_policy=policy, dtype=dtype))
     patch = pytest.MonkeyPatch()
     try:
-        if kernels:
-            patch.setattr(shortconv, "kernels_apply", functools.partial(
-                shortconv.kernels_apply, compiled=True
+        for ops in (shortconv, gatenorm) if kernels else ():
+            patch.setattr(ops, "kernels_apply", functools.partial(
+                ops.kernels_apply, compiled=True
             ))
         # The CPU has no bfloat16 x bfloat16 = float32 product of the plain
         # delta rule's shapes: the rule itself in float32, either way.
@@ -336,6 +337,11 @@ def _wide_plain(policy):
     return _wide_loss_and_gradient(policy, kernels=False)
 
 
+@functools.cache
+def _wide_kernels(policy):
+    return _wide_loss_and_gradient(policy, kernels=True)
+
+
 @pytest.mark.parametrize("policy", ["none", "full", "mlp", "flash"])
 def test_the_convolutions_as_kernels_give_the_plain_paths_loss_and_gradient(
     policy,
@@ -348,7 +354,7 @@ def test_the_convolutions_as_kernels_give_the_plain_paths_loss_and_gradient(
     bfloat16's rounding."""
     plain_names, want = _wide_plain(policy)
     assert not [n for n in plain_names if n.startswith("shortconv")]
-    names, got = _wide_loss_and_gradient(policy, kernels=True)
+    names, got = _wide_kernels(policy)
     again = 3 if policy in ("full", "flash") else 0
     assert names.count("shortconv_fwd") == 3 + again
     assert names.count("shortconv_bwd") == 3
@@ -360,9 +366,22 @@ def test_the_convolutions_as_kernels_give_the_plain_paths_loss_and_gradient(
         assert gap < 5e-2, gap
 
 
+@pytest.mark.parametrize("policy", ["none", "full", "mlp", "flash"])
+def test_the_gated_norm_is_one_kernel_each_way_a_delta_layer(policy):
+    """The same programs (held to the plain path's loss and gradients
+    above): one `gatenorm_fwd` and one `gatenorm_bwd` the delta layer, the
+    forward's run again only where a checkpoint keeps nothing of it."""
+    assert not [n for n in _wide_plain(policy)[0] if n.startswith("gatenorm")]
+    names, _ = _wide_kernels(policy)
+    assert names.count("gatenorm_fwd") == 1 + (policy in ("full", "flash"))
+    assert names.count("gatenorm_bwd") == 1
+
+
 def test_float32_streams_take_the_plain_convolutions_whatever_the_backend():
-    """What `shortconv.kernels_apply` refuses runs the plain expression:
-    the traced gradient is the one traced where no kernel compiles."""
+    """What `shortconv.kernels_apply` and `gatenorm.kernels_apply` refuse
+    runs the plain expression: the traced gradient is the one traced where
+    no kernel compiles."""
     told, _ = _wide_loss_and_gradient("none", kernels=True, dtype=jnp.float32)
     plain, _ = _wide_loss_and_gradient("none", kernels=False, dtype=jnp.float32)
-    assert told == plain and "shortconv_fwd" not in told
+    assert told == plain
+    assert not {"shortconv_fwd", "gatenorm_fwd"} & set(told)

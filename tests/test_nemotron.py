@@ -565,15 +565,16 @@ def test_the_head_shares_of_attention_add_up_to_the_whole_layer(seeded):
 
 
 def _wide_mixers(policy, kernels: bool, dtype=jnp.bfloat16):
-    """Loss and gradient of two state-space mixers whose xBC is three lane
-    tiles wide (4 heads of 32, 2 groups of a state of 64) over sequences of
-    one row block, the convolution as XLA's passes or as `shortconv_*`
-    (which the CPU is told compile, and interprets)."""
-    from kubeflow_tpu.ops import shortconv
+    """Loss and gradient of two state-space mixers whose xBC is four lane
+    tiles wide (8 heads of 32 in 2 groups of one lane tile, a state of 64)
+    over sequences of one row block, the convolution and the gated norm as
+    XLA's passes or as `shortconv_*` and `gatenorm_*` (which the CPU is
+    told compile, and interprets)."""
+    from kubeflow_tpu.ops import gatenorm, shortconv
 
     cfg = TransformerConfig(
         vocab_size=64, d_model=32, n_layers=2, layer_pattern="MM", n_heads=4,
-        head_dim=8, d_ff=16, ssm_heads=4, ssm_head_dim=32, ssm_state=64,
+        head_dim=8, d_ff=16, ssm_heads=8, ssm_head_dim=32, ssm_state=64,
         ssm_groups=2, ssm_chunk=16, tie_embeddings=False, dtype=dtype,
         attention_impl="dense", remat_policy=policy,
     )
@@ -583,9 +584,9 @@ def _wide_mixers(policy, kernels: bool, dtype=jnp.bfloat16):
     loss = _program_loss(cfg)
     patch = pytest.MonkeyPatch()
     try:
-        if kernels:
-            patch.setattr(shortconv, "kernels_apply", functools.partial(
-                shortconv.kernels_apply, compiled=True
+        for ops in (shortconv, gatenorm) if kernels else ():
+            patch.setattr(ops, "kernels_apply", functools.partial(
+                ops.kernels_apply, compiled=True
             ))
         args = (params, tokens[:, :-1], tokens[:, 1:])
         names = jaxpr_kernel_names(jax.make_jaxpr(jax.grad(loss))(*args).jaxpr)
@@ -597,6 +598,11 @@ def _wide_mixers(policy, kernels: bool, dtype=jnp.bfloat16):
 @functools.cache
 def _wide_plain(policy):
     return _wide_mixers(policy, kernels=False)
+
+
+@functools.cache
+def _wide_kernels(policy):
+    return _wide_mixers(policy, kernels=True)
 
 
 @pytest.mark.parametrize("policy", ["none", "full", "mlp", "flash"])
@@ -612,7 +618,7 @@ def test_the_convolution_as_kernels_gives_the_plain_paths_loss_and_gradient(
     leaf between "none" and "full")."""
     plain_names, want = _wide_plain(policy)
     assert not [n for n in plain_names if n.startswith("shortconv")]
-    names, got = _wide_mixers(policy, kernels=True)
+    names, got = _wide_kernels(policy)
     again = 2 if policy in ("full", "flash") else 0
     assert names.count("shortconv_fwd") == 2 + again
     assert names.count("shortconv_bwd") == 2
@@ -623,7 +629,21 @@ def test_the_convolution_as_kernels_gives_the_plain_paths_loss_and_gradient(
         assert gap < 1e-2, gap
 
 
+@pytest.mark.parametrize("policy", ["none", "full", "mlp", "flash"])
+def test_the_gated_norm_is_one_kernel_each_way_a_mixer(policy):
+    """The same programs (held to the plain path's loss and gradients
+    above, `D`'s and the norm's scale's among them): one `gatenorm_fwd`
+    and one `gatenorm_bwd` a mixer, the forward's run again only where a
+    checkpoint keeps nothing of it."""
+    assert not [n for n in _wide_plain(policy)[0] if n.startswith("gatenorm")]
+    names, _ = _wide_kernels(policy)
+    again = 2 if policy in ("full", "flash") else 0
+    assert names.count("gatenorm_fwd") == 2 + again
+    assert names.count("gatenorm_bwd") == 2
+
+
 def test_float32_mixers_take_the_plain_convolution_whatever_the_backend():
     told, _ = _wide_mixers("none", kernels=True, dtype=jnp.float32)
     plain, _ = _wide_mixers("none", kernels=False, dtype=jnp.float32)
-    assert told == plain and "shortconv_fwd" not in told
+    assert told == plain
+    assert not {"shortconv_fwd", "gatenorm_fwd"} & set(told)

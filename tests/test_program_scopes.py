@@ -199,6 +199,39 @@ def test_the_short_convolution_kernels_sit_under_their_mixers_scopes(name, want)
     assert (scope.path, scope.phase, scope.kind) == (*want, "kernel")
 
 
+# The gated-norm kernels as the chip's compiler names them in the nemotron
+# and the kimi cell's steps (their op_names, operands cut).
+GATENORM = '''HloModule jit_train_step, is_scheduled=true
+
+ENTRY %main (y: bf16[1,8192,4096], t: f32[8,4096]) -> bf16[1,8192,4096] {
+  %y = bf16[1,8192,4096]{2,1,0} parameter(0)
+  %t = f32[8,4096]{1,0} parameter(1)
+  %gatenorm_fwd.5 = bf16[1,8192,4096]{2,1,0} custom-call(%y, %y, %y, %t), custom_call_target="tpu_custom_call", metadata={op_name="jit(train_step)/jvp(TransformerLM)/layer_0/ssm/ssm.gate_norm/jit(_fwd)/gatenorm_fwd/pallas_call"}
+  %gatenorm_fwd.2 = bf16[1,8192,4096]{2,1,0} custom-call(%y, %y, %t), custom_call_target="tpu_custom_call", metadata={op_name="jit(train_step)/jvp(TransformerLM)/layer_4/kda/kda.gate_norm/jit(_fwd)/gatenorm_fwd/pallas_call"}
+  %gatenorm_bwd.9 = (bf16[1,8192,4096]{2,1,0}, bf16[1,8192,4096]{2,1,0}, bf16[1,8192,4096]{2,1,0}, f32[16,4096]{1,0}) custom-call(%y, %y, %y, %gatenorm_fwd.5, %t), custom_call_target="tpu_custom_call", metadata={op_name="jit(train_step)/transpose(jvp(TransformerLM))/jvp(TransformerLM)/checkpoint/layer_0/ssm/ssm.gate_norm/jit(_bwd)/gatenorm_bwd/pallas_call"}
+  %gatenorm_bwd.3 = (bf16[1,8192,4096]{2,1,0}, bf16[1,8192,4096]{2,1,0}, f32[8,4096]{1,0}) custom-call(%y, %y, %gatenorm_fwd.2, %t), custom_call_target="tpu_custom_call", metadata={op_name="jit(train_step)/transpose(jvp(TransformerLM))/jvp(TransformerLM)/checkpoint/layer_4/kda/kda.gate_norm/jit(_bwd)/gatenorm_bwd/pallas_call"}
+  %gatenorm_fwd.11 = bf16[1,8192,4096]{2,1,0} custom-call(%y, %y, %t), custom_call_target="tpu_custom_call", metadata={op_name="jit(train_step)/transpose(jvp(TransformerLM))/jvp(TransformerLM)/checkpoint/rematted_computation/layer_4/kda/kda.gate_norm/jit(_fwd)/gatenorm_fwd/pallas_call"}
+  ROOT %dy = bf16[1,8192,4096]{2,1,0} get-tuple-element(%gatenorm_bwd.9), index=0
+}
+'''
+
+
+@pytest.mark.parametrize("name, want", [
+    ("gatenorm_fwd.5", ("layer_0/ssm/ssm.gate_norm/gatenorm_fwd", "forward")),
+    ("gatenorm_fwd.2", ("layer_4/kda/kda.gate_norm/gatenorm_fwd", "forward")),
+    ("gatenorm_bwd.9", ("layer_0/ssm/ssm.gate_norm/gatenorm_bwd", "backward")),
+    ("gatenorm_bwd.3", ("layer_4/kda/kda.gate_norm/gatenorm_bwd", "backward")),
+    # what a plan that refuses `mixer_gated` would run again
+    ("gatenorm_fwd.11", ("layer_4/kda/kda.gate_norm/gatenorm_fwd", "recompute")),
+])
+def test_the_gated_norm_kernels_sit_under_their_mixers_scopes(name, want):
+    """Kind `kernel` under `ssm.gate_norm` / `kda.gate_norm`, forward and
+    backward: what `kda_layer_time_pct.train`, `gatenorm_time_pct.train`
+    and the `[scopes]` line read them by."""
+    scope = program_scopes(GATENORM, root="TransformerLM")[name]
+    assert (scope.path, scope.phase, scope.kind) == (*want, "kernel")
+
+
 @pytest.mark.parametrize("op_name, want", [
     ("jit(train_step)/jvp(TransformerLM)/layer_1/moe/moe.route/dot_general",
      ("layer_1/moe/moe.route", "forward")),

@@ -27,7 +27,7 @@ from kubeflow_tpu.models.transformer import (
     TransformerLM,
     remat_plan,
 )
-from kubeflow_tpu.ops import shortconv
+from kubeflow_tpu.ops import gatenorm, shortconv
 from kubeflow_tpu.ops import streams as streams_ops
 from kubeflow_tpu.parallel import MeshSpec, build_mesh
 from kubeflow_tpu.testing.hlo import _walk_eqns, jaxpr_kernel_names
@@ -76,8 +76,9 @@ CELL_PLANS = {
             "moe_route": 146_800_640, "moe_latent_in": 83_886_080,
             "ssm_in_proj": 765_460_480, "mlp_hidden": 440_401_920,
             "attn_qkv": 37_748_736, "ssm_conv": 419_430_400,
+            "mixer_gated": 335_544_320,
         },
-        (), 15_391_717_516,
+        (), 15_727_261_836,
     ),
     "laguna-s-2.1-ep32.train-8k": (
         8_110_182_412, 3_244_072_960,
@@ -104,15 +105,17 @@ CELL_PLANS = {
     "kimi-linear-48b-a3b-ep32.train-8k": (
         6_024_344_332, 2_409_737_728,
         # Four delta-rule layers: their three projections, the
-        # convolutions' results under silu, the decay's product; the one
-        # latent layer's down-projection (no q rank) and its five operands.
+        # convolutions' results under silu, the gated norm's result, the
+        # decay's and the gate's products; the one latent layer's
+        # down-projection (no q rank) and its five operands.
         {
             "moe_route": 83_886_080, "attn_residual": 188_743_680,
             "kda_proj": 805_306_368, "mlp_hidden": 436_207_616,
             "attn_latent": 10_485_760, "attn_qkv": 236_978_176,
-            "kda_conv": 805_306_368, "kda_decay": 268_435_456,
+            "kda_conv": 805_306_368, "mixer_gated": 268_435_456,
+            "kda_decay": 268_435_456, "kda_gate": 268_435_456,
         },
-        (), 13_461_893_900,
+        (), 13_596_111_628,
     ),
     "zaya1-8b-ep2.train-8k": (
         9_223_475_372, 3_689_390_144,
@@ -552,7 +555,7 @@ FAMILIES = {
     "a pattern of mixers as kernels, relu2 experts and attention": dict(
         n_layers=3, layer_pattern="ME*", num_experts=4, router="sigmoid",
         experts_per_token=2, moe_latent=16, moe_shared_ff=48, mlp_act="relu2",
-        ssm_heads=4, ssm_head_dim=32, ssm_state=64, ssm_groups=2, ssm_chunk=8,
+        ssm_heads=8, ssm_head_dim=32, ssm_state=64, ssm_groups=2, ssm_chunk=8,
         rope_fraction=0.0, tie_embeddings=False, n_kv_heads=1,
     ),
     "a pattern of mixers, latent relu2 experts and attention": dict(
@@ -572,13 +575,14 @@ def test_the_plans_bytes_are_those_of_the_results_the_layers_name(
     traced forward names: every `name` equation's result, its minor
     dimension in whole lane tiles, summed by name. The streams' mixes as
     XLA's code and as the row-block kernels, the mixers' convolutions as
-    XLA's passes and as the `shortconv_*` pair (the CPU is told they
-    compile) name the same results: under the pair `kda_conv` sits on q,
-    k and v as the delta rule reads them, the pre-activations' bytes."""
+    XLA's passes and as the `shortconv_*` pair, their gated norm as the
+    `gatenorm_*` pair (the CPU is told they compile) name the same
+    results: under the pair `kda_conv` sits on q, k and v as the delta
+    rule reads them, the pre-activations' bytes."""
     cfg = dataclasses.replace(SMALL, dtype=jnp.bfloat16, **FAMILIES[family])
     kernels = "as kernels" in family
     if kernels:
-        for ops in (streams_ops, shortconv):
+        for ops in (streams_ops, shortconv, gatenorm):
             monkeypatch.setattr(ops, "kernels_apply", functools.partial(
                 ops.kernels_apply, compiled=True
             ))
@@ -588,6 +592,10 @@ def test_the_plans_bytes_are_those_of_the_results_the_layers_name(
     assert kernels == bool(
         {"hc_pre_fwd", "shortconv_fwd"} & set(jaxpr_kernel_names(forward))
     )
+    mixers = "M" in (cfg.layer_pattern or "") or any(
+        kind.mixer == "delta" for kind in cfg.attention_kinds
+    )
+    assert (kernels and mixers) == ("gatenorm_fwd" in jaxpr_kernel_names(forward))
     named: dict = {}
     for eqn in _walk_eqns(forward):
         if eqn.primitive.name == "name" and eqn.params["name"] in SAVED_RESULTS:
@@ -618,8 +626,10 @@ def test_a_delta_rule_layer_keeps_its_kernels_results_and_counts_its_work():
     assert [("kda_proj" in layer, "attn_qkv" in layer) for layer in layers] == [
         (True, False), (True, False), (True, False), (False, True), (True, False)
     ]
-    assert layers[0]["kda_work"] == 6 * wide * 4 == 805_306_368
+    assert layers[0]["kda_work"] == 2 * wide * 4 == 268_435_456
     assert layers[0]["kda_conv"] == 3 * wide * 2 == 201_326_592
+    for name in ("mixer_gated", "kda_gate"):
+        assert layers[0][name] == wide * 2 and name not in layers[3]
     assert "kda_work" not in SAVED_RESULTS and "mlp_hidden" in layers[0]
     sched = kda.kda_schedule(8192, heads=32, head_dim=128, chunk=cfg.ssm_chunk)
     stream = tokens * 2304 * 2
@@ -639,21 +649,66 @@ def test_a_convolution_kept_runs_no_forward_kernel_again(
     """`kda_proj` + `kda_conv` (`ssm_in_proj` + `ssm_conv`) kept: the
     gradient holds the forward's `shortconv_fwd` calls (q, k and v; xBC)
     and no second set, and one `shortconv_bwd` each, whose only reads are
-    the kept projection and the cotangent. With nothing stated the
-    checkpoint forms the convolutions again."""
-    monkeypatch.setattr(shortconv, "kernels_apply", functools.partial(
-        shortconv.kernels_apply, compiled=True
-    ))
+    the kept projection and the cotangent. `mixer_gated` kept: the one
+    `gatenorm_fwd` of the mixer layer and no second (its operands are the
+    kernels' results and the kept projections: the backward reads them,
+    the out-projection's weight gradient the kept result). With nothing
+    stated the checkpoint forms both again."""
+    for ops in (shortconv, gatenorm):
+        monkeypatch.setattr(ops, "kernels_apply", functools.partial(
+            ops.kernels_apply, compiled=True
+        ))
     cfg = dataclasses.replace(SMALL, dtype=jnp.bfloat16, **FAMILIES[family])
     plan = remat_plan(cfg, 256, ROOMY)
-    assert plan.refused == () and (
+    assert plan.refused == () and "mixer_gated" in plan.names and (
         {"kda_proj", "kda_conv"} <= set(plan.names)
         or {"ssm_in_proj", "ssm_conv"} <= set(plan.names)
     )
-    for stated, forwards in ((ROOMY, calls), (None, 2 * calls)):
+    for stated, again in ((ROOMY, 1), (None, 2)):
         names = jaxpr_kernel_names(_grad_jaxpr(cfg, stated, shape=(2, 128)))
-        assert names.count("shortconv_fwd") == forwards, (stated, names)
+        assert names.count("shortconv_fwd") == again * calls, (stated, names)
         assert names.count("shortconv_bwd") == calls
+        assert names.count("gatenorm_fwd") == again, (stated, names)
+        assert names.count("gatenorm_bwd") == 1
+
+
+@pytest.mark.parametrize("name", sorted(CELL_PLANS))
+def test_a_name_only_a_mixer_forms_costs_the_other_cells_nothing(
+    name, monkeypatch
+):
+    """`mixer_gated` is formed by a recurrent mixer alone: without it in
+    `SAVED_RESULTS` the cells with no such mixer get the plan they get
+    with it, byte for byte, and the two with one lose that name alone
+    (`kda_gate`, the delta mixer's own, likewise costs kimi alone)."""
+    cfg, tokens, trainer = _cell(name)
+    stated = dataclasses.replace(trainer.step_memory(), limit_bytes=V5E_LIMIT)
+    plan = remat_plan(cfg, tokens, stated)
+    monkeypatch.setattr(transformer, "SAVED_RESULTS", tuple(
+        n for n in SAVED_RESULTS if n != transformer.GATED_RESULT
+    ))
+    without = remat_plan(cfg, tokens, stated)
+    if name.startswith(("kimi", "nemotron")):
+        assert set(plan.names) - set(without.names) == {"mixer_gated"}
+        assert plan.saved_bytes - without.saved_bytes == dict(plan.bytes)[
+            "mixer_gated"
+        ] == 8192 * 4096 * 2 * (5 if name.startswith("nemotron") else 4)
+    else:
+        assert plan == without
+
+
+def test_the_kimi_cells_predicted_peak_bounds_the_compilers_count():
+    """With the gated norm's float32 arrays in VMEM a delta layer's work
+    is the log decay and its gradient (`KDA_WORK_ARRAYS` 2): the plan's
+    peak still lies over what the chip's compiler counts for the cell's
+    whole step (arguments, temporaries and code, compiled for a described
+    v5e: 13,161,131,520 bytes, PERF.md §6, PR 43), with all ten names
+    admitted."""
+    cfg, tokens, trainer = _cell("kimi-linear-48b-a3b-ep32.train-8k")
+    plan = remat_plan(cfg, tokens, dataclasses.replace(
+        trainer.step_memory(), limit_bytes=V5E_LIMIT
+    ))
+    assert transformer.KDA_WORK_ARRAYS == 2 and plan.refused == ()
+    assert 13_161_131_520 < plan.predicted_peak < 13_161_131_520 + (1 << 29)
 
 
 def test_fit_records_how_far_the_plan_engaged(monkeypatch):
