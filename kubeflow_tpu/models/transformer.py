@@ -401,13 +401,9 @@ SAVED_RESULTS = (
 # the named results, in [tokens, heads x head_dim] float32 arrays: the log
 # decay and its gradient (held so that the peak stays an upper bound of the
 # chip's compiler's figure for the kimi cell's step: 13.16 GB against
-# 13.60). The gated norm's o and gate and the gradient of each were four
-# more (12.84 against 13.46, PR 42) until they stayed in VMEM
-# (`ops/gatenorm.py`, PR 43), the convolutions' float32 sums and what
-# `silu` and the norms made of them six more (13.69 against 14.27, PR 41)
-# until those did (`ops/shortconv.py`, PR 42): where the plain expressions
-# run instead (float32 streams, a mesh of several devices) a layer holds
-# those ten more than is counted here; no cell runs `flash` there
+# 13.60). Where `ops/shortconv.py`'s and `ops/gatenorm.py`'s plain forms run
+# in place of their kernels (float32 streams, a mesh of several devices) a
+# layer holds ten more than is counted here; no cell runs `flash` there
 # (PERF.md §7).
 KDA_WORK_ARRAYS = 2
 
@@ -744,24 +740,6 @@ class RMSNorm(nn.Module):
         return rms_norm(x, scale, dtype=self.dtype, eps=self.eps)
 
 
-def _shift(x, steps: int):
-    """x[t - steps] at position t along the sequence axis (1), zeros
-    before the first token: a causal tap."""
-    if steps == 0:
-        return x
-    pad = [(0, 0)] * x.ndim
-    pad[1] = (steps, 0)
-    return jnp.pad(x, pad)[:, : x.shape[1]]
-
-
-def _causal_conv(x, w):
-    """A causal depthwise convolution along the sequence of x [B, S, W] by
-    w [taps, W]: tap j multiplies the value j tokens back. float32 out.
-    The state-space and the delta-rule mixers' short convolution."""
-    x = x.astype(jnp.float32)
-    return sum(w[j] * _shift(x, j) for j in range(w.shape[0]))
-
-
 def _replicated(init, rank: int):
     return nn.with_logical_partitioning(init, (None,) * rank)
 
@@ -803,13 +781,13 @@ class Attention(nn.Module):
             ),
             (k1, heads, d, d), jnp.float32,
         )
-        c0 = sum(w0[j] * _shift(u, j) for j in range(k0))
+        c0 = sum(w0[j] * shortconv.shift(u, j) for j in range(k0))
         c0 = c0.astype(cfg.dtype)
         # The products leave in cfg.dtype like every other projection's
         # (the CPU backend has no batched bf16 dot that leaves in f32).
         return sum(
             jnp.einsum(
-                "bshd,hde->bshe", _shift(c0, j), w1[j].astype(cfg.dtype)
+                "bshd,hde->bshe", shortconv.shift(c0, j), w1[j].astype(cfg.dtype)
             ).astype(jnp.float32)
             for j in range(k1)
         )
@@ -837,7 +815,7 @@ class Attention(nn.Module):
         # The second half of the kv heads carry the token before.
         now = hk - hk // 2
         v = jnp.concatenate(
-            [v[:, :, :now], _shift(v[:, :, now:], 1)], axis=2
+            [v[:, :, :now], shortconv.shift(v[:, :, now:], 1)], axis=2
         )
         return q, k, v
 
@@ -1310,6 +1288,30 @@ def _inverse_softplus(x):
     return x + jnp.log(-jnp.expm1(-x))
 
 
+def _vector(module: nn.Module, name: str, init, size: int):
+    """A replicated float32 vector of `module`'s parameters."""
+    return module.param(name, _replicated(init, 1), (size,), jnp.float32)
+
+
+def _steps(ssm_dt):
+    """`dt_bias`'s initialiser: steps drawn log-uniform in `ssm_dt`'s
+    (lo, hi), no smaller than its floor, through softplus's inverse."""
+    lo, hi, floor = ssm_dt
+
+    def steps(key, shape, dtype):
+        drawn = jnp.exp(jax.random.uniform(
+            key, shape, dtype, math.log(lo), math.log(hi)
+        ))
+        return _inverse_softplus(jnp.maximum(drawn, floor))
+
+    return steps
+
+
+def _a_log(key, shape, dtype):
+    """`A_log`'s initialiser: the heads' rates 1 to 16, evenly."""
+    return jnp.log(jnp.linspace(1.0, 16.0, shape[0], dtype=dtype))
+
+
 class StateSpaceMixer(nn.Module):
     """Mamba-2's mixer over u [B, S, d_model], from the configuration's
     numbers: H = `ssm_heads` heads of P = `ssm_head_dim`, G = `ssm_groups`
@@ -1337,9 +1339,7 @@ class StateSpaceMixer(nn.Module):
             raise ValueError(f"{h} state-space heads do not divide into {g} groups")
         d_in, gn, taps = h * p, g * n, cfg.ssm_conv
         f32 = jnp.float32
-        vector = lambda name, init, size: self.param(
-            name, _replicated(init, 1), (size,), f32
-        )
+        vector = functools.partial(_vector, self)
         with jax.named_scope("ssm.in_proj"):
             proj = checkpoint_name(_dense(
                 2 * d_in + 2 * gn + h, ("embed", None), "in_proj", cfg.dtype
@@ -1352,49 +1352,26 @@ class StateSpaceMixer(nn.Module):
                 (taps, d_in + 2 * gn), f32,
             )
             bias = vector("conv_bias", nn.initializers.zeros, d_in + 2 * gn)
-            if shortconv.kernels_apply(xbc, taps, 0, self.mesh):
-                xbc = shortconv.short_conv(xbc, w, bias)
-            else:
-                xbc = nn.silu(_causal_conv(xbc, w) + bias).astype(cfg.dtype)
-            xbc = checkpoint_name(xbc, CONV_RESULT)
+            xbc = checkpoint_name(
+                shortconv.short_conv(xbc, w, bias, mesh=self.mesh), CONV_RESULT
+            )
         x, b, c = jnp.split(xbc, [d_in, d_in + gn], axis=-1)
-
-        def steps(key, shape, dtype):
-            lo, hi, floor = cfg.ssm_dt
-            drawn = jnp.exp(jax.random.uniform(
-                key, shape, dtype, math.log(lo), math.log(hi)
-            ))
-            return _inverse_softplus(jnp.maximum(drawn, floor))
-
-        dt = nn.softplus(dt.astype(f32) + vector("dt_bias", steps, h))
-        a = -jnp.exp(vector(
-            "A_log", lambda *_: jnp.log(jnp.linspace(1.0, 16.0, h, dtype=f32)), h
-        ))
+        dt = nn.softplus(
+            dt.astype(f32) + vector("dt_bias", _steps(cfg.ssm_dt), h)
+        )
+        a = -jnp.exp(vector("A_log", _a_log, h))
         with jax.named_scope("ssm.scan"):
             y = ssd_scan(
                 x, dt, a, b, c, groups=g, chunk=cfg.ssm_chunk, mesh=self.mesh
             )
             skip = jnp.repeat(vector("D", nn.initializers.ones, h), p)
-            kernels = gatenorm.kernels_apply(y, z, d_in // g, self.mesh)
-            if not kernels:
-                y = y.astype(f32) + skip * x.astype(f32)
         with jax.named_scope("ssm.gate_norm"):
-            scale = vector("norm_scale", nn.initializers.ones, d_in)
-            if kernels:
-                # The skip's sum is the kernels': float32, in VMEM.
-                y = gatenorm.gated_norm(
-                    y, z, scale, group=d_in // g, eps=cfg.norm_eps,
-                    gate_first=True, skip=(x, skip),
-                )
-            else:
-                y = y * nn.silu(z.astype(f32))
-                grouped = y.reshape(*y.shape[:-1], g, d_in // g)
-                grouped = grouped * jax.lax.rsqrt(
-                    jnp.mean(grouped * grouped, axis=-1, keepdims=True)
-                    + cfg.norm_eps
-                )
-                y = (grouped.reshape(y.shape) * scale).astype(cfg.dtype)
-            y = checkpoint_name(y, GATED_RESULT)
+            # The skip's sum is the gated norm's: float32, never rounded.
+            y = checkpoint_name(gatenorm.gated_norm(
+                y, z, vector("norm_scale", nn.initializers.ones, d_in),
+                group=d_in // g, eps=cfg.norm_eps, gate_first=True,
+                skip=(x, skip), mesh=self.mesh,
+            ), GATED_RESULT)
         with jax.named_scope("ssm.out_proj"):
             return _dense(cfg.d_model, (None, "embed"), "out_proj", cfg.dtype)(y)
 
@@ -1426,18 +1403,7 @@ class DeltaMixer(nn.Module):
         cfg = self.config
         h, d, taps = self.kind.n_heads, cfg.head_dim, cfg.ssm_conv
         wide, f32 = h * d, jnp.float32
-        vector = lambda name, init, size: self.param(
-            name, _replicated(init, 1), (size,), f32
-        )
-        # A head's sum over its d lanes and back, as products with the
-        # heads' indicator [H·d, H]: [.., H·d] -> [.., H, d] is a relayout
-        # on the TPU (a copy a pass), a product of 32 columns is not.
-        lanes_of = (
-            jnp.arange(wide)[:, None] // d == jnp.arange(h)[None]
-        ).astype(f32)
-        dot = functools.partial(jnp.dot, precision=jax.lax.Precision.HIGH)
-        over_head = lambda u: dot(u, lanes_of)
-        to_lanes = lambda r: dot(r, lanes_of.T)
+        vector = functools.partial(_vector, self)
         with jax.named_scope("kda.proj"):
             q, k, v = (
                 checkpoint_name(_dense(
@@ -1446,49 +1412,28 @@ class DeltaMixer(nn.Module):
                 for name in ("wq", "wk", "wv")
             )
         with jax.named_scope("kda.conv"):
-            kernels = shortconv.kernels_apply(q, taps, d, self.mesh)
-
             def conv(u, name, scale=None):  # with a scale: a head's u / |u|
                 w = self.param(
                     f"conv_{name}",
                     _replicated(nn.initializers.normal(taps ** -0.5), 2),
                     (taps, wide), f32,
                 )
-                if kernels:
-                    # What is named is what the delta rule's backward reads.
-                    return checkpoint_name(shortconv.short_conv(
-                        u, w, sum_dtype=cfg.dtype, head_dim=d if scale else 0,
-                        scale=scale or 1.0, eps=cfg.norm_eps,
-                    ), KDA_CONV_RESULT)
-                # What is named is what `silu`'s slope reads.
-                u = nn.silu(checkpoint_name(
-                    _causal_conv(u, w).astype(cfg.dtype), KDA_CONV_RESULT
-                ).astype(f32))
-                if scale:
-                    u = u * to_lanes(scale * jax.lax.rsqrt(
-                        over_head(u * u) + cfg.norm_eps
-                    ))
-                return u.astype(cfg.dtype)
+                return shortconv.short_conv(
+                    u, w, sum_dtype=cfg.dtype, head_dim=d if scale else 0,
+                    scale=scale or 1.0, eps=cfg.norm_eps,
+                    name=KDA_CONV_RESULT, mesh=self.mesh,
+                )
 
             q, k, v = conv(q, "q", d ** -0.5), conv(k, "k", 1.0), conv(v, "v")
         with jax.named_scope("kda.gates"):
-            def steps(key, shape, dtype):
-                lo, hi, floor = cfg.ssm_dt
-                drawn = jnp.exp(jax.random.uniform(
-                    key, shape, dtype, math.log(lo), math.log(hi)
-                ))
-                return _inverse_softplus(jnp.maximum(drawn, floor))
-
             low = _dense(d, ("embed", None), "wf_a", cfg.dtype)(x)
             decay = checkpoint_name(
                 _dense(wide, (None, "heads"), "wf_b", cfg.dtype)(low),
                 KDA_DECAY_RESULT,
             )
-            rate = jnp.repeat(jnp.exp(vector(
-                "A_log", lambda *_: jnp.log(jnp.linspace(1.0, 16.0, h, dtype=f32)), h
-            )), d)
+            rate = jnp.repeat(jnp.exp(vector("A_log", _a_log, h)), d)
             g = -rate * nn.softplus(
-                decay.astype(f32) + vector("dt_bias", steps, wide)
+                decay.astype(f32) + vector("dt_bias", _steps(cfg.ssm_dt), wide)
             )
             wb = self.param(
                 "wb",
@@ -1522,17 +1467,10 @@ class DeltaMixer(nn.Module):
                 KDA_GATE_RESULT,
             )
             scale = jnp.tile(vector("norm_scale", nn.initializers.ones, d), h)
-            if gatenorm.kernels_apply(o, gate, d, self.mesh):
-                y = gatenorm.gated_norm(
-                    o, gate, scale, group=d, eps=cfg.norm_eps, gate_first=False
-                )
-            else:
-                o = o.astype(f32)
-                o = o * to_lanes(jax.lax.rsqrt(
-                    over_head(o * o) / d + cfg.norm_eps
-                )) * scale
-                y = (o * jax.nn.sigmoid(gate.astype(f32))).astype(cfg.dtype)
-            y = checkpoint_name(y, GATED_RESULT)
+            y = checkpoint_name(gatenorm.gated_norm(
+                o, gate, scale, group=d, eps=cfg.norm_eps, gate_first=False,
+                mesh=self.mesh,
+            ), GATED_RESULT)
         with jax.named_scope("kda.out_proj"):
             return _dense(cfg.d_model, ("heads", "embed"), "wo", cfg.dtype)(y)
 
@@ -1553,21 +1491,15 @@ class StreamMaps(nn.Module):
     streams: X is read, never written. What is named (`HC_RESULT`) is the
     RAW product and that scalar, so the backward forms neither again.
 
-    With `kernels` (`ops/streams.kernels_apply`: where kernels compile,
-    bfloat16 streams, d whole lane tiles, a sequence of whole 128-row
-    blocks, one device) the norm, the product, Hp and h are ONE pass
-    over X (`hc_pre_fwd`) and the backward is `ops/streams.mix_in`'s
-    rule: the maps' backward in XLA, then `hc_pre_bwd`, which takes Hp's
-    cotangent from dh itself and writes dX once; the streams returned
-    are then for `ops/streams.mix_out` ALONE (the two rules share that
-    write). Without, XLA's code (`ops/streams.mixed_in`). Sows
-    `hc_sinkhorn_err` (the largest |row or column sum - 1| of Hr) and
-    `hc_res_diag_mean` (Hr's mean diagonal), each over the stack's
-    sublayers' count so that the step's sum is a mean, and
-    `hc_kernel_sublayers`, 1 where the kernels ran."""
+    The mix is `ops/streams.mix_in`, which decides its form: the streams
+    returned are for `ops/streams.mix_out` ALONE (the kernels' two rules
+    share one write of dX). Sows `hc_sinkhorn_err` (the largest |row or
+    column sum - 1| of Hr) and `hc_res_diag_mean` (Hr's mean diagonal),
+    each over the stack's sublayers' count so that the step's sum is a
+    mean, and `hc_kernel_sublayers`, 1 where the kernels ran."""
 
     config: TransformerConfig
-    kernels: bool = False
+    mesh: Mesh | None = None
 
     @nn.compact
     def __call__(self, streams):
@@ -1592,8 +1524,10 @@ class StreamMaps(nn.Module):
             (spec.maps,), f32,
         )
         a = self.param("a", _replicated(nn.initializers.ones, 1), (3,), f32)
-        mix = streams_ops.mix_in if self.kernels else streams_ops.mixed_in
-        h, streams, ho, hr = mix(streams, phi, a, bias, spec)
+        ran = streams_ops.kernels_apply(streams, spec, self.mesh)  # to report
+        h, streams, ho, hr = streams_ops.mix_in(
+            streams, phi, a, bias, spec, self.mesh
+        )
         with jax.named_scope("hc.maps"):
             sums = jnp.concatenate([hr.sum(axis=2), hr.sum(axis=1)], axis=1)
             sublayers = 2 * cfg.n_layers
@@ -1602,7 +1536,7 @@ class StreamMaps(nn.Module):
                 ("hc_res_diag_mean", jnp.mean(
                     jnp.trace(hr, axis1=1, axis2=2) / n
                 ) / sublayers),
-                ("hc_kernel_sublayers", float(self.kernels)),
+                ("hc_kernel_sublayers", float(ran)),
             ):
                 self.sow(
                     "counters", name, value,
@@ -1643,23 +1577,18 @@ class Block(nn.Module):
         `sum_i Hp[i] X[i]` (`StreamMaps`), and `X'[i] = sum_j Hr[i, j]
         X[j] + Ho[i] y` with y its output. The sums in float32, the
         streams stored in `dtype`. `sublayer(h)` -> (y, what it returns
-        beside). Where `ops/streams.kernels_apply` says so the round's
-        passes over the streams are the row-block kernels with their own
-        backward (`hc_pre_fwd` and `hc_post_fwd` forward, `hc_post_bwd`
-        and `hc_pre_bwd` backward); anywhere else (the CPU, float32
-        streams, a d that is not whole lane tiles, a sequence that is not
-        whole blocks of 128 rows, a mesh of several devices) XLA's code,
-        differentiated by JAX."""
+        beside). The round's passes over the streams are
+        `ops/streams.mix_in` and `mix_out`, which decide their form."""
         cfg = self.config
-        spec = _stream_maps(cfg)
-        kernels = streams_ops.kernels_apply(streams, spec, self.mesh)
-        h, streams, ho, hr = StreamMaps(cfg, kernels, name=f"hc_{name}")(streams)
+        h, streams, ho, hr = StreamMaps(cfg, self.mesh, name=f"hc_{name}")(streams)
         y, beside = sublayer(h)
         # `Ho`'s gradient reads y: named, or the sublayer's last product
         # runs again for it alone.
         y = checkpoint_name(y, STREAM_OUT_RESULT)
-        mix = streams_ops.mix_out if kernels else streams_ops.mixed_out
-        return mix(streams, y, hr, ho, spec), beside
+        mixed = streams_ops.mix_out(
+            streams, y, hr, ho, _stream_maps(cfg), self.mesh
+        )
+        return mixed, beside
 
     @nn.compact
     def __call__(self, x, positions, router_state=None):

@@ -188,6 +188,27 @@ def _auto_interpret(interpret: bool | None) -> bool:
     return not kernels_compiled()
 
 
+def row_blocks_apply(x, tile: int, mesh, compiled: bool | None = None) -> bool:
+    """What the row-block kernel pairs' `kernels_apply` share
+    (`ops/shortconv.py`, `gatenorm.py`, `streams.py`): kernels compile
+    (`compiled` stands in for the backend: tests make the CPU interpret),
+    x [B, S, W] is bfloat16, whole tiles of `tile` lanes (whole lane
+    tiles) by whole blocks of 128 rows, on one device: a Pallas call does
+    not partition itself under `jit`, so on a mesh the plain forms run
+    (where the scans run in `shard_map`: ROADMAP Design 15)."""
+    if compiled is None:
+        compiled = kernels_compiled()
+    return (
+        compiled
+        and x.ndim == 3
+        and x.dtype == jnp.bfloat16
+        and tile % _LANES == 0
+        and x.shape[-1] % tile == 0
+        and x.shape[1] % _LANES == 0
+        and (mesh is None or mesh.size == 1)
+    )
+
+
 def _causal_mask(s, i, j, bq, bk):
     q_pos = i * bq + lax.broadcasted_iota(jnp.int32, s.shape, 0)
     k_pos = j * bk + lax.broadcasted_iota(jnp.int32, s.shape, 1)

@@ -1,10 +1,10 @@
 """The recurrent mixers' gated norm as one kernel pair (Pallas TPU).
 
-What `models/transformer.StateSpaceMixer` does between its scan and its
-out-projection, and `DeltaMixer` between the delta rule and its own, over
-o [B, S, W] and a gate [B, S, W] in bfloat16, a group of `group` lanes (a
-head, or a state-space group's channels), a learned scale [W] and `eps`,
-in the two orders the two published mixers use:
+What a state-space mixer does between its scan and its out-projection, and
+a delta-rule mixer between the delta rule and its own, over o [B, S, W]
+and a gate [B, S, W] in bfloat16, a group of `group` lanes (a head, or a
+state-space group's channels), a learned scale [W] and `eps`, in the two
+orders the two published mixers use:
 
     gate first (Mamba-2):   s = o + d * x       the skip, where there is one:
                                                 x [B, S, W] bfloat16, d [W],
@@ -38,11 +38,10 @@ and its convolution's results, which XLA copies out for a Pallas call:
 read through the blocks' index maps where they lie the step was no
 faster (PERF.md §6, PR 43), so the kernels take what they are handed.
 
-`kernels_apply` says where the pair runs, from what the program can see:
-where kernels compile (`ops/flash.kernels_compiled`), o and the gate are
-bfloat16, the group is whole lane tiles and W whole groups, S is whole
-blocks of 128 rows and one device holds the arrays. Elsewhere the mixers
-run their plain expressions, which the tests hold the kernels to.
+`gated_norm` is the one entry and decides which form runs, from what the
+program can see (`kernels_apply`): the pair, or `gated_norm_plain`, XLA's
+passes, which the CPU, float32 and a mesh of several devices run and the
+tests hold the kernels to.
 """
 
 from __future__ import annotations
@@ -58,7 +57,7 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh
 
 from kubeflow_tpu.ops import flash
-from kubeflow_tpu.ops.shortconv import _chunks
+from kubeflow_tpu.ops.shortconv import _chunks, head_sums
 
 _LANES = flash._LANES
 # The most rows of a block at a group of one lane tile, and the most
@@ -82,23 +81,30 @@ def kernels_apply(
     o, gate, group: int, mesh: Mesh | None, compiled: bool | None = None,
 ) -> bool:
     """Whether the gated norm over o and gate [B, S, W] runs as the kernel
-    pair (module docstring). A Pallas call does not partition itself under
-    `jit`: on a mesh of several devices the plain form runs. `compiled`
-    stands in for the backend's answer (tests: the CPU interprets the
-    kernels it is made to run)."""
-    if compiled is None:
-        compiled = flash.kernels_compiled()
+    pair: `flash.row_blocks_apply` over whole groups no wider than a
+    block, and a bfloat16 gate."""
     return (
-        compiled
-        and o.ndim == 3
-        and o.dtype == jnp.bfloat16
+        0 < group <= _MAX_GROUP
+        and flash.row_blocks_apply(o, group, mesh, compiled)
         and gate.dtype == jnp.bfloat16
-        and 0 < group <= _MAX_GROUP
-        and group % _LANES == 0
-        and o.shape[-1] % group == 0
-        and o.shape[1] % _LANES == 0
-        and (mesh is None or mesh.size == 1)
     )
+
+
+def gated_norm_plain(
+    o, gate, scale, group: int, eps: float, gate_first: bool, skip=None
+):
+    """`gated_norm` as XLA's passes (module docstring's two orders), every
+    array float32 from the operands' casts to the result's."""
+    f32 = jnp.float32
+    s, gate = o.astype(f32), gate.astype(f32)
+    if skip is not None:
+        x, d = skip
+        s = s + d * x.astype(f32)
+    t = s * jax.nn.silu(gate) if gate_first else s
+    out = t * lax.rsqrt(head_sums(t * t, group) / group + eps) * scale
+    if not gate_first:
+        out = out * jax.nn.sigmoid(gate)
+    return out.astype(o.dtype)
 
 
 def _halved(n: int, least: int, room: int) -> int:
@@ -283,14 +289,17 @@ def _bwd(o, gate, x, dout, table, *, group, gate_first, eps, interpret):
 
 def gated_norm(
     o, gate, scale, *, group: int, eps: float, gate_first: bool,
-    skip=None, interpret: bool | None = None,
+    skip=None, mesh: Mesh | None = None, interpret: bool | None = None,
 ):
-    """o [B, S, W] bfloat16 through the gated norm a group of `group`
-    lanes with the learned `scale` [W], as the kernel pair (module
-    docstring; `kernels_apply` says where). `gate_first`: the gate's `silu`
-    then the norm (Mamba-2's order), else the norm then the gate's sigmoid
-    (KDA's). `skip` = (x [B, S, W] bfloat16, d [W]) adds `d * x` to o in
-    float32 first. `interpret` as `flash_attention`'s."""
+    """o [B, S, W] through the gated norm a group of `group` lanes with
+    the learned `scale` [W]. `gate_first`: the gate's `silu` then the norm
+    (Mamba-2's order), else the norm then the gate's sigmoid (KDA's).
+    `skip` = (x [B, S, W], d [W]) adds `d * x` to o in float32 first. The
+    kernel pair where `kernels_apply` says so (or under the interpreter
+    when `interpret` is True, as `ssd_scan` reads it), `gated_norm_plain`
+    anywhere else."""
+    if interpret is None and not kernels_apply(o, gate, group, mesh):
+        return gated_norm_plain(o, gate, scale, group, eps, gate_first, skip)
     x, d = skip or (None, None)
     return _gated_norm(
         o, gate, x, d, scale, group, float(eps), bool(gate_first),

@@ -1,9 +1,9 @@
 """The recurrent mixers' short convolution, `silu` and a head's unit norm
 as one kernel pair (Pallas TPU).
 
-What `models/transformer.DeltaMixer` does to each of q, k and v between
-its projections and the delta rule, and `StateSpaceMixer` to xBC between
-its in-projection and the scan, over u [B, S, W] in bfloat16:
+What a delta-rule mixer does to each of q, k and v between its projections
+and the delta rule, and a state-space mixer to xBC between its
+in-projection and the scan, over u [B, S, W] in bfloat16:
 
     m_t = sum_j w_j u_(t-j) (+ bias)     a causal depthwise convolution of
                                          `taps` along the sequence, zeros
@@ -38,11 +38,9 @@ The backward's only reads of HBM are u and dy: with u kept (`kda_proj`,
 `ssm_in_proj`) and y kept (`kda_conv`, `ssm_conv`: what the NEXT kernel's
 backward reads) no forward kernel runs again under `remat_policy="flash"`.
 
-`kernels_apply` says where the pair runs, from what the program can see:
-where kernels compile (`ops/flash.kernels_compiled`), u is bfloat16, W is
-whole lane tiles (and whole heads of whole lane tiles), S is whole blocks
-of 128 rows and one device holds the arrays. Elsewhere the mixers run
-their plain expressions over `models/transformer._causal_conv`, which the
+`short_conv` is the one entry and decides which form runs, from what the
+program can see (`kernels_apply`): the pair, or `short_conv_plain`, XLA's
+passes, which the CPU, float32 and a mesh of several devices run and the
 tests hold the kernels to.
 """
 
@@ -54,6 +52,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh
@@ -78,26 +77,63 @@ _TABLE = 8
 
 
 def kernels_apply(
-    u, taps: int, head_dim: int, mesh: Mesh | None,
-    compiled: bool | None = None,
+    u, taps: int, head_dim: int, mesh: Mesh | None, compiled: bool | None = None
 ) -> bool:
-    """Whether the convolution over u [B, S, W] runs as the kernel pair
-    (module docstring). A Pallas call does not partition itself under
-    `jit`: on a mesh of several devices the plain form runs. `compiled`
-    stands in for the backend's answer (tests: the CPU interprets the
-    kernels it is made to run)."""
-    if compiled is None:
-        compiled = flash.kernels_compiled()
+    """Whether the convolution over u [B, S, W] runs as the kernel pair:
+    `flash.row_blocks_apply` over whole heads (lane tiles without), and
+    the taps within the table (and their reach inside the halo)."""
     return (
-        compiled
-        and u.ndim == 3
-        and u.dtype == jnp.bfloat16
-        and u.shape[-1] % (head_dim or _LANES) == 0
-        and head_dim % _LANES == 0
-        and u.shape[1] % _LANES == 0
-        and 1 <= taps <= _TABLE - 2  # and their reach inside the halo
-        and (mesh is None or mesh.size == 1)
+        flash.row_blocks_apply(u, head_dim or _LANES, mesh, compiled)
+        and 1 <= taps <= _TABLE - 2
     )
+
+
+def shift(x, steps: int):
+    """x[t - steps] at position t along the sequence axis (1), zeros
+    before the first token: a causal tap."""
+    if steps == 0:
+        return x
+    pad = [(0, 0)] * x.ndim
+    pad[1] = (steps, 0)
+    return jnp.pad(x, pad)[:, : x.shape[1]]
+
+
+def _causal_conv(x, w):
+    """A causal depthwise convolution along the sequence of x [B, S, W] by
+    w [taps, W]: tap j multiplies the value j tokens back. float32 out."""
+    x = x.astype(jnp.float32)
+    return sum(w[j] * shift(x, j) for j in range(w.shape[0]))
+
+
+def head_sums(squares, head_dim: int):
+    """A head's sum of `squares` [.., H·d] on every lane of the head, as
+    products with the heads' indicator [H·d, H]: [.., H·d] -> [.., H, d]
+    is a relayout on the TPU (a copy a pass), a product of H columns not."""
+    width = squares.shape[-1]
+    lanes_of = (
+        jnp.arange(width)[:, None] // head_dim
+        == jnp.arange(width // head_dim)[None]
+    ).astype(jnp.float32)
+    dot = functools.partial(jnp.dot, precision=lax.Precision.HIGH)
+    return dot(dot(squares, lanes_of), lanes_of.T)
+
+
+def short_conv_plain(
+    u, w, bias=None, sum_dtype=jnp.float32, head_dim: int = 0,
+    scale: float = 1.0, eps: float = 0.0, name: str | None = None,
+):
+    """`short_conv` as XLA's passes (module docstring's three lines).
+    `name`: what `silu`'s slope reads, the rounded sum."""
+    m = _causal_conv(u, w)
+    if bias is not None:
+        m = m + bias
+    m = m.astype(sum_dtype)
+    if name is not None:
+        m = checkpoint_name(m, name)
+    a = jax.nn.silu(m.astype(jnp.float32))
+    if head_dim:
+        a = a * (scale * lax.rsqrt(head_sums(a * a, head_dim) + eps))
+    return a.astype(u.dtype)
 
 
 def _block(seq: int, head_dim: int) -> tuple[int, int, int]:
@@ -336,18 +372,24 @@ def _bwd(u, dy, table, *, taps, bias, head, sum_dtype, eps, interpret):
 
 def short_conv(
     u, w, bias=None, *, sum_dtype=jnp.float32, head_dim: int = 0,
-    scale: float = 1.0, eps: float = 0.0, interpret: bool | None = None,
+    scale: float = 1.0, eps: float = 0.0, name: str | None = None,
+    mesh: Mesh | None = None, interpret: bool | None = None,
 ):
-    """u [B, S, W] bfloat16 through the causal depthwise convolution by
-    `w` [taps, W] (tap j multiplies the value j tokens back) and `bias`
-    [W] or None, the sum rounded to `sum_dtype`, `silu` and, with
-    `head_dim`, `scale` times each head's unit vector (`eps` under the
-    root), as the kernel pair (module docstring; `kernels_apply` says
-    where). `interpret` as `flash_attention`'s."""
-    return _short_conv(
+    """u [B, S, W] through the causal depthwise convolution by `w`
+    [taps, W] (tap j multiplies the value j tokens back) and `bias` [W] or
+    None, the sum rounded to `sum_dtype`, `silu` and, with `head_dim`,
+    `scale` times each head's unit vector (`eps` under the root). The
+    kernel pair where `kernels_apply` says so (or under the interpreter
+    when `interpret` is True, as `ssd_scan` reads it), `short_conv_plain`
+    anywhere else. `name`: a `checkpoint_name` on what this op's backward
+    reads of it: the pair's result, the plain form's rounded sum."""
+    if interpret is None and not kernels_apply(u, w.shape[0], head_dim, mesh):
+        return short_conv_plain(u, w, bias, sum_dtype, head_dim, scale, eps, name)
+    y = _short_conv(
         u, w, bias, jnp.dtype(sum_dtype), head_dim, float(scale), float(eps),
         flash._auto_interpret(interpret),
     )
+    return y if name is None else checkpoint_name(y, name)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))

@@ -8,14 +8,16 @@ sublayer's round is a handful of weighted sums and dot products over the
 SAME [S, n·d] array, each of them a reduction over a token's row or a sum
 a token: a block of whole token rows needs no second pass.
 
-Two forms of one round:
+`mix_in` and `mix_out` are the round's two entries, and decide between
+two forms of it from what the program can see (`kernels_apply`, the same
+answer for both: x passes from one to the other unchanged):
 
-- **The plain form** (`exact_product`, `maps_of_products` and the sums
-  `Block._mixed` writes out): XLA's fusions, which read X once for the
-  norm, once for the product, once a mix and, backward, once a stream a
-  map (4.3 times the least traffic in the xing cell, PERF.md §5, PR 39).
-  What the CPU, float32 streams and a mesh of several devices run.
-- **The row-block kernels** (`mix_in`, `mix_out`; `hc_*` in a trace): Pallas
+- **The plain form** (`mixed_in`, `mixed_out`): XLA's fusions, which read
+  X once for the norm, once for the product, once a mix and, backward,
+  once a stream a map (4.3 times the least traffic in the xing cell,
+  PERF.md §5, PR 39). What the CPU, float32 streams and a mesh of
+  several devices run, and what the tests hold the kernels to.
+- **The row-block kernels** (`hc_*` in a trace): Pallas
   passes over blocks of 128 whole token rows, with hand-written backward
   rules so that each [S, n·d] array is read once a pass and dX is
   written once:
@@ -38,7 +40,7 @@ Two forms of one round:
   ITSELF as that copy's cotangent, and `mix_in`'s rule, which holds Hr,
   forms `sum_i Hr[i, j] dX'[i]` with everything else dX is made of. The
   pair is only a gradient together: nothing but `mix_out` may read the
-  copy (`Block._mixed` is the one caller).
+  copy (`models/transformer.Block._mixed` is the one caller).
 
 The maps stay [B, n, S] with the sequence in the lanes between the
 passes (a trailing axis of 4 pads a tile 32 times); a kernel wants a
@@ -48,10 +50,8 @@ token beside rows of 28 KB) and slices what comes back; a kernel turns a
 block [128, 128] in VMEM. (XLA's own transposes to [tokens, 128] columns
 took 0.2 ms each, 11 ms a step: PERF.md §6, PR 40.)
 
-`kernels_apply` says which form runs, from what the program can see:
-where kernels compile (`ops/flash.kernels_compiled`), the streams are
-bfloat16, d is whole lane tiles, the sequence is whole blocks of 128
-rows, and one device holds the arrays.
+`kernels_apply` is `flash.row_blocks_apply` over streams of whole lane
+tiles, and phi's three pieces within a tile's lanes (`_Lanes.fit`).
 """
 
 from __future__ import annotations
@@ -199,9 +199,7 @@ def _maps_of_raw(t, inv_rms, a, bias, spec: Maps):
 
 
 def mixed_in(x, phi, a, bias, spec: Maps):
-    """The mix into a sublayer, the plain form: streams x [B, S, n·d] ->
-    (h [B, S, d] = `sum_i Hp[i] X[i]`, x, Ho [B, n, S], Hr [B, n, n, S]),
-    as `mix_in` returns them. bfloat16 streams take the product in one
+    """`mix_in`, the plain form. bfloat16 streams take the product in one
     MXU pass (`exact_product`), others at `highest`; what is named
     (`CHECKPOINT_MAPS_NAME`) is the raw product and the norm's scalar;
     the iterations sit in a checkpoint of their own, so their backward
@@ -229,8 +227,7 @@ def mixed_in(x, phi, a, bias, spec: Maps):
 
 
 def mixed_out(x, y, hr, ho, spec: Maps):
-    """The mix out of a sublayer, the plain form: `X'[i] = sum_j Hr[i, j]
-    X[j] + Ho[i] y`, the sums in float32."""
+    """`mix_out`, the plain form: the sums in float32."""
     n, d, f32 = spec.n, spec.d, jnp.float32
     column = lambda m: m[..., None]  # [B, S] -> a factor a token
     with jax.named_scope("hc.post"):
@@ -252,20 +249,12 @@ def kernels_apply(
     streams, spec: Maps, mesh: Mesh | None, compiled: bool | None = None
 ) -> bool:
     """Whether a round over `streams` [B, S, n·d] runs as the row-block
-    kernels (module docstring). A Pallas call does not partition itself
-    under `jit`: on a mesh of several devices the plain form runs.
-    `compiled` stands in for the backend's answer (tests: the CPU
-    interprets the kernels it is made to run)."""
-    if compiled is None:
-        compiled = flash.kernels_compiled()
+    kernels: `flash.row_blocks_apply` over the n streams, and everything
+    a token's few numbers need within a tile's lanes."""
     return (
-        compiled
-        and streams.dtype == jnp.bfloat16
-        and spec.d % _LANES == 0
+        flash.row_blocks_apply(streams, spec.d, mesh, compiled)
         and streams.shape[-1] == spec.n * spec.d
         and _Lanes(spec).fit
-        and streams.shape[1] % _ROWS == 0
-        and (mesh is None or mesh.size == 1)
     )
 
 
@@ -637,12 +626,16 @@ def _maps_backward(t, inv_rms, a, bias, dho, dhr, spec: Maps, interpret: bool):
 # -- the two rules ---------------------------------------------------------------
 
 
-def mix_in(x, phi, a, bias, spec: Maps, interpret: bool | None = None):
-    """The mix into a sublayer, as kernels: streams x [B, S, n·d]
-    bfloat16, `phi` [n·d, n² + 2n], `a` [3] and `bias` [n² + 2n] float32
-    -> (h [B, S, d], x itself for `mix_out` and nothing else (module
-    docstring), Ho [B, n, S], Hr [B, n, n, S]). `interpret` as
-    `flash_attention`'s."""
+def mix_in(x, phi, a, bias, spec: Maps, mesh: Mesh | None = None,
+           interpret: bool | None = None):
+    """The mix into a sublayer: streams x [B, S, n·d], `phi`
+    [n·d, n² + 2n], `a` [3] and `bias` [n² + 2n] float32 -> (h [B, S, d]
+    = `sum_i Hp[i] X[i]`, x itself for `mix_out` and nothing else (module
+    docstring), Ho [B, n, S], Hr [B, n, n, S]). The kernels where
+    `kernels_apply` says so (or under the interpreter when `interpret` is
+    True, as `ssd_scan` reads it), `mixed_in` anywhere else."""
+    if interpret is None and not kernels_apply(x, spec, mesh):
+        return mixed_in(x, phi, a, bias, spec)
     return _mix_in(x, phi, a, bias, spec, flash._auto_interpret(interpret))
 
 
@@ -688,11 +681,15 @@ def _mix_in_bwd(spec, interpret, residuals, cotangents):
 _mix_in.defvjp(_mix_in_fwd, _mix_in_bwd)
 
 
-def mix_out(x, y, hr, ho, spec: Maps, interpret: bool | None = None):
-    """The mix out of a sublayer, as kernels: `x` is `mix_in`'s second
-    result, y [B, S, d] the sublayer's output, Hr and Ho `mix_in`'s. Its
-    rule hands dX' back as x's cotangent: `mix_in`'s rule applies Hr to
-    it."""
+def mix_out(x, y, hr, ho, spec: Maps, mesh: Mesh | None = None,
+            interpret: bool | None = None):
+    """The mix out of a sublayer, `X'[i] = sum_j Hr[i, j] X[j] + Ho[i] y`:
+    `x` is `mix_in`'s second result, y [B, S, d] the sublayer's output, Hr
+    and Ho `mix_in`'s; the form `mix_in` took, by the same `mesh` and
+    `interpret`. The kernels' rule hands dX' back as x's cotangent:
+    `mix_in`'s rule applies Hr to it."""
+    if interpret is None and not kernels_apply(x, spec, mesh):
+        return mixed_out(x, y, hr, ho, spec)
     return _mix_out(x, y, hr, ho, spec, flash._auto_interpret(interpret))
 
 

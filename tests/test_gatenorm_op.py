@@ -1,7 +1,7 @@
 """The gated-norm kernel pair (`ops/gatenorm.py`, interpreted here) against
-the plain expressions the mixers run elsewhere: `StateSpaceMixer`'s skip,
-`silu` gate and a group's RMS norm, `DeltaMixer`'s head norm and sigmoid
-gate."""
+the op's plain form, `gated_norm_plain`, which runs wherever the pair does
+not (Mamba-2's skip, `silu` gate and a group's RMS norm; KDA's head norm
+and sigmoid gate); and which of the two `gated_norm` takes where."""
 
 import functools
 
@@ -18,25 +18,11 @@ F32, BF16 = jnp.float32, jnp.bfloat16
 EPS = 1e-5
 
 
-def _group_norm(t, group):
-    grouped = t.reshape(*t.shape[:-1], -1, group)
-    return (grouped * jax.lax.rsqrt(
-        jnp.mean(grouped * grouped, axis=-1, keepdims=True) + EPS
-    )).reshape(t.shape)
-
-
 def plain(o, gate, scale, x=None, d=None, *, group, gate_first):
-    """What `StateSpaceMixer` (`gate_first`, a skip) and `DeltaMixer`
-    write out: every array float32 from the operands' casts to the
-    result's."""
-    s = o.astype(F32)
-    if x is not None:
-        s = s + d * x.astype(F32)
-    if gate_first:
-        out = _group_norm(s * jax.nn.silu(gate.astype(F32)), group) * scale
-    else:
-        out = _group_norm(s, group) * scale * jax.nn.sigmoid(gate.astype(F32))
-    return out.astype(o.dtype)
+    return gatenorm.gated_norm_plain(
+        o, gate, scale, group=group, eps=EPS, gate_first=gate_first,
+        skip=None if x is None else (x, d),
+    )
 
 
 def kernels(o, gate, scale, x=None, d=None, *, group, gate_first):
@@ -165,12 +151,13 @@ FALLBACKS = {
     "a group wider than a block": dict(width=4096, group=4096),
     "a sequence of 100 rows": dict(seq=100),
     "a mesh of two devices": dict(devices=2),
+    "a mesh of four": dict(devices=4),
     "a backend that interprets": dict(compiled=None),
 }
 
 
 @pytest.mark.parametrize("case", [None, *sorted(FALLBACKS)])
-def test_where_the_kernels_apply(case):
+def test_where_the_kernels_apply(case, monkeypatch):
     how = dict(
         dtype=BF16, seq=256, width=256, group=128, devices=1, compiled=True,
     ) | (FALLBACKS[case] if case else {})
@@ -182,6 +169,27 @@ def test_where_the_kernels_apply(case):
         o, gate, how["group"], mesh, compiled=how["compiled"]
     ) is (case is None)
     assert gatenorm.kernels_apply(o, gate, how["group"], None) is False
+    # ... and `gated_norm` takes the form it says: the pair, or the plain
+    # form's very equations and no kernel.
+    monkeypatch.setattr(gatenorm, "kernels_apply", functools.partial(
+        gatenorm.kernels_apply, compiled=how["compiled"]
+    ))
+    scale = jax.ShapeDtypeStruct(shape[-1:], F32)
+    args = dict(group=how["group"], eps=EPS, gate_first=False)
+    traced = lambda fn, **more: jax.make_jaxpr(
+        functools.partial(fn, **args, **more)
+    )(o, gate, scale)
+    entry = traced(gatenorm.gated_norm, mesh=mesh)
+    if case is None:
+        assert jaxpr_kernel_names(entry.jaxpr) == ["gatenorm_fwd"]
+    else:
+        assert jaxpr_kernel_names(entry.jaxpr) == []
+        assert str(entry) == str(traced(gatenorm.gated_norm_plain))
+    if not set(FALLBACKS.get(case, {})) - {"devices", "compiled"}:
+        # shapes the pair takes: told to interpret, it runs whatever the
+        # backend and the mesh
+        told = traced(gatenorm.gated_norm, mesh=mesh, interpret=True)
+        assert jaxpr_kernel_names(told.jaxpr) == ["gatenorm_fwd"]
 
 
 @pytest.mark.parametrize("form", sorted(FORMS))

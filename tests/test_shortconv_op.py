@@ -1,6 +1,6 @@
 """The short-convolution kernel pair (`ops/shortconv.py`, interpreted here)
-against the plain expression the mixers run elsewhere:
-`models/transformer._causal_conv`, the bias, `silu` and a head's unit norm."""
+against the op's plain form, `short_conv_plain`, which runs wherever the
+pair does not; and which of the two `short_conv` takes where."""
 
 import functools
 
@@ -10,7 +10,6 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh
 
-from kubeflow_tpu.models.transformer import _causal_conv
 from kubeflow_tpu.ops import shortconv
 from kubeflow_tpu.testing.hlo import jaxpr_kernel_names
 
@@ -18,19 +17,7 @@ F32, BF16 = jnp.float32, jnp.bfloat16
 SCALE, EPS = 0.3, 1e-6
 
 
-def plain(u, w, bias=None, *, sum_dtype=F32, head_dim=0):
-    """What `DeltaMixer` (the sum rounded, a head's norm) and
-    `StateSpaceMixer` (a bias) write out over `_causal_conv`."""
-    m = _causal_conv(u, w)
-    if bias is not None:
-        m = m + bias
-    a = jax.nn.silu(m.astype(sum_dtype).astype(F32))
-    if head_dim:
-        heads = a.reshape(*a.shape[:-1], -1, head_dim)
-        a = (SCALE * heads * jax.lax.rsqrt(
-            jnp.sum(heads * heads, axis=-1, keepdims=True) + EPS
-        )).reshape(a.shape)
-    return a.astype(u.dtype)
+plain = functools.partial(shortconv.short_conv_plain, scale=SCALE, eps=EPS)
 
 
 def kernels(u, w, bias=None, *, sum_dtype=F32, head_dim=0):
@@ -162,12 +149,13 @@ FALLBACKS = {
     "heads that are not whole lane tiles": dict(head_dim=64),
     "more taps than the table holds": dict(taps=7),
     "a mesh of several devices": dict(devices=2),
+    "a mesh of four": dict(devices=4),
     "a backend that interprets": dict(compiled=None),
 }
 
 
 @pytest.mark.parametrize("case", [None, *sorted(FALLBACKS)])
-def test_where_the_kernels_apply(case):
+def test_where_the_kernels_apply(case, monkeypatch):
     how = dict(
         dtype=BF16, seq=256, width=256, head_dim=128, taps=4, devices=1,
         compiled=True,
@@ -178,6 +166,27 @@ def test_where_the_kernels_apply(case):
         u, how["taps"], how["head_dim"], mesh, compiled=how["compiled"]
     ) is (case is None)
     assert shortconv.kernels_apply(u, how["taps"], how["head_dim"], None) is False
+    # ... and `short_conv` takes the form it says: the pair, or the plain
+    # form's very equations and no kernel.
+    monkeypatch.setattr(shortconv, "kernels_apply", functools.partial(
+        shortconv.kernels_apply, compiled=how["compiled"]
+    ))
+    w = jax.ShapeDtypeStruct((how["taps"], how["width"]), F32)
+    args = dict(sum_dtype=BF16, head_dim=how["head_dim"], scale=SCALE, eps=EPS)
+    traced = lambda fn, **more: jax.make_jaxpr(
+        functools.partial(fn, **args, **more)
+    )(u, w)
+    entry = traced(shortconv.short_conv, mesh=mesh)
+    if case is None:
+        assert jaxpr_kernel_names(entry.jaxpr) == ["shortconv_fwd"]
+    else:
+        assert jaxpr_kernel_names(entry.jaxpr) == []
+        assert str(entry) == str(traced(shortconv.short_conv_plain))
+    if not set(FALLBACKS.get(case, {})) - {"devices", "compiled"}:
+        # shapes the pair takes: told to interpret, it runs whatever the
+        # backend and the mesh
+        told = traced(shortconv.short_conv, mesh=mesh, interpret=True)
+        assert jaxpr_kernel_names(told.jaxpr) == ["shortconv_fwd"]
 
 
 def test_the_pair_is_one_call_each_way_under_its_names(blocks_of_128_rows):

@@ -246,13 +246,40 @@ class _Mesh:
     (dict(compiled=False), False),     # the CPU
 ], ids=["cell-like", "float32", "d96", "48-tokens", "n6", "mesh-of-one",
         "mesh-of-four", "not-compiled"])
-def test_the_kernels_run_where_they_apply(change, runs):
+def test_the_kernels_run_where_they_apply(change, runs, monkeypatch):
     change = dict(change)
     mesh, compiled = change.pop("mesh", None), change.pop("compiled", True)
     streams, spec = _streams(**change)
     assert so.kernels_apply(streams, spec, mesh, compiled=compiled) is runs
     # What the program asks by itself is the backend: the CPU interprets.
     assert so.kernels_apply(streams, spec, mesh) is False
+    # ... and the round's two entries take the form it says, both the
+    # same: the kernels, or the plain form's very equations and no kernel.
+    monkeypatch.setattr(
+        so, "kernels_apply", functools.partial(so.kernels_apply, compiled=compiled)
+    )
+    shaped = lambda *shape: jax.ShapeDtypeStruct(shape, F32)
+    (b, s, _), n = streams.shape, spec.n
+    into = streams, shaped(n * spec.d, spec.maps), shaped(3), shaped(spec.maps)
+    out = (
+        streams, jax.ShapeDtypeStruct((b, s, spec.d), streams.dtype),
+        shaped(b, n, n, s), shaped(b, n, s),
+    )
+    for entry, plain, args, name in (
+        (so.mix_in, so.mixed_in, into, "hc_pre_fwd"),
+        (so.mix_out, so.mixed_out, out, "hc_post_fwd"),
+    ):
+        traced = jax.make_jaxpr(lambda *a: entry(*a, spec, mesh))(*args)
+        assert jaxpr_kernel_names(traced.jaxpr) == ([name] if runs else [])
+        if not runs:
+            assert str(traced) == str(
+                jax.make_jaxpr(lambda *a: plain(*a, spec))(*args)
+            )
+        if not set(change):  # the cell's shapes: told to interpret, it does
+            told = jax.make_jaxpr(
+                lambda *a: entry(*a, spec, mesh, interpret=True)
+            )(*args)
+            assert jaxpr_kernel_names(told.jaxpr) == [name]
 
 
 def _block_config(**how):
