@@ -67,7 +67,15 @@ class AttentionKind:
     attention_factor), yarn's blended frequencies with cos and sin times
     the attention factor (`ops/rope.yarn_inv_freq`). `mixer` "delta": the
     layer's mixer is no attention but the gated delta rule over `n_heads`
-    heads of `head_dim` (`DeltaMixer`), which knows no window and no rope."""
+    value heads (`DeltaMixer`), which knows no window and no rope. Such a
+    row also says: `key_heads`, the heads of q and k under its value heads
+    (None: as many; else a divisor, value head h reading key head
+    h // (n_heads / key_heads)); `head_dim`, its heads' width where that is
+    not the stack's; `decay`, whether the state forgets by a key CHANNEL
+    ("channel": the decay and the norm's gate each through a bottleneck of
+    `head_dim`, Kimi's) or by ONE factor a value head ("head": both straight
+    from the layer's input, Gated DeltaNet's); and `gate_act`, the
+    activation that gates its norm, "sigmoid" or "silu"."""
 
     n_heads: int
     window: int | None = None
@@ -75,6 +83,10 @@ class AttentionKind:
     rope_fraction: float = 1.0
     rope_yarn: tuple[float, int, float, float, float] | None = None
     mixer: str = "attention"
+    key_heads: int | None = None
+    head_dim: int | None = None
+    decay: str = "channel"
+    gate_act: str = "sigmoid"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -118,6 +130,11 @@ class TransformerConfig:
     # The share of each head's dims (the first ones) that rope turns.
     rope_fraction: float = 1.0
     norm_eps: float = 1e-6
+    # Every RMS norm with a learned scale but a recurrent mixer's gated one
+    # scales by (1 + w), w zero at the seed, in place of w.
+    norm_unit_offset: bool = False
+    # A head's RMS norm, with a learned scale, on q and on k before rope.
+    qk_norm: bool = False
     # CCA (compressed convolutional attention): q and k are mixed along
     # the sequence by two causal convolutions (kernel sizes below: a
     # depthwise one, then one grouped by head), get the mean of the
@@ -132,7 +149,8 @@ class TransformerConfig:
     # softmax router MLP whose hidden state is carried from layer to
     # layer (top-1); "sigmoid" scores every expert by a sigmoid of one
     # matmul and weighs the chosen by their scores normalised to
-    # `routed_scaling`. `experts_held` = (first, count) is the contiguous
+    # `routed_scaling`; "softmax" the same under a softmax over all the
+    # experts (and no correction). `experts_held` = (first, count) is the contiguous
     # range this program holds (None = all): the router still routes over
     # `num_experts`, a token's rows to experts held elsewhere add nothing.
     num_experts: int = 0
@@ -146,9 +164,11 @@ class TransformerConfig:
     mlp_act: str = "swiglu"
     # A latent expert space: the experts work in `moe_latent` dims between
     # a projection into it and one back (0 = in d_model). A shared expert
-    # of width `moe_shared_ff` that every token passes (0 = none).
+    # of width `moe_shared_ff` that every token passes (0 = none), under a
+    # sigmoid gate a token of its own (`moe_shared_gate`) or unweighted.
     moe_latent: int = 0
     moe_shared_ff: int = 0
+    moe_shared_gate: bool = False
     # For measuring an untrained model: every token's experts are drawn
     # evenly at random, by position and layer and the same in every run,
     # in place of the router's choice; the weights stay the router's
@@ -167,7 +187,8 @@ class TransformerConfig:
     # of `ssm_conv` taps, the scan in chunks of `ssm_chunk` positions.
     # `ssm_dt` = (min, max, floor) of the time steps the bias is drawn for.
     # The last three are the delta-rule mixer's too (`DeltaMixer`: a layer
-    # whose `AttentionKind.mixer` is "delta"); its heads are its row's.
+    # whose `AttentionKind.mixer` is "delta"); its heads, their width and
+    # the kind of its decay are its row's.
     ssm_heads: int = 0
     ssm_head_dim: int = 64
     ssm_state: int = 128
@@ -182,9 +203,12 @@ class TransformerConfig:
     # stack's `n_heads`, `rope_theta`, `rope_fraction`, no window.
     attention_kinds: tuple[AttentionKind, ...] = ()
     attention_pattern: tuple[int, ...] = ()
-    # A sigmoid gate a query head and position on attention's output,
-    # from the layer's normed input, before the output projection.
-    attention_gate: bool = False
+    # A sigmoid gate on attention's output before the output projection,
+    # from the layer's normed input. True (or "head"): one a query head and
+    # position, by a matrix of its own. "channel": one an output CHANNEL,
+    # projected beside q by q's own matrix (a head's q and gate side by
+    # side in it).
+    attention_gate: bool | str = False
     # Leading layers whose feed-forward half is the dense MLP, of width
     # `dense_d_ff`, in a stack whose other layers have experts.
     dense_layers: int = 0
@@ -219,6 +243,17 @@ class TransformerConfig:
     hc_iters: int = 20
     hc_clamp: float = 30.0
     hc_eps: float = 1e-6
+
+
+def _gate_kind(cfg: TransformerConfig) -> str | None:
+    """None, "head" or "channel": what `attention_gate` asks for."""
+    gate = {False: None, True: "head"}.get(cfg.attention_gate, cfg.attention_gate)
+    if gate not in (None, "head", "channel"):
+        raise ValueError(
+            f"attention_gate {cfg.attention_gate!r}: expected False, True "
+            "(or 'head') or 'channel'"
+        )
+    return gate
 
 
 def _own_kind(cfg: TransformerConfig) -> AttentionKind:
@@ -266,6 +301,45 @@ def _attention_kinds(cfg: TransformerConfig) -> list[AttentionKind]:
                 f"chunks of {cfg.ssm_chunk}: expected 'attention' or, in a "
                 "stack of blocks, 'delta' (no window, chunks a power of two)"
             )
+        if kind.mixer == "delta" and (
+            kind.decay not in ("channel", "head")
+            or kind.gate_act not in gatenorm.ACTIVATIONS
+            or (kind.key_heads or kind.n_heads) < 1
+            or kind.n_heads % (kind.key_heads or kind.n_heads)
+            or (kind.decay == "channel" and kind.key_heads not in (
+                None, kind.n_heads
+            ))
+        ):
+            raise ValueError(
+                f"a delta row of {kind.n_heads} value heads over "
+                f"{kind.key_heads} key heads with a decay a {kind.decay!r} "
+                f"gated by {kind.gate_act!r}: the key heads divide the "
+                "value heads (and equal them where the decay is a "
+                "'channel''s, else a 'head''s), the gate is "
+                f"{sorted(gatenorm.ACTIVATIONS)}"
+            )
+        if kind.mixer == "attention" and (
+            kind.key_heads is not None or kind.head_dim is not None
+        ):
+            raise ValueError(
+                f"an attention row with key_heads {kind.key_heads} and a "
+                f"head_dim of {kind.head_dim} of its own: both are a delta "
+                "row's (attention's are the stack's `n_kv_heads`, `head_dim`)"
+            )
+    gate = _gate_kind(cfg)
+    if gate == "channel" and (cfg.kv_latent or cfg.cca or any(
+        k.window is not None for k in kinds if k.mixer == "attention"
+    )):
+        raise ValueError(
+            "attention_gate 'channel' (projected beside q, a head's q and "
+            "gate side by side in one matrix) with a window, latent "
+            "attention or CCA: it is built for plain full attention"
+        )
+    if cfg.qk_norm and (cfg.kv_latent or cfg.cca):
+        raise ValueError(
+            "qk_norm with latent attention or CCA: they norm q and k "
+            "themselves"
+        )
     if cfg.kv_latent or cfg.q_latent or cfg.rope_head_dim:
         attention = [k for k in kinds if k.mixer == "attention"]
         if (
@@ -339,7 +413,8 @@ HC_RESULT = streams_ops.CHECKPOINT_MAPS_NAME  # the residual streams' maps'
                                   # raw products and the norm's scalar
 KDA_PROJ_RESULT = "kda_proj"      # the delta mixer's q, k and v projections
 KDA_CONV_RESULT = "kda_conv"      # their convolutions' results, under silu
-KDA_DECAY_RESULT = "kda_decay"    # the decay's product, under softplus
+KDA_DECAY_RESULT = "kda_decay"    # the decay's product, under softplus (a
+                                  # decay a head: b's product beside it)
 GATED_RESULT = "mixer_gated"      # a recurrent mixer's gated norm's result,
                                   # which its out-projection reads
 KDA_GATE_RESULT = "kda_gate"      # the delta mixer's gate's product, under
@@ -398,7 +473,8 @@ SAVED_RESULTS = (
     GATED_RESULT, KDA_DECAY_RESULT, KDA_GATE_RESULT,
 )
 # What a delta-rule layer's second forward and backward hold at once beyond
-# the named results, in [tokens, heads x head_dim] float32 arrays: the log
+# the named results, in [tokens, heads x head_dim] float32 arrays (a decay a
+# head: [tokens, heads]): the log
 # decay and its gradient (held so that the peak stays an upper bound of the
 # chip's compiler's figure for the kimi cell's step: 13.16 GB against
 # 13.60). Where `ops/shortconv.py`'s and `ops/gatenorm.py`'s plain forms run
@@ -486,7 +562,10 @@ def _result_bytes(cfg: "TransformerConfig", tokens: int) -> list[dict]:
                 (cfg.q_latent and _lanes(cfg.q_latent))
                 + _lanes(cfg.kv_latent + r)
             )
-        if cfg.attention_gate:
+        gate = _gate_kind(cfg)
+        if gate == "channel":  # the product beside q's, as wide
+            out[GATE_RESULT] = tokens * _lanes(kind.n_heads * cfg.head_dim) * act
+        elif gate:
             out[GATE_RESULT] = tokens * _lanes(kind.n_heads) * 4
 
     def mlp(out: dict, d_ff: int):
@@ -507,12 +586,20 @@ def _result_bytes(cfg: "TransformerConfig", tokens: int) -> list[dict]:
             mlp(out, cfg.moe_shared_ff)
 
     def delta(out: dict, kind: AttentionKind):
-        wide = tokens * _lanes(kind.n_heads * cfg.head_dim)
-        out[KDA_PROJ_RESULT] = out[KDA_CONV_RESULT] = 3 * wide * act
-        for name in (KDA_DECAY_RESULT, GATED_RESULT, KDA_GATE_RESULT):
-            out[name] = wide * act
+        d = kind.head_dim or cfg.head_dim
+        wide = tokens * _lanes(kind.n_heads * d)
+        keys = tokens * _lanes((kind.key_heads or kind.n_heads) * d)
+        out[KDA_PROJ_RESULT] = out[KDA_CONV_RESULT] = (2 * keys + wide) * act
+        out[GATED_RESULT] = out[KDA_GATE_RESULT] = wide * act
+        # the log decay in float32: a key channel's, or ONE a value head
+        decay = (
+            tokens * _lanes(kind.n_heads) if kind.decay == "head" else wide
+        ) * 4
+        # its product as the layer names it: float32 beside b's a head, the
+        # activations' dtype a channel
+        out[KDA_DECAY_RESULT] = decay if kind.decay == "head" else wide * act
         # never a candidate (no name): alive where the layer is formed again
-        out["kda_work"] = KDA_WORK_ARRAYS * wide * 4
+        out["kda_work"] = KDA_WORK_ARRAYS * decay
 
     def mixer(out: dict):
         d_in = cfg.ssm_heads * cfg.ssm_head_dim
@@ -573,8 +660,9 @@ def _kept_always_bytes(cfg: "TransformerConfig", tokens: int) -> int:
 
     def attention(kind: AttentionKind) -> int:
         if kind.mixer == "delta":  # o, and a [d, H·d] state a chunk
-            wide = _lanes(kind.n_heads * cfg.head_dim) * act
-            return (tokens + -(-tokens // cfg.ssm_chunk) * cfg.head_dim) * wide
+            d = kind.head_dim or cfg.head_dim
+            wide = _lanes(kind.n_heads * d) * act
+            return (tokens + -(-tokens // cfg.ssm_chunk) * d) * wide
         width = (cfg.kv_latent and cfg.v_head_dim) or cfg.head_dim
         return tokens * (
             _lanes(kind.n_heads * width) * act + _lanes(kind.n_heads) * 4
@@ -725,19 +813,36 @@ def rms_norm(x, scale, *, dtype, eps: float = 1e-6):
     return (norm * scale).astype(dtype)
 
 
+def _scale_init(offset: bool):
+    """A norm's learned w at the seed: zero where it scales by (1 + w)."""
+    return nn.initializers.zeros if offset else nn.initializers.ones
+
+
 class RMSNorm(nn.Module):
+    """`offset`: the learned w scales by (1 + w) and starts at zero
+    (`TransformerConfig.norm_unit_offset`), in place of w from one."""
+
     dtype: Any = jnp.bfloat16
     eps: float = 1e-6
+    offset: bool = False
 
     @nn.compact
     def __call__(self, x):
         scale = self.param(
             "scale",
-            nn.with_logical_partitioning(nn.initializers.ones, ("norm",)),
-            (x.shape[-1],),
-            jnp.float32,
+            nn.with_logical_partitioning(_scale_init(self.offset), ("norm",)),
+            (x.shape[-1],), jnp.float32,
         )
+        if self.offset:
+            scale = 1.0 + scale
         return rms_norm(x, scale, dtype=self.dtype, eps=self.eps)
+
+
+def _norm_cls(cfg: TransformerConfig):
+    """`RMSNorm` as the stack's configuration has it."""
+    return functools.partial(
+        RMSNorm, cfg.dtype, cfg.norm_eps, cfg.norm_unit_offset
+    )
 
 
 def _replicated(init, rank: int):
@@ -751,7 +856,9 @@ class Attention(nn.Module):
     k and v along the sequence when `cca` is on (OLMo's case is all of
     them off and equal heads); with `kv_latent`, latent attention
     (`_latent_qkv`): low-rank q and k/v with a norm between, two-part
-    scores over values of their own width."""
+    scores over values of their own width. With `qk_norm` a head's RMS
+    norm on q and k before rope; with `attention_gate` a sigmoid gate on
+    the output, a head's (`_gate`) or a channel's (`_channel_gate`)."""
 
     config: TransformerConfig
     mesh: Mesh | None = None
@@ -839,12 +946,7 @@ class Attention(nn.Module):
         gate = jax.nn.sigmoid(checkpoint_name(jnp.dot(
             x.astype(jnp.float32), wg, precision=jax.lax.Precision.HIGHEST
         ), GATE_RESULT))
-        # A gate stuck at 0 or 1 is the failure this shows; the step sums
-        # a counter over the layers that sow it.
-        self.sow(
-            "counters", "attn_gate_mean", jnp.mean(gate) / cfg.n_layers,
-            reduce_fn=lambda _, new: new, init_fn=lambda: 0.0,
-        )
+        self._sow_gate_mean(gate)
         width = heads * out.shape[-1]
         lanes_of = (
             jax.lax.broadcasted_iota(jnp.int32, (heads, width), 1)
@@ -853,6 +955,41 @@ class Attention(nn.Module):
         ).astype(cfg.dtype)
         wide = jnp.dot(gate.astype(cfg.dtype), lanes_of)  # [B, S, H·D]
         return (out.reshape(wide.shape) * wide).reshape(out.shape)
+
+    def _sow_gate_mean(self, gate):
+        """A gate stuck at 0 or 1 is the failure this shows; the step sums
+        a counter over the attention layers, which sow it."""
+        layers = sum(
+            kind.mixer == "attention" for kind in _attention_kinds(self.config)
+        )
+        self.sow(
+            "counters", "attn_gate_mean", jnp.mean(gate) / layers,
+            reduce_fn=lambda _, new: new, init_fn=lambda: 0.0,
+        )
+
+    def _channel_gate(self, out, gate):
+        """`out` [B, S, H, D] times `sigmoid(gate)`, gate [B, S, H·D] the
+        product q's own matrix gave beside q: a gate an output channel, the
+        sigmoid and the product in float32 on [B, S, H·D]."""
+        # The product is what is named (`SAVED_RESULTS`).
+        gate = jax.nn.sigmoid(
+            checkpoint_name(gate, GATE_RESULT).astype(jnp.float32)
+        )
+        self._sow_gate_mean(gate)
+        gated = out.reshape(gate.shape).astype(jnp.float32) * gate
+        return gated.astype(out.dtype).reshape(out.shape)
+
+    def _head_norm(self, u, name: str, d: int):
+        """u [B, S, H·d] under a head's RMS norm with ONE learned scale [d]
+        (`norm_unit_offset`: 1 + w), float32, on [B, S, H·d] where the
+        projection left it (`shortconv.head_sums`: no [B, S, H, d])."""
+        cfg = self.config
+        scale = _vector(
+            self, name, _scale_init(cfg.norm_unit_offset), d
+        ) + float(cfg.norm_unit_offset)
+        u32 = u.astype(jnp.float32)
+        r = jax.lax.rsqrt(shortconv.head_sums(u32 * u32, d) / d + cfg.norm_eps)
+        return (u32 * r * jnp.tile(scale, u.shape[-1] // d)).astype(u.dtype)
 
     @nn.nowrap
     def _by_parts(self, x, name: str, heads: int, widths: tuple[int, ...]):
@@ -894,7 +1031,7 @@ class Attention(nn.Module):
         [B, S, H·D], v [B, S, H·Dv], q_rope [B, S, H·R], k_rope [B, S, R]."""
         cfg = self.config
         h, d, r = kind.n_heads, cfg.head_dim, cfg.rope_head_dim
-        norm = functools.partial(RMSNorm, cfg.dtype, cfg.norm_eps)
+        norm = _norm_cls(cfg)
         # The products are what is named: a norm's backward reads its input.
         named = lambda u: checkpoint_name(u, ATTN_LATENT_RESULT)
         with jax.named_scope("attn.latent_q"):
@@ -957,13 +1094,21 @@ class Attention(nn.Module):
         # q, k and v stay [B, S, H·d] from the projections' matmuls to the
         # attention kernels (`_dot_folded`); only CCA's mixing splits the
         # heads out, and `attend` takes them as a reshape.
-        q = _dense((h, d), ("embed", "heads", "kv"), "wq", cfg.dtype)(x)
+        gate = None
+        if _gate_kind(cfg) == "channel":  # a head's q and gate, one matrix
+            q, gate = self._by_parts(x, "wq", h, (d, d))
+        else:
+            q = _dense((h, d), ("embed", "heads", "kv"), "wq", cfg.dtype)(x)
         k = _dense((hk, d), ("embed", "heads", "kv"), "wk", cfg.dtype)(x)
         v = _dense((hk, d), ("embed", "heads", "kv"), "wv", cfg.dtype)(x)
         if cfg.cca:
             with jax.named_scope("cca.mix"):
                 q, k, v = self._cca_mix(heads(q), heads(k), heads(v))
             q, k, v = (u.reshape(*u.shape[:2], -1) for u in (q, k, v))
+        if cfg.qk_norm:
+            with jax.named_scope("attn.qk_norm"):
+                q = self._head_norm(q, "q_norm", d)
+                k = self._head_norm(k, "k_norm", d)
         if kind.rope_fraction > 0:  # 0: no rotation (Nemotron-H's attention)
             how = {}
             if kind.rope_yarn is not None:
@@ -993,16 +1138,20 @@ class Attention(nn.Module):
                 heads(q), heads(k), heads(v), mesh=self.mesh,
                 impl=cfg.attention_impl, window=kind.window,
             )
-        return self._out(x, out, h)
+        return self._out(x, out, h, gate)
 
     @nn.nowrap
-    def _out(self, x, out, h: int):
+    def _out(self, x, out, h: int, gate=None):
         """Attention's output [B, S, H, D] through the gate (where the
-        stack has one) and the output projection."""
+        stack has one; `gate`: a channel gate's product) and the output
+        projection."""
         cfg = self.config
-        if cfg.attention_gate:
+        if _gate_kind(cfg):
             with jax.named_scope("attn.gate"):
-                out = self._gate(x, out, h)
+                out = (
+                    self._gate(x, out, h) if gate is None
+                    else self._channel_gate(out, gate)
+                )
         return _dense(
             cfg.d_model, ("heads", "kv", "embed"), "wo", cfg.dtype,
             axis=(-2, -1),
@@ -1076,10 +1225,13 @@ class ExpertLayer(nn.Module):
     `experts_per_token` largest of `p + b` are chosen (`b`, `router_bias`,
     a per-expert correction that gets no gradient: what a balancing rule
     would move, and nothing here does) and weigh `routed_scaling * p_e /
-    sum of the chosen p`. No state is carried (`router_state` passes
-    through). With `moe_latent` the experts read `x W_latent_in` and their
+    sum of the chosen p`. "softmax": the same with `p = softmax(x W_r)`
+    over all `num_experts` and no correction. No state is carried
+    (`router_state` passes through). With `moe_latent` the experts read `x W_latent_in` and their
     sum goes through `W_latent_out`; with `moe_shared_ff` a shared expert
-    of that width, which every token passes, is added. `mlp_act` says
+    of that width, which every token passes, is added, under
+    `moe_shared_gate` times `sigmoid(x w_s)`, `w_s` [d, 1], float32 at
+    full precision (sows `shared_gate_mean`). `mlp_act` says
     which expert: gated, or `relu(.)^2`.
 
     Nothing therefore keeps an untrained router even, and a dropless
@@ -1160,6 +1312,8 @@ class ExpertLayer(nn.Module):
         return expert, gate, r
 
     def _route_sigmoid(self, x):
+        """The one-matmul top-k routers, "sigmoid" and "softmax" (the name
+        is a frame of the accepted cells' scope tables: kept)."""
         cfg = self.config
         k, n = cfg.experts_per_token, cfg.num_experts
         w = self.param(
@@ -1170,11 +1324,16 @@ class ExpertLayer(nn.Module):
             ),
             (x.shape[-1], n), jnp.float32,
         )
-        bias = self.param(
-            "router_bias", _replicated(nn.initializers.zeros, 1), (n,),
-            jnp.float32,
+        bias = None  # a softmax router has no correction
+        if cfg.router == "sigmoid":
+            bias = self.param(
+                "router_bias", _replicated(nn.initializers.zeros, 1), (n,),
+                jnp.float32,
+            )
+        scores = jax.nn.sigmoid if cfg.router == "sigmoid" else (
+            functools.partial(jax.nn.softmax, axis=-1)
         )
-        probs = jax.nn.sigmoid(checkpoint_name(jnp.dot(
+        probs = scores(checkpoint_name(jnp.dot(
             x.astype(jnp.float32), w, precision=jax.lax.Precision.HIGHEST
         ), ROUTE_RESULT))
         if cfg.router_force_balance:
@@ -1183,7 +1342,10 @@ class ExpertLayer(nn.Module):
                 expert.reshape(x.shape[-2], k), (*probs.shape[:-1], k)
             )
         else:
-            _, expert = jax.lax.top_k(probs + jax.lax.stop_gradient(bias), k)
+            _, expert = jax.lax.top_k(
+                probs if bias is None else probs + jax.lax.stop_gradient(bias),
+                k,
+            )
         expert = checkpoint_name(expert.astype(jnp.int32), ROUTE_RESULT)
         # The gather is what the backward would form again (1.8 ms a layer
         # at 22 of 512, PERF.md §6 PR 38): its result is named too.
@@ -1204,12 +1366,12 @@ class ExpertLayer(nn.Module):
                 f"experts_held {cfg.experts_held} is not a range of the "
                 f"{cfg.num_experts} experts"
             )
-        if cfg.router not in ("mlp", "sigmoid") or (
+        if cfg.router not in ("mlp", "sigmoid", "softmax") or (
             cfg.router == "mlp" and cfg.experts_per_token != 1
         ):
             raise ValueError(
                 f"router {cfg.router!r} with {cfg.experts_per_token} experts "
-                "a token: expected 'mlp' (one a token) or 'sigmoid'"
+                "a token: expected 'mlp' (one a token), 'sigmoid' or 'softmax'"
             )
         with jax.named_scope("moe.route"):
             if cfg.router == "mlp":
@@ -1277,11 +1439,40 @@ class ExpertLayer(nn.Module):
                 )(out)
         if cfg.moe_shared_ff:
             with jax.named_scope("moe.shared"):
-                out = out + _mlp_cls(cfg)(
+                shared = _mlp_cls(cfg)(
                     dataclasses.replace(cfg, d_ff=cfg.moe_shared_ff),
                     name="shared",
                 )(x)
+                if not cfg.moe_shared_gate:
+                    out = out + shared
+            if cfg.moe_shared_gate:
+                with jax.named_scope("moe.shared_gate"):
+                    out = out + self._shared_gate(x, shared)
         return out, router_state
+
+    def _shared_gate(self, x, shared):
+        """`shared` times `sigmoid(x w_s)`, one gate a token."""
+        cfg = self.config
+        w = self.param(
+            "shared_gate",
+            nn.with_logical_partitioning(
+                nn.initializers.variance_scaling(1.0, "fan_in", "normal"),
+                ("embed", None),
+            ),
+            (x.shape[-1], 1), jnp.float32,
+        )
+        gate = jax.nn.sigmoid(jnp.dot(
+            x.astype(jnp.float32), w, precision=jax.lax.Precision.HIGHEST
+        ))
+        layers = (
+            cfg.layer_pattern.count("E") if cfg.layer_pattern is not None
+            else cfg.n_layers - cfg.dense_layers
+        )
+        self.sow(
+            "counters", "shared_gate_mean", jnp.mean(gate) / layers,
+            reduce_fn=lambda _, new: new, init_fn=lambda: 0.0,
+        )
+        return (shared.astype(jnp.float32) * gate).astype(shared.dtype)
 
 
 def _inverse_softplus(x):
@@ -1377,22 +1568,34 @@ class StateSpaceMixer(nn.Module):
 
 
 class DeltaMixer(nn.Module):
-    """Kimi Delta Attention's mixer over x [B, S, d_model], from the
-    configuration's numbers and the layer's row: H = `kind.n_heads` heads of
-    d = `head_dim` key and value channels, D = H d.
+    """The gated delta rule's mixer over x [B, S, d_model], from the
+    configuration's numbers and the layer's row (`AttentionKind`): H =
+    `kind.n_heads` value heads over H_k = `kind.key_heads` key heads of d =
+    the row's (or the stack's) `head_dim` key and value channels.
 
-    `q~ = x wq`, `k~ = x wk`, `v~ = x wv` (no bias); each through a causal
-    depthwise convolution of `ssm_conv` taps (no bias) and `silu`; a
-    head's `q = d^-1/2 q^ / |q^|`, `k = k^ / |k^|`; the decay a channel `g
-    = -exp(A_log[h]) softplus((x wf_a) wf_b + dt_bias)`, the low rank d; `b
-    = sigmoid(x wb)` a head, float32 at full precision; the delta rule
-    (`ops/kda.kda_scan`: `S_t = (I - b_t k_t k_t^T) diag(e^(g_t)) S_(t-1) +
-    b_t k_t v_t^T`, `o_t = S_t^T q_t`); `y = RMSNorm_head(o) w_n
-    sigmoid((x wg_a) wg_b)`, the norm over each head's d with ONE learned
-    scale [d]; `y wo`. q, k, v, g and o stay [B, S, H·d], as the kernels
-    read them. Sows `kda_decay_mean` (the mean of `e^g`: the share of the
-    state that survives a token) and `kda_beta_mean`, each over the count
-    of the stack's delta layers so that the step's sum is a mean."""
+    `q~ = x wq`, `k~ = x wk` (H_k heads), `v~ = x wv` (H heads), no bias;
+    each through a causal depthwise convolution of `ssm_conv` taps (no
+    bias) and `silu`; a head's `q = d^-1/2 q^ / |q^|`, `k = k^ / |k^|`; `b =
+    sigmoid(x wb)` a value head; the delta rule (`ops/kda.kda_scan`: `S_t =
+    (I - b_t k_t k_t^T) diag(e^(g_t)) S_(t-1) + b_t k_t v_t^T`, `o_t = S_t^T
+    q_t`, value head h reading key head h // (H / H_k)); `y =
+    RMSNorm_head(o) w_n act(gate)`, the norm over each head's d with ONE
+    learned scale [d], `act` the row's `gate_act`; `y wo`. By the row's
+    `decay`:
+
+    - "channel" (Kimi Delta Attention; H_k = H): `g = -exp(A_log[h])
+      softplus((x wf_a) wf_b + dt_bias)` a key CHANNEL and `gate = (x wg_a)
+      wg_b`, each through a bottleneck of d;
+    - "head" (Gated DeltaNet): `g = -exp(A_log[h]) softplus(x wa +
+      dt_bias[h])`, ONE a value head, beside b in float32 at full
+      precision, and `gate = x wg`, one matrix (projected beside q, k and
+      v: its product is `kda.proj`'s).
+
+    q, k, v and o stay [B, S, heads·d], as the kernels read them, and g is
+    as wide as it is published: [B, S, H·d] or [B, S, H]. Sows
+    `kda_decay_mean` (the mean of `e^g`: the share of the state that
+    survives a token) and `kda_beta_mean`, each over the count of the
+    stack's delta layers so that the step's sum is a mean."""
 
     config: TransformerConfig
     mesh: Mesh | None = None
@@ -1400,23 +1603,37 @@ class DeltaMixer(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        cfg = self.config
-        h, d, taps = self.kind.n_heads, cfg.head_dim, cfg.ssm_conv
+        cfg, kind = self.config, self.kind
+        h, d, taps = kind.n_heads, kind.head_dim or cfg.head_dim, cfg.ssm_conv
+        hk, by_head = kind.key_heads or h, kind.decay == "head"
         wide, f32 = h * d, jnp.float32
         vector = functools.partial(_vector, self)
+        matrix = lambda name, cols: self.param(
+            name,
+            nn.with_logical_partitioning(
+                nn.initializers.variance_scaling(1.0, "fan_in", "normal"),
+                ("embed", "heads"),
+            ),
+            (x.shape[-1], cols), f32,
+        )
         with jax.named_scope("kda.proj"):
             q, k, v = (
                 checkpoint_name(_dense(
-                    (h, d), ("embed", "heads", "kv"), name, cfg.dtype
+                    (n, d), ("embed", "heads", "kv"), name, cfg.dtype
                 )(x), KDA_PROJ_RESULT)
-                for name in ("wq", "wk", "wv")
+                for name, n in (("wq", hk), ("wk", hk), ("wv", h))
             )
+            if by_head:  # What is named is what the gated norm's backward reads.
+                gate = checkpoint_name(
+                    _dense(wide, ("embed", "heads"), "wg", cfg.dtype)(x),
+                    KDA_GATE_RESULT,
+                )
         with jax.named_scope("kda.conv"):
             def conv(u, name, scale=None):  # with a scale: a head's u / |u|
                 w = self.param(
                     f"conv_{name}",
                     _replicated(nn.initializers.normal(taps ** -0.5), 2),
-                    (taps, wide), f32,
+                    (taps, u.shape[-1]), f32,
                 )
                 return shortconv.short_conv(
                     u, w, sum_dtype=cfg.dtype, head_dim=d if scale else 0,
@@ -1426,26 +1643,31 @@ class DeltaMixer(nn.Module):
 
             q, k, v = conv(q, "q", d ** -0.5), conv(k, "k", 1.0), conv(v, "v")
         with jax.named_scope("kda.gates"):
-            low = _dense(d, ("embed", None), "wf_a", cfg.dtype)(x)
-            decay = checkpoint_name(
-                _dense(wide, (None, "heads"), "wf_b", cfg.dtype)(low),
-                KDA_DECAY_RESULT,
-            )
-            rate = jnp.repeat(jnp.exp(vector("A_log", _a_log, h)), d)
-            g = -rate * nn.softplus(
-                decay.astype(f32) + vector("dt_bias", _steps(cfg.ssm_dt), wide)
-            )
-            wb = self.param(
-                "wb",
-                nn.with_logical_partitioning(
-                    nn.initializers.variance_scaling(1.0, "fan_in", "normal"),
-                    ("embed", "heads"),
-                ),
-                (x.shape[-1], h), f32,
-            )
-            beta = jax.nn.sigmoid(jnp.dot(
-                x.astype(f32), wb, precision=jax.lax.Precision.HIGHEST
-            ))
+            if by_head:  # b and the decay: one product of 2 H columns
+                both = checkpoint_name(jnp.dot(
+                    x.astype(f32),
+                    jnp.concatenate([matrix("wb", h), matrix("wa", h)], axis=1),
+                    precision=jax.lax.Precision.HIGHEST,
+                ), KDA_DECAY_RESULT)
+                g = -jnp.exp(vector("A_log", _a_log, h)) * nn.softplus(
+                    both[..., h:] + vector("dt_bias", _steps(cfg.ssm_dt), h)
+                )
+                beta = jax.nn.sigmoid(both[..., :h])
+            else:
+                low = _dense(d, ("embed", None), "wf_a", cfg.dtype)(x)
+                decay = checkpoint_name(
+                    _dense(wide, (None, "heads"), "wf_b", cfg.dtype)(low),
+                    KDA_DECAY_RESULT,
+                )
+                rate = jnp.repeat(jnp.exp(vector("A_log", _a_log, h)), d)
+                g = -rate * nn.softplus(
+                    decay.astype(f32)
+                    + vector("dt_bias", _steps(cfg.ssm_dt), wide)
+                )
+                beta = jax.nn.sigmoid(jnp.dot(
+                    x.astype(f32), matrix("wb", h),
+                    precision=jax.lax.Precision.HIGHEST,
+                ))
             layers = sum(
                 kind.mixer == "delta" for kind in _attention_kinds(cfg)
             )
@@ -1460,16 +1682,17 @@ class DeltaMixer(nn.Module):
         with jax.named_scope("kda.scan"):
             o = kda_scan(q, k, v, g, beta, chunk=cfg.ssm_chunk, mesh=self.mesh)
         with jax.named_scope("kda.gate_norm"):
-            low = _dense(d, ("embed", None), "wg_a", cfg.dtype)(x)
-            # What is named is what the gated norm's backward reads.
-            gate = checkpoint_name(
-                _dense(wide, (None, "heads"), "wg_b", cfg.dtype)(low),
-                KDA_GATE_RESULT,
-            )
+            if not by_head:
+                low = _dense(d, ("embed", None), "wg_a", cfg.dtype)(x)
+                # What is named is what the gated norm's backward reads.
+                gate = checkpoint_name(
+                    _dense(wide, (None, "heads"), "wg_b", cfg.dtype)(low),
+                    KDA_GATE_RESULT,
+                )
             scale = jnp.tile(vector("norm_scale", nn.initializers.ones, d), h)
             y = checkpoint_name(gatenorm.gated_norm(
                 o, gate, scale, group=d, eps=cfg.norm_eps, gate_first=False,
-                mesh=self.mesh,
+                act=kind.gate_act, mesh=self.mesh,
             ), GATED_RESULT)
         with jax.named_scope("kda.out_proj"):
             return _dense(cfg.d_model, ("heads", "embed"), "wo", cfg.dtype)(y)
@@ -1593,7 +1816,7 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x, positions, router_state=None):
         cfg = self.config
-        norm = functools.partial(RMSNorm, cfg.dtype, cfg.norm_eps)
+        norm = _norm_cls(cfg)
         # The "mlp" policy's only checkpoint: the MLP half recomputes in
         # the backward, attention's residuals stay saved (the lifted
         # transform keeps the param path, so weights are identical to
@@ -1652,7 +1875,7 @@ class Sublayer(nn.Module):
     @nn.compact
     def __call__(self, x, positions, router_state=None):
         cfg, kind = self.config, self.kind
-        h = RMSNorm(cfg.dtype, cfg.norm_eps, name="ln")(x)
+        h = _norm_cls(cfg)(name="ln")(x)
         # As in `Block`: the "mlp" policy's only checkpoint.
         wrap = nn.remat if cfg.remat_policy == "mlp" else (lambda cls: cls)
         if kind == "M":
@@ -2064,7 +2287,7 @@ class TransformerLM(nn.Module):
                         jnp.float32
                     ) for i in range(n)
                 ).astype(cfg.dtype)
-        x = RMSNorm(cfg.dtype, cfg.norm_eps, name="ln_final")(x)
+        x = _norm_cls(cfg)(name="ln_final")(x)
         head = embed
         if not cfg.tie_embeddings:
             head = self.param(

@@ -3,16 +3,18 @@
 What a state-space mixer does between its scan and its out-projection, and
 a delta-rule mixer between the delta rule and its own, over o [B, S, W]
 and a gate [B, S, W] in bfloat16, a group of `group` lanes (a head, or a
-state-space group's channels), a learned scale [W] and `eps`, in the two
-orders the two published mixers use:
+state-space group's channels), a learned scale [W] and `eps`, in either
+order and under either activation `act` of the gate, `silu` or `sigmoid`:
 
-    gate first (Mamba-2):   s = o + d * x       the skip, where there is one:
-                                                x [B, S, W] bfloat16, d [W],
-                                                the sum float32, never rounded
-                            t = s silu(gate)
-                            out = scale t / sqrt(mean_group(t^2) + eps)
-    norm first (KDA):       out = scale s / sqrt(mean_group(s^2) + eps)
-                                  * sigmoid(gate)
+    s = o + d * x                   the skip, where there is one: x [B, S, W]
+                                    bfloat16, d [W], the sum float32, never
+                                    rounded
+    gate first:   t = s act(gate)
+                  out = scale t / sqrt(mean_group(t^2) + eps)
+    norm first:   out = scale s / sqrt(mean_group(s^2) + eps) * act(gate)
+
+(Mamba-2: gate first under `silu`; Kimi's delta mixer: norm first under
+`sigmoid`; Gated DeltaNet: norm first under `silu`.)
 
 XLA makes float32 elementwise passes over [tokens, W] of this: the widened
 operands, the activation, the squares' sum and its way back over the
@@ -90,20 +92,35 @@ def kernels_apply(
     )
 
 
+ACTIVATIONS = {"silu": jax.nn.silu, "sigmoid": jax.nn.sigmoid}
+
+
+def _activation_of(gate_first: bool, act: str | None) -> str:
+    """`act`, or the one the order's published mixer has."""
+    act = act or ("silu" if gate_first else "sigmoid")
+    if act not in ACTIVATIONS:
+        raise ValueError(
+            f"the gate's activation {act!r}: expected one of {sorted(ACTIVATIONS)}"
+        )
+    return act
+
+
 def gated_norm_plain(
-    o, gate, scale, group: int, eps: float, gate_first: bool, skip=None
+    o, gate, scale, group: int, eps: float, gate_first: bool, skip=None,
+    act: str | None = None,
 ):
     """`gated_norm` as XLA's passes (module docstring's two orders), every
     array float32 from the operands' casts to the result's."""
     f32 = jnp.float32
     s, gate = o.astype(f32), gate.astype(f32)
+    a = ACTIVATIONS[_activation_of(gate_first, act)]
     if skip is not None:
         x, d = skip
         s = s + d * x.astype(f32)
-    t = s * jax.nn.silu(gate) if gate_first else s
+    t = s * a(gate) if gate_first else s
     out = t * lax.rsqrt(head_sums(t * t, group) / group + eps) * scale
     if not gate_first:
-        out = out * jax.nn.sigmoid(gate)
+        out = out * a(gate)
     return out.astype(o.dtype)
 
 
@@ -121,7 +138,7 @@ def _block(seq: int, group: int) -> tuple[int, int]:
     return rows, _halved(rows, 16, max(_CHUNK // group, 16))
 
 
-def _normed(o_ref, gate_ref, x_ref, w_ref, rows, *, gate_first, inv, eps):
+def _normed(o_ref, gate_ref, x_ref, w_ref, rows, *, gate_first, act, inv, eps):
     """Rows of a block up to the norm: (s, sigmoid(gate), silu(gate), t =
     what the norm reads, r = `1 / sqrt(mean t² + eps)` a row)."""
     f32 = jnp.float32
@@ -134,33 +151,33 @@ def _normed(o_ref, gate_ref, x_ref, w_ref, rows, *, gate_first, inv, eps):
     half = 0.5 * gate_ref[rows, :].astype(f32)
     tanh = jnp.tanh(half)
     sig, silu = 0.5 * tanh + 0.5, half * tanh + half
-    t = s * silu if gate_first else s
+    t = s * (silu if act == "silu" else sig) if gate_first else s
     # a group's lane tiles added tile-wise, then one reduction a row group
     r = lax.rsqrt(jnp.sum(t * t, axis=1, keepdims=True) * inv + eps)
     return s, sig, silu, t, r
 
 
-def _fwd_kernel(*refs, chunk, skip, gate_first, inv, eps):
+def _fwd_kernel(*refs, chunk, skip, gate_first, act, inv, eps):
     o_ref, gate_ref, x_ref, w_ref, out_ref = (
         refs if skip else (*refs[:2], None, *refs[2:])
     )
 
     def body(first, carry):
         rows = pl.ds(first, chunk)
-        _, sig, _, t, r = _normed(
+        _, sig, silu, t, r = _normed(
             o_ref, gate_ref, x_ref, w_ref, rows, gate_first=gate_first,
-            inv=inv, eps=eps,
+            act=act, inv=inv, eps=eps,
         )
         out = t * (r * w_ref[0:1, :])
         if not gate_first:
-            out = out * sig
+            out = out * (silu if act == "silu" else sig)
         out_ref[rows, :] = out.astype(out_ref.dtype)
         return carry
 
     _chunks(o_ref.shape[0], chunk, body)
 
 
-def _bwd_kernel(*refs, chunk, skip, gate_first, inv, eps):
+def _bwd_kernel(*refs, chunk, skip, gate_first, act, inv, eps):
     f32 = jnp.float32
     if skip:
         (o_ref, gate_ref, x_ref, dout_ref, w_ref,
@@ -181,21 +198,26 @@ def _bwd_kernel(*refs, chunk, skip, gate_first, inv, eps):
         rows = pl.ds(first, chunk)
         s, sig, silu, t, r = _normed(
             o_ref, gate_ref, x_ref, w_ref, rows, gate_first=gate_first,
-            inv=inv, eps=eps,
+            act=act, inv=inv, eps=eps,
+        )
+        # the gate's activation and, where it is asked for, its slope
+        a, slope = (
+            (silu, lambda: sig + silu * (1.0 - sig)) if act == "silu"
+            else (sig, lambda: sig * (1.0 - sig))
         )
         dout = dout_ref[rows, :].astype(f32)
         scale = w_ref[0:1, :]
-        dn = dout if gate_first else dout * sig  # the norm's cotangent
+        dn = dout if gate_first else dout * a  # the norm's cotangent
         # n = c t r, r = (mean t² + eps)^-1/2: dt = r (c dn - t r² mean(c dn t))
         g = scale * dn
         dot = jnp.sum(g * t, axis=1, keepdims=True) * inv
         dt = r * (g - t * (r * r * dot))
         if gate_first:
-            ds = dt * silu
-            dgate = dt * s * (sig + silu * (1.0 - sig))
+            ds = dt * a
+            dgate = dt * s * slope()
         else:
             ds = dt
-            dgate = dout * (t * (r * scale)) * (sig * (1.0 - sig))
+            dgate = dout * (t * (r * scale)) * slope()
         do_ref[rows, :] = ds.astype(do_ref.dtype)
         dgate_ref[rows, :] = dgate.astype(dgate_ref.dtype)
         parts = [dn * t * r]  # the scale's gradient a row, then d's
@@ -225,7 +247,7 @@ def _table(scale, d):
 
 # Under `jit`: a stack's layers trace and lower each body once a program.
 _pass = functools.partial(jax.jit, static_argnames=(
-    "group", "gate_first", "eps", "interpret",
+    "group", "gate_first", "act", "eps", "interpret",
 ))
 
 
@@ -238,13 +260,13 @@ def _specs(o, group):
 
 
 @_pass
-def _fwd(o, gate, x, table, *, group, gate_first, eps, interpret):
+def _fwd(o, gate, x, table, *, group, gate_first, act, eps, interpret):
     grid, chunk, block, tab = _specs(o, group)
     operands = [o, gate] + ([] if x is None else [x])
     return pl.pallas_call(
         functools.partial(
             _fwd_kernel, chunk=chunk, skip=x is not None,
-            gate_first=gate_first, inv=1.0 / group, eps=eps,
+            gate_first=gate_first, act=act, inv=1.0 / group, eps=eps,
         ),
         grid=grid,
         in_specs=[block] * len(operands) + [tab(_TABLE)],
@@ -259,7 +281,7 @@ def _fwd(o, gate, x, table, *, group, gate_first, eps, interpret):
 
 
 @_pass
-def _bwd(o, gate, x, dout, table, *, group, gate_first, eps, interpret):
+def _bwd(o, gate, x, dout, table, *, group, gate_first, act, eps, interpret):
     """-> (do, dgate, dx or None, the scale's gradient [W], d's or None)."""
     grid, chunk, block, tab = _specs(o, group)
     skip = x is not None
@@ -268,7 +290,7 @@ def _bwd(o, gate, x, dout, table, *, group, gate_first, eps, interpret):
     *grads, partial = pl.pallas_call(
         functools.partial(
             _bwd_kernel, chunk=chunk, skip=skip, gate_first=gate_first,
-            inv=1.0 / group, eps=eps,
+            act=act, inv=1.0 / group, eps=eps,
         ),
         grid=grid,
         in_specs=[block] * len(operands) + [tab(_TABLE)],
@@ -289,44 +311,47 @@ def _bwd(o, gate, x, dout, table, *, group, gate_first, eps, interpret):
 
 def gated_norm(
     o, gate, scale, *, group: int, eps: float, gate_first: bool,
-    skip=None, mesh: Mesh | None = None, interpret: bool | None = None,
+    act: str | None = None, skip=None, mesh: Mesh | None = None,
+    interpret: bool | None = None,
 ):
     """o [B, S, W] through the gated norm a group of `group` lanes with
-    the learned `scale` [W]. `gate_first`: the gate's `silu` then the norm
-    (Mamba-2's order), else the norm then the gate's sigmoid (KDA's).
+    the learned `scale` [W]. `gate_first`: the gate's activation then the
+    norm (Mamba-2's order), else the norm then the gate's (the delta
+    mixers'). `act`: the gate's activation, "silu" or "sigmoid" (None: the
+    order's published mixer's, `silu` gate first, else `sigmoid`).
     `skip` = (x [B, S, W], d [W]) adds `d * x` to o in float32 first. The
     kernel pair where `kernels_apply` says so (or under the interpreter
     when `interpret` is True, as `ssd_scan` reads it), `gated_norm_plain`
     anywhere else."""
+    act = _activation_of(gate_first, act)
     if interpret is None and not kernels_apply(o, gate, group, mesh):
-        return gated_norm_plain(o, gate, scale, group, eps, gate_first, skip)
+        return gated_norm_plain(o, gate, scale, group, eps, gate_first, skip, act)
     x, d = skip or (None, None)
     return _gated_norm(
-        o, gate, x, d, scale, group, float(eps), bool(gate_first),
+        o, gate, x, d, scale, group, float(eps), (bool(gate_first), act),
         flash._auto_interpret(interpret),
     )
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
-def _gated_norm(o, gate, x, d, scale, group, eps, gate_first, interpret):
-    return _gated_norm_fwd(
-        o, gate, x, d, scale, group, eps, gate_first, interpret
-    )[0]
+def _gated_norm(o, gate, x, d, scale, group, eps, how, interpret):
+    """`how` = (gate first, the gate's activation)."""
+    return _gated_norm_fwd(o, gate, x, d, scale, group, eps, how, interpret)[0]
 
 
-def _gated_norm_fwd(o, gate, x, d, scale, group, eps, gate_first, interpret):
+def _gated_norm_fwd(o, gate, x, d, scale, group, eps, how, interpret):
     out = _fwd(
-        o, gate, x, _table(scale, d), group=group, gate_first=gate_first,
-        eps=eps, interpret=interpret,
+        o, gate, x, _table(scale, d), group=group, gate_first=how[0],
+        act=how[1], eps=eps, interpret=interpret,
     )
     return out, (o, gate, x, d, scale)
 
 
-def _gated_norm_bwd(group, eps, gate_first, interpret, residuals, dout):
+def _gated_norm_bwd(group, eps, how, interpret, residuals, dout):
     o, gate, x, d, scale = residuals
     do, dgate, dx, dscale, dd = _bwd(
         o, gate, x, dout, _table(scale, d), group=group,
-        gate_first=gate_first, eps=eps, interpret=interpret,
+        gate_first=how[0], act=how[1], eps=eps, interpret=interpret,
     )
     return (
         do, dgate, dx, None if d is None else dd.astype(d.dtype),

@@ -1,7 +1,13 @@
-"""Chunked gated delta rule (Kimi Delta Attention), forward and backward (Pallas TPU).
+"""Chunked gated delta rule in two forms, forward and backward (Pallas TPU).
 
 What `models/transformer.DeltaMixer` runs between its convolutions and its
-gated norm. Per head of `d` key and `d` value channels, with a state S
+gated norm: ONE rule, `kda_scan` its one entry, in the form the decay's
+shape asks for. A decay a key CHANNEL (Kimi Delta Attention) is the form
+described first; a decay a HEAD over grouped key heads (Gated DeltaNet) is
+the same rule with `diag(a_t) = a_t I`, whose chunk simplifies: see "the
+decay a head" below, `gdn_chunked` and the kernels `gdn_fwd` / `gdn_bwd`.
+
+Per head of `d` key and `d` value channels, with a state S
 [d, d] that starts at zero, a decay `a_t = exp(g_t)` a CHANNEL of the key
 (`g` < 0) and a step `b_t` in (0, 1) a head:
 
@@ -66,6 +72,23 @@ On the CPU backend (`ops/flash.kernels_compiled`) `kda_scan` runs
 `kda_chunked`, the same chunked arithmetic in plain `jax.numpy` with
 `jax`'s own gradient; `interpret=True` runs the kernels under the Pallas
 interpreter (tests).
+
+**The decay a head** (`g` [B, S, H] float32, q and k of H_k key heads, value
+head h reading key head h // (H / H_k)). A head's scalar factors out of `K
+K^T`: `A = strict_lower(b_t (k_t . k_s) e^(G_t - G_s))` is ONE [C, C]
+product and a mask of differences that are never positive (no sub-block,
+no clip), `e^G` scales whole rows of the products against the state, and
+the raw `K K^T` and `Q K^T` are a KEY head's, shared by the value heads
+that read it. `gdn_fwd` / `gdn_bwd` hold one chunk of `_HEADS_A_STEP` value
+heads and their key heads a program: q and k are read as they are stored
+([B, S, H_k·d], never repeated), g and b as one [C, 2·heads] float32 block
+(never widened to a head's lanes; a column becomes a row by a select
+against the diagonal), and the decay's gradient is taken from ONE array of
+pairs, added at a pair's row and taken at its column, then summed back
+along the chunk. o and the states are named as the channel form's, so
+`remat_policy="flash"` drops `gdn_fwd` from the backward likewise.
+`gdn_chunked` is the same arithmetic in `jax.numpy` (the CPU, float32, a
+mesh of several devices).
 """
 
 from __future__ import annotations
@@ -102,12 +125,27 @@ def _heads_a_step(heads: int) -> int:
 
 def kda_schedule(
     seq_len: int, *, heads: int, head_dim: int, chunk: int, batch: int = 1,
-    dtype_bytes: int = 2,
+    dtype_bytes: int = 2, key_heads: int | None = None,
 ) -> dict:
     """Static accounting of the calls `kda_scan` makes, for tests and
     benches: the grid, a program's heads, the sub-blocks of the in-chunk
-    products and what the forward saves for the backward."""
+    products and what the forward saves for the backward. `key_heads`: the
+    decay-a-head form over that many key heads (no sub-block: a head's
+    decay factors out of the chunk's products)."""
     chunks = -(-seq_len // chunk)
+    saved = batch * chunks * (
+        chunk * heads * head_dim + head_dim * heads * head_dim
+    ) * dtype_bytes
+    if key_heads is not None:
+        ks, hs = _group_heads(heads, key_heads)
+        return {
+            "form": "head", "chunks": chunks,
+            "padded_seq_len": chunks * chunk,
+            "grid": (batch, heads // hs, chunks),
+            "heads_a_step": hs, "key_heads_a_step": ks,
+            "saved_bytes_a_call": saved,
+            "state_scratch_bytes": head_dim * hs * head_dim * 4,
+        }
     hs = _heads_a_step(heads)
     n = chunk // _sub(chunk)
     return {
@@ -117,9 +155,7 @@ def kda_schedule(
         "heads_a_step": hs,
         "sub_block": _sub(chunk),
         "sub_block_pairs": n * (n + 1) // 2,
-        "saved_bytes_a_call": batch * chunks * (
-            chunk * heads * head_dim + head_dim * heads * head_dim
-        ) * dtype_bytes,
+        "saved_bytes_a_call": saved,
         "state_scratch_bytes": head_dim * hs * head_dim * 4,
     }
 
@@ -634,6 +670,392 @@ def _kda_kernels(q, k, v, g, b, *, chunk, interpret):
     return o
 
 
+# -- the decay a head: the second form ------------------------------------------
+#
+# `g` [B, S, H]: ONE decay a value head and token, `a_t I` in place of
+# `diag(a_t)`, over q and k of H_k heads, value head h reading key head
+# h // (H / H_k) (Gated DeltaNet). A scalar factors out of every product of
+# the chunk: with `G` the running sum of g and `D[t, s] = e^(G_t - G_s)` for
+# t >= s (never a positive exponent, so no sub-block and no clip),
+#
+#     A = strict_lower(diag(b) (K K^T) * D)        M = lower((Q K^T) * D)
+#     Vn = T (V - e^G * (K S))                     O = e^G * (Q S) + M Vn
+#     S' = e^(G_C) S + K^T (e^(G_C - G) * Vn)
+#
+# with `*` a row's scale. K K^T and Q K^T are raw products of a KEY head,
+# shared by the value heads that read it, and q, k enter every product as
+# they are stored: neither g widened to a head's lanes nor q, k repeated
+# is ever formed.
+
+
+def _group_heads(heads: int, key_heads: int) -> tuple[int, int]:
+    """(key heads, value heads) a grid step of the head form holds: whole
+    groups, `_HEADS_A_STEP` value heads where they divide."""
+    r = heads // key_heads
+    ks = math.gcd(key_heads, max(_HEADS_A_STEP // r, 1))
+    return ks, ks * r
+
+
+def gdn_chunked(q, k, v, g, b, *, chunk: int):
+    """The decay-a-head form in plain `jax.numpy`: q, k [B, S, H_k·d], v
+    [B, S, H·d], g (log decay, negative) and b [B, S, H] float32; S a
+    multiple of `chunk`. Matmul operands in q's dtype, sums and the state
+    float32, as the kernels. Returns o [B, S, H·d] float32."""
+    bsz, s, width = v.shape
+    h = b.shape[-1]
+    d = width // h
+    hk = k.shape[-1] // d
+    r, nc = h // hk, s // chunk
+    f32 = jnp.float32
+    lo = lambda u: u.astype(q.dtype)
+    dot = functools.partial(jnp.einsum, preferred_element_type=f32)
+    grouped = lambda u: u.reshape(bsz, nc, chunk, hk, r)
+    cum = jnp.cumsum(grouped(g), axis=2)                 # [B, nc, C, Hk, r]
+    beta = grouped(b)
+    qc, kc = (u.reshape(bsz, nc, chunk, hk, d) for u in (q, k))
+    t_at, s_at = _iotas(chunk)
+    over = lambda u: jnp.moveaxis(u, 2, -1)              # time last
+    gap = over(cum)[..., :, None] - over(cum)[..., None, :]
+    decay = jnp.where(
+        t_at >= s_at, jnp.exp(jnp.where(t_at >= s_at, gap, 0.0)), 0.0
+    )                                                    # [B, nc, Hk, r, C, C]
+    kk = dot("bcthd,bcshd->bchts", kc, kc)[:, :, :, None]
+    qk = dot("bcthd,bcshd->bchts", qc, kc)[:, :, :, None]
+    a = jnp.where(t_at > s_at, kk * decay * over(beta)[..., :, None], 0.0)
+    t = lo(_unit_lower_inverse(a) * over(beta)[..., None, :])
+    m = lo(qk * decay)
+    by_chunk = lambda u: jnp.moveaxis(u, 1, 0)
+
+    def step(state, xs):  # state [B, Hk, r, d_k, d_v] float32
+        qj, kj, vj, gj, tj, mj = xs
+        eg = jnp.exp(gj)[..., None]                      # [B, C, Hk, r, 1]
+        last = gj[:, -1:]
+        sb = lo(state)
+        rest = lo(vj.astype(f32) - eg * dot("bthk,bhrkv->bthrv", kj, sb))
+        vn = lo(dot("bhrts,bshrv->bthrv", tj, rest))
+        o = eg * dot("bthk,bhrkv->bthrv", qj, sb) + dot(
+            "bhrts,bshrv->bthrv", mj, vn
+        )
+        out = lo(vn.astype(f32) * jnp.exp(last - gj)[..., None])
+        state = state * jnp.exp(last)[:, 0, :, :, None, None] + dot(
+            "bthk,bthrv->bhrkv", kj, out
+        )
+        return state, o
+
+    _, o = lax.scan(
+        step, jnp.zeros((bsz, hk, r, d, d), f32),
+        (by_chunk(qc), by_chunk(kc),
+         by_chunk(v.reshape(bsz, nc, chunk, hk, r, d)), by_chunk(cum),
+         by_chunk(t), by_chunk(m)),
+    )
+    return jnp.moveaxis(o, 0, 1).reshape(bsz, s, width)
+
+
+def _to_row(col, eye):
+    """A column [C, 1] as the row [1, C], exactly (no transpose unit: a
+    select against the diagonal and a sum along the sublanes)."""
+    return jnp.sum(jnp.where(eye, col, 0.0), axis=0, keepdims=True)
+
+
+def _to_col(row, eye):
+    return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
+
+
+def _gdn_chunks(q_ref, k_ref, cols_ref, d: int, r: int, lo):
+    """What each head's chunk needs before the state. Lists over the
+    program's KEY heads (`q`, `k`, the raw products) and over its value
+    heads (the rest); `key[j]` is value head j's key head."""
+    size = q_ref.shape[1]
+    ks = q_ref.shape[2] // d
+    hs = ks * r
+    row, col = _iotas(size)
+    eye = row == col
+    ones = jnp.where(row >= col, 1.0, 0.0).astype(jnp.bfloat16)
+    cols = cols_ref[0, 0, 0]                             # [C, 2 hs]: g, then b
+    cum = _running(cols[:, :hs], _dot_nn, ones)
+    c = dict(
+        ones=ones, eye=eye, key=[j // r for j in range(hs)],
+        kcols=[slice(i * d, (i + 1) * d) for i in range(ks)],
+        vcols=[slice(j * d, (j + 1) * d) for j in range(hs)],
+    )
+    c["q"] = [q_ref[0, :, at] for at in c["kcols"]]
+    c["k"] = [k_ref[0, :, at] for at in c["kcols"]]
+    c["raw_k"] = _each(_dot_nt, c["k"], c["k"])
+    c["raw_q"] = _each(_dot_nt, c["q"], c["k"])
+    c["g"] = [cum[:, j:j + 1] for j in range(hs)]
+    c["b_col"] = [cols[:, hs + j:hs + j + 1] for j in range(hs)]
+    c["b_row"] = [_to_row(u, eye) for u in c["b_col"]]
+    c["decay"] = [
+        jnp.where(row >= col, jnp.exp(jnp.minimum(gj - _to_row(gj, eye), 0.0)), 0.0)
+        for gj in c["g"]
+    ]
+    c["a"] = [
+        jnp.where(row > col, c["raw_k"][i] * dj * bj, 0.0)
+        for i, dj, bj in zip(c["key"], c["decay"], c["b_col"])
+    ]
+    c["x"] = _inverses(c["a"], lo)
+    c["t"] = _each(lambda x, bj: lo(x * bj), c["x"], c["b_row"])
+    c["m32"] = [c["raw_q"][i] * dj for i, dj in zip(c["key"], c["decay"])]
+    c["m"] = _each(lo, c["m32"])
+    c["eg"] = _each(jnp.exp, c["g"])
+    c["last"] = [gj[size - 1:, :] for gj in c["g"]]
+    c["out"] = _each(lambda last, gj: jnp.exp(last - gj), c["last"], c["g"])
+    c["keep"] = _each(jnp.exp, c["last"])
+    # the same along a state's lanes ([1, 1] broadcasts one way at a time)
+    c["keep_row"] = [
+        jnp.exp(jnp.broadcast_to(gj, (size, d))[size - 1:, :]) for gj in c["g"]
+    ]
+    return c
+
+
+def _gdn_fwd_kernel(
+    q_ref, k_ref, v_ref, cols_ref, o_ref, st_ref, state, *, d: int, r: int,
+):
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        state[...] = jnp.zeros_like(state)
+
+    f32 = jnp.float32
+    lo = lambda u: u.astype(q_ref.dtype)
+    c = _gdn_chunks(q_ref, k_ref, cols_ref, d, r, lo)
+    cols = c["vcols"]
+    q = [c["q"][i] for i in c["key"]]
+    k = [c["k"][i] for i in c["key"]]
+    old = [state[:, at] for at in cols]          # S^T [d_v, d_k] float32
+    sb = _each(lo, old)
+    rest = _each(
+        lambda at, kj, s, eg: lo(v_ref[0, :, at].astype(f32) - eg * _dot_nt(kj, s)),
+        cols, k, sb, c["eg"],
+    )
+    vn = _each(lambda t, u: lo(_dot_nn(t, u)), c["t"], rest)
+    o = _each(
+        lambda qj, s, eg, m, u: eg * _dot_nt(qj, s) + _dot_nn(m, u),
+        q, sb, c["eg"], c["m"], vn,
+    )
+    new = _each(
+        lambda s, keep, u, out, kj: s * keep + _dot_tn(lo(u.astype(f32) * out), kj),
+        old, c["keep_row"], vn, c["out"], k,
+    )
+    for at, s, u, n in zip(cols, sb, o, new):
+        st_ref[0, 0, :, at] = s
+        o_ref[0, :, at] = u.astype(o_ref.dtype)
+        state[:, at] = n
+
+
+def _gdn_bwd_kernel(
+    q_ref, k_ref, v_ref, cols_ref, st_ref, do_ref,
+    dq_ref, dk_ref, dv_ref, dcols_ref, dstate, *, d: int, r: int,
+):
+    size = q_ref.shape[1]
+    f32 = jnp.float32
+
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        dstate[...] = jnp.zeros_like(dstate)
+
+    lo = lambda u: u.astype(q_ref.dtype)
+    c = _gdn_chunks(q_ref, k_ref, cols_ref, d, r, lo)
+    cols, eye, key = c["vcols"], c["eye"], c["key"]
+    row, col = _iotas(size)
+    at_last = lax.broadcasted_iota(jnp.int32, (size, 1), 0) == size - 1
+    q = [c["q"][i] for i in key]
+    k = [c["k"][i] for i in key]
+    sb = [st_ref[0, 0, :, at] for at in cols]    # S^T entering the chunk
+    do = [do_ref[0, :, at] for at in cols]
+    d_new = [dstate[:, at] for at in cols]       # dS'^T [d_v, d_k] float32
+    d_new_lo = _each(lo, d_new)
+    # Vn again, from the saved state.
+    ks_ = _each(_dot_nt, k, sb)
+    qs_ = _each(_dot_nt, q, sb)
+    rest = _each(
+        lambda at, u, eg: lo(v_ref[0, :, at].astype(f32) - eg * u),
+        cols, ks_, c["eg"],
+    )
+    vn = _each(lambda t, u: lo(_dot_nn(t, u)), c["t"], rest)
+    vo = _each(lambda u, out: lo(u.astype(f32) * out), vn, c["out"])
+    dvo = _each(_dot_nt, k, d_new_lo)
+    dvn = _each(
+        lambda m, u, out, w: lo(_dot_tn(m, u) + out * w),
+        c["m"], do, c["out"], dvo,
+    )
+    dm = [jnp.where(row >= col, _dot_nt(u, w), 0.0) for u, w in zip(do, vn)]
+    dt = _each(_dot_nt, dvn, rest)
+    dr = _each(_dot_tn, c["t"], dvn)             # = dV [C, d_v]
+    do32 = [u.astype(f32) for u in do]
+    dqs = _each(lambda eg, u: lo(eg * u), c["eg"], do32)
+    dks = _each(lambda eg, u: lo(-eg * u), c["eg"], dr)
+    # The running decay's gradient a row: through e^G, e^(G_C - G), e^(G_C).
+    through = _each(
+        lambda w, u, out: jnp.sum(w * u.astype(f32), axis=1, keepdims=True) * out,
+        dvo, vn, c["out"],
+    )
+    dg = _each(
+        lambda eg, u, a, w, b_, th: eg * jnp.sum(
+            w * b_ - u * a, axis=1, keepdims=True
+        ) - th,
+        c["eg"], dr, ks_, do32, qs_, through,
+    )
+    tail = _each(
+        lambda th, keep, dn, s: jnp.sum(th, axis=0, keepdims=True) + keep * jnp.sum(
+            jnp.sum(dn * s.astype(f32), axis=1, keepdims=True), axis=0,
+            keepdims=True,
+        ),
+        through, c["keep"], d_new, sb,
+    )
+    d_old = _each(
+        lambda dn, keep, u, qj, w, kj: dn * keep + _dot_tn(u, qj) + _dot_tn(w, kj),
+        d_new, c["keep_row"], dqs, q, dks, k,
+    )
+    # Through T = X diag(b), X = (I + A)^-1, A = diag(b) strict(K K^T * D)
+    # and M = lower(Q K^T * D). What a pair (t, s) adds to G at its row it
+    # takes at its column, from ONE array: the two cancel past the pair.
+    x_lo = _each(lo, c["x"])
+    db_row = _each(lambda u, x: jnp.sum(u * x, axis=0, keepdims=True), dt, c["x"])
+    da = _each(lambda x, u, bj: lo(_dot_tn(x, lo(u * bj))), x_lo, dt, c["b_row"])
+    da = _each(lambda u, x: jnp.where(row > col, -_dot_nt(u, x), 0.0), da, x_lo)
+    pairs = _each(
+        lambda u, a, w, m: u * a + w * m, da, c["a"], dm, c["m32"]
+    )
+    dg = _each(
+        lambda u, p: u + jnp.sum(p, axis=1, keepdims=True) - _to_col(
+            jnp.sum(p, axis=0, keepdims=True), eye
+        ),
+        dg, pairs,
+    )
+    db = [
+        jnp.sum(u * c["raw_k"][i] * dj, axis=1, keepdims=True) + _to_col(w, eye)
+        for u, i, dj, w in zip(da, key, c["decay"], db_row)
+    ]
+    draw_k = _each(lambda u, dj, bj: u * dj * bj, da, c["decay"], c["b_col"])
+    draw_q = _each(jnp.multiply, dm, c["decay"])
+    dq_heads = _each(_dot_nn, dqs, sb)
+    dk_heads = _each(
+        lambda u, s, w, dn: _dot_nn(u, s) + _dot_nn(w, dn), dks, sb, vo, d_new_lo
+    )
+    for i, at in enumerate(c["kcols"]):
+        mine = [j for j, of in enumerate(key) if of == i]
+        of_k = lo(sum(draw_k[j] for j in mine))
+        of_q = lo(sum(draw_q[j] for j in mine))
+        dq_ref[0, :, at] = (
+            sum(dq_heads[j] for j in mine) + _dot_nn(of_q, c["k"][i])
+        ).astype(dq_ref.dtype)
+        dk_ref[0, :, at] = (
+            sum(dk_heads[j] for j in mine) + _dot_tn(of_q, c["q"][i])
+            + _dot_nn(of_k, c["k"][i]) + _dot_tn(of_k, c["k"][i])
+        ).astype(dk_ref.dtype)
+    for j, at in enumerate(cols):
+        dstate[:, at] = d_old[j]
+        dv_ref[0, :, at] = dr[j].astype(dv_ref.dtype)
+    # The gradient of the running sum, summed back from each row to the
+    # chunk's last: g's own.
+    dg = jnp.concatenate(
+        [jnp.where(at_last, u + tl, u) for u, tl in zip(dg, tail)], axis=1
+    )
+    dcols_ref[0, 0, 0] = jnp.concatenate(
+        [_running(dg, _dot_tn, c["ones"]), *db], axis=1
+    )
+
+
+def _gdn_specs(c: int, ks: int, hs: int, d: int, chunk_of):
+    return {
+        "key": pl.BlockSpec((1, c, ks * d), lambda b, h, i: (b, chunk_of(i), h)),
+        "value": pl.BlockSpec((1, c, hs * d), lambda b, h, i: (b, chunk_of(i), h)),
+        "col": pl.BlockSpec(
+            (1, 1, 1, c, 2 * hs), lambda b, h, i: (b, h, chunk_of(i), 0, 0)
+        ),
+        "state": pl.BlockSpec(
+            (1, 1, d, hs * d), lambda b, h, i: (b, chunk_of(i), 0, h)
+        ),
+    }
+
+
+@functools.partial(jax.jit, static_argnames=("d", "chunk", "interpret"))
+def _gdn_fwd(q, k, v, cols, *, d, chunk, interpret):
+    bsz, s, width = v.shape
+    steps, hs = cols.shape[1], cols.shape[4] // 2
+    ks = q.shape[2] // (steps * d)
+    nc = s // chunk
+    spec = _gdn_specs(chunk, ks, hs, d, lambda i: i)
+    return pl.pallas_call(
+        functools.partial(_gdn_fwd_kernel, d=d, r=hs // ks),
+        grid=(bsz, steps, nc),
+        in_specs=[spec[x] for x in ("key", "key", "value", "col")],
+        out_specs=[spec["value"], spec["state"]],
+        out_shape=[
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
+            jax.ShapeDtypeStruct((bsz, nc, d, width), q.dtype),
+        ],
+        scratch_shapes=[pltpu.VMEM((d, hs * d), jnp.float32)],
+        compiler_params=_SEMANTICS,
+        interpret=interpret,
+        name="gdn_fwd",
+    )(q, k, v, cols)
+
+
+@functools.partial(jax.jit, static_argnames=("d", "chunk", "interpret"))
+def _gdn_bwd(q, k, v, cols, states, do, *, d, chunk, interpret):
+    bsz, s, _ = v.shape
+    steps, hs = cols.shape[1], cols.shape[4] // 2
+    ks = q.shape[2] // (steps * d)
+    nc = s // chunk
+    spec = _gdn_specs(chunk, ks, hs, d, lambda i: nc - 1 - i)
+    like = lambda u: jax.ShapeDtypeStruct(u.shape, u.dtype)
+    return pl.pallas_call(
+        functools.partial(_gdn_bwd_kernel, d=d, r=hs // ks),
+        grid=(bsz, steps, nc),
+        in_specs=[spec[x] for x in (
+            "key", "key", "value", "col", "state", "value"
+        )],
+        out_specs=[spec[x] for x in ("key", "key", "value", "col")],
+        out_shape=[like(q), like(k), like(v), like(cols)],
+        scratch_shapes=[pltpu.VMEM((d, hs * d), jnp.float32)],
+        compiler_params=_SEMANTICS,
+        interpret=interpret,
+        name="gdn_bwd",
+    )(q, k, v, cols, states, do)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _gdn_core(q, k, v, cols, d, chunk, interpret):
+    """(o, states) of the head form, as `_kda_core`'s: `cols` [B, steps,
+    chunks, C, 2·hs] float32 is g then b of a grid step's value heads, time
+    along the sublanes (one layout: the kernels turn a column to a row
+    themselves)."""
+    return _gdn_vjp_fwd(q, k, v, cols, d, chunk, interpret)[0]
+
+
+def _gdn_vjp_fwd(q, k, v, cols, d, chunk, interpret):
+    o, states = _gdn_fwd(q, k, v, cols, d=d, chunk=chunk, interpret=interpret)
+    o = checkpoint_name(o, CHECKPOINT_OUT_NAME)
+    states = checkpoint_name(states, CHECKPOINT_STATES_NAME)
+    return (o, states), (q, k, v, cols, states)
+
+
+def _gdn_vjp_bwd(d, chunk, interpret, residuals, cts):
+    do, _ = cts
+    return tuple(_gdn_bwd(
+        *residuals, do, d=d, chunk=chunk, interpret=interpret
+    ))
+
+
+_gdn_core.defvjp(_gdn_vjp_fwd, _gdn_vjp_bwd)
+
+
+def _gdn_kernels(q, k, v, g, b, *, chunk, interpret):
+    """The head form's kernels over a sequence of whole chunks; g and b
+    side by side a grid step's heads are XLA's (two [tokens, H] arrays)."""
+    bsz, s, width = v.shape
+    h = b.shape[-1]
+    d = width // h
+    _, hs = _group_heads(h, k.shape[-1] // d)
+    by_step = lambda u: u.reshape(bsz, s // chunk, chunk, h // hs, hs)
+    cols = jnp.concatenate([by_step(g), by_step(b)], axis=-1)
+    o, _ = _gdn_core(
+        q, k, v, cols.transpose(0, 3, 1, 2, 4), d, chunk, interpret
+    )
+    return o
+
+
 def _kda_plain(q, k, v, g, b, *, chunk):
     """`kda_chunked`, o named as the kernels'."""
     return checkpoint_name(
@@ -642,22 +1064,54 @@ def _kda_plain(q, k, v, g, b, *, chunk):
     )
 
 
+def _head_form_kernels(v, mesh: Mesh | None) -> bool:
+    """Whether the head form runs `gdn_fwd` / `gdn_bwd`: where kernels
+    compile, over bfloat16 operands, on one device (no `shard_map` of it
+    is written: a mesh of several devices runs `gdn_chunked`)."""
+    return (
+        kernels_compiled() and v.dtype == jnp.bfloat16
+        and (mesh is None or mesh.size == 1)
+    )
+
+
 def kda_scan(
     q, k, v, g, b, *, chunk: int, mesh: Mesh | None = None,
     interpret: bool | None = None,
 ):
-    """o [B, S, H·d] of the gated delta rule over q, k, v [B, S, H·d] with
-    g [B, S, H·d] (float32 log decay a key channel, negative) and b
-    [B, S, H] (float32, in (0, 1)). The kernels wherever they compile (or
-    under the interpreter when `interpret` is True), the plain chunked form
-    on the CPU. A Pallas call does not partition itself under `jit`, so
-    with a mesh the kernels run in `shard_map` over the batch axes and,
-    where `tp` divides the heads, whole heads over `tp`."""
+    """o [B, S, H·d] of the gated delta rule over v [B, S, H·d] and b
+    [B, S, H] (float32, in (0, 1)), in the form the decay's shape says:
+
+    - g [B, S, H·d] (float32 log decay a key CHANNEL, negative), q and k
+      [B, S, H·d]: the channel form. The kernels `kda_fwd` / `kda_bwd`
+      wherever they compile (or under the interpreter when `interpret` is
+      True), `kda_chunked` on the CPU. A Pallas call does not partition
+      itself under `jit`, so with a mesh the kernels run in `shard_map`
+      over the batch axes and, where `tp` divides the heads, whole heads
+      over `tp`.
+    - g [B, S, H] (ONE decay a value head), q and k [B, S, H_k·d] with H_k
+      dividing H, value head h reading key head h // (H / H_k): the head
+      form. The kernels `gdn_fwd` / `gdn_bwd` where `_head_form_kernels`
+      says so (or under the interpreter), `gdn_chunked` on the CPU, in
+      float32 and on a mesh of several devices.
+
+    Either way o and the chunk states carry `CHECKPOINT_OUT_NAME` /
+    `CHECKPOINT_STATES_NAME` where the kernels ran."""
     h = b.shape[-1]
-    if q.shape[-1] % h or not (q.shape == k.shape == v.shape == g.shape):
+    by_head = g.shape == b.shape
+    d = max(v.shape[-1] // h, 1)
+    if by_head:  # q and k of key heads that divide the value heads
+        fits = (
+            q.shape[:2] == v.shape[:2] and q.shape[-1] % d == 0
+            and h % max(q.shape[-1] // d, 1) == 0
+        )
+    else:
+        fits = q.shape == v.shape == g.shape
+    if v.shape[-1] % h or q.shape != k.shape or not fits:
         raise ValueError(
             f"q {q.shape}, k {k.shape}, v {v.shape}, g {g.shape} over {h} "
-            "heads: one shape, its last axis whole heads"
+            "heads: one shape, its last axis whole heads (a decay a channel), "
+            "or g as b and q, k of key heads that divide the value heads (a "
+            "decay a head)"
         )
     g, b = g.astype(jnp.float32), b.astype(jnp.float32)
     s = q.shape[1]
@@ -667,7 +1121,17 @@ def kda_scan(
         # corrected nor takes input.
         grow = lambda u: jnp.pad(u, ((0, 0), (0, pad), (0, 0)))
         q, k, v, g, b = grow(q), grow(k), grow(v), grow(g), grow(b)
-    if interpret is None and not kernels_compiled():
+    if by_head:
+        if interpret is None and not _head_form_kernels(v, mesh):
+            o = checkpoint_name(
+                gdn_chunked(q, k, v, g, b, chunk=chunk).astype(v.dtype),
+                CHECKPOINT_OUT_NAME,
+            )
+        else:
+            o = _gdn_kernels(
+                q, k, v, g, b, chunk=chunk, interpret=bool(interpret)
+            )
+    elif interpret is None and not kernels_compiled():
         o = _kda_plain(q, k, v, g, b, chunk=chunk)
     elif mesh is None:
         o = _kda_kernels(q, k, v, g, b, chunk=chunk, interpret=bool(interpret))

@@ -172,6 +172,23 @@ def test_grouped_head_flash_compiles_at_the_zaya_cell_shape(one_chip):
     assert tpu_kernel_calls(text) == len(names)
 
 
+def test_heads_of_256_compile_at_the_qwen3_next_cell_shape(one_chip):
+    """16 query heads of 256 over 2 K/V heads at S = 8192, batch 2
+    (`qwen3-next-80b-a3b-ep16.train-8k`'s one attention layer): twice the
+    width every other cell has, eight query heads a K/V head; the schedule
+    keeps blocks of 1,024 and the fused backward inside its VMEM budget,
+    and the chip's compiler takes both."""
+    sched = flash_schedule(8192, 8192, head_dim=256, dtype_bytes=2)
+    assert sched["layout"] == "seq_major" and sched["bwd_fused"]
+    assert (sched["block_q"], sched["block_k"]) == (1024, 1024)
+    assert sched["bwd_fused_vmem_bytes"] <= _FUSED_VMEM_BUDGET
+    q = jax.ShapeDtypeStruct((2, 8192, 16, 256), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((2, 8192, 2, 256), jnp.bfloat16, sharding=one_chip)
+    text, names = _compile(jax.grad(_loss, argnums=(0, 1, 2)), q, kv, kv)
+    assert names == ["flash_fwd_compact", "flash_delta", "flash_bwd_fused"]
+    assert tpu_kernel_calls(text) == len(names)
+
+
 # (B, S, H, Hkv) of a layer's attention in each cell: `olmo-1b-cut.train-2k`,
 # `-8k`, `zaya1-8b-ep2.train-8k`, a shard of `olmo-1b.train-2k-dp2tp2`, and
 # `nemotron-3-super-tp2ep64.train-8k`'s 16 query heads over one K/V head.
@@ -483,11 +500,39 @@ def test_delta_rule_kernels_compile_at_the_kimi_cell_shape(one_chip):
     assert tpu_kernel_calls(text) == 2
 
 
+def test_delta_rule_head_form_kernels_compile_at_the_qwen3_next_cell_shape(one_chip):
+    """The delta rule with a decay a HEAD, forward and backward, at the
+    cell's size: two sequences of 8,192, 32 value heads of 128 over 16 key
+    heads, chunks of 128, 8 value heads over 4 key heads a program
+    (`ops/kda.py`): g and b enter as one [C, 16] float32 block a program,
+    q and k as 4 lane tiles where v has 8, and a head's columns of it are
+    lane slices of a value."""
+    from kubeflow_tpu.ops import kda
+
+    shape = lambda dims, dtype: jax.ShapeDtypeStruct(
+        dims, dtype, sharding=one_chip
+    )
+    narrow = shape((2, 8192, 16 * 128), jnp.bfloat16)
+    per_head = shape((2, 8192, 32), jnp.float32)
+
+    def loss(q, k, v, g, b):
+        o = kda.kda_scan(q, k, v, g, b, chunk=128, interpret=False)
+        return o.astype(jnp.float32).sum()
+
+    text, names = _compile(
+        jax.grad(loss, argnums=(0, 1, 2, 3, 4)), narrow, narrow,
+        shape((2, 8192, 32 * 128), jnp.bfloat16), per_head, per_head,
+    )
+    assert names == ["gdn_fwd", "gdn_bwd"]
+    assert tpu_kernel_calls(text) == 2
+
+
 @pytest.mark.parametrize("width, how", [
     (32 * 128, dict(sum_dtype=jnp.bfloat16, head_dim=128, scale=128 ** -0.5)),
     (32 * 128, dict(sum_dtype=jnp.bfloat16)),
     (4096 + 2 * 4 * 128, dict(bias=True)),
-], ids=["kimi q and k", "kimi v", "nemotron xBC"])
+    (16 * 128, dict(sum_dtype=jnp.bfloat16, head_dim=128, scale=128 ** -0.5)),
+], ids=["kimi q and k", "kimi v", "nemotron xBC", "qwen3-next q and k"])
 def test_short_convolution_kernels_compile_at_the_cells_shapes(
     one_chip, width, how
 ):
@@ -523,7 +568,9 @@ def test_short_convolution_kernels_compile_at_the_cells_shapes(
 @pytest.mark.parametrize("how", [
     dict(group=1024, gate_first=True, skip=True),
     dict(group=128, gate_first=False, skip=False),
-], ids=["nemotron: silu, groups of 1,024, the skip", "kimi: heads of 128, sigmoid"])
+    dict(group=128, gate_first=False, skip=False, act="silu"),
+], ids=["nemotron: silu, groups of 1,024, the skip", "kimi: heads of 128, sigmoid",
+        "qwen3-next: heads of 128, the norm then silu"])
 def test_gated_norm_kernels_compile_at_the_cells_shapes(one_chip, how):
     """The recurrent mixers' gated norm, forward and backward, at one
     sequence of 8,192 by 4,096 lanes (`ops/gatenorm.py`): blocks one group
@@ -541,7 +588,8 @@ def test_gated_norm_kernels_compile_at_the_cells_shapes(one_chip, how):
     def loss(o, gate, scale, skip):
         y = gatenorm.gated_norm(
             o, gate, scale, group=how["group"], eps=1e-5,
-            gate_first=how["gate_first"], skip=skip, interpret=False,
+            gate_first=how["gate_first"], act=how.get("act"), skip=skip,
+            interpret=False,
         )
         return y.astype(jnp.float32).sum()
 

@@ -18,17 +18,17 @@ F32, BF16 = jnp.float32, jnp.bfloat16
 EPS = 1e-5
 
 
-def plain(o, gate, scale, x=None, d=None, *, group, gate_first):
+def plain(o, gate, scale, x=None, d=None, *, group, gate_first, act=None):
     return gatenorm.gated_norm_plain(
         o, gate, scale, group=group, eps=EPS, gate_first=gate_first,
-        skip=None if x is None else (x, d),
+        skip=None if x is None else (x, d), act=act,
     )
 
 
-def kernels(o, gate, scale, x=None, d=None, *, group, gate_first):
+def kernels(o, gate, scale, x=None, d=None, *, group, gate_first, act=None):
     return gatenorm.gated_norm(
         o, gate, scale, group=group, eps=EPS, gate_first=gate_first,
-        skip=None if x is None else (x, d), interpret=True,
+        skip=None if x is None else (x, d), act=act, interpret=True,
     )
 
 
@@ -52,13 +52,22 @@ FORMS = {
     "a head's norm then sigmoid": dict(
         group=128, gate_first=False, skip=False, width=256,
     ),
+    # the activation apart from the order (Gated DeltaNet's: norm, then silu)
+    "a head's norm then silu": dict(
+        group=128, gate_first=False, skip=False, width=256, act="silu",
+    ),
+    "sigmoid then a group's norm": dict(
+        group=256, gate_first=True, skip=False, width=512, act="sigmoid",
+    ),
 }
 NAMES = ("o", "gate", "scale", "x", "d")
 
 
 def _held_to_plain(form, shape, seed=0):
     spec = FORMS[form]
-    how = dict(group=spec["group"], gate_first=spec["gate_first"])
+    how = dict(
+        group=spec["group"], gate_first=spec["gate_first"], act=spec.get("act")
+    )
     args, dout = _drawn((*shape, spec["width"]), spec["skip"], seed)
     got, got_vjp = jax.vjp(functools.partial(kernels, **how), *args)
     want, want_vjp = jax.vjp(functools.partial(plain, **how), *args)
@@ -98,7 +107,9 @@ def test_lane_slices_of_wider_arrays_are_operands_like_any(form):
     72.5 and 40 lane tiles wide: sliced out, the same values and
     gradients, each gradient of the slice's own shape."""
     spec = FORMS[form]
-    how = dict(group=spec["group"], gate_first=spec["gate_first"])
+    how = dict(
+        group=spec["group"], gate_first=spec["gate_first"], act=spec.get("act")
+    )
     (o, gate, scale, x, d), dout = _drawn(
         (2, 256, spec["width"]), spec["skip"], seed=1
     )
@@ -195,7 +206,9 @@ def test_where_the_kernels_apply(case, monkeypatch):
 @pytest.mark.parametrize("form", sorted(FORMS))
 def test_the_pair_is_one_call_each_way_under_its_names(form):
     spec = FORMS[form]
-    how = dict(group=spec["group"], gate_first=spec["gate_first"])
+    how = dict(
+        group=spec["group"], gate_first=spec["gate_first"], act=spec.get("act")
+    )
     args, dout = _drawn((1, 256, spec["width"]), spec["skip"])
     run = functools.partial(kernels, **how)
     forward = jax.make_jaxpr(run)(*args)

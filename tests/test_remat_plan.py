@@ -117,6 +117,22 @@ CELL_PLANS = {
         },
         (), 13_596_111_628,
     ),
+    "qwen3-next-80b-a3b-ep16.train-8k": (
+        6_256_671_372, 2_502_668_544,
+        # Three delta-rule layers with a decay a head: q and k at 16 key
+        # heads beside v at 32, the gate's product as wide as v, the decay's
+        # (with b's beside it) [tokens, 64] float32 in one lane tile; the one
+        # attention layer's q, k, v at heads of 256 and its gate a channel,
+        # the product beside q's, as wide.
+        {
+            "attn_gate": 134_217_728, "moe_route": 234_881_024,
+            "attn_residual": 268_435_456, "kda_proj": 805_306_368,
+            "mlp_hidden": 134_217_728, "attn_qkv": 167_772_160,
+            "kda_conv": 805_306_368, "mixer_gated": 402_653_184,
+            "kda_decay": 25_165_824, "kda_gate": 402_653_184,
+        },
+        (), 15_316_368_012,
+    ),
     "zaya1-8b-ep2.train-8k": (
         9_223_475_372, 3_689_390_144,
         # CCA: q, k and v are no candidate (its backward forms them again).
@@ -639,6 +655,43 @@ def test_a_delta_rule_layer_keeps_its_kernels_results_and_counts_its_work():
     )
 
 
+def test_a_decay_a_head_over_grouped_key_heads_counts_its_own_widths():
+    """The qwen3-next cell's rows: a delta layer's q and k are its KEY
+    heads wide, its decay one float32 a value head (a lane tile a token, not
+    a head's lanes), its heads' width the row's own beside attention's 256;
+    the channel gate's product is as wide as q; o and the chunk states are
+    a VALUE head's, kept whatever the plan."""
+    from kubeflow_tpu.ops import kda
+
+    cfg, tokens, _ = _cell("qwen3-next-80b-a3b-ep16.train-8k")
+    assert tokens == 16_384 and cfg.head_dim == 256
+    delta, attention = cfg.attention_kinds
+    assert (delta.n_heads, delta.key_heads, delta.head_dim) == (32, 16, 128)
+    assert (delta.decay, delta.gate_act) == ("head", "silu")
+    layers = transformer._result_bytes(cfg, tokens)
+    assert [("kda_proj" in layer, "attn_qkv" in layer) for layer in layers] == [
+        (True, False), (True, False), (True, False), (False, True)
+    ]
+    keys, wide = tokens * 2048, tokens * 4096
+    assert layers[0]["kda_proj"] == layers[0]["kda_conv"] == (2 * keys + wide) * 2
+    assert layers[0]["kda_decay"] == tokens * 128 * 4   # [tokens, 64] in a tile
+    assert layers[0]["kda_work"] == 2 * layers[0]["kda_decay"]
+    for name in ("mixer_gated", "kda_gate"):
+        assert layers[0][name] == wide * 2 and name not in layers[3]
+    assert layers[3]["attn_gate"] == tokens * 16 * 256 * 2
+    assert layers[3]["attn_qkv"] == tokens * (16 + 2 + 2) * 256 * 2
+    # softmax over 512 and three [tokens, 10] arrays, a lane tile each
+    assert layers[0]["moe_route"] == tokens * 4 * (512 + 3 * 128)
+    sched = kda.kda_schedule(
+        8192, heads=32, key_heads=16, head_dim=128, chunk=cfg.ssm_chunk, batch=2
+    )
+    stream = tokens * 2048 * 2
+    full = tokens * (4096 * 2 + 128 * 4)  # o and a log-sum-exp a head
+    assert transformer._kept_always_bytes(cfg, tokens) == (
+        4 * stream + 3 * sched["saved_bytes_a_call"] + full
+    )
+
+
 @pytest.mark.parametrize("family, calls", [
     ("blocks of delta mixers as kernels beside un-rotated latent attention", 3),
     ("a pattern of mixers as kernels, relu2 experts and attention", 1),
@@ -678,8 +731,9 @@ def test_a_name_only_a_mixer_forms_costs_the_other_cells_nothing(
 ):
     """`mixer_gated` is formed by a recurrent mixer alone: without it in
     `SAVED_RESULTS` the cells with no such mixer get the plan they get
-    with it, byte for byte, and the two with one lose that name alone
-    (`kda_gate`, the delta mixer's own, likewise costs kimi alone)."""
+    with it, byte for byte, and the three with one lose that name alone
+    (`kda_gate`, the delta mixer's own, likewise costs kimi and qwen3-next
+    alone)."""
     cfg, tokens, trainer = _cell(name)
     stated = dataclasses.replace(trainer.step_memory(), limit_bytes=V5E_LIMIT)
     plan = remat_plan(cfg, tokens, stated)
@@ -687,11 +741,14 @@ def test_a_name_only_a_mixer_forms_costs_the_other_cells_nothing(
         n for n in SAVED_RESULTS if n != transformer.GATED_RESULT
     ))
     without = remat_plan(cfg, tokens, stated)
-    if name.startswith(("kimi", "nemotron")):
+    if name.startswith(("kimi", "nemotron", "qwen3-next")):
         assert set(plan.names) - set(without.names) == {"mixer_gated"}
+        layers_by_tokens = {"nemotron": 5, "kimi": 4, "qwen3-next": 2 * 3}
         assert plan.saved_bytes - without.saved_bytes == dict(plan.bytes)[
             "mixer_gated"
-        ] == 8192 * 4096 * 2 * (5 if name.startswith("nemotron") else 4)
+        ] == 8192 * 4096 * 2 * next(
+            n for who, n in layers_by_tokens.items() if name.startswith(who)
+        )
     else:
         assert plan == without
 
