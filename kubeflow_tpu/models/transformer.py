@@ -43,7 +43,8 @@ from kubeflow_tpu.ops.kda import (
     kda_scan,
 )
 from kubeflow_tpu.ops.moe import (
-    BLOCK_ROWS, expert_mlp_on_mesh, row_tiles, tiles_in_use,
+    BLOCK_ROWS, CHECKPOINT_ROWS_NAME as MOE_ROWS_NAME, expert_mlp_on_mesh,
+    row_tiles, tiles_in_use,
 )
 from kubeflow_tpu.ops.rope import rope, yarn_inv_freq
 from kubeflow_tpu.ops.ssd import (
@@ -380,10 +381,12 @@ def _attention_kinds(cfg: TransformerConfig) -> list[AttentionKind]:
 # What `remat_policy="flash"` always keeps: the results of the attention,
 # scan and delta-rule kernels, which name them themselves (`ops/flash.py`,
 # `ops/ssd.py`, `ops/kda.py`), so that no forward kernel runs again in the
-# backward.
+# backward, and the expert layer's rows' tokens (`ops/moe.py`: int32, a
+# row each, 0.7 MB a layer in the widest cell; the grouped matmuls' and
+# the movers' results, the worst-case row buffer, are formed again).
 KERNEL_RESULTS = (
     CHECKPOINT_OUT_NAME, CHECKPOINT_LSE_NAME, SSD_OUT_NAME, SSD_STATES_NAME,
-    KDA_OUT_NAME, KDA_STATES_NAME,
+    KDA_OUT_NAME, KDA_STATES_NAME, MOE_ROWS_NAME,
 )
 # Results the layers name where they form them (`checkpoint_name`: a name
 # lowers to no operation), for `remat_plan` to keep as many of as the
@@ -652,7 +655,8 @@ def _kept_always_bytes(cfg: "TransformerConfig", tokens: int) -> int:
     """Bytes every layer's checkpoint holds whatever the plan:
     its inputs (the residual stream; the router's carried state) and
     `KERNEL_RESULTS` (attention's output and log-sum-exp, the scan's or
-    the delta rule's output and chunk states)."""
+    the delta rule's output and chunk states; not the expert layer's rows'
+    tokens, 4 bytes a row of its buffer: under a MB a layer)."""
     act = jnp.dtype(cfg.dtype).itemsize
     stream = tokens * _stream_lanes(cfg) * act
     if cfg.num_experts > 0 and cfg.router == "mlp":

@@ -20,7 +20,35 @@ x capacity` and not the buffer's length.
   t's expert and `n_tiles` how many tiles are in use. Pairs routed to
   experts held elsewhere get no row. A tile never straddles two experts,
   so the kernels need no masks: a grouped matmul is a tiled matmul whose
-  weight block is picked by a scalar-prefetched table.
+  weight block is picked by a scalar-prefetched table. What is built
+  from what: `mine` [n_held, N] (token n is routed to held expert e)
+  from the ids by compares; `count`, its running sum along the tokens,
+  gives a pair's place among its expert's rows and, at its end, the
+  expert's tiles; `tile_expert` is a compare of the tiles' numbers with
+  the experts' last tiles; a token's rows (`token_rows`), its slots'
+  weights (`slot_weights`) and their transpose are selects and sums over
+  `[h, n_held, N]`, which the vector unit does at its own rate. The one
+  step that turns "token n has a row of expert e" round into "row r is
+  token n's" is the kernel `moe_plan_rows`, at k > 1, over the row tiles
+  IN USE (its grid ends at `n_tiles`): row i of expert e is the token
+  `#{n : count[e, n] <= i}`, counted in two levels: the whole lines of
+  128 tokens whose last count is at or under i (a compare with the
+  lines' ends), then, in the next line, the tokens at or under i; a
+  row's line is picked from the expert's `[N / 128, 128]` counts by a
+  product with a one at its number, a byte a product so that it is exact.
+  The backward's `moe_plan_weights` picks a row's weight the same way,
+  `held[e, n]` at the row's token, where a scatter of `h x N` weights
+  stood, and `moe_plan_tokens`, its transpose, puts a row's product with
+  its token's cotangent at the token's place in its expert's block (the
+  weights' gradient), where a gather of `h x N` elements stood: the
+  combine takes its weights by held expert, `[n_held, N]`
+  (`held_weights`). No step is sized by `n_held x N` or `h x N` single
+  elements:
+  XLA scatters one element in 5 ns on this chip, 524,288 of them twice a
+  layer (forward, and again under remat) to place 10,240 rows until
+  PR 46. (At one expert a token the plan scatters a row's token for each
+  of N tokens and the movers are XLA's gathers: `moe_schedule()`'s
+  `plan_updates`.)
 - **`moe_gmm_fwd` / `moe_gmm_dlhs`**: `out[rows of e] = lhs[rows of e] @
   w[e]` (or `@ w[e].T` for the operand's gradient). Grid (column tiles,
   row tiles, contraction tiles); consecutive row tiles of one expert keep
@@ -72,8 +100,11 @@ again in the backward, so no bfloat16 copy of an expert's weights is kept
 as a residual. On the CPU backend the kernels run under the Pallas
 interpreter (tests), which fills unwritten memory with NaN; anywhere else
 they are compiled (`ops/flash.kernels_compiled`). Every `pallas_call` has
-a `name=` starting `moe_gmm_` or `moe_rows_`: what a device trace keys
-their time on.
+a `name=` starting `moe_gmm_`, `moe_rows_` or `moe_plan_`: what a device
+trace keys their time on. `expert_mlp` forms the plan and the weights by
+held expert and by slot under the scope `moe.plan` (the backward's two
+`moe_plan_*` calls too), beside `moe.dispatch`, `moe.experts` and
+`moe.combine`.
 """
 
 from __future__ import annotations
@@ -85,6 +116,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, PartitionSpec as P
@@ -93,6 +125,10 @@ from kubeflow_tpu.ops.flash import kernels_compiled
 from kubeflow_tpu.parallel.sharding import batch_axes
 
 BLOCK_ROWS = 256
+# `jax.checkpoint_name` of the rows' tokens, `moe_plan_rows`' result: a
+# policy that keeps it (`remat_policy="flash"`) runs the kernel once a
+# layer, forward, and not again for the backward.
+CHECKPOINT_ROWS_NAME = "moe_plan_rows"
 # Tile caps: a weight block of 2048 x 2048 bf16 is 8 MiB (16 double-
 # buffered), which keeps an expert of this width to one fetch a pass; a
 # side may be as long as 4096 where the other is short (1024 x 2688: one
@@ -104,6 +140,7 @@ _VMEM_LIMIT = 48 * 1024 * 1024
 _SUBLANES = 8  # a float32 tile's height: the least a DMA may slice
 _SUM_TOKENS = 256  # tokens a grid step of `moe_rows_sum`
 _SUM_VMEM = 8 * 1024 * 1024  # its gathered rows, [slots, tokens, d] float32
+_PLAN_ROWS = 128  # rows a pass of the `moe_plan_*` kernels: 16 registers a line
 # The forms of expert by their count of matrices: what the last matmul on
 # the rows makes of its product (`_activate`, `_slope`).
 _FORMS = {2: "relu2", 3: "gated"}
@@ -143,27 +180,29 @@ def tiles_in_use(counts, block_rows: int = BLOCK_ROWS):
     return jnp.maximum(-(-counts // block_rows), 1)
 
 
-def plan_dispatch(expert, lo, n_held: int, block_rows: int = BLOCK_ROWS):
+def plan_dispatch(expert, lo, n_held: int, block_rows: int = BLOCK_ROWS,
+                  interpret: bool | None = None):
     """Where each token-expert pair's row is, from the expert ids.
 
     expert: [N] or [N, k] int32 (a token's k experts all differ), ids
     over ALL experts; this shard holds `lo .. lo + n_held - 1`. An
     expert's rows are in the order of their tokens. Everything comes from
     `mine` [n_held, N] (is token n routed to held expert e) by cumulative
-    sums and selects over [n_held, N]; the one scatter is of a row's
-    token. Returns a dict: `tile_expert` [tiles] (local expert of each
-    row tile), `n_tiles` [1] (tiles in use), and for [N]: `dst` [N] (the
-    token's row, or `rows` = out of range where its expert is held
-    elsewhere), `src` [rows] (the row's token, or N for a row of
-    padding). For [N, k] a token's rows side by side: of its k pairs at
-    most `h = min(k, n_held)` are held here, in the order of their
-    experts, so `token_rows` [h, N] lists them (`rows` where it has
-    fewer), `token_count` [N] says how many it has, `row_token` [rows] is
-    a row's token (N for padding), and `hit` [k, n_held, N] (pair j of
-    token n is held expert e) with `place` [h, n_held, N] (held expert e
-    is token n's slot s) carry a pair's weight to its slot
-    (`slot_weights`): the movers read h slots a token, not k (8 for 22
-    in the Nemotron cell).
+    sums and selects over [n_held, N]; a row's token is counted from
+    `count`, an expert's running count of its tokens, over the tiles in
+    use (`moe_plan_rows`; at [N] the one scatter, of N tokens). Returns a
+    dict: `tile_expert` [tiles] (local expert of each row tile), `n_tiles`
+    [1] (tiles in use), and for [N]: `dst` [N] (the token's row, or
+    `rows` = out of range where its expert is held elsewhere), `src`
+    [rows] (the row's token, or N for a row of padding). For [N, k] a
+    token's rows side by side: of its k pairs at most `h = min(k,
+    n_held)` are held here, in the order of their experts, so
+    `token_rows` [h, N] lists them (`rows` where it has fewer),
+    `token_count` [N] says how many it has, `row_token` [rows] is a row's
+    token (N for padding), and `hit` [k, n_held, N] (pair j of token n is
+    held expert e) with `place` [h, n_held, N] (held expert e is token
+    n's slot s) carry a pair's weight to its slot (`slot_weights`): the
+    movers read h slots a token, not k (8 for 22 in the Nemotron cell).
     """
     by_pairs = expert.ndim == 2
     pairs = expert.T if by_pairs else expert[None, :]
@@ -175,43 +214,61 @@ def plan_dispatch(expert, lo, n_held: int, block_rows: int = BLOCK_ROWS):
     )[None, :, None]
     mine = jnp.any(hit, axis=0)
     ones = mine.astype(jnp.int32)
-    group_tiles = tiles_in_use(jnp.sum(ones, axis=1), block_rows)
+    count = jnp.cumsum(ones, axis=1)
+    group_tiles = tiles_in_use(count[:, -1], block_rows)
     ends = jnp.cumsum(group_tiles)
-    first_row = (ends - group_tiles) * block_rows
+    tile_first = (ends - group_tiles).astype(jnp.int32)
     row = jnp.where(
-        mine, first_row[:, None] + jnp.cumsum(ones, axis=1) - 1, rows
+        mine, (tile_first * block_rows)[:, None] + count - 1, rows
     ).astype(jnp.int32)
-    token = jnp.arange(tokens, dtype=jnp.int32)
     tile_expert = jnp.minimum(
-        jnp.searchsorted(ends, jnp.arange(tiles), side="right"), n_held - 1
+        jnp.searchsorted(
+            ends, jnp.arange(tiles), side="right", method="compare_all"
+        ),
+        n_held - 1,
     ).astype(jnp.int32)
-    plan = {"tile_expert": tile_expert, "n_tiles": ends[-1:].astype(jnp.int32)}
-    row_token = lambda at, of: jnp.full((rows,), tokens, jnp.int32).at[at].set(
-        of, mode="drop"
-    )
+    n_tiles = ends[-1:].astype(jnp.int32)
+    plan = {"tile_expert": tile_expert, "n_tiles": n_tiles}
     if not by_pairs:
         dst = jnp.min(row, axis=0)
-        plan.update(dst=dst, src=row_token(dst, token))
+        src = jnp.full((rows,), tokens, jnp.int32).at[dst].set(
+            jnp.arange(tokens, dtype=jnp.int32), mode="drop"
+        )
+        plan.update(dst=dst, src=src)
         return plan
     h = min(k, n_held)
     slot = jnp.cumsum(ones, axis=0) - 1
     place = mine[None] & (
         slot[None] == jnp.arange(h, dtype=jnp.int32)[:, None, None]
     )
+    in_use = jnp.arange(rows, dtype=jnp.int32) // block_rows < n_tiles
     plan.update(
         hit=hit, place=place,
         token_rows=jnp.min(jnp.where(place, row[None], rows), axis=1),
         token_count=jnp.sum(ones, axis=0),
-        row_token=row_token(row.reshape(-1), jnp.tile(token, n_held)),
+        row_token=checkpoint_name(jnp.where(in_use, _plan_rows(
+            count, tile_expert, n_tiles, tile_first, block_rows=block_rows,
+            interpret=_interpreted(interpret),
+        ), tokens), CHECKPOINT_ROWS_NAME),
     )
     return plan
 
 
+def held_weights(gate, plan):
+    """`gate` [N, k] by held expert, [n_held, N] (zeros where token n is
+    not routed to e): selects and sums, so its transpose is too."""
+    return jnp.sum(jnp.where(plan["hit"], gate.T[:, None, :], 0.0), axis=0)
+
+
+def _by_slot(held, plan):
+    """[n_held, N] by a token's slots, [h, N] (zeros where it has fewer)."""
+    return jnp.sum(jnp.where(plan["place"], held[None], 0.0), axis=1)
+
+
 def slot_weights(gate, plan):
     """`gate` [N, k] by a token's slots, [h, N] (zeros where it has
-    fewer): selects and sums, so its transpose is too."""
-    held = jnp.sum(jnp.where(plan["hit"], gate.T[:, None, :], 0.0), axis=0)
-    return jnp.sum(jnp.where(plan["place"], held[None], 0.0), axis=1)
+    fewer)."""
+    return _by_slot(held_weights(gate, plan), plan)
 
 
 def moe_schedule(
@@ -225,7 +282,11 @@ def moe_schedule(
     once at 2 bytes (4 where packed) and every held expert's weights once
     a column tile. `activation` says for each form of expert who forms
     its activation and slope, `results_past_live` how many kernel results
-    are written past the tiles in use."""
+    are written past the tiles in use, `plan_updates` how many single
+    elements the plan and its backward scatter: none at k > 1 (a row's
+    token is counted by `moe_plan_rows` and its weight picked by
+    `moe_plan_weights`, a grid step a tile in use each), a row's token
+    for each of the N tokens at k = 1."""
     tiles = row_tiles(tokens, k, held, block_rows)
     live = tiles if live_tiles is None else live_tiles
     tc, to = _gmm_tiles(width_in, width_out)
@@ -241,6 +302,8 @@ def moe_schedule(
         "activation": {form: "kernel" for form in _FORMS.values()},
         "results_past_live": 0,
         "movers": "moe_rows" if k > 1 else "xla_gather",
+        "plan_updates": 0 if k > 1 else tokens,
+        "plan_rows_grid_steps": live if k > 1 else 0,
         "rows_take_grid_steps": tiles,
         "rows_take_bytes": rows(live) * width_in * (4 + 2),
         "rows_sum_grid_steps": tokens // _sum_tokens(tokens, min(k, held), width_in),
@@ -745,6 +808,214 @@ def _rows_sum(rows, token_rows, token_count, weight=None, *, width, out_dtype,
       *([by_step(weight)] if weighted else []))
 
 
+# -- the plan's rows at k > 1 --------------------------------------------------
+
+
+def _by_bytes(bits, parts: int, product):
+    """`product` of int32 `bits` taken a byte at a time and put together
+    again: whole numbers under 256 are exact in bfloat16, so a product
+    with zeros and a single one moves any 32 bits, a float's too, to the
+    bit."""
+    return functools.reduce(jnp.bitwise_or, (
+        product(
+            ((bits >> (8 * p)) & 255).astype(jnp.float32).astype(jnp.bfloat16)
+        ).astype(jnp.int32) << (8 * p)
+        for p in range(parts)
+    ))
+
+
+def _plan_kernel(te_ref, nt_ref, *refs, weighted: bool, parts: int):
+    del nt_ref  # the grid's length
+    first_ref, index_ref, table_ref, out_ref = refs if not weighted else (
+        None, *refs
+    )
+    t = pl.program_id(0)
+    rows = out_ref.shape[2]
+    lines, lanes = table_ref.shape[1:]
+    table = table_ref[0]
+    step = min(rows, _PLAN_ROWS)
+    for r in range(0, rows, step):
+        if weighted:  # the rows' tokens, along the lanes: a token's line
+            token = _turned(index_ref[0, :, r:r + step])
+            line, lane = token // lanes, token % lanes
+        else:  # the expert's count at each line's end: the lines before
+            number = (t - first_ref[te_ref[t]]) * rows + r + (
+                lax.broadcasted_iota(jnp.int32, (step, 1), 0)
+            )
+            line = jnp.sum(
+                (index_ref[0] <= number).astype(jnp.int32), axis=1, keepdims=True
+            )
+        its = (
+            lax.broadcasted_iota(jnp.int32, (step, lines), 1) == line
+        ).astype(jnp.float32).astype(jnp.bfloat16)  # no line: zeros
+        picked = _by_bytes(table, parts, lambda plane: jnp.dot(
+            its, plane, preferred_element_type=jnp.float32
+        ))
+        if weighted:
+            at = lax.broadcasted_iota(jnp.int32, (step, lanes), 1) == lane
+            found = jnp.sum(jnp.where(at, picked, 0), axis=1, keepdims=True)
+        else:
+            found = jnp.minimum(line * lanes + jnp.sum(
+                (picked <= number).astype(jnp.int32), axis=1, keepdims=True
+            ), lines * lanes)
+        out_ref[0, :, r:r + step] = _turned(found)
+
+
+def _interpreted(interpret: bool | None) -> bool:
+    """The kernels' form where the caller leaves it open: compiled
+    wherever the backend compiles them (`ops/flash.kernels_compiled`)."""
+    return not kernels_compiled() if interpret is None else interpret
+
+
+def _token_lines(tokens: int, interpret: bool) -> tuple[int, int]:
+    """(lines, lanes) of an expert's tokens as the plan's kernels hold
+    them, `[N / 128, 128]` (one line where N is not made of them, which
+    only the interpreter takes)."""
+    if not interpret and tokens % 128:
+        raise ValueError(
+            "more than one expert a token: the plan's rows are compiled "
+            f"for tokens in whole lines of 128, not {tokens}"
+        )
+    lanes = 128 if tokens % 128 == 0 else tokens
+    return tokens // lanes, lanes
+
+
+@functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
+def _plan_rows(table, tile_expert, n_tiles, tile_first=None, row_token=None, *,
+               block_rows, interpret):
+    """What each row in use finds in its held expert's line of `table`
+    [n_held, N]; tiles past `n_tiles` are not visited (the grid ends
+    there). `table` the expert's running count of its tokens, with
+    `tile_first` [n_held]: the row's token, row i of expert e being
+    `#{n : count[e, n] <= i}` (N where e has no such row): the whole
+    lines of `lanes` tokens that end at or under i, counted from the
+    lines' last counts, and of the next line, picked by a product with a
+    one at its number, the tokens at or under i. `table` float32 with
+    `row_token` [rows]: its value at the row's token, found the same way
+    (zero for a row of padding). [rows] of the table's dtype; nothing is
+    sized by more than the rows in use times a line."""
+    n_held, tokens = table.shape
+    lines, lanes = _token_lines(tokens, interpret)
+    tiles = tile_expert.shape[0]
+    weighted = row_token is not None
+    by_expert = lambda *block: pl.BlockSpec(
+        (1, *block), lambda t, te, *_: (te[t], 0, 0)
+    )
+    by_tile = pl.BlockSpec((1, 1, block_rows), lambda t, *_: (t, 0, 0))
+    bits = lax.bitcast_convert_type(table, jnp.int32)
+    if weighted:
+        scalars = (tile_expert, n_tiles)
+        index, index_spec = row_token.reshape(tiles, 1, block_rows), by_tile
+    else:
+        scalars = (tile_expert, n_tiles, tile_first)
+        index = lax.slice_in_dim(  # the lines' last counts
+            table.reshape(n_held, lines, lanes), lanes - 1, lanes, axis=2
+        ).reshape(n_held, 1, lines)
+        index_spec = by_expert(1, lines)
+    out = pl.pallas_call(
+        functools.partial(
+            _plan_kernel, weighted=weighted,
+            parts=4 if weighted else -(-tokens.bit_length() // 8),
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars),
+            grid=(n_tiles[0],),
+            in_specs=[index_spec, by_expert(lines, lanes)],
+            out_specs=by_tile,
+        ),
+        out_shape=jax.ShapeDtypeStruct((tiles, 1, block_rows), jnp.int32),
+        compiler_params=_params(("arbitrary",)),
+        interpret=interpret,
+        name="moe_plan_weights" if weighted else "moe_plan_rows",
+    )(*scalars, index, bits.reshape(n_held, lines, lanes))
+    return lax.bitcast_convert_type(out.reshape(tiles * block_rows), table.dtype)
+
+
+def rows_values(plan, value, interpret: bool | None = None):
+    """`value[e, n]` [n_held, N] float32 at each row in use, e its
+    tile's expert and n its token (zero for a row of padding); tiles
+    past `n_tiles` are left alone. No element is scattered or gathered
+    one by one (`_plan_rows`)."""
+    te, row_token = plan["tile_expert"], plan["row_token"]
+    return _plan_rows(
+        value, te, plan["n_tiles"], row_token=row_token,
+        block_rows=row_token.shape[0] // te.shape[0],
+        interpret=_interpreted(interpret),
+    )
+
+
+def _tokens_kernel(te_ref, nt_ref, token_ref, value_ref, out_ref):
+    del nt_ref  # the grid's length
+    t = pl.program_id(0)
+    rows = token_ref.shape[2]
+    lines, lanes = out_ref.shape[1:]
+
+    @pl.when((t == 0) | (te_ref[jnp.maximum(t - 1, 0)] != te_ref[t]))
+    def _an_experts_first_tile():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    step = min(rows, _PLAN_ROWS)
+    for r in range(0, rows, step):
+        token = _turned(token_ref[0, :, r:r + step])
+        bits = _turned(value_ref[0, :, r:r + step])
+        its = (  # a row of padding (token N) has no line: zeros
+            lax.broadcasted_iota(jnp.int32, (step, lines), 1) == token // lanes
+        ).astype(jnp.float32).astype(jnp.bfloat16)
+        at = lax.broadcasted_iota(jnp.int32, (step, lanes), 1) == token % lanes
+        # a token is one row's: each place is a sum of one term
+        out_ref[0] |= _by_bytes(
+            jnp.where(at, bits, 0), 4, lambda plane: lax.dot_general(
+                its, plane, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ),
+        )
+
+
+@functools.partial(jax.jit, static_argnames=("n_held", "tokens", "interpret"))
+def _plan_tokens(value, row_token, tile_expert, n_tiles, *, n_held, tokens,
+                 interpret):
+    """`_plan_rows`' transpose: `value` [rows] float32 at each held
+    expert's tokens, [n_held, N], zeros where token n is not e's. A row
+    in use puts its value at its token's place in its expert's `[N / 128,
+    128]` block, which stays in VMEM over the expert's tiles: a product
+    of the rows' lines with their values at their lanes, a byte a
+    product."""
+    lines, lanes = _token_lines(tokens, interpret)
+    tiles = tile_expert.shape[0]
+    by_tile = pl.BlockSpec(
+        (1, 1, row_token.shape[0] // tiles), lambda t, *_: (t, 0, 0)
+    )
+    out = pl.pallas_call(
+        _tokens_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n_tiles[0],),
+            in_specs=[by_tile, by_tile],
+            out_specs=pl.BlockSpec(
+                (1, lines, lanes), lambda t, te, nt: (te[t], 0, 0)
+            ),
+        ),
+        out_shape=jax.ShapeDtypeStruct((n_held, lines, lanes), jnp.int32),
+        compiler_params=_params(("arbitrary",)),
+        interpret=interpret,
+        name="moe_plan_tokens",
+    )(tile_expert, n_tiles, row_token.reshape(tiles, 1, -1),
+      lax.bitcast_convert_type(value, jnp.int32).reshape(tiles, 1, -1))
+    return lax.bitcast_convert_type(out, value.dtype).reshape(n_held, tokens)
+
+
+def tokens_values(plan, value, interpret: bool | None = None):
+    """`rows_values`' transpose: `value` [rows] float32, a number a row,
+    at each held expert's tokens: [n_held, N], `value[r]` where row r is
+    expert e's and token n's, zeros where token n is not routed to e
+    (`_plan_tokens`)."""
+    _, n_held, tokens = plan["hit"].shape
+    return _plan_tokens(
+        value, plan["row_token"], plan["tile_expert"], plan["n_tiles"],
+        n_held=n_held, tokens=tokens, interpret=_interpreted(interpret),
+    )
+
+
 # -- dispatch, matmuls, combine ------------------------------------------------
 
 
@@ -825,8 +1096,8 @@ _experts_in.defvjp(_experts_in_fwd, _experts_in_bwd)
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
 def _experts_out(hidden, w_down, weight, plan, how: _How):
     """`hidden @ w_down[e]` [rows, d], then each token's rows by their
-    weights (`weight` float32: [N], or [h, N] by a token's slots), added
-    up in float32: [N, d]."""
+    weights (`weight` float32: [N], or [n_held, N] by held expert, zeros
+    where a token is not routed to it), added up in float32: [N, d]."""
     return _experts_out_fwd(hidden, w_down, weight, plan, how)[0]
 
 
@@ -837,10 +1108,13 @@ def _experts_out_fwd(hidden, w_down, weight, plan, how):
             plan["n_tiles"], block_rows=how.block_rows, packed=how.by_pairs,
             interpret=how.interpret,
         )
+    if how.by_pairs:
+        with jax.named_scope("moe.plan"):
+            by_slot = _by_slot(weight, plan)
     with jax.named_scope("moe.combine"):
         if how.by_pairs:
             y = _rows_sum(
-                out, plan["token_rows"], plan["token_count"], weight,
+                out, plan["token_rows"], plan["token_count"], by_slot,
                 width=w_down.shape[2], out_dtype=hidden.dtype,
                 interpret=how.interpret,
             )
@@ -854,18 +1128,18 @@ def _experts_out_bwd(how, res, g):
     hidden, w_down, weight, plan, out = res
     te, nt = plan["tile_expert"], plan["n_tiles"]
     kernel = dict(block_rows=how.block_rows, interpret=how.interpret)
-    with jax.named_scope("moe.combine"):
-        if how.by_pairs:
-            slots = plan["token_rows"].reshape(-1)
-            by_row = jnp.zeros(plan["row_token"].shape, jnp.float32).at[
-                slots
-            ].set(weight.reshape(-1), mode="drop")
+    if how.by_pairs:
+        with jax.named_scope("moe.plan"):  # a row's weight: its token's
+            by_row = rows_values(plan, weight, how.interpret)
+        with jax.named_scope("moe.combine"):
             d_out, inner = _rows_take(
                 _pack(g), plan["row_token"], nt, by_row, out,
                 width=g.shape[1], out_dtype=hidden.dtype, **kernel,
             )
-            d_weight = _take(inner, slots).reshape(weight.shape)
-        else:
+        with jax.named_scope("moe.plan"):  # and a token's, its row's product
+            d_weight = tokens_values(plan, inner, how.interpret)
+    else:
+        with jax.named_scope("moe.combine"):
             g = g.astype(jnp.float32)
             d_weight = jnp.sum(g * out, axis=1)
             d_out = _take(
@@ -900,15 +1174,15 @@ def expert_mlp(
     token's experts e that are held of `gate * expert_e(x)`, zeros for a
     token with none.
     """
-    if interpret is None:
-        interpret = not kernels_compiled()
+    interpret = _interpreted(interpret)
     if len(weights) not in _FORMS:
         raise ValueError(f"an expert of {len(weights)} matrices: {_FORMS}")
     *into, w_down = weights
     how = _How(block_rows, interpret, expert.ndim == 2)
-    plan = plan_dispatch(expert, lo, w_down.shape[0], block_rows)
+    with jax.named_scope("moe.plan"):
+        plan = plan_dispatch(expert, lo, w_down.shape[0], block_rows, interpret)
+        weight = held_weights(gate, plan) if how.by_pairs else gate
     hidden = _experts_in(x, tuple(into), plan, how)
-    weight = slot_weights(gate, plan) if how.by_pairs else gate
     return _experts_out(hidden, w_down, weight, plan, how)
 
 

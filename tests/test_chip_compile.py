@@ -339,13 +339,17 @@ def test_latent_top_k_expert_kernels_compile_at_the_nemotron_cell_shape(
     )
     # 22 experts a token: the rows move by the two `moe_rows_*` kernels,
     # each in both directions (the forward's combine is not in a gradient
-    # of a sum, so one `moe_rows_sum` of the two is traced and dropped)
+    # of a sum, so one `moe_rows_sum` of the two is traced and dropped);
+    # `moe_plan_rows` gives the rows their tokens and, backward,
+    # `moe_plan_weights` their weights and `moe_plan_tokens` the tokens
+    # their rows' products: the plan scatters and gathers nothing
     assert sorted(set(names)) == [
-        "moe_gmm_dlhs", "moe_gmm_dw", "moe_gmm_fwd", "moe_rows_sum",
-        "moe_rows_take",
+        "moe_gmm_dlhs", "moe_gmm_dw", "moe_gmm_fwd", "moe_plan_rows",
+        "moe_plan_tokens", "moe_plan_weights", "moe_rows_sum", "moe_rows_take",
     ]
-    assert [names.count(n) for n in sorted(set(names))] == [2, 2, 2, 2, 2]
-    assert tpu_kernel_calls(text) == 9
+    assert [names.count(n) for n in sorted(set(names))] == [2, 2, 2, 1, 1, 1, 2, 2]
+    assert tpu_kernel_calls(text) == 12
+    assert " scatter(" not in text
 
 
 @pytest.mark.parametrize("h, window, fwd, bwd", [
@@ -405,15 +409,52 @@ def test_gated_top_10_expert_kernels_compile_at_the_laguna_cell_shape(
         shape((8192, 10), jnp.int32),
     )
     assert sorted(set(names)) == [
-        "moe_gmm_dlhs", "moe_gmm_dw", "moe_gmm_fwd", "moe_rows_sum",
-        "moe_rows_take",
+        "moe_gmm_dlhs", "moe_gmm_dw", "moe_gmm_fwd", "moe_plan_rows",
+        "moe_plan_tokens", "moe_plan_weights", "moe_rows_sum", "moe_rows_take",
     ]
     # three matmuls forward and three of each kind back; the rows taken
     # once forward and once back, and summed once forward (traced, and
     # dropped from a gradient of a sum) and once back: the second
-    # matrix's `dlhs` adds onto the first's result
-    assert [names.count(n) for n in sorted(set(names))] == [3, 3, 3, 2, 2]
-    assert tpu_kernel_calls(text) == 12
+    # matrix's `dlhs` adds onto the first's result; the rows' tokens
+    # counted forward, their weights and the tokens' products backward
+    assert [names.count(n) for n in sorted(set(names))] == [3, 3, 3, 1, 1, 1, 2, 2]
+    assert tpu_kernel_calls(text) == 15
+    assert " scatter(" not in text
+
+
+@pytest.mark.parametrize("tokens,k,held", [
+    (16384, 10, 32),  # qwen3-next-80b-a3b-ep16.train-8k's: 672 row tiles
+    (8192, 22, 8),    # nemotron-3-super-tp2ep64.train-8k's
+])
+def test_the_plan_compiles_at_the_cells_shapes_and_scatters_nothing(
+    one_chip, monkeypatch, tokens, k, held
+):
+    """`plan_dispatch`, the slots' weights and the backward's rows'
+    weights at the widest cell's size and at the four cells' of 8 held: a
+    running count a held expert in VMEM a tile in use, one call each of
+    `moe_plan_rows`, `moe_plan_weights` and `moe_plan_tokens`, and no
+    `scatter`, no `gather`, no `sort` and no `while` in the chip's program (the parent's held one of `n_held x N` single elements and a
+    `searchsorted` loop)."""
+    from kubeflow_tpu.ops import moe
+
+    monkeypatch.setattr(moe, "kernels_compiled", lambda: True)
+    shape = lambda dims, dtype: jax.ShapeDtypeStruct(
+        dims, dtype, sharding=one_chip
+    )
+
+    def plan(expert, gate):
+        plan = moe.plan_dispatch(expert, 0, held)
+        by_row = moe.rows_values(plan, moe.held_weights(gate, plan))
+        back = moe.tokens_values(plan, by_row)
+        return plan, moe.slot_weights(gate, plan), by_row, back
+
+    text, names = _compile(
+        plan, shape((tokens, k), jnp.int32), shape((tokens, k), jnp.float32)
+    )
+    assert names == ["moe_plan_rows", "moe_plan_weights", "moe_plan_tokens"]
+    assert tpu_kernel_calls(text) == 3 and " gather(" not in text
+    assert " scatter(" not in text and " while(" not in text and " sort(" not in text
+    assert moe.moe_schedule(tokens, k, held, 1024, 1024)["plan_updates"] == 0
 
 
 @pytest.mark.parametrize("tokens,k,held,d", [

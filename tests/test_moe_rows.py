@@ -10,10 +10,11 @@ import numpy as np
 import pytest
 
 from kubeflow_tpu.models.transformer import (
-    TransformerConfig, TransformerLM, forced_experts,
+    KERNEL_RESULTS, TransformerConfig, TransformerLM, forced_experts,
 )
 from kubeflow_tpu.ops import moe
 from kubeflow_tpu.parallel import MeshSpec, build_mesh
+from kubeflow_tpu.testing.hlo import pallas_kernel_names
 from kubeflow_tpu.train import SyntheticTokens, TrainConfig, Trainer, fit
 
 N, D, F, E = 64, 32, 48, 16
@@ -330,6 +331,140 @@ def test_the_movers_at_a_width_of_several_lane_pieces():
     assert (np.asarray(plan["token_count"]) == has.sum(0)).all()
 
 
+# -- the plan ---------------------------------------------------------------------
+
+
+def _plan_by_loop(expert, lo, n_held, block_rows):
+    """The plan as `plan_dispatch` documents it, by a loop over the held
+    experts in order and over each one's tokens in order (NumPy)."""
+    expert = np.asarray(expert)
+    pairs = expert.reshape(expert.shape[0], -1)
+    tokens, k = pairs.shape
+    h = min(k, n_held)
+    tiles = -(-tokens * h // block_rows) + n_held
+    rows = tiles * block_rows
+    row_token = np.full(rows, tokens, np.int32)
+    tile_expert = np.full(tiles, n_held - 1, np.int32)
+    token_rows = np.full((h, tokens), rows, np.int32)
+    token_count = np.zeros(tokens, np.int32)
+    hit = np.zeros((k, n_held, tokens), bool)
+    place = np.zeros((h, n_held, tokens), bool)
+    tile = 0
+    for e in range(n_held):
+        pair, its = np.nonzero((pairs == lo + e).T)
+        for i, (j, n) in enumerate(sorted(zip(pair, its), key=lambda u: u[1])):
+            row = tile * block_rows + i
+            row_token[row] = n
+            token_rows[token_count[n], n] = row
+            place[token_count[n], e, n] = hit[j, e, n] = True
+            token_count[n] += 1
+        used = max(-(-len(its) // block_rows), 1)
+        tile_expert[tile:tile + used] = e
+        tile += used
+    plan = {"tile_expert": tile_expert, "n_tiles": np.array([tile], np.int32)}
+    if expert.ndim == 1:
+        return {**plan, "dst": token_rows[0], "src": row_token}
+    return {
+        **plan, "row_token": row_token, "token_rows": token_rows,
+        "token_count": token_count, "hit": hit, "place": place,
+    }
+
+
+_CELLS = {  # tokens, experts, k, held: one layer of a cell
+    "qwen3-next": (16384, 512, 10, 32),
+    "nemotron": (8192, 512, 22, 8),
+    "laguna": (8192, 256, 10, 8),
+}
+_PLANS = [
+    *(pytest.param(cell, layer, id=f"{cell} layer {layer}")
+      for cell, layers in (
+          ("qwen3-next", (0, 1, 2, 3)), ("nemotron", (1, 10)), ("laguna", (1, 4)),
+      ) for layer in layers),
+    *(pytest.param(routing, k, id=f"{routing} k={k}")
+      for routing in ("uneven", "none held", "one held expert", "all held")
+      for k in (0, 2, 8)),  # 0: `expert` [N]; 8 > held
+]
+
+
+@pytest.mark.parametrize("case, at", _PLANS)
+def test_every_key_of_the_plan_matches_a_loop_over_the_held_experts(case, at):
+    """At a layer of the three cells of more than one expert a token
+    (qwen3-next: 672 tiles, 64 in use, ~10,300 rows) and at the small
+    routings: no token held, every pair held, k > held, held experts with
+    no row. A row past the tiles in use reads N like a row of padding."""
+    if case in _CELLS:
+        tokens, experts, k, held = _CELLS[case]
+        expert, lo, rows = forced_experts(at, tokens, experts, k), 0, moe.BLOCK_ROWS
+    else:
+        expert, lo, held, rows = _experts(case, at), LO, HELD, ROWS
+    got = moe.plan_dispatch(expert, lo, held, rows)
+    want = _plan_by_loop(expert, lo, held, rows)
+    for key, value in want.items():
+        np.testing.assert_array_equal(got[key], value, err_msg=key)
+    if case == "qwen3-next":
+        assert want["tile_expert"].shape == (672,) and want["n_tiles"] == 64
+        assert 10_000 < (want["row_token"] < 16384).sum() < 10_600
+    if case == "one held expert" and at:
+        assert (want["token_count"] == 1).all()  # three held experts of no row
+        assert want["n_tiles"] == N // ROWS + HELD - 1
+
+
+@pytest.mark.parametrize("weighted", [False, True], ids=["tokens", "weights"])
+@pytest.mark.parametrize(
+    "counts",
+    [(40, 0, 3, 16), (0, 0, 0, 0), (16, 16, 16, 16), (1, 64, 0, 2)],
+    ids=["uneven", "no row", "whole tiles", "one full"],
+)
+def test_the_plans_kernel_counts_a_rows_token_over_the_tiles_in_use(
+    counts, weighted
+):
+    """`moe_plan_rows`, `moe_plan_weights` and `moe_plan_tokens` alone:
+    an expert's rows name its tokens in order (or carry their values, to
+    the bit, and hand them back), a tile's expert with fewer rows than the
+    tile pads with N (zeros), the last tile in use is written whole, and
+    the tiles past it are never visited."""
+    rng = np.random.default_rng(3)
+    mine = np.zeros((HELD, N), bool)
+    for e, c in enumerate(counts):
+        mine[e, rng.choice(N, c, replace=False)] = True
+    used = np.maximum(-(-np.array(counts) // ROWS), 1)
+    tiles = moe.row_tiles(N, HELD, HELD, ROWS)
+    value = np.where(mine, rng.uniform(0.5, 1.5, mine.shape), 0).astype(np.float32)
+    tile_expert = jnp.asarray(
+        np.repeat(np.arange(HELD), used).tolist()
+        + [HELD - 1] * (tiles - used.sum()), jnp.int32,
+    )
+    n_tiles = jnp.asarray([used.sum()], jnp.int32)
+    got = moe._plan_rows(
+        jnp.asarray(np.cumsum(mine, axis=1), jnp.int32), tile_expert, n_tiles,
+        jnp.asarray(np.cumsum(used) - used, jnp.int32), block_rows=ROWS,
+        interpret=True,
+    )
+    if weighted:  # the backward's: a row's value, found from its token
+        in_use = jnp.arange(tiles * ROWS) // ROWS < n_tiles
+        plan = {
+            "tile_expert": tile_expert, "n_tiles": n_tiles,
+            "row_token": jnp.where(in_use, got, N),
+        }
+        got = moe.rows_values(plan, jnp.asarray(value), interpret=True)
+        # and back, by `moe_plan_tokens`: each token's value where it was
+        back = moe.tokens_values(
+            {**plan, "hit": jnp.asarray(mine)[None]}, got, interpret=True
+        )
+        np.testing.assert_array_equal(back, value)
+    got = np.asarray(got)
+    live = used.sum() * ROWS
+    assert got.shape == (tiles * ROWS,) and live < got.shape[0]
+    want = np.full(live, 0.0 if weighted else N, got.dtype)
+    for e, first in enumerate(np.cumsum(used) - used):
+        its = np.nonzero(mine[e])[0]
+        want[first * ROWS:first * ROWS + len(its)] = value[e, its] if weighted else its
+    np.testing.assert_array_equal(got[:live], want)
+    # what the interpreter leaves in an int32 result nobody wrote (the
+    # values go through the kernel as their bits)
+    assert (got[live:].view(np.int32) == np.iinfo(np.int32).min).all()
+
+
 # -- the schedule and the counters ---------------------------------------------
 
 
@@ -384,6 +519,76 @@ def test_the_schedule_at_the_gated_cells_shapes():
         assert sched["activation"] == {"relu2": "kernel", "gated": "kernel"}
         assert sched["results_past_live"] == 0
         assert "gmm_bytes_zeroing" not in sched
+
+
+def test_the_plan_updates_at_the_cells_shapes():
+    """The single elements the plan scatters: 524,288 a layer in the
+    qwen3-next cell until PR 46 (`n_held x N`, to place ~10,300 rows),
+    none since in any cell of more than one expert a token; zaya's one
+    expert a token scatters a row's token for each of its 16,384."""
+    for tokens, _, k, held in _CELLS.values():
+        sched = moe.moe_schedule(tokens, k, held, 2048, 512)
+        assert sched["plan_updates"] == 0
+        assert sched["plan_rows_grid_steps"] == sched["tiles"]
+    widest = moe.moe_schedule(16384, 10, 32, 2048, 512, live_tiles=64)
+    assert widest["tiles"] == 672 and widest["plan_rows_grid_steps"] == 64
+    zaya = moe.moe_schedule(16384, 1, 8, 2048, 2048)
+    assert zaya["plan_updates"] == 16384 and zaya["plan_rows_grid_steps"] == 0
+
+
+@pytest.mark.parametrize("k", [0, 2, 8])  # 0: `expert` [N]; 8 > held
+def test_the_plans_scatters_add_up_to_plan_updates(k):
+    """In the gradient of `expert_mlp` (the plan, the weights by slot and
+    both backward halves) the `scatter` equations' updates are the
+    schedule's `plan_updates`: nothing of `n_held x N` or `h x N` single
+    elements at k > 1, where `moe_plan_rows` runs once forward and
+    `moe_plan_weights` and `moe_plan_tokens` once backward; no `gather`
+    of single elements either."""
+    expert, x, gate, target, mine = _setup(k, "gated")
+
+    def loss(x, gate, *w):
+        out = moe.expert_mlp(x, expert, gate, w, LO, block_rows=ROWS)
+        return (out * target).sum()
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, tuple(range(2 + len(mine)))))(
+        x, gate, *mine
+    )
+    updates, plan_kernels = [], []
+    for eqn in _equations(jaxpr.jaxpr):
+        if eqn.primitive.name.startswith("scatter"):
+            updates.append(int(np.prod(eqn.invars[2].aval.shape)))
+        if k and eqn.primitive.name == "gather":  # an expert's last count
+            assert np.prod(eqn.outvars[0].aval.shape) <= HELD
+        if eqn.primitive.name == "pallas_call":
+            plan_kernels += [eqn.params["name"]] * ("plan" in eqn.params["name"])
+    sched = moe.moe_schedule(N, max(k, 1), HELD, D, F, block_rows=ROWS)
+    assert sum(updates) == sched["plan_updates"] == (0 if k else N)
+    assert HELD * N not in updates and min(k, HELD) * N not in updates or not k
+    assert plan_kernels == [
+        "moe_plan_rows", "moe_plan_weights", "moe_plan_tokens"
+    ] * bool(k)
+
+
+def test_the_flash_policy_does_not_count_the_rows_tokens_again():
+    """`remat_policy="flash"` keeps the rows' tokens by name
+    (`KERNEL_RESULTS`): `moe_plan_rows` runs once, forward, where a
+    checkpoint that keeps nothing runs it again for the second forward
+    (as the scatter it replaces ran twice a layer)."""
+    expert, x, gate, target, mine = _setup(2, "gated")
+
+    def loss(x, gate, *w):
+        out = moe.expert_mlp(x, expert, gate, w, LO, block_rows=ROWS)
+        return (out * target).sum()
+
+    def plan_kernels(policy):
+        grad = jax.grad(jax.checkpoint(loss, policy=policy), (0, 1))
+        names = pallas_kernel_names(grad, x, gate, *mine)
+        return names.count("moe_plan_rows"), names.count("moe_plan_weights")
+
+    assert moe.CHECKPOINT_ROWS_NAME in KERNEL_RESULTS
+    keep = jax.checkpoint_policies.save_only_these_names(*KERNEL_RESULTS)
+    assert plan_kernels(keep) == (1, 1)
+    assert plan_kernels(jax.checkpoint_policies.nothing_saveable) == (2, 1)
 
 
 def test_the_tiles_in_use_follow_the_rows():
