@@ -33,6 +33,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from kubeflow_tpu.ops import gatenorm
+from kubeflow_tpu.ops import router as router_ops
 from kubeflow_tpu.ops import shortconv
 from kubeflow_tpu.ops import streams as streams_ops
 from kubeflow_tpu.ops.attention import attend
@@ -1292,9 +1293,8 @@ class ExpertLayer(nn.Module):
             "router_norm", _replicated(nn.initializers.ones, 1), (rh,),
             jnp.float32,
         )
-        r = jnp.dot(
-            x.astype(jnp.float32), mat("router_in", x.shape[-1], rh,
-                                       ("embed", None)), precision=hi,
+        r = router_ops.exact_dot(
+            x, mat("router_in", x.shape[-1], rh, ("embed", None))
         ) + carry * router_state
         r = checkpoint_name(r, ROUTE_RESULT)
         z = rms_norm(r, scale, dtype=jnp.float32, eps=cfg.norm_eps)
@@ -1334,32 +1334,25 @@ class ExpertLayer(nn.Module):
                 "router_bias", _replicated(nn.initializers.zeros, 1), (n,),
                 jnp.float32,
             )
-        scores = jax.nn.sigmoid if cfg.router == "sigmoid" else (
-            functools.partial(jax.nn.softmax, axis=-1)
-        )
-        probs = scores(checkpoint_name(jnp.dot(
-            x.astype(jnp.float32), w, precision=jax.lax.Precision.HIGHEST
-        ), ROUTE_RESULT))
+        logits = checkpoint_name(router_ops.exact_dot(x, w), ROUTE_RESULT)
         if cfg.router_force_balance:
             expert = forced_experts(self.layer, x.shape[-2], n, k)
             expert = jnp.broadcast_to(
-                expert.reshape(x.shape[-2], k), (*probs.shape[:-1], k)
+                expert.reshape(x.shape[-2], k), (*logits.shape[:-1], k)
             )
         else:
+            probs = router_ops.SCORES[cfg.router](logits)
             _, expert = jax.lax.top_k(
                 probs if bias is None else probs + jax.lax.stop_gradient(bias),
                 k,
             )
         expert = checkpoint_name(expert.astype(jnp.int32), ROUTE_RESULT)
-        # The gather is what the backward would form again (1.8 ms a layer
-        # at 22 of 512, PERF.md §6 PR 38): its result is named too.
-        chosen = checkpoint_name(
-            jnp.take_along_axis(probs, expert, axis=-1), ROUTE_RESULT
+        # The chosen scores and the weights carry the name too: no gather
+        # (plain form) and no kernel runs again in the backward.
+        return expert, router_ops.route_weights(
+            logits, expert, scoring=cfg.router, scaling=cfg.routed_scaling,
+            name=ROUTE_RESULT, mesh=self.mesh,
         )
-        gate = cfg.routed_scaling * chosen / (
-            jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20
-        )
-        return expert, checkpoint_name(gate, ROUTE_RESULT)
 
     @nn.compact
     def __call__(self, x, router_state):

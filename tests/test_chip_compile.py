@@ -989,3 +989,62 @@ def test_a_xing_shaped_step_compiles_with_the_two_part_kernels(topo, monkeypatch
     assert state + plan.saved_bytes < used <= plan.predicted_peak, (
         used, plan
     )
+
+
+def test_a_qwen3_next_shaped_expert_layer_routes_with_no_gather_and_no_scatter(
+    topo, monkeypatch
+):
+    """One layer at Qwen3-Next's expert widths and its cell's 2 x 8,192
+    tokens (ten of 512 experts by softmax, 32 held), under
+    `remat_policy="flash"` with a v5e's limit stated: the chip's compiler
+    takes the router's kernel pair (`ops/router.py`), once each a layer
+    and neither again under the checkpoint (the chosen scores and the
+    weights are kept by name), and nothing under `moe.route` moves an
+    element singly: no `gather` and no `scatter` there (the parent's
+    `take_along_axis` held one of each, 163,840 elements a layer)."""
+    from kubeflow_tpu.models.transformer import TransformerConfig, TransformerLM
+    from kubeflow_tpu.ops import moe, router
+    from kubeflow_tpu.parallel import MeshSpec, build_mesh
+    from kubeflow_tpu.train import TrainConfig, Trainer
+    from kubeflow_tpu.utils import memory
+
+    _as_on_the_chip(monkeypatch)
+    monkeypatch.setattr(moe, "kernels_compiled", lambda: True)
+    monkeypatch.setattr(memory, "device_limit", lambda mesh: 16_909_336_064)
+    cfg = TransformerConfig(
+        vocab_size=16384, d_model=2048, n_layers=1, n_heads=16, n_kv_heads=2,
+        head_dim=HEAD_DIM, tie_embeddings=False, remat_policy="flash",
+        d_ff=512, num_experts=512, experts_held=(0, 32), experts_per_token=10,
+        router="softmax", moe_shared_ff=512, moe_shared_gate=True,
+        router_force_balance=True,
+    )
+    mesh = build_mesh(MeshSpec(), list(topo.devices)[:1])
+    trainer = Trainer(
+        TransformerLM(cfg, mesh=mesh),
+        TrainConfig(batch_size=2, optimizer="adamw", label_smoothing=0.0,
+                    fsdp_params=False, train_metrics="loss"),
+        mesh, example_input_shape=(2, 8192), example_input_dtype=jnp.int32,
+        input_key="tokens", label_key="labels",
+    )
+    tokens = jax.ShapeDtypeStruct(
+        (2, 8192), jnp.int32, sharding=trainer.batch_sharding(2)
+    )
+    text = trainer.make_train_step().lower(
+        trainer.abstract_state(), {"tokens": tokens, "labels": tokens}
+    ).compile().as_text()
+    routed = [line for line in text.splitlines() if "moe.route" in line]
+    assert routed
+    assert not [
+        line for line in routed if " gather(" in line or " scatter(" in line
+    ]
+    calls = [
+        line for line in routed
+        if "tpu_custom_call" in line and "route_weights_" in line
+    ]
+    assert sorted(
+        re.search(r"route_weights_(fwd|bwd)", line).group(0) for line in calls
+    ) == ["route_weights_bwd", "route_weights_fwd"]
+    assert not [line for line in calls if "rematted_computation" in line]
+    schedule = router.router_schedule(16384, 2048, 512, 10, jnp.bfloat16, compiled=True)
+    assert schedule["form"] == "kernels"
+    assert schedule["gathered_elements"] == schedule["scattered_elements"] == 0
