@@ -36,7 +36,7 @@ from kubeflow_tpu.ops import gatenorm
 from kubeflow_tpu.ops import router as router_ops
 from kubeflow_tpu.ops import shortconv
 from kubeflow_tpu.ops import streams as streams_ops
-from kubeflow_tpu.ops.attention import attend
+from kubeflow_tpu.ops.attention import attend, latent_form
 from kubeflow_tpu.ops.flash import CHECKPOINT_LSE_NAME, CHECKPOINT_OUT_NAME
 from kubeflow_tpu.ops.kda import (
     CHECKPOINT_OUT_NAME as KDA_OUT_NAME,
@@ -222,7 +222,11 @@ class TransformerConfig:
     # `head_dim` dims of their own plus `rope_head_dim` that rope turns (or,
     # at a `rope_fraction` of 0, does not), and the rope part of
     # k is ONE key for all heads, projected beside the latent; v is
-    # `v_head_dim` wide (0: `head_dim`; the flash kernels want them equal).
+    # `v_head_dim` wide (0: `head_dim`). Which calls run is
+    # `ops/attention.latent_form`'s to say, from the three widths: v as
+    # wide as the head's own part, the two-part flash calls; as wide as
+    # own + rope, the one-part calls at that width over q and k projected
+    # with a head's two parts side by side; any other, dense.
     # The layer's `AttentionKind` gives the rope (yarn too) over the whole
     # rope part. `softmax_scale`: the scores' factor where it is not
     # (width of q·k)^-1/2 (yarn's mscale squared times it).
@@ -245,6 +249,16 @@ class TransformerConfig:
     hc_iters: int = 20
     hc_clamp: float = 30.0
     hc_eps: float = 1e-6
+    # The multi-token module (DeepSeek-V3's report, arXiv 2412.19437 §2.2;
+    # 0 = none, 1 = one further token, the only depth built): outside the
+    # stack, ONE further `Block` of the stack's own class with parameters
+    # of its own over `[norm(Emb(t_(i+1))) | norm(h_i)] W_eh`, h the main
+    # stack's normed output, then a norm and the SHARED head: position i
+    # predicts t_(i+2). It is part of the objective, not of the logits: a
+    # model given `labels` returns `main + mtp_weight * mtp`
+    # (`TransformerLM`), one given none its main logits, the module unrun.
+    mtp_layers: int = 0
+    mtp_weight: float = 0.3
 
 
 def _gate_kind(cfg: TransformerConfig) -> str | None:
@@ -358,7 +372,10 @@ def _attention_kinds(cfg: TransformerConfig) -> list[AttentionKind]:
                 "takes a K/V rank (a q rank of 0 projects q directly), an "
                 "even part beside the head's own (turned or, at a "
                 "rope_fraction of 0, not), equal heads in every attention "
-                f"row ({cfg.n_heads} over {hk}), no window and no CCA"
+                f"row ({cfg.n_heads} over {hk}), no window and no CCA; v "
+                f"({cfg.v_head_dim or cfg.head_dim}) as wide as the own "
+                f"part ({cfg.head_dim}) or as own + rope runs the flash "
+                "kernels, any other width dense"
             )
     if cfg.residual_streams < 0 or cfg.hc_iters < 1 or (
         cfg.residual_streams and cfg.layer_pattern is not None
@@ -376,7 +393,23 @@ def _attention_kinds(cfg: TransformerConfig) -> list[AttentionKind]:
             f"{cfg.dense_d_ff} in a stack of {cfg.n_layers} with "
             f"{cfg.num_experts} experts"
         )
+    if cfg.mtp_layers not in (0, 1) or (cfg.mtp_layers and (
+        cfg.residual_streams or cfg.layer_pattern is not None
+    )):
+        raise ValueError(
+            f"{cfg.mtp_layers} multi-token module(s) with "
+            f"{cfg.residual_streams} residual streams and a layer_pattern "
+            f"of {cfg.layer_pattern!r}: one module of one further block is "
+            "built, behind a stack of blocks with one residual stream"
+        )
     return [kinds[k] for k in pattern]
+
+
+def _block_kinds(cfg: TransformerConfig) -> list[AttentionKind]:
+    """`_attention_kinds` and, behind them, the kind of the multi-token
+    module's block: the last layer's."""
+    kinds = _attention_kinds(cfg)
+    return kinds + kinds[-1:] * cfg.mtp_layers
 
 
 # What `remat_policy="flash"` always keeps: the results of the attention,
@@ -558,10 +591,13 @@ def _result_bytes(cfg: "TransformerConfig", tokens: int) -> list[dict]:
         )
         if cfg.kv_latent:  # q's and k's two parts and v; the two latents
             h, r = kind.n_heads, cfg.rope_head_dim
+            wide = cfg.v_head_dim or cfg.head_dim
             out[QKV_RESULT] = tokens * act * (
-                2 * _lanes(h * cfg.head_dim) + _lanes(h * r) + _lanes(r)
-                + _lanes(h * (cfg.v_head_dim or cfg.head_dim))
-            )
+                # a head's two parts side by side in q and in k
+                2 * _lanes(h * (cfg.head_dim + r))
+                if latent_form(cfg.head_dim, r, wide) == "joined"
+                else 2 * _lanes(h * cfg.head_dim) + _lanes(h * r) + _lanes(r)
+            ) + tokens * act * _lanes(h * wide)
             out[ATTN_LATENT_RESULT] = tokens * act * (
                 (cfg.q_latent and _lanes(cfg.q_latent))
                 + _lanes(cfg.kv_latent + r)
@@ -615,7 +651,8 @@ def _result_bytes(cfg: "TransformerConfig", tokens: int) -> list[dict]:
     layers = []
     if cfg.layer_pattern is None:
         n = cfg.residual_streams
-        for i, kind in enumerate(_attention_kinds(cfg)):
+        # the multi-token module's block last: one more expert layer
+        for i, kind in enumerate(_block_kinds(cfg)):
             out = {RESIDUAL_RESULT: tokens * _stream_lanes(cfg) * act}
             if n:  # n² + 2n raw products and the norm's scalar a token,
                 # float32, round both sublayers
@@ -674,7 +711,11 @@ def _kept_always_bytes(cfg: "TransformerConfig", tokens: int) -> int:
         )
 
     if cfg.layer_pattern is None:
-        return sum(stream + attention(k) for k in _attention_kinds(cfg))
+        # The module's block beside the layers, and what its projection
+        # reads: the two normed halves side by side.
+        return sum(stream + attention(k) for k in _block_kinds(cfg)) + (
+            cfg.mtp_layers * tokens * _lanes(2 * cfg.d_model) * act
+        )
     d_in = cfg.ssm_heads * cfg.ssm_head_dim
     scan = (tokens + -(-tokens // cfg.ssm_chunk) * cfg.ssm_state) * d_in * act
     return sum(
@@ -716,7 +757,10 @@ def _peak_bytes(
         again += 2 * tokens * _stream_lanes(cfg) * 4
     kept = lambda layer: sum(layer.get(name, 0) for name in names)
     kept_all = sum(kept(layer) for layer in layers)
-    at_top = _kept_always_bytes(cfg, tokens) + kept_all + 2 * logits
+    # a multi-token module's logits and their cotangent beside the main's
+    at_top = _kept_always_bytes(cfg, tokens) + kept_all + (
+        2 * (1 + cfg.mtp_layers) * logits
+    )
     at_bottom = stated.grad_bytes + kept_all - kept(widest)
     return (
         stated.state_bytes + REMAT_CODE_BYTES + rows + again
@@ -965,7 +1009,7 @@ class Attention(nn.Module):
         """A gate stuck at 0 or 1 is the failure this shows; the step sums
         a counter over the attention layers, which sow it."""
         layers = sum(
-            kind.mixer == "attention" for kind in _attention_kinds(self.config)
+            kind.mixer == "attention" for kind in _block_kinds(self.config)
         )
         self.sow(
             "counters", "attn_gate_mean", jnp.mean(gate) / layers,
@@ -1005,9 +1049,20 @@ class Attention(nn.Module):
         product would be a relayout of the activation (192 = 128 + 64 is
         no whole tile; PERF.md §6, PR 31). The slices are of the weight."""
         cfg = self.config
+        kernel = self._heads_kernel(x.shape[-1], name, heads, sum(widths))
+        out, at = [], 0
+        for w in widths:
+            part = kernel[:, :, at:at + w].reshape(x.shape[-1], heads * w)
+            out.append(jnp.dot(x, part.astype(cfg.dtype)))
+            at += w
+        return out
+
+    @nn.nowrap
+    def _heads_kernel(self, rows: int, name: str, heads: int, width: int):
+        """The stored matrix [rows, H, width] of a projection onto heads
+        whose parts lie side by side (`_by_parts`, `_joined_qkv`)."""
         init = nn.initializers.variance_scaling(1.0, "fan_in", "normal")
-        shape = (x.shape[-1], heads, sum(widths))
-        kernel = self.param(
+        return self.param(
             name,
             nn.with_logical_partitioning(
                 # drawn as the [E, H·w] matrix it is
@@ -1016,14 +1071,50 @@ class Attention(nn.Module):
                 ).reshape(shape),
                 (None, "heads", "kv"),
             ),
-            shape, jnp.float32,
+            (rows, heads, width), jnp.float32,
         )
-        out, at = [], 0
-        for w in widths:
-            part = kernel[:, :, at:at + w].reshape(shape[0], heads * w)
-            out.append(jnp.dot(x, part.astype(cfg.dtype)))
-            at += w
-        return out
+
+    @nn.nowrap
+    def _joined_qkv(self, c_q, name_q: str, c_kv, k_rope, turn, h: int):
+        """Latent attention's operands for the ONE-part kernels at a head
+        of r + d lanes, where v is that wide (`latent_form` "joined"):
+        a head's `[q_rope | q]` and `[k_rope | k]` side by side, rope part
+        first (rope turns a head's FIRST lanes; a dot product does not
+        mind the order), each as ONE product [B, S, H·(r + d)] and no
+        concatenation of activations by head (192 + 64 lanes a head split
+        no tile: a relayout each way). q: the stored matrix with a head's
+        columns in that order, then `turn` (rope, or None) over the rope
+        lanes. k: `[c_kv | turn(k_rope)] W`, W the own part's columns of
+        `wkv_b` under zeros in a head's rope lanes, over an identity that
+        copies the turned `k_rope` [B, S, r] into every head's (a product
+        by 1.0 is exact, and its transpose sums the one key's gradient over
+        the heads). -> q, k [B, S, H·(r + d)], v [B, S, H·Dv]."""
+        cfg = self.config
+        d, r, kvl = cfg.head_dim, cfg.rope_head_dim, cfg.kv_latent
+        if turn is not None:
+            with jax.named_scope("rope"):
+                k_rope = turn(k_rope)
+        wq = self._heads_kernel(c_q.shape[-1], name_q, h, d + r)
+        wq = jnp.concatenate([wq[:, :, d:], wq[:, :, :d]], axis=-1)
+        q = jnp.dot(c_q, wq.reshape(-1, h * (d + r)).astype(cfg.dtype))
+        wkv = self._heads_kernel(kvl, "wkv_b", h, d + cfg.v_head_dim)
+        place = jnp.broadcast_to(
+            jnp.eye(r, dtype=wkv.dtype)[:, None, :], (r, h, r)
+        )
+        wk = jnp.concatenate([
+            jnp.pad(wkv[:, :, :d], ((0, 0), (0, 0), (r, 0))),
+            jnp.pad(place, ((0, 0), (0, 0), (0, d))),
+        ], axis=0).reshape(kvl + r, h * (d + r))
+        k = jnp.dot(
+            jnp.concatenate([c_kv, k_rope], axis=-1), wk.astype(cfg.dtype)
+        )
+        v = jnp.dot(c_kv, wkv[:, :, d:].reshape(kvl, -1).astype(cfg.dtype))
+        if turn is not None:
+            with jax.named_scope("rope"):
+                # + 0.5: `int(head_dim * fraction)` is r whatever the
+                # division rounds to
+                q = turn(q, fraction=(r + 0.5) / (d + r), head_dim=d + r)
+        return q, k, v
 
     @nn.nowrap
     def _latent_qkv(self, x, positions, kind: AttentionKind):
@@ -1033,47 +1124,62 @@ class Attention(nn.Module):
         `[c | k_rope] = x wkv_a`, `c_kv = norm(c)`, a head's `[k | v] =
         c_kv wkv_b`; rope turns `q_rope` and the ONE `k_rope` all heads
         share, unless the layer's kind has a `rope_fraction` of 0. -> q, k
-        [B, S, H·D], v [B, S, H·Dv], q_rope [B, S, H·R], k_rope [B, S, R]."""
+        [B, S, H·D], v [B, S, H·Dv], q_rope [B, S, H·R], k_rope [B, S, R];
+        or, where `ops/attention.latent_form` says the one-part calls run
+        ("joined": v as wide as a head's two parts together), q, k
+        [B, S, H·(R + D)] with the parts side by side (`_joined_qkv`), v,
+        and no pair."""
         cfg = self.config
         h, d, r = kind.n_heads, cfg.head_dim, cfg.rope_head_dim
+        joined = latent_form(d, r, cfg.v_head_dim or d) == "joined"
         norm = _norm_cls(cfg)
         # The products are what is named: a norm's backward reads its input.
         named = lambda u: checkpoint_name(u, ATTN_LATENT_RESULT)
+
+        def turn():
+            """rope over the pair's second part, as the layer's kind has
+            it; None at a `rope_fraction` of 0: not turned."""
+            if kind.rope_fraction == 0:
+                return None
+            how = {}
+            if kind.rope_yarn is not None:
+                factor, original, fast, slow, attention_factor = kind.rope_yarn
+                how = dict(
+                    inv_freq=yarn_inv_freq(
+                        kind.rope_theta, r, factor=factor,
+                        original_max=original, beta_fast=fast, beta_slow=slow,
+                    ),
+                    scale=attention_factor,
+                )
+            return functools.partial(
+                rope, positions=positions, theta=kind.rope_theta, head_dim=r,
+                mesh=self.mesh, **how,
+            )
+
         with jax.named_scope("attn.latent_q"):
+            c_q, name_q = x, "wq"
             if cfg.q_latent:
-                c_q = norm(name="q_norm")(named(_dense(
+                c_q, name_q = norm(name="q_norm")(named(_dense(
                     cfg.q_latent, ("embed", None), "wq_a", cfg.dtype
-                )(x)))
-                q, q_rope = self._by_parts(c_q, "wq_b", h, (d, r))
-            else:
-                q, q_rope = self._by_parts(x, "wq", h, (d, r))
+                )(x))), "wq_b"
+            if not joined:
+                q, q_rope = self._by_parts(c_q, name_q, h, (d, r))
         with jax.named_scope("attn.latent_kv"):
             joint = named(_dense(
                 cfg.kv_latent + r, ("embed", None), "wkv_a", cfg.dtype
             )(x))
             c_kv = norm(name="kv_norm")(joint[..., : cfg.kv_latent])
             k_rope = joint[..., cfg.kv_latent:]
-            k, v = self._by_parts(
-                c_kv, "wkv_b", h, (d, cfg.v_head_dim or d)
-            )
-        if kind.rope_fraction == 0:  # the pair's second part, not turned
-            return q, k, v, q_rope, k_rope
-        how = {}
-        if kind.rope_yarn is not None:
-            factor, original, fast, slow, attention_factor = kind.rope_yarn
-            how = dict(
-                inv_freq=yarn_inv_freq(
-                    kind.rope_theta, r, factor=factor, original_max=original,
-                    beta_fast=fast, beta_slow=slow,
-                ),
-                scale=attention_factor,
-            )
-        turn = functools.partial(
-            rope, positions=positions, theta=kind.rope_theta, head_dim=r,
-            mesh=self.mesh, **how,
-        )
-        with jax.named_scope("rope"):
-            q_rope, k_rope = turn(q_rope), turn(k_rope)
+            if not joined:
+                k, v = self._by_parts(
+                    c_kv, "wkv_b", h, (d, cfg.v_head_dim or d)
+                )
+        turn = turn()
+        if joined:
+            return self._joined_qkv(c_q, name_q, c_kv, k_rope, turn, h)
+        if turn is not None:
+            with jax.named_scope("rope"):
+                q_rope, k_rope = turn(q_rope), turn(k_rope)
         return q, k, v, q_rope, k_rope
 
     @nn.compact
@@ -1090,10 +1196,14 @@ class Attention(nn.Module):
             )
             with jax.named_scope("attend"):
                 out = attend(
-                    heads(q), heads(k), heads(v, v.shape[-1] // h),
-                    mesh=self.mesh, impl=cfg.attention_impl,
-                    q_rope=heads(pair[0], cfg.rope_head_dim), k_rope=pair[1],
-                    scale=cfg.softmax_scale,
+                    heads(q, q.shape[-1] // h), heads(k, k.shape[-1] // h),
+                    heads(v, v.shape[-1] // h), mesh=self.mesh,
+                    impl=cfg.attention_impl, scale=cfg.softmax_scale,
+                    # no pair where a head's parts lie side by side in q, k
+                    **(dict(
+                        q_rope=heads(pair[0], cfg.rope_head_dim),
+                        k_rope=pair[1],
+                    ) if pair else {}),
                 )
             return self._out(x, out, h)
         # q, k and v stay [B, S, H·d] from the projections' matmuls to the
@@ -1463,7 +1573,7 @@ class ExpertLayer(nn.Module):
         ))
         layers = (
             cfg.layer_pattern.count("E") if cfg.layer_pattern is not None
-            else cfg.n_layers - cfg.dense_layers
+            else cfg.n_layers - cfg.dense_layers + cfg.mtp_layers
         )
         self.sow(
             "counters", "shared_gate_mean", jnp.mean(gate) / layers,
@@ -1665,9 +1775,7 @@ class DeltaMixer(nn.Module):
                     x.astype(f32), matrix("wb", h),
                     precision=jax.lax.Precision.HIGHEST,
                 ))
-            layers = sum(
-                kind.mixer == "delta" for kind in _attention_kinds(cfg)
-            )
+            layers = sum(kind.mixer == "delta" for kind in _block_kinds(cfg))
             for name, value in (
                 ("kda_decay_mean", jnp.mean(jnp.exp(g))),
                 ("kda_beta_mean", jnp.mean(beta)),
@@ -1918,6 +2026,48 @@ def _layer_classes(cfg: TransformerConfig, keep: tuple[str, ...] = ()) -> list:
     return [functools.partial(sublayer, kind=k) for k in cfg.layer_pattern]
 
 
+class MultiTokenModule(nn.Module):
+    """The multi-token module (`TransformerConfig.mtp_layers`; DeepSeek-V3's
+    report, arXiv 2412.19437 §2.2, depth 1; leaves as the published
+    checkpoints name them): for position i, `z_i = [norm(Emb(t_(i+1));
+    enorm) | norm(h_i; hnorm)] eh_proj` with `h` the main stack's output
+    after its final norm and the embedding's half first; `y = block(z)`,
+    ONE further `Block` of the stack's own class (an expert layer where
+    the stack has experts, the last layer's attention kind, the same
+    positions, parameters of its own; `layer` = `n_layers`, so a forced
+    selection folds its own index and the routing counters gain its row);
+    `logits'_i = norm(y_i; head_norm) head^T`, the embedding and the head
+    the main model's own matrices: logits of t_(i+2). Everything of it is
+    under the `mtp` frame of a profile's paths (`mtp.proj`, the block's
+    usual scopes, `mtp.head`)."""
+
+    config: TransformerConfig
+    mesh: Mesh | None = None
+    keep: tuple[str, ...] = ()  # the remat plan's names, as the layers'
+
+    @nn.compact
+    def __call__(self, h, next_tokens, embed, head, positions, router_state):
+        cfg = self.config
+        norm = _norm_cls(cfg)
+        with jax.named_scope("mtp.proj"):
+            e = embed.astype(cfg.dtype)[next_tokens]
+            z = _dense(cfg.d_model, (None, "embed"), "eh_proj", cfg.dtype)(
+                jnp.concatenate(
+                    [norm(name="enorm")(e), norm(name="hnorm")(h)], axis=-1
+                )
+            )
+        block = _block_cls(cfg, keep=self.keep)
+        if cfg.attention_kinds or cfg.dense_layers:
+            block = functools.partial(
+                block, attention=_attention_kinds(cfg)[-1], dense=False
+            )
+        y, _ = block(cfg, self.mesh, layer=cfg.n_layers, name="block")(
+            z, positions, router_state
+        )
+        with jax.named_scope("mtp.head"):
+            return lm_head(norm(name="head_norm")(y), head, dtype=cfg.dtype)
+
+
 class _PipelineStage(nn.Module):
     """`layers_per_stage` sequential Blocks = one pipeline stage.
 
@@ -1987,12 +2137,12 @@ class PipelinedTransformerLM(nn.Module):
         cfg = self.config
         if (
             cfg.num_experts > 0 or cfg.layer_pattern or not cfg.tie_embeddings
-            or cfg.attention_kinds or cfg.residual_streams
+            or cfg.attention_kinds or cfg.residual_streams or cfg.mtp_layers
         ):
             raise ValueError(
                 "pipelined transformer does not support MoE, a layer "
-                "pattern, attention kinds by layer, residual streams or an "
-                "untied head"
+                "pattern, attention kinds by layer, residual streams, an "
+                f"untied head or a multi-token module ({cfg.mtp_layers})"
             )
         if cfg.n_layers % self.n_stages:
             raise ValueError(
@@ -2221,13 +2371,25 @@ class PipelinedTransformerLM(nn.Module):
 class TransformerLM(nn.Module):
     """Embed → N layers → norm → logits. apply(tokens[, train]) → [B,S,V].
     The layers are `Block`s, or the single sublayers `layer_pattern`
-    names; the head is the embedding's matrix or, untied, its own."""
+    names; the head is the embedding's matrix or, untied, its own.
+
+    apply(tokens, labels=[B, S]) → the scalar objective
+    (`TrainConfig.loss_in_model`): the mean next-token cross entropy
+    `main` and, with a multi-token module (`mtp_layers`:
+    `MultiTokenModule`, over the normed output, the embedding read a
+    second time at the label and the head multiplied a second time),
+    `main + mtp_weight * mtp`, `mtp` the mean over positions i < S - 1 of
+    the cross entropy of the module's logits against `labels[i + 1]`: the
+    labels shifted by one more and the last position masked, so a batch
+    stays [B, S + 1] tokens. `main_loss` and `mtp_loss` are sown under
+    "counters". Without labels the module does not run (but to make its
+    parameters, at `init`)."""
 
     config: TransformerConfig
     mesh: Mesh | None = None
 
     @nn.compact
-    def __call__(self, tokens, train: bool = False):
+    def __call__(self, tokens, train: bool = False, labels=None):
         cfg = self.config
         embed = self.param(
             "embedding",
@@ -2297,4 +2459,32 @@ class TransformerLM(nn.Module):
                 jnp.float32,
             )
         with jax.named_scope("head"):
-            return lm_head(x, head, dtype=cfg.dtype)
+            logits = lm_head(x, head, dtype=cfg.dtype)
+        if cfg.mtp_layers and (labels is not None or self.is_initializing()):
+            # The module's name is the `mtp` frame of a profile's paths; at
+            # `init` it runs to make its parameters, over any tokens.
+            further = MultiTokenModule(cfg, self.mesh, plan.names, name="mtp")(
+                x, tokens if labels is None else labels, embed, head,
+                positions, router_state,
+            )
+        if labels is None:
+            return logits
+        from kubeflow_tpu.train.trainer import softmax_cross_entropy
+
+        with jax.named_scope("loss"):
+            loss = softmax_cross_entropy(logits, labels)
+        if cfg.mtp_layers:
+            with jax.named_scope("mtp"), jax.named_scope("mtp.loss"):
+                # Position i's second target is labels[i + 1]; the last has
+                # none and is masked, over logits left whole.
+                mtp = softmax_cross_entropy(
+                    further, jnp.roll(labels, -1, axis=1),
+                    where=jnp.arange(labels.shape[1]) < labels.shape[1] - 1,
+                )
+            for name, value in (("main_loss", loss), ("mtp_loss", mtp)):
+                self.sow(
+                    "counters", name, value,
+                    reduce_fn=lambda _, new: new, init_fn=lambda: 0.0,
+                )
+            loss = loss + cfg.mtp_weight * mtp
+        return loss

@@ -167,6 +167,27 @@ def ring_attention(
     )(q, k, v)
 
 
+def latent_form(own: int, rope: int, wide: int) -> str:
+    """Which calls run latent attention whose heads have `own` dims of
+    their own beside `rope` against the one rope key, over values `wide`
+    wide — from the widths alone, so a model can lay its projections out
+    for them:
+
+    - "two_part": v as wide as the own part. The `flash_*_mla*` calls,
+      which read the rope key once a K block and never repeated a head.
+    - "joined": v as wide as a head's two parts together. The ONE-part
+      calls at `own + rope` lanes a head, over q's parts side by side and
+      k's own part beside the rope key repeated a head (8,192 x 20 x 64
+      bfloat16 is 21 MB a pass more than the own parts' 63, where heads of
+      192 lanes, no whole tiles, would cost the two-part kernels two
+      head-major transposes of 63-84 MB each).
+    - "dense": any other width. No kernel has a head of that shape.
+    """
+    if wide == own:
+        return "two_part"
+    return "joined" if wide == own + rope else "dense"
+
+
 def attend(
     q, k, v, *, mesh: Mesh | None, impl: str, window: int | None = None,
     q_rope=None, k_rope=None, scale: float | None = None,
@@ -182,11 +203,18 @@ def attend(
 
     With `q_rope` [B, S, H, R] and `k_rope` [B, S, R] (latent attention)
     the scores have two parts, D dims a head and R against one rope key
-    for all heads, over values D wide; `scale` is the scores' factor
-    ((D + R)^-1/2, or D^-1/2 without a rope part, unless given). The ring,
-    a window and a `tp` axis refuse the rope part with their numbers: the
-    one key's gradient is a sum over the heads, which nothing has run
-    across shards.
+    for all heads; `scale` is the scores' factor ((D + R)^-1/2, or D^-1/2
+    without a rope part, unless given). The ring, a window and a `tp` axis
+    refuse the rope part with their numbers: the one key's gradient is a
+    sum over the heads, which nothing has run across shards. The values'
+    width picks the calls (`latent_form`): D wide, the two-part kernels;
+    D + R wide, the parts are joined here, a head's q as `[q_rope | q]`
+    and its k as `[k_rope | k]` with the rope key repeated a head (whose
+    gradient the broadcast's transpose sums over the heads), and the
+    one-part kernels run at D + R (a model that projects q and k joined
+    hands them in as one part and spares the concatenation:
+    `models/transformer.Attention._joined_qkv`); any other width runs
+    dense, and where the kernels would have run says so.
 
     The flash kernel is a Pallas call, which does not auto-partition under
     pjit — with a mesh it runs inside shard_map over the batch/tp axes
@@ -219,6 +247,36 @@ def attend(
             f"window={window} on a mesh {shape}: two-part scores run the "
             "causal triangle on shards of the batch only"
         )
+    if two_part and q.shape[-1] != v.shape[-1]:
+        form = latent_form(q.shape[-1], q_rope.shape[-1], v.shape[-1])
+        if form == "joined":
+            if scale is None:
+                scale = 1.0 / math.sqrt(q.shape[-1] + q_rope.shape[-1])
+            q = jnp.concatenate([q_rope, q], axis=-1)
+            k = jnp.concatenate([
+                jnp.broadcast_to(
+                    k_rope[:, :, None, :], (*k.shape[:3], k_rope.shape[-1])
+                ), k,
+            ], axis=-1)
+            two_part = False
+        elif impl == "flash":
+            raise ValueError(
+                f"attention_impl='flash': a head of {q.shape[-1]} + "
+                f"{q_rope.shape[-1]} dims over values {v.shape[-1]} wide "
+                "has no kernel (values as wide as the own part, or as both "
+                "parts together)"
+            )
+        else:
+            if impl == "auto" and kernels_compiled():
+                warnings.warn(
+                    f"attention_impl='auto': a head of {q.shape[-1]} + "
+                    f"{q_rope.shape[-1]} dims over values {v.shape[-1]} "
+                    "wide has no kernel; running DENSE O(S²) attention "
+                    "instead of the flash kernels",
+                    RuntimeWarning,
+                    stacklevel=2,
+                )
+            impl = "dense"
     # What the rope part adds to a call: nothing to one without it.
     rope = {} if scale is None else dict(scale=scale)
     if two_part:

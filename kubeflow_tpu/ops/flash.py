@@ -2190,7 +2190,9 @@ def flash_attention(
             f"flash attention: {h} query heads over k {k.shape} / v "
             f"{v.shape}: k and v must agree and their heads divide q's; a "
             "q and k wider than v go as two parts, the part as wide as v "
-            "and a rope part (q_rope [B, S, H, R], k_rope [B, S, R])"
+            "and a rope part (q_rope [B, S, H, R], k_rope [B, S, R]); two "
+            "parts that are together as wide as v go joined, as one "
+            "(`ops/attention.attend` joins them; `latent_form`)"
         )
     interp = _auto_interpret(interpret)
     window = _checked_window(window, causal, sk)

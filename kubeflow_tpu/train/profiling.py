@@ -265,7 +265,8 @@ def scope_of_op_name(op_name: str, root: str = "") -> tuple[str, str]:
     """(`path`, `phase`) of one `op_name`. The path is the name stack with
     the transformations unwrapped (`jvp(loss)` -> `loss`), the frames that
     are no place dropped (`jit(f)`, `checkpoint`, ...), the primitive at its
-    end and the root module's name `root` taken off. The phase: `update`
+    end and the root module's name `root` taken off (and with it whatever
+    stands before its last appearance). The phase: `update`
     under the `optimizer` scope; under a `transpose(` `recompute` if also
     under `rematted_computation` (the forward run again; a `checkpoint`
     frame alone marks the backward OF a checkpointed block) and `backward`
@@ -295,7 +296,12 @@ def scope_of_op_name(op_name: str, root: str = "") -> tuple[str, str]:
         if frame in _NOT_PLACES or _BRANCH.match(frame):
             transforms.add(frame)
             continue
-        if frame and frame != root and not last:
+        if root and frame == root:
+            # A checkpoint inside a named module traces its body from the
+            # root again (`mtp/jvp(TransformerLM)/mtp/checkpoint/block`):
+            # the path starts over, or the module's frame stands twice.
+            places = []
+        elif frame and not last:
             places.append(frame)
     path = "/".join(places)
     if places and places[0] == UPDATE_SCOPE:

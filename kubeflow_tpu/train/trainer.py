@@ -201,9 +201,16 @@ def _summed_by_name(counters) -> dict:
     return out
 
 
-def softmax_cross_entropy(logits, labels, label_smoothing: float = 0.0):
+def softmax_cross_entropy(
+    logits, labels, label_smoothing: float = 0.0, where=None,
+):
     """Fused gather-based cross entropy (equals
-    `optax.softmax_cross_entropy(logits, smoothed_onehot).mean()`).
+    `optax.softmax_cross_entropy(logits, smoothed_onehot).mean()`); with
+    `where` (bool, broadcast over `labels`) the mean over the positions it
+    takes alone: a position that has no target is masked HERE, over whole
+    logits, because a slice of them to a row count that is no whole number
+    of tiles (S - 1 of S) is a relayout of [tokens, vocab] float32 on the
+    TPU (20 ms of a 300 ms step where it was tried, PERF.md §6, PR 48).
 
     The one-hot formulation materializes a [B, S, vocab] dense target and
     streams it from HBM alongside the logits; at LM vocab sizes that is
@@ -221,7 +228,10 @@ def softmax_cross_entropy(logits, labels, label_smoothing: float = 0.0):
     if label_smoothing:
         uniform = log_z - logits.mean(axis=-1)
         nll = (1.0 - label_smoothing) * nll + label_smoothing * uniform
-    return nll.mean()
+    if where is None:
+        return nll.mean()
+    where = jnp.broadcast_to(where, nll.shape)
+    return jnp.sum(jnp.where(where, nll, 0.0)) / jnp.sum(where)
 
 
 class Trainer:
@@ -433,7 +443,13 @@ class Trainer:
         def train_step(state: TrainState, batch):
             def forward_loss(params, mb, stats_in):
                 """(loss, (batch_stats, accuracy, counters)) for one
-                (micro)batch.
+                (micro)batch. The loss is the cross entropy of the logits
+                the model returns or, under `loss_in_model`, the model's
+                own scalar: a `TransformerLM` with a multi-token module
+                returns `main_loss + mtp_weight * mtp_loss` (both by
+                `softmax_cross_entropy`, the second over the labels
+                shifted by one more) and reports the two apart among
+                the counters.
 
                 Metrics that survive accumulation are SCALARS computed
                 in here (accuracy is an argmax reduced to a mean, never
@@ -654,13 +670,20 @@ class Trainer:
             if cfg.loss_in_model:
                 # The model computes its own objective; no logits ever
                 # reach the host side of the step, so loss is the only
-                # eval metric on this path.
-                return {
-                    "loss": state.apply_fn(
-                        variables, batch[input_key], train=False,
-                        labels=batch[label_key],
-                    )
+                # eval metric on this path. An objective with a further
+                # term (a multi-token module's) is no other model's
+                # "loss": such a model counts its next-token loss apart
+                # (`main_loss`), which is what is reported, the further
+                # term beside it.
+                loss, counted = state.apply_fn(
+                    variables, batch[input_key], train=False,
+                    labels=batch[label_key], mutable=["counters"],
+                )
+                counted = _summed_by_name(counted.get("counters", {}))
+                further = {
+                    k: counted[k] for k in ("mtp_loss",) if k in counted
                 }
+                return {"loss": counted.get("main_loss", loss), **further}
             logits = state.apply_fn(variables, batch[input_key], train=False)
             return {
                 "loss": softmax_cross_entropy(logits, batch[label_key]),

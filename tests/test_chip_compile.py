@@ -189,6 +189,21 @@ def test_heads_of_256_compile_at_the_qwen3_next_cell_shape(one_chip):
     assert tpu_kernel_calls(text) == len(names)
 
 
+def test_twenty_equal_heads_of_256_compile_at_the_glm_cell_shape(one_chip):
+    """20 query heads of 256 over 20 K/V heads at S = 8192, batch 1
+    (`glm-4.7-flash-ep8.train-8k`: a head's 64 rope and 192 own lanes side
+    by side over values of 256, the one-part calls): equal heads at that
+    width ran in no cell before; the fused backward's VMEM is the grouped
+    call's, and the chip's compiler takes both."""
+    sched = flash_schedule(8192, 8192, head_dim=256, dtype_bytes=2)
+    assert sched["layout"] == "seq_major" and sched["bwd_fused"]
+    assert sched["bwd_fused_vmem_bytes"] <= _FUSED_VMEM_BUDGET
+    q = jax.ShapeDtypeStruct((1, 8192, 20, 256), jnp.bfloat16, sharding=one_chip)
+    text, names = _compile(jax.grad(_loss, argnums=(0, 1, 2)), q, q, q)
+    assert names == ["flash_fwd_compact", "flash_delta", "flash_bwd_fused"]
+    assert tpu_kernel_calls(text) == len(names)
+
+
 # (B, S, H, Hkv) of a layer's attention in each cell: `olmo-1b-cut.train-2k`,
 # `-8k`, `zaya1-8b-ep2.train-8k`, a shard of `olmo-1b.train-2k-dp2tp2`, and
 # `nemotron-3-super-tp2ep64.train-8k`'s 16 query heads over one K/V head.
@@ -979,6 +994,92 @@ def test_a_xing_shaped_step_compiles_with_the_two_part_kernels(topo, monkeypatch
                  "hc_post_bwd", "hc_pre_bwd"):
         assert re.search(rf"{name}[^\n]*tpu_custom_call|tpu_custom_call[^\n]*{name}", text), name
     assert "flash_fwd_compact" not in text and "flash_bwd_fused" not in text
+    counted = compiled.memory_analysis()
+    used = (
+        counted.argument_size_in_bytes + counted.output_size_in_bytes
+        - counted.alias_size_in_bytes + counted.temp_size_in_bytes
+        + counted.generated_code_size_in_bytes
+    )
+    state = trainer.step_memory().state_bytes
+    assert state + plan.saved_bytes < used <= plan.predicted_peak, (
+        used, plan
+    )
+
+
+def test_a_glm_shaped_step_compiles_with_its_multi_token_module(topo, monkeypatch):
+    """GLM-4.7-Flash's published widths at its cell's 8,192 tokens, cut to
+    the leading dense layer and one expert layer, WITH the multi-token
+    module (its block, its projection, the shared head a second time and
+    the loss over two targets, the model's own scalar), under
+    `remat_policy="flash"` with a v5e's limit stated: the chip's compiler
+    takes the one-part flash calls at a head of 64 + 192 lanes over values
+    of 256 (none with two parts), rope's kernel over a head's first 64
+    lanes and the experts' kernels; the plan admits every name, the
+    module's block's among them, no flash forward runs again, and what the
+    compiler counts stays under the plan's predicted peak."""
+    from kubeflow_tpu.models.transformer import (
+        AttentionKind, TransformerConfig, TransformerLM, remat_plan,
+    )
+    from kubeflow_tpu.ops import moe
+    from kubeflow_tpu.parallel import MeshSpec, build_mesh
+    from kubeflow_tpu.train import TrainConfig, Trainer, profiling
+    from kubeflow_tpu.utils import memory
+
+    _as_on_the_chip(monkeypatch)
+    monkeypatch.setattr(moe, "kernels_compiled", lambda: True)
+    monkeypatch.setattr(memory, "device_limit", lambda mesh: 16_909_336_064)
+    cfg = TransformerConfig(
+        vocab_size=19360, d_model=2048, n_layers=2, n_heads=20, head_dim=192,
+        q_latent=768, kv_latent=512, rope_head_dim=64, v_head_dim=256,
+        norm_eps=1e-5, attention_kinds=(AttentionKind(20, rope_theta=1e6),),
+        attention_pattern=(0, 0), tie_embeddings=False, remat_policy="flash",
+        dense_layers=1, dense_d_ff=10240, d_ff=1536, num_experts=64,
+        experts_held=(0, 8), experts_per_token=4, router="sigmoid",
+        routed_scaling=1.8, moe_shared_ff=1536, router_force_balance=True,
+        mtp_layers=1, mtp_weight=0.3,
+    )
+    mesh = build_mesh(MeshSpec(), list(topo.devices)[:1])
+    trainer = Trainer(
+        TransformerLM(cfg, mesh=mesh),
+        TrainConfig(batch_size=1, optimizer="adamw", label_smoothing=0.0,
+                    fsdp_params=False, train_metrics="loss",
+                    loss_in_model=True),
+        mesh, example_input_shape=(2, 8192), example_input_dtype=jnp.int32,
+        input_key="tokens", label_key="labels",
+    )
+    tokens = jax.ShapeDtypeStruct(
+        (1, 8192), jnp.int32, sharding=trainer.batch_sharding(2)
+    )
+    plan = remat_plan(cfg, 8192, trainer.step_memory())
+    assert plan.names == (
+        "moe_route", "attn_residual", "mlp_hidden", "attn_latent", "attn_qkv",
+    ) and plan.refused == ()
+    compiled = trainer.make_train_step().lower(
+        trainer.abstract_state(), {"tokens": tokens, "labels": tokens}
+    ).compile()
+    text = compiled.as_text()
+    for name in ("flash_fwd_compact", "flash_bwd_fused", "flash_delta",
+                 "rope_turn_fwd", "moe_gmm_fwd"):
+        assert re.search(rf"{name}[^\n]*tpu_custom_call|tpu_custom_call[^\n]*{name}", text), name
+    assert "mla" not in text
+    # The module's loss masks its last position over logits left whole (the
+    # labels alone are rolled): a float array of S - 1 rows is no whole
+    # number of tiles, and XLA relaid the float32 logits out for one in a
+    # loop (25.8 ms a step on the chip, PERF.md §6, PR 48).
+    assert not re.search(r"(f32|bf16)\[[\d,]*\b8191\b[\d,]*\]", text)
+    table = profiling.program_scopes(text, root="TransformerLM")
+    flash = {
+        (scope.path.split("/")[0], scope.phase)
+        for name, scope in table.items() if name.startswith("flash_fwd")
+    }
+    # three blocks' forward calls, the module's under its frame; none again
+    assert flash == {
+        ("layer_0", "forward"), ("layer_1", "forward"), ("mtp", "forward")}
+    module = {scope.path for scope in table.values() if scope.path.startswith("mtp/")}
+    assert {"mtp/mtp.proj/eh_proj", "mtp/mtp.loss"} <= module
+    assert any(p.startswith("mtp/mtp.head") for p in module)
+    assert any(p.startswith("mtp/block/attn/attend") for p in module)
+    assert not any(p.startswith("mtp/mtp/") for p in module)
     counted = compiled.memory_analysis()
     used = (
         counted.argument_size_in_bytes + counted.output_size_in_bytes

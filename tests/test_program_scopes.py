@@ -256,6 +256,17 @@ def test_the_gated_norm_kernels_sit_under_their_mixers_scopes(name, want):
      "jit(train_step)/jvp(TransformerLM)/layer_0/moe/jit(searchsorted)/"
      "vmap()/closed_call/while/body/closed_call/gather",
      ("layer_7/moe", "forward")),
+    # A checkpoint inside a named module (the multi-token module's block)
+    # traces its body from the root again: the module's frame stands once.
+    ("jit(train_step)/jvp(TransformerLM)/mtp/block/attn/wo/dot_general",
+     ("mtp/block/attn/wo", "forward")),
+    ("jit(train_step)/transpose(jvp(TransformerLM))/mtp/jvp(TransformerLM)/mtp/"
+     "checkpoint/rematted_computation/block/attn/wo/dot_general",
+     ("mtp/block/attn/wo", "recompute")),
+    ("jit(train_step)/transpose(jvp(TransformerLM))/mtp/jvp(TransformerLM)/mtp/"
+     "checkpoint/block/moe/moe.route/mul", ("mtp/block/moe/moe.route", "backward")),
+    ("jit(train_step)/transpose(jvp(TransformerLM))/mtp/mtp.loss/sub",
+     ("mtp/mtp.loss", "backward")),
     ("state.params['layer_0']['attn']['wq']['kernel']", ("", "other")),
     ("reduce_sum", ("", "other")),
     ("", ("", "other")),

@@ -133,6 +133,20 @@ CELL_PLANS = {
         },
         (), 15_316_368_012,
     ),
+    "glm-4.7-flash-ep8.train-8k": (
+        7_065_188_492, 2_826_075_392,
+        # Five layers AND the multi-token module's block: six residuals, six
+        # layers' q and k at a head's two parts side by side (20 x 256
+        # lanes each) and v, six latents; five expert layers' routes and
+        # shared experts beside the dense layer's 10,240. The peak counts
+        # the module's float32 logits and their cotangent beside the main's.
+        {
+            "moe_route": 83_886_080, "attn_residual": 201_326_592,
+            "mlp_hidden": 587_202_560, "attn_latent": 138_412_032,
+            "attn_qkv": 1_509_949_440,
+        },
+        (), 13_987_887_244,
+    ),
     "zaya1-8b-ep2.train-8k": (
         9_223_475_372, 3_689_390_144,
         # CCA: q, k and v are no candidate (its backward forms them again).
@@ -626,6 +640,57 @@ def test_the_plans_bytes_are_those_of_the_results_the_layers_name(
             )
     assert named == dict(remat_plan(cfg, shape[0] * shape[1], ROOMY).bytes)
     assert set(KERNEL_RESULTS).isdisjoint(SAVED_RESULTS)
+
+
+def test_the_multi_token_module_is_one_more_block_and_one_more_head_of_the_plan():
+    """With `mtp_layers` the plan's bytes are those of the results the
+    layers AND the module's block name (traced with labels: without them
+    the module does not run), its latent attention's q and k at a head's
+    two parts side by side; every checkpoint's bytes gain the block's and
+    what its projection reads; the top of the backward gains the module's
+    float32 logits and their cotangent."""
+    joined = dict(
+        n_layers=2, num_experts=4, router="sigmoid", experts_per_token=2,
+        moe_shared_ff=32, dense_layers=1, dense_d_ff=96, q_latent=12,
+        kv_latent=8, head_dim=24, rope_head_dim=8, v_head_dim=32,
+        tie_embeddings=False, dtype=jnp.bfloat16,
+    )
+    plain = dataclasses.replace(SMALL, **joined)
+    cfg = dataclasses.replace(plain, mtp_layers=1)
+    model = TransformerLM(cfg)
+    tokens = jnp.zeros((2, 8), jnp.int32)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)
+    forward = jax.make_jaxpr(
+        lambda p, t: model.apply(p, t, labels=t, mutable=["counters"])
+    )(params, tokens).jaxpr
+    named: dict = {}
+    for eqn in _walk_eqns(forward):
+        if eqn.primitive.name == "name" and eqn.params["name"] in SAVED_RESULTS:
+            aval = eqn.outvars[0].aval
+            size = int(np.prod(aval.shape[:-1])) * transformer._lanes(aval.shape[-1])
+            named[eqn.params["name"]] = (
+                named.get(eqn.params["name"], 0) + size * aval.dtype.itemsize
+            )
+    assert named == dict(remat_plan(cfg, 16, ROOMY).bytes)
+    with_module, without = (
+        transformer._result_bytes(c, 16) for c in (cfg, plain)
+    )
+    # the module's block: an expert layer with the last layer's attention
+    assert len(with_module) == 3 and with_module[:2] == without
+    assert with_module[2] == without[1]
+    lanes = transformer._lanes
+    # q and k joined (2 heads x (8 + 24)) and v (2 x 32): the turned key
+    # is inside k
+    assert without[0][transformer.QKV_RESULT] == 16 * 2 * 3 * lanes(64)
+    kept = transformer._kept_always_bytes
+    block = 16 * (lanes(32) * 2 + lanes(2 * 32) * 2 + lanes(2) * 4)
+    assert kept(cfg, 16) - kept(plain, 16) == block + 16 * lanes(2 * 32) * 2
+    logits = 16 * lanes(64) * 4
+    top = StepMemory(state_bytes=1 << 20, grad_bytes=0, limit_bytes=1 << 40)
+    at = lambda c, layers: transformer._peak_bytes(c, 16, layers, top)
+    assert at(cfg, with_module) - at(plain, without) == (
+        2 * logits + kept(cfg, 16) - kept(plain, 16)
+    )
 
 
 def test_a_delta_rule_layer_keeps_its_kernels_results_and_counts_its_work():
