@@ -53,8 +53,27 @@ x capacity` and not the buffer's length.
   w[e]` (or `@ w[e].T` for the operand's gradient). Grid (column tiles,
   row tiles, contraction tiles); consecutive row tiles of one expert keep
   the weight block's index, so an expert's weights are fetched once a
-  column tile. A tile past `n_tiles` costs a grid step and nothing else:
-  no matmul, no fetch and no write (every index is clamped to the last
+  column tile. The weights are read as they lie in HBM, at the
+  parameters' own dtype: where that is not the rows' (float32 parameters
+  under bfloat16 rows) a fetched block is rounded to the rows' dtype in
+  VMEM, to nearest even as XLA's convert rounds, ONCE, on the first row
+  tile that meets it (`t == 0` or another expert than the tile before;
+  every step where the contraction takes several, since each fetches),
+  into a scratch the matmuls of the expert's other row tiles read; no
+  copy of the weights in the rows' dtype is ever written to HBM. Such a
+  block is twice the bytes for the same matmuls, and the pipeline would
+  fetch it one grid step ahead, behind an expert's LAST row tile alone
+  (16 MiB behind 12 us of matmul in zaya), into one of two buffers: with
+  the contraction in one step the kernel fetches it itself, an expert
+  ahead, behind all of the expert's tiles, into the ONE block it has
+  just rounded (`_rounded_weights`; a float32 block and its rounded copy
+  are 24 MiB in zaya where two bfloat16 buffers were 16, and the VMEM
+  limit grows by the difference: XLA keeps arrays of its own in VMEM
+  across a call, zaya's 64 MiB row buffers among them, in what the
+  call's limit leaves of the chip's 128 MiB). Where
+  the two dtypes are equal there is no scratch and no rounding. A tile
+  past `n_tiles` costs a grid step and nothing else: no matmul, no
+  fetch, no rounding and no write (every index is clamped to the last
   tile in use, whose output block stays resident and is written back
   once). The expert's activation is the epilogue, on the float32
   accumulator, of the last matmul that reads the rows, which also
@@ -94,10 +113,12 @@ anything.
 
 `_experts_in` (dispatch, then the matmuls that read the rows) and
 `_experts_out` (the last matmul, then the combine) tie the kernels
-together with a `custom_vjp` each; the weights go in at their own dtype
-(float32 parameters) and are cast for the kernels in the forward and
-again in the backward, so no bfloat16 copy of an expert's weights is kept
-as a residual. On the CPU backend the kernels run under the Pallas
+together with a `custom_vjp` each; the weights go into every grouped
+matmul at their own dtype (float32 parameters), forward and backward, and
+the kernels round the blocks they fetch: no bfloat16 copy of an expert's
+weights is formed outside a kernel, kept as a residual or read twice
+(`moe_schedule()`'s `weight_itemsize`, `weight_rounds` and
+`weight_cast_bytes`). On the CPU backend the kernels run under the Pallas
 interpreter (tests), which fills unwritten memory with NaN; anywhere else
 they are compiled (`ops/flash.kernels_compiled`). Every `pallas_call` has
 a `name=` starting `moe_gmm_`, `moe_rows_` or `moe_plan_`: what a device
@@ -132,7 +153,9 @@ CHECKPOINT_ROWS_NAME = "moe_plan_rows"
 # Tile caps: a weight block of 2048 x 2048 bf16 is 8 MiB (16 double-
 # buffered), which keeps an expert of this width to one fetch a pass; a
 # side may be as long as 4096 where the other is short (1024 x 2688: one
-# block an expert, one grid step a row tile).
+# block an expert, one grid step a row tile). The caps count elements at
+# the rows' 2 bytes: where the weights lie wider, a call's VMEM limit
+# grows by the bytes that adds (`_gmm`), not the blocks smaller.
 _TILE_SIDE = 4096
 _TILE_WEIGHT = 2048 * 2048
 _TILE_DW = 3 * 1024 * 1024  # elements of dw's float32 accumulator
@@ -141,6 +164,7 @@ _SUBLANES = 8  # a float32 tile's height: the least a DMA may slice
 _SUM_TOKENS = 256  # tokens a grid step of `moe_rows_sum`
 _SUM_VMEM = 8 * 1024 * 1024  # its gathered rows, [slots, tokens, d] float32
 _PLAN_ROWS = 128  # rows a pass of the `moe_plan_*` kernels: 16 registers a line
+_ROUND_ROWS = 256  # rows of a weight block rounded a pass
 # The forms of expert by their count of matrices: what the last matmul on
 # the rows makes of its product (`_activate`, `_slope`).
 _FORMS = {2: "relu2", 3: "gated"}
@@ -274,13 +298,22 @@ def slot_weights(gate, plan):
 def moe_schedule(
     tokens: int, k: int, held: int, width_in: int, width_out: int, *,
     live_tiles: int | None = None, block_rows: int = BLOCK_ROWS,
+    weight_itemsize: int = 4,
 ) -> dict:
     """What one `moe_gmm_fwd` call of `width_in` -> `width_out` and the
     rows' movers touch, from the shapes and the count of tiles in use
     (all of them where `live_tiles` is None): static, for tests and for
     reading a trace. `*_bytes` count a row tile's operands and results
-    once at 2 bytes (4 where packed) and every held expert's weights once
-    a column tile. `activation` says for each form of expert who forms
+    once at 2 bytes (4 where packed) and every weight block the call
+    fetches at the `weight_itemsize` bytes it lies in HBM at (float32
+    parameters: 4): each held expert's once a column tile where the
+    contraction is one step, a row tile's every time where it is several.
+    `weight_rounds` of those blocks are rounded in VMEM to the rows' 2
+    bytes, one rounding a fetch (none where the weights lie at 2 bytes
+    already), and `weight_cast_bytes`, what XLA moves to cast a matrix
+    for the call, is 0 (`6 x held x d x f` a pass until PR 49, and the
+    kernel read the 2-byte copy besides). `activation` says for each
+    form of expert who forms
     its activation and slope, `results_past_live` how many kernel results
     are written past the tiles in use, `plan_updates` how many single
     elements the plan and its backward scatter: none at k > 1 (a row's
@@ -291,7 +324,8 @@ def moe_schedule(
     live = tiles if live_tiles is None else live_tiles
     tc, to = _gmm_tiles(width_in, width_out)
     grid = (width_out // to, tiles, width_in // tc)
-    weights = 2 * held * width_in * width_out
+    fetches = grid[0] * (held if grid[2] == 1 else live * grid[2])
+    weights = fetches * tc * to * weight_itemsize
     rows = lambda t: t * block_rows
     return {
         "tiles": tiles, "rows": rows(tiles),
@@ -299,6 +333,9 @@ def moe_schedule(
         "gmm_grid": grid, "gmm_grid_steps": math.prod(grid),
         "gmm_dead_steps": grid[0] * (tiles - live) * grid[2],
         "gmm_bytes": weights + 2 * rows(live) * (width_in + width_out),
+        "weight_itemsize": weight_itemsize,
+        "weight_rounds": fetches if weight_itemsize != 2 else 0,
+        "weight_cast_bytes": 0,
         "activation": {form: "kernel" for form in _FORMS.values()},
         "results_past_live": 0,
         "movers": "moe_rows" if k > 1 else "xla_gather",
@@ -314,9 +351,9 @@ def moe_schedule(
 # -- kernels -----------------------------------------------------------------
 
 
-def _params(semantics):
+def _params(semantics, more_vmem: int = 0):
     return pltpu.CompilerParams(
-        dimension_semantics=semantics, vmem_limit_bytes=_VMEM_LIMIT
+        dimension_semantics=semantics, vmem_limit_bytes=_VMEM_LIMIT + more_vmem
     )
 
 
@@ -405,22 +442,80 @@ class _Packed(NamedTuple):
             ref[pl.ds(s, rows, stride=self.sublanes), :] = piece.astype(ref.dtype)
 
 
+def _round_block(src_ref, dst_ref):
+    """A block rounded to `dst_ref`'s dtype, `_ROUND_ROWS` rows a pass:
+    a whole block taken as one value would lie in VMEM a second time."""
+    rows = src_ref.shape[0]
+    step = _tile(rows, _ROUND_ROWS)
+
+    def some(i, carry):
+        at = pl.ds(pl.multiple_of(i * step, step), step)
+        dst_ref[at, :] = src_ref[at, :].astype(dst_ref.dtype)
+        return carry
+
+    lax.fori_loop(0, rows // step, some, 0)
+
+
+def _rounded_weights(te_ref, rhs_ref, rounded_ref, *ahead, live,
+                     transpose_rhs: bool):
+    """The weight block of this grid step in the rows' dtype, where the
+    weights lie in another: `rounded_ref`, filled once each time a block
+    is fetched. With the contraction in steps (`ahead` empty) the pipeline
+    fetches `rhs_ref`'s block every step and every step in use rounds it.
+    With the contraction in one step `rhs_ref` is the whole operand, left
+    in HBM, and the fetch is the kernel's own (`ahead`: one block in the
+    weights' dtype and its semaphore): an expert's first row tile within
+    a column tile waits for its block, rounds it, and starts the NEXT
+    expert's into the block it has just read (every held expert has a
+    tile in use, `tiles_in_use`, so expert e + 1 follows e); the expert's
+    other row tiles read the rounded block. A block is so fetched behind
+    all of an expert's matmuls, where the pipeline, a grid step ahead,
+    would fetch it behind the last one's and hold two of them."""
+    if not ahead:
+        pl.when(live)(lambda: _round_block(rhs_ref.at[0], rounded_ref.at[0]))
+        return rounded_ref
+    wide, sem = ahead
+    o, t = pl.program_id(0), pl.program_id(1)
+    e = te_ref[t]
+    to = rounded_ref.shape[1] if transpose_rhs else rounded_ref.shape[2]
+
+    def fetch(expert):
+        cols = pl.ds(pl.multiple_of(o * to, to), to)
+        block = rhs_ref.at[expert, cols, :] if transpose_rhs else (
+            rhs_ref.at[expert, :, cols]
+        )
+        return pltpu.make_async_copy(block, wide, sem)
+
+    @pl.when(live & ((t == 0) | (te_ref[jnp.maximum(t - 1, 0)] != e)))
+    def _an_experts_first_tile():
+        pl.when(t == 0)(lambda: fetch(e).start())
+        fetch(e).wait()
+        _round_block(wide, rounded_ref.at[0])
+        pl.when(e + 1 < rhs_ref.shape[0])(lambda: fetch(e + 1).start())
+
+    return rounded_ref
+
+
 def _gmm_kernel(te_ref, nt_ref, *refs, transpose_rhs: bool, saved: int,
                 wrt: int, act: bool, gated: bool, onto: bool, packed: bool):
-    del te_ref
     lhs_ref, rhs_ref, *refs = refs
     saved_refs, refs = refs[:saved], refs[saved:]
     gate_ref = refs.pop(0) if gated else None
     onto_ref = refs.pop(0) if onto else None
     out_ref, *refs = refs
     pre_ref = refs.pop(0) if act else None
-    (acc,) = refs
+    acc, *rounding = refs
     t, c = pl.program_id(1), pl.program_id(2)
     live = t < nt_ref[0]
 
     @pl.when(c == 0)
     def _init():
         acc[...] = jnp.zeros_like(acc)
+
+    if rounding:  # the weights lie in another dtype than the rows'
+        rhs_ref = _rounded_weights(
+            te_ref, rhs_ref, *rounding, live=live, transpose_rhs=transpose_rhs
+        )
 
     @pl.when(live)
     def _compute():
@@ -525,6 +620,20 @@ def _gmm(lhs, rhs, tile_expert, n_tiles, saved=(), gate=None, onto=None, *,
         )
         out_shape = jax.ShapeDtypeStruct(form.shape(rows), jnp.float32)
     gated, added = gate is not None, onto is not None
+    # weights that lie in another dtype than the rows': a block rounded in
+    # VMEM (`_rounded_weights`), fetched an expert ahead by the kernel where
+    # the contraction is one step; the limit grows by what the blocks take
+    # beyond the two in the rows' dtype the tile caps are sized for
+    block, more_vmem, rounding = rhs_spec.block_shape, 0, []
+    if rhs.dtype != lhs.dtype:
+        rounding = [pltpu.VMEM(block, lhs.dtype)]
+        fetched = 2  # the pipeline's two buffers
+        if steps == 1:
+            rounding += [pltpu.VMEM(block[1:], rhs.dtype), pltpu.SemaphoreType.DMA(())]
+            rhs_spec, fetched = pl.BlockSpec(memory_space=pl.ANY), 1
+        more_vmem = math.prod(block) * max(
+            fetched * rhs.dtype.itemsize - lhs.dtype.itemsize, 0
+        )
     operands = (
         tile_expert, n_tiles, lhs, rhs, *saved, *([gate] * gated),
         *([onto] * added),
@@ -540,11 +649,11 @@ def _gmm(lhs, rhs, tile_expert, n_tiles, saved=(), gate=None, onto=None, *,
             in_specs=[operand, rhs_spec] + [operand] * len(saved)
             + [plain] * gated + [result] * added,
             out_specs=[result] * (1 + act),
-            scratch_shapes=[pltpu.VMEM((block_rows, to), jnp.float32)],
+            scratch_shapes=[pltpu.VMEM((block_rows, to), jnp.float32)] + rounding,
         ),
         out_shape=[out_shape] * (1 + act),
         input_output_aliases={len(operands) - 1: 0} if added else {},
-        compiler_params=_params(("parallel", "arbitrary", "arbitrary")),
+        compiler_params=_params(("parallel", "arbitrary", "arbitrary"), more_vmem),
         interpret=interpret,
         name="moe_gmm_dlhs" if transpose_rhs else "moe_gmm_fwd",
     )(*operands)
@@ -1051,7 +1160,7 @@ def _experts_in_fwd(x, weights, plan, how):
         else:
             rows = _take(x, plan["src"])
     with jax.named_scope("moe.experts"):
-        *w_gate, w_in = (w.astype(rows.dtype) for w in weights)
+        *w_gate, w_in = weights
         gate = [_gmm(rows, w, te, nt, **kernel) for w in w_gate]
         hidden, pre = _gmm(
             rows, w_in, te, nt, gate=gate[0] if gate else None, act=True,
@@ -1068,7 +1177,7 @@ def _experts_in_bwd(how, res, g):
         d_rows = None  # each matrix's part is added onto the one before
         for wrt, w in enumerate(weights):
             d_rows = _gmm(
-                g, w.astype(g.dtype), te, nt, saved, onto=d_rows, wrt=wrt,
+                g, w, te, nt, saved, onto=d_rows, wrt=wrt,
                 transpose_rhs=True, packed=how.by_pairs, **kernel,
             )
         d_weights = tuple(
@@ -1104,8 +1213,8 @@ def _experts_out(hidden, w_down, weight, plan, how: _How):
 def _experts_out_fwd(hidden, w_down, weight, plan, how):
     with jax.named_scope("moe.experts"):
         out = _gmm(
-            hidden, w_down.astype(hidden.dtype), plan["tile_expert"],
-            plan["n_tiles"], block_rows=how.block_rows, packed=how.by_pairs,
+            hidden, w_down, plan["tile_expert"], plan["n_tiles"],
+            block_rows=how.block_rows, packed=how.by_pairs,
             interpret=how.interpret,
         )
     if how.by_pairs:
@@ -1146,10 +1255,7 @@ def _experts_out_bwd(how, res, g):
                 (g * weight[:, None]).astype(hidden.dtype), plan["src"]
             )
     with jax.named_scope("moe.experts"):
-        d_hidden = _gmm(
-            d_out, w_down.astype(d_out.dtype), te, nt, transpose_rhs=True,
-            **kernel,
-        )
+        d_hidden = _gmm(d_out, w_down, te, nt, transpose_rhs=True, **kernel)
         d_w = _gmm_dw(
             hidden, d_out, te, nt, n_experts=w_down.shape[0],
             out_dtype=w_down.dtype, **kernel,

@@ -323,6 +323,52 @@ def test_grouped_matmul_kernels_compile_at_the_zaya_cell_shape(
     assert len(names) == 9 == tpu_kernel_calls(text)
 
 
+@pytest.mark.parametrize("call", ["forward", "dlhs"])
+@pytest.mark.parametrize("cell, held, d, f, tiles, by_pairs", [
+    ("zaya", 8, 2048, 2048, 72, False),  # 16 MiB a block and 8 rounded
+    ("qwen3-next", 32, 2048, 512, 672, True),
+    ("xing", 8, 3584, 1024, 264, True),  # 14 MiB a block beside a packed result
+])
+def test_grouped_matmuls_compile_with_float32_weights_at_the_cells_shapes(
+    one_chip, cell, held, d, f, tiles, by_pairs, call
+):
+    """`moe_gmm_fwd` with the gated activation and `moe_gmm_dlhs` with
+    the slope formed on the way in, added onto the call before (packed
+    at more than one expert a token), bfloat16 rows over float32 weights
+    as they lie: a block is fetched at 4 bytes, an expert ahead, by the
+    kernel's own DMA, rounded into a scratch of the rows' dtype, and the
+    VMEM limit follows what that adds."""
+    from kubeflow_tpu.ops import moe
+
+    assert moe._gmm_tiles(d, f) == (d, f) and moe._VMEM_LIMIT == 48 * 2 ** 20
+    rows = tiles * moe.BLOCK_ROWS
+    shape = lambda dims, dtype: jax.ShapeDtypeStruct(
+        dims, dtype, sharding=one_chip
+    )
+    how = dict(block_rows=moe.BLOCK_ROWS, interpret=False)
+    wide, narrow = shape((rows, d), jnp.bfloat16), shape((rows, f), jnp.bfloat16)
+    table = (shape((tiles,), jnp.int32), shape((1,), jnp.int32))
+    if call == "forward":
+        fn = lambda x, w, te, nt, gate: moe._gmm(
+            x, w, te, nt, gate=gate, act=True, **how
+        )
+        args = (wide, shape((held, d, f), jnp.float32), *table, narrow)
+    else:
+        packed = shape(moe._Packed.of(d).shape(rows), jnp.float32)
+        fn = lambda g, w, te, nt, a, b, onto: moe._gmm(
+            g, w, te, nt, (a, b), onto=onto, wrt=1, transpose_rhs=True,
+            packed=by_pairs, **how,
+        )
+        args = (
+            narrow, shape((held, d, f), jnp.float32), *table, narrow, narrow,
+            packed if by_pairs else wide,
+        )
+    text, names = _compile(fn, *args)
+    assert names == ["moe_gmm_fwd" if call == "forward" else "moe_gmm_dlhs"]
+    assert tpu_kernel_calls(text) == 1
+    assert " convert(" not in text.split("ENTRY")[1]
+
+
 def test_latent_top_k_expert_kernels_compile_at_the_nemotron_cell_shape(
     one_chip, monkeypatch
 ):

@@ -239,6 +239,173 @@ def test_the_activation_and_its_slope_are_the_matmuls_own(form):
         )
 
 
+# -- the weights as they lie ------------------------------------------------------
+
+# rows an expert: three tiles (the rounded block kept across them), none
+# (its one zero tile), a part of one, one full; three tiles past them
+_ROUND_COUNTS = (40, 0, 3, 16)
+# the call beside the same call on `w.astype(bfloat16)`; the last three at
+# shapes of their own (a module value read at trace time does not retrace)
+_ROUND_CASES = {
+    "forward": dict(),
+    "transposed": dict(transpose_rhs=True),
+    "relu2": dict(act=True),
+    "gated": dict(act=True, gate=True),
+    "saved": dict(transpose_rhs=True, saved=1, wrt=0),
+    "saved onto": dict(transpose_rhs=True, saved=2, wrt=1, onto=True),
+    "packed": dict(packed=True),
+    "packed onto": dict(transpose_rhs=True, saved=2, wrt=0, onto=True, packed=True),
+    "two column tiles": dict(d=48, f=256, side=128),
+    "two column tiles transposed": dict(d=256, f=48, side=128, transpose_rhs=True),
+    "two contraction steps": dict(d=256, f=80, weight=128 * 128),
+}
+
+
+def _bits(a):
+    a = np.asarray(a)
+    return a.view({2: np.uint16, 4: np.uint32}[a.dtype.itemsize])
+
+
+@pytest.mark.parametrize("case", _ROUND_CASES)
+def test_float32_weights_rounded_in_the_kernel_equal_the_cast_to_the_bit(
+    case, monkeypatch
+):
+    """bfloat16 rows and float32 weights, as the cells have them: every
+    form of `_gmm` gives the bits of the same call on `w.astype(bfloat16)`
+    (the parent's call sites), over an expert with no row, with one tile
+    and with several, and nothing is written past the tiles in use."""
+    how = dict(_ROUND_CASES[case])
+    d, f = how.pop("d", D), how.pop("f", F)
+    if "side" in how:
+        monkeypatch.setattr(moe, "_TILE_SIDE", how.pop("side"))
+    if "weight" in how:
+        monkeypatch.setattr(moe, "_TILE_WEIGHT", how.pop("weight"))
+    n_saved, gated, added = (
+        how.pop("saved", 0), how.pop("gate", False), how.pop("onto", False)
+    )
+    turned = how.get("transpose_rhs", False)
+    contract, cols = (f, d) if turned else (d, f)
+    tc, to = moe._gmm_tiles(contract, cols, how.get("packed", False))
+    assert (cols // to, contract // tc) == {
+        "two column tiles": (2, 1), "two column tiles transposed": (2, 1),
+        "two contraction steps": (1, 2),
+    }.get(case, (1, 1))
+    used = np.maximum(-(-np.array(_ROUND_COUNTS) // ROWS), 1)
+    tiles = int(used.sum()) + 3
+    tile_expert = jnp.asarray(
+        np.repeat(np.arange(HELD), used).tolist() + [HELD - 1] * 3, jnp.int32
+    )
+    n_tiles = jnp.asarray([used.sum()], jnp.int32)
+    live = int(used.sum()) * ROWS
+    ks = jax.random.split(jax.random.PRNGKey(7), 6)
+    draw = lambda key, *shape: jax.random.normal(key, shape).astype(jnp.bfloat16)
+    lhs = draw(ks[0], tiles * ROWS, contract)
+    w = jax.random.normal(ks[1], (HELD, d, f)) / 6
+    assert (w.astype(jnp.bfloat16).astype(jnp.float32) != w).mean() > 0.9
+    saved = tuple(draw(k, tiles * ROWS, contract) for k in ks[2:2 + n_saved])
+    gate = draw(ks[4], tiles * ROWS, cols) if gated else None
+    onto = None
+    if added:
+        onto = jax.random.normal(ks[5], (tiles * ROWS, cols))
+        onto = moe._pack(onto) if how.get("packed") else onto.astype(jnp.bfloat16)
+
+    def call(w):
+        out = moe._gmm(
+            lhs, w, tile_expert, n_tiles, saved, gate,
+            None if onto is None else onto + 0, block_rows=ROWS,
+            interpret=True, **how,
+        )
+        return out if isinstance(out, tuple) else (out,)
+
+    by_row = lambda r: np.asarray(r).reshape(tiles * ROWS, -1)
+    if how.get("packed"):  # the sublanes past a row's pieces: unwritten
+        form = moe._Packed.of(cols)
+        by_row = lambda r: np.asarray(r).reshape(
+            tiles * ROWS, form.sublanes, form.lanes
+        )[:, :form.pieces].reshape(tiles * ROWS, -1)
+    got, want = call(w), call(w.astype(jnp.bfloat16))
+    for a, b in zip(got, want, strict=True):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        assert np.isfinite(by_row(a)[:live].astype(np.float32)).all()
+        np.testing.assert_array_equal(_bits(by_row(a)[:live]), _bits(by_row(b)[:live]))
+        assert np.isnan(by_row(a)[live:].astype(np.float32)).all() or added
+
+
+def _gmm_call(rows_dtype, weight_dtype, **how):
+    """The `pallas_call` equation of one `_gmm` call."""
+    tiles = 5
+    jaxpr = jax.make_jaxpr(
+        lambda lhs, w, te, nt: moe._gmm(
+            lhs, w, te, nt, block_rows=ROWS, interpret=True, **how
+        )
+    )(
+        jnp.zeros((tiles * ROWS, D), rows_dtype),
+        jnp.zeros((HELD, D, F), weight_dtype),
+        jnp.zeros((tiles,), jnp.int32), jnp.ones((1,), jnp.int32),
+    )
+    (call,) = (
+        e for e in _equations(jaxpr.jaxpr) if e.primitive.name == "pallas_call"
+    )
+    return call
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_equal_dtypes_trace_the_kernel_without_a_rounding(dtype):
+    """Rows and weights of one dtype (the float32 tests; a model that
+    held bfloat16 weights): the accumulator is the call's one scratch and
+    the body is `_init`, `_compute`, `_write`, as before the kernels took
+    float32 weights; float32 weights under bfloat16 rows add the rounded
+    block, its fetch and their `pl.when`, nothing else."""
+    scratch = lambda call: call.params["grid_mapping"].num_scratch_operands
+    conds = lambda call: sum(
+        e.primitive.name == "cond" for e in call.params["jaxpr"].eqns
+    )
+    blocks = lambda call: {  # the dtypes a value of the block's shape takes
+        jnp.dtype(v.aval.dtype) for e in _equations(call.params["jaxpr"])
+        for v in e.outvars if getattr(v.aval, "shape", None) == (D, F)
+    }
+    same = _gmm_call(dtype, dtype)
+    assert (scratch(same), conds(same)) == (1, 3)
+    assert blocks(same) == {jnp.dtype(dtype)}
+    if dtype == jnp.bfloat16:
+        # the rounded block, the block fetched an expert ahead and its
+        # semaphore; the body's fourth `pl.when`, an expert's first tile
+        mixed = _gmm_call(jnp.bfloat16, jnp.float32)
+        assert (scratch(mixed), conds(mixed)) == (4, 4)
+        assert blocks(mixed) == {jnp.dtype(jnp.float32), jnp.dtype(jnp.bfloat16)}
+        assert mixed.params["name"] == same.params["name"] == "moe_gmm_fwd"
+
+
+@pytest.mark.parametrize("form", ["relu2", "gated"])
+@pytest.mark.parametrize("k", [0, 2])
+def test_no_cast_of_an_experts_weights_outside_the_kernels(k, form):
+    """In a layer's forward and backward, rows bfloat16 and weights
+    float32, no `convert_element_type` outside a `pallas_call` has an
+    operand of an expert matrix's shape, `[held, d, f]` or `[held, f,
+    d]`: the kernels read the parameters as they lie."""
+    expert, x, gate, target, mine = _setup(k, form)
+    x = x.astype(jnp.bfloat16)
+    matrices = {(HELD, D, F), (HELD, F, D)}
+
+    def loss(x, gate, *w):
+        out = moe.expert_mlp(x, expert, gate, w, LO, block_rows=ROWS)
+        return (out * target).sum()
+
+    jaxpr = jax.make_jaxpr(
+        jax.value_and_grad(loss, tuple(range(2 + len(mine))))
+    )(x, gate, *mine)
+    casts, kernels = [], 0
+    for eqn in _equations(jaxpr.jaxpr):
+        kernels += eqn.primitive.name == "pallas_call"
+        if eqn.primitive.name == "convert_element_type":
+            casts.append(eqn.invars[0].aval.shape)
+    assert kernels >= 3 * len(mine)
+    assert not matrices & set(casts), casts
+    # the weights' gradients leave `moe_gmm_dw` as float32, as they did
+    grads = jax.grad(loss, tuple(range(2, 2 + len(mine))))(x, gate, *mine)
+    assert all(g.dtype == jnp.float32 for g in grads)
+
+
 def _equations(jaxpr):
     """Every equation of a jaxpr and of the jaxprs its equations hold, a
     `pallas_call`'s body apart."""
@@ -486,8 +653,17 @@ def test_the_schedule_at_the_nemotron_cells_shape():
     assert live["gmm_grid"] == (1, 264, 1) and live["gmm_dead_steps"] == 248
     assert live["movers"] == "moe_rows" and live["rows_take_grid_steps"] == 264
     assert live["rows_sum_grid_steps"] == 32
-    weights = 2 * 8 * 1024 * 2688
+    # the weights at the 4 bytes they lie at, each block rounded once a
+    # fetch; no cast outside the kernels (6 B a parameter a pass at PR 48)
+    weights = 4 * 8 * 1024 * 2688
     assert live["gmm_bytes"] == weights + 2 * 4096 * (1024 + 2688)
+    assert (live["weight_itemsize"], live["weight_rounds"]) == (4, 8)
+    assert live["weight_cast_bytes"] == 0
+    narrow = moe.moe_schedule(
+        8192, 22, 8, 1024, 2688, live_tiles=16, weight_itemsize=2
+    )
+    assert narrow["gmm_bytes"] == live["gmm_bytes"] - weights // 2
+    assert narrow["weight_rounds"] == 0 == narrow["weight_cast_bytes"]
     assert live["activation"]["relu2"] == "kernel"
     assert live["results_past_live"] == 0
 
@@ -515,10 +691,31 @@ def test_the_schedule_at_the_gated_cells_shapes():
     zaya = moe.moe_schedule(16384, 1, 8, 2048, 2048, live_tiles=36)
     assert zaya["tiles"] == 72 and zaya["movers"] == "xla_gather"
     assert zaya["gmm_grid"] == (1, 72, 1) and zaya["gmm_dead_steps"] == 36
+    # a block an expert, fetched at 4 bytes and rounded once: 134 MB of
+    # weights a call in zaya where the cast's copy was 67 read after 201
+    # moved to make it
+    assert zaya["weight_rounds"] == laguna["weight_rounds"] == 8
+    assert zaya["gmm_bytes"] == 4 * 8 * 2048 * 2048 + 2 * 36 * 256 * 4096
+    qwen = moe.moe_schedule(16384, 10, 32, 2048, 512, live_tiles=64)
+    assert qwen["weight_rounds"] == 32 and qwen["gmm_grid"] == (1, 672, 1)
+    for sched in (laguna, zaya, qwen):
+        assert sched["weight_itemsize"] == 4 and sched["weight_cast_bytes"] == 0
     for sched in (laguna, zaya):
         assert sched["activation"] == {"relu2": "kernel", "gated": "kernel"}
         assert sched["results_past_live"] == 0
         assert "gmm_bytes_zeroing" not in sched
+
+
+@pytest.mark.parametrize("d, f, grid, fetches", [
+    (2048, 2048, (1, 72, 1), 8),  # zaya: a block an expert
+    (1024, 8192, (2, 72, 1), 16),  # two column tiles: each expert's twice
+    (8192, 4096, (1, 72, 8), 36 * 8),  # a contraction in steps: every step fetches
+])
+def test_the_schedule_rounds_a_block_once_a_fetch(d, f, grid, fetches):
+    sched = moe.moe_schedule(16384, 1, 8, d, f, live_tiles=36)
+    assert sched["gmm_grid"] == grid and sched["weight_rounds"] == fetches
+    tc, to = moe._gmm_tiles(d, f)
+    assert sched["gmm_bytes"] == 4 * fetches * tc * to + 2 * 36 * 256 * (d + f)
 
 
 def test_the_plan_updates_at_the_cells_shapes():
