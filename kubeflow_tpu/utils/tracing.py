@@ -21,7 +21,13 @@ OTel-shaped core small enough to have zero dependencies:
   running", when the annotation is inert.
 - finished spans land in a bounded ring buffer (`export()` drains JSON
   dicts, oldest dropped on overflow) — the in-process collector; ship
-  them wherever by draining periodically.
+  them wherever by draining periodically. `snapshot()` reads the same
+  dicts and leaves the ring whole, for a second reader.
+- `Tracer.record(name, start_ns, end_ns, **attrs)` takes a span that is
+  already over (what `utils/compile_cache.py` hears from JAX once a
+  trace, a lowering or a compile has ended) into the same ring, linked
+  to the span current on the calling thread. It enters no annotation:
+  the profiler cannot be told of the past.
 - `trace_header()`/`from_header()` carry the trace id across HTTP hops
   (`x-kftpu-trace-id`, the platform's traceparent analog), so a web
   request's span tree continues into kfam/controllers.
@@ -158,16 +164,47 @@ class Tracer:
             raise
         finally:
             _current.reset(token)
-            with self._lock:
-                if len(self._finished) == self._capacity:
-                    self.dropped += 1
-                self._finished.append(span)
+            self._keep(span)
+
+    def record(
+        self, name: str, start_ns: int, end_ns: int, **attributes: Any
+    ) -> Span:
+        """A span that is already over, on `time.perf_counter_ns`'s
+        clock: a child of the calling thread's current span, in its
+        trace, or the root of a trace of its own."""
+        parent = _current.get()
+        span = Span(
+            name=name,
+            trace_id=parent.trace_id if parent is not None else _new_id(),
+            span_id=_new_id(),
+            parent_id=parent.span_id if parent is not None else None,
+            start=time.time() - (time.perf_counter_ns() - start_ns) / 1e9,
+            attributes=attributes,
+            start_ns=start_ns,
+            end_ns=end_ns,
+        )
+        self._keep(span)
+        return span
+
+    def _keep(self, span: Span) -> None:
+        with self._lock:
+            if len(self._finished) == self._capacity:
+                self.dropped += 1
+            self._finished.append(span)
 
     def export(self) -> list[dict]:
         """Drain all finished spans (oldest first)."""
         with self._lock:
             out = list(self._finished)
             self._finished.clear()
+        return [span.to_dict() for span in out]
+
+    def snapshot(self) -> list[dict]:
+        """The finished spans (oldest first), left in the ring: what
+        `export()` would drain, for a reader that is not the one that
+        ships them."""
+        with self._lock:
+            out = list(self._finished)
         return [span.to_dict() for span in out]
 
     def pending(self) -> int:

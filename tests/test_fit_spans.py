@@ -1,7 +1,9 @@
 """fit() reports to the platform's one span primitive (ISSUE 26): a
 `train.step` parent span a step with the step's number, children where the
 work happens, `FitResult.timings` added up from the same spans, and the
-same spans on the host plane of a profile taken meanwhile."""
+same spans on the host plane of a profile taken meanwhile. Since ISSUE 50
+the whole call is one `train.fit` span, the root of everything it reports,
+and what it compiles is in the ring too (`tests/test_compile_spans.py`)."""
 
 import glob
 
@@ -40,13 +42,19 @@ def _job(devices, dp=1):
 
 
 def _fit_spans(*args, **kwargs):
-    """fit(), and the `train.*` spans it left in the ring, oldest first."""
+    """fit(), and the `train.*` and `compile.*` spans it left in the ring,
+    oldest first: the call's own `train.fit` ends last."""
     tracing.tracer.export()
     result = fit(*args, **kwargs)
     spans = [
-        s for s in tracing.tracer.export() if s["name"].startswith("train.")
+        s for s in tracing.tracer.export()
+        if s["name"].startswith(("train.", "compile."))
     ]
     return result, spans
+
+
+def _train(spans):
+    return [s for s in spans if s["name"].startswith("train.")]
 
 
 def _by_step(spans):
@@ -80,8 +88,19 @@ def test_one_step_span_a_step_with_children_in_order(devices):
             parent = by_id[s["parentId"]]
             assert parent["startNs"] <= s["startNs"] <= s["endNs"] <= parent["endNs"]
             assert s["traceId"] == parent["traceId"]
-    # set-up before the first step: the state, then the step function
-    assert [s["name"] for s in spans[:2]] == ["train.init", "train.init"]
+    # the call is the root: one trace, and everything else inside it
+    root = spans[-1]
+    assert root["name"] == "train.fit" and root["parentId"] is None
+    assert root["attributes"] == {
+        "total_steps": 5, "start_step": 0, "resumed_from": None,
+    }
+    assert {s["traceId"] for s in spans} == {root["traceId"]}
+    # set-up before the first step: the state, then the step function,
+    # children of the call as the steps are
+    first = [s for s in _train(spans) if s["parentId"] == root["spanId"]][:3]
+    assert [s["name"] for s in first] == [
+        "train.init", "train.init", "train.step",
+    ]
 
 
 @pytest.mark.parametrize("log_every,save_every,readback_at", [
@@ -119,8 +138,10 @@ def test_timings_equal_the_rings_sums(devices, tmp_path):
     result, spans = _fit_spans(
         trainer, data, total_steps=4, log_every=1, checkpointer=ckpt
     )
+    *inside, root = spans
+    assert root["name"] == "train.fit" and root["parentId"] is None
     sums: dict = {}
-    for s in spans:
+    for s in inside:
         total = sums.setdefault(
             s["name"].removeprefix("train."), {"count": 0, "seconds": 0.0}
         )
@@ -128,6 +149,7 @@ def test_timings_equal_the_rings_sums(devices, tmp_path):
         total["seconds"] += (s["endNs"] - s["startNs"]) / 1e9
     assert set(result.timings) == {
         "restore", "init", "step", "data", "dispatch", "readback", "save",
+        "compile.trace", "compile.lower", "compile.backend",
     }
     for name, total in result.timings.items():
         assert total["count"] == sums[name]["count"], name
@@ -137,7 +159,9 @@ def test_timings_equal_the_rings_sums(devices, tmp_path):
     # saves at steps 1 (an empty directory's first), 2 and 4, and the final
     # wait that makes them durable
     assert result.timings["save"]["count"] == 4
-    assert spans[-1]["name"] == "train.save" and spans[-1]["parentId"] is None
+    # ... the last thing the call does, under its root
+    last = _train(inside)[-1]
+    assert last["name"] == "train.save" and last["parentId"] == root["spanId"]
 
     # a resumed call finds the checkpoint: a restore, no new state, no step
     again, spans = _fit_spans(
@@ -145,8 +169,13 @@ def test_timings_equal_the_rings_sums(devices, tmp_path):
         checkpointer=Checkpointer(tmp_path / "ck", save_interval_steps=2),
     )
     assert again.resumed_from == 4 and again.steps_done == 0
-    assert [s["name"] for s in spans] == ["train.restore"]
-    assert set(again.timings) == {"restore"}
+    assert [s["name"] for s in _train(spans)] == ["train.restore", "train.fit"]
+    assert spans[-1]["attributes"] == {
+        "total_steps": 4, "start_step": 4, "resumed_from": 4,
+    }
+    assert {n for n in again.timings if not n.startswith("compile.")} == {
+        "restore"
+    }
     ckpt.close()
 
 
